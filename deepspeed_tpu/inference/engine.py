@@ -76,7 +76,6 @@ survives every step. One engine, sharded or not.
 """
 
 import contextlib
-import functools
 import time
 
 import jax
@@ -125,6 +124,7 @@ from deepspeed_tpu.inference.kv_pool import (
 from deepspeed_tpu.inference.paging import PageAllocator
 from deepspeed_tpu.inference.adapters import GPT2Adapter
 from deepspeed_tpu.inference.scheduler import QueueFull, Scheduler
+from deepspeed_tpu.ops.transformer.kernels.attention import kernels_on_mesh
 from deepspeed_tpu.parallel import mesh as mesh_lib
 from deepspeed_tpu.telemetry import (
     HBMLedger,
@@ -597,7 +597,7 @@ class InferenceEngine(object):
 
         # Per-engine jit instances: their _cache_size() IS the compile
         # counter the zero-recompile guarantee is asserted against. The
-        # functools.partial wrapper gives each engine a distinct callable
+        # ``own`` wrapper gives each engine a distinct callable
         # — jax's pjit cache is keyed on the underlying function, so two
         # engines jitting the bare program would pool their cache entries
         # and the counter would read other engines' compiles. Donating
@@ -605,14 +605,22 @@ class InferenceEngine(object):
         # call instead of double-buffering gigabytes of k/v. All three
         # wrappers exist on every engine (trace-free until called);
         # chunked mode only ever calls _mixed, legacy only the other two.
+        def own(program):
+            # This engine's own callable, traced with the kernels launched
+            # shard-local over its mesh (no mesh: launched as they are).
+            def on_mesh(*args):
+                with kernels_on_mesh(mesh):
+                    return program(*args)
+            return on_mesh
+
         self._prefill = jax.jit(
-            functools.partial(_prefill_program), static_argnums=(1,),
+            own(_prefill_program), static_argnums=(1,),
             donate_argnums=(2,), out_shardings=prefill_out)
         self._decode = jax.jit(
-            functools.partial(_decode_chunk_program), static_argnums=(1, 2),
+            own(_decode_chunk_program), static_argnums=(1, 2),
             donate_argnums=(3,), out_shardings=decode_out)
         self._mixed = jax.jit(
-            functools.partial(_mixed_step_program), static_argnums=(1, 2, 3),
+            own(_mixed_step_program), static_argnums=(1, 2, 3),
             donate_argnums=(4,), out_shardings=mixed_out)
 
         # Perf X-ray (telemetry/xray.py): the compiled-program cost/

@@ -2,11 +2,10 @@
 
 Chaos engineering's core discipline (Basiri et al., "Chaos Engineering",
 IEEE Software '16) is that failure handling you never exercise is
-failure handling you don't have — the wedged-accelerator runs that
-blinded BENCH_r02–r05 went unnoticed for exactly that reason. This
-module is the exercise machinery: a ``FaultPlan`` names WHICH faults
-fire at WHICH engine steps, deterministically, so a chaos test is as
-reproducible as any other test in the suite.
+failure handling you don't have. This module is the exercise machinery:
+a ``FaultPlan`` names WHICH faults fire at WHICH engine steps,
+deterministically, so a chaos test is as reproducible as any other test
+in the suite.
 
 Fault model (each a distinct failure the engine must survive — see
 docs/RESILIENCE.md for the recovery story):

@@ -1653,6 +1653,14 @@ class ServingFleet(object):
             return None
 
     @property
+    def param_devices(self):
+        """The device each replica's parameters actually live on, read
+        back from the arrays (not from the placement plan) — what a
+        multi-chip smoke checks: one replica per chip."""
+        return [next(iter(jax.tree_util.tree_leaves(
+            rep.engine._params)[0].devices())) for rep in self.replicas]
+
+    @property
     def compile_counts(self):
         """Per-replica compiled-program counts — what the failover
         invariant pins: killing replica K must leave every other

@@ -16,8 +16,8 @@ module holds the pieces that don't touch the device:
   program call — nothing host-side can preempt it, so the watchdog's
   job is DETECTION, not interruption: a timer thread fires loudly
   (warning log + ``step_stalls`` counter + degraded health) the moment
-  a step overruns its budget, turning "the run went quiet" (the
-  BENCH_r02–r05 failure mode) into a timestamped, counted event.
+  a step overruns its budget, turning "the run went quiet" into a
+  timestamped, counted event.
 - The error taxonomy: ``NumericsError`` (harvest validity check caught
   device garbage), ``EngineDeadError`` (recovery retries exhausted —
   terminal), ``EngineDraining`` (admissions rejected during drain), and

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.ops.transformer import (DeepSpeedTransformerConfig,
                                            DeepSpeedTransformerLayer)
-from deepspeed_tpu.utils import jax_compat
 
 
 @dataclasses.dataclass
@@ -130,7 +129,7 @@ class BertEmbeddings(nn.Module):
             if sp is not None:
                 # Token-sharded: this shard holds global positions
                 # [idx*t, (idx+1)*t).
-                n = jax_compat.axis_size(sp)
+                n = jax.lax.axis_size(sp)
                 assert n * t <= cfg.max_position_embeddings, (
                     "global sequence {} exceeds max_position_embeddings={}"
                     .format(n * t, cfg.max_position_embeddings))
@@ -344,7 +343,7 @@ class BertForPreTraining(nn.Module):
                 # the same with or without this — the engine pmean's
                 # grads over 'seq' — but the psum makes the replication
                 # visible to vma checks and readers.
-                n = jax_compat.axis_size(sp)
+                n = jax.lax.axis_size(sp)
                 nsp = jax.lax.psum(nsp / n, sp)
             total = total + nsp
         return total

@@ -22,8 +22,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.utils import jax_compat
-
 
 @dataclasses.dataclass
 class GPT2Config:
@@ -197,10 +195,10 @@ class GPT2LMHeadModel(nn.Module):
             # sequence: offset the position table slice. The GLOBAL length
             # must fit the table — dynamic_slice would silently clamp an
             # out-of-range start to reuse early positions.
-            assert jax_compat.axis_size(sp) * T <= cfg.n_positions, (
+            assert jax.lax.axis_size(sp) * T <= cfg.n_positions, (
                 "global sequence {} ({} shards x {} local) exceeds "
-                "n_positions={}".format(jax_compat.axis_size(sp) * T,
-                                        jax_compat.axis_size(sp), T,
+                "n_positions={}".format(jax.lax.axis_size(sp) * T,
+                                        jax.lax.axis_size(sp), T,
                                         cfg.n_positions))
             pos0 = jax.lax.axis_index(sp) * T
             pe = jax.lax.dynamic_slice(wpe, (pos0, 0), (T, cfg.n_embd))
@@ -252,7 +250,7 @@ def _sequence_parallel_xent(x, wte, labels, cfg, axis):
     """
     from deepspeed_tpu.models.heads import chunked_tied_softmax_xent
 
-    n = jax_compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     # Shard i receives shard (i+1)'s first label (source j sends to j-1).
     perm = [(i, (i - 1) % n) for i in range(n)]
@@ -380,7 +378,7 @@ def gpt2_pipeline(config=None, num_stages=2, tied=None, compiled=False,
         raise ValueError("compiled GPT-2 pipeline requires tied=False")
     # (Flash attention works in compiled pipelines: the engine's
     # shard_map worker runs blocks shard-locally and flash entry points
-    # launch raw pallas kernels under the shard_local_kernels context.)
+    # launch raw pallas kernels inside a shard_map region.)
     blocks = [LayerSpec(GPT2PipeBlock, cfg) for _ in range(cfg.n_layer)]
     if tied:
         layers = ([TiedLayerSpec("embed", GPT2PipeEmbed, cfg)] + blocks +

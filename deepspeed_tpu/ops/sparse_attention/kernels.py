@@ -23,22 +23,17 @@ identical code path.
 """
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from deepspeed_tpu.ops import pallas_mode
 from deepspeed_tpu.ops.transformer.kernels.attention import (
-    _bwd_mode, _mxu_precision)
+    _bwd_mode, _mask_operand, _mxu_precision)
 
 NEG_INF = -1e30
-
-
-def _interpret():
-    return jax.default_backend() != "tpu"
-
 
 _LUT_OP = None  # lazily-loaded C++ lowering op (None until first use)
 
@@ -100,6 +95,14 @@ def build_luts(layout):
     return rows_to_lut(layout), rows_to_lut(layout.transpose(0, 2, 1))
 
 
+def _lut_row(lut_ref, deg):
+    """Offset of this program's row in the flattened LUT: the table rides
+    in SMEM (scalar prefetch) as one int32 vector [H * rows * deg] — a
+    (1, 1, deg) VMEM window on the 3-D table is a shape Mosaic refuses,
+    and its entries steer addresses, which is what SMEM is for."""
+    return (pl.program_id(1) * pl.num_programs(2) + pl.program_id(2)) * deg
+
+
 def _apply_masks(s, q_start, c, blk, kpm_blk, bias_blk, valid, causal,
                  kpm_mode, bias_mode):
     """Score post-processing shared by all kernels. s: [bq, blk] fp32."""
@@ -116,9 +119,10 @@ def _apply_masks(s, q_start, c, blk, kpm_blk, bias_blk, valid, causal,
 
 
 def _unpack(refs, n_out, has_kpm, has_bias):
-    """Split the flat pallas ref list into (q, k, v, lut, kpm, bias, rest...)."""
+    """Split the flat pallas ref list into (q, k, v, lut, kpm, bias, rest...).
+    The LUT is the scalar-prefetch operand, so it leads the list."""
     refs = list(refs)
-    q_ref, k_ref, v_ref, lut_ref = refs[:4]
+    lut_ref, q_ref, k_ref, v_ref = refs[:4]
     idx = 4
     kpm_ref = bias_ref = None
     if has_kpm:
@@ -130,7 +134,7 @@ def _unpack(refs, n_out, has_kpm, has_bias):
     return q_ref, k_ref, v_ref, lut_ref, kpm_ref, bias_ref, refs[idx:]
 
 
-def _fwd_kernel(*refs, scale, blk, causal, has_kpm, has_bias, kpm_mode,
+def _fwd_kernel(*refs, scale, blk, deg, causal, has_kpm, has_bias, kpm_mode,
                 bias_mode, precision):
     (q_ref, k_ref, v_ref, lut_ref, kpm_ref, bias_ref,
      (o_ref, lse_ref)) = _unpack(refs, 2, has_kpm, has_bias)
@@ -138,11 +142,11 @@ def _fwd_kernel(*refs, scale, blk, causal, has_kpm, has_bias, kpm_mode,
     q = q_ref[0, 0].astype(jnp.float32) * scale            # [bq, d]
     bq, d = q.shape
     iq = pl.program_id(2)
-    max_deg = lut_ref.shape[2]
+    row = _lut_row(lut_ref, deg)
 
     def body(j, carry):
         acc, m_prev, l_prev = carry
-        col = lut_ref[0, 0, j]
+        col = lut_ref[row + j]
         valid = col >= 0
         c = jnp.maximum(col, 0)
         k_blk = k_ref[0, 0, pl.ds(c * blk, blk)].astype(jnp.float32)
@@ -169,7 +173,7 @@ def _fwd_kernel(*refs, scale, blk, causal, has_kpm, has_bias, kpm_mode,
         return acc, m_new, l_new
 
     acc, m, l = jax.lax.fori_loop(
-        0, max_deg, body,
+        0, deg, body,
         (jnp.zeros((bq, d), jnp.float32),
          jnp.full((bq, 1), NEG_INF, jnp.float32),
          jnp.zeros((bq, 1), jnp.float32)))
@@ -205,8 +209,8 @@ def _recompute_p_ds(q, do, lse, delta, k_blk, v_blk, kpm_blk, bias_blk,
     return p, ds
 
 
-def _bwd_dq_kernel(*refs, scale, blk, causal, has_kpm, has_bias, kpm_mode,
-                   bias_mode, precision):
+def _bwd_dq_kernel(*refs, scale, blk, deg, causal, has_kpm, has_bias,
+                   kpm_mode, bias_mode, precision):
     (q_ref, k_ref, v_ref, lut_ref, kpm_ref, bias_ref,
      (do_ref, lse_ref, delta_ref, dq_ref)) = _unpack(refs, 1, has_kpm, has_bias)
 
@@ -216,9 +220,10 @@ def _bwd_dq_kernel(*refs, scale, blk, causal, has_kpm, has_bias, kpm_mode,
     delta = delta_ref[0, 0]
     bq, d = q.shape
     iq = pl.program_id(2)
+    row = _lut_row(lut_ref, deg)
 
     def body(j, dq):
-        col = lut_ref[0, 0, j]
+        col = lut_ref[row + j]
         valid = col >= 0
         c = jnp.maximum(col, 0)
         kv = pl.ds(c * blk, blk)
@@ -233,12 +238,11 @@ def _bwd_dq_kernel(*refs, scale, blk, causal, has_kpm, has_bias, kpm_mode,
                                         preferred_element_type=jnp.float32,
                                         precision=precision)
 
-    dq = jax.lax.fori_loop(0, lut_ref.shape[2], body,
-                           jnp.zeros((bq, d), jnp.float32))
+    dq = jax.lax.fori_loop(0, deg, body, jnp.zeros((bq, d), jnp.float32))
     dq_ref[0, 0] = dq.astype(dq_ref.dtype)
 
 
-def _bwd_fused_kernel(*refs, scale, blk, causal, has_kpm, has_bias,
+def _bwd_fused_kernel(*refs, scale, blk, deg, causal, has_kpm, has_bias,
                       kpm_mode, bias_mode, precision):
     """One-pass backward: dq, dk, dv from a single LUT-steered sweep.
 
@@ -269,8 +273,10 @@ def _bwd_fused_kernel(*refs, scale, blk, causal, has_kpm, has_bias,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
+    row = _lut_row(lut_ref, deg)
+
     def body(j, dq):
-        col = lut_ref[0, 0, j]
+        col = lut_ref[row + j]
         valid = col >= 0
         c = jnp.maximum(col, 0)
         kv = pl.ds(c * blk, blk)
@@ -291,8 +297,7 @@ def _bwd_fused_kernel(*refs, scale, blk, causal, has_kpm, has_bias,
                                         preferred_element_type=jnp.float32,
                                         precision=precision)
 
-    dq = jax.lax.fori_loop(0, lut_ref.shape[2], body,
-                           jnp.zeros((bq, d), jnp.float32))
+    dq = jax.lax.fori_loop(0, deg, body, jnp.zeros((bq, d), jnp.float32))
     dq_ref[0, 0] = dq.astype(dq_ref.dtype)
 
     @pl.when(i == n_q - 1)
@@ -301,8 +306,8 @@ def _bwd_fused_kernel(*refs, scale, blk, causal, has_kpm, has_bias,
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_dkv_kernel(*refs, scale, blk, bq, causal, has_kpm, has_bias, kpm_mode,
-                    bias_mode, precision):
+def _bwd_dkv_kernel(*refs, scale, blk, bq, deg, causal, has_kpm, has_bias,
+                    kpm_mode, bias_mode, precision):
     (q_ref, k_ref, v_ref, tlut_ref, kpm_ref, bias_ref,
      (do_ref, lse_ref, delta_ref, dk_ref, dv_ref)) = _unpack(
          refs, 2, has_kpm, has_bias)
@@ -312,10 +317,11 @@ def _bwd_dkv_kernel(*refs, scale, blk, bq, causal, has_kpm, has_bias, kpm_mode,
     d = k_blk.shape[1]
     jk = pl.program_id(2)
     kpm_blk = kpm_ref[0][None, :] if kpm_ref is not None else None  # [1, blk]
+    lut_row = _lut_row(tlut_ref, deg)
 
     def body(j, carry):
         dk, dv = carry
-        row = tlut_ref[0, 0, j]
+        row = tlut_ref[lut_row + j]
         valid = row >= 0
         r = jnp.maximum(row, 0)
         q = q_ref[0, 0, pl.ds(r * bq, bq)].astype(jnp.float32)
@@ -336,44 +342,10 @@ def _bwd_dkv_kernel(*refs, scale, blk, bq, causal, has_kpm, has_bias, kpm_mode,
         return dk, dv
 
     dk, dv = jax.lax.fori_loop(
-        0, tlut_ref.shape[2], body,
+        0, deg, body,
         (jnp.zeros((blk, d), jnp.float32), jnp.zeros((blk, d), jnp.float32)))
     dk_ref[0, 0] = dk.astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
-
-
-@functools.lru_cache(maxsize=None)
-def _sparse_fused_supported():
-    """One-time probe for the SPARSE fused backward: its dk/dv scratch
-    accumulation indexes VMEM by a LUT-loaded (data-dependent) offset —
-    strictly harder for Mosaic than the dense fused kernel's loop-index
-    offsets, so the dense probe (_fused_bwd_supported) does not cover it.
-    On rejection, auto mode keeps the split kernels for sparse attention
-    only. Off-TPU (interpret mode) the semantics are test-covered."""
-    if jax.default_backend() != "tpu":
-        return True
-    # Force the fused path for the probe itself via _make_fn's force_bwd
-    # parameter: attend_bwd consults this function on the auto path, so
-    # probing through the public grad would otherwise recurse (and
-    # mutating the DS_TPU_FLASH_BWD env var here would leak the forced
-    # mode to concurrent traces on other threads).
-    try:
-        blk = 128
-        layout = np.ones((1, 2, 2), np.int64)
-        fwd_lut, bwd_lut = build_luts(layout)
-        fn = _make_fn(fwd_lut, bwd_lut, blk, 1.0, False, False, False,
-                      'add', 'add', precision=None, force_bwd="fused")
-        q = jnp.zeros((1, 1, 2 * blk, 128), jnp.bfloat16)
-        g = jax.grad(lambda q_: jnp.sum(
-            fn(q_, q, q, None, None).astype(jnp.float32)))(q)
-        jax.block_until_ready(g)
-        return True
-    except Exception as e:  # compile/verification failure — not data
-        import warnings
-        warnings.warn("fused sparse backward unsupported on this backend "
-                      "({}); auto mode falls back to the split kernels"
-                      .format(str(e)[:500]))
-        return False
 
 
 # ---------------------------------------------------------------------------
@@ -385,10 +357,7 @@ _FN_CACHE = {}
 
 
 def _make_fn(fwd_lut, bwd_lut, blk, scale, causal, has_kpm, has_bias,
-             kpm_mode, bias_mode, precision=None, force_bwd=None):
-    # force_bwd pins the backward path ("fused"/"split") for this closure
-    # regardless of DS_TPU_FLASH_BWD / the support probe — used by
-    # _sparse_fused_supported so the probe never touches process state.
+             kpm_mode, bias_mode, precision=None):
     # LUTs stay numpy in the closure; they are converted per call so that a
     # closure first built under a jit trace never caches tracer constants.
     fwd_lut = np.asarray(fwd_lut)
@@ -396,35 +365,53 @@ def _make_fn(fwd_lut, bwd_lut, blk, scale, causal, has_kpm, has_bias,
     flags = dict(causal=causal, has_kpm=has_kpm, has_bias=has_bias,
                  kpm_mode=kpm_mode, bias_mode=bias_mode, precision=precision)
 
+    def launch(kernel, lut, grid, in_specs, out_specs, out_shape, args,
+               scratch=()):
+        """pallas_call with the LUT as the scalar-prefetch operand: one
+        flattened int32 vector in SMEM, read by the kernel bodies (index
+        maps receive it as a trailing argument and ignore it)."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        return pl.pallas_call(
+            functools.partial(kernel, scale=scale, blk=blk,
+                              deg=lut.shape[2], **flags),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+                out_specs=out_specs, scratch_shapes=list(scratch)),
+            out_shape=out_shape,
+            interpret=pallas_mode.interpret(),
+        )(jnp.asarray(lut.reshape(-1)), *args)
+
+    def specs(t, d):
+        """(q-block, full-length, row-block, full-row, mask, bias) specs of
+        the (batch, head, q-block) grid."""
+        return (
+            pl.BlockSpec((1, 1, blk, d), lambda b_, h_, i, _: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, t, d), lambda b_, h_, i, _: (b_, h_, 0, 0)),
+            pl.BlockSpec((1, 1, blk, 1), lambda b_, h_, i, _: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, t, 1), lambda b_, h_, i, _: (b_, h_, 0, 0)),
+            # [B, 1, T] with the batch dim squeezed: the kernel sees
+            # (1, T), and the window's last two dims equal the array's.
+            pl.BlockSpec((None, 1, t), lambda b_, h_, i, _: (b_, 0, 0)),
+            pl.BlockSpec((1, 1, blk, t), lambda b_, h_, i, _: (b_, h_, i, 0)),
+        )
+
     def fwd(q, k, v, kpm, bias):
         b, h, t, d = q.shape
-        lut = jnp.asarray(fwd_lut)
-        nq = t // blk
-        grid = (b, h, nq)
-        q_spec = pl.BlockSpec((1, 1, blk, d), lambda b_, h_, i: (b_, h_, i, 0))
-        full = pl.BlockSpec((1, 1, t, d), lambda b_, h_, i: (b_, h_, 0, 0))
-        lut_spec = pl.BlockSpec((1, 1, fwd_lut.shape[2]),
-                                lambda b_, h_, i: (h_, i, 0))
-        in_specs = [q_spec, full, full, lut_spec]
-        args = [q, k, v, lut]
+        q_spec, full, row_blk, _, kpm_spec, bias_spec = specs(t, d)
+        in_specs = [q_spec, full, full]
+        args = [q, k, v]
         if has_kpm:
-            in_specs.append(pl.BlockSpec((1, t), lambda b_, h_, i: (b_, 0)))
-            args.append(kpm.astype(jnp.float32))
+            in_specs.append(kpm_spec)
+            args.append(_mask_operand(kpm))
         if has_bias:
-            in_specs.append(pl.BlockSpec((1, 1, blk, t),
-                                         lambda b_, h_, i: (b_, h_, i, 0)))
+            in_specs.append(bias_spec)
             args.append(bias.astype(jnp.float32))
-        o, lse = pl.pallas_call(
-            functools.partial(_fwd_kernel, scale=scale, blk=blk, **flags),
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=[q_spec,
-                       pl.BlockSpec((1, 1, blk, 1),
-                                    lambda b_, h_, i: (b_, h_, i, 0))],
-            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                       jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32)],
-            interpret=_interpret(),
-        )(*args)
+        o, lse = launch(
+            _fwd_kernel, fwd_lut, (b, h, t // blk), in_specs,
+            [q_spec, row_blk],
+            [jax.ShapeDtypeStruct(q.shape, q.dtype),
+             jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32)], args)
         return o, lse
 
     @jax.custom_vjp
@@ -438,91 +425,59 @@ def _make_fn(fwd_lut, bwd_lut, blk, scale, causal, has_kpm, has_bias,
     def attend_bwd(res, g):
         q, k, v, kpm, bias, o, lse = res
         b, h, t, d = q.shape
-        lut = jnp.asarray(fwd_lut)
-        tlut = jnp.asarray(bwd_lut)
+        grid = (b, h, t // blk)
         do = g
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1, keepdims=True)
-        q_spec = pl.BlockSpec((1, 1, blk, d), lambda b_, h_, i: (b_, h_, i, 0))
-        full = pl.BlockSpec((1, 1, t, d), lambda b_, h_, i: (b_, h_, 0, 0))
-        row_blk = pl.BlockSpec((1, 1, blk, 1), lambda b_, h_, i: (b_, h_, i, 0))
-        row_full = pl.BlockSpec((1, 1, t, 1), lambda b_, h_, i: (b_, h_, 0, 0))
-        lut_spec = pl.BlockSpec((1, 1, fwd_lut.shape[2]),
-                                lambda b_, h_, i: (h_, i, 0))
+        q_spec, full, row_blk, row_full, kpm_spec, bias_spec = specs(t, d)
+        qkv_shapes = [jax.ShapeDtypeStruct(x.shape, x.dtype)
+                      for x in (q, k, v)]
 
-        in_specs = [q_spec, full, full, lut_spec]
-        args = [q, k, v, lut]
+        in_specs = [q_spec, full, full]
+        args = [q, k, v]
         if has_kpm:
-            in_specs.append(pl.BlockSpec((1, t), lambda b_, h_, i: (b_, 0)))
-            args.append(kpm.astype(jnp.float32))
+            in_specs.append(kpm_spec)
+            args.append(_mask_operand(kpm))
         if has_bias:
-            in_specs.append(pl.BlockSpec((1, 1, blk, t),
-                                         lambda b_, h_, i: (b_, h_, i, 0)))
+            in_specs.append(bias_spec)
             args.append(bias.astype(jnp.float32))
         in_specs += [q_spec, row_blk, row_blk]
         args += [do, lse, delta]
 
-        if force_bwd:
-            use_fused = force_bwd == "fused"
-        else:
-            use_fused = _bwd_mode(t, d, q.dtype) == "fused" and (
-                os.environ.get("DS_TPU_FLASH_BWD") == "fused"
-                or _sparse_fused_supported())
-        if use_fused:
+        if _bwd_mode(t, d, q.dtype) == "fused":
             # One LUT-steered sweep produces dq and scatter-accumulates
             # dk/dv into full-length fp32 scratch (same input layout as
             # the dq kernel, so the spec/arg lists are shared).
             from jax.experimental.pallas import tpu as pltpu
 
-            dq, dk, dv = pl.pallas_call(
-                functools.partial(_bwd_fused_kernel, scale=scale, blk=blk,
-                                  **flags),
-                grid=(b, h, t // blk),
-                in_specs=in_specs,
-                out_specs=[q_spec, full, full],
-                out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                           jax.ShapeDtypeStruct(k.shape, k.dtype),
-                           jax.ShapeDtypeStruct(v.shape, v.dtype)],
-                scratch_shapes=[pltpu.VMEM((t, d), jnp.float32),
-                                pltpu.VMEM((t, d), jnp.float32)],
-                interpret=_interpret(),
-            )(*args)
+            dq, dk, dv = launch(
+                _bwd_fused_kernel, fwd_lut, grid, in_specs,
+                [q_spec, full, full], qkv_shapes, args,
+                scratch=[pltpu.VMEM((t, d), jnp.float32),
+                         pltpu.VMEM((t, d), jnp.float32)])
             return _finish_bwd(q, k, v, kpm, bias, do, lse, delta,
                                dq, dk, dv)
 
-        dq = pl.pallas_call(
-            functools.partial(_bwd_dq_kernel, scale=scale, blk=blk, **flags),
-            grid=(b, h, t // blk),
-            in_specs=in_specs,
-            out_specs=q_spec,
-            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-            interpret=_interpret(),
-        )(*args)
+        dq = launch(_bwd_dq_kernel, fwd_lut, grid, in_specs, q_spec,
+                    qkv_shapes[0], args)
 
-        kv_spec = pl.BlockSpec((1, 1, blk, d), lambda b_, h_, j: (b_, h_, j, 0))
-        tlut_spec = pl.BlockSpec((1, 1, bwd_lut.shape[2]),
-                                 lambda b_, h_, j: (h_, j, 0))
-        in_specs = [full, kv_spec, kv_spec, tlut_spec]
-        args = [q, k, v, tlut]
+        # dk/dv: the same grid over KEY blocks, walking the transposed LUT.
+        kv_spec = q_spec
+        in_specs = [full, kv_spec, kv_spec]
+        args = [q, k, v]
         if has_kpm:
-            in_specs.append(pl.BlockSpec((1, blk), lambda b_, h_, j: (b_, j)))
-            args.append(kpm.astype(jnp.float32))
+            in_specs.append(pl.BlockSpec(
+                (None, 1, blk), lambda b_, h_, j, _: (b_, 0, j)))
+            args.append(_mask_operand(kpm))
         if has_bias:
-            in_specs.append(pl.BlockSpec((1, 1, t, blk),
-                                         lambda b_, h_, j: (b_, h_, 0, j)))
+            in_specs.append(pl.BlockSpec(
+                (1, 1, t, blk), lambda b_, h_, j, _: (b_, h_, 0, j)))
             args.append(bias.astype(jnp.float32))
         in_specs += [full, row_full, row_full]
         args += [do, lse, delta]
-        dk, dv = pl.pallas_call(
-            functools.partial(_bwd_dkv_kernel, scale=scale, blk=blk, bq=blk,
-                              **flags),
-            grid=(b, h, t // blk),
-            in_specs=in_specs,
-            out_specs=[kv_spec, kv_spec],
-            out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                       jax.ShapeDtypeStruct(v.shape, v.dtype)],
-            interpret=_interpret(),
-        )(*args)
+        dk, dv = launch(
+            functools.partial(_bwd_dkv_kernel, bq=blk), bwd_lut, grid,
+            in_specs, [kv_spec, kv_spec], qkv_shapes[1:], args)
 
         return _finish_bwd(q, k, v, kpm, bias, do, lse, delta, dq, dk, dv)
 

@@ -59,19 +59,16 @@ import threading
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.custom_partitioning import custom_partitioning
-from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
+
+from deepspeed_tpu.ops import pallas_mode
+from deepspeed_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 NEG_INF = -1e30
 # Lane width for the fp32 softmax-statistic scratch rows: Mosaic pads
 # second-minor×minor tiles to (8, 128), so statistics are kept broadcast
 # across a full 128-lane row instead of a width-1 column.
 _STATS_LANES = 128
-
-
-def _interpret():
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -119,6 +116,22 @@ def mha_reference(q, k, v, mask=None, causal=False, scale=None,
     return o
 
 
+def _mask_operand(mask):
+    """The [B, T_kv] additive padding mask as the fp32 [B, 1, T_kv] array
+    the kernels take. Mosaic wants the last two dims of a block to tile by
+    (8, 128) or equal the array's: a one-row window on the 2-D mask does
+    neither (1 is not B), the same window on the 3-D form does."""
+    return mask.astype(jnp.float32)[:, None, :]
+
+
+def _mask_spec(block_k, kv_block):
+    """BlockSpec for ``_mask_operand``: row b, key block ``kv_block(*grid
+    indices)``. The batch dim is squeezed, so the kernel still sees a
+    (1, block_k) ref."""
+    return pl.BlockSpec((None, 1, block_k),
+                        lambda b_, *idx: (b_, 0, kv_block(b_, *idx)))
+
+
 def _last_kv_block(iq, block_q, block_k):
     """Index of the last key block a causal query block iq attends to."""
     return ((iq + 1) * block_q - 1) // block_k
@@ -132,8 +145,7 @@ def _first_q_block(jk, block_q, block_k):
 def _tril_block(block_q, block_k):
     """Constant additive causal mask for a diagonal block (bq == bk).
     Built from iota primitives (not a materialized array) so functions
-    passing it stay const-free — custom_partitioning requires closed
-    jaxprs; XLA folds it to a constant anyway."""
+    passing it stay const-free; XLA folds it to a constant anyway."""
     r = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
     c = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
     return jnp.where(r >= c, jnp.float32(0.0), jnp.float32(NEG_INF))
@@ -352,9 +364,9 @@ def _flash_fwd_pallas(q, k, v, mask, scale, causal, block_q, block_k):
     ]
     args = [q, k, v]
     if mask is not None:
-        in_specs.append(
-            pl.BlockSpec((1, block_k), lambda b_, h_, i, j: (b_, kv_index(b_, h_, i, j)[2])))
-        args.append(mask.astype(jnp.float32))
+        in_specs.append(_mask_spec(
+            block_k, lambda b_, h_, i, j: kv_index(b_, h_, i, j)[2]))
+        args.append(_mask_operand(mask))
     if use_tril:
         in_specs.append(
             pl.BlockSpec((block_q, block_k), lambda b_, h_, i, j: (0, 0)))
@@ -380,7 +392,7 @@ def _flash_fwd_pallas(q, k, v, mask, scale, causal, block_q, block_k):
             pltpu.VMEM((block_q, _STATS_LANES), jnp.float32),
             pltpu.VMEM((block_q, _STATS_LANES), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(*args)
     return o, lse
 
@@ -681,45 +693,19 @@ def _fit_fused_bwd_tiles(t_kv, d, dtype, block_q, block_k, causal):
     return bq, bk
 
 
-@functools.lru_cache(maxsize=None)
-def _fused_bwd_supported():
-    """One-time probe: does this backend compile the fused backward's
-    dynamic-offset VMEM scratch accumulation? On a Mosaic version that
-    rejects the pattern, 'auto' must degrade to the split kernels instead
-    of failing every training step. Concrete tiny-shape call, so it is
-    safe to run even while an outer trace is in progress; off-TPU
-    (interpret mode) the semantics are test-covered, return True."""
-    if jax.default_backend() != "tpu":
-        return True
-    try:
-        b, h, t, d = 1, 1, 256, 128
-        z = jnp.zeros((b, h, t, d), jnp.bfloat16)
-        row = jnp.zeros((b, h, t, 1), jnp.float32)
-        out = _flash_bwd_fused_pallas(z, z, z, None, row, row, z,
-                                      scale=1.0, causal=True,
-                                      block_q=128, block_k=128)
-        jax.block_until_ready(out)
-        return True
-    except Exception as e:  # compile/verification failure — not data
-        import warnings
-        warnings.warn("fused flash backward unsupported on this backend "
-                      "({}); auto mode falls back to the split kernels"
-                      .format(str(e)[:500]))
-        return False
-
-
 def _bwd_mode(t_kv, d, dtype):
-    """'fused' or 'split' — env DS_TPU_FLASH_BWD overrides the VMEM fit.
-    Governs both the dense flash backward and the block-sparse one
+    """'fused' or 'split', decided from the shapes alone: fused when the
+    resident k/v/dk/dv set fits its VMEM budget (env DS_TPU_FLASH_BWD
+    overrides). Nothing is probed on the device — a fused kernel the
+    compiler refuses raises at the call that asked for it. Governs both
+    the dense flash backward and the block-sparse one
     (ops/sparse_attention/kernels.py), which share the kernel structure."""
     mode = os.environ.get("DS_TPU_FLASH_BWD", "auto")
     if mode in ("fused", "split"):
         return mode
     itemsize = jnp.dtype(dtype).itemsize
     resident = t_kv * d * (4 * itemsize + 2 * 4)
-    if resident > _RESIDENT_BWD_VMEM_BUDGET:
-        return "split"
-    return "fused" if _fused_bwd_supported() else "split"
+    return "split" if resident > _RESIDENT_BWD_VMEM_BUDGET else "fused"
 
 
 def _flash_bwd_fused_pallas(q, k, v, mask, delta, lse, do, scale, causal,
@@ -740,8 +726,8 @@ def _flash_bwd_fused_pallas(q, k, v, mask, delta, lse, do, scale, causal,
     in_specs = [q_spec, kv_full, kv_full]
     args = [q, k, v]
     if mask is not None:
-        in_specs.append(pl.BlockSpec((1, t_kv), lambda b_, h_, i: (b_, 0)))
-        args.append(mask.astype(jnp.float32))
+        in_specs.append(_mask_spec(t_kv, lambda b_, h_, i: 0))
+        args.append(_mask_operand(mask))
     if use_tril:
         in_specs.append(
             pl.BlockSpec((block_q, block_k), lambda b_, h_, i: (0, 0)))
@@ -761,11 +747,10 @@ def _flash_bwd_fused_pallas(q, k, v, mask, delta, lse, do, scale, causal,
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((t_kv, d), jnp.float32),
                         pltpu.VMEM((t_kv, d), jnp.float32)],
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(*args)
-    # Tuple, not pallas_call's list: the custom_partitioning wrapper
-    # declares tuple outputs and jax's out-tree flattening is
-    # container-type strict.
+    # Tuple, not pallas_call's list: callers unpack and re-wrap it, and
+    # jax's out-tree flattening is container-type strict.
     return dq, dk, dv
 
 
@@ -820,9 +805,9 @@ def _flash_bwd_pallas(q, k, v, mask, delta, lse, g, scale, causal, block_q,
     in_specs = [q_spec, kv_spec, kv_spec]
     args = [q, k, v]
     if mask is not None:
-        in_specs.append(
-            pl.BlockSpec((1, block_k), lambda b_, h_, i, j: (b_, kv_index(b_, h_, i, j)[2])))
-        args.append(mask.astype(jnp.float32))
+        in_specs.append(_mask_spec(
+            block_k, lambda b_, h_, i, j: kv_index(b_, h_, i, j)[2]))
+        args.append(_mask_operand(mask))
     if use_tril:
         in_specs.append(tril_spec)
         args.append(tril)
@@ -839,7 +824,7 @@ def _flash_bwd_pallas(q, k, v, mask, delta, lse, g, scale, causal, block_q,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[] if n_kv == 1 else
         [pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(*args)
 
     # dk/dv: grid over (kv block, q block), q innermost and pipelined.
@@ -862,8 +847,8 @@ def _flash_bwd_pallas(q, k, v, mask, delta, lse, g, scale, causal, block_q,
     in_specs = [q_spec2, kv_spec2, kv_spec2]
     args = [q, k, v]
     if mask is not None:
-        in_specs.append(pl.BlockSpec((1, block_k), lambda b_, h_, jk, i: (b_, jk)))
-        args.append(mask.astype(jnp.float32))
+        in_specs.append(_mask_spec(block_k, lambda b_, h_, jk, i: jk))
+        args.append(_mask_operand(mask))
     if use_tril:
         in_specs.append(tril_spec)
         args.append(tril)
@@ -882,153 +867,102 @@ def _flash_bwd_pallas(q, k, v, mask, delta, lse, g, scale, causal, block_q,
         scratch_shapes=[] if n_q == 1 else
         [pltpu.VMEM((block_k, d), jnp.float32),
          pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(*args)
 
     return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------
-# GSPMD integration — batch/head-parallel partitioning of the kernels.
+# Kernels on a mesh — batch/head-parallel, through shard_map.
 #
-# XLA's SPMD partitioner cannot see inside a pallas_call: without a rule it
-# replicates the operands ("involuntary full rematerialization"), turning
-# data-parallel attention into a full all-gather per step. The kernels are
-# embarrassingly parallel over batch and heads, so custom_partitioning
-# declares exactly that: b/h follow the operand sharding, sequence and
-# head-dim are replicated (for both the GSPMD callback API and the Shardy
-# einsum rule). Each shard then runs the plain pallas kernel on its local
-# [b/dp, h/mp, T, D] block. This is the TPU analogue of the reference's
-# data-parallel engine wrapping its CUDA kernels (engine.py:508-528 —
-# kernels see local tensors, the framework owns the distribution).
+# XLA's SPMD partitioner cannot see inside a pallas_call, and for a TPU it
+# refuses to partition one at all ("Mosaic kernels cannot be automatically
+# partitioned. Please wrap the call in a shard_map"). The kernels are
+# embarrassingly parallel over batch and heads, so that is what the wrapper
+# declares: batch over 'data', heads over 'model', sequence and head-dim
+# whole. Each shard then runs the plain pallas kernel on its local
+# [b/dp, h/mp, T, D] block — the TPU analogue of the reference's
+# data-parallel engine wrapping its CUDA kernels (engine.py:508-528: kernels
+# see local tensors, the framework owns the distribution).
+# (jax.experimental.custom_partitioning could read the split off the operands
+# instead, but the TPU compiler of this installation refuses it: "Custom
+# emitter for CustomSPMDPartitioning not found", on four v5e chips, PR 21.)
+#
+# The mesh is AMBIENT: whoever jits a model enters kernels_on_mesh(mesh)
+# inside the function it jits, so every trace of that function passes
+# through it. Inside a shard_map region arrays are already shard-local and
+# the kernels launch raw.
 # ---------------------------------------------------------------------------
 
-
-_shard_local = threading.local()
+_ambient = threading.local()
 
 
 @contextlib.contextmanager
-def shard_local_kernels():
-    """Within this context, flash entry points skip the
-    custom_partitioning wrapper and launch the raw pallas kernels —
-    for callers that are ALREADY inside a manual-sharding region
-    (shard_map), where every array is shard-local and GSPMD has nothing
-    to partition (custom_partitioning is not usable there). Thread-local
-    and re-entrant; only tracing cares."""
-    prev = getattr(_shard_local, "on", False)
-    _shard_local.on = True
+def kernels_on_mesh(mesh):
+    """Within this context the kernel entry points (flash attention here,
+    the decode family in decode_attention.py) split their [B, H, ...]
+    operands over ``mesh`` — batch over its 'data' axis, heads over 'model'
+    — and launch shard-local. Thread-local and re-entrant; only tracing
+    cares, so enter it inside the traced function."""
+    prev = getattr(_ambient, "mesh", None)
+    _ambient.mesh = mesh
     try:
         yield
     finally:
-        _shard_local.on = prev
+        _ambient.mesh = prev
 
 
-def _use_custom_partitioning():
-    return os.environ.get("DS_TPU_NO_CUSTOM_PARTITION", "0") != "1" \
-        and not getattr(_shard_local, "on", False)
+def kernel_sharding(batch, heads):
+    """How a kernel call with ``batch`` rows and ``heads`` heads is split:
+    None (launch raw: no ambient mesh, a one-device mesh, or already inside
+    a shard_map region) or the hashable ``(mesh, batch_axis, head_axis)``
+    — an axis is None when the mesh does not split that dim evenly, and the
+    kernel then sees it whole on every shard."""
+    mesh = getattr(_ambient, "mesh", None)
+    if mesh is None or mesh.size == 1 or \
+            jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+
+    def axis(name, extent):
+        size = mesh.shape.get(name, 1)
+        return name if size > 1 and extent % size == 0 else None
+
+    return mesh, axis(DATA_AXIS, batch), axis(MODEL_AXIS, heads)
 
 
-def _bh_spec(sharding):
-    """(batch, head) partition entries of an operand sharding, or (None,
-    None) when unknown/unsharded."""
-    spec = getattr(sharding, "spec", None)
-    if spec is None:
-        return (None, None)
-    spec = tuple(spec) + (None,) * (4 - len(spec))
-    return spec[0], spec[1]
+def on_shards(fn, shard, in_dims, out_dims):
+    """``fn`` launched shard-local per ``shard`` (a ``kernel_sharding``
+    result; None returns ``fn`` itself). ``in_dims`` / ``out_dims`` name,
+    per operand and per result, what its leading dims are: ``"bh"`` for
+    [B, H, ...], ``"b"`` for [B, ...], ``"-h"`` for [pages, H, ...]; the
+    remaining dims stay whole."""
+    if shard is None:
+        return fn
+    mesh, b, h = shard
+
+    def spec(dims):
+        return P(*({"b": b, "h": h, "-": None}[c] for c in dims))
+
+    outs = tuple(spec(d) for d in out_dims)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=tuple(spec(d) for d in in_dims),
+                         out_specs=outs if len(outs) > 1 else outs[0],
+                         check_vma=False)
 
 
-def _def_partition(cp, partition, infer, rule, factors):
-    """def_partition across jax versions: newer jax accepts a Shardy
-    ``sharding_rule`` (+ ``need_replication_factors``); jax 0.4.37's
-    def_partition takes neither and relies on the GSPMD callbacks alone.
-    Feature-detect so the same wrapper works on both."""
-    import inspect
-    params = inspect.signature(
-        custom_partitioning.def_partition).parameters
-    kw = {}
-    if "sharding_rule" in params:
-        kw["sharding_rule"] = rule
-        if "need_replication_factors" in params:
-            kw["need_replication_factors"] = factors
-    cp.def_partition(partition=partition,
-                     infer_sharding_from_operands=infer, **kw)
-
-
-def _cp_wrap(fn, n_in, n_out, rule, mask_pos=None):
-    """Wrap fn (shard-local pallas launcher) in custom_partitioning with
-    b/h-parallel shardings. Inputs/outputs are [B, H, ...] except an
-    optional [B, T_kv] mask at mask_pos; lse outputs are [B, H, T, 1]."""
-    cp = custom_partitioning(fn)
-
-    def shardings(mesh, q_sharding):
-        b, h = _bh_spec(q_sharding)
-        full = NamedSharding(mesh, P(b, h, None, None))
-        mask_sh = NamedSharding(mesh, P(b, None))
-        args = tuple(full if i != mask_pos else mask_sh
-                     for i in range(n_in))
-        outs = (full,) * n_out
-        return args, outs
-
-    def infer(mesh, arg_shapes, shape):
-        _, outs = shardings(mesh, arg_shapes[0].sharding)
-        return outs if n_out > 1 else outs[0]
-
-    def partition(mesh, arg_shapes, result_shape):
-        args, outs = shardings(mesh, arg_shapes[0].sharding)
-        return mesh, fn, (outs if n_out > 1 else outs[0]), args
-
-    # Factors ordered by first appearance in the rule (Shardy requires
-    # sorted factor indices): t then d (from q), s (from k), u (from lse).
-    _def_partition(cp, partition, infer, rule, ("t", "d", "s", "u"))
-    return cp
-
-
-@functools.lru_cache(maxsize=None)
-def _fwd_partitioned(has_mask, scale, causal, block_q, block_k):
-    if has_mask:
-        def f(q, k, v, mask):
-            return _flash_fwd_pallas(q, k, v, mask, scale, causal,
-                                     block_q, block_k)
-        rule = "b h t d, b h s d, b h s d, b s -> b h t d, b h t u"
-        return _cp_wrap(f, 4, 2, rule, mask_pos=3)
-
-    def f(q, k, v):
-        return _flash_fwd_pallas(q, k, v, None, scale, causal,
-                                 block_q, block_k)
-    rule = "b h t d, b h s d, b h s d -> b h t d, b h t u"
-    return _cp_wrap(f, 3, 2, rule)
-
-
-@functools.lru_cache(maxsize=None)
-def _bwd_partitioned(has_mask, scale, causal, block_q, block_k):
-    if has_mask:
-        def f(q, k, v, mask, delta, lse, do):
-            return _flash_bwd_pallas(q, k, v, mask, delta, lse, do, scale,
-                                     causal, block_q, block_k)
-        rule = ("b h t d, b h s d, b h s d, b s, b h t u, b h t u, b h t d "
-                "-> b h t d, b h s d, b h s d")
-        return _cp_wrap(f, 7, 3, rule, mask_pos=3)
-
-    def f(q, k, v, delta, lse, do):
-        return _flash_bwd_pallas(q, k, v, None, delta, lse, do, scale,
+def _flash_fwd(q, k, v, mask, scale, causal, block_q, block_k, shard=None):
+    def f(q, k, v, *mask):
+        return _flash_fwd_pallas(q, k, v, mask[0] if mask else None, scale,
                                  causal, block_q, block_k)
-    rule = ("b h t d, b h s d, b h s d, b h t u, b h t u, b h t d "
-            "-> b h t d, b h s d, b h s d")
-    return _cp_wrap(f, 6, 3, rule)
 
-
-def _flash_fwd(q, k, v, mask, scale, causal, block_q, block_k, raw=False):
-    if not raw and _use_custom_partitioning():
-        f = _fwd_partitioned(mask is not None, scale, causal,
-                             block_q, block_k)
-        args = (q, k, v) if mask is None else (q, k, v, mask)
-        return f(*args)
-    return _flash_fwd_pallas(q, k, v, mask, scale, causal, block_q, block_k)
+    args = (q, k, v) if mask is None else (q, k, v, mask)
+    in_dims = ("bh",) * 3 + (("b",) if mask is not None else ())
+    return on_shards(f, shard, in_dims, ("bh", "bh"))(*args)
 
 
 def _flash_bwd(res, g, scale, causal, block_q, block_k, dlse=None,
-               raw=False):
+               shard=None):
     q, k, v, mask, o, lse = res
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)
@@ -1036,15 +970,14 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, dlse=None,
         # An lse cotangent folds into the same kernels: dlse_i/ds_ij = p_ij,
         # so ds = p * (dp - (delta - dlse)) — a pure delta shift.
         delta = delta - dlse
-    if not raw and _use_custom_partitioning():
-        f = _bwd_partitioned(mask is not None, scale, causal,
-                             block_q, block_k)
-        args = (q, k, v, delta, lse, g) if mask is None else \
-            (q, k, v, mask, delta, lse, g)
-        dq, dk, dv = f(*args)
-    else:
-        dq, dk, dv = _flash_bwd_pallas(q, k, v, mask, delta, lse, g, scale,
-                                       causal, block_q, block_k)
+
+    def f(q, k, v, delta, lse, g, *mask):
+        return _flash_bwd_pallas(q, k, v, mask[0] if mask else None, delta,
+                                 lse, g, scale, causal, block_q, block_k)
+
+    args = (q, k, v, delta, lse, g) + (() if mask is None else (mask,))
+    in_dims = ("bh",) * 6 + (("b",) if mask is not None else ())
+    dq, dk, dv = on_shards(f, shard, in_dims, ("bh",) * 3)(*args)
     dmask = None if mask is None else jnp.zeros_like(mask)
     return dq, dk, dv, dmask
 
@@ -1053,26 +986,26 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, dlse=None,
 # Public entry point
 # ---------------------------------------------------------------------------
 
-# ``raw`` (shard-local) is a STATIC nondiff arg captured at the public
-# entry: the custom_vjp backward is traced lazily at transpose time —
-# possibly after the shard_local_kernels context has exited — so the
+# ``shard`` (a kernel_sharding result) is a STATIC nondiff arg captured at
+# the public entry: the custom_vjp backward is traced lazily at transpose
+# time — possibly after the kernels_on_mesh context has exited — so the
 # decision must ride the residual-free static args, not the thread-local.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash_attention(q, k, v, mask, scale, causal, block_q, block_k, raw):
+def _flash_attention(q, k, v, mask, scale, causal, block_q, block_k, shard):
     o, _ = _flash_fwd(q, k, v, mask, scale, causal, block_q, block_k,
-                      raw=raw)
+                      shard=shard)
     return o
 
 
 def _flash_attention_fwd(q, k, v, mask, scale, causal, block_q, block_k,
-                         raw):
+                         shard):
     o, lse = _flash_fwd(q, k, v, mask, scale, causal, block_q, block_k,
-                        raw=raw)
+                        shard=shard)
     return o, (q, k, v, mask, o, lse)
 
 
-def _flash_attention_bwd(scale, causal, block_q, block_k, raw, res, g):
-    return _flash_bwd(res, g, scale, causal, block_q, block_k, raw=raw)
+def _flash_attention_bwd(scale, causal, block_q, block_k, shard, res, g):
+    return _flash_bwd(res, g, scale, causal, block_q, block_k, shard=shard)
 
 
 _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
@@ -1080,24 +1013,25 @@ _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _flash_attention_lse(q, k, v, mask, scale, causal, block_q, block_k,
-                         raw):
+                         shard):
     """(o, lse) variant — lse is differentiable too (ring attention merges
     partial results through it)."""
     return _flash_fwd(q, k, v, mask, scale, causal, block_q, block_k,
-                      raw=raw)
+                      shard=shard)
 
 
 def _flash_attention_lse_fwd(q, k, v, mask, scale, causal, block_q,
-                             block_k, raw):
+                             block_k, shard):
     o, lse = _flash_fwd(q, k, v, mask, scale, causal, block_q, block_k,
-                        raw=raw)
+                        shard=shard)
     return (o, lse), (q, k, v, mask, o, lse)
 
 
-def _flash_attention_lse_bwd(scale, causal, block_q, block_k, raw, res, g):
+def _flash_attention_lse_bwd(scale, causal, block_q, block_k, shard, res,
+                             g):
     do, dlse = g
     return _flash_bwd(res, do, scale, causal, block_q, block_k, dlse=dlse,
-                      raw=raw)
+                      shard=shard)
 
 
 _flash_attention_lse.defvjp(_flash_attention_lse_fwd,
@@ -1118,7 +1052,7 @@ def flash_attention_with_lse(q, k, v, mask=None, causal=False, scale=None,
                              scale=scale, return_lse=True)
     return _flash_attention_lse(q, k, v, mask, float(scale), bool(causal),
                                 block_q, block_k,
-                                not _use_custom_partitioning())
+                                kernel_sharding(q.shape[0], q.shape[1]))
 
 
 def flash_signature(b, h, t_q, t_kv, d, dtype, causal):
@@ -1168,7 +1102,7 @@ def _autotuned_blocks(q, k, v, causal, default_q, default_k):
                 x_, y_, z_ = carry
                 g = jax.grad(lambda a, b_, c: _flash_attention(
                     a, b_, c, None, 1.0 / d ** 0.5, bool(causal), bq, bk,
-                    False).astype(jnp.float32).sum(),
+                    None).astype(jnp.float32).sum(),
                     argnums=(0, 1, 2))(x_, y_, z_)
                 return (x_ + g[0] * eps, y_ + g[1] * eps,
                         z_ + g[2] * eps), None
@@ -1195,7 +1129,7 @@ def resolve_block_sizes(q, k, v, causal, block_q, block_k,
     TPU), default otherwise, clamp to the sequence extents, and flag
     shapes the tiled kernels cannot take (ragged => dense fallback)."""
     t_q, t_kv = q.shape[2], k.shape[2]
-    if block_q is None and block_k is None and not _interpret():
+    if block_q is None and block_k is None and not pallas_mode.interpret():
         block_q, block_k = _autotuned_blocks(q, k, v, causal,
                                              default_q, default_k)
     bq = min(int(block_q or default_q), t_q)
@@ -1234,4 +1168,4 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
         return mha_reference(q, k, v, mask=mask, causal=causal, scale=scale)
     return _flash_attention(q, k, v, mask, float(scale), bool(causal),
                             block_q, block_k,
-                            not _use_custom_partitioning())
+                            kernel_sharding(q.shape[0], q.shape[1]))

@@ -43,22 +43,18 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.custom_partitioning import custom_partitioning
-from jax.sharding import NamedSharding
-from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.analysis.annotations import hot_path
+from deepspeed_tpu.ops import pallas_mode
 from deepspeed_tpu.ops.transformer.kernels.attention import (
     NEG_INF,
     _STATS_LANES,
-    _bh_spec,
-    _def_partition,
     _exp_lowp,
-    _interpret,
     _is_lowp,
     _mxu_precision,
     _pv_rowsum,
-    _use_custom_partitioning,
+    kernel_sharding,
+    on_shards,
 )
 
 # Length-dimension tile quantum: one 128-lane row of the score tile. The
@@ -295,7 +291,7 @@ def _flash_decode_pallas(q, k, v, pos, scale, block_k):
                           single_kv=n_kv == 1),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d), q.dtype),
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(pos, q, k, v)
     return out[:, :, :s] if s_blk != s else out
 
@@ -430,7 +426,7 @@ def _flash_decode_q8_pallas(q, k, v, k_scale, v_scale, pos, scale, block_k):
                           single_kv=n_kv == 1),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d), q.dtype),
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(pos, q, k, v, k_scale, v_scale)
     return out[:, :, :s] if s_blk != s else out
 
@@ -527,76 +523,19 @@ def resolve_decode_block(q, k, block_k=None, v=None, pos=None, scales=None,
     traced = any(isinstance(x, jax.core.Tracer)
                  for x in operands if x is not None)
     arrays = None
-    if not traced and not _interpret() and v is not None and pos is not None:
+    if not traced and not pallas_mode.interpret() and v is not None and pos is not None:
         arrays = (q, k, v) + (tuple(scales) if scales else ())
     return _autotuned_block((b, h, s, t_kv, d), q.dtype, cands, default,
                             arrays=arrays, family=family)
 
 
 # ---------------------------------------------------------------------------
-# GSPMD integration — batch/head-parallel partitioning, mirroring
-# attention.py's _cp_wrap (b/h follow the operand sharding, length and
-# head-dim replicate; pos is a [B] vector sharded like the batch dim).
-# Without the rule XLA would replicate the whole kv pool into every shard.
+# On a mesh the kernels launch shard-local through attention.on_shards —
+# batch over 'data', heads over 'model', length and head-dim whole; pos is a
+# [B] vector split like the batch dim, a page arena [P, H, page_len, D]
+# splits its heads only. Without it XLA would refuse to partition the
+# kernel (TPU) or replicate the whole kv pool into every shard.
 # ---------------------------------------------------------------------------
-
-@functools.lru_cache(maxsize=None)
-def _decode_partitioned(scale, block_k):
-    def f(q, k, v, pos):
-        return _flash_decode_pallas(q, k, v, pos, scale, block_k)
-
-    cp = custom_partitioning(f)
-
-    def shardings(mesh, q_sharding):
-        b, h = _bh_spec(q_sharding)
-        full = NamedSharding(mesh, P(b, h, None, None))
-        pos_sh = NamedSharding(mesh, P(b))
-        return (full, full, full, pos_sh), (full,)
-
-    def infer(mesh, arg_shapes, shape):
-        return shardings(mesh, arg_shapes[0].sharding)[1][0]
-
-    def partition(mesh, arg_shapes, result_shape):
-        args, outs = shardings(mesh, arg_shapes[0].sharding)
-        return mesh, f, outs[0], args
-
-    # Factors ordered by first appearance in the rule (Shardy requires
-    # sorted factor indices): t, d (from q), s (from k).
-    _def_partition(cp, partition, infer,
-                   "b h t d, b h s d, b h s d, b -> b h t d",
-                   ("t", "d", "s"))
-    return cp
-
-
-@functools.lru_cache(maxsize=None)
-def _decode_q8_partitioned(scale, block_k):
-    def f(q, k, v, k_scale, v_scale, pos):
-        return _flash_decode_q8_pallas(q, k, v, k_scale, v_scale, pos,
-                                       scale, block_k)
-
-    cp = custom_partitioning(f)
-
-    def shardings(mesh, q_sharding):
-        b, h = _bh_spec(q_sharding)
-        full = NamedSharding(mesh, P(b, h, None, None))
-        sc = NamedSharding(mesh, P(b, h, None))
-        pos_sh = NamedSharding(mesh, P(b))
-        return (full, full, full, sc, sc, pos_sh), (full,)
-
-    def infer(mesh, arg_shapes, shape):
-        return shardings(mesh, arg_shapes[0].sharding)[1][0]
-
-    def partition(mesh, arg_shapes, result_shape):
-        args, outs = shardings(mesh, arg_shapes[0].sharding)
-        return mesh, f, outs[0], args
-
-    # Scale planes shard exactly like their codes minus the head dim:
-    # [b, h, s] follows the kv sharding, length replicated.
-    _def_partition(cp, partition, infer,
-                   "b h t d, b h s d, b h s d, b h s, b h s, b -> b h t d",
-                   ("t", "d", "s"))
-    return cp
-
 
 # ---------------------------------------------------------------------------
 # Public entry point
@@ -627,9 +566,11 @@ def flash_decode_attention(q, k, v, pos, scale=None, block_k=None):
     bk = resolve_decode_block(q, k, block_k=block_k, v=v, pos=pos)
     if bk is None:
         return decode_attention_reference(q, k, v, pos, scale=scale)
-    if _use_custom_partitioning():
-        return _decode_partitioned(float(scale), int(bk))(q, k, v, pos)
-    return _flash_decode_pallas(q, k, v, pos, float(scale), int(bk))
+    return on_shards(
+        functools.partial(_flash_decode_pallas, scale=float(scale),
+                          block_k=int(bk)),
+        kernel_sharding(q.shape[0], q.shape[1]),
+        ("bh", "bh", "bh", "b"), ("bh",))(q, k, v, pos)
 
 
 @hot_path
@@ -652,11 +593,12 @@ def flash_decode_attention_q8(q, k, v, k_scale, v_scale, pos, scale=None,
     if bk is None:
         return decode_attention_q8_reference(q, k, v, k_scale, v_scale,
                                              pos, scale=scale)
-    if _use_custom_partitioning():
-        return _decode_q8_partitioned(float(scale), int(bk))(
+    return on_shards(
+        functools.partial(_flash_decode_q8_pallas, scale=float(scale),
+                          block_k=int(bk)),
+        kernel_sharding(q.shape[0], q.shape[1]),
+        ("bh", "bh", "bh", "bh", "bh", "b"), ("bh",))(
             q, k, v, k_scale, v_scale, pos)
-    return _flash_decode_q8_pallas(q, k, v, k_scale, v_scale, pos,
-                                   float(scale), int(bk))
 
 
 # ---------------------------------------------------------------------------
@@ -768,7 +710,7 @@ def _flash_decode_paged_pallas(q, k, v, tbl, pos, scale):
                           single_kv=n_lp == 1),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d), q.dtype),
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(pos, tbl, q, k, v)
     return out[:, :, :s] if s_blk != s else out
 
@@ -819,72 +761,9 @@ def _flash_decode_paged_q8_pallas(q, k, v, k_scale, v_scale, tbl, pos,
                           block_k=page_len, single_kv=n_lp == 1),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d), q.dtype),
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(pos, tbl, q, k, v, k_scale, v_scale)
     return out[:, :, :s] if s_blk != s else out
-
-
-@functools.lru_cache(maxsize=None)
-def _decode_paged_partitioned(scale):
-    def f(q, k, v, tbl, pos):
-        return _flash_decode_paged_pallas(q, k, v, tbl, pos, scale)
-
-    cp = custom_partitioning(f)
-
-    def shardings(mesh, q_sharding):
-        b, h = _bh_spec(q_sharding)
-        full = NamedSharding(mesh, P(b, h, None, None))
-        # The arena's page dim replicates (every shard must reach every
-        # page — the table is data, not layout); heads shard like q.
-        arena = NamedSharding(mesh, P(None, h, None, None))
-        tbl_sh = NamedSharding(mesh, P(b, None))
-        pos_sh = NamedSharding(mesh, P(b))
-        return (full, arena, arena, tbl_sh, pos_sh), (full,)
-
-    def infer(mesh, arg_shapes, shape):
-        return shardings(mesh, arg_shapes[0].sharding)[1][0]
-
-    def partition(mesh, arg_shapes, result_shape):
-        args, outs = shardings(mesh, arg_shapes[0].sharding)
-        return mesh, f, outs[0], args
-
-    # Factors ordered by first appearance: t, d (q), p, s (arena),
-    # n (table).
-    _def_partition(cp, partition, infer,
-                   "b h t d, p h s d, p h s d, b n, b -> b h t d",
-                   ("t", "d", "p", "s", "n"))
-    return cp
-
-
-@functools.lru_cache(maxsize=None)
-def _decode_paged_q8_partitioned(scale):
-    def f(q, k, v, k_scale, v_scale, tbl, pos):
-        return _flash_decode_paged_q8_pallas(q, k, v, k_scale, v_scale,
-                                             tbl, pos, scale)
-
-    cp = custom_partitioning(f)
-
-    def shardings(mesh, q_sharding):
-        b, h = _bh_spec(q_sharding)
-        full = NamedSharding(mesh, P(b, h, None, None))
-        arena = NamedSharding(mesh, P(None, h, None, None))
-        sc = NamedSharding(mesh, P(None, h, None))
-        tbl_sh = NamedSharding(mesh, P(b, None))
-        pos_sh = NamedSharding(mesh, P(b))
-        return (full, arena, arena, sc, sc, tbl_sh, pos_sh), (full,)
-
-    def infer(mesh, arg_shapes, shape):
-        return shardings(mesh, arg_shapes[0].sharding)[1][0]
-
-    def partition(mesh, arg_shapes, result_shape):
-        args, outs = shardings(mesh, arg_shapes[0].sharding)
-        return mesh, f, outs[0], args
-
-    _def_partition(
-        cp, partition, infer,
-        "b h t d, p h s d, p h s d, p h s, p h s, b n, b -> b h t d",
-        ("t", "d", "p", "s", "n"))
-    return cp
 
 
 @hot_path
@@ -915,11 +794,10 @@ def flash_decode_attention_paged(q, k, v, block_tbl, pos, scale=None):
     if not decode_supported(page_len):
         return decode_attention_paged_reference(q, k, v, block_tbl, pos,
                                                 scale=scale)
-    if _use_custom_partitioning():
-        return _decode_paged_partitioned(float(scale))(
-            q, k, v, block_tbl, pos)
-    return _flash_decode_paged_pallas(q, k, v, block_tbl, pos,
-                                      float(scale))
+    return on_shards(
+        functools.partial(_flash_decode_paged_pallas, scale=float(scale)),
+        kernel_sharding(q.shape[0], q.shape[1]),
+        ("bh", "-h", "-h", "b", "b"), ("bh",))(q, k, v, block_tbl, pos)
 
 
 @hot_path
@@ -936,8 +814,8 @@ def flash_decode_attention_paged_q8(q, k, v, k_scale, v_scale, block_tbl,
     if not decode_supported(page_len):
         return decode_attention_paged_q8_reference(
             q, k, v, k_scale, v_scale, block_tbl, pos, scale=scale)
-    if _use_custom_partitioning():
-        return _decode_paged_q8_partitioned(float(scale))(
+    return on_shards(
+        functools.partial(_flash_decode_paged_q8_pallas, scale=float(scale)),
+        kernel_sharding(q.shape[0], q.shape[1]),
+        ("bh", "-h", "-h", "-h", "-h", "b", "b"), ("bh",))(
             q, k, v, k_scale, v_scale, block_tbl, pos)
-    return _flash_decode_paged_q8_pallas(q, k, v, k_scale, v_scale,
-                                         block_tbl, pos, float(scale))

@@ -16,9 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret():
-    return jax.default_backend() != "tpu"
+from deepspeed_tpu.ops import pallas_mode
 
 
 def _mask_from_bits(bits, rate):
@@ -53,7 +51,7 @@ def _dropout_fwd(x, seed, rate, bias, residual):
     hidden = x.shape[-1]
     x2 = x.reshape(-1, hidden)
     n = x2.shape[0]
-    if _interpret():
+    if pallas_mode.interpret():
         # Off-TPU: identical semantics via threefry (pltpu PRNG only lowers
         # on real TPUs; interpret mode has no prng_seed primitive).
         z = x2.astype(jnp.float32)
@@ -122,7 +120,7 @@ def _dropout_vjp_bwd(rate, res, g):
     n = x.size // hidden
     # Regenerate the identical mask from (seed, offset); matches what the
     # fwd kernel drew because both use the same counter stream.
-    if _interpret():
+    if pallas_mode.interpret():
         keep = _dropout_mask_jnp((n, hidden), seed, rate)
     else:
         keep = _regen_mask_tpu((n, hidden), seed, rate)

@@ -14,11 +14,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from deepspeed_tpu.ops import pallas_mode
+
 _SQRT_2_OVER_PI = 0.7978845608028654
-
-
-def _interpret():
-    return jax.default_backend() != "tpu"
 
 
 def _gelu_f32(z):
@@ -50,7 +48,7 @@ def _bias_gelu_fwd(x, bias):
                   pl.BlockSpec((hidden,), lambda i: (0,))],
         out_specs=pl.BlockSpec((max(rows, 1), hidden), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, hidden), x.dtype),
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(x2, bias)
     return o.reshape(x.shape)
 

@@ -18,9 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _interpret():
-    return jax.default_backend() != "tpu"
+from deepspeed_tpu.ops import pallas_mode
 
 
 def _pick_block_rows(n_rows, hidden):
@@ -94,7 +92,7 @@ def _ln_fwd(x, gamma, beta, bias, residual, eps):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(*args)
     return o.reshape(orig_shape), mu, rstd
 

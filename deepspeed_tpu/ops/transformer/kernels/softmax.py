@@ -14,11 +14,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from deepspeed_tpu.ops import pallas_mode
+from deepspeed_tpu.ops.transformer.kernels.attention import _mask_operand
+
 NEG_INF = -1e30
-
-
-def _interpret():
-    return jax.default_backend() != "tpu"
 
 
 def _softmax_kernel(s_ref, o_ref, *, scale, causal, mask_ref=None):
@@ -50,8 +49,10 @@ def _softmax_fwd(scores, mask, scale, causal):
     args = [scores]
     in_specs = [spec]
     if mask is not None:
-        in_specs.append(pl.BlockSpec((1, t_k), lambda b_, h_, i: (b_, 0)))
-        args.append(mask.astype(jnp.float32))
+        # [B, 1, T] with the batch dim squeezed, so the kernel sees (1, T).
+        in_specs.append(pl.BlockSpec((None, 1, t_k),
+                                     lambda b_, h_, i: (b_, 0, 0)))
+        args.append(_mask_operand(mask))
 
         def kernel(s_ref, m_ref, o_ref):
             _softmax_kernel(s_ref, o_ref, scale=scale, causal=causal,
@@ -65,7 +66,7 @@ def _softmax_fwd(scores, mask, scale, causal):
         in_specs=in_specs,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(scores.shape, scores.dtype),
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(*args)
 
 

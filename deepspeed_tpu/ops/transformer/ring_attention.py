@@ -43,8 +43,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.utils import jax_compat
-
 from deepspeed_tpu.ops.transformer.kernels.attention import (
     NEG_INF, _flash_bwd_pallas, _flash_fwd_pallas, _mxu_precision,
     flash_attention_with_lse, mha_reference, resolve_block_sizes)
@@ -121,7 +119,7 @@ def _block_bwd(q, k, v, mask, delta, lse, do, scale, causal, bq, bk,
 
 def _ring_fwd_scan(q, k, v, mask, axis_name, causal, scale, bq, bk, dense):
     """(o fp32, lse) after the full ring. mask: fp32 [B, T_local] or None."""
-    n = jax_compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     b, h, t_local, d = q.shape
     o0 = jnp.zeros((b, h, t_local, d), jnp.float32)
@@ -190,7 +188,7 @@ def _ring_bwd_scan(q, k, v, mask, o, lse, do, axis_name, causal, scale,
     in buffers that TRAVEL WITH their k/v block and arrive home after the
     n-th rotation.
     """
-    n = jax_compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     has_mask = mask is not None
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -301,7 +299,7 @@ def ring_flash_attention(q, k, v, axis_name, causal=False, mask=None,
         score memory per block pair).
     Returns: [B, H, T_local, D] in q.dtype.
     """
-    n = jax_compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     d = q.shape[-1]
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
 
@@ -329,7 +327,7 @@ def sequence_parallel_attention(mesh, q, k, v, axis_name="data",
     mask. Batch/head dims stay replicated here — compose with
     data-parallel batch sharding by calling ring_flash_attention directly
     inside your own shard_map."""
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(None, None, axis_name, None)
@@ -386,7 +384,7 @@ def ulysses_attention(q, k, v, axis_name, causal=False, mask=None,
     from deepspeed_tpu.ops.transformer.kernels.attention import (
         flash_attention)
 
-    n = jax_compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return flash_attention(q, k, v, mask=mask, causal=causal,
                                scale=scale, block_q=block_q,

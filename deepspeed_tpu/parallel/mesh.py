@@ -78,25 +78,21 @@ def _arrange(devices, shape, explicit):
     An EXPLICIT device list keeps the caller's order (tests and
     submesh-pinning callers depend on it), and non-TPU platforms keep the
     plain reshape (virtual CPU meshes have no topology; a reorder would
-    only shuffle test determinism)."""
+    only shuffle test determinism). A shape the topology solver refuses
+    raises: a flat reshape there would run with an arbitrary ICI mapping
+    and say nothing."""
     num_pp, num_dp, num_sp, num_mp = shape
     if explicit or not devices or devices[0].platform != "tpu" or \
             len(devices) == 1:
         return np.asarray(devices).reshape(shape)
-    try:
-        from jax.experimental import mesh_utils
+    from jax.experimental import mesh_utils
 
-        slices = len({getattr(d, "slice_index", 0) for d in devices})
-        if slices > 1 and num_dp % slices == 0:
-            return mesh_utils.create_hybrid_device_mesh(
-                (num_pp, num_dp // slices, num_sp, num_mp),
-                (1, slices, 1, 1), devices=devices)
-        return mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception as e:  # topology solver unavailable/unhappy: still run
-        from deepspeed_tpu.utils.logging import logger
-        logger.warning("mesh_utils arrangement failed (%s); falling back "
-                       "to flat device order", e)
-        return np.asarray(devices).reshape(shape)
+    slices = len({getattr(d, "slice_index", 0) for d in devices})
+    if slices > 1 and num_dp % slices == 0:
+        return mesh_utils.create_hybrid_device_mesh(
+            (num_pp, num_dp // slices, num_sp, num_mp),
+            (1, slices, 1, 1), devices=devices)
+    return mesh_utils.create_device_mesh(shape, devices=devices)
 
 
 def default_mesh() -> Mesh:

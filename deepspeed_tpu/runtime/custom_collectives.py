@@ -12,7 +12,7 @@ the same 1-bit-per-element compression the reference achieves with
 cupy.packbits (onebit_adam.py:98-102).
 
 Everything here is pure-functional and shard_map-compatible; use inside
-``shard_map(..., mesh, in_specs=..., check_rep=False)`` over the 'data' axis.
+``shard_map(..., mesh, in_specs=..., check_vma=False)`` over the 'data' axis.
 """
 
 import jax

@@ -38,6 +38,7 @@ import numpy as np
 
 from deepspeed_tpu.ops.adam.fused_adam import FusedAdam
 from deepspeed_tpu.ops.lamb.fused_lamb import FusedLamb
+from deepspeed_tpu.ops.transformer.kernels.attention import kernels_on_mesh
 from deepspeed_tpu.parallel import mesh as mesh_lib
 from deepspeed_tpu.runtime import lr_schedules
 from deepspeed_tpu.runtime.config import (
@@ -940,6 +941,7 @@ class DeepSpeedEngine(object):
         that already ran _module_apply_setup pass it through."""
         cast = self._cast_to_compute
         apply_fn, accepts_deterministic = setup or self._module_apply_setup()
+        mesh = self.mesh
 
         def make(args, traced_kwargs, rng, scale):
             def loss_fn(p):
@@ -949,9 +951,10 @@ class DeepSpeedEngine(object):
                 if train:
                     if accepts_deterministic:
                         call_kwargs.setdefault("deterministic", False)
-                    out = apply_fn({"params": cp}, *args,
-                                   rngs={"dropout": rng}, **call_kwargs)
-                else:
+                    call_kwargs["rngs"] = {"dropout": rng}
+                # The model's Pallas kernels launch shard-local over the
+                # engine's mesh (entered here, inside what gets jitted).
+                with kernels_on_mesh(mesh):
                     out = apply_fn({"params": cp}, *args, **call_kwargs)
                 loss = out[0] if isinstance(out, tuple) else out
                 return loss * scale, out
@@ -1029,7 +1032,7 @@ class DeepSpeedEngine(object):
         (v0.3.10 has no sequence parallelism, SURVEY §0)."""
         from functools import partial
 
-        from deepspeed_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         mesh = self.mesh
         dp = mesh_lib.dp_size(mesh)
@@ -1136,7 +1139,7 @@ class DeepSpeedEngine(object):
         DP, engine.py:180-185,1186-1242)."""
         from functools import partial
 
-        from deepspeed_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         from deepspeed_tpu.runtime.csr_tensor import sparse_grad_exchange
 
@@ -1897,7 +1900,7 @@ class DeepSpeedEngine(object):
         static (a collective cannot live inside lax.cond), so the step
         re-traces once at the freeze boundary; train_batch keys its cache
         on the phase."""
-        from deepspeed_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from deepspeed_tpu.runtime.fp16.onebit_adam import onebit_adam_update
@@ -2028,12 +2031,14 @@ class DeepSpeedEngine(object):
             clip = self.gradient_clipping()
             optimizer = self.optimizer
             grad_constraint = self._grad_constraint
+            mesh = self.mesh
 
             def fused(params, opt_state, args, rng, lr, beta1, beta2):
                 def loss_fn(p):
                     cp = cast(p)
-                    return module.apply({"params": cp}, *args,
-                                        rngs={"dropout": rng})
+                    with kernels_on_mesh(mesh):
+                        return module.apply({"params": cp}, *args,
+                                            rngs={"dropout": rng})
 
                 loss, grads = jax.value_and_grad(loss_fn)(params)
                 if grad_constraint is not None:

@@ -315,12 +315,7 @@ class CompiledPipelineEngine(PipelineEngine):
                                 rngs=rngs_of(jax.random.fold_in(rng, l)))
             return h
 
-        try:
-            from jax import shard_map
-            _rep_kw = {"check_vma": False}
-        except ImportError:  # older jax keeps it under experimental
-            from jax.experimental.shard_map import shard_map
-            _rep_kw = {"check_rep": False}
+        from jax import shard_map
 
         axis_p, axis_d = mesh_lib.PIPE_AXIS, mesh_lib.DATA_AXIS
         # No wraparound edge: stage 0 always takes the fresh micro-batch,
@@ -335,14 +330,7 @@ class CompiledPipelineEngine(PipelineEngine):
             the SAME function on every shard (SPMD), with this shard's
             [1, L, ...] block slice. Inside shard_map arrays are
             shard-local, so blocks launch the raw pallas flash kernels
-            (shard_local_kernels — scoped HERE so GSPMD-region callers
-            like the prologue keep their partitioning wrappers)."""
-            from deepspeed_tpu.ops.transformer.kernels.attention import (
-                shard_local_kernels)
-            with shard_local_kernels():
-                return _worker_body(bp, epi_params, h, ys, rng)
-
-        def _worker_body(bp, epi_params, h, ys, rng):
+            (attention.kernel_sharding sees the manual region)."""
             sidx = jax.lax.axis_index(axis_p)
             p_stage = tm(lambda a: a[0], bp)
             slab0 = jnp.zeros(h.shape[1:], h.dtype)   # [mb_loc, ...]
@@ -417,8 +405,8 @@ class CompiledPipelineEngine(PipelineEngine):
                 in_specs=(P(axis_p), P(), P(None, axis_d),
                           P(None, axis_d), P()),
                 out_specs=P(),
-                **_rep_kw)(params["blocks"], params["epilogue"],
-                           h, ys, rng)
+                check_vma=False)(params["blocks"], params["epilogue"],
+                                 h, ys, rng)
 
         return loss_of
 

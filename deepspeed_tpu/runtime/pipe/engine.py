@@ -308,7 +308,7 @@ class PipelineEngine(DeepSpeedEngine):
         freeze_step, onebit_adam.py:369-372)."""
         if stage_id in self._stage_bwd_local:
             return self._stage_bwd_local[stage_id]
-        from deepspeed_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         mesh = self.stage_meshes[stage_id]
         axis = mesh_lib.DATA_AXIS
@@ -838,7 +838,7 @@ class PipelineEngine(DeepSpeedEngine):
 
             fn = jax.jit(multi, donate_argnums=(0, 2))
         else:
-            from deepspeed_tpu.utils.jax_compat import shard_map
+            from jax import shard_map
 
             from deepspeed_tpu.runtime.fp16.onebit_adam import (
                 onebit_adam_update)

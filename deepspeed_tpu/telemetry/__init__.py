@@ -29,7 +29,7 @@ One dependency-free subsystem every engine emits into:
 - ``ProgramRegistry`` / ``HBMLedger`` / ``cost_model_gate`` (xray.py):
   the compiled-program cost/memory observatory — per-program HLO
   fingerprints, cost_analysis flops/bytes, roofline gauges against
-  ``PLATFORM_PEAKS``, the predicted-vs-live HBM ledger, and the
+  ``DEVICE_PEAKS``, the predicted-vs-live HBM ledger, and the
   hardware-free cost-model regression gate.
 
 See docs/OBSERVABILITY.md for the full contract.
@@ -72,7 +72,7 @@ from deepspeed_tpu.telemetry.registry import (
 from deepspeed_tpu.telemetry.timeseries import TimeseriesCollector
 from deepspeed_tpu.telemetry.tracing import NullRecorder, SpanRecorder
 from deepspeed_tpu.telemetry.xray import (
-    PLATFORM_PEAKS,
+    DEVICE_PEAKS,
     HBMLedger,
     ProgramRegistry,
     cost_model_gate,
@@ -109,5 +109,5 @@ __all__ = [
     "ProgramRegistry",
     "HBMLedger",
     "cost_model_gate",
-    "PLATFORM_PEAKS",
+    "DEVICE_PEAKS",
 ]

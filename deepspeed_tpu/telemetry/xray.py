@@ -30,8 +30,8 @@ Three pieces:
 
 - Roofline gauges: per-program ``xray_mfu`` / ``xray_mbu`` /
   ``xray_roofline_ratio`` from cost-model flops ÷ sampled step wall
-  time against ``PLATFORM_PEAKS``. Platforms without a peaks entry
-  (CPU) publish the cost facts with ``platform="cpu"`` labels and NO
+  time against the device's ``DEVICE_PEAKS`` row. The CPU has none: it
+  publishes the cost facts with ``platform="cpu"`` labels and NO
   utilization gauges — a fabricated MFU is worse than none.
 
 - Step-time decomposition: ``due()``/``sample_step()`` bracket 1-in-N
@@ -60,26 +60,42 @@ from deepspeed_tpu.utils.logging import logger
 # field rename/removal; the gate refuses to compare across versions.
 SCHEMA_VERSION = 1
 
+# How a compiled Pallas TPU kernel and the collectives read in the
+# optimized HLO text (``compiled.as_text()``).
+_KERNEL_CALL = 'custom_call_target="tpu_custom_call"'
+_COLLECTIVE_OPS = ("all-reduce", "reduce-scatter", "all-gather",
+                   "all-to-all", "collective-permute")
+
 # Bound on retained recompile events: a genuine recompile loop must not
 # grow the autopsy (or the registry) without bound. Overflow is counted
 # in ``recompile_events_dropped``, never silent.
 RECOMPILE_EVENT_CAP = 64
 
-# Per-platform peak compute / memory bandwidth for the roofline gauges.
-# Entries are honest or absent: a platform mapped to None (or missing)
-# gets cost-model facts only — no MFU/MBU is ever computed against a
-# made-up peak. The TPU row is v5e bf16 (the chip bench.py's
-# PEAK_FLOPS_TPU targets); override per-deployment via
-# ProgramRegistry(peaks=...).
-PLATFORM_PEAKS = {
-    "tpu": {
-        "flops_per_s": 197e12,       # v5e bf16 peak
-        "hbm_bytes_per_s": 819e9,    # v5e HBM bandwidth
-        "source": "TPU v5e datasheet (bf16)",
+# Peak compute / memory bandwidth per chip, keyed by the string the
+# device reports as ``jax.devices()[0].device_kind``. This is the ONE
+# peaks table: the roofline gauges here and bench.py's MFU both read it.
+# A device kind that is not in the table is an error where a utilization
+# is asked for (``device_peaks``) — no chip is given another chip's row.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "flops_per_s": 197e12,       # bf16
+        "hbm_bytes_per_s": 819e9,
+        "source": "Google Cloud documentation, \"TPU v5e\": 197 TFLOP/s "
+                  "bf16, 16 GB HBM at 819 GB/s per chip",
     },
-    "cpu": None,
-    "gpu": None,
 }
+
+
+def device_peaks(device_kind):
+    """The peaks row for ``device_kind``; an unknown kind raises."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            "no peaks row for device kind {!r} (known: {}); add it to "
+            "telemetry.xray.DEVICE_PEAKS with its source before asking "
+            "for a utilization".format(
+                device_kind, sorted(DEVICE_PEAKS))) from None
 
 
 _tree_leaves_fn = None
@@ -123,13 +139,19 @@ def _signature(args, kwargs):
 def _abstractify(tree):
     """Replace every array leaf with a ShapeDtypeStruct so a stash
     retains shapes, never buffers — the engine donates its pool into
-    the very programs being observed."""
+    the very programs being observed. A committed array keeps its
+    sharding: the AOT analysis must lower the program the call ran
+    (ZeRO-sharded state, a replica pinned to its chip), and a donated
+    sharded argument lowered as unsharded cannot even alias its
+    output."""
     import jax
     import numpy as np
 
     def conv(x):
         if hasattr(x, "shape") and hasattr(x, "dtype"):
-            return jax.ShapeDtypeStruct(tuple(x.shape), np.dtype(x.dtype))
+            sharding = x.sharding if getattr(x, "committed", False) else None
+            return jax.ShapeDtypeStruct(tuple(x.shape), np.dtype(x.dtype),
+                                        sharding=sharding)
         return x
 
     return jax.tree_util.tree_map(conv, tree)
@@ -180,7 +202,7 @@ class ProgramRegistry(object):
     """The observatory. ``registry`` is a MetricsRegistry (or None for
     a private, unpublished instance — the flops profiler's mode);
     ``platform`` is a jax backend name (detected lazily when omitted);
-    ``peaks`` overrides the PLATFORM_PEAKS row; ``sample_every`` is the
+    ``peaks`` overrides the DEVICE_PEAKS row; ``sample_every`` is the
     1-in-N step-decomposition sampling period (0 disables)."""
 
     def __init__(self, registry=None, platform=None, peaks=None,
@@ -375,20 +397,23 @@ class ProgramRegistry(object):
 
     def platform(self):
         if self._platform is None:
-            try:
-                import jax
+            import jax
 
-                self._platform = jax.default_backend()
-            except Exception:
-                self._platform = "unknown"
+            self._platform = jax.default_backend()
         return self._platform
 
     def peaks(self):
-        """The roofline peaks row for this platform, or None — in
-        which case no utilization number is ever derived."""
+        """The roofline peaks row: the ``peaks`` override, else the
+        attached device's ``DEVICE_PEAKS`` row. The CPU has no row and
+        gets None — no utilization is ever derived there; an accelerator
+        whose kind is not in the table raises."""
         if self._peaks_override is not None:
             return self._peaks_override
-        return PLATFORM_PEAKS.get(self.platform())
+        if self.platform() == "cpu":
+            return None
+        import jax
+
+        return device_peaks(jax.devices()[0].device_kind)
 
     def _analyze(self, stash):
         """AOT lower+compile the stashed program and read the compiler
@@ -402,12 +427,24 @@ class ProgramRegistry(object):
         out = {"fingerprint": None, "flops": 0.0, "bytes_accessed": 0.0,
                "argument_bytes": 0, "output_bytes": 0, "temp_bytes": 0,
                "alias_bytes": 0, "generated_code_bytes": 0,
-               "peak_hbm_bytes": 0, "error": None}
+               "peak_hbm_bytes": 0, "kernel_calls": 0, "collectives": {},
+               "error": None}
         try:
             lowered = stash.jitted.lower(*stash.args, **stash.kwargs)
             out["fingerprint"] = hashlib.sha256(
                 lowered.as_text().encode()).hexdigest()[:16]
             compiled = lowered.compile()
+            text = compiled.as_text()
+            # What the compiler actually put in the program: Pallas
+            # kernels (a kernel that ran in interpret mode, or gave way
+            # to a jnp reference, leaves no custom call) and the
+            # collectives the partitioner inserted.
+            out["kernel_calls"] = text.count(_KERNEL_CALL)
+            for op in _COLLECTIVE_OPS:
+                n = text.count(" {}(".format(op)) + \
+                    text.count(" {}-start(".format(op))
+                if n:
+                    out["collectives"][op] = n
             cost = compiled.cost_analysis()
             if isinstance(cost, (list, tuple)):
                 cost = cost[0] if cost else {}
@@ -831,12 +868,12 @@ def _self_check():
     tiny real program, schema shape, and gate A/A + synthetic-delta
     behavior. Exit 0 on success (bin/lint.sh runs this)."""
     failures = []
-    for plat, row in PLATFORM_PEAKS.items():
-        if row is None:
-            continue
+    for kind, row in DEVICE_PEAKS.items():
         if not (row.get("flops_per_s", 0) > 0
-                and row.get("hbm_bytes_per_s", 0) > 0):
-            failures.append("peaks[{}] not positive: {}".format(plat, row))
+                and row.get("hbm_bytes_per_s", 0) > 0
+                and row.get("source")):
+            failures.append("peaks[{}] not positive or unsourced: {}"
+                            .format(kind, row))
     try:
         import jax
         import jax.numpy as jnp

@@ -28,12 +28,12 @@ from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
 
 def main():
     on_tpu = jax.default_backend() == "tpu"
-    if not on_tpu and os.environ.get("DS_BENCH_REQUIRE_TPU") == "1":
-        # Under the battery a CPU run must FAIL (exit 3, like bench.py's
-        # guard) so the stage is retried on the chip, not recorded as a
-        # permanent tiny-model pass.
-        print("decode_bench: TPU required but backend is {}".format(
-            jax.default_backend()), file=sys.stderr)
+    if not on_tpu and os.environ.get("JAX_PLATFORMS", "") != "cpu":
+        # Like bench.py's guard: without a TPU the run fails unless the
+        # tiny CPU smoke was asked for explicitly.
+        print("decode_bench: needs a TPU, got backend {!r} (set "
+              "JAX_PLATFORMS=cpu for the tiny CPU smoke)".format(
+                  jax.default_backend()), file=sys.stderr)
         return 3
     if on_tpu:
         cfg = GPT2Config.gpt2_medium(dropout=0.0, n_positions=2048)

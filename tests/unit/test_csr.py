@@ -4,7 +4,7 @@ the sparse allgather collective on the 8-device mesh)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deepspeed_tpu.runtime.csr_tensor import (CSRTensor, csr_allreduce,

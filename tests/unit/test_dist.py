@@ -9,7 +9,7 @@ import jax
 import jax.experimental.mesh_utils  # noqa: F401 (registers the attr the monkeypatch below replaces)
 import jax.numpy as jnp
 import numpy as np
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deepspeed_tpu.utils import distributed as dist
@@ -104,8 +104,8 @@ def test_active_sp_axis_outside_shard_map():
 def test_arrange_topology_paths(monkeypatch):
     """_arrange: explicit lists and CPU devices keep caller/flat order;
     fake-TPU devices route through mesh_utils (hybrid when multi-process,
-    ICI-aware otherwise) and fall back to flat order if the solver
-    throws."""
+    ICI-aware otherwise). A solver failure raises:
+    tests/unit/test_chip_smoke.py::test_arrange_reraises."""
     import jax.experimental
 
     from deepspeed_tpu.parallel import mesh as mesh_lib
@@ -159,12 +159,3 @@ def test_arrange_topology_paths(monkeypatch):
     # dp=2 splits across 2 slices: dcn carries data, ICI the rest.
     assert calls == {"hybrid": ((1, 1, 1, 4), (1, 2, 1, 1))}
     assert arr.shape == shape
-
-    class Broken:
-        @staticmethod
-        def create_device_mesh(shape_, devices=None):
-            raise RuntimeError("no topology")
-
-    monkeypatch.setattr(jax.experimental, "mesh_utils", Broken)
-    arr = mesh_lib._arrange(tpus, shape, explicit=False)
-    assert [d.id for d in arr.reshape(-1)] == list(range(8))

@@ -388,19 +388,23 @@ def test_flash_decode_flag_resolution():
 # ------------------------------------------------------------- tensor parallel
 
 
-def test_tensor_sharded_serving_matches_unsharded(eight_devices):
+@pytest.mark.parametrize("flash_decode", [False, True])
+def test_tensor_sharded_serving_matches_unsharded(eight_devices,
+                                                  flash_decode):
     """Serving over a mesh with a 'model' axis: params shard by the TP
     rules, the KV pool shards its heads dim, and the tokens match the
-    unsharded engine exactly."""
+    unsharded engine exactly — on the einsum path, and with the decode
+    kernel launched shard-local over the mesh (kernels_on_mesh)."""
     cfg, model, params = make_model()  # tiny: n_head=4, divisible by mp
     mesh = mesh_lib.build_mesh(devices=jax.devices()[:4], num_mp=4,
                                num_dp=1)
     ps = prompts_of(cfg, [5, 9, 3])
-    base = engine_of(model, params)
+    base = engine_of(model, params, use_flash_decode=flash_decode)
     want = [base.submit(p, max_new_tokens=6) for p in ps]
     base.run()
 
-    eng = engine_of(model, params, mesh=mesh)
+    eng = engine_of(model, params, mesh=mesh, use_flash_decode=flash_decode)
+    assert eng.metrics()["flash_decode"] is flash_decode
     got = [eng.submit(p, max_new_tokens=6) for p in ps]
     eng.run()
     for w, g in zip(want, got):

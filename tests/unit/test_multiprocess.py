@@ -141,7 +141,7 @@ def test_two_process_bootstrap_and_train():
 
 
 # ---------------------------------------------------------------- sharded
-# VERDICT r4 missing#4: the 2-process rendezvous test proves the bootstrap
+# The 2-process rendezvous test proves the bootstrap
 # contract but not a SHARDED PROGRAM SPANNING PROCESSES (the v5e-64
 # execution shape: GSPMD partitioning over devices owned by different
 # controllers). This variant gives each worker 4 virtual CPU devices and

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 import deepspeed_tpu as deepspeed
 from deepspeed_tpu.ops.adam.fused_adam import FusedAdam

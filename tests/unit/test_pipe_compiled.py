@@ -230,8 +230,8 @@ def test_compiled_zero_shards_moments_over_data(eight_devices):
 
 def test_gpt2_pipeline_compiled_flash_matches_dense(eight_devices):
     """Flash attention runs INSIDE the compiled pipeline (the shard_map
-    worker launches raw pallas kernels via shard_local_kernels) and
-    matches the dense-attention path numerically."""
+    worker launches raw pallas kernels) and matches the dense-attention
+    path numerically."""
     from deepspeed_tpu.models.gpt2 import GPT2Config, gpt2_pipeline
 
     def run(flash):

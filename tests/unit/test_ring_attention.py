@@ -207,7 +207,7 @@ def test_ring_ragged_blocks_with_mask():
 
 
 def _shard_map_ulysses(mesh, q, k, v, mask=None, causal=False, **kw):
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     from deepspeed_tpu.ops.transformer.ring_attention import (
         ulysses_attention)
@@ -282,7 +282,7 @@ def test_ulysses_rejects_indivisible_heads():
 def test_ring_inside_user_shard_map():
     """ring_flash_attention composes inside a caller's shard_map with a
     batch x seq mesh (dp on batch, ring on sequence)."""
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     q, k, v = make_qkv(b=4, t=128, h=2)
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("data", "seq"))

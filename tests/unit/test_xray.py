@@ -33,7 +33,7 @@ from deepspeed_tpu.telemetry import (
     prometheus_text,
 )
 from deepspeed_tpu.telemetry.xray import (
-    PLATFORM_PEAKS,
+    DEVICE_PEAKS,
     SCHEMA_VERSION,
     HBMLedger,
     ProgramRegistry,
@@ -396,15 +396,14 @@ def test_module_self_check_passes():
     assert _self_check() == 0
 
 
-def test_platform_peaks_table_is_honest():
-    # Platforms either state positive peaks with a source, or None —
-    # no zero/negative rows that would make MFU read as infinity.
-    for plat, row in PLATFORM_PEAKS.items():
-        if row is None:
-            continue
+def test_device_peaks_table_is_honest():
+    # Every row states positive peaks with a source; the CPU has no row
+    # (a registry on the CPU derives no utilization at all).
+    for row in DEVICE_PEAKS.values():
         assert row["flops_per_s"] > 0 and row["hbm_bytes_per_s"] > 0
         assert row.get("source")
-    assert PLATFORM_PEAKS["cpu"] is None
+    assert "cpu" not in DEVICE_PEAKS
+    assert ProgramRegistry(platform="cpu").peaks() is None
 
 
 # ----------------------------------------------------- engine integration
