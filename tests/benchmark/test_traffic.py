@@ -1,0 +1,94 @@
+"""The one generator: repeats for a seed, differs across seeds, and under
+stratified sampling offers every seed the same amount of work."""
+
+import numpy as np
+import pytest
+
+from benchmark import traffic
+
+MIX = {"arrival": "poisson", "rate": 0.5,
+       "prompt": {"median": 96, "sigma": 0.8, "min": 16, "max": 384},
+       "output": {"median": 48, "sigma": 0.6, "min": 8, "max": 128}}
+
+
+def _draw(seed, sampling, seconds=40):
+    mix = dict(MIX, sampling=sampling)
+    due = traffic.arrivals(seed, 1, seconds, mix)
+    return due, traffic.requests(seed, 1, len(due), mix, 50257)
+
+
+@pytest.mark.parametrize("sampling", traffic.SAMPLINGS)
+def test_same_seed_same_inputs_other_seed_other_inputs(sampling):
+    due_a, reqs_a = _draw(7, sampling)
+    due_b, reqs_b = _draw(7, sampling)
+    due_c, reqs_c = _draw(8, sampling)
+    assert np.array_equal(due_a, due_b)
+    assert all(np.array_equal(p, q) and m == n
+               for (p, m), (q, n) in zip(reqs_a, reqs_b))
+    assert not np.array_equal(due_a[:5], due_c[:5])
+    assert not np.array_equal(reqs_a[0][0][:8], reqs_c[0][0][:8])
+
+
+def test_stratified_offers_every_seed_the_same_work():
+    (due_a, reqs_a), (due_b, reqs_b) = _draw(1, "stratified"), \
+        _draw(2, "stratified")
+    assert len(due_a) == len(due_b) == 20       # round(0.5 * 40)
+    assert sorted(len(p) for p, _ in reqs_a) == \
+        sorted(len(p) for p, _ in reqs_b)
+    assert sorted(o for _, o in reqs_a) == sorted(o for _, o in reqs_b)
+    for due in (due_a, due_b):
+        assert np.all(np.diff(due) > 0) and 0 <= due[0] and due[-1] < 40
+
+
+def test_lengths_are_a_clipped_lognormal_given_by_its_median():
+    rng = np.random.RandomState(0)
+    lens = traffic.lognormal_lengths(rng, 2001, MIX["prompt"], "stratified")
+    assert lens.min() == 16 and lens.max() == 384
+    assert abs(np.median(lens) - 96) <= 1
+    # ln(len) has the sigma asked for, in the part the clip leaves alone
+    z = np.log(np.sort(lens)[400:1600] / 96.0)
+    assert abs(z.std() - 0.8 * 0.4632) < 0.02   # std of a normal's middle 60%
+
+
+def test_iid_poisson_rate():
+    rng = np.random.RandomState(3)
+    due = traffic.poisson_arrivals(rng, 5.0, 400.0, "iid")
+    assert abs(len(due) / 400.0 - 5.0) < 0.4
+
+
+def test_tokens_are_uniform_not_a_tiled_phrase():
+    _, reqs = _draw(5, "iid")
+    prompt = max((p for p, _ in reqs), key=len)
+    assert len(set(prompt.tolist())) > 0.9 * len(prompt)
+
+
+def test_training_batches_are_distinct_and_seeded():
+    a = traffic.token_batches(1, 4, 2, 16, 1000)
+    assert a.shape == (4, 2, 16) and a.dtype == np.int32
+    assert np.array_equal(a, traffic.token_batches(1, 4, 2, 16, 1000))
+    assert not np.array_equal(a[0], a[1])
+    assert not np.array_equal(a, traffic.token_batches(2, 4, 2, 16, 1000))
+
+
+def test_unknown_sampling_is_refused():
+    with pytest.raises(ValueError, match="unknown sampling"):
+        traffic.lognormal_lengths(np.random.RandomState(0), 4,
+                                  MIX["prompt"], "sobol")
+
+
+def test_a_fixed_schedule_is_replayed_with_seeded_jitter_and_new_tokens():
+    mix = dict(MIX, sampling="stratified", schedule_seed=2,
+               arrival_jitter_s=0.1)
+    due_a = traffic.arrivals(1, 1, 40, mix)
+    due_b = traffic.arrivals(9, 1, 40, mix)
+    plain = traffic.arrivals(123, 1, 40, dict(mix, arrival_jitter_s=0.0))
+    assert len(due_a) == len(due_b) == len(plain)
+    assert not np.array_equal(due_a, due_b)
+    for due in (due_a, due_b):
+        assert np.all(np.diff(due) >= 0)
+        assert np.all(np.abs(np.sort(due) - plain) <= 0.1 + 1e-9)
+    reqs_a = traffic.requests(1, 1, len(due_a), mix, 50257)
+    reqs_b = traffic.requests(9, 1, len(due_b), mix, 50257)
+    assert [(len(p), o) for p, o in reqs_a] == \
+        [(len(p), o) for p, o in reqs_b]
+    assert not np.array_equal(reqs_a[0][0], reqs_b[0][0])
