@@ -72,12 +72,6 @@ SANCTIONED_SYNC_SITES = {
     "deepspeed_tpu/inference/engine.py": frozenset({
         "_step_chunked", "_step_legacy",
     }),
-    # Perf X-ray step decomposition (telemetry/xray.py): the sampled
-    # 1-in-N bracketed block_until_ready that splits host-schedule
-    # from device-compute time. The sync is the measurement.
-    "deepspeed_tpu/telemetry/xray.py": frozenset({
-        "sample_step",
-    }),
 }
 
 # Modules where DETERMINISM applies to EVERY function, not just

@@ -36,7 +36,6 @@ INFERENCE_DEFAULTS = {
     "telemetry": True,
     "trace_ring": 4096,
     "perf_xray": True,
-    "xray_sample_every": 64,
     "fault_injection": False,
     "step_budget_s": None,
     "recovery_max_retries": 2,
@@ -152,11 +151,6 @@ class InferenceConfig:
     # AOT lower+compile that reads XLA's cost/memory model. Off, no
     # stash, no ledger, no roofline gauges.
     perf_xray: bool = True
-    # Step-time decomposition sampling period: 1-in-N steps pay a real
-    # bracketed block_until_ready to split host-schedule from
-    # device-compute time (the roofline's measured denominator). 0
-    # disables sampling; the cost/memory observatory stays on.
-    xray_sample_every: int = 64
     # Chaos switch: engine.inject_faults(FaultPlan) only arms when True
     # (inference/faults.py). Off (the default), the injector is None and
     # every hook is one ``is not None`` test — production configs cannot
@@ -291,10 +285,6 @@ class InferenceConfig:
         if self.trace_ring < 1:
             raise ValueError("inference.trace_ring must be >= 1, got "
                              "{}".format(self.trace_ring))
-        if self.xray_sample_every < 0:
-            raise ValueError("inference.xray_sample_every must be >= 0 "
-                             "(0 disables step-decomposition sampling), "
-                             "got {}".format(self.xray_sample_every))
         if self.step_budget_s is not None and self.step_budget_s <= 0:
             raise ValueError("inference.step_budget_s must be > 0 (or None "
                              "to disable the watchdog), got "

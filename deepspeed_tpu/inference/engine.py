@@ -75,7 +75,6 @@ match, and every program pins its out_shardings so the cache layout
 survives every step. One engine, sharded or not.
 """
 
-import contextlib
 import time
 
 import jax
@@ -133,7 +132,6 @@ from deepspeed_tpu.telemetry import (
     ProgramRegistry,
     RecompileDetector,
     SpanRecorder,
-    annotate,
     prometheus_digest,
     prometheus_text,
 )
@@ -142,7 +140,6 @@ from deepspeed_tpu.utils.logging import logger
 from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer
 
 _NEG = None  # set lazily: jnp.finfo(jnp.float32).min
-_NULL_CTX = contextlib.nullcontext()  # reusable & reentrant by contract
 
 
 def _neg():
@@ -243,12 +240,13 @@ def _prefill_program(params, adapter, pool, prompt, prompt_len, slot,
     frontier). Returns (pool', first_token). The explicit ``pos``
     install below overrides the append's own frontier advance, so the
     adapter's prefill primitive serves both entry modes."""
-    cache = slot_cache_view(pool, slot, jnp.zeros((1,), jnp.int32))
-    logits, cache = adapter.prefill_append(params, prompt, cache)
-    last = logits[0, prompt_len - 1]                    # true last row [V]
-    first = _sample_rows(last[None], temp[None], top_k[None], seed[None],
-                         prompt_len[None])[0]
-    pool = write_slot_cache(pool, slot, cache)
+    with jax.named_scope("prefill_lane"):
+        cache = slot_cache_view(pool, slot, jnp.zeros((1,), jnp.int32))
+        logits, cache = adapter.prefill_append(params, prompt, cache)
+        last = logits[0, prompt_len - 1]                # true last row [V]
+        first = _sample_rows(last[None], temp[None], top_k[None],
+                             seed[None], prompt_len[None])[0]
+        pool = write_slot_cache(pool, slot, cache)
     # The first token counts against the budget; a request can finish at
     # admission (max_new==1, or its first token IS its EOS).
     finished = (max_new <= 1) | ((eos_id >= 0) & (first == eos_id))
@@ -268,19 +266,26 @@ def _decode_chunk_program(params, adapter, chunk, pool):
     request. Frozen slots still flow through decode_step (the static
     shape requires it) but their pos is pinned and writes land at their
     frozen frontier, where the next admission overwrites them before any
-    causal mask can see them."""
+    causal mask can see them.
+
+    In a trace the scan is the region ``decode_scan`` (``jax.named_scope``),
+    and inside it ``kv_view`` (``cache_view``, the planes attention reads),
+    ``attn`` / ``kv_write`` / ``mlp`` / ``lm_head`` (the model's, see
+    ``models/generation.py`` ``_forward``; ``fold_cache`` is ``kv_write``
+    too) and ``sample``; the speculative scan adds ``draft``."""
 
     def step(pool, _):
         was_active = pool["active"]
         old_pos = pool["pos"]
         logits, cache = adapter.decode_step(
             params, pool["last_tok"], cache_view(pool))
-        nxt = _sample_rows(logits, pool["temp"], pool["top_k"],
-                           pool["seed"], cache["pos"])
-        nxt = jnp.where(was_active, nxt, pool["last_tok"])
-        hit_eos = (pool["eos"] >= 0) & (nxt == pool["eos"])
-        remaining = jnp.where(was_active, pool["remaining"] - 1,
-                              pool["remaining"])
+        with jax.named_scope("sample"):
+            nxt = _sample_rows(logits, pool["temp"], pool["top_k"],
+                               pool["seed"], cache["pos"])
+            nxt = jnp.where(was_active, nxt, pool["last_tok"])
+            hit_eos = (pool["eos"] >= 0) & (nxt == pool["eos"])
+            remaining = jnp.where(was_active, pool["remaining"] - 1,
+                                  pool["remaining"])
         pool = dict(fold_cache(pool, cache),
                     pos=jnp.where(was_active, cache["pos"], old_pos),
                     last_tok=nxt,
@@ -289,7 +294,8 @@ def _decode_chunk_program(params, adapter, chunk, pool):
         emit = jnp.where(was_active, nxt, -1)
         return pool, (emit, was_active)
 
-    pool, (toks, valid) = jax.lax.scan(step, pool, None, length=chunk)
+    with jax.named_scope("decode_scan"):
+        pool, (toks, valid) = jax.lax.scan(step, pool, None, length=chunk)
     return pool, toks, valid
 
 
@@ -320,37 +326,39 @@ def _spec_decode_chunk_program(params, adapter, chunk, spec_k, spec_ngram,
     def step(pool, _):
         was_active = pool["active"]
         old_pos = pool["pos"]
-        draft = adapter.ngram_draft(pool["toks"], old_pos, spec_ngram,
-                                    spec_k)
+        with jax.named_scope("draft"):
+            draft = adapter.ngram_draft(pool["toks"], old_pos, spec_ngram,
+                                        spec_k)
         ids = jnp.concatenate([pool["last_tok"][:, None], draft], axis=1)
         logits, cache = adapter.verify_forward(params, ids,
                                                cache_view(pool))
-        R = ids.shape[0]
-        # choices[:, i] = the model's pick for position old_pos+1+i,
-        # conditioned on the draft prefix (== the true prefix wherever
-        # the prefix is accepted). Same sampler, same per-(seed, pos)
-        # rng as the 1-token path — bit-identical streams.
-        position = old_pos[:, None] + 1 + jnp.arange(kp1)[None]
-        choices = _sample_rows(
-            logits.reshape(R * kp1, -1),
-            jnp.repeat(pool["temp"], kp1), jnp.repeat(pool["top_k"], kp1),
-            jnp.repeat(pool["seed"], kp1),
-            position.reshape(-1)).reshape(R, kp1)
-        n_acc = adapter.accept_counts(draft, choices,
-                                      ok=pool["spec"][:, None])
-        # Budget clamp first (the max() keeps frozen rows' gather index
-        # valid), then EOS truncation WITHIN the accepted prefix — the
-        # same emit-EOS-then-stop order as the 1-token path.
-        n_acc = jnp.minimum(n_acc, jnp.maximum(pool["remaining"], 1))
-        lane = jnp.arange(kp1)[None]
-        is_eos = (pool["eos"][:, None] >= 0) & \
-            (choices == pool["eos"][:, None]) & (lane < n_acc[:, None])
-        hit_eos = jnp.any(is_eos, axis=1)
-        n_acc = jnp.where(hit_eos, jnp.argmax(is_eos, axis=1) + 1, n_acc)
-        last = jnp.take_along_axis(choices, (n_acc - 1)[:, None],
-                                   axis=1)[:, 0]
-        remaining = jnp.where(was_active, pool["remaining"] - n_acc,
-                              pool["remaining"])
+        with jax.named_scope("sample"):
+            R = ids.shape[0]
+            # choices[:, i] = the model's pick for position old_pos+1+i,
+            # conditioned on the draft prefix (== the true prefix wherever
+            # the prefix is accepted). Same sampler, same per-(seed, pos)
+            # rng as the 1-token path — bit-identical streams.
+            position = old_pos[:, None] + 1 + jnp.arange(kp1)[None]
+            choices = _sample_rows(
+                logits.reshape(R * kp1, -1),
+                jnp.repeat(pool["temp"], kp1), jnp.repeat(pool["top_k"], kp1),
+                jnp.repeat(pool["seed"], kp1),
+                position.reshape(-1)).reshape(R, kp1)
+            n_acc = adapter.accept_counts(draft, choices,
+                                          ok=pool["spec"][:, None])
+            # Budget clamp first (the max() keeps frozen rows' gather index
+            # valid), then EOS truncation WITHIN the accepted prefix — the
+            # same emit-EOS-then-stop order as the 1-token path.
+            n_acc = jnp.minimum(n_acc, jnp.maximum(pool["remaining"], 1))
+            lane = jnp.arange(kp1)[None]
+            is_eos = (pool["eos"][:, None] >= 0) & \
+                (choices == pool["eos"][:, None]) & (lane < n_acc[:, None])
+            hit_eos = jnp.any(is_eos, axis=1)
+            n_acc = jnp.where(hit_eos, jnp.argmax(is_eos, axis=1) + 1, n_acc)
+            last = jnp.take_along_axis(choices, (n_acc - 1)[:, None],
+                                       axis=1)[:, 0]
+            remaining = jnp.where(was_active, pool["remaining"] - n_acc,
+                                  pool["remaining"])
         # Ring: ALL kp1 choices land at old_pos+1 (frozen rows included)
         # — entries past the post-accept frontier are stale-rule garbage
         # a later write covers before the drafter can match them.
@@ -364,7 +372,8 @@ def _spec_decode_chunk_program(params, adapter, chunk, spec_k, spec_ngram,
         ok = was_active[:, None] & (lane < n_acc[:, None])
         return pool, (jnp.where(ok, choices, -1), ok)
 
-    pool, (toks, valid) = jax.lax.scan(step, pool, None, length=chunk)
+    with jax.named_scope("decode_scan"):
+        pool, (toks, valid) = jax.lax.scan(step, pool, None, length=chunk)
     return pool, toks, valid
 
 
@@ -400,6 +409,10 @@ def _mixed_step_program(params, adapter, chunk, spec, pool, p_ids, p_slot,
     Returns (pool', first_token, tokens, valid): the first token is -1
     unless ``p_done``; tokens/valid are [chunk, slots] without
     speculation, [chunk, slots, spec_k+1] with it.
+
+    In a trace the two lanes are the regions ``prefill_lane`` (its arena
+    write-back ``prefill_lane/kv_write``, its kernel ``prefill_attn``) and
+    ``decode_scan``.
     """
     C = p_ids.shape[1]
 
@@ -442,8 +455,9 @@ def _mixed_step_program(params, adapter, chunk, spec, pool, p_ids, p_slot,
                 jnp.where(p_done, first, at_front))
         return pool, jnp.where(p_done, first, jnp.int32(-1))
 
-    pool, first = jax.lax.cond(
-        p_valid > 0, _lane, lambda pool: (pool, jnp.int32(-1)), pool)
+    with jax.named_scope("prefill_lane"):
+        pool, first = jax.lax.cond(
+            p_valid > 0, _lane, lambda pool: (pool, jnp.int32(-1)), pool)
     if spec is None:
         pool, toks, valid = _decode_chunk_program(params, adapter, chunk,
                                                   pool)
@@ -483,6 +497,7 @@ class InferenceEngine(object):
         # replica survives. Both touched only under the same external
         # serialization as step() itself.
         "_handoff_outbox", "_handoff_enabled",
+        "_steps",           # step number the spans carry; stepper-owned
     })
 
     def __init__(self, model, params, config=None, mesh=None, adapter=None):
@@ -605,22 +620,27 @@ class InferenceEngine(object):
         # call instead of double-buffering gigabytes of k/v. All three
         # wrappers exist on every engine (trace-free until called);
         # chunked mode only ever calls _mixed, legacy only the other two.
-        def own(program):
+        def own(program, name):
             # This engine's own callable, traced with the kernels launched
-            # shard-local over its mesh (no mesh: launched as they are).
+            # shard-local over its mesh (no mesh: launched as they are),
+            # and named for what it is: a trace's ``hlo_module`` reads
+            # ``jit_<name>``.
             def on_mesh(*args):
                 with kernels_on_mesh(mesh):
                     return program(*args)
+            on_mesh.__name__ = on_mesh.__qualname__ = name
             return on_mesh
 
         self._prefill = jax.jit(
-            own(_prefill_program), static_argnums=(1,),
+            own(_prefill_program, "prefill"), static_argnums=(1,),
             donate_argnums=(2,), out_shardings=prefill_out)
         self._decode = jax.jit(
-            own(_decode_chunk_program), static_argnums=(1, 2),
+            own(_decode_chunk_program, "decode_chunk"),
+            static_argnums=(1, 2),
             donate_argnums=(3,), out_shardings=decode_out)
         self._mixed = jax.jit(
-            own(_mixed_step_program), static_argnums=(1, 2, 3),
+            own(_mixed_step_program, "mixed_step"),
+            static_argnums=(1, 2, 3),
             donate_argnums=(4,), out_shardings=mixed_out)
 
         # Perf X-ray (telemetry/xray.py): the compiled-program cost/
@@ -632,8 +652,7 @@ class InferenceEngine(object):
         self._ledger = None
         if config.perf_xray:
             self._xray = ProgramRegistry(
-                self.telemetry, platform=jax.default_backend(),
-                sample_every=config.xray_sample_every)
+                self.telemetry, platform=jax.default_backend())
 
         # Recompile detection: the test-only compile_count contract as a
         # RUNTIME gauge. The mixed program auto-warms after its first
@@ -794,14 +813,7 @@ class InferenceEngine(object):
         self._accept_base = np.zeros_like(self._accept_hist)
         self._t0 = time.time()
         self._window_t0 = self._t0
-
-    def _annotate(self, name):
-        """Profiler annotation scope, or a free no-op with telemetry
-        off (TraceAnnotation construction is cheap but not free — the
-        off-path must cost nothing)."""
-        if not self.config.telemetry:
-            return _NULL_CTX
-        return annotate(name)
+        self._steps = 0
 
     # --------------------------------------------------------- resilience
 
@@ -1176,6 +1188,9 @@ class InferenceEngine(object):
         if req.first_token_time is None:
             req.first_token_time = time.time()
             self._ttft_hist.observe(req.first_token_time - req.submit_time)
+            self.tracer.instant(
+                "request/first_token", tid=req.trace.tid, rid=req.rid,
+                hop=req.trace.hop(), **req.phase_ms())
         self.counters["tokens_out"] += 1
         if req.max_new_tokens <= 1 or \
                 (req.eos_token_id >= 0 and first == req.eos_token_id):
@@ -1236,10 +1251,12 @@ class InferenceEngine(object):
             with self._watchdog:
                 if stall > 0:
                     time.sleep(stall)
-                if self.config.chunked_prefill:
-                    done = self._step_chunked()
-                else:
-                    done = self._step_legacy()
+                with self.tracer.timed("inference/step",
+                                       step=self._steps + 1):
+                    if self.config.chunked_prefill:
+                        done = self._step_chunked()
+                    else:
+                        done = self._step_legacy()
         except self._fatal as exc:
             done = self._recover(exc)
         else:
@@ -1818,66 +1835,73 @@ class InferenceEngine(object):
 
     def _step_chunked(self):
         done = []
-        offload = self._hier is not None and self._hier.spec.offload
-        resumed = self._swap_in_ready() if offload else []
-        self._admit()
-        if offload:
-            self._maybe_swap_out(resumed)
-        pf = self._scheduler.next_prefill()
-        C = self.config.prefill_chunk
-        ids = np.zeros((1, C), np.int32)
-        if pf is not None:
-            cur = pf.cursor
-            n = int(min(C, pf.prompt.size - cur))
-            ids[0, :n] = pf.prompt[cur:cur + n]
-            slot, frontier, n_valid = pf.slot, cur, n
-            p_done = cur + n >= pf.prompt.size
-            p_spec = pf.spec
-            max_new, eos = pf.max_new_tokens, pf.eos_token_id
-            temp, top_k, seed = pf.temperature, pf.top_k, pf.seed
-        else:
-            # Idle lane: p_valid == 0 short-circuits it inside the
-            # program (lax.cond) — the remaining args are inert.
-            slot = frontier = n_valid = 0
-            p_done, max_new, eos, temp, top_k, seed = False, 1, -1, 0.0, 0, 0
-            p_spec = False
+        self._steps += 1
+        step = self._steps
+        with self.tracer.timed("inference/schedule", step=step):
+            offload = self._hier is not None and self._hier.spec.offload
+            resumed = self._swap_in_ready() if offload else []
+            self._admit()
+            if offload:
+                self._maybe_swap_out(resumed)
+            pf = self._scheduler.next_prefill()
+            C = self.config.prefill_chunk
+            ids = np.zeros((1, C), np.int32)
+            if pf is not None:
+                cur = pf.cursor
+                n = int(min(C, pf.prompt.size - cur))
+                ids[0, :n] = pf.prompt[cur:cur + n]
+                slot, frontier, n_valid = pf.slot, cur, n
+                p_done = cur + n >= pf.prompt.size
+                p_spec = pf.spec
+                max_new, eos = pf.max_new_tokens, pf.eos_token_id
+                temp, top_k, seed = pf.temperature, pf.top_k, pf.seed
+            else:
+                # Idle lane: p_valid == 0 short-circuits it inside the
+                # program (lax.cond) — the remaining args are inert.
+                slot = frontier = n_valid = 0
+                p_done, max_new, eos = False, 1, -1
+                temp, top_k, seed = 0.0, 0, 0
+                p_spec = False
 
-        if self._pager is not None:
-            # Map every position this step can write, THEN rebind the
-            # device block table if the host copy moved — the one
-            # host->device upload that makes freed rows' zeroing and
-            # fresh mappings visible atomically before the program runs.
-            self._ensure_paged_mappings(pf, n_valid, p_done)
+            if self._pager is not None:
+                # Map every position this step can write, THEN rebind the
+                # device block table if the host copy moved — the one
+                # host->device upload that makes freed rows' zeroing and
+                # fresh mappings visible atomically before the program runs.
+                self._ensure_paged_mappings(pf, n_valid, p_done)
 
+            # Device scalars built before the call so the xray stash sees
+            # the exact argument structure the program is dispatched with.
+            ids_d = jnp.asarray(ids)
+            slot_d, frontier_d = jnp.int32(slot), jnp.int32(frontier)
+            n_valid_d, p_done_d = jnp.int32(n_valid), jnp.asarray(p_done)
+            p_spec_d, max_new_d = jnp.asarray(p_spec), jnp.int32(max_new)
+            eos_d, temp_d = jnp.int32(eos), jnp.float32(temp)
+            top_k_d, seed_d = jnp.int32(top_k), jnp.uint32(seed)
+            if self._xray is not None:
+                # Shapes-only capture (signature tuple + dict compare in
+                # the steady state). track_change only after warmup so the
+                # legacy of per-bucket variety never logs as a recompile.
+                self._xray.stash(
+                    "mixed_step", self._mixed, self._params, self._adapter,
+                    self.config.chunk_size, self._spec, self._pool, ids_d,
+                    slot_d, frontier_d, n_valid_d, p_done_d, p_spec_d,
+                    max_new_d, eos_d, temp_d, top_k_d, seed_d,
+                    donate=("pool",),
+                    track_change=self.recompile_detector.warm)
         if self._injector is not None:
             # A "raise" fault fires HERE, in place of the program call —
             # the pool must be presumed donated-and-lost, exactly like a
             # real XlaRuntimeError out of the call below.
             self._injector.maybe_raise()
         self.timers("inference/decode").start()
-        # Device scalars built before the call so the xray stash sees
-        # the exact argument structure the program is dispatched with.
-        ids_d = jnp.asarray(ids)
-        slot_d, frontier_d = jnp.int32(slot), jnp.int32(frontier)
-        n_valid_d, p_done_d = jnp.int32(n_valid), jnp.asarray(p_done)
-        p_spec_d, max_new_d = jnp.asarray(p_spec), jnp.int32(max_new)
-        eos_d, temp_d = jnp.int32(eos), jnp.float32(temp)
-        top_k_d, seed_d = jnp.int32(top_k), jnp.uint32(seed)
-        if self._xray is not None:
-            # Shapes-only capture (signature tuple + dict compare in
-            # the steady state). track_change only after warmup so the
-            # legacy of per-bucket variety never logs as a recompile.
-            self._xray.stash(
-                "mixed_step", self._mixed, self._params, self._adapter,
-                self.config.chunk_size, self._spec, self._pool, ids_d,
-                slot_d, frontier_d, n_valid_d, p_done_d, p_spec_d,
-                max_new_d, eos_d, temp_d, top_k_d, seed_d,
-                donate=("pool",),
-                track_change=self.recompile_detector.warm)
         tok_before = self.counters["tokens_out"]
-        t_dispatch0 = time.perf_counter()
-        with self.tracer.timed("step/mixed", prefill_tokens=n_valid), \
-                self._annotate("inference/mixed_step"):
+        decoding = sum(1 for r in self._scheduler.running.values()
+                       if r.phase == "decoding")
+        t_dispatch = time.perf_counter()
+        with self.tracer.timed("inference/mixed_step", step=step,
+                               prefill_tokens=n_valid,
+                               active_slots=decoding):
             self._pool, first, toks, valid = self._mixed(
                 self._params, self._adapter, self.config.chunk_size,
                 self._spec,
@@ -1885,106 +1909,107 @@ class InferenceEngine(object):
                 frontier_d, n_valid_d, p_done_d,
                 p_spec_d, max_new_d, eos_d,
                 temp_d, top_k_d, seed_d)
-        if self._xray is not None and self._xray.due():
-            # Sampled 1-in-N step decomposition: bracketed
-            # block_until_ready (sanctioned sync — xray.sample_step)
-            # splits host-schedule from device-compute time and feeds
-            # the roofline's measured step seconds.
-            self._xray.sample_step(
-                "mixed_step", (self._pool, first, toks, valid),
-                time.perf_counter() - t_dispatch0)
+        t_harvest = time.perf_counter()
         # ONE batched host sync per step: tokens, validity, the per-slot
         # scalar snapshot (pos/active/last_tok in a single transfer) and
         # the (possible) first token all land together.
-        with self.tracer.timed("step/harvest"), \
-                self._annotate("inference/harvest"):
+        with self.tracer.timed("inference/harvest", step=step):
             toks = np.asarray(toks)
             valid = np.asarray(valid)
             snap = harvest_snapshot(self._pool)
-        self._last_snap = snap
-        active = snap["active"]
-        # Adapter gauges off the same host snapshot — no extra sync.
-        self._adapter.observe(snap, self.telemetry)
-        self.timers("inference/decode").stop()
-        if self._injector is not None:
-            toks = self._injector.corrupt_harvest(toks, valid)
-        # Numerics gate: AFTER the device sync, BEFORE any token reaches
-        # a request — a garbage harvest is discarded whole, which is
-        # what keeps replay recovery bit-identical.
-        self._check_harvest(toks, valid)
-        self.counters["chunks"] += 1
-        if toks.ndim == 2:
-            # Plain decode lane: one token per slot-step. Normalize to
-            # the speculative [chunk, slots, lanes] emission layout so
-            # the harvest below is one code path.
-            toks = toks[:, :, None]
-            valid = valid[:, :, None]
-        occupied = valid.any(axis=2)
-        self.counters["occupied_slot_steps"] += int(occupied.sum())
-        self.counters["slot_steps"] += occupied.size
-        if self._spec is not None:
-            self._accept_hist += np.bincount(
-                valid.sum(axis=2)[occupied],
-                minlength=self._accept_hist.size)
-            n_occ = int(occupied.sum())
-            if n_occ:
-                # draft/verify/accept summary for this step: n_occ
-                # verifies ran (one per occupied slot-step), each
-                # drafting spec_k tokens; ``accepted`` counts the
-                # emissions they produced (bonus token included).
-                self.tracer.instant(
-                    "spec/verify", verifies=n_occ,
-                    drafted=n_occ * self.config.spec_k,
-                    accepted=int(valid.sum()))
-
-        if pf is not None:
-            self.counters["prefill_tokens"] += n_valid
-            if self._scheduler.advance_prefill(pf, n_valid):
-                self.counters["prefills"] += 1
-                if self._hier is not None:
-                    # The slot's plane now holds the full prompt's k/v —
-                    # publish a missed prefix into the shared store
-                    # (eager copy; no compile).
-                    self._pool = self._hier.on_prefill_done(self._pool, pf)
-                self._harvest_first(pf, int(first), done)
-
-        harvest_t = time.time()
-        for slot, req in list(self._scheduler.running.items()):
-            if req.phase != "decoding":
-                continue  # mid-prefill slots emit nothing
-            # Boolean-mask select flattens row-major — (step, lane) IS
-            # emission order.
-            emitted = toks[:, slot][valid[:, slot]].tolist()
-            req.tokens.extend(emitted)
-            self.counters["tokens_out"] += len(emitted)
-            if emitted:
-                # Progress stamp the idle-aware swap-victim policy
-                # reads: a session that stops emitting goes stale here
-                # and becomes the preferred victim.
-                req.last_touch = harvest_t
-                # Per-chunk decode progress on the request's own track:
-                # at most one instant per emitting slot per step (ring-
-                # bounded; drops surface as trace_spans_dropped).
-                self.tracer.instant(
-                    "request/chunk", tid=req.trace.tid, rid=req.rid,
-                    hop=req.trace.hop(), emitted=len(emitted),
-                    tokens=len(req.tokens))
-            if not active[slot]:
-                self._complete(req, done)
-        if self._handoff_enabled:
-            # Prefill role: everything still decoding after this step's
-            # harvest (its prompt just finished, same-step tokens kept —
-            # they are part of the one bit-identical stream) leaves for
-            # the handoff outbox in one batched capture. Requests that
-            # COMPLETED this step already finished locally above.
-            self._capture_handoffs()
         if self._xray is not None:
-            # Per-program call/token accounting (two int adds): the
-            # flops-per-token and bytes-per-token denominators.
-            self._xray.note("mixed_step",
-                            tokens=self.counters["tokens_out"]
-                            - tok_before)
-        self._observe_compiles()
+            # The split of a step that the two spans around dispatch and
+            # harvest give on every step for nothing (the harvest blocks
+            # anyway): host dispatch against device wait, and the
+            # roofline gauges' measured step seconds. No sync of its own.
+            self._xray.observe_step(
+                "mixed_step", t_harvest - t_dispatch,
+                time.perf_counter() - t_harvest)
+        with self.tracer.timed("inference/deliver", step=step):
+            self._last_snap = snap
+            active = snap["active"]
+            # Adapter gauges off the same host snapshot — no extra sync.
+            self._adapter.observe(snap, self.telemetry)
+            self.timers("inference/decode").stop()
+            if self._injector is not None:
+                toks = self._injector.corrupt_harvest(toks, valid)
+            # Numerics gate: AFTER the device sync, BEFORE any token reaches
+            # a request — a garbage harvest is discarded whole, which is
+            # what keeps replay recovery bit-identical.
+            self._check_harvest(toks, valid)
+            self.counters["chunks"] += 1
+            if toks.ndim == 2:
+                # Plain decode lane: one token per slot-step. Normalize to
+                # the speculative [chunk, slots, lanes] emission layout so
+                # the harvest below is one code path.
+                toks = toks[:, :, None]
+                valid = valid[:, :, None]
+            occupied = valid.any(axis=2)
+            self.counters["occupied_slot_steps"] += int(occupied.sum())
+            self.counters["slot_steps"] += occupied.size
+            if self._spec is not None:
+                self._accept_hist += np.bincount(
+                    valid.sum(axis=2)[occupied],
+                    minlength=self._accept_hist.size)
+                n_occ = int(occupied.sum())
+                if n_occ:
+                    # draft/verify/accept summary for this step: n_occ
+                    # verifies ran (one per occupied slot-step), each
+                    # drafting spec_k tokens; ``accepted`` counts the
+                    # emissions they produced (bonus token included).
+                    self.tracer.instant(
+                        "spec/verify", verifies=n_occ,
+                        drafted=n_occ * self.config.spec_k,
+                        accepted=int(valid.sum()))
+
+            if pf is not None:
+                self.counters["prefill_tokens"] += n_valid
+                if self._scheduler.advance_prefill(pf, n_valid):
+                    self.counters["prefills"] += 1
+                    if self._hier is not None:
+                        # The slot's plane now holds the full prompt's k/v —
+                        # publish a missed prefix into the shared store
+                        # (eager copy; no compile).
+                        self._pool = self._hier.on_prefill_done(self._pool, pf)
+                    self._harvest_first(pf, int(first), done)
+
+            harvest_t = time.time()
+            for slot, req in list(self._scheduler.running.items()):
+                if req.phase != "decoding":
+                    continue  # mid-prefill slots emit nothing
+                # Boolean-mask select flattens row-major — (step, lane) IS
+                # emission order.
+                emitted = toks[:, slot][valid[:, slot]].tolist()
+                req.tokens.extend(emitted)
+                self.counters["tokens_out"] += len(emitted)
+                if emitted:
+                    # Progress stamp the idle-aware swap-victim policy
+                    # reads: a session that stops emitting goes stale here
+                    # and becomes the preferred victim.
+                    req.last_touch = harvest_t
+                    # Per-chunk decode progress on the request's own track:
+                    # at most one instant per emitting slot per step (ring-
+                    # bounded; drops surface as trace_spans_dropped).
+                    self.tracer.instant(
+                        "request/chunk", tid=req.trace.tid, rid=req.rid,
+                        hop=req.trace.hop(), emitted=len(emitted),
+                        tokens=len(req.tokens), **req.phase_ms())
+                if not active[slot]:
+                    self._complete(req, done)
+            if self._handoff_enabled:
+                # Prefill role: everything still decoding after this step's
+                # harvest (its prompt just finished, same-step tokens kept —
+                # they are part of the one bit-identical stream) leaves for
+                # the handoff outbox in one batched capture. Requests that
+                # COMPLETED this step already finished locally above.
+                self._capture_handoffs()
+            if self._xray is not None:
+                # Per-program call/token accounting (two int adds): the
+                # flops-per-token and bytes-per-token denominators.
+                self._xray.note("mixed_step",
+                                tokens=self.counters["tokens_out"]
+                                - tok_before)
+            self._observe_compiles()
         return done
 
     def _step_legacy(self):
@@ -1992,9 +2017,10 @@ class InferenceEngine(object):
         admitted = []
         if self._injector is not None:
             self._injector.maybe_raise()
+        self._steps += 1
+        step = self._steps
         self.timers("inference/prefill").start()
-        with self.tracer.timed("step/prefill"), \
-                self._annotate("inference/prefill"):
+        with self.tracer.timed("inference/prefill", step=step):
             for req, slot in self._scheduler.admissions():
                 # Dispatch EVERY prefill before the first host sync: N
                 # admissions pipeline on device instead of paying N
@@ -2014,22 +2040,21 @@ class InferenceEngine(object):
                     donate=("pool",),
                     track_change=self.recompile_detector.warm)
             tok_before = self.counters["tokens_out"]
-            t_dispatch0 = time.perf_counter()
-            with self.tracer.timed("step/decode"), \
-                    self._annotate("inference/decode_chunk"):
+            t_dispatch = time.perf_counter()
+            with self.tracer.timed("inference/decode_chunk", step=step):
                 self._pool, toks, valid = self._decode(
                     self._params, self._adapter, self.config.chunk_size,
                     self._pool)
-            if self._xray is not None and self._xray.due():
-                self._xray.sample_step(
-                    "decode_chunk", (self._pool, toks, valid),
-                    time.perf_counter() - t_dispatch0)
+            t_harvest = time.perf_counter()
             self.timers("inference/decode").stop()
-            with self.tracer.timed("step/harvest"), \
-                    self._annotate("inference/harvest"):
+            with self.tracer.timed("inference/harvest", step=step):
                 toks = np.asarray(toks)
                 valid = np.asarray(valid)
                 snap = harvest_snapshot(self._pool)
+            if self._xray is not None:
+                self._xray.observe_step(
+                    "decode_chunk", t_harvest - t_dispatch,
+                    time.perf_counter() - t_harvest)
             self._last_snap = snap
             active = snap["active"]
             self._adapter.observe(snap, self.telemetry)
@@ -2344,45 +2369,19 @@ class InferenceEngine(object):
                               if self._xray is not None else 0),
         }
 
-    def _xray_stash_aux(self):
-        """AOT-observe the engine programs the current serving mode
-        never dispatches (chunked mode never calls prefill/decode;
-        legacy mode never calls mixed), so every export covers the
-        full program family. Shapes come from the live pool/config;
-        zero executions — cost model only."""
-        xr, cfg = self._xray, self.config
-        i32 = jax.ShapeDtypeStruct((), jnp.int32)
-        f32 = jax.ShapeDtypeStruct((), jnp.float32)
-        u32 = jax.ShapeDtypeStruct((), jnp.uint32)
-        b = jax.ShapeDtypeStruct((), jnp.bool_)
-        if not xr.seen("decode_chunk"):
-            xr.stash("decode_chunk", self._decode, self._params,
-                     self._adapter, cfg.chunk_size, self._pool,
-                     donate=("pool",))
-        if not xr.seen("prefill"):
-            padded = jax.ShapeDtypeStruct(
-                (1, cfg.prefill_buckets[0]), jnp.int32)
-            xr.stash("prefill", self._prefill, self._params,
-                     self._adapter, self._pool, padded, i32, i32, i32,
-                     i32, f32, i32, u32, donate=("pool",))
-        if not xr.seen("mixed_step") and cfg.chunked_prefill:
-            ids = jax.ShapeDtypeStruct((1, cfg.prefill_chunk), jnp.int32)
-            xr.stash("mixed_step", self._mixed, self._params,
-                     self._adapter, cfg.chunk_size, self._spec,
-                     self._pool, ids, i32, i32, i32, b, b, i32, i32,
-                     f32, i32, u32, donate=("pool",))
-
     def perf_xray(self):
         """The schema-versioned ``perf_xray`` artifact section
         (telemetry/xray.py): per-program HLO fingerprints, cost-model
         flops/bytes, the peak-HBM split, flops/bytes per token, the
-        HBM ledger, and any post-warm recompile events. First call
-        pays the one-time AOT lower+compile of each program (off the
-        steady path; never grows a jit dispatch cache). None when
-        ``config.perf_xray`` is off."""
+        HBM ledger, and any post-warm recompile events — of the programs
+        this engine DISPATCHED (chunked mode: ``mixed_step``; legacy
+        mode: ``prefill`` per bucket and ``decode_chunk``), each analysed
+        from the shapes its step path stashed. First call pays the
+        one-time AOT lower+compile of each of those (off the steady
+        path; never grows a jit dispatch cache), and of nothing the
+        engine never ran. None when ``config.perf_xray`` is off."""
         if self._xray is None:
             return None
-        self._xray_stash_aux()
         out = self._xray.to_json()
         if self._ledger is not None:
             out["hbm"] = self._ledger.to_json()

@@ -252,6 +252,7 @@ def pool_nbytes(pool):
 
 
 @hot_path
+@jax.named_scope("kv_view")
 def cache_view(pool):
     """The pool's k/v/pos as a ``models.generation`` cache dict — the
     decode step program consumes the pool's slots directly as batch rows.
@@ -289,6 +290,7 @@ def cache_view(pool):
 
 
 @hot_path
+@jax.named_scope("kv_view")
 def slot_cache_view(pool, slot, pos):
     """ONE slot's k/v as a batch-1 cache dict for the prefill lane:
     plane slices (and scale slices when int8) along the slot axis, plus
@@ -337,6 +339,7 @@ def slot_cache_view(pool, slot, pos):
 
 
 @hot_path
+@jax.named_scope("kv_write")
 def write_slot_cache(pool, slot, cache):
     """Fold a ``slot_cache_view`` batch-1 cache back into the pool.
     Only the slot's WRITABLE state returns: k/v (+ scales); the prefix
@@ -370,6 +373,7 @@ def write_slot_cache(pool, slot, cache):
 
 
 @hot_path
+@jax.named_scope("kv_write")
 def fold_cache(pool, cache):
     """Fold a full-batch ``cache_view`` cache back into the pool after a
     decode/verify step: k/v planes and scale planes. The gathered

@@ -41,6 +41,12 @@ own track (tid=rid): a ``request/queued`` span (submit -> admit), a
 ``request/prefill`` span (admit -> first token sampled), a
 ``request/decode`` span (first token -> finish) and a whole-lifetime
 ``request`` span, with ``request/cancelled`` instants for evictions.
+Those spans are retroactive, so they reach the ring (the Chrome export)
+only; each TRANSITION is also an instant (``request/submitted``,
+``request/admitted``, ``request/first_token`` from the engine's harvest,
+``request/finished``, and the preempt / swap / handoff / expiry ones) that
+the one span call writes to the ring and, with the request's ``rid``, onto
+the profiler's clock: in a device trace a request is its instants.
 """
 
 import collections
@@ -164,6 +170,21 @@ class Request(object):
     @property
     def done(self):
         return self.finish_time is not None
+
+    def phase_ms(self):
+        """The phases this request has left behind, in milliseconds, as
+        arguments for its instants: ``queue_ms`` (submit -> admit) once
+        admitted, ``prefill_ms`` (admit -> first token) once it has one. A
+        reader of a trace that opened after a transition finds the phase
+        on any later instant of the request."""
+        out = {}
+        if self.admit_time is not None:
+            out["queue_ms"] = round(
+                (self.admit_time - self.submit_time) * 1e3, 3)
+            if self.first_token_time is not None:
+                out["prefill_ms"] = round(
+                    (self.first_token_time - self.admit_time) * 1e3, 3)
+        return out
 
 
 class Scheduler(object):
@@ -354,6 +375,9 @@ class Scheduler(object):
             if self._queue_wait is not None:
                 self._queue_wait.observe(req.admit_time - req.submit_time)
             if self.tracer is not None:
+                self.tracer.instant(
+                    "request/admitted", tid=req.trace.tid, rid=req.rid,
+                    hop=req.trace.hop(), slot=slot, **req.phase_ms())
                 self.tracer.span("request/queued", req.submit_time,
                                  req.admit_time, tid=req.trace.tid,
                                  rid=req.rid, hop=req.trace.hop(), slot=slot,
@@ -512,6 +536,9 @@ class Scheduler(object):
                 req.priority,
                 collections.deque(maxlen=32)).append(req.finish_time)
         if self.tracer is not None:
+            self.tracer.instant("request/finished", tid=req.trace.tid,
+                                rid=req.rid, hop=req.trace.hop(),
+                                tokens=len(req.tokens), **req.phase_ms())
             if req.first_token_time is not None:
                 self.tracer.span("request/decode", req.first_token_time,
                                  req.finish_time, tid=req.trace.tid,

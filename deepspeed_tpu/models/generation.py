@@ -127,7 +127,7 @@ def _dense(x, p):
 
 
 @hot_path
-def _forward(params, cfg, ids, cache, last_only=False):
+def _forward(params, cfg, ids, cache, last_only=False, attn_name=None):
     """ids [B, S], row b starting at cache['pos'][b]; returns
     (logits [B, S, V] fp32, updated cache). S=prompt_len for prefill, S=1
     inside the decode scan. Positions are PER ROW: each row embeds, masks
@@ -135,7 +135,15 @@ def _forward(params, cfg, ids, cache, last_only=False):
     sequence lengths (the serving engine's slots) share one program.
     ``last_only`` evaluates the LM head on the final position only (the
     prefill path — sampling reads just that row, and a [B, Tp, vocab]
-    fp32 buffer would otherwise dominate prefill memory).
+    fp32 buffer would otherwise dominate prefill memory). ``attn_name``
+    names the attention kernel in a trace where the caller is not the
+    decode lane (``append_forward``: ``prefill_attn``).
+
+    The regions of a trace (``jax.named_scope``, under the caller's
+    ``prefill_lane`` / ``decode_scan``): ``embed``, then per layer ``attn``
+    (LayerNorm, qkv, attention, projection), ``kv_write`` (the frontier
+    write into the planes), ``kv_view`` (the planes attention reads: the
+    page gather, the prefix select) and ``mlp``, then ``lm_head``.
 
     KV-hierarchy dispatch is DATA-DRIVEN off the cache dict
     (inference/kv_hierarchy): an int8 ``k`` plane means frontier writes
@@ -180,7 +188,8 @@ def _forward(params, cfg, ids, cache, last_only=False):
     wte = params["wte"].astype(cfg.dtype)
     q_pos = pos[:, None] + jnp.arange(S)[None]         # [B, S]
     pe = params["wpe"].astype(cfg.dtype)[q_pos]        # [B, S, C] gather
-    x = wte[ids] + pe
+    with jax.named_scope("embed"):
+        x = wte[ids] + pe
 
     # Flash-decode engages when the flag is on AND the cache plane length
     # fits the kernel's block quantum (kv_pool pads its pool; ad-hoc
@@ -275,94 +284,104 @@ def _forward(params, cfg, ids, cache, last_only=False):
 
     for i in range(cfg.n_layer):
         blk = params["h_{}".format(i)]
-        h = _ln(x, blk["ln_1"], eps)
-        qkv = _dense(h, blk["attn"]["c_attn"])
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
-        if int8:
-            kq, ks = decode_attention.quantize_kv(k)
-            vq, vs = decode_attention.quantize_kv(v)
-            k_cache = k_cache.at[i].set(write_rows(k_cache[i], kq))
-            v_cache = v_cache.at[i].set(write_rows(v_cache[i], vq))
-            ks_cache = ks_cache.at[i].set(write_scale_rows(ks_cache[i], ks))
-            vs_cache = vs_cache.at[i].set(write_scale_rows(vs_cache[i], vs))
-        else:
-            k_cache = k_cache.at[i].set(write_rows(k_cache[i], k))
-            v_cache = v_cache.at[i].set(write_rows(v_cache[i], v))
-        # Effective planes: the row's own just-written plane, with the
-        # aliased prefix selected in below pbase[b] (codes AND scales —
-        # both tiers compose). Paged rows GATHER their logical plane
-        # through the block table AFTER the write (the einsum/reference
-        # path; the paged flash kernel gathers in its own index map and
-        # skips this materialization).
-        if paged and not use_flash:
-            k_eff, v_eff = gather_pages(k_cache[i]), gather_pages(v_cache[i])
+        with jax.named_scope("attn"):
+            h = _ln(x, blk["ln_1"], eps)
+            qkv = _dense(h, blk["attn"]["c_attn"])
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
+            k = k.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
+            v = v.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
+        with jax.named_scope("kv_write"):
             if int8:
-                ks_eff = gather_pages(ks_cache[i])
-                vs_eff = gather_pages(vs_cache[i])
-        else:
-            k_eff, v_eff = k_cache[i], v_cache[i]
-            if int8:
-                ks_eff, vs_eff = ks_cache[i], vs_cache[i]
-        if has_prefix:
-            k_eff = jnp.where(psel, pad_prefix(cache["pk"][i]), k_eff)
-            v_eff = jnp.where(psel, pad_prefix(cache["pv"][i]), v_eff)
-            if int8:
-                ks_eff = jnp.where(
-                    psel_s, pad_prefix(cache["pk_scale"][i]), ks_eff)
-                vs_eff = jnp.where(
-                    psel_s, pad_prefix(cache["pv_scale"][i]), vs_eff)
-        if use_flash:
-            # Fused QK-score + online softmax + PV over the cache plane,
-            # frontier-aware: blocks past pos[b]+S-1 are skipped. The
-            # cache was just written, so pos is the PRE-write frontier
-            # the kernel's mask convention expects. The q8 family
-            # dequantizes in-block from codes + scales.
-            if paged:
-                # Block-table flash decode: the kernel's scalar-prefetch
-                # index map resolves (row, block j) -> arena page, so
-                # pages stream into VMEM straight from the table with
-                # the same straddle-only masking as the dense kernel.
-                if int8:
-                    y = decode_attention.flash_decode_attention_paged_q8(
-                        q, k_eff, v_eff, ks_eff, vs_eff, tbl, pos,
-                        scale=1.0 / float(hd) ** 0.5)
-                else:
-                    y = decode_attention.flash_decode_attention_paged(
-                        q, k_eff, v_eff, tbl, pos,
-                        scale=1.0 / float(hd) ** 0.5)
-            elif int8:
-                y = decode_attention.flash_decode_attention_q8(
-                    q, k_eff, v_eff, ks_eff, vs_eff, pos,
-                    scale=1.0 / float(hd) ** 0.5)
+                kq, ks = decode_attention.quantize_kv(k)
+                vq, vs = decode_attention.quantize_kv(v)
+                k_cache = k_cache.at[i].set(write_rows(k_cache[i], kq))
+                v_cache = v_cache.at[i].set(write_rows(v_cache[i], vq))
+                ks_cache = ks_cache.at[i].set(
+                    write_scale_rows(ks_cache[i], ks))
+                vs_cache = vs_cache.at[i].set(
+                    write_scale_rows(vs_cache[i], vs))
             else:
-                y = decode_attention.flash_decode_attention(
-                    q, k_eff, v_eff, pos, scale=1.0 / float(hd) ** 0.5)
-        else:
-            if int8:
-                k_eff = decode_attention.dequantize_kv(k_eff, ks_eff,
-                                                       cfg.dtype)
-                v_eff = decode_attention.dequantize_kv(v_eff, vs_eff,
-                                                       cfg.dtype)
-            att = jnp.einsum("bhqd,bhkd->bhqk", q, k_eff).astype(
-                jnp.float32) / jnp.sqrt(hd)
-            att = jnp.where(mask[:, None], att, neg)
-            att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
-            y = jnp.einsum("bhqk,bhkd->bhqd", att, v_eff)
-        y = y.transpose(0, 2, 1, 3).reshape(B, S, cfg.n_embd)
-        x = x + _dense(y, blk["attn"]["c_proj"])
-        h = _ln(x, blk["ln_2"], eps)
-        h = _dense(h, blk["mlp"]["c_fc"])
-        h = jax.nn.gelu(h, approximate=True)
-        x = x + _dense(h, blk["mlp"]["c_proj"])
+                k_cache = k_cache.at[i].set(write_rows(k_cache[i], k))
+                v_cache = v_cache.at[i].set(write_rows(v_cache[i], v))
+        with jax.named_scope("kv_view"):
+            # Effective planes: the row's own just-written plane, with the
+            # aliased prefix selected in below pbase[b] (codes AND scales —
+            # both tiers compose). Paged rows GATHER their logical plane
+            # through the block table AFTER the write (the einsum/reference
+            # path; the paged flash kernel gathers in its own index map and
+            # skips this materialization).
+            if paged and not use_flash:
+                k_eff = gather_pages(k_cache[i])
+                v_eff = gather_pages(v_cache[i])
+                if int8:
+                    ks_eff = gather_pages(ks_cache[i])
+                    vs_eff = gather_pages(vs_cache[i])
+            else:
+                k_eff, v_eff = k_cache[i], v_cache[i]
+                if int8:
+                    ks_eff, vs_eff = ks_cache[i], vs_cache[i]
+            if has_prefix:
+                k_eff = jnp.where(psel, pad_prefix(cache["pk"][i]), k_eff)
+                v_eff = jnp.where(psel, pad_prefix(cache["pv"][i]), v_eff)
+                if int8:
+                    ks_eff = jnp.where(
+                        psel_s, pad_prefix(cache["pk_scale"][i]), ks_eff)
+                    vs_eff = jnp.where(
+                        psel_s, pad_prefix(cache["pv_scale"][i]), vs_eff)
+        with jax.named_scope("attn"):
+            if use_flash:
+                # Fused QK-score + online softmax + PV over the cache plane,
+                # frontier-aware: blocks past pos[b]+S-1 are skipped. The
+                # cache was just written, so pos is the PRE-write frontier
+                # the kernel's mask convention expects. The q8 family
+                # dequantizes in-block from codes + scales.
+                if paged:
+                    # Block-table flash decode: the kernel's scalar-prefetch
+                    # index map resolves (row, block j) -> arena page, so
+                    # pages stream into VMEM straight from the table with
+                    # the same straddle-only masking as the dense kernel.
+                    if int8:
+                        y = decode_attention.flash_decode_attention_paged_q8(
+                            q, k_eff, v_eff, ks_eff, vs_eff, tbl, pos,
+                            scale=1.0 / float(hd) ** 0.5, name=attn_name)
+                    else:
+                        y = decode_attention.flash_decode_attention_paged(
+                            q, k_eff, v_eff, tbl, pos,
+                            scale=1.0 / float(hd) ** 0.5, name=attn_name)
+                elif int8:
+                    y = decode_attention.flash_decode_attention_q8(
+                        q, k_eff, v_eff, ks_eff, vs_eff, pos,
+                        scale=1.0 / float(hd) ** 0.5, name=attn_name)
+                else:
+                    y = decode_attention.flash_decode_attention(
+                        q, k_eff, v_eff, pos, scale=1.0 / float(hd) ** 0.5,
+                        name=attn_name)
+            else:
+                if int8:
+                    k_eff = decode_attention.dequantize_kv(k_eff, ks_eff,
+                                                           cfg.dtype)
+                    v_eff = decode_attention.dequantize_kv(v_eff, vs_eff,
+                                                           cfg.dtype)
+                att = jnp.einsum("bhqd,bhkd->bhqk", q, k_eff).astype(
+                    jnp.float32) / jnp.sqrt(hd)
+                att = jnp.where(mask[:, None], att, neg)
+                att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
+                y = jnp.einsum("bhqk,bhkd->bhqd", att, v_eff)
+            y = y.transpose(0, 2, 1, 3).reshape(B, S, cfg.n_embd)
+            x = x + _dense(y, blk["attn"]["c_proj"])
+        with jax.named_scope("mlp"):
+            h = _ln(x, blk["ln_2"], eps)
+            h = _dense(h, blk["mlp"]["c_fc"])
+            h = jax.nn.gelu(h, approximate=True)
+            x = x + _dense(h, blk["mlp"]["c_proj"])
 
     if last_only:
         x = x[:, -1:]
-    x = _ln(x, params["ln_f"], eps)
-    logits = jnp.einsum("bsc,vc->bsv", x.astype(jnp.float32),
-                        params["wte"].astype(jnp.float32))
+    with jax.named_scope("lm_head"):
+        x = _ln(x, params["ln_f"], eps)
+        logits = jnp.einsum("bsc,vc->bsv", x.astype(jnp.float32),
+                            params["wte"].astype(jnp.float32))
     # dict(cache, ...) — NOT a fresh literal — so hierarchy keys (scale
     # planes, prefix views) survive the decode scan's cache threading.
     out = dict(cache, k=k_cache, v=v_cache, pos=pos + S)
@@ -389,7 +408,8 @@ def append_forward(params, cfg, ids, cache, n_valid=None):
     frontier write never clamps (inference/kv_pool.py over-allocates by
     ``prefill_chunk``)."""
     pos0 = cache["pos"]
-    logits, cache = _forward(params, cfg, ids, cache)
+    logits, cache = _forward(params, cfg, ids, cache,
+                             attn_name="prefill_attn")
     if n_valid is not None:
         cache = dict(cache, pos=pos0 + n_valid)
     return logits, cache

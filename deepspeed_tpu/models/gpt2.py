@@ -16,6 +16,7 @@ TPU-first design notes:
 """
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -163,11 +164,19 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, deterministic=True):
         cfg = self.config
-        # Pre-LN transformer block (GPT-2 style).
-        h = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, name="ln_1")(x)
-        x = x + CausalSelfAttention(cfg, name="attn")(h, deterministic)
-        h = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, name="ln_2")(x)
-        x = x + MLP(cfg, name="mlp")(h, deterministic)
+        # Pre-LN transformer block (GPT-2 style). The regions of a trace:
+        # block/ln, block/attn, block/mlp (jax.named_scope).
+        ln = functools.partial(nn.LayerNorm, epsilon=cfg.layer_norm_epsilon,
+                               dtype=cfg.dtype)
+        with jax.named_scope("block"):
+            with jax.named_scope("ln"):
+                h = ln(name="ln_1")(x)
+            with jax.named_scope("attn"):
+                x = x + CausalSelfAttention(cfg, name="attn")(h, deterministic)
+            with jax.named_scope("ln"):
+                h = ln(name="ln_2")(x)
+            with jax.named_scope("mlp"):
+                x = x + MLP(cfg, name="mlp")(h, deterministic)
         return x
 
 
@@ -204,8 +213,9 @@ class GPT2LMHeadModel(nn.Module):
             pe = jax.lax.dynamic_slice(wpe, (pos0, 0), (T, cfg.n_embd))
         else:
             pe = wpe[:T]
-        x = wte.astype(cfg.dtype)[input_ids] + pe.astype(cfg.dtype)[None]
-        x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
+        with jax.named_scope("embed"):
+            x = wte.astype(cfg.dtype)[input_ids] + pe.astype(cfg.dtype)[None]
+            x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
 
         block_cls = Block
         if cfg.remat:
@@ -213,12 +223,15 @@ class GPT2LMHeadModel(nn.Module):
         for i in range(cfg.n_layer):
             x = block_cls(cfg, name="h_{}".format(i))(x, deterministic)
 
-        x = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, name="ln_f")(x)
+        with jax.named_scope("lm_head"):
+            x = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
+                             name="ln_f")(x)
 
         if labels is None:
             # Tied LM head: logits in fp32 for a stable softmax-xent.
-            return jnp.einsum("btc,vc->btv", x.astype(jnp.float32),
-                              wte.astype(jnp.float32))
+            with jax.named_scope("lm_head"):
+                return jnp.einsum("btc,vc->btv", x.astype(jnp.float32),
+                                  wte.astype(jnp.float32))
 
         if sp is not None:
             return _sequence_parallel_xent(x, wte, labels, cfg, sp)

@@ -81,6 +81,7 @@ def _chunked_xe_total_fwd(dtype, xc, w, lc, vc, bias_f):
     return total, res
 
 
+@jax.named_scope("lm_head")
 def _chunked_xe_total_bwd(dtype, res, g):
     # w entered as model-dtype (the nondiff arg) and bias_f as fp32, so
     # the cotangent dtypes are static; lc (int) and vc (mask) get zeros.
@@ -125,10 +126,12 @@ def _xe_head_impl(impl):
     return impl
 
 
+@jax.named_scope("lm_head")
 def chunked_tied_softmax_xent(x, wte, labels, dtype, chunk=2048, bias=None,
                               ignore_index=None, reduction="mean",
                               impl=None):
-    """Token cross-entropy against a tied [V, C] embedding decoder.
+    """Token cross-entropy against a tied [V, C] embedding decoder. In a
+    trace, head and loss, forward and backward, are the region ``lm_head``.
 
     Args:
       x: [B, T, C] final hidden states.

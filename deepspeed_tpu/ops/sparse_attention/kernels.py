@@ -365,21 +365,20 @@ def _make_fn(fwd_lut, bwd_lut, blk, scale, causal, has_kpm, has_bias,
     flags = dict(causal=causal, has_kpm=has_kpm, has_bias=has_bias,
                  kpm_mode=kpm_mode, bias_mode=bias_mode, precision=precision)
 
-    def launch(kernel, lut, grid, in_specs, out_specs, out_shape, args,
-               scratch=()):
-        """pallas_call with the LUT as the scalar-prefetch operand: one
+    def launch(name, kernel, lut, grid, in_specs, out_specs, out_shape,
+               args, scratch=()):
+        """Kernel ``name`` with the LUT as the scalar-prefetch operand: one
         flattened int32 vector in SMEM, read by the kernel bodies (index
         maps receive it as a trailing argument and ignore it)."""
         from jax.experimental.pallas import tpu as pltpu
 
-        return pl.pallas_call(
-            functools.partial(kernel, scale=scale, blk=blk,
-                              deg=lut.shape[2], **flags),
+        return pallas_mode.kernel_call(
+            name, functools.partial(kernel, scale=scale, blk=blk,
+                                    deg=lut.shape[2], **flags),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
                 out_specs=out_specs, scratch_shapes=list(scratch)),
             out_shape=out_shape,
-            interpret=pallas_mode.interpret(),
         )(jnp.asarray(lut.reshape(-1)), *args)
 
     def specs(t, d):
@@ -408,7 +407,7 @@ def _make_fn(fwd_lut, bwd_lut, blk, scale, causal, has_kpm, has_bias,
             in_specs.append(bias_spec)
             args.append(bias.astype(jnp.float32))
         o, lse = launch(
-            _fwd_kernel, fwd_lut, (b, h, t // blk), in_specs,
+            "sparse_attn_fwd", _fwd_kernel, fwd_lut, (b, h, t // blk), in_specs,
             [q_spec, row_blk],
             [jax.ShapeDtypeStruct(q.shape, q.dtype),
              jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32)], args)
@@ -451,15 +450,15 @@ def _make_fn(fwd_lut, bwd_lut, blk, scale, causal, has_kpm, has_bias,
             from jax.experimental.pallas import tpu as pltpu
 
             dq, dk, dv = launch(
-                _bwd_fused_kernel, fwd_lut, grid, in_specs,
+                "sparse_attn_bwd_fused", _bwd_fused_kernel, fwd_lut, grid, in_specs,
                 [q_spec, full, full], qkv_shapes, args,
                 scratch=[pltpu.VMEM((t, d), jnp.float32),
                          pltpu.VMEM((t, d), jnp.float32)])
             return _finish_bwd(q, k, v, kpm, bias, do, lse, delta,
                                dq, dk, dv)
 
-        dq = launch(_bwd_dq_kernel, fwd_lut, grid, in_specs, q_spec,
-                    qkv_shapes[0], args)
+        dq = launch("sparse_attn_bwd_dq", _bwd_dq_kernel, fwd_lut, grid,
+                    in_specs, q_spec, qkv_shapes[0], args)
 
         # dk/dv: the same grid over KEY blocks, walking the transposed LUT.
         kv_spec = q_spec
@@ -476,6 +475,7 @@ def _make_fn(fwd_lut, bwd_lut, blk, scale, causal, has_kpm, has_bias,
         in_specs += [full, row_full, row_full]
         args += [do, lse, delta]
         dk, dv = launch(
+            "sparse_attn_bwd_dkv",
             functools.partial(_bwd_dkv_kernel, bq=blk), bwd_lut, grid,
             in_specs, [kv_spec, kv_spec], qkv_shapes[1:], args)
 

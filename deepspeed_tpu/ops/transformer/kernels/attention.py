@@ -372,7 +372,8 @@ def _flash_fwd_pallas(q, k, v, mask, scale, causal, block_q, block_k):
             pl.BlockSpec((block_q, block_k), lambda b_, h_, i, j: (0, 0)))
         args.append(_tril_block(block_q, block_k))
 
-    o, lse = pl.pallas_call(
+    o, lse = pallas_mode.kernel_call(
+        "flash_fwd",
         functools.partial(_fwd_kernel, causal=causal,
                           block_q=block_q, block_k=block_k,
                           has_mask=mask is not None, has_tril=use_tril,
@@ -392,7 +393,6 @@ def _flash_fwd_pallas(q, k, v, mask, scale, causal, block_q, block_k):
             pltpu.VMEM((block_q, _STATS_LANES), jnp.float32),
             pltpu.VMEM((block_q, _STATS_LANES), jnp.float32),
         ],
-        interpret=pallas_mode.interpret(),
     )(*args)
     return o, lse
 
@@ -735,7 +735,8 @@ def _flash_bwd_fused_pallas(q, k, v, mask, delta, lse, do, scale, causal,
     in_specs += [q_spec, row_spec, row_spec]
     args += [do, lse, delta]
 
-    dq, dk, dv = pl.pallas_call(
+    dq, dk, dv = pallas_mode.kernel_call(
+        "flash_bwd_fused",
         functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
                           has_mask=mask is not None, has_tril=use_tril),
@@ -747,7 +748,6 @@ def _flash_bwd_fused_pallas(q, k, v, mask, delta, lse, do, scale, causal,
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((t_kv, d), jnp.float32),
                         pltpu.VMEM((t_kv, d), jnp.float32)],
-        interpret=pallas_mode.interpret(),
     )(*args)
     # Tuple, not pallas_call's list: callers unpack and re-wrap it, and
     # jax's out-tree flattening is container-type strict.
@@ -813,7 +813,8 @@ def _flash_bwd_pallas(q, k, v, mask, delta, lse, g, scale, causal, block_q,
         args.append(tril)
     in_specs += [q_spec, row_spec, row_spec]
     args += [do, lse, delta]
-    dq = pl.pallas_call(
+    dq = pallas_mode.kernel_call(
+        "flash_bwd_dq",
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
                           has_mask=mask is not None, has_tril=use_tril,
@@ -824,7 +825,6 @@ def _flash_bwd_pallas(q, k, v, mask, delta, lse, g, scale, causal, block_q,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[] if n_kv == 1 else
         [pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=pallas_mode.interpret(),
     )(*args)
 
     # dk/dv: grid over (kv block, q block), q innermost and pipelined.
@@ -854,7 +854,8 @@ def _flash_bwd_pallas(q, k, v, mask, delta, lse, g, scale, causal, block_q,
         args.append(tril)
     in_specs += [q_spec2, row_spec2, row_spec2]
     args += [do, lse, delta]
-    dk, dv = pl.pallas_call(
+    dk, dv = pallas_mode.kernel_call(
+        "flash_bwd_dkv",
         functools.partial(_bwd_dkv_kernel, causal=causal,
                           block_q=block_q, block_k=block_k,
                           has_mask=mask is not None, has_tril=use_tril,
@@ -867,7 +868,6 @@ def _flash_bwd_pallas(q, k, v, mask, delta, lse, g, scale, causal, block_q,
         scratch_shapes=[] if n_q == 1 else
         [pltpu.VMEM((block_k, d), jnp.float32),
          pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=pallas_mode.interpret(),
     )(*args)
 
     return dq, dk, dv

@@ -246,7 +246,8 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, *scratch,
         o_ref[0, 0] = (acc[...] / l).astype(o_ref.dtype)
 
 
-def _flash_decode_pallas(q, k, v, pos, scale, block_k):
+def _flash_decode_pallas(q, k, v, pos, scale, block_k,
+                         name=None):
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, s, d = q.shape
@@ -286,12 +287,12 @@ def _flash_decode_pallas(q, k, v, pos, scale, block_k):
             pltpu.VMEM((s_blk, _STATS_LANES), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    out = pallas_mode.kernel_call(
+        name or "decode_attn",
         functools.partial(_decode_kernel, s_len=s, block_k=block_k,
                           single_kv=n_kv == 1),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d), q.dtype),
-        interpret=pallas_mode.interpret(),
     )(pos, q, k, v)
     return out[:, :, :s] if s_blk != s else out
 
@@ -378,7 +379,8 @@ def _decode_kernel_q8(pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
         o_ref[0, 0] = (acc[...] / l).astype(o_ref.dtype)
 
 
-def _flash_decode_q8_pallas(q, k, v, k_scale, v_scale, pos, scale, block_k):
+def _flash_decode_q8_pallas(q, k, v, k_scale, v_scale, pos, scale, block_k,
+                            name=None):
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, s, d = q.shape
@@ -421,12 +423,12 @@ def _flash_decode_q8_pallas(q, k, v, k_scale, v_scale, pos, scale, block_k):
             pltpu.VMEM((s_blk, _STATS_LANES), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    out = pallas_mode.kernel_call(
+        name or "decode_attn_q8",
         functools.partial(_decode_kernel_q8, s_len=s, block_k=block_k,
                           single_kv=n_kv == 1),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d), q.dtype),
-        interpret=pallas_mode.interpret(),
     )(pos, q, k, v, k_scale, v_scale)
     return out[:, :, :s] if s_blk != s else out
 
@@ -542,7 +544,8 @@ def resolve_decode_block(q, k, block_k=None, v=None, pos=None, scales=None,
 # ---------------------------------------------------------------------------
 
 @hot_path
-def flash_decode_attention(q, k, v, pos, scale=None, block_k=None):
+def flash_decode_attention(q, k, v, pos, scale=None, block_k=None,
+                           name=None):
     """Length-aware fused cache attention over a slotted KV plane.
 
     Args:
@@ -558,6 +561,10 @@ def flash_decode_attention(q, k, v, pos, scale=None, block_k=None):
       scale: score scale; default 1/sqrt(D).
       block_k: length-dim tile; default consults the autotuner
         ("decode_attention" family). DS_TPU_FLASH_DECODE_BLOCK overrides.
+      name: the kernel's name in a trace where the caller is not the decode
+        lane (the prefill lane passes ``prefill_attn``); default: the
+        family's own (``decode_attn``, ``decode_attn_q8``, ``paged_decode``,
+        ``paged_decode_q8``). The same on all four entry points.
     Returns: [B, H, S, D] in q.dtype.
     """
     d = q.shape[-1]
@@ -568,14 +575,14 @@ def flash_decode_attention(q, k, v, pos, scale=None, block_k=None):
         return decode_attention_reference(q, k, v, pos, scale=scale)
     return on_shards(
         functools.partial(_flash_decode_pallas, scale=float(scale),
-                          block_k=int(bk)),
+                          block_k=int(bk), name=name),
         kernel_sharding(q.shape[0], q.shape[1]),
         ("bh", "bh", "bh", "b"), ("bh",))(q, k, v, pos)
 
 
 @hot_path
 def flash_decode_attention_q8(q, k, v, k_scale, v_scale, pos, scale=None,
-                              block_k=None):
+                              block_k=None, name=None):
     """int8-cache flash decode: same contract as ``flash_decode_attention``
     but k/v are int8 codes with fp32 per-(head, position) scales
     (``quantize_kv``'s output layout, [B, H, T] alongside [B, H, T, D]
@@ -595,7 +602,7 @@ def flash_decode_attention_q8(q, k, v, k_scale, v_scale, pos, scale=None,
                                              pos, scale=scale)
     return on_shards(
         functools.partial(_flash_decode_q8_pallas, scale=float(scale),
-                          block_k=int(bk)),
+                          block_k=int(bk), name=name),
         kernel_sharding(q.shape[0], q.shape[1]),
         ("bh", "bh", "bh", "bh", "bh", "b"), ("bh",))(
             q, k, v, k_scale, v_scale, pos)
@@ -666,7 +673,8 @@ def decode_attention_paged_q8_reference(q, k, v, k_scale, v_scale,
     return decode_attention_reference(q, kf, vf, pos, scale=scale)
 
 
-def _flash_decode_paged_pallas(q, k, v, tbl, pos, scale):
+def _flash_decode_paged_pallas(q, k, v, tbl, pos, scale,
+                               name=None):
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, s, d = q.shape
@@ -705,18 +713,18 @@ def _flash_decode_paged_pallas(q, k, v, tbl, pos, scale):
             pltpu.VMEM((s_blk, _STATS_LANES), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    out = pallas_mode.kernel_call(
+        name or "paged_decode",
         functools.partial(_decode_kernel_paged, s_len=s, block_k=page_len,
                           single_kv=n_lp == 1),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d), q.dtype),
-        interpret=pallas_mode.interpret(),
     )(pos, tbl, q, k, v)
     return out[:, :, :s] if s_blk != s else out
 
 
 def _flash_decode_paged_q8_pallas(q, k, v, k_scale, v_scale, tbl, pos,
-                                  scale):
+                                  scale, name=None):
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, s, d = q.shape
@@ -756,18 +764,19 @@ def _flash_decode_paged_q8_pallas(q, k, v, k_scale, v_scale, tbl, pos,
             pltpu.VMEM((s_blk, _STATS_LANES), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    out = pallas_mode.kernel_call(
+        name or "paged_decode_q8",
         functools.partial(_decode_kernel_paged_q8, s_len=s,
                           block_k=page_len, single_kv=n_lp == 1),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d), q.dtype),
-        interpret=pallas_mode.interpret(),
     )(pos, tbl, q, k, v, k_scale, v_scale)
     return out[:, :, :s] if s_blk != s else out
 
 
 @hot_path
-def flash_decode_attention_paged(q, k, v, block_tbl, pos, scale=None):
+def flash_decode_attention_paged(q, k, v, block_tbl, pos, scale=None,
+                                 name=None):
     """Block-table flash decode over a page arena.
 
     Args:
@@ -795,14 +804,15 @@ def flash_decode_attention_paged(q, k, v, block_tbl, pos, scale=None):
         return decode_attention_paged_reference(q, k, v, block_tbl, pos,
                                                 scale=scale)
     return on_shards(
-        functools.partial(_flash_decode_paged_pallas, scale=float(scale)),
+        functools.partial(_flash_decode_paged_pallas, scale=float(scale),
+                          name=name),
         kernel_sharding(q.shape[0], q.shape[1]),
         ("bh", "-h", "-h", "b", "b"), ("bh",))(q, k, v, block_tbl, pos)
 
 
 @hot_path
 def flash_decode_attention_paged_q8(q, k, v, k_scale, v_scale, block_tbl,
-                                    pos, scale=None):
+                                    pos, scale=None, name=None):
     """int8 block-table flash decode: ``flash_decode_attention_paged``
     over int8 code arenas with fp32 per-(head, position) scale arenas
     [P, H, page_len], dequantizing in-block exactly like the dense q8
@@ -815,7 +825,8 @@ def flash_decode_attention_paged_q8(q, k, v, k_scale, v_scale, block_tbl,
         return decode_attention_paged_q8_reference(
             q, k, v, k_scale, v_scale, block_tbl, pos, scale=scale)
     return on_shards(
-        functools.partial(_flash_decode_paged_q8_pallas, scale=float(scale)),
+        functools.partial(_flash_decode_paged_q8_pallas, scale=float(scale),
+                          name=name),
         kernel_sharding(q.shape[0], q.shape[1]),
         ("bh", "-h", "-h", "-h", "-h", "b", "b"), ("bh",))(
             q, k, v, k_scale, v_scale, block_tbl, pos)

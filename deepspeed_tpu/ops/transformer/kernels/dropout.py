@@ -91,13 +91,12 @@ def _dropout_fwd(x, seed, rate, bias, residual):
     else:
         kernel = functools.partial(_dropout_kernel, rate=rate, n_cols=hidden)
 
-    o = pl.pallas_call(
-        kernel,
+    o = pallas_mode.kernel_call(
+        "dropout_fwd", kernel,
         grid=(n // rows,),
         in_specs=in_specs,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((n, hidden), x.dtype),
-        interpret=False,
     )(*args)
     return o.reshape(x.shape)
 
@@ -150,13 +149,12 @@ def _regen_mask_tpu(shape, seed, rate):
     while n % rows:
         rows //= 2
     rows = max(rows, 1)
-    return pl.pallas_call(
-        functools.partial(_mask_kernel, rate=rate),
+    return pallas_mode.kernel_call(
+        "dropout_mask", functools.partial(_mask_kernel, rate=rate),
         grid=(n // rows,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((rows, hidden), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, hidden), jnp.float32),
-        interpret=False,
     )(jnp.asarray([seed], jnp.int32))
 
 

@@ -41,14 +41,13 @@ def _bias_gelu_fwd(x, bias):
     rows = max(8, min(n, (2 * 1024 * 1024) // max(1, hidden * 4)))
     while n % rows:
         rows //= 2
-    o = pl.pallas_call(
-        _bias_gelu_kernel,
+    o = pallas_mode.kernel_call(
+        "bias_gelu", _bias_gelu_kernel,
         grid=(n // max(rows, 1),),
         in_specs=[pl.BlockSpec((max(rows, 1), hidden), lambda i: (i, 0)),
                   pl.BlockSpec((hidden,), lambda i: (0,))],
         out_specs=pl.BlockSpec((max(rows, 1), hidden), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, hidden), x.dtype),
-        interpret=pallas_mode.interpret(),
     )(x2, bias)
     return o.reshape(x.shape)
 

@@ -82,8 +82,8 @@ def _ln_fwd(x, gamma, beta, bias, residual, eps):
     else:
         kernel = functools.partial(_ln_fwd_kernel, eps=eps)
 
-    o, mu, rstd = pl.pallas_call(
-        kernel,
+    o, mu, rstd = pallas_mode.kernel_call(
+        "layer_norm_fwd", kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=[row_spec, stat_spec, stat_spec],
@@ -92,7 +92,6 @@ def _ln_fwd(x, gamma, beta, bias, residual, eps):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
-        interpret=pallas_mode.interpret(),
     )(*args)
     return o.reshape(orig_shape), mu, rstd
 

@@ -60,13 +60,12 @@ def _softmax_fwd(scores, mask, scale, causal):
     else:
         kernel = functools.partial(_softmax_kernel, scale=scale, causal=causal)
 
-    return pl.pallas_call(
-        kernel,
+    return pallas_mode.kernel_call(
+        "attn_softmax", kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(scores.shape, scores.dtype),
-        interpret=pallas_mode.interpret(),
     )(*args)
 
 

@@ -61,7 +61,7 @@ from deepspeed_tpu.runtime.utils import (
 )
 from deepspeed_tpu.runtime.utils import global_norm as utils_global_norm
 from deepspeed_tpu.telemetry import (MetricsRegistry, ProgramRegistry,
-                                     TensorBoardScalarWriter)
+                                     SpanRecorder, TensorBoardScalarWriter)
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 
@@ -211,6 +211,12 @@ class DeepSpeedEngine(object):
         # (Prometheus text, the TensorBoard scalar writer behind the
         # tensorboard_* config keys) read the same registry.
         self.telemetry = MetricsRegistry(engine="training")
+        # The span recorder the serving engine has (telemetry/tracing.py):
+        # one call writes the ring and the profiler's trace under one name.
+        # train_batch leaves train/step > train/shard_batch, train/dispatch,
+        # train/bookkeeping; the three-call path train/forward,
+        # train/backward, train/update. No span syncs the device.
+        self.tracer = SpanRecorder()
         self.timers = SynchronizedWallClockTimer(registry=self.telemetry)
         self.tput_timer = ThroughputTimer(
             batch_size=self.train_micro_batch_size_per_gpu(),
@@ -231,8 +237,7 @@ class DeepSpeedEngine(object):
         # (microseconds; no compile). perf_xray() / the flops profiler
         # materialize the cost/memory records on demand.
         self.xray = ProgramRegistry(self.telemetry,
-                                    platform=jax.default_backend(),
-                                    sample_every=0)
+                                    platform=jax.default_backend())
 
         self.training_dataloader = self.deepspeed_io(training_data) \
             if training_data else None
@@ -1270,17 +1275,20 @@ class DeepSpeedEngine(object):
             fwd_bwd = self._get_streaming_fwd_bwd(
                 len(inputs), static_kwargs, traced_kwargs.keys(),
                 self.training)
-            out, sqnorms, token = fwd_bwd(self.params, inputs,
-                                          traced_kwargs, step_rng, scale)
+            with self.tracer.timed("train/forward",
+                                   step_num=self.global_steps):
+                out, sqnorms, token = fwd_bwd(self.params, inputs,
+                                              traced_kwargs, step_rng, scale)
             self._cached_grads = _StreamedGrads(sqnorms, token)
             if self.wall_clock_breakdown():
-                self.timers("forward").stop()
+                self.timers("forward").stop(wait_for=(out, sqnorms))
                 self.timers("forward_microstep").stop()
             return out
         fwd_bwd = self._get_fwd_bwd(len(inputs), static_kwargs,
                                     traced_kwargs.keys(), self.training)
-        out, grads = fwd_bwd(self.params, inputs, traced_kwargs,
-                             step_rng, scale)
+        with self.tracer.timed("train/forward", step_num=self.global_steps):
+            out, grads = fwd_bwd(self.params, inputs, traced_kwargs,
+                                 step_rng, scale)
         if pg_correctness_test and self.training:
             self._pg_correctness_check(inputs, static_kwargs, traced_kwargs,
                                        step_rng, scale, grads)
@@ -1300,7 +1308,9 @@ class DeepSpeedEngine(object):
             self._cached_grads = grads
 
         if self.wall_clock_breakdown():
-            self.timers("forward").stop()
+            # The opt-in breakdown waits for what each phase produced
+            # (utils/timer.py): forward + backward are one program here.
+            self.timers("forward").stop(wait_for=(out, grads))
             self.timers("forward_microstep").stop()
 
         if self.flops_profiler_enabled() and \
@@ -1421,18 +1431,19 @@ class DeepSpeedEngine(object):
                 self.timers("backward_microstep").stop()
             return loss
 
-        if self._grad_acc is None:
-            if gas > 1:
-                self._grad_acc = jax.tree_util.tree_map(
-                    lambda g: g / gas, grads)
+        with self.tracer.timed("train/backward", step_num=self.global_steps):
+            if self._grad_acc is None:
+                if gas > 1:
+                    self._grad_acc = jax.tree_util.tree_map(
+                        lambda g: g / gas, grads)
+                else:
+                    self._grad_acc = grads
             else:
-                self._grad_acc = grads
-        else:
-            self._grad_acc = jax.tree_util.tree_map(
-                lambda a, g: a + g / gas, self._grad_acc, grads)
+                self._grad_acc = jax.tree_util.tree_map(
+                    lambda a, g: a + g / gas, self._grad_acc, grads)
 
         if self.wall_clock_breakdown():
-            self.timers("backward").stop()
+            self.timers("backward").stop(wait_for=self._grad_acc)
             self.timers("backward_microstep").stop()
 
         return loss
@@ -1462,6 +1473,7 @@ class DeepSpeedEngine(object):
         optimizer = self.optimizer
         clip = self.gradient_clipping()
 
+        @jax.named_scope("optimizer")
         def update(params, opt_state, grads, inv_scale, lr, beta1, beta2):
             grads = jax.tree_util.tree_map(
                 lambda g: g.astype(jnp.float32) * inv_scale, grads)
@@ -1839,12 +1851,14 @@ class DeepSpeedEngine(object):
         if self.is_gradient_accumulation_boundary():
             if self.progressive_layer_drop:
                 self.progressive_layer_drop.update_state(self.global_steps)
-            self._take_model_step(lr_kwargs)
+            with self.tracer.timed("train/update",
+                                   step_num=self.global_steps):
+                self._take_model_step(lr_kwargs)
 
         self.tput_timer.stop(self.global_rank == 0)
 
         if self.wall_clock_breakdown():
-            self.timers("step").stop()
+            self.timers("step").stop(wait_for=self.params)
             self.timers("step_microstep").stop()
             if self.is_gradient_accumulation_boundary() and \
                     self.global_steps % self.steps_per_print() == 0:
@@ -1964,7 +1978,7 @@ class DeepSpeedEngine(object):
                                         new_st["server_error"])
             return loss, new_params, new_st
 
-        def fused(params, opt_state, args, rng, lr, beta1, beta2):
+        def train_step(params, opt_state, args, rng, lr, beta1, beta2):
             in_specs = (rep_spec(params), state_spec,
                         tuple(mesh_lib.batch_partition_spec(x, dp)
                               for x in args), P(), P(), P(), P())
@@ -1977,7 +1991,7 @@ class DeepSpeedEngine(object):
         if self._shardings_ready:
             out_shardings = (None, self.param_sharding,
                              self.opt_state_sharding)
-        return jax.jit(fused, donate_argnums=(0, 1),
+        return jax.jit(train_step, donate_argnums=(0, 1),
                        out_shardings=out_shardings)
 
     def train_batch(self, batch=None, data_iter=None):
@@ -1992,6 +2006,13 @@ class DeepSpeedEngine(object):
         if batch is None:
             assert data_iter is not None
             batch = next(data_iter)
+        # A StepTraceAnnotation-style span (``_r``): the profiler's tools
+        # group the trace by it.
+        with self.tracer.timed("train/step", step_num=self.global_steps,
+                               _r=1):
+            return self._train_batch(batch)
+
+    def _train_batch(self, batch):
         if self.fp16_enabled() or self.gradient_accumulation_steps() > 1 or \
                 self._offload_mode():
             loss = self.forward(*batch) if isinstance(batch, (tuple, list)) \
@@ -2005,7 +2026,8 @@ class DeepSpeedEngine(object):
                            for x in batch)
         else:
             inputs = (jnp.asarray(batch),)
-        inputs = mesh_lib.shard_batch(self.mesh, inputs)
+        with self.tracer.timed("train/shard_batch"):
+            inputs = mesh_lib.shard_batch(self.mesh, inputs)
 
         if self.params is None:
             variables = self.module.init(
@@ -2033,7 +2055,11 @@ class DeepSpeedEngine(object):
             grad_constraint = self._grad_constraint
             mesh = self.mesh
 
-            def fused(params, opt_state, args, rng, lr, beta1, beta2):
+            # Named for what it is: a trace's hlo_module reads
+            # jit_train_step. Its regions (jax.named_scope): the model's
+            # (embed, block/ln|attn|mlp, lm_head: models/gpt2.py) and
+            # optimizer (gradient cast, clip, update).
+            def train_step(params, opt_state, args, rng, lr, beta1, beta2):
                 def loss_fn(p):
                     cp = cast(p)
                     with kernels_on_mesh(mesh):
@@ -2044,12 +2070,14 @@ class DeepSpeedEngine(object):
                 if grad_constraint is not None:
                     grads = jax.lax.with_sharding_constraint(
                         grads, grad_constraint)
-                grads = jax.tree_util.tree_map(
-                    lambda g: g.astype(jnp.float32), grads)
-                if clip > 0.0:
-                    grads, _ = clip_grad_norm_(grads, clip)
-                new_params, new_state = optimizer.update(
-                    params, grads, opt_state, lr=lr, betas=(beta1, beta2))
+                with jax.named_scope("optimizer"):
+                    grads = jax.tree_util.tree_map(
+                        lambda g: g.astype(jnp.float32), grads)
+                    if clip > 0.0:
+                        grads, _ = clip_grad_norm_(grads, clip)
+                    new_params, new_state = optimizer.update(
+                        params, grads, opt_state, lr=lr,
+                        betas=(beta1, beta2))
                 return loss, new_params, new_state
 
             out_shardings = None
@@ -2057,7 +2085,8 @@ class DeepSpeedEngine(object):
                 out_shardings = (None, self.param_sharding,
                                  self.opt_state_sharding)
             self._fused_step_cache[key] = jax.jit(
-                fused, donate_argnums=(0, 1), out_shardings=out_shardings)
+                train_step, donate_argnums=(0, 1),
+                out_shardings=out_shardings)
 
         self.tput_timer.start()
         group = self.optimizer.param_groups[0]
@@ -2075,18 +2104,21 @@ class DeepSpeedEngine(object):
                         donate=("params", "opt_state"))
         self.xray.note("fused_train_step[{}]".format(key),
                        tokens=self.train_batch_size())
-        loss, self.params, self.opt_state = jitted(
-            self.params, self.opt_state, inputs, rng, lr_d, b1_d, b2_d)
-        if self.lr_scheduler is not None:
-            self.lr_scheduler.step()
-        self.global_steps += 1
-        self.global_samples += self.train_batch_size()
-        self.micro_steps += 1
-        self._last_loss = loss
-        self._tensorboard_step_events()
-        if hasattr(self.optimizer, "notify_step"):
-            self.optimizer.notify_step(self.global_steps - self.skipped_steps)
-        self.tput_timer.stop(True)
+        with self.tracer.timed("train/dispatch"):
+            loss, self.params, self.opt_state = jitted(
+                self.params, self.opt_state, inputs, rng, lr_d, b1_d, b2_d)
+        with self.tracer.timed("train/bookkeeping"):
+            if self.lr_scheduler is not None:
+                self.lr_scheduler.step()
+            self.global_steps += 1
+            self.global_samples += self.train_batch_size()
+            self.micro_steps += 1
+            self._last_loss = loss
+            self._tensorboard_step_events()
+            if hasattr(self.optimizer, "notify_step"):
+                self.optimizer.notify_step(
+                    self.global_steps - self.skipped_steps)
+            self.tput_timer.stop(True)
         return loss
 
     # -------------------------------------------------------- flops profiler

@@ -21,12 +21,32 @@ keeps the crash, not the boot). Span counts per name are tracked
 EXACTLY (counters, not ring occupancy) so bench can report how many
 spans each phase emitted even after the ring wrapped.
 
+ONE CALL, TWO SINKS. ``timed(name, **args)`` and ``instant(name, **args)``
+write the ring AND a ``jax.profiler.TraceAnnotation(name, **args)`` under
+the SAME name, so a phase or a request transition that the Chrome export
+shows is also on the profiler's clock, beside the device's operations, in
+a ``jax.profiler`` capture (the keyword arguments arrive there as the
+event's stats; a ``tid`` other than 0 rides along as one more). Outside a
+capture the annotation costs one flag test. ``span(name, start, end)``
+records after the fact and so reaches the ring only.
+
 ``NullRecorder`` is the telemetry-off stand-in: same surface, no work.
 """
 
 import collections
+import contextlib
 import json
 import time
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or a stand-in that does nothing
+    where jax is absent (this package imports clean without it)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return lambda name, **args: contextlib.nullcontext()
+    return TraceAnnotation
 
 
 class SpanRecorder(object):
@@ -36,6 +56,7 @@ class SpanRecorder(object):
         self.capacity = capacity
         self._clock = clock
         self._pid = pid
+        self._annotation = _profiler_annotation()
         self._ring = collections.deque(maxlen=capacity)
         self._counts = {}
         self._t0 = clock()
@@ -65,7 +86,16 @@ class SpanRecorder(object):
             "args": args,
         })
 
+    def _profiled(self, name, tid, args):
+        if tid:
+            return self._annotation(name, tid=tid, **args)
+        return self._annotation(name, **args)
+
     def instant(self, name, tid=0, **args):
+        """One instant ("i") event in the ring, and a zero-length
+        annotation of the same name on the profiler's clock."""
+        with self._profiled(name, tid, args):
+            pass
         self._emit({
             "name": name,
             "ph": "i",
@@ -77,7 +107,7 @@ class SpanRecorder(object):
         })
 
     class _Timed(object):
-        __slots__ = ("rec", "name", "tid", "args", "_start")
+        __slots__ = ("rec", "name", "tid", "args", "_start", "_ann")
 
         def __init__(self, rec, name, tid, args):
             self.rec = rec
@@ -85,17 +115,22 @@ class SpanRecorder(object):
             self.tid = tid
             self.args = args
             self._start = None
+            self._ann = None
 
         def __enter__(self):
+            self._ann = self.rec._profiled(self.name, self.tid, self.args)
+            self._ann.__enter__()
             self._start = self.rec._clock()
             return self
 
         def __exit__(self, *exc):
             self.rec.span(self.name, self._start, tid=self.tid, **self.args)
+            self._ann.__exit__(*exc)
             return False
 
     def timed(self, name, tid=0, **args):
-        """Context manager: records one span around the body."""
+        """Context manager: one span around the body, in the ring and
+        (same name, same arguments) on the profiler's clock."""
         return self._Timed(self, name, tid, args)
 
     # ------------------------------------------------------------ export

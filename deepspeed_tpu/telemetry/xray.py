@@ -34,11 +34,12 @@ Three pieces:
   publishes the cost facts with ``platform="cpu"`` labels and NO
   utilization gauges — a fabricated MFU is worse than none.
 
-- Step-time decomposition: ``due()``/``sample_step()`` bracket 1-in-N
-  dispatches with ``jax.block_until_ready`` to split host-schedule time
-  from device-compute time. The sync is real — ``sample_step`` is a
-  graftlint ``SANCTIONED_SYNC_SITES`` entry — but sampled, off the
-  steady path, and feeds the only measured seconds the roofline uses.
+- Step-time decomposition: ``observe_step(label, dispatch_s, wait_s)``
+  takes, on EVERY step, the two durations the engine already has: its
+  span around the dispatch (host schedule) and its span around the
+  harvest, which blocks on the step's outputs anyway (device wait). No
+  sync of its own; their sum is the only measured seconds the roofline
+  uses.
 
 ``HBMLedger`` reconciles predicted HBM (params + KV arena + program
 temp) against live ``device.memory_stats()`` where the backend has it,
@@ -50,6 +51,7 @@ imported lazily inside the functions that need it.
 """
 
 import hashlib
+import re
 import threading
 import time
 from itertools import chain as _chain
@@ -65,6 +67,23 @@ SCHEMA_VERSION = 1
 _KERNEL_CALL = 'custom_call_target="tpu_custom_call"'
 _COLLECTIVE_OPS = ("all-reduce", "reduce-scatter", "all-gather",
                    "all-to-all", "collective-permute")
+
+# ``{module name: {instruction name: op_name}}`` of every program an export
+# analysed, from its optimised HLO text: the ``jax.named_scope`` path of each
+# instruction. Process-wide, for a reader of a profiler trace whose file
+# does not embed the program (the profiler leaves out the four-chip SPMD
+# step; benchmark/scope_reduce.py looks here then).
+OP_NAMES = {}
+_HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+_HLO_OP_NAME = re.compile(
+    r'^\s*(?:ROOT )?%?([\w.\-]+) = .*?\bop_name="([^"]*)"', re.M)
+
+
+def _record_op_names(text):
+    module = _HLO_MODULE.match(text)
+    if module is not None:
+        OP_NAMES[module.group(1)] = dict(_HLO_OP_NAME.findall(text))
+
 
 # Bound on retained recompile events: a genuine recompile loop must not
 # grow the autopsy (or the registry) without bound. Overflow is counted
@@ -202,15 +221,12 @@ class ProgramRegistry(object):
     """The observatory. ``registry`` is a MetricsRegistry (or None for
     a private, unpublished instance — the flops profiler's mode);
     ``platform`` is a jax backend name (detected lazily when omitted);
-    ``peaks`` overrides the DEVICE_PEAKS row; ``sample_every`` is the
-    1-in-N step-decomposition sampling period (0 disables)."""
+    ``peaks`` overrides the DEVICE_PEAKS row."""
 
-    def __init__(self, registry=None, platform=None, peaks=None,
-                 sample_every=64):
+    def __init__(self, registry=None, platform=None, peaks=None):
         self._registry = registry
         self._platform = platform
         self._peaks_override = peaks
-        self._sample_every = int(sample_every)
         self._lock = threading.Lock()
         self._programs = {}      # label -> [stash, ...] (insertion order)
         self._sig_index = {}     # label -> {sig: stash}
@@ -220,11 +236,10 @@ class ProgramRegistry(object):
         self._active_parts = {}  # label -> per-arg parts (fast path)
         self._sig_memo = {}      # label -> [(arg, parts) | None, ...]
         self._pending = {}       # label -> [calls, tokens] pre-stash
-        self._step_s = {}        # label -> EWMA sampled step seconds
+        self._step_s = {}        # label -> EWMA observed step seconds
         self._decomp = {}        # label -> [n, host_sum, wait_sum]
         self._gauged = set()     # labels with published gauges
         self._analysis = {}      # (id(jitted), sig) -> analysis dict
-        self._tick = 0
         # Program-identity changes flagged by a call site (the engine
         # passes track_change=detector.warm, so pre-warmup bucket
         # accumulation never lands here; an already-seen signature
@@ -357,25 +372,12 @@ class ProgramRegistry(object):
         p[0] += 1
         p[1] += tokens
 
-    def due(self):
-        """Deterministic 1-in-N sampler for the step decomposition.
-        Call once per step; True on every Nth tick (never the first —
-        the first dispatch includes the compile)."""
-        if self._sample_every <= 0:
-            return False
-        self._tick += 1
-        return self._tick % self._sample_every == 0
-
-    def sample_step(self, label, outputs, dispatch_s):
-        """SANCTIONED SYNC (analysis/annotations.py): bracket one
-        sampled step with ``block_until_ready`` to split host-schedule
-        from device-compute time. The measured total feeds the per-
-        program EWMA the roofline gauges divide by."""
-        import jax
-
-        t0 = time.perf_counter()
-        jax.block_until_ready(outputs)
-        wait_s = time.perf_counter() - t0
+    def observe_step(self, label, dispatch_s, wait_s):
+        """One step's split, from the caller's own clock readings around
+        work it does anyway: ``dispatch_s`` the jitted call (host
+        schedule), ``wait_s`` the harvest that blocks on its outputs
+        (device wait). Costs no sync. The total feeds the per-program
+        EWMA the roofline gauges divide by."""
         step_s = dispatch_s + wait_s
         prev = self._step_s.get(label)
         self._step_s[label] = (step_s if prev is None
@@ -435,6 +437,7 @@ class ProgramRegistry(object):
                 lowered.as_text().encode()).hexdigest()[:16]
             compiled = lowered.compile()
             text = compiled.as_text()
+            _record_op_names(text)
             # What the compiler actually put in the program: Pallas
             # kernels (a kernel that ran in interpret mode, or gave way
             # to a jnp reference, leaves no custom call) and the

@@ -2,10 +2,15 @@
 
 TPU-native counterpart of reference utils/timer.py: the reference's
 ``SynchronizedWallClockTimer`` brackets intervals with ``cuda.synchronize``
-(utils/timer.py:26-80); here device synchronization is
-``jax.effects_barrier()`` — but since most of our hot path is a single
-jitted function, the barrier is cheap and the timers are plain host
-wall-clock around it.
+(utils/timer.py:26-80). JAX has no such call: a jitted function returns
+when its work is ENQUEUED, and ``jax.effects_barrier()`` waits only for
+programs with side effects (callbacks, ``io_callback``), which a train step
+is not. So an interval here is host wall-clock, and it times the device
+only where it is told what to wait for: ``stop(wait_for=arrays)`` blocks
+on those arrays (the opt-in ``wall_clock_breakdown`` path of the training
+engine does), and an interval stopped without them times the dispatch.
+The profiler's trace (telemetry/tracing.py spans beside the device's
+operations) is where a step's device time is read.
 """
 
 import time
@@ -13,19 +18,28 @@ import time
 from deepspeed_tpu.utils.logging import logger
 
 
-def _device_synchronize():
+def _device_synchronize(wait_for=None):
+    """Block until ``wait_for`` (any pytree of arrays) is computed, and
+    until every EFFECTFUL program dispatched so far has run
+    (``jax.effects_barrier``: host callbacks, the offload gradient stream).
+    It does NOT drain pure programs that nobody waits for: without
+    ``wait_for`` a caller times the enqueue of those."""
     try:
         import jax
 
-        jax.effects_barrier()  # drain all dispatched device work
-    except Exception:
+        if wait_for is not None:
+            jax.block_until_ready(wait_for)
+        jax.effects_barrier()
+    except ImportError:
         pass
 
 
 class _Interval:
-    """One named accumulating interval. start()/stop() bracket device
-    work (synchronized on both edges); elapsed() reads the accumulated
-    seconds without disturbing a running interval.
+    """One named accumulating interval of host wall-clock. ``stop(
+    wait_for=arrays)`` first blocks on the arrays the bracketed work
+    produced, so the interval covers the device's part; stopped without
+    them it times the dispatch (see the module docstring). elapsed() reads
+    the accumulated seconds without disturbing a running interval.
 
     ``histogram`` (optional) is a telemetry sink with an ``observe(v)``
     method — every completed start/stop interval is observed into it, so
@@ -45,10 +59,10 @@ class _Interval:
         _device_synchronize()
         self._t0 = time.time()
 
-    def stop(self, reset=False):
+    def stop(self, reset=False, wait_for=None):
         if self._t0 is None:
             raise RuntimeError("timer {!r} not started".format(self.name))
-        _device_synchronize()
+        _device_synchronize(wait_for)
         dt = time.time() - self._t0
         self._acc = dt if reset else self._acc + dt
         self._t0 = None
@@ -131,7 +145,14 @@ class SynchronizedWallClockTimer:
 class ThroughputTimer:
     """Samples/sec every ``steps_per_output`` steps (reference
     timer.py:86-183). The first ``start_step`` steps are warmup
-    (compile + cache churn) and are excluded from the average."""
+    (compile + cache churn) and are excluded from the average.
+
+    It syncs nothing (it is on in every ``train_batch``): a step's time is
+    the LOOP's period, from the first timed ``start()`` to the latest
+    ``stop()``, over the timed steps. The host may run ahead of the device
+    by the few steps its queue holds; over a run that constant washes out,
+    and a loop that reads its loss has none. Timing each start-to-stop
+    bracket instead would time the enqueue."""
 
     def __init__(self, batch_size, num_workers, start_step=2,
                  steps_per_output=50, monitor_memory=False,
@@ -151,27 +172,26 @@ class ThroughputTimer:
         self.local_step_count = 0
         self.total_step_count = 0
         self.total_elapsed_time = 0.0
-        self._running_since = None
+        self._timed_since = None   # start() of the first timed step
+        self._running = False
 
     def update_epoch_count(self):
         self.epoch_count += 1
         self.local_step_count = 0
 
     def start(self):
-        if self.total_step_count >= self.start_step:
-            _device_synchronize()
-            self._running_since = time.time()
-        else:
-            self._running_since = 0.0  # warmup step: counted, not timed
+        self._running = True
+        if self._timed_since is None and \
+                self.total_step_count >= self.start_step:
+            self._timed_since = time.time()  # warmup steps are not timed
 
     def stop(self, report_speed=True):
-        if self._running_since is None:
+        if not self._running:
             return
-        timed = self._running_since > 0.0
+        self._running = False
+        timed = self._timed_since is not None
         if timed:
-            _device_synchronize()
-            self.total_elapsed_time += time.time() - self._running_since
-        self._running_since = None
+            self.total_elapsed_time = time.time() - self._timed_since
         self.total_step_count += 1
         self.local_step_count += 1
         if (timed and report_speed

@@ -12,6 +12,7 @@ a chip run; ``chip_smoke.py`` is.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -162,6 +163,44 @@ CASES = {
 }
 
 
+KERNEL_NAME = re.compile(
+    r"^(flash_fwd|flash_bwd_fused|flash_bwd_dq|flash_bwd_dkv|decode_attn|"
+    r"decode_attn_q8|paged_decode|paged_decode_q8|prefill_attn|"
+    r"sparse_attn_fwd|sparse_attn_bwd_fused|sparse_attn_bwd_dq|"
+    r"sparse_attn_bwd_dkv|dropout_fwd|dropout_mask|bias_gelu|"
+    r"layer_norm_fwd|attn_softmax)(\.\d+)?$")
+
+
+def _kernel_calls(text):
+    return re.findall(
+        r'%([\w.-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+
+
+def test_kernel_names_survive_scan_cond_and_the_lanes_own_name(
+        chip, monkeypatch):
+    """The decode kernel inside a scan and the prefill lane's call of the
+    same body inside a cond keep their own names (a trace of the parent
+    printed ``closed_call`` and ``branch_1_fun`` there)."""
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+
+    def step(q, k, v, tbl, pos):
+        def lane(q):
+            return da.flash_decode_attention_paged(q, k, v, tbl, pos,
+                                                   name="prefill_attn")
+
+        def body(q, _):
+            return da.flash_decode_attention_paged(q, k, v, tbl, pos), None
+
+        q = jax.lax.cond(pos[0] > 0, lane, lambda q: q, q)
+        return jax.lax.scan(body, q, None, length=2)[0]
+
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in _paged_args(128, 1)]
+    calls = _kernel_calls(jax.jit(step).lower(*args).compile().as_text())
+    assert sorted(c.split(".")[0] for c in calls) == \
+        ["paged_decode", "prefill_attn"]
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(name, chip, monkeypatch):
     fn, shapes, env = CASES[name]
@@ -172,3 +211,8 @@ def test_kernel_compiles_for_v5e(name, chip, monkeypatch):
             for shape, dtype in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    # Every kernel's custom call is named after the kernel (what a trace
+    # of the chip prints), never after where it sits.
+    assert _kernel_calls(text) and all(
+        KERNEL_NAME.match(c) for c in _kernel_calls(text)), \
+        _kernel_calls(text)

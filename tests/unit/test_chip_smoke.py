@@ -195,7 +195,9 @@ def test_arrange_reraises(monkeypatch):
 
 
 def test_interpret_mode_is_decided_in_one_place():
-    """Every kernel file launches through ops.pallas_mode.interpret()."""
+    """Every kernel file launches through ops.pallas_mode: kernel_call
+    names the kernel and decides interpret mode, and no file passes an
+    ``interpret=`` of its own."""
     from deepspeed_tpu.ops import pallas_mode
 
     assert pallas_mode.interpret() is True   # the CPU test mesh
@@ -207,7 +209,9 @@ def test_interpret_mode_is_decided_in_one_place():
     for path in kernels:
         with open(path) as f:
             text = f.read()
-        assert "pallas_mode.interpret()" in text, path
+        assert "pallas_mode.kernel_call(" in text, path
+        assert "pl.pallas_call(" not in text, path
+        assert "interpret=" not in text, path
         assert "def _interpret" not in text, path
 
 

@@ -466,7 +466,7 @@ def test_engine_spans_cover_request_lifecycle(tmp_path):
     eng.run()
     counts = eng.tracer.span_counts()
     for name in ("request/queued", "request/prefill", "request/decode",
-                 "request", "step/mixed", "step/harvest"):
+                 "request", "inference/mixed_step", "inference/harvest"):
         assert counts.get(name, 0) >= 1, name
     path = eng.write_trace(str(tmp_path / "t.json"))
     doc = json.loads(open(path).read())

@@ -78,7 +78,7 @@ def test_telemetry_adds_no_recompiles_and_bounded_host_overhead():
     # The on-engine actually recorded: the comparison was not no-op
     # against no-op.
     counts = on.tracer.span_counts()
-    assert counts.get("step/mixed", 0) > 0
+    assert counts.get("inference/mixed_step", 0) > 0
     assert off.tracer.span_counts() == {}
 
 
@@ -211,10 +211,10 @@ def test_perf_xray_holds_the_overhead_gate():
 
     # The observatory genuinely observed the hot path...
     assert on.telemetry_snapshot()["xray_programs"] >= 1
-    # ...and a full export (AOT lower+compile of the whole program
-    # family) perturbs nothing the dispatch caches or detector see.
+    # ...and a full export (AOT lower+compile of what was dispatched)
+    # perturbs nothing the dispatch caches or detector see.
     out = on.perf_xray()
-    assert len([p for p in out["programs"] if not p["superseded"]]) >= 3
+    assert len([p for p in out["programs"] if not p["superseded"]]) >= 1
     assert on.compile_count == 1
     assert on.metrics()["recompiles"] == 0
     assert out["recompiles"] == []

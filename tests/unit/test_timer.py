@@ -142,3 +142,47 @@ def test_throughput_timer_warmup_and_average(monkeypatch):
     # 8 samples per 0.5 s step.
     assert tt.avg_samples_per_sec() == pytest.approx(16.0)
     assert reg.gauge("samples_per_sec").value == pytest.approx(16.0)
+
+
+def test_throughput_timer_syncs_nothing_and_times_the_loop(monkeypatch):
+    """It is on in every train_batch, so it may not wait for the device;
+    a step's time is the loop's period, the gap between steps included
+    (timing each start-to-stop bracket would time the enqueue)."""
+    import deepspeed_tpu.utils.timer as timer_mod
+
+    def no_sync(*_a, **_k):
+        raise AssertionError("ThroughputTimer must not synchronize")
+    monkeypatch.setattr(timer_mod, "_device_synchronize", no_sync)
+    clock = [10.0]
+    monkeypatch.setattr("deepspeed_tpu.utils.timer.time",
+                        type("T", (), {"time": staticmethod(
+                            lambda: clock[0])}))
+    tt = ThroughputTimer(batch_size=16, num_workers=1, start_step=1,
+                         steps_per_output=100)
+    tt.start()
+    tt.stop()  # warmup
+    for _ in range(4):
+        tt.start()
+        clock[0] += 0.01   # the dispatch returns at once...
+        tt.stop()
+        clock[0] += 0.39   # ...and the loop waits for its loss
+    # 4 steps from the first timed start to the last stop: 3 x 0.4 + 0.01
+    assert tt.total_elapsed_time == pytest.approx(1.21)
+    assert tt.avg_samples_per_sec() == pytest.approx(16 * 4 / 1.21)
+    tt.stop()  # a stop without a start changes nothing
+    assert tt.total_step_count == 5
+
+
+def test_interval_waits_for_the_arrays_it_is_given(monkeypatch):
+    import jax
+
+    waited = []
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: waited.append(x) or x)
+    t = _Interval("t")
+    t.start()
+    t.stop()
+    assert waited == []  # stopped without arrays: it times the dispatch
+    t.start()
+    t.stop(wait_for=("loss", "grads"))
+    assert waited == [("loss", "grads")]

@@ -13,7 +13,7 @@ Covers the ISSUE's acceptance surface on the CPU tier-1 path:
   is unknown — the default alert rule can then never fire),
 - cost_model_gate: A/A clean, 2x bytes flagged, improvement recorded,
   platform/schema mismatch caveats,
-- the serving-engine integration: a perf_xray() export covers >= 3
+- the serving-engine integration: a perf_xray() export covers the
   programs with nonzero flops and predicted peak HBM, adds NO compiles
   to the jit dispatch caches and NO recompile events, and the
   RecompileDetector warning + autopsy share the xray identity key.
@@ -201,24 +201,23 @@ def test_xray_gauges_at_parser_level_no_fabricated_mfu():
         assert fabricated not in kinds
 
 
-def test_xray_roofline_gauges_with_peaks_and_sampled_step():
+def test_xray_roofline_gauges_with_peaks_and_observed_step():
     fn, x, y = _toy()
     reg = MetricsRegistry()
     peaks = {"flops_per_s": 1e9, "hbm_bytes_per_s": 1e9, "source": "test"}
-    xr = ProgramRegistry(reg, platform="tpu", peaks=peaks, sample_every=1)
+    xr = ProgramRegistry(reg, platform="tpu", peaks=peaks)
     xr.observe("mixed_step", fn, x, y, tokens=4)
     _, before = _parse_prom(prometheus_text(reg))
     lbl = (("platform", "tpu"), ("program", "mixed_step"))
-    # Gauges exist but read 0 until a step has actually been SAMPLED —
+    # Gauges exist but read 0 until a step has actually been OBSERVED —
     # utilization against an unmeasured step time would be fabricated.
     assert before[("ds_tpu_xray_mfu", lbl)] == 0.0
-    out = fn(x, y)
-    xr.sample_step("mixed_step", out, dispatch_s=0.001)
+    xr.observe_step("mixed_step", dispatch_s=0.001, wait_s=0.004)
     kinds, samples = _parse_prom(prometheus_text(reg))
     assert samples[("ds_tpu_xray_mfu", lbl)] > 0
     assert samples[("ds_tpu_xray_mbu", lbl)] > 0
     assert samples[("ds_tpu_xray_roofline_ratio", lbl)] > 0
-    # The decomposition histograms recorded the sampled bracket.
+    # The decomposition histograms recorded the two span durations.
     assert kinds["ds_tpu_xray_host_dispatch_seconds"] == "summary"
     assert samples[("ds_tpu_xray_device_wait_seconds_count",
                     (("program", "mixed_step"),))] == 1
@@ -254,27 +253,34 @@ def test_xray_series_carry_replica_labels_through_merge():
 # -------------------------------------------------------- decomposition
 
 
-def test_due_sampling_cadence_skips_first_and_disables_at_zero():
-    xr = ProgramRegistry(sample_every=3)
-    assert [xr.due() for _ in range(7)] == [False, False, True,
-                                            False, False, True, False]
-    off = ProgramRegistry(sample_every=0)
-    assert not any(off.due() for _ in range(5))
+def test_observe_step_counts_every_step_and_syncs_nothing(monkeypatch):
+    """The split is fed on every step from durations the caller already
+    has: no sampling cadence, and no device sync of its own."""
+    def no_sync(*_a, **_k):
+        raise AssertionError("observe_step must not sync the device")
+    monkeypatch.setattr(jax, "block_until_ready", no_sync)
+    xr = ProgramRegistry()
+    for i in range(7):
+        assert xr.observe_step("p", 0.001, 0.002 + i) == \
+            pytest.approx(0.003 + i)
+    assert xr.to_json()["decomposition"]["p"]["samples"] == 7
+    assert not hasattr(xr, "due") and not hasattr(xr, "sample_step")
 
 
 def test_decomposition_lands_in_export():
     fn, x, y = _toy()
-    xr = ProgramRegistry(sample_every=1)
+    xr = ProgramRegistry()
     xr.observe("p", fn, x, y, tokens=2)
-    xr.sample_step("p", fn(x, y), dispatch_s=0.002)
-    xr.sample_step("p", fn(x, y), dispatch_s=0.001)
+    xr.observe_step("p", dispatch_s=0.002, wait_s=0.010)
+    xr.observe_step("p", dispatch_s=0.001, wait_s=0.020)
     section = xr.to_json()
     d = section["decomposition"]["p"]
     assert d["samples"] == 2
     assert d["host_dispatch_s"] == pytest.approx(0.003)
-    assert d["device_wait_s"] >= 0
+    assert d["device_wait_s"] == pytest.approx(0.030)
     (entry,) = [e for e in section["programs"] if not e["superseded"]]
-    assert entry["sampled_step_seconds"] > 0
+    # EWMA of the observed totals: 0.8 * 0.012 + 0.2 * 0.021
+    assert entry["sampled_step_seconds"] == pytest.approx(0.0138)
 
 
 # --------------------------------------------------------------- ledger
@@ -422,14 +428,30 @@ def _serve_engine():
     return eng
 
 
-def test_engine_perf_xray_covers_program_family_without_recompiles():
+class _Compilations(object):
+    """Counts the compilations asked for (``Lowered.compile``: the AOT
+    path the observatory takes; a jit dispatch does not pass here)."""
+
+    def __init__(self, monkeypatch):
+        self.n = 0
+        real = jax.stages.Lowered.compile
+
+        def counted(lowered, *args, **kwargs):
+            self.n += 1
+            return real(lowered, *args, **kwargs)
+        monkeypatch.setattr(jax.stages.Lowered, "compile", counted)
+
+
+def test_engine_perf_xray_analyses_only_dispatched_programs(monkeypatch):
     eng = _serve_engine()
     compiles_before = eng.compile_count
+    log = _Compilations(monkeypatch)
     out = eng.perf_xray()
     active = [p for p in out["programs"] if not p["superseded"]]
-    assert len(active) >= 3
-    labels = {p["program"] for p in active}
-    assert {"mixed_step", "prefill", "decode_chunk"} <= labels
+    # Chunked mode dispatches one program, and the export compiles that
+    # one and nothing it never ran (no legacy prefill / decode_chunk).
+    assert {p["program"] for p in active} == {"mixed_step"}
+    assert log.n == 1
     for p in active:
         assert p["flops"] > 0, p
         assert p["peak_hbm_bytes"] > 0, p
@@ -454,6 +476,7 @@ def test_engine_perf_xray_covers_program_family_without_recompiles():
     assert out["recompiles"] == []
     assert eng.metrics()["recompiles"] == 0
     again = eng.perf_xray()
+    assert log.n == 1  # the analysis is cached: a second export is free
     assert [p["fingerprint"] for p in again["programs"]] == \
         [p["fingerprint"] for p in out["programs"]]
     # Prometheus surface: cost gauges exist, utilization gauges do not.
@@ -461,7 +484,30 @@ def test_engine_perf_xray_covers_program_family_without_recompiles():
     assert "ds_tpu_xray_flops" in kinds
     assert "ds_tpu_hbm_predicted_bytes" in kinds
     assert "ds_tpu_xray_mfu" not in kinds
-    assert eng.telemetry_snapshot()["xray_programs"] >= 3
+    assert eng.telemetry_snapshot()["xray_programs"] == 1
+    # The export also keeps each analysed program's instruction -> op_name
+    # (the named_scope path), for a trace that does not embed the program.
+    from deepspeed_tpu.telemetry import xray
+
+    op_names = xray.OP_NAMES["jit_mixed_step"].values()
+    assert any("/decode_scan/" in n and "/kv_write/" in n for n in op_names)
+    assert any("/prefill_lane/" in n for n in op_names)
+
+
+def test_legacy_engine_perf_xray_covers_its_two_programs():
+    from tests.unit.test_chunked_prefill import (
+        engine_of,
+        make_model,
+        prompts_of,
+    )
+
+    cfg, model, params = make_model()
+    eng = engine_of(model, params, chunked_prefill=False)
+    eng.generate([prompts_of(cfg, [5])[0]], max_new_tokens=3)
+    out = eng.perf_xray()
+    assert {p["program"] for p in out["programs"]
+            if not p["superseded"]} == {"prefill", "decode_chunk"}
+    assert out["decomposition"]["decode_chunk"]["samples"] >= 1
 
 
 def test_engine_perf_xray_off_is_none():
