@@ -55,7 +55,7 @@ HOT_PATH_FUNCTIONS = {
         "decode_attention_q8_reference",
         "flash_decode_attention_paged", "flash_decode_attention_paged_q8",
         "decode_attention_paged_reference",
-        "decode_attention_paged_q8_reference",
+        "decode_attention_paged_q8_reference", "kv_append",
     }),
 }
 

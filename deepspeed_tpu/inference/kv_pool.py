@@ -76,6 +76,19 @@ Layout invariants the flash-decode kernel
   advances by S); host code never writes ``pos`` directly, which is what
   makes ``max_active_frontier`` a safe work-bound hint between chunks.
 
+WHERE A PAGED POOL IS WRITTEN. The paged layout (``init_pool(page_len=)``)
+keeps ONE arena ``[layers, pages, heads, page_len, head_dim]`` per k and
+v. When ``page_len`` is a kernel block (a multiple of 128: what the chip
+serves), no program ever forms a per-layer value of it: the frontier
+rows are appended in place by the ``kv_append`` kernel and attention
+reads pages through the decode kernel's own index map, both addressing
+``arena[layer, block_tbl[slot, pos // page_len], head, pos % page_len]``
+(ops/transformer/kernels/decode_attention.py). The views below pass the
+arenas through untouched, so the donated buffer the step received is the
+buffer it returns. An append rewrites the frontier page of each row,
+which is sound because a live frontier page belongs to one row; frozen
+rows all point at the trash page 0, which nothing reads.
+
 CRASH-ONLY: the pool is DISPOSABLE state (docs/RESILIENCE.md). The
 durable truth about every request lives host-side in the scheduler's
 records; on a fatal step error the engine throws the pool away and
@@ -265,8 +278,11 @@ def cache_view(pool):
     pbase of 0 selects none of it.
 
     PAGED pools pass the arenas WHOLE (no slot axis to slice — _forward
-    scatters and gathers through ``block_tbl``); the table and the
-    frontiers ride along as traced values."""
+    writes and reads through ``block_tbl``); the table and the frontiers
+    ride along as traced values. Where a page is a kernel block the
+    arenas STAY whole all the way down: ``kv_append`` writes the frontier
+    rows in place and the decode kernel indexes the layer itself, so the
+    buffer this view names is the buffer ``fold_cache`` gets back."""
     cache = {"k": pool["k"], "v": pool["v"], "pos": pool["pos"]}
     if "block_tbl" in pool:
         cache["block_tbl"] = pool["block_tbl"]
@@ -346,11 +362,12 @@ def write_slot_cache(pool, slot, cache):
     planes are read-only to aliasers and ``pos`` install stays with the
     caller (the lane's conditional slot-field writes).
 
-    PAGED pools fold the arenas back WHOLESALE: _forward scattered the
-    slot's writes through the block table into the arena copy it was
-    handed, so the updated arena IS the pool's new truth. The table
-    itself never folds back — it is host-owned (inference/paging.py)
-    and the device only reads it."""
+    PAGED pools fold the arenas back WHOLESALE: _forward wrote the
+    slot's rows through the block table into the arena it was handed
+    (in place, by ``kv_append``, where a page is a kernel block; through
+    an XLA scatter otherwise), so the updated arena IS the pool's new
+    truth. The table itself never folds back — it is host-owned
+    (inference/paging.py) and the device only reads it."""
     if "block_tbl" in pool:
         pool = dict(pool)
         for name in ("k", "v", "k_scale", "v_scale"):

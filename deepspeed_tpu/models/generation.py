@@ -145,6 +145,17 @@ def _forward(params, cfg, ids, cache, last_only=False, attn_name=None):
     write into the planes), ``kv_view`` (the planes attention reads: the
     page gather, the prefix select) and ``mlp``, then ``lm_head``.
 
+    WHERE THE FRONTIER WRITE HAPPENS. A paged pool whose page is a kernel
+    block (``"block_tbl" in cache`` and ``decode_supported(page_len)``
+    under ``use_flash_decode``: what the chip serves) is written IN PLACE
+    by the ``kv_append`` kernel and read by the paged decode kernel with
+    the layer in its index map; the arenas pass through this function
+    whole, and no per-layer value of them is formed (``kv_write`` holds
+    the kernel, ``kv_view`` nothing). Every other cache — a paged pool
+    with smaller pages, the dense slot pool, ``generate()``'s own cache —
+    takes ``cache.at[i].set(write(cache[i], new))`` and, on the chip,
+    pays XLA's slice, scatter and update of a whole layer for it.
+
     KV-hierarchy dispatch is DATA-DRIVEN off the cache dict
     (inference/kv_hierarchy): an int8 ``k`` plane means frontier writes
     quantize (codes + per-(head, position) ``k_scale``/``v_scale``) and
@@ -165,8 +176,9 @@ def _forward(params, cfg, ids, cache, last_only=False, attn_name=None):
     # PAGED dispatch (inference/kv_pool.py paged layout): a block table
     # means k/v are a page ARENA [L, P, H, page_len, D] and row b's
     # logical plane is the concatenation of its table's pages. Writes
-    # scatter through the table; reads gather through it (or hand the
-    # table to the paged flash kernel). The gathered logical plane is
+    # go through the table (an XLA scatter, or in place by kv_append);
+    # reads gather through it (or hand the table and the whole arena to
+    # the paged flash kernel). The gathered logical plane is
     # elementwise equal to what the dense pool holds at every valid
     # position — trash/unwritten pages are finite garbage the causal
     # mask zeroes exactly — so streams stay bit-identical to dense.
@@ -293,31 +305,43 @@ def _forward(params, cfg, ids, cache, last_only=False, attn_name=None):
             v = v.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
         with jax.named_scope("kv_write"):
             if int8:
-                kq, ks = decode_attention.quantize_kv(k)
-                vq, vs = decode_attention.quantize_kv(v)
-                k_cache = k_cache.at[i].set(write_rows(k_cache[i], kq))
-                v_cache = v_cache.at[i].set(write_rows(v_cache[i], vq))
-                ks_cache = ks_cache.at[i].set(
-                    write_scale_rows(ks_cache[i], ks))
-                vs_cache = vs_cache.at[i].set(
-                    write_scale_rows(vs_cache[i], vs))
+                k, ks = decode_attention.quantize_kv(k)
+                v, vs = decode_attention.quantize_kv(v)
+            if paged and use_flash:
+                # In place: the arenas go through ``kv_append`` WHOLE and
+                # come back the same buffers with the frontier pages
+                # rewritten. No per-layer value of an arena exists in this
+                # branch, on the write side or (below) the read side.
+                if int8:
+                    k_cache, v_cache, ks_cache, vs_cache = \
+                        decode_attention.kv_append(
+                            (k_cache, v_cache, ks_cache, vs_cache),
+                            (k, v, ks, vs), tbl, pos, layer=i)
+                else:
+                    k_cache, v_cache = decode_attention.kv_append(
+                        (k_cache, v_cache), (k, v), tbl, pos, layer=i)
             else:
                 k_cache = k_cache.at[i].set(write_rows(k_cache[i], k))
                 v_cache = v_cache.at[i].set(write_rows(v_cache[i], v))
+                if int8:
+                    ks_cache = ks_cache.at[i].set(
+                        write_scale_rows(ks_cache[i], ks))
+                    vs_cache = vs_cache.at[i].set(
+                        write_scale_rows(vs_cache[i], vs))
         with jax.named_scope("kv_view"):
             # Effective planes: the row's own just-written plane, with the
             # aliased prefix selected in below pbase[b] (codes AND scales —
             # both tiers compose). Paged rows GATHER their logical plane
             # through the block table AFTER the write (the einsum/reference
-            # path; the paged flash kernel gathers in its own index map and
-            # skips this materialization).
+            # path; the paged flash kernel gathers in its own index map,
+            # layer included, and forms no view at all).
             if paged and not use_flash:
                 k_eff = gather_pages(k_cache[i])
                 v_eff = gather_pages(v_cache[i])
                 if int8:
                     ks_eff = gather_pages(ks_cache[i])
                     vs_eff = gather_pages(vs_cache[i])
-            else:
+            elif not paged:
                 k_eff, v_eff = k_cache[i], v_cache[i]
                 if int8:
                     ks_eff, vs_eff = ks_cache[i], vs_cache[i]
@@ -341,14 +365,18 @@ def _forward(params, cfg, ids, cache, last_only=False, attn_name=None):
                     # index map resolves (row, block j) -> arena page, so
                     # pages stream into VMEM straight from the table with
                     # the same straddle-only masking as the dense kernel.
+                    # It takes the arenas whole and the layer as part of
+                    # the page's address.
                     if int8:
                         y = decode_attention.flash_decode_attention_paged_q8(
-                            q, k_eff, v_eff, ks_eff, vs_eff, tbl, pos,
-                            scale=1.0 / float(hd) ** 0.5, name=attn_name)
+                            q, k_cache, v_cache, ks_cache, vs_cache, tbl,
+                            pos, scale=1.0 / float(hd) ** 0.5,
+                            name=attn_name, layer=i)
                     else:
                         y = decode_attention.flash_decode_attention_paged(
-                            q, k_eff, v_eff, tbl, pos,
-                            scale=1.0 / float(hd) ** 0.5, name=attn_name)
+                            q, k_cache, v_cache, tbl, pos,
+                            scale=1.0 / float(hd) ** 0.5, name=attn_name,
+                            layer=i)
                 elif int8:
                     y = decode_attention.flash_decode_attention_q8(
                         q, k_eff, v_eff, ks_eff, vs_eff, pos,

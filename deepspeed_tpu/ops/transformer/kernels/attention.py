@@ -935,8 +935,9 @@ def on_shards(fn, shard, in_dims, out_dims):
     """``fn`` launched shard-local per ``shard`` (a ``kernel_sharding``
     result; None returns ``fn`` itself). ``in_dims`` / ``out_dims`` name,
     per operand and per result, what its leading dims are: ``"bh"`` for
-    [B, H, ...], ``"b"`` for [B, ...], ``"-h"`` for [pages, H, ...]; the
-    remaining dims stay whole."""
+    [B, H, ...], ``"b"`` for [B, ...], ``"-h"`` for [pages, H, ...] (any
+    whole leading dim: a batch no shard may split), ``"--h"`` for a whole
+    paged arena [layers, pages, H, ...]; the remaining dims stay whole."""
     if shard is None:
         return fn
     mesh, b, h = shard

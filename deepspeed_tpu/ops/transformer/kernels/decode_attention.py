@@ -673,12 +673,35 @@ def decode_attention_paged_q8_reference(q, k, v, k_scale, v_scale,
     return decode_attention_reference(q, kf, vf, pos, scale=scale)
 
 
+def _div(a, b):
+    """``a // b`` and ``a % b`` for a frontier (never negative) and a static
+    size, as ONE machine operation each: ``//`` and ``%`` round towards
+    minus infinity through a sign correction that Pallas traces and lowers
+    anew at every use, in every index map of every call: 2.5 ms apiece,
+    seconds of set-up over a step's 48 appends."""
+    return jax.lax.div(a, jnp.int32(b))
+
+
+def _rem(a, b):
+    return jax.lax.rem(a, jnp.int32(b))
+
+
+def _whole_arena(layer, *arenas):
+    """(layer, arenas) as the launchers index them: ``[L, P, H, ...]`` and
+    a static layer. ``layer`` None means the caller holds ONE layer's
+    ``[P, H, ...]`` arena; a leading unit dim is a bitcast, not a copy."""
+    if layer is None:
+        return 0, tuple(a[None] for a in arenas)
+    return int(layer), arenas
+
+
 def _flash_decode_paged_pallas(q, k, v, tbl, pos, scale,
-                               name=None):
+                               name=None, layer=None):
     from jax.experimental.pallas import tpu as pltpu
 
+    layer, (k, v) = _whole_arena(layer, k, v)
     b, h, s, d = q.shape
-    page_len = k.shape[2]
+    page_len = k.shape[3]
     n_lp = tbl.shape[1]
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     pos = pos.astype(jnp.int32)
@@ -692,8 +715,11 @@ def _flash_decode_paged_pallas(q, k, v, tbl, pos, scale,
         # Logical block j of row b_ lives in arena page tbl[b_, j];
         # past-frontier blocks clamp to the last useful LOGICAL block
         # first, so the resolved PAGE repeats and issues no new DMA.
-        last = (pos_ref[b_] + s - 1) // page_len
-        return (tbl_ref[b_, jnp.minimum(j, last)], h_, 0, 0)
+        # The LAYER is part of the index too: the arena is never sliced
+        # into a per-layer value outside the kernel (a 38 MB copy a call
+        # at 355M), the squeezed leading block dim picks it in the DMA.
+        last = _div(pos_ref[b_] + (s - 1), page_len)
+        return (layer, tbl_ref[b_, jnp.minimum(j, last)], h_, 0, 0)
 
     def q_index(b_, h_, j, pos_ref, tbl_ref):
         return (b_, h_, 0, 0)
@@ -703,8 +729,8 @@ def _flash_decode_paged_pallas(q, k, v, tbl, pos, scale,
         grid=(b, h, n_lp),
         in_specs=[
             pl.BlockSpec((1, 1, s_blk, d), q_index),
-            pl.BlockSpec((1, 1, page_len, d), kv_index),
-            pl.BlockSpec((1, 1, page_len, d), kv_index),
+            pl.BlockSpec((None, 1, 1, page_len, d), kv_index),
+            pl.BlockSpec((None, 1, 1, page_len, d), kv_index),
         ],
         out_specs=pl.BlockSpec((1, 1, s_blk, d), q_index),
         scratch_shapes=[] if n_lp == 1 else [
@@ -724,11 +750,18 @@ def _flash_decode_paged_pallas(q, k, v, tbl, pos, scale,
 
 
 def _flash_decode_paged_q8_pallas(q, k, v, k_scale, v_scale, tbl, pos,
-                                  scale, name=None):
+                                  scale, name=None, layer=None):
     from jax.experimental.pallas import tpu as pltpu
 
+    if layer is not None:
+        # The scale arenas ARE sliced to the layer: the kernel body wants
+        # a scale per key as a [page_len, 1] column, and a trailing unit
+        # dim on the whole [L, P, H, page_len] arena would pad every
+        # scale to a lane tile. The code arenas stay whole.
+        k_scale, v_scale = k_scale[layer], v_scale[layer]
+    layer, (k, v) = _whole_arena(layer, k, v)
     b, h, s, d = q.shape
-    page_len = k.shape[2]
+    page_len = k.shape[3]
     n_lp = tbl.shape[1]
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     pos = pos.astype(jnp.int32)
@@ -740,9 +773,15 @@ def _flash_decode_paged_q8_pallas(q, k, v, k_scale, v_scale, tbl, pos,
     if s_blk != s:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, s_blk - s), (0, 0)))
 
+    def page(b_, j, pos_ref, tbl_ref):
+        last = _div(pos_ref[b_] + (s - 1), page_len)
+        return tbl_ref[b_, jnp.minimum(j, last)]
+
     def kv_index(b_, h_, j, pos_ref, tbl_ref):
-        last = (pos_ref[b_] + s - 1) // page_len
-        return (tbl_ref[b_, jnp.minimum(j, last)], h_, 0, 0)
+        return (layer, page(b_, j, pos_ref, tbl_ref), h_, 0, 0)
+
+    def scale_index(b_, h_, j, pos_ref, tbl_ref):
+        return (page(b_, j, pos_ref, tbl_ref), h_, 0, 0)
 
     def q_index(b_, h_, j, pos_ref, tbl_ref):
         return (b_, h_, 0, 0)
@@ -752,10 +791,10 @@ def _flash_decode_paged_q8_pallas(q, k, v, k_scale, v_scale, tbl, pos,
         grid=(b, h, n_lp),
         in_specs=[
             pl.BlockSpec((1, 1, s_blk, d), q_index),
-            pl.BlockSpec((1, 1, page_len, d), kv_index),
-            pl.BlockSpec((1, 1, page_len, d), kv_index),
-            pl.BlockSpec((1, 1, page_len, 1), kv_index),
-            pl.BlockSpec((1, 1, page_len, 1), kv_index),
+            pl.BlockSpec((None, 1, 1, page_len, d), kv_index),
+            pl.BlockSpec((None, 1, 1, page_len, d), kv_index),
+            pl.BlockSpec((1, 1, page_len, 1), scale_index),
+            pl.BlockSpec((1, 1, page_len, 1), scale_index),
         ],
         out_specs=pl.BlockSpec((1, 1, s_blk, d), q_index),
         scratch_shapes=[] if n_lp == 1 else [
@@ -776,19 +815,24 @@ def _flash_decode_paged_q8_pallas(q, k, v, k_scale, v_scale, tbl, pos,
 
 @hot_path
 def flash_decode_attention_paged(q, k, v, block_tbl, pos, scale=None,
-                                 name=None):
+                                 name=None, layer=None):
     """Block-table flash decode over a page arena.
 
     Args:
       q: [B, H, S, D] query rows at per-row frontiers ``pos``; each
-        row's k/v for those positions must already be SCATTERED into
-        its pages (models/generation.py writes before attending).
-      k, v: [P, H, page_len, D] page arenas (one layer's view of the
-        paged pool; page 0 is the trash page freed rows point at).
+        row's k/v for those positions must already be written into its
+        pages (models/generation.py writes before attending).
+      k, v: the paged pool's arenas WHOLE, [L, P, H, page_len, D], with
+        ``layer`` naming the layer to attend — the serving path: the
+        kernel's index map picks the layer, so no per-layer value of the
+        arena is ever formed. With ``layer`` None, one layer's
+        [P, H, page_len, D] arena. Page 0 is the trash page freed rows
+        point at.
       block_tbl: [B, n_lp] int32 — row b's logical block j lives in
         arena page ``block_tbl[b, j]``.
       pos: [B] int32 per-row frontiers.
       scale: score scale; default 1/sqrt(D).
+      layer: static int, or None (see k, v).
 
     block_k is page_len by construction (kernel blocks == pages), so
     there is no autotuned tile here; page_len must be a multiple of
@@ -797,36 +841,252 @@ def flash_decode_attention_paged(q, k, v, block_tbl, pos, scale=None,
     Returns: [B, H, S, D] in q.dtype.
     """
     d = q.shape[-1]
-    page_len = k.shape[2]
+    page_len = k.shape[-2]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if not decode_supported(page_len):
+        if layer is not None:
+            k, v = k[layer], v[layer]
         return decode_attention_paged_reference(q, k, v, block_tbl, pos,
                                                 scale=scale)
+    arena = "-h" if layer is None else "--h"
     return on_shards(
         functools.partial(_flash_decode_paged_pallas, scale=float(scale),
-                          name=name),
+                          name=name, layer=layer),
         kernel_sharding(q.shape[0], q.shape[1]),
-        ("bh", "-h", "-h", "b", "b"), ("bh",))(q, k, v, block_tbl, pos)
+        ("bh", arena, arena, "b", "b"), ("bh",))(q, k, v, block_tbl, pos)
 
 
 @hot_path
 def flash_decode_attention_paged_q8(q, k, v, k_scale, v_scale, block_tbl,
-                                    pos, scale=None, name=None):
+                                    pos, scale=None, name=None, layer=None):
     """int8 block-table flash decode: ``flash_decode_attention_paged``
     over int8 code arenas with fp32 per-(head, position) scale arenas
-    [P, H, page_len], dequantizing in-block exactly like the dense q8
-    family."""
+    ([L, P, H, page_len] beside [L, P, H, page_len, D] codes and a static
+    ``layer``, or one layer's with ``layer`` None), dequantizing in-block
+    exactly like the dense q8 family."""
     d = q.shape[-1]
-    page_len = k.shape[2]
+    page_len = k.shape[-2]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if not decode_supported(page_len):
+        if layer is not None:
+            k, v, k_scale, v_scale = (a[layer]
+                                      for a in (k, v, k_scale, v_scale))
         return decode_attention_paged_q8_reference(
             q, k, v, k_scale, v_scale, block_tbl, pos, scale=scale)
+    arena = "-h" if layer is None else "--h"
     return on_shards(
         functools.partial(_flash_decode_paged_q8_pallas, scale=float(scale),
-                          name=name),
+                          name=name, layer=layer),
         kernel_sharding(q.shape[0], q.shape[1]),
-        ("bh", "-h", "-h", "-h", "-h", "b", "b"), ("bh",))(
+        ("bh", arena, arena, arena, arena, "b", "b"), ("bh",))(
             q, k, v, k_scale, v_scale, block_tbl, pos)
+
+
+# ---------------------------------------------------------------------------
+# In-place frontier append (kernel "kv_append") — the WRITE half of the
+# paged pool. An XLA scatter into one layer of the arena slices the layer
+# out, scatters into the slice and updates it back: three arena-sized
+# passes for a few rows, and it hands the arena a layout Mosaic has to
+# convert back around every decode call. This kernel aliases the arena to
+# its output and rewrites only the frontier page(s) of each row, addressed
+# ``(layer, block_tbl[b, pos[b] // page_len + j], ..)`` by scalar prefetch:
+# the page's [H, page_len, D] block (for a one-row append only the
+# [H, 32, D] tile around the frontier) comes into VMEM, the new rows are
+# selected in at their offsets, and the block goes back. Everything else
+# in the arena is never touched, so it keeps ONE layout — the decode
+# kernel's — from the step's entry to its exit.
+#
+# Rewriting a whole page is sound because a live frontier page belongs to
+# ONE row (the block table is injective per row outside the trash page;
+# copy-on-write gives a prefix's straddle page a private copy before it
+# is written). Frozen rows share the trash page 0, which nothing reads.
+# ---------------------------------------------------------------------------
+
+# Rows of a page a ONE-row append brings in and writes back: the packed
+# sublane tile of the narrowest pool dtype (int8: 32 rows; bf16: 16).
+_APPEND_TILE = 32
+
+
+def _append_touched(s_len, page_len):
+    """Pages a write of ``s_len`` rows (at most one page's worth) can
+    touch from an arbitrary frontier."""
+    assert 1 <= s_len <= page_len, (s_len, page_len)
+    return 1 if s_len == 1 else 2
+
+
+def _append_page(pos_b, j, s_len, page_len, n_lp):
+    """Logical page the j-th grid step of a row rewrites. Steps past the
+    write's last page REPEAT it (same block: no new DMA, and the body
+    recomputes the same page from the same input, so the revisit is
+    idempotent); a frontier past the plane clamps to the last page and
+    selects nothing."""
+    last = _div(pos_b + (s_len - 1), page_len)
+    return jnp.minimum(jnp.minimum(_div(pos_b, page_len) + j, last),
+                       n_lp - 1)
+
+
+def _append_kernel(pos_ref, tbl_ref, *refs, n, s_len, page_len):
+    from jax.experimental.pallas import tpu as pltpu
+
+    news, olds, outs = refs[:n], refs[n:2 * n], refs[2 * n:]
+    pos_b = pos_ref[pl.program_id(0)]
+    lp = _append_page(pos_b, pl.program_id(1), s_len, page_len,
+                      tbl_ref.shape[1])
+    off = _rem(pos_b, page_len)
+
+    def place(new, axis, rows):
+        # [.., s, ..] new values -> the block's ``rows`` positions along
+        # ``axis``: one row broadcasts; several are rolled by the
+        # frontier's offset (a row that lands on the NEXT page wraps to
+        # that page's start, which is where it belongs there).
+        shape = list(new.shape)
+        if s_len == 1:
+            shape[axis] = rows
+            return jnp.broadcast_to(new, shape)
+        if shape[axis] != rows:
+            shape[axis] = rows - shape[axis]
+            new = jnp.concatenate([new, jnp.zeros(shape, new.dtype)], axis)
+        return pltpu.roll(new, off, axis)
+
+    def select_in(new_ref, old_ref, out_ref):
+        # Selects run on 32-bit values (bf16 -> f32 and int8 -> int32 are
+        # exact both ways): a packed row cannot be rolled by an odd count.
+        wide = jnp.float32 if jnp.issubdtype(old_ref.dtype, jnp.floating) \
+            else jnp.int32
+        axis = 0 if len(old_ref.shape) == 4 else 1
+        rows = old_ref.shape[2]
+        # The block's position p holds plane position start + p; it takes
+        # new row r = start + p - pos_b where 0 <= r < s_len and keeps
+        # what it holds everywhere else.
+        start = lp * page_len + _div(off, rows) * rows
+        r = start - pos_b + jax.lax.broadcasted_iota(
+            jnp.int32, old_ref.shape[-2:], axis)
+        keep = (r < 0) | (r >= s_len)
+
+        def merged(old, new):
+            return jnp.where(keep, old.astype(wide),
+                             place(new.astype(wide), axis, rows)
+                             ).astype(out_ref.dtype)
+
+        if axis == 1:                        # scales [1, H, page_len]
+            out_ref[0] = merged(old_ref[0], new_ref[0])
+            return
+
+        def one_head(h_, _):                 # rows [1, H, rows, D]
+            out_ref[0, h_] = merged(old_ref[0, h_], new_ref[0, h_])
+
+        # A loop, not 16 copies of the body: the step holds this kernel
+        # once a layer and lane, and its size is set-up time.
+        jax.lax.fori_loop(0, old_ref.shape[1], one_head, None)
+
+    for new_ref, old_ref, out_ref in zip(news, olds, outs):
+        select_in(new_ref, old_ref, out_ref)
+
+
+def _kv_append_pallas(pos, tbl, *ops, layer, s_len):
+    from jax.experimental.pallas import tpu as pltpu
+
+    n = len(ops) // 2
+    news, arenas = ops[:n], ops[n:]
+    page_len = arenas[0].shape[3]
+    n_lp = tbl.shape[1]
+    pos = pos.astype(jnp.int32)
+    tbl = tbl.astype(jnp.int32)
+
+    def new_spec(new):
+        zeros = (0,) * (new.ndim - 1)
+        return pl.BlockSpec((1,) + new.shape[1:],
+                            lambda b_, j, pos_ref, tbl_ref: (b_,) + zeros)
+
+    def arena_spec(arena):
+        # A row arena's block is [H, rows, D] of one page: the whole page,
+        # or for a one-row append only the tile that holds the frontier
+        # (16 times less to bring in and write back). A scale arena's is
+        # [H, page_len], positions on the lanes.
+        rows = _APPEND_TILE if s_len == 1 and arena.ndim == 5 else page_len
+
+        def index(b_, j, pos_ref, tbl_ref):
+            pos_b = pos_ref[b_]
+            lp = _append_page(pos_b, j, s_len, page_len, n_lp)
+            tile = (_div(_rem(pos_b, page_len), rows), 0) \
+                if arena.ndim == 5 else (0,)
+            return (layer, tbl_ref[b_, lp], 0) + tile
+
+        return pl.BlockSpec((None, 1, arena.shape[2], rows)
+                            + arena.shape[4:], index)
+
+    arena_specs = [arena_spec(a) for a in arenas]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(pos.shape[0], _append_touched(s_len, page_len)),
+        in_specs=[new_spec(x) for x in news] + arena_specs,
+        out_specs=arena_specs,
+    )
+    out = pallas_mode.kernel_call(
+        "kv_append",
+        functools.partial(_append_kernel, n=n, s_len=s_len,
+                          page_len=page_len),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in arenas],
+        # Operands count the two scalar-prefetch arguments.
+        input_output_aliases={2 + n + i: i for i in range(n)},
+    )(pos, tbl, *news, *arenas)
+    return tuple(out)
+
+
+@hot_path
+def kv_append(arenas, new, block_tbl, pos, layer):
+    """Append each row's new k/v at its frontier, IN PLACE in the arenas.
+
+    Args:
+      arenas: tuple of page arenas of one paged pool (k and v together,
+        at least), each WHOLE: [L, P, H, page_len, D] rows (bf16, or int8
+        codes) and, for an int8 pool, [L, P, H, page_len] fp32 scales.
+        Donate them (the serving step does): each comes back as the same
+        buffer.
+      new: the matching tuple of new values, [B, H, S, D] per row arena
+        and [B, H, S] per scale arena: row b's S positions
+        ``pos[b] .. pos[b]+S-1`` (S = 1 in the decode scan, spec_k + 1 in
+        a verify, prefill_chunk in the lane; any frontier, a write may
+        straddle a page boundary).
+      block_tbl: [B, n_lp] int32; pos: [B] int32 pre-write frontiers.
+      layer: static int.
+
+    Bit for bit ``arena.at[layer, pg, :, off, :].set(new)`` with
+    ``pg, off`` through the table, for every position inside a row's
+    plane — except the trash page 0, which several frozen rows may share
+    and nothing reads. ``page_len`` must be a kernel block
+    (``decode_supported``): the branch of ``models/generation.py``
+    ``_forward`` that calls the paged decode kernel calls this.
+    Returns the tuple of updated arenas.
+    """
+    page_len = arenas[0].shape[3]
+    assert decode_supported(page_len) and len(arenas) > 1, page_len
+    s = new[0].shape[2]
+    b, h = new[0].shape[:2]
+    # Lane-dim padding of the scales' new values cannot be made inside the
+    # kernel (an unaligned lane concatenate), so they arrive page-wide.
+    # Row values pad up to a packed sublane tile at most.
+    sub = _APPEND_TILE
+    out = tuple(arenas)
+    for lo in range(0, s, page_len):      # at most a page's rows a call
+        n_rows = min(page_len, s - lo)
+        part = []
+        for x, a in zip(new, arenas):
+            x = jax.lax.slice_in_dim(x, lo, lo + n_rows, axis=2)
+            if n_rows > 1 and a.ndim == 4:
+                x = jnp.pad(x, ((0, 0), (0, 0), (0, page_len - n_rows)))
+            elif n_rows > 1 and n_rows % sub:
+                x = jnp.pad(x, ((0, 0), (0, 0),
+                                (0, sub - n_rows % sub), (0, 0)))
+            part.append(x)
+        specs = ("-", "-") + tuple("-h" for _ in part) \
+            + tuple("--h" for _ in out)
+        out = on_shards(
+            functools.partial(_kv_append_pallas, layer=int(layer),
+                              s_len=n_rows),
+            kernel_sharding(b, h), specs, tuple("--h" for _ in out))(
+                pos + lo, block_tbl, *part, *out)
+    return tuple(out)

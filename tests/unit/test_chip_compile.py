@@ -91,6 +91,45 @@ def _paged_args(page_len, rows, int8=False):
             + [((SLOTS, n_lp), I32), ((SLOTS,), I32)])
 
 
+# The serving cells' paged pool: 24 layers, 16 slots x 9 pages + trash.
+LAYERS, PAGES, PAGE = 24, SLOTS * (T_KV + 128) // 128 + 1, 128
+ARENA = ((LAYERS, PAGES, HEADS, PAGE, HD), BF16)
+ARENA_Q8 = ((LAYERS, PAGES, HEADS, PAGE, HD), I8)
+ARENA_SCALE = ((LAYERS, PAGES, HEADS, PAGE), F32)
+
+
+def _whole_arena_args(rows, slots, int8=False):
+    """q, the arenas WHOLE, table, frontiers: what ``_forward`` hands the
+    layer-indexed paged kernels."""
+    return ([((slots, HEADS, rows, HD), BF16)]
+            + ([ARENA_Q8] * 2 + [ARENA_SCALE] * 2 if int8 else [ARENA] * 2)
+            + [((slots, PAGES // SLOTS), I32), ((slots,), I32)])
+
+
+def _paged_whole(q, k, v, tbl, pos):
+    return da.flash_decode_attention_paged(q, k, v, tbl, pos,
+                                           layer=LAYERS - 1)
+
+
+def _paged_whole_q8(q, k, v, ks, vs, tbl, pos):
+    return da.flash_decode_attention_paged_q8(q, k, v, ks, vs, tbl, pos,
+                                              layer=LAYERS - 1)
+
+
+def _append_args(rows, slots, int8=False):
+    new = ((slots, HEADS, rows, HD), I8 if int8 else BF16)
+    new_scale = ((slots, HEADS, rows), F32)
+    return ([new] * 2 + ([new_scale] * 2 if int8 else [])
+            + ([ARENA_Q8] * 2 + [ARENA_SCALE] * 2 if int8 else [ARENA] * 2)
+            + [((slots, PAGES // SLOTS), I32), ((slots,), I32)])
+
+
+def _append(*args):
+    n = (len(args) - 2) // 2
+    return da.kv_append(args[n:2 * n], args[:n], args[-2], args[-1],
+                        layer=LAYERS - 1)
+
+
 def _dense_decode_args(rows, int8=False):
     plane = ((SLOTS, HEADS, T_KV, HD), I8 if int8 else BF16)
     scale = ((SLOTS, HEADS, T_KV), F32)
@@ -138,6 +177,25 @@ CASES = {
                                         _paged_args(128, 5), {}),
     "decode_paged_prefill_chunk_32_rows": (da.flash_decode_attention_paged,
                                            _paged_args(128, 32), {}),
+    # The arena whole and the layer in the index map: what the serving
+    # step runs (decode scan, speculative verify, the prefill lane).
+    "decode_paged_whole_arena_1_row": (_paged_whole,
+                                       _whole_arena_args(1, SLOTS), {}),
+    "decode_paged_whole_arena_verify_5_rows": (
+        _paged_whole, _whole_arena_args(5, SLOTS), {}),
+    "decode_paged_whole_arena_lane_128_rows": (
+        _paged_whole, _whole_arena_args(128, 1), {}),
+    "decode_paged_whole_arena_q8": (_paged_whole_q8,
+                                    _whole_arena_args(1, SLOTS, int8=True),
+                                    {}),
+    "kv_append_1_row": (_append, _append_args(1, SLOTS), {}),
+    "kv_append_verify_5_rows": (_append, _append_args(5, SLOTS), {}),
+    "kv_append_lane_128_rows": (_append, _append_args(128, 1), {}),
+    "kv_append_q8_1_row": (_append, _append_args(1, SLOTS, int8=True), {}),
+    "kv_append_q8_verify_5_rows": (_append,
+                                   _append_args(5, SLOTS, int8=True), {}),
+    "kv_append_q8_lane_128_rows": (_append,
+                                   _append_args(128, 1, int8=True), {}),
     "fused_layer_norm_fwd_bwd": (
         _fwd_bwd(lambda x, g, b: layer_norm.fused_layer_norm(x, g, b), 3),
         [LN_X, VEC, VEC], {}),
@@ -165,7 +223,7 @@ CASES = {
 
 KERNEL_NAME = re.compile(
     r"^(flash_fwd|flash_bwd_fused|flash_bwd_dq|flash_bwd_dkv|decode_attn|"
-    r"decode_attn_q8|paged_decode|paged_decode_q8|prefill_attn|"
+    r"decode_attn_q8|paged_decode|paged_decode_q8|prefill_attn|kv_append|"
     r"sparse_attn_fwd|sparse_attn_bwd_fused|sparse_attn_bwd_dq|"
     r"sparse_attn_bwd_dkv|dropout_fwd|dropout_mask|bias_gelu|"
     r"layer_norm_fwd|attn_softmax)(\.\d+)?$")
@@ -216,3 +274,128 @@ def test_kernel_compiles_for_v5e(name, chip, monkeypatch):
     assert _kernel_calls(text) and all(
         KERNEL_NAME.match(c) for c in _kernel_calls(text)), \
         _kernel_calls(text)
+
+
+# ------------------------------------------------- the serving step itself
+
+# No byte moves in these: a parameter, a tuple, an element of one, a bitcast,
+# and control flow (its computations are read in their own right).
+_PLUMBING = ("parameter", "get-tuple-element", "tuple", "bitcast",
+             "conditional", "while")
+
+
+def _computations(text):
+    """Optimised HLO text -> {computation name: [instruction lines]}."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.-]+) \(.*\) -> .* \{$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and " = " in line:
+            cur.append(line.strip())
+    return comps
+
+
+def _reachable(comps, root):
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += re.findall(
+                r"(?:calls|to_apply|body|condition|true_computation|"
+                r"false_computation)=%([\w.-]+)", line)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                todo += re.findall(r"%([\w.-]+)", group)
+    return seen
+
+
+def _arena_shaped(lines, shapes):
+    """Instructions with a result of one of ``shapes`` that are neither a
+    Pallas kernel nor plumbing."""
+    found = []
+    for line in lines:
+        m = re.match(r"(?:ROOT )?%([\w.-]+) = (.*?) ([\w-]+)\(", line)
+        if not m or m.group(3) in _PLUMBING or \
+                'custom_call_target="tpu_custom_call"' in line:
+            continue
+        if any(shape in m.group(2) for shape in shapes):
+            found.append((m.group(1), m.group(3)))
+    return found
+
+
+def test_mixed_step_forms_no_layer_of_the_arena_in_the_decode_scan(
+        chip, monkeypatch):
+    """The engine's mixed step (355M widths, 4 layers, the cells' paged
+    pool: 16 slots, page 128, chunk 16, prefill chunk 128) compiled for
+    the described chip: inside the decode scan's ``while`` body nothing
+    but the kernels has a result shaped like the arena or one layer of it
+    — no slice, scatter, ``dynamic-update-slice`` or layout ``copy``. (A
+    5-D XLA scatter in place of ``kv_append`` passes every parity test
+    and fails here: XLA gives the arena the scatter's layout and converts
+    all of it around every kernel call.) Outside the scan the arenas meet
+    exactly the layout copies of the step's entry and exit."""
+    from deepspeed_tpu.inference import engine as engine_mod
+    from deepspeed_tpu.inference import kv_pool
+    from deepspeed_tpu.inference.adapters.gpt2 import GPT2Adapter
+    from deepspeed_tpu.inference.config import InferenceConfig
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    n_layer, chunk, lane = 4, 16, 128
+    model = GPT2LMHeadModel(GPT2Config(
+        n_embd=HEADS * HD, n_layer=n_layer, n_head=HEADS, n_positions=T_KV,
+        vocab_size=50257, dtype=BF16, dropout=0.0))
+    config = InferenceConfig.from_dict(dict(
+        max_slots=SLOTS, max_len=T_KV, chunk_size=chunk, paged_kv=True,
+        kv_page_len=PAGE, prefill_chunk=lane, use_flash_decode=True))
+    adapter = GPT2Adapter.from_model(model, use_flash_decode=True).bind(
+        config, None)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), I32))["params"])
+    pool = jax.eval_shape(lambda: kv_pool.init_pool(
+        adapter.cache_spec(), SLOTS, T_KV, slack=lane, page_len=PAGE,
+        num_pages=PAGES - 1))
+    assert pool["k"].shape == (n_layer,) + ARENA[0][1:]
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+
+    def scalar(dtype):
+        return jax.ShapeDtypeStruct((), dtype, sharding=chip)
+
+    def mixed_step(*args):
+        return engine_mod._mixed_step_program(*args)
+
+    text = jax.jit(mixed_step, static_argnums=(1, 2, 3),
+                   donate_argnums=(4,)).lower(
+        on_chip(params), adapter, chunk, None, on_chip(pool),
+        jax.ShapeDtypeStruct((1, lane), I32, sharding=chip),
+        scalar(I32), scalar(I32), scalar(I32), scalar(jnp.bool_),
+        scalar(jnp.bool_), scalar(I32), scalar(I32), scalar(F32),
+        scalar(I32), scalar(jnp.uint32)).compile().as_text()
+
+    comps = _computations(text)
+    bodies = [m for lines in comps.values() for line in lines
+              if "decode_scan/while" in line and " while(" in line
+              for m in re.findall(r"body=%([\w.-]+)", line)]
+    assert len(bodies) == 1, bodies
+    scan = _reachable(comps, bodies[0])
+    in_scan = [line for name in scan for line in comps[name]]
+    names = sorted(c.split(".")[0] for c in _kernel_calls(
+        "\n".join(in_scan)))
+    assert names == ["kv_append"] * n_layer + ["paged_decode"] * n_layer
+    shapes = ["[{},{},{},{},{}]".format(n_layer, PAGES, HEADS, PAGE, HD),
+              "[{},{},{},{}]".format(PAGES, HEADS, PAGE, HD)]
+    assert _arena_shaped(in_scan, shapes) == []
+    # Outside it: the lane's cond and the scan's carry add nothing of their
+    # own. What is left converts k and v between the stored layout and the
+    # kernels' where the step enters (once in each branch of the lane's
+    # cond) and back where it leaves.
+    outside = _arena_shaped(
+        [line for name, lines in comps.items() if name not in scan
+         for line in lines], shapes)
+    assert [op for _, op in outside] == ["copy"] * 6, outside

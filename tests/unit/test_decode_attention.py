@@ -14,10 +14,11 @@ import pytest
 from deepspeed_tpu.models.generation import (
     _forward, as_gencfg, decode_step, generate, init_cache)
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+from deepspeed_tpu.ops.transformer.kernels import decode_attention as da
 from deepspeed_tpu.ops.transformer.kernels.decode_attention import (
     BLOCK_MIN, decode_attention_q8_reference, decode_attention_reference,
     decode_supported, dequantize_kv, flash_decode_attention,
-    flash_decode_attention_q8, pad_cache_len, planned_block_k,
+    flash_decode_attention_q8, kv_append, pad_cache_len, planned_block_k,
     quantize_kv, resolve_decode_block)
 
 
@@ -364,3 +365,145 @@ def test_q8_unsupported_length_falls_back_to_reference():
     out = flash_decode_attention_q8(q, kq, vq, ks, vs, pos)
     ref = decode_attention_q8_reference(q, kq, vq, ks, vs, pos)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+# ----------------------------------------- paged arena: in-place append
+#
+# The write half and the read half of "a per-layer value of the paged
+# arena is never formed": ``kv_append`` against the XLA scatter it
+# replaced, over the WHOLE arena, and the layer-indexed paged kernels
+# against the references on ``arena[layer]``. Page 128 is a kernel block,
+# so these run the Pallas bodies (interpret mode here).
+
+_PAGE = 128
+
+
+def _scatter_reference(arena, new, tbl, pos, layer):
+    """What ``models/generation.py`` ``_forward`` did before the kernel:
+    ``arena.at[layer, pg, :, off, :].set(new)`` through the block table."""
+    s = new.shape[2]
+    w_pos = pos[:, None] + jnp.arange(s)[None]
+    w_pg = tbl[jnp.arange(new.shape[0])[:, None],
+               jnp.minimum(w_pos // _PAGE, tbl.shape[1] - 1)]
+    w_off = w_pos % _PAGE
+    if arena.ndim == 5:
+        return arena.at[layer, w_pg, :, w_off, :].set(
+            new.transpose(0, 2, 1, 3))
+    return arena.at[layer, w_pg, :, w_off].set(new.transpose(0, 2, 1))
+
+
+def _append_case(s, pos, int8, layer, frozen=(), n_layer=3, h=2, d=8,
+                 n_lp=3, seed=0):
+    rng = np.random.RandomState(seed)
+    b = len(pos)
+    n_pages = b * n_lp + 1
+    tbl = (1 + rng.permutation(n_pages - 1)).reshape(b, n_lp)
+    for row in frozen:
+        tbl[row] = 0                       # a freed row: all on the trash page
+    tbl = jnp.asarray(tbl, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+
+    def rows(shape):
+        if int8:
+            return jnp.asarray(rng.randint(-127, 128, shape), jnp.int8)
+        return jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+
+    arenas = [rows((n_layer, n_pages, h, _PAGE, d)) for _ in range(2)]
+    new = [rows((b, h, s, d)) for _ in range(2)]
+    if int8:
+        arenas += [jnp.asarray(rng.rand(n_layer, n_pages, h, _PAGE),
+                               jnp.float32) for _ in range(2)]
+        new += [jnp.asarray(rng.rand(b, h, s), jnp.float32)
+                for _ in range(2)]
+    got = jax.jit(lambda a, n: kv_append(tuple(a), tuple(n), tbl, pos,
+                                         layer))(arenas, new)
+    assert len(got) == len(arenas)
+    for arena, x, g in zip(arenas, new, got):
+        want = np.array(_scatter_reference(arena, x, tbl, pos, layer)
+                        .astype(jnp.float32))
+        g = np.array(g.astype(jnp.float32))
+        assert g.dtype == want.dtype and g.shape == want.shape
+        # Bit for bit over the whole arena: every layer, every page. Only
+        # the trash page's written layer is unchecked when frozen rows
+        # share it (their order of arrival there is nobody's business).
+        if frozen:
+            g[layer, 0], want[layer, 0] = 0, 0
+        np.testing.assert_array_equal(g, want)
+
+
+APPEND_CASES = {
+    # name: (S, frontiers, int8, layer, frozen rows)
+    "decode_one_row_first_layer": (1, [0, 127, 128, 300], False, 0, ()),
+    "decode_one_row_last_layer": (1, [31, 32, 255, 383], False, 2, ()),
+    "verify_5_rows_straddling_a_page": (5, [0, 125, 126, 251], False, 1, ()),
+    "lane_128_rows_page_aligned": (128, [0, 128, 256], False, 1, ()),
+    "lane_128_rows_from_mid_page": (128, [7, 100, 255], False, 2, ()),
+    "lane_40_rows_padded_to_a_tile": (40, [100, 3, 250], False, 0, ()),
+    "lane_200_rows_in_two_calls": (200, [0, 60, 184], False, 1, ()),
+    "int8_one_row": (1, [0, 127, 128, 300], True, 2, ()),
+    "int8_verify_5_rows_straddling": (5, [0, 125, 126, 251], True, 0, ()),
+    "int8_lane_128_rows_from_mid_page": (128, [7, 100, 255], True, 1, ()),
+    "frozen_rows_share_the_trash_page": (
+        1, [5, 77, 200, 0, 129, 9], False, 1, (0, 3, 5)),
+    "frozen_rows_share_the_trash_page_verify": (
+        5, [5, 126, 200, 0, 129, 9], True, 2, (0, 3, 5)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(APPEND_CASES))
+def test_kv_append_is_the_scatter_bit_for_bit(name):
+    """The in-place append equals ``arena.at[layer, pg, :, off, :].set``
+    over the whole arena — nothing else in it may change: other layers,
+    other pages, the rows of a frontier page below and above the write."""
+    s, pos, int8, layer, frozen = APPEND_CASES[name]
+    _append_case(s, pos, int8, layer, frozen=frozen)
+
+
+@pytest.mark.parametrize("int8", [False, True])
+@pytest.mark.parametrize("s", [1, 5])
+def test_layer_indexed_paged_decode_matches_reference(s, int8):
+    """The paged kernels given the arena WHOLE and a non-zero ``layer``
+    equal the paged reference on ``arena[layer]``, and equal themselves
+    on the sliced layer bit for bit (same body, same DMAs)."""
+    rng = np.random.RandomState(3)
+    n_layer, b, h, d, n_lp, layer = 3, 3, 2, 16, 3, 2
+    n_pages = b * n_lp + 1
+    q = jnp.asarray(rng.randn(b, h, s, d), jnp.float32)
+    tbl = jnp.asarray((1 + rng.permutation(n_pages - 1)).reshape(b, n_lp),
+                      jnp.int32)
+    pos = jnp.asarray([0, 130, 3 * _PAGE - s], jnp.int32)
+    kf = jnp.asarray(rng.randn(n_layer, n_pages, h, _PAGE, d), jnp.float32)
+    vf = jnp.asarray(rng.randn(n_layer, n_pages, h, _PAGE, d), jnp.float32)
+    if int8:
+        (k, ks), (v, vs) = da.quantize_kv(kf), da.quantize_kv(vf)
+        got = da.flash_decode_attention_paged_q8(q, k, v, ks, vs, tbl, pos,
+                                                 layer=layer)
+        sliced = da.flash_decode_attention_paged_q8(
+            q, k[layer], v[layer], ks[layer], vs[layer], tbl, pos)
+        want = da.decode_attention_paged_q8_reference(
+            q, k[layer], v[layer], ks[layer], vs[layer], tbl, pos)
+    else:
+        got = da.flash_decode_attention_paged(q, kf, vf, tbl, pos,
+                                              layer=layer)
+        sliced = da.flash_decode_attention_paged(q, kf[layer], vf[layer],
+                                                 tbl, pos)
+        want = da.decode_attention_paged_reference(q, kf[layer], vf[layer],
+                                                   tbl, pos)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(sliced))
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def test_layer_indexed_paged_decode_falls_back_on_small_pages():
+    """A page that is no kernel block takes the gather + reference path,
+    with the layer sliced there (the CPU test geometries)."""
+    rng = np.random.RandomState(4)
+    q = jnp.asarray(rng.randn(2, 2, 1, 4), jnp.float32)
+    k = jnp.asarray(rng.randn(3, 5, 2, 8, 4), jnp.float32)
+    v = jnp.asarray(rng.randn(3, 5, 2, 8, 4), jnp.float32)
+    tbl = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    pos = jnp.asarray([3, 12], jnp.int32)
+    np.testing.assert_array_equal(
+        np.asarray(da.flash_decode_attention_paged(q, k, v, tbl, pos,
+                                                   layer=1)),
+        np.asarray(da.decode_attention_paged_reference(q, k[1], v[1], tbl,
+                                                       pos)))

@@ -303,6 +303,37 @@ def test_all_three_tiers_mixed_spec_nonspec_one_compile():
     assert m["kv_bytes_per_slot"] < m["kv_bytes_per_slot_flat"]
 
 
+def test_paged_flash_int8_prefix_streams_equal_the_dense_pools():
+    """The int8 tier over a paged pool whose page is a kernel block
+    (128): codes AND scales are appended in place by ``kv_append`` and
+    read by the layer-indexed q8 paged kernel, and a shared prefix puts
+    the aliasers' first chunk at a MID-PAGE frontier of a copy-on-write
+    page. Pinned stream for stream against the dense int8 pool under the
+    dense q8 kernel (int8 waives identity with fp, not with itself)."""
+    cfg, model, params = make_model(n_positions=256)
+    prompts = shared_prefix_prompts(cfg, 12, [4, 125, 6])
+
+    def serve(**extra):
+        eng = engine_of(model, params, max_slots=2, max_len=192,
+                        prefill_chunk=64, use_flash_decode=True,
+                        int8_kv=True, prefix_cache=True, prefix_slots=2,
+                        prefix_len=16, min_prefix_len=4, **extra)
+        first = eng.submit(prompts[0], max_new_tokens=6)
+        eng.run()                       # publish the prefix
+        rest = [eng.submit(p, max_new_tokens=6) for p in prompts[1:]]
+        eng.run()
+        return eng, [r.tokens for r in (first,) + tuple(rest)]
+
+    dense, want = serve()
+    paged, got = serve(paged_kv=True, kv_page_len=128)
+    assert paged._pool["k"].dtype == np.int8
+    assert paged._pool["k_scale"].shape[3] == 128
+    assert got == want, "paged + flash int8 streams diverged from dense"
+    assert paged.compile_count == dense.compile_count == 1
+    assert paged.metrics()["prefix_hits"] == dense.metrics()["prefix_hits"] \
+        and paged.metrics()["prefix_hits"] >= 1
+
+
 # ---------------------------------------------------------- backpressure
 
 

@@ -239,6 +239,63 @@ def test_paged_engine_parity_greedy_sampled_one_program():
     assert m["kv_hbm_bytes"] > 0
 
 
+def test_paged_flash_engine_streams_equal_the_dense_pools():
+    """Page 128 is a kernel block, so this engine takes the branch the
+    chip serves: ``kv_append`` writes the arenas in place (one row a slot
+    in the scan; the lane's chunks from page-aligned and, after a 5-token
+    prompt's neighbour, mid-page frontiers) and the paged kernel reads
+    them through the layer's index. Greedy and sampled streams, prompts
+    and generations that cross a page boundary, slot churn: byte-equal to
+    the dense pool's under the dense kernel, on ONE program."""
+    cfg, model, params = make_model(n_positions=512)
+    lens = [5, 130, 3, 140, 7, 60]
+
+    def serve(**extra):
+        eng = engine_of(model, params, max_slots=3, max_len=384,
+                        prefill_chunk=64, use_flash_decode=True, **extra)
+        reqs = []
+        for i, p in enumerate(prompts_of(cfg, lens)):
+            kw = {"max_new_tokens": 5 + (i % 3)}
+            if i % 2:
+                kw.update(temperature=0.8, seed=40 + i)
+            reqs.append(eng.submit(p, **kw))
+        eng.run()
+        return eng, [r.tokens for r in reqs]
+
+    dense, want = serve()
+    paged, got = serve(paged_kv=True, kv_page_len=128)
+    assert paged._pool["k"].shape[3] == 128 and "block_tbl" in paged._pool
+    assert got == want, "paged + flash streams diverged from dense"
+    assert paged.compile_count == dense.compile_count == 1
+    assert paged.kv_page_stats()["pages_in_use"] == 0
+
+
+def test_paged_flash_engine_with_tensor_sharded_heads(eight_devices):
+    """The same branch over a mesh with a 'model' axis: both kernels
+    launch shard-local on the arenas' head shards (the append with every
+    row on every shard: no shard may keep a row's write to itself), and
+    the streams equal the unsharded dense pool's."""
+    import jax
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+
+    cfg, model, params = make_model(n_positions=512)   # 4 heads over mp=4
+    mesh = mesh_lib.build_mesh(devices=jax.devices()[:4], num_mp=4,
+                               num_dp=1)
+    prompts = prompts_of(cfg, [5, 130, 60])
+
+    def serve(mesh=None, **extra):
+        eng = engine_of(model, params, mesh=mesh, max_slots=3, max_len=384,
+                        prefill_chunk=64, use_flash_decode=True, **extra)
+        reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+        eng.run()
+        return eng, [r.tokens for r in reqs]
+
+    _, want = serve()
+    paged, got = serve(mesh=mesh, paged_kv=True, kv_page_len=128)
+    assert paged._pool["k"].sharding.spec[2] == mesh_lib.MODEL_AXIS
+    assert got == want, "tensor-sharded paged + flash streams diverged"
+
+
 def test_spec_decode_rollback_across_page_boundary():
     """Speculative verify writes spec_k+1 positions per step; with
     page_len 4 < spec_k+1 every verify straddles a page boundary, so

@@ -95,6 +95,32 @@ def test_speculation_invisible_across_chunk_and_k(spec_k, chunk_size):
         "spec tokens diverge at spec_k={} chunk={}".format(spec_k, chunk_size)
 
 
+def test_paged_flash_spec_streams_equal_the_dense_pools():
+    """Speculation over a paged pool whose page is a kernel block (128):
+    every verify appends spec_k + 1 rows in place through ``kv_append``,
+    some across the boundary between a row's first and second page, and
+    scores them through the layer-indexed paged kernel. Streams equal the
+    dense pool's under the dense kernel, and the plain greedy stream."""
+    cfg, model, params = make_model(n_positions=256)
+    prompts = [rep_prompt(cfg, phrase=4, reps=30, seed=1),    # 120 tokens
+               rep_prompt(cfg, phrase=5, reps=25, seed=2),    # 125
+               prompts_of(cfg, [9])[0]]
+
+    def serve(**extra):
+        eng = spec_engine(model, params, max_len=192, prefill_chunk=64,
+                          use_flash_decode=True, **extra)
+        reqs = [eng.submit(p, max_new_tokens=14) for p in prompts]
+        eng.run()
+        assert eng.compile_count == 1
+        return eng, [r.tokens for r in reqs]
+
+    _, want = serve()
+    paged, got = serve(paged_kv=True, kv_page_len=128)
+    assert got == want, "paged + flash spec streams diverged from dense"
+    assert paged.metrics()["accepted_per_step_mean"] > 1.0
+    assert got[2] == seq_greedy(model, params, prompts[2], 14)
+
+
 def test_sampled_stream_identical_spec_on_off():
     """Under temperature sampling the verify lane draws each position
     with the SAME fold_in(seed, position) rng the 1-token path uses, so
