@@ -65,14 +65,21 @@ def flash_attention_cost(batch, heads, seq_len, head_dim, causal=True,
     }
 
 
-def decode_attention_cost(context_lens, heads, head_dim, dtype_bytes=2):
+def decode_attention_cost(context_lens, heads, head_dim, dtype_bytes=2,
+                          kv_bytes_per_token=None):
     """FLOPs and HBM bytes of one decode-attention call (one layer, one
     new token for each slot): every slot reads the keys and the values of
     its own context once (q and the output are negligible and left out),
-    and does q.K^T and p.V, 2 FLOPs a multiply-add each."""
+    and does q.K^T and p.V, 2 FLOPs a multiply-add each for each of the
+    ``heads`` query heads. ``kv_bytes_per_token`` is what one token's cache
+    holds in one layer, as the model's builder states it (grouped-query or
+    latent heads hold less than a key and a value for every query head,
+    which is what is counted without it)."""
     total = float(sum(context_lens))
+    if kv_bytes_per_token is None:
+        kv_bytes_per_token = 2 * heads * head_dim * dtype_bytes
     return {"flops": 4 * heads * head_dim * total,
-            "bytes": 2 * heads * head_dim * dtype_bytes * total}
+            "bytes": kv_bytes_per_token * total}
 
 
 def least_seconds(flops, nbytes, peaks):

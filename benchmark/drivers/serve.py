@@ -321,8 +321,9 @@ def run(run):
          engine_compile_count=engine.compile_count, token_margins=margins,
          **dict(values, **counters))
     counters.update(
-        trace_steps=len(loop.steps) - trace_first, slots=slots, n_layer=model.n_layer,
-        n_head=model.n_head, head_dim=model.head_dim,
+        trace_steps=len(loop.steps) - trace_first, slots=slots,
+        n_layer=model.n_layer, n_head=model.n_head, head_dim=model.head_dim,
+        kv_bytes_token_layer=model.kv_bytes_per_token_layer(),
         chunk_size=int(mix["engine"]["chunk_size"]),
         trace_context=loop.context[trace_first:])
     engine.close()

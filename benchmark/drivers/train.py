@@ -96,7 +96,7 @@ def run(run):
          flops_per_token=flops_per_token, **model.sizes())
 
     pool = traffic.token_batches(run.seed, BATCH_POOL, batch, seq_len,
-                                 model.vocab_size)
+                                 model.vocab_size, mix)
     # deepspeed.initialize makes both moments whole on JAX's default device
     # before it shards them: where the deployment says so the host is that
     # device, as a user whose model exceeds one chip has to do today.

@@ -39,6 +39,12 @@ def load_json(path):
         return json.load(f)
 
 
+def paths():
+    """``paths`` of ``BENCHMARK.json``: the directories every file of the
+    benchmark is looked up under. A manifest the tests build keeps them."""
+    return load_json(MANIFEST)["paths"]
+
+
 def _find(paths, *parts):
     """The first ``<path>/<parts...>`` that exists under the manifest's
     ``paths``: how every per-cell file is found by its name."""
@@ -49,6 +55,25 @@ def _find(paths, *parts):
         if os.path.exists(candidate):
             return candidate
     raise FileNotFoundError("none of {} exists".format(tried))
+
+
+def find_all(folder, suffix):
+    """Every ``<path>/<folder>/*<suffix>`` under ``paths``, sorted within a
+    path: the files a later PR adds beside the ones that are there (names
+    files, stand-ins, builders)."""
+    out = []
+    for base in paths():
+        found = os.path.join(ROOT, base, folder)
+        if os.path.isdir(found):
+            out += [os.path.join(found, f) for f in sorted(os.listdir(found))
+                    if f.endswith(suffix)]
+    return out
+
+
+def load_by_name(folder, name):
+    """The module ``<path>/<folder>/<name>.py`` under ``paths``."""
+    return _load_module(_find(paths(), folder, name + ".py"),
+                        "benchmark_{}_{}".format(folder, name))
 
 
 def _load_module(path, name):

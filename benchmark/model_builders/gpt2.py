@@ -1,7 +1,9 @@
 """Configurations of ``"model_type": "gpt2"``: the program's model built from
 a configuration file, its weights, its plain reference and its costs. A
 configuration of another family brings a file of its own beside this one,
-found by its ``model_type``.
+found by its ``model_type``; what the drivers and the readers ask of it is
+listed in ``benchmark/README.md`` ("What a builder owes") and held by
+``tests/benchmark/test_builders.py``.
 """
 
 import contextlib
@@ -51,6 +53,11 @@ class Model(object):
             return jax.jit(lambda key: self.module.init(
                 key, jnp.zeros((1, 16), jnp.int32))["params"])(
                     jax.random.PRNGKey(seed))
+
+    def kv_bytes_per_token_layer(self):
+        """Bytes of cache one token holds in one layer: a key and a value
+        for every head, in the type the engine computes and stores in."""
+        return 2 * self.n_head * self.head_dim * self.cfg.dtype.itemsize
 
     def train_flops_per_token(self, seq_len):
         c = self.cfg

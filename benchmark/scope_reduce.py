@@ -32,20 +32,24 @@ A parent commit has none of the names: every table is then empty or
 ``(unscoped)``, nothing raises, and a reader returns nothing.
 """
 
-import json
 import os
 import re
 
 from benchmark import trace_reduce
 
-_HERE = os.path.dirname(os.path.abspath(__file__))
 _WRAPPER = re.compile(r"^[\w.-]+\((.*)\)$")
 UNSCOPED = "(unscoped)"
 
 
 def scope_names():
-    with open(os.path.join(_HERE, "scope_names.json")) as f:
-        return json.load(f)
+    """``scope_names.json`` with every family's region words, kernel names
+    and movement opcodes merged in (``trace_reduce.merge_names``);
+    ``kernel`` is the expression that finds a kernel: one of the ``kernels``
+    at the start of a name (``flash_bwd`` finds ``flash_bwd_fused``)."""
+    names = trace_reduce.merge_names("scope_names.json")
+    names["kernel"] = "^({})".format(
+        "|".join(re.escape(k) for k in names["kernels"]))
+    return names
 
 
 # ---------------------------------------------------------------------------
@@ -296,7 +300,8 @@ def reduce_scopes(path, planes, names=None, trace_names=None):
     - ``kernels``: kernel name -> {``s``: self seconds, ``calls``: calls};
     - ``move_scan_s``: self seconds of pure data movement inside the
       decode scan (by region, or, where nothing has a region, by nesting
-      under a top-level ``while``);
+      under a top-level ``while``); ``move_outside_s``: the same outside it
+      (the layout copies where a serving step enters and leaves);
     - ``named_s``: self seconds under any region or kernel name;
     - ``regions``: the scope words the embedded programs hold at all (a
       region that did not run reads 0, one the program lacks reads nothing);
@@ -343,7 +348,7 @@ class _Reduction(object):
         self.chips = float(chips)
         self.scope_s, self.inherited_s, self.scope_ops = {}, {}, {}
         self.kernels, self.host, self.instants = {}, {}, {}
-        self.move_scan_s = self.named_s = 0.0
+        self.move_scan_s = self.move_outside_s = self.named_s = 0.0
 
     def result(self):
         for k in self.kernels.values():
@@ -358,7 +363,9 @@ class _Reduction(object):
                           if part in self.words})
         return {"scope_s": self.scope_s, "inherited_s": self.inherited_s,
                 "scope_ops": self.scope_ops, "kernels": self.kernels,
-                "move_scan_s": self.move_scan_s, "named_s": self.named_s,
+                "move_scan_s": self.move_scan_s,
+                "move_outside_s": self.move_outside_s,
+                "named_s": self.named_s,
                 "regions": regions, "host": self.host,
                 "instants": self.instants}
 
@@ -408,9 +415,11 @@ class _Reduction(object):
             path = e["label"].split(" ")[0].split("/")
             in_scan = self.scan in region[i].split("/") if any_region \
                 else (path[0] == "while" and len(path) > 1)
-            if in_scan and is_movement(e["show"], e["opcode"],
-                                       self.movement):
-                self.move_scan_s += s
+            if is_movement(e["show"], e["opcode"], self.movement):
+                if in_scan:
+                    self.move_scan_s += s
+                else:
+                    self.move_outside_s += s
 
     def _count_kernel(self, kernel, site, parent, instruction, i, e, s):
         k = self.kernels.setdefault(
@@ -560,5 +569,6 @@ def of_run(run):
                      inherited_s=reduced["inherited_s"],
                      kernels=reduced["kernels"], host=reduced["host"],
                      named_s=reduced["named_s"],
-                     move_scan_s=reduced["move_scan_s"])
+                     move_scan_s=reduced["move_scan_s"],
+                     move_outside_s=reduced["move_outside_s"])
     return _CACHE[key]

@@ -7,7 +7,8 @@ Reads the ``.xplane.pb`` the JAX profiler writes with
 numbers the same way, and ``tests/benchmark/test_trace_reduce.py`` checks the
 arithmetic on a hand-built trace.
 
-What is read, by name (``kernel_names.json`` holds the patterns):
+What is read, by name (``kernel_names.json`` holds the patterns, and every
+``names/*.json`` under ``paths`` adds a family's: ``merge_names``):
 
 - device planes ``/device:TPU:<n>``; on each, the line ``XLA Ops``: what the
   core executes, one operation at a time. Events nest (a ``while`` holds its
@@ -19,15 +20,14 @@ What is read, by name (``kernel_names.json`` holds the patterns):
   (``%closed_call.7 = bf16[..] custom-call(..), custom_call_target="tpu_custom_call"``).
   It is cut down to a LABEL, ``<path> <opcode> <target>``, where the path is
   the instruction's name under its parents' with the numbering dropped
-  (``while/closed_call custom-call tpu_custom_call``): kernels have no names
-  of their own yet, so where a call sits is what tells a decode kernel (in
-  the scan) from a flash kernel. The patterns match labels.
+  (``while/paged_decode custom-call tpu_custom_call``). The patterns match
+  labels; a class of kernels is told by the kernel's own name, the last
+  part of the path (``pallas_call(name=...)`` names the custom call).
 - host planes: every event whose name looks like a span (``a/b``, as
   ``jax.profiler.TraceAnnotation`` writes them), whatever thread it is on.
 """
 
 import glob
-import json
 import os
 import re
 
@@ -35,9 +35,54 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 _SUFFIX = re.compile(r"[._]\d+$")
 
 
+# What a names file may hold: lists that are united, and tables (class ->
+# patterns) that are added to. Keys ending in ``why`` are prose.
+_NAME_LISTS = ("scopes", "kernels", "movement", "collective", "control_flow")
+_NAME_TABLES = ("classes",)
+
+
+def merge_names(base_file):
+    """``base_file`` (one of this directory's two names files) with what
+    every ``names/*.json`` under ``paths`` adds to the keys it has: a list
+    gains the entries it lacks, in the order the files are found; a table
+    gains the entries it lacks, and an entry two files give different
+    content is an error that names both files. A family's words, kernels
+    and classes arrive as such a file (benchmark/README.md)."""
+    from benchmark import harness
+
+    base_path = os.path.join(_HERE, base_file)
+    out = harness.load_json(base_path)
+    owner = {(k, name): base_path for k in _NAME_TABLES if k in out
+             for name in out[k]}
+    for path in harness.find_all("names", ".json"):
+        body = harness.load_json(path)
+        unknown = sorted(k for k in body if not k.endswith("why")
+                         and k not in _NAME_LISTS + _NAME_TABLES)
+        if unknown:
+            raise ValueError("{} has keys no reduction reads: {} (known: {})"
+                             .format(path, unknown,
+                                     _NAME_LISTS + _NAME_TABLES))
+        for key in _NAME_LISTS:
+            if key in out:
+                out[key] += [x for x in body.get(key, [])
+                             if x not in out[key]]
+        for key in _NAME_TABLES:
+            if key not in out:
+                continue
+            for name, content in body.get(key, {}).items():
+                if out[key].setdefault(name, content) != content:
+                    raise ValueError(
+                        "{} {!r} is defined twice with different content: "
+                        "in {} and in {}".format(
+                            key, name, owner[key, name], path))
+                owner.setdefault((key, name), path)
+    return out
+
+
 def kernel_names():
-    with open(os.path.join(_HERE, "kernel_names.json")) as f:
-        return json.load(f)
+    """``kernel_names.json`` with every family's ``classes`` (and
+    collectives) merged in."""
+    return merge_names("kernel_names.json")
 
 
 def find_xplane(trace_dir):

@@ -16,6 +16,15 @@ draws only the tokens (and the weights) and moves each due time by up to
 ``"arrival_jitter_s"``. That is a recorded trace replayed with a little
 jitter: what a cell needs whose window holds too few requests for a median
 over freshly drawn arrivals to repeat (PERF.md section 4).
+
+Where the tokens and the due times come from is found by NAME: a mix may
+give ``"tokens"`` (default ``uniform``) and ``"arrival"`` (default
+``poisson``), each a file ``traffic_sources/<name>.py`` under ``paths``. A
+token source has ``prompts(rng, lengths, vocab_size, mix)`` and ``batches(rng,
+n, batch, seq_len, vocab_size, mix)`` (whichever its kind of traffic draws),
+an arrival process ``arrivals(rng, rate, seconds, sampling, mix)``; each is
+handed the generator this module seeded, so the same ``--seed`` gives the
+same inputs. A later PR adds a source as a file (benchmark/README.md).
 """
 
 import math
@@ -64,6 +73,18 @@ def poisson_arrivals(rng, rate, seconds, sampling):
     return np.asarray(times)
 
 
+def source(name):
+    """The token source or arrival process ``name``: the module
+    ``traffic_sources/<name>.py`` under ``paths``."""
+    from benchmark import harness
+
+    try:
+        return harness.load_by_name("traffic_sources", name)
+    except FileNotFoundError as e:
+        raise ValueError("unknown token source or arrival process {!r}: {}"
+                         .format(name, e))
+
+
 def requests(seed, stream, n, traffic, vocab_size):
     """``n`` requests of a serving mix: a list of (prompt tokens int32,
     max_new_tokens). ``stream`` separates the warm-up, the window and the
@@ -73,20 +94,20 @@ def requests(seed, stream, n, traffic, vocab_size):
     sampling = traffic.get("sampling", "iid")
     p_lens = lognormal_lengths(rng, n, traffic["prompt"], sampling)
     o_lens = lognormal_lengths(rng, n, traffic["output"], sampling)
-    tokens = np.random.RandomState([int(seed), int(stream), 4])
-    return [(tokens.randint(0, vocab_size, size=(int(p),)).astype(np.int32),
-             int(o)) for p, o in zip(p_lens, o_lens)]
+    prompts = source(traffic.get("tokens", "uniform")).prompts(
+        np.random.RandomState([int(seed), int(stream), 4]), p_lens,
+        vocab_size, traffic)
+    return [(np.asarray(p, np.int32), int(o))
+            for p, o in zip(prompts, o_lens)]
 
 
 def arrivals(seed, stream, seconds, traffic):
     """Due times of an open-loop mix over ``seconds`` seconds."""
     schedule = traffic.get("schedule_seed", seed)
     rng = np.random.RandomState([int(schedule), int(stream), 2])
-    if traffic["arrival"] != "poisson":
-        raise ValueError("unknown arrival process {!r}".format(
-            traffic["arrival"]))
-    due = poisson_arrivals(rng, float(traffic["rate"]), float(seconds),
-                           traffic.get("sampling", "iid"))
+    due = np.asarray(source(traffic.get("arrival", "poisson")).arrivals(
+        rng, float(traffic["rate"]), float(seconds),
+        traffic.get("sampling", "iid"), traffic), np.float64)
     jitter = float(traffic.get("arrival_jitter_s", 0.0))
     if jitter:
         due = np.sort(due + np.random.RandomState(
@@ -94,9 +115,10 @@ def arrivals(seed, stream, seconds, traffic):
     return due
 
 
-def token_batches(seed, n, batch, seq_len, vocab_size):
-    """``n`` distinct training batches ``[n, batch, seq_len]`` of uniform
-    tokens."""
-    rng = np.random.RandomState([int(seed), 3])
-    return rng.randint(0, vocab_size, size=(n, batch, seq_len)).astype(
-        np.int32)
+def token_batches(seed, n, batch, seq_len, vocab_size, traffic=None):
+    """``n`` distinct training batches ``[n, batch, seq_len]`` of tokens
+    from the source the mix ``traffic`` names (uniform without one)."""
+    traffic = traffic or {}
+    return np.asarray(source(traffic.get("tokens", "uniform")).batches(
+        np.random.RandomState([int(seed), 3]), n, batch, seq_len,
+        vocab_size, traffic), np.int32)

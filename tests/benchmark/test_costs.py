@@ -51,6 +51,19 @@ def test_decode_counts_by_hand():
     assert seconds == pytest.approx(c["bytes"] / 819e9)
 
 
+def test_decode_bytes_are_what_the_builder_says_a_token_holds():
+    # GPT-2 355M in bf16: a key and a value for each of 16 heads of 64
+    plain = costs.decode_attention_cost([100, 300], heads=16, head_dim=64)
+    assert costs.decode_attention_cost(
+        [100, 300], 16, 64, kv_bytes_per_token=2 * 16 * 64 * 2) == plain
+    # four key/value heads under sixteen query heads: a quarter of the
+    # bytes, the same operations
+    grouped = costs.decode_attention_cost(
+        [100, 300], 16, 64, kv_bytes_per_token=2 * 4 * 64 * 2)
+    assert grouped["bytes"] * 4 == plain["bytes"]
+    assert grouped["flops"] == plain["flops"]
+
+
 def test_peaks_are_keyed_by_device_kind_and_unknown_is_an_error():
     row = costs.device_peaks("TPU v5 lite")
     assert row["flops_per_s"] == 197e12 and row["hbm_bytes_per_s"] == 819e9

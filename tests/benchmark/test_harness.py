@@ -9,14 +9,10 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
 from benchmark import costs, harness
 from tests.benchmark import tiny
-
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
-DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
 @pytest.fixture
@@ -25,64 +21,22 @@ def cpu_peaks(monkeypatch):
     monkeypatch.setattr(costs, "device_peaks", lambda kind: tiny.CPU_PEAKS)
 
 
-def _run(manifest, workload, trace, seconds=1.0, seed=3):
-    result = harness.run_cell(manifest, workload, seed, seconds, trace,
-                              jax.devices(),
-                              trace_names=tiny.cpu_trace_names())
-    json.dumps(result)  # the last line must serialise as it is
-    return result
+@pytest.mark.parametrize("standin", tiny.cases("untraced"))
+def test_untraced_run_reports_the_cells_end_to_end_metrics(standin):
+    tiny.check_untraced(tiny.manifest(), standin)
 
 
-def _reported(manifest, workload, section):
-    return {m["name"] for m in manifest[section]
-            if "workloads" not in m or workload in m["workloads"]}
-
-
-@pytest.mark.parametrize("workload", [
-    "train-tiny", "serve-tiny-closed", "serve-tiny-open"])
-def test_untraced_run_reports_the_cells_end_to_end_metrics(workload):
-    manifest = tiny.manifest()
-    result = _run(manifest, workload, trace=0)
-    assert set(result) == RESULT_KEYS
-    assert set(result["device"]) == DEVICE_KEYS
-    assert result["correct"] is True and result["failed"] == 0
-    assert result["attempted"] > 0
-    assert set(result["metrics"]) == _reported(manifest, workload,
-                                               "end_to_end")
-    for name, reading in result["metrics"].items():
-        assert set(reading) == {"value", "unit"} and reading["value"] > 0
-    assert result["device"]["platform"] == "cpu"
-
-
-@pytest.mark.parametrize("workload, absent", [
-    # flash kernels run interpreted on the CPU, so there is none to time;
-    ("train-tiny-dp4", {"flash_roofline", "train.peak_hbm_gib"}),
-    ("serve-tiny-closed", {"decode.decode_attn_roofline",
-                           "decode.peak_hbm_gib"}),
-    ("serve-tiny-open", {"chat.decode_attn_roofline", "chat.peak_hbm_gib"}),
-])
+@pytest.mark.parametrize("standin", tiny.cases("traced"))
 def test_traced_run_reports_per_layer_metrics_and_a_breakdown(
-        workload, absent, cpu_peaks):
-    manifest = tiny.manifest()
-    result = _run(manifest, workload, trace=1)
-    assert set(result) == RESULT_KEYS | {"breakdown"}
-    assert set(result["device"]) == DEVICE_KEYS | {"busy_s", "window_s"}
-    assert 0 < result["device"]["busy_s"] <= result["device"]["window_s"]
+        standin, cpu_peaks):
+    tiny.check_traced(tiny.manifest(), standin)
+
+
+@pytest.mark.parametrize("standin", tiny.cases("sharded"))
+def test_zero_cell_checks_that_the_moments_are_sharded(standin, capsys):
+    result = tiny.run(tiny.manifest(), standin["cell"], trace=0)
     assert result["correct"] is True
-    # A reader that finds nothing to read returns nothing, and the harness
-    # leaves that metric out of the line.
-    assert set(result["metrics"]) == \
-        _reported(manifest, workload, "per_layer") - absent
-    assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
-    for rows in result["breakdown"].values():
-        assert 0 < len(rows) <= 10
-        assert all(isinstance(n, str) and s >= 0 for n, s in rows)
-
-
-def test_zero_cell_checks_that_the_moments_are_sharded(capsys):
-    manifest = tiny.manifest()
-    result = _run(manifest, "train-tiny-dp4", trace=0)
-    assert result["correct"] is True and result["device"]["count"] >= 4
+    assert result["device"]["count"] >= standin["chips"] > 1
     closed = [json.loads(line) for line in capsys.readouterr().out.splitlines()
               if '"window_closed"' in line][-1]
     assert closed["checks"]["moments_sharded"] is True
@@ -95,6 +49,7 @@ def test_a_fifth_cell_is_only_new_files_and_entries(cpu_peaks):
     configuration, one traffic mix, one cell and one per-layer metric, each
     a new file under ``paths`` and a new entry; no existing file edited."""
     manifest = copy.deepcopy(harness.load_json(harness.MANIFEST))
+    cells_before = len(manifest["workloads"])
     manifest["configs"].append({
         "name": "gpt2-tiny-3layer", "source": "tests only", "reduced": [],
         "file": "tests/benchmark/configs/gpt2-tiny-3layer.json",
@@ -109,12 +64,12 @@ def test_a_fifth_cell_is_only_new_files_and_entries(cpu_peaks):
         "name": "steps_counted", "unit": "steps", "better": "higher",
         "source": "program_counter", "layer": "training engine",
         "moves": "train_tok_s_chip", "workloads": ["train-tiny-fifth"]})
-    assert len(manifest["workloads"]) == 5
+    assert len(manifest["workloads"]) == cells_before + 1
 
-    plain = _run(manifest, "train-tiny-fifth", trace=0)
+    plain = tiny.run(manifest, "train-tiny-fifth", trace=0)
     assert plain["correct"] is True
     assert set(plain["metrics"]) == {"train_tok_s_chip", "setup_s"}
-    traced = _run(manifest, "train-tiny-fifth", trace=1)
+    traced = tiny.run(manifest, "train-tiny-fifth", trace=1)
     assert set(traced["metrics"]) == {"steps_counted"}
     assert traced["metrics"]["steps_counted"] == {
         "value": float(traced["attempted"]), "unit": "steps"}

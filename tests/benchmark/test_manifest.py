@@ -7,6 +7,7 @@ import re
 import pytest
 
 from benchmark import harness
+from tests.benchmark import tiny
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -66,13 +67,16 @@ def test_every_named_file_exists_under_paths(manifest):
         assert body["reduced"] == config["reduced"]
     for row in manifest["workloads"]:
         cell = harness.Cell(manifest, row["name"])
-        assert cell.traffic["kind"] in ("train", "serve")
+        # each is found by name under ``paths``, as the harness finds it
+        harness._find(manifest["paths"], "drivers",
+                      cell.traffic["kind"] + ".py")
+        harness._find(manifest["paths"], "model_builders",
+                      cell.config["model_type"] + ".py")
         assert cell.config["deployment"]["chips"] == row["chips"] or \
             row["chips"] == 1
     for metric in manifest["per_layer"]:
-        reader = metric["name"].rsplit(".", 1)[-1] + ".py"
-        assert os.path.exists(os.path.join(
-            harness.ROOT, "benchmark", "layer_metrics", reader)), reader
+        harness._find(manifest["paths"], "layer_metrics",
+                      metric["name"].rsplit(".", 1)[-1] + ".py")
 
 
 def test_every_cell_reports_what_the_contract_asks(manifest):
@@ -93,7 +97,49 @@ def test_every_cell_reports_what_the_contract_asks(manifest):
         assert _cells_of(manifest, metric) <= _cells_of(manifest, moved)
 
 
+def _spelled_alike(layer):
+    """What two spellings of one layer share: case, spacing, hyphens and a
+    plural do not make another layer."""
+    return [w[:-1] if w.endswith("s") else w
+            for w in re.split(r"[\s_-]+", layer.strip().lower())]
+
+
 def test_one_layer_one_spelling(manifest):
-    assert {m["layer"] for m in manifest["per_layer"]} == {
-        "load generator", "training engine", "sharding", "serving engine",
-        "kernels", "device"}
+    """A layer is spelled once: a new layer is welcome (``PERF.md`` lists
+    it), a second spelling of one that is there is not."""
+    spellings = {}
+    for metric in manifest["per_layer"]:
+        assert metric["layer"] == metric["layer"].strip() \
+            and "\n" not in metric["layer"]
+        spellings.setdefault(tuple(_spelled_alike(metric["layer"])),
+                             set()).add(metric["layer"])
+    twice = [sorted(s) for s in spellings.values() if len(s) > 1]
+    assert not twice, "one layer under two spellings: {}".format(twice)
+    assert _spelled_alike("Serving  engines") == \
+        _spelled_alike("serving-engine") == ["serving", "engine"]
+
+
+def test_every_cell_has_a_stand_in(manifest):
+    """The tests run a cell through its tiny stand-in, one file a cell; a
+    cell without one is run by no test."""
+    found = tiny.standins()
+    missing = [tiny.standin_file(w["name"]) for w in manifest["workloads"]
+               if w["name"] not in found]
+    assert not missing, (
+        "a cell of BENCHMARK.json has no stand-in: add {} (benchmark/"
+        "README.md, \"Adding a model family and its cell\")".format(
+            ", ".join(missing)))
+    for cell in manifest["workloads"]:
+        check_stand_in(manifest, cell, found[cell["name"]])
+
+
+def check_stand_in(manifest, cell, standin):
+    """A stand-in against the cell it stands for."""
+    assert standin["chips"] == cell["chips"], standin["file"]
+    assert standin["cases"], standin["file"]
+    # what a CPU cannot report is a metric of the cell, with a reason
+    mine = {m["name"] for m in manifest["per_layer"]
+            if cell["name"] in _cells_of(manifest, m)}
+    assert set(standin["absent_on_cpu"]) <= mine, standin["file"]
+    assert all(standin["absent_on_cpu"].values()), standin["file"]
+    assert set(standin.get("traced_readings", {})) <= mine, standin["file"]

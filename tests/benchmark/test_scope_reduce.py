@@ -237,6 +237,8 @@ def test_pure_data_movement_inside_the_scan(chip):
     # copy.5 (by nesting under the scan) and slice_bitcast_fusion.2; not
     # fusion.9 (it may compute), not copy.7 (outside the scan)
     assert chip["move_scan_s"] == pytest.approx(7 * US)
+    # copy.7, where the step leaves: what step_move_time_pct reads
+    assert chip["move_outside_s"] == pytest.approx(2 * US)
     assert sr.under(chip["scope_s"], "decode_scan") == pytest.approx(20 * US)
     assert sr.under(chip["scope_s"], "prefill_lane") == pytest.approx(8 * US)
     assert sr.under(chip["scope_s"], "lm_head") == 0
@@ -248,7 +250,7 @@ def test_an_interpreted_kernel_is_found_by_its_scope_and_counted(cpu):
         "flash_fwd": {"s": pytest.approx(14 * US), "calls": 2}}
     assert cpu["scope_s"] == pytest.approx(
         {"block/attn": 14 * US, "lm_head": 5 * US})
-    assert cpu["move_scan_s"] == 0
+    assert cpu["move_scan_s"] == 0 and cpu["move_outside_s"] == 0
 
 
 def test_host_spans_give_self_time(chip):
@@ -342,11 +344,32 @@ def test_regions_are_cut_from_op_names(op_name, parts, region):
     assert sr.scope_path(parts, set(sr.scope_names()["scopes"])) == region
 
 
+@pytest.mark.parametrize("parts, instruction, kernel", [
+    # off the chip: a scope of the op_name; on it: the instruction's name
+    (["mixed_step", "decode_scan", "while", "body", "attn", "paged_decode",
+      "pallas_call"], "custom-call.3", "paged_decode"),
+    ([], "prefill_attn.7", "prefill_attn"),
+    ([], "paged_decode_q8.2", "paged_decode_q8"),
+    (["train_step", "block", "attn", "flash_bwd_fused", "while"], "while.4",
+     "flash_bwd_fused"),
+    (["mixed_step", "decode_scan", "mlp", "dot_general"], "fusion.9", None),
+])
+def test_a_kernel_is_found_by_the_start_of_its_name(parts, instruction,
+                                                    kernel):
+    import re
+
+    found, _ = sr.kernel_name(parts, instruction,
+                              re.compile(sr.scope_names()["kernel"]))
+    assert found == kernel
+
+
 @pytest.mark.parametrize("show, opcode, moves", [
     ("copy", "copy", True), ("slice_bitcast_fusion", "fusion", True),
     ("bitcast_dynamic-update-slice_fusion", "fusion", True),
     ("fusion", "fusion", False), ("convert_reduce_fusion", "fusion", False),
     ("paged_decode (custom-call)", "custom-call", False),
+    # the wait for an asynchronous slice is not counted as movement
+    ("slice-done (async-done)", "async-done", False),
 ])
 def test_what_counts_as_pure_data_movement(show, opcode, moves):
     assert sr.is_movement(show, opcode,
@@ -389,30 +412,12 @@ def _traced(workload, monkeypatch):
     return _RUNS[workload]
 
 
-PCT, MS = (0.0, 100.0), (0.0, 60e3)
-# The CPU "device" is one plane whose four virtual devices run on threads of
-# their own: self times add up across threads while busy time is their
-# union, so a share can pass 100 there (on the chip a plane is one core).
-CPU_MESH_PCT = (0.0, 100.0 * 8)
-
-
-@pytest.mark.parametrize("workload, metric, low_high", [
-    ("train-tiny-dp4", "flash_fwd_roofline", (0.0, 1e4)),
-    ("train-tiny-dp4", "flash_bwd_roofline", (0.0, 1e4)),
-    ("train-tiny-dp4", "lm_head_time_pct", CPU_MESH_PCT),
-    ("train-tiny-dp4", "optimizer_time_pct", CPU_MESH_PCT),
-    ("train-tiny-dp4", "train_host_ms_step", MS),
-    ("serve-tiny-closed", "decode.kv_move_time_pct", PCT),
-    ("serve-tiny-closed", "decode.host_ms_step", MS),
-    ("serve-tiny-open", "chat.kv_move_time_pct", PCT),
-    ("serve-tiny-open", "chat.prefill_lane_time_pct", PCT),
-    ("serve-tiny-open", "chat.host_ms_step", MS),
-    ("serve-tiny-open", "chat.queue_wait_p50_ms", MS),
-    ("serve-tiny-open", "chat.prefill_p50_ms", MS),
-])
-def test_each_new_reader_reports_on_the_tiny_cells(workload, metric,
+@pytest.mark.parametrize("standin, metric, low_high", tiny.reading_cases())
+def test_each_new_reader_reports_on_the_tiny_cells(standin, metric,
                                                    low_high, monkeypatch):
-    result = _traced(workload, monkeypatch)
+    """Every reader of ``scope_reduce`` lands in the range the cell's
+    stand-in gives it (``traced_readings``), traced on the CPU."""
+    result = _traced(standin["cell"], monkeypatch)
     assert result["correct"] is True
     value = result["metrics"][metric]["value"]
     low, high = low_high

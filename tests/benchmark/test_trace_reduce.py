@@ -5,7 +5,7 @@ The trace, in microseconds on the profiler's clock (one chip):
     host    bench/window   0 ................................. 20
             bench/submit                        11.5-12.5
     XLA Ops while.3         1 ............. 11
-              closed_call.9   2-4   (Pallas, in the scan: decode kernel)
+              paged_decode.9  2-4   (Pallas, in the scan: decode kernel)
               copy.12             5 - 8
             fusion.7                              13 - 15
     Async   all-gather-start.1        7 ............. 14
@@ -32,7 +32,7 @@ planes { id: 1 name: "/device:TPU:0"
     events { metadata_id: 7 offset_ps: 0 duration_ps: 19000000 }
   }
   event_metadata { key: 1 value { id: 1 name: "%while.3 = (s32[]{:T(128)}, bf16[4]{0:T(8,128)(2,1)}) while((s32[]{:T(128)}, bf16[4]{0}) %tuple.1), condition=%c, body=%b" } }
-  event_metadata { key: 2 value { id: 2 name: "%closed_call.9 = bf16[4]{0:T(8,128)(2,1)S(1)} custom-call(bf16[4]{0} %x), custom_call_target=\\"tpu_custom_call\\", frontend_attributes={kernel_metadata={}}" } }
+  event_metadata { key: 2 value { id: 2 name: "%paged_decode.9 = bf16[4]{0:T(8,128)(2,1)S(1)} custom-call(bf16[4]{0} %x), custom_call_target=\\"tpu_custom_call\\", frontend_attributes={kernel_metadata={}}" } }
   event_metadata { key: 3 value { id: 3 name: "%copy.12 = bf16[4]{0:T(8,128)(2,1)} copy(bf16[4]{0} %x)" } }
   event_metadata { key: 4 value { id: 4 name: "%fusion.7 = bf16[4]{0} fusion(bf16[4]{0} %x), kind=kLoop, calls=%f" } }
   event_metadata { key: 5 value { id: 5 name: "%all-gather-start.1 = (f32[4]{0}, f32[16]{0}) all-gather-start(f32[4]{0} %p), dimensions={0}" } }
@@ -69,14 +69,37 @@ def test_busy_idle_and_window(reduced):
 def test_self_time_by_name(reduced):
     assert reduced["op_s"] == pytest.approx({
         "while": 5e-6,                       # 10 - (2 + 3)
-        "closed_call (custom-call)": 2e-6,
+        "paged_decode (custom-call)": 2e-6,
         "copy": 3e-6, "fusion": 2e-6})
     assert reduced["device_ops"][0] == ["while", pytest.approx(5e-6)]
 
 
-def test_kernels_are_told_by_where_they_sit(reduced):
+def test_kernels_are_told_by_their_names(reduced):
     assert reduced["classes"] == pytest.approx(
         {"pallas": 2e-6, "decode_attn": 2e-6})
+
+
+@pytest.mark.parametrize("label, classes", [
+    ("while/paged_decode custom-call tpu_custom_call",
+     {"pallas", "decode_attn"}),
+    ("while/decode_attn_q8 custom-call tpu_custom_call",
+     {"pallas", "decode_attn"}),
+    # the frontier write runs in the same scan and is no decode kernel
+    ("while/kv_append custom-call tpu_custom_call", {"pallas"}),
+    ("conditional/prefill_attn custom-call tpu_custom_call", {"pallas"}),
+    # a Pallas call without a name of its own is in no class but pallas
+    ("while/closed_call custom-call tpu_custom_call", {"pallas"}),
+    ("closed_call custom-call tpu_custom_call", {"pallas"}),
+    ("flash_fwd custom-call tpu_custom_call", {"pallas", "flash"}),
+    ("call/flash_bwd_fused custom-call tpu_custom_call",
+     {"pallas", "flash"}),
+    ("flash_fwd/fusion fusion", set()),
+    ("while/fusion fusion", set()),
+])
+def test_a_class_is_told_by_the_kernels_name_alone(label, classes):
+    table = tr.kernel_names()["classes"]
+    assert {c for c in ("pallas", "flash", "decode_attn")
+            if tr._matches(table[c], label)} == classes
 
 
 def test_collective_time_and_the_part_nothing_hides(reduced):

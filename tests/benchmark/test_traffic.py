@@ -1,10 +1,13 @@
 """The one generator: repeats for a seed, differs across seeds, and under
 stratified sampling offers every seed the same amount of work."""
 
+import hashlib
+import os
+
 import numpy as np
 import pytest
 
-from benchmark import traffic
+from benchmark import harness, traffic
 
 MIX = {"arrival": "poisson", "rate": 0.5,
        "prompt": {"median": 96, "sigma": 0.8, "min": 16, "max": 384},
@@ -92,3 +95,60 @@ def test_a_fixed_schedule_is_replayed_with_seeded_jitter_and_new_tokens():
     assert [(len(p), o) for p, o in reqs_a] == \
         [(len(p), o) for p, o in reqs_b]
     assert not np.array_equal(reqs_a[0][0], reqs_b[0][0])
+
+
+def _digest(*arrays):
+    h = hashlib.sha256()
+    for a in arrays:
+        a = np.ascontiguousarray(a)
+        h.update(str(a.dtype).encode() + str(a.shape).encode() + a.tobytes())
+    return h.hexdigest()
+
+
+def _requests_digest(reqs):
+    return _digest(*[p for p, _ in reqs],
+                   np.asarray([o for _, o in reqs], np.int64))
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_the_four_mixes_draw_what_they_drew_before_sources_had_names(seed):
+    """``traffic_recorded.json`` holds digests of what the generator gave
+    for the benchmark's four mixes, called as the drivers call it, at the
+    commit before token sources and arrival processes were found by name
+    (PR 26): ``uniform`` and ``poisson`` are the same draws, so no cell's
+    inputs moved."""
+    want = harness.load_json(os.path.join(
+        harness.ROOT, "tests/benchmark/traffic_recorded.json"))[str(seed)]
+    vocab = 50257
+
+    def mix(name):
+        return harness.load_json(os.path.join(
+            harness.ROOT, "benchmark/workloads", name + ".json"))
+
+    closed = mix("decode-closed")
+    reqs = traffic.requests(seed, 1, closed["request_pool"], closed, vocab)
+    assert _requests_digest(reqs) == want["decode-closed"]["requests"]
+    assert reqs[0][0][:4].tolist() == \
+        want["decode-closed"]["first_prompt_head"]
+    chat = mix("chat-open")
+    for stream, seconds in ((0, chat["warmup_s"]), (1, 51),
+                            (2, chat["trace_tail_s"])):
+        due = traffic.arrivals(seed, stream, seconds, chat)
+        recorded = want["chat-open"][str(stream)]
+        assert len(due) == recorded["n"] and due[0] == recorded["first_due"]
+        assert _digest(np.asarray(due, np.float64)) == recorded["arrivals"]
+        assert _requests_digest(traffic.requests(
+            seed, stream, len(due), chat, vocab)) == recorded["requests"]
+    for name, chips in (("pretrain-t1024-b16", 1),
+                        ("pretrain-t1024-b4-dp4", 4)):
+        train = mix(name)
+        batches = traffic.token_batches(
+            seed, 2, train["batch_per_chip"] * chips, train["seq_len"],
+            vocab, train)
+        assert _digest(batches) == want[name]["batches"]
+        assert batches[0, 0, :4].tolist() == want[name]["head"]
+
+
+def test_an_unknown_source_is_refused():
+    with pytest.raises(ValueError, match="unknown token source or arrival"):
+        traffic.arrivals(1, 1, 10, dict(MIX, arrival="no_such_process"))
