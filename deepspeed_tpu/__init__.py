@@ -110,9 +110,11 @@ def init_inference(model=None, params=None, config=None, mesh=None):
     ``deepspeed.init_inference`` shape, which v0.3.10 does not have —
     its only inference surface is pipelined eval_batch).
 
-    ``model`` is a GPT2LMHeadModel (or its config); ``params`` the trained
-    pytree. ``config`` may be an ``InferenceConfig``, a bare ``inference``
-    block dict, a full ds_config dict carrying an ``"inference"`` key, or
+    ``model`` is a GPT2LMHeadModel or a ``models.decoder.DecoderLM`` (or
+    the config of either): the engine serves it through the adapter of its
+    class (``inference.adapters.adapter_class_for``). ``params`` is the
+    trained pytree. ``config`` may be an ``InferenceConfig``, a bare
+    ``inference`` block dict, a full ds_config dict carrying an ``"inference"`` key, or
     a parsed ``DeepSpeedConfig``. Extra TPU-only kwarg: ``mesh`` — pass a
     mesh with a 'model' axis to serve a tensor-sharded model.
 

@@ -121,7 +121,7 @@ from deepspeed_tpu.inference.kv_pool import (
     write_slot_cache,
 )
 from deepspeed_tpu.inference.paging import PageAllocator
-from deepspeed_tpu.inference.adapters import GPT2Adapter
+from deepspeed_tpu.inference.adapters import adapter_class_for
 from deepspeed_tpu.inference.scheduler import QueueFull, Scheduler
 from deepspeed_tpu.ops.transformer.kernels.attention import kernels_on_mesh
 from deepspeed_tpu.parallel import mesh as mesh_lib
@@ -507,16 +507,17 @@ class InferenceEngine(object):
             config = InferenceConfig.from_dict(config)
         self.config = config
         # The engine<->model boundary is the ModelAdapter protocol
-        # (inference/adapters): None builds the GPT-2 adapter over the
-        # model's config — the engine's use_flash_decode wins over the
-        # model config's, None defers down the chain (model config, then
-        # on-TPU default). ``bind`` lets any adapter specialize to this
+        # (inference/adapters): None builds the adapter of the model's
+        # own class (``adapter_class_for``: a DecoderLM's DecoderAdapter,
+        # else GPT-2's) over the model's config — the engine's
+        # use_flash_decode wins over the model config's, None defers
+        # down the chain (model config, then on-TPU default). ``bind`` lets any adapter specialize to this
         # engine's config and mesh (sparse/ring mode, expert parallelism).
         # The adapter IS the static arg of every jitted program, so the
         # model dispatch is baked at trace time — no per-call branching,
         # and the compile-count contract is per (engine, adapter).
         if adapter is None:
-            adapter = GPT2Adapter.from_model(
+            adapter = adapter_class_for(model).from_model(
                 model, use_flash_decode=config.use_flash_decode)
         self._adapter = adapter.bind(config, mesh)
         # The adapter's cache spec drives every shape downstream: pool

@@ -126,183 +126,177 @@ def _dense(x, p):
             p["bias"].astype(x.dtype))
 
 
-@hot_path
-def _forward(params, cfg, ids, cache, last_only=False, attn_name=None):
-    """ids [B, S], row b starting at cache['pos'][b]; returns
-    (logits [B, S, V] fp32, updated cache). S=prompt_len for prefill, S=1
-    inside the decode scan. Positions are PER ROW: each row embeds, masks
-    and writes its k/v against its own frontier, so rows at different
-    sequence lengths (the serving engine's slots) share one program.
-    ``last_only`` evaluates the LM head on the final position only (the
-    prefill path — sampling reads just that row, and a [B, Tp, vocab]
-    fp32 buffer would otherwise dominate prefill memory). ``attn_name``
-    names the attention kernel in a trace where the caller is not the
-    decode lane (``append_forward``: ``prefill_attn``).
+class CacheAttention(object):
+    """The cache side of one forward pass over ``ids`` [B, S]: where each
+    layer's new keys and values are written, what attention reads, and the
+    attention itself. Built once a pass from the cache dict, then called
+    once a layer with that layer's ``q, k, v`` [B, H, S, D]; GPT-2's block
+    (``_forward``) and the config-driven decoder block (``models/decoder``)
+    share it, so a cache layout or a kernel is wired in one place.
 
-    The regions of a trace (``jax.named_scope``, under the caller's
-    ``prefill_lane`` / ``decode_scan``): ``embed``, then per layer ``attn``
-    (LayerNorm, qkv, attention, projection), ``kv_write`` (the frontier
-    write into the planes), ``kv_view`` (the planes attention reads: the
-    page gather, the prefix select) and ``mlp``, then ``lm_head``.
+    The regions of a trace it owns (``jax.named_scope``): ``kv_write`` (the
+    frontier write into the planes), ``kv_view`` (the planes attention
+    reads: the page gather, the prefix select) and the attention proper
+    under ``attn``.
 
     WHERE THE FRONTIER WRITE HAPPENS. A paged pool whose page is a kernel
     block (``"block_tbl" in cache`` and ``decode_supported(page_len)``
     under ``use_flash_decode``: what the chip serves) is written IN PLACE
     by the ``kv_append`` kernel and read by the paged decode kernel with
-    the layer in its index map; the arenas pass through this function
-    whole, and no per-layer value of them is formed (``kv_write`` holds
-    the kernel, ``kv_view`` nothing). Every other cache — a paged pool
-    with smaller pages, the dense slot pool, ``generate()``'s own cache —
-    takes ``cache.at[i].set(write(cache[i], new))`` and, on the chip,
-    pays XLA's slice, scatter and update of a whole layer for it.
+    the layer in its index map; the arenas pass through whole, and no
+    per-layer value of them is formed (``kv_write`` holds the kernel,
+    ``kv_view`` nothing). Every other cache (a paged pool with smaller
+    pages, the dense slot pool, ``generate()``'s own cache) takes
+    ``cache.at[i].set(write(cache[i], new))`` and, on the chip, pays XLA's
+    slice, scatter and update of a whole layer for it.
 
     KV-hierarchy dispatch is DATA-DRIVEN off the cache dict
     (inference/kv_hierarchy): an int8 ``k`` plane means frontier writes
     quantize (codes + per-(head, position) ``k_scale``/``v_scale``) and
-    attention dequantizes — in-block in the q8 flash kernel, before the
+    attention dequantizes, in-block in the q8 flash kernel, before the
     einsum otherwise; a ``pk`` key means each row's positions
     ``< pbase[b]`` resolve to its aliased read-only prefix plane via a
-    per-position SELECT. The select is elementwise — no arithmetic — and
+    per-position SELECT. The select is elementwise (no arithmetic) and
     the prefix entries are bit-identical to what the row's own prefill
     would have written (causality: position p's k/v depend only on
     tokens <= p, which match by construction), so aliased and private
     greedy streams are bit-identical. A plain cache hits neither branch
     and lowers exactly as before."""
-    B, S = ids.shape
-    nh, hd = cfg.n_head, cfg.n_embd // cfg.n_head
-    pos = cache["pos"]                                 # [B] row frontiers
-    int8 = cache["k"].dtype == jnp.int8
-    has_prefix = "pk" in cache
-    # PAGED dispatch (inference/kv_pool.py paged layout): a block table
-    # means k/v are a page ARENA [L, P, H, page_len, D] and row b's
-    # logical plane is the concatenation of its table's pages. Writes
-    # go through the table (an XLA scatter, or in place by kv_append);
-    # reads gather through it (or hand the table and the whole arena to
-    # the paged flash kernel). The gathered logical plane is
-    # elementwise equal to what the dense pool holds at every valid
-    # position — trash/unwritten pages are finite garbage the causal
-    # mask zeroes exactly — so streams stay bit-identical to dense.
-    paged = "block_tbl" in cache
-    if paged:
-        assert not has_prefix, "paged pools share prefixes via pages"
-        tbl = cache["block_tbl"]                       # [B, n_lp]
-        page_len = cache["k"].shape[3]
-        n_lp = tbl.shape[1]
-        max_len = n_lp * page_len                      # logical plane len
-        w_pos = pos[:, None] + jnp.arange(S)[None]     # [B, S]
-        w_pg = tbl[jnp.arange(B)[:, None],
-                   jnp.minimum(w_pos // page_len, n_lp - 1)]
-        w_off = w_pos % page_len
-    else:
-        max_len = cache["k"].shape[3]
 
-    eps = cfg.layer_norm_epsilon
-    wte = params["wte"].astype(cfg.dtype)
-    q_pos = pos[:, None] + jnp.arange(S)[None]         # [B, S]
-    pe = params["wpe"].astype(cfg.dtype)[q_pos]        # [B, S, C] gather
-    with jax.named_scope("embed"):
-        x = wte[ids] + pe
+    def __init__(self, cfg, cache, S, attn_name=None):
+        self.cfg, self.cache, self.S = cfg, cache, S
+        self.attn_name = attn_name
+        self.nh, self.hd = cfg.n_head, cfg.n_embd // cfg.n_head
+        B = cache["pos"].shape[0]
+        self.pos = pos = cache["pos"]                  # [B] row frontiers
+        self.int8 = cache["k"].dtype == jnp.int8
+        self.has_prefix = "pk" in cache
+        # PAGED dispatch (inference/kv_pool.py paged layout): a block table
+        # means k/v are a page ARENA [L, P, H, page_len, D] and row b's
+        # logical plane is the concatenation of its table's pages. Writes
+        # go through the table (an XLA scatter, or in place by kv_append);
+        # reads gather through it (or hand the table and the whole arena to
+        # the paged flash kernel). The gathered logical plane is
+        # elementwise equal to what the dense pool holds at every valid
+        # position (trash/unwritten pages are finite garbage the causal
+        # mask zeroes exactly), so streams stay bit-identical to dense.
+        self.paged = paged = "block_tbl" in cache
+        if paged:
+            assert not self.has_prefix, "paged pools share prefixes via pages"
+            self.tbl = tbl = cache["block_tbl"]        # [B, n_lp]
+            self.page_len = page_len = cache["k"].shape[3]
+            self.n_lp = n_lp = tbl.shape[1]
+            self.max_len = n_lp * page_len             # logical plane len
+            w_pos = pos[:, None] + jnp.arange(S)[None]  # [B, S]
+            self.w_pg = tbl[jnp.arange(B)[:, None],
+                            jnp.minimum(w_pos // page_len, n_lp - 1)]
+            self.w_off = w_pos % page_len
+        else:
+            self.max_len = cache["k"].shape[3]
+        self.q_pos = pos[:, None] + jnp.arange(S)[None]  # [B, S]
+        max_len = self.max_len
+        # Flash-decode engages when the flag is on AND the cache plane
+        # length fits the kernel's block quantum (kv_pool pads its pool;
+        # ad-hoc caches of other lengths take the einsum path below, the
+        # same math). Paged pools key on PAGE length instead: kernel
+        # blocks == pages, so the paged kernel engages when one page is a
+        # whole block quantum; smaller pages (CPU-test geometries) gather
+        # + einsum below.
+        self.use_flash = cfg.use_flash_decode and \
+            decode_attention.decode_supported(
+                self.page_len if self.paged else max_len)
+        sparse_thr = getattr(cfg, "sparse_threshold", 0)
+        if sparse_thr and self.use_flash:
+            raise ValueError(
+                "block-sparse decode (sparse_threshold > 0) requires the "
+                "einsum attention path; construct the config with "
+                "use_flash_decode=False")
+        if not self.use_flash:
+            k_pos = jnp.arange(max_len)                # [max_len]
+            # Causal vs each row's GLOBAL position: key j visible to query
+            # i iff j <= i. Cache slots past a row's frontier are excluded
+            # by the same comparison (they hold zeros, or a stale request's
+            # k/v, which decode overwrites before the frontier reaches
+            # them).
+            mask = k_pos[None, None, :] <= self.q_pos[:, :, None]
+            if sparse_thr:
+                # Long-context composition: rows whose query position
+                # crossed the threshold see only the block-sparse layout;
+                # below it the extra term is all-True, leaving the causal
+                # mask bit-identical to the dense path (the parity half of
+                # the adapter contract).
+                blk = cfg.sparse_block
+                nb = -(-max_len // blk)
+                layout = jnp.asarray(_sparse_layout(
+                    blk, cfg.sparse_num_local, cfg.sparse_num_global, nb))
+                q_blk = jnp.minimum(self.q_pos // blk, nb - 1)  # [B, S]
+                visible = layout[q_blk[:, :, None],
+                                 (k_pos // blk)[None, None, :]]
+                mask = mask & ((self.q_pos < sparse_thr)[:, :, None]
+                               | visible)
+            self.mask = mask                           # [B, S, max_len]
+            self.neg = jnp.finfo(jnp.float32).min
+        if self.has_prefix:
+            pbase = cache["pbase"]                     # [B] aliased spans
+            # Select masks against the full plane length; pad positions
+            # can never be selected because pbase <= prefix_len <= max_len.
+            self.psel = jnp.arange(max_len)[None, None, :, None] < \
+                pbase[:, None, None, None]             # [B, 1, T, 1]
+            self.psel_s = self.psel[..., 0]            # [B, 1, T]
+        # What the layers thread: (k, v) or (k, v, k_scale, v_scale).
+        self.planes = (cache["k"], cache["v"]) + (
+            (cache["k_scale"], cache["v_scale"]) if self.int8 else ())
 
-    # Flash-decode engages when the flag is on AND the cache plane length
-    # fits the kernel's block quantum (kv_pool pads its pool; ad-hoc
-    # caches of other lengths take the einsum path below — same math).
-    # Paged pools key on PAGE length instead: kernel blocks == pages, so
-    # the paged kernel engages when one page is a whole block quantum;
-    # smaller pages (CPU-test geometries) gather + einsum below.
-    if paged:
-        use_flash = cfg.use_flash_decode and \
-            decode_attention.decode_supported(page_len)
-    else:
-        use_flash = cfg.use_flash_decode and \
-            decode_attention.decode_supported(max_len)
-    sparse_thr = getattr(cfg, "sparse_threshold", 0)
-    if sparse_thr and use_flash:
-        raise ValueError(
-            "block-sparse decode (sparse_threshold > 0) requires the einsum "
-            "attention path; construct the config with use_flash_decode=False")
-    if not use_flash:
-        k_pos = jnp.arange(max_len)                    # [max_len]
-        # Causal vs each row's GLOBAL position: key j visible to query i
-        # iff j <= i. Cache slots past a row's frontier are excluded by
-        # the same comparison (they hold zeros — or a stale request's
-        # k/v, which decode overwrites before the frontier reaches them).
-        mask = k_pos[None, None, :] <= q_pos[:, :, None]  # [B, S, max_len]
-        if sparse_thr:
-            # Long-context composition: rows whose query position crossed
-            # the threshold see only the block-sparse layout; below it the
-            # extra term is all-True, leaving the causal mask bit-identical
-            # to the dense path (the parity half of the adapter contract).
-            blk = cfg.sparse_block
-            nb = -(-max_len // blk)
-            layout = jnp.asarray(_sparse_layout(
-                blk, cfg.sparse_num_local, cfg.sparse_num_global, nb))
-            q_blk = jnp.minimum(q_pos // blk, nb - 1)    # [B, S]
-            visible = layout[q_blk[:, :, None],
-                             (k_pos // blk)[None, None, :]]
-            mask = mask & ((q_pos < sparse_thr)[:, :, None] | visible)
-        neg = jnp.finfo(jnp.float32).min
-    k_cache, v_cache = cache["k"], cache["v"]
-    if int8:
-        ks_cache, vs_cache = cache["k_scale"], cache["v_scale"]
-    if has_prefix:
-        pbase = cache["pbase"]                         # [B] aliased spans
-        # Select masks against the full plane length; pad positions can
-        # never be selected because pbase <= prefix_len <= max_len.
-        psel = jnp.arange(max_len)[None, None, :, None] < \
-            pbase[:, None, None, None]                 # [B, 1, T, 1]
-        psel_s = psel[..., 0]                          # [B, 1, T]
+    def _pad_prefix(self, p):
+        # [B, H, prefix_len, ...] -> [B, H, max_len, ...]; the pad is
+        # inert (never selected), zeros keep it cheap.
+        if p.shape[2] == self.max_len:
+            return p
+        pad = [(0, 0)] * p.ndim
+        pad[2] = (0, self.max_len - p.shape[2])
+        return jnp.pad(p, pad)
 
-        def pad_prefix(p):
-            # [B, H, prefix_len, ...] -> [B, H, max_len, ...]; the pad
-            # is inert (never selected), zeros keep it cheap.
-            if p.shape[2] == max_len:
-                return p
-            pad = [(0, 0)] * p.ndim
-            pad[2] = (0, max_len - p.shape[2])
-            return jnp.pad(p, pad)
-
-    if paged:
-        def write_rows(arena_l, new):
+    def _write_rows(self, plane_l, new):
+        if self.paged:
             # Page arena [P, H, page_len, D] <- [B, H, S, D] scattered
             # at (page, offset) through the block table. Distinct live
             # positions map to distinct (page, offset) pairs (the table
             # is injective per row outside the trash page), so the
             # scatter is collision-free wherever it is ever read.
-            return arena_l.at[w_pg, :, w_off, :].set(
+            return plane_l.at[self.w_pg, :, self.w_off, :].set(
                 new.transpose(0, 2, 1, 3))
+        # [B, H, T, D] cache plane <- [B, H, S, D] at each row's frontier
+        # (vmapped dynamic_update_slice lowers to one scatter).
+        return jax.vmap(lambda c, n, p: jax.lax.dynamic_update_slice(
+            c, n, (0, p, 0)))(plane_l, new, self.pos)
 
-        def write_scale_rows(arena_l, new):
+    def _write_scale_rows(self, plane_l, new):
+        if self.paged:
             # Scale arena [P, H, page_len] <- [B, H, S] likewise.
-            return arena_l.at[w_pg, :, w_off].set(new.transpose(0, 2, 1))
+            return plane_l.at[self.w_pg, :, self.w_off].set(
+                new.transpose(0, 2, 1))
+        # [B, H, T] scale plane <- [B, H, S] at each row's frontier.
+        return jax.vmap(lambda c, n, p: jax.lax.dynamic_update_slice(
+            c, n, (0, p)))(plane_l, new, self.pos)
 
-        def gather_pages(arena_l):
-            # [P, H, page_len, ...] -> row-major logical planes
-            # [B, H, n_lp * page_len, ...] via one table gather.
-            g = jnp.take(arena_l, tbl, axis=0)         # [B, n_lp, H, p, ...]
-            g = jnp.moveaxis(g, 2, 1)                  # [B, H, n_lp, p, ...]
-            return g.reshape((B, nh, max_len) + g.shape[4:])
-    else:
-        def write_rows(cache_l, new):
-            # [B, H, T, D] cache plane <- [B, H, S, D] at each row's
-            # frontier (vmapped dynamic_update_slice lowers to one
-            # scatter).
-            return jax.vmap(lambda c, n, p: jax.lax.dynamic_update_slice(
-                c, n, (0, p, 0)))(cache_l, new, pos)
+    def _gather_pages(self, arena_l):
+        # [P, H, page_len, ...] -> row-major logical planes
+        # [B, H, n_lp * page_len, ...] via one table gather.
+        g = jnp.take(arena_l, self.tbl, axis=0)        # [B, n_lp, H, p, ...]
+        g = jnp.moveaxis(g, 2, 1)                      # [B, H, n_lp, p, ...]
+        return g.reshape((g.shape[0], self.nh, self.max_len) + g.shape[4:])
 
-        def write_scale_rows(cache_l, new):
-            # [B, H, T] scale plane <- [B, H, S] at each row's frontier.
-            return jax.vmap(lambda c, n, p: jax.lax.dynamic_update_slice(
-                c, n, (0, p)))(cache_l, new, pos)
-
-    for i in range(cfg.n_layer):
-        blk = params["h_{}".format(i)]
-        with jax.named_scope("attn"):
-            h = _ln(x, blk["ln_1"], eps)
-            qkv = _dense(h, blk["attn"]["c_attn"])
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
-            k = k.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
-            v = v.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
+    def __call__(self, i, q, k, v, planes):
+        """Layer ``i``'s attention: write ``k, v`` at the frontiers, read
+        the cache, attend. Returns (y [B, H, S, D], the planes with layer
+        ``i`` written)."""
+        cfg, cache = self.cfg, self.cache
+        int8, paged, use_flash = self.int8, self.paged, self.use_flash
+        pos, hd = self.pos, self.hd
+        if int8:
+            k_cache, v_cache, ks_cache, vs_cache = planes
+        else:
+            k_cache, v_cache = planes
         with jax.named_scope("kv_write"):
             if int8:
                 k, ks = decode_attention.quantize_kv(k)
@@ -316,43 +310,44 @@ def _forward(params, cfg, ids, cache, last_only=False, attn_name=None):
                     k_cache, v_cache, ks_cache, vs_cache = \
                         decode_attention.kv_append(
                             (k_cache, v_cache, ks_cache, vs_cache),
-                            (k, v, ks, vs), tbl, pos, layer=i)
+                            (k, v, ks, vs), self.tbl, pos, layer=i)
                 else:
                     k_cache, v_cache = decode_attention.kv_append(
-                        (k_cache, v_cache), (k, v), tbl, pos, layer=i)
+                        (k_cache, v_cache), (k, v), self.tbl, pos, layer=i)
             else:
-                k_cache = k_cache.at[i].set(write_rows(k_cache[i], k))
-                v_cache = v_cache.at[i].set(write_rows(v_cache[i], v))
+                k_cache = k_cache.at[i].set(self._write_rows(k_cache[i], k))
+                v_cache = v_cache.at[i].set(self._write_rows(v_cache[i], v))
                 if int8:
                     ks_cache = ks_cache.at[i].set(
-                        write_scale_rows(ks_cache[i], ks))
+                        self._write_scale_rows(ks_cache[i], ks))
                     vs_cache = vs_cache.at[i].set(
-                        write_scale_rows(vs_cache[i], vs))
+                        self._write_scale_rows(vs_cache[i], vs))
         with jax.named_scope("kv_view"):
             # Effective planes: the row's own just-written plane, with the
-            # aliased prefix selected in below pbase[b] (codes AND scales —
+            # aliased prefix selected in below pbase[b] (codes AND scales:
             # both tiers compose). Paged rows GATHER their logical plane
             # through the block table AFTER the write (the einsum/reference
             # path; the paged flash kernel gathers in its own index map,
             # layer included, and forms no view at all).
             if paged and not use_flash:
-                k_eff = gather_pages(k_cache[i])
-                v_eff = gather_pages(v_cache[i])
+                k_eff = self._gather_pages(k_cache[i])
+                v_eff = self._gather_pages(v_cache[i])
                 if int8:
-                    ks_eff = gather_pages(ks_cache[i])
-                    vs_eff = gather_pages(vs_cache[i])
+                    ks_eff = self._gather_pages(ks_cache[i])
+                    vs_eff = self._gather_pages(vs_cache[i])
             elif not paged:
                 k_eff, v_eff = k_cache[i], v_cache[i]
                 if int8:
                     ks_eff, vs_eff = ks_cache[i], vs_cache[i]
-            if has_prefix:
-                k_eff = jnp.where(psel, pad_prefix(cache["pk"][i]), k_eff)
-                v_eff = jnp.where(psel, pad_prefix(cache["pv"][i]), v_eff)
+            if self.has_prefix:
+                psel, psel_s, pad = self.psel, self.psel_s, self._pad_prefix
+                k_eff = jnp.where(psel, pad(cache["pk"][i]), k_eff)
+                v_eff = jnp.where(psel, pad(cache["pv"][i]), v_eff)
                 if int8:
                     ks_eff = jnp.where(
-                        psel_s, pad_prefix(cache["pk_scale"][i]), ks_eff)
+                        psel_s, pad(cache["pk_scale"][i]), ks_eff)
                     vs_eff = jnp.where(
-                        psel_s, pad_prefix(cache["pv_scale"][i]), vs_eff)
+                        psel_s, pad(cache["pv_scale"][i]), vs_eff)
         with jax.named_scope("attn"):
             if use_flash:
                 # Fused QK-score + online softmax + PV over the cache plane,
@@ -360,6 +355,7 @@ def _forward(params, cfg, ids, cache, last_only=False, attn_name=None):
                 # cache was just written, so pos is the PRE-write frontier
                 # the kernel's mask convention expects. The q8 family
                 # dequantizes in-block from codes + scales.
+                scale = 1.0 / float(hd) ** 0.5
                 if paged:
                     # Block-table flash decode: the kernel's scalar-prefetch
                     # index map resolves (row, block j) -> arena page, so
@@ -369,22 +365,21 @@ def _forward(params, cfg, ids, cache, last_only=False, attn_name=None):
                     # the page's address.
                     if int8:
                         y = decode_attention.flash_decode_attention_paged_q8(
-                            q, k_cache, v_cache, ks_cache, vs_cache, tbl,
-                            pos, scale=1.0 / float(hd) ** 0.5,
-                            name=attn_name, layer=i)
+                            q, k_cache, v_cache, ks_cache, vs_cache,
+                            self.tbl, pos, scale=scale,
+                            name=self.attn_name, layer=i)
                     else:
                         y = decode_attention.flash_decode_attention_paged(
-                            q, k_cache, v_cache, tbl, pos,
-                            scale=1.0 / float(hd) ** 0.5, name=attn_name,
-                            layer=i)
+                            q, k_cache, v_cache, self.tbl, pos,
+                            scale=scale, name=self.attn_name, layer=i)
                 elif int8:
                     y = decode_attention.flash_decode_attention_q8(
                         q, k_eff, v_eff, ks_eff, vs_eff, pos,
-                        scale=1.0 / float(hd) ** 0.5, name=attn_name)
+                        scale=scale, name=self.attn_name)
                 else:
                     y = decode_attention.flash_decode_attention(
-                        q, k_eff, v_eff, pos, scale=1.0 / float(hd) ** 0.5,
-                        name=attn_name)
+                        q, k_eff, v_eff, pos, scale=scale,
+                        name=self.attn_name)
             else:
                 if int8:
                     k_eff = decode_attention.dequantize_kv(k_eff, ks_eff,
@@ -393,9 +388,64 @@ def _forward(params, cfg, ids, cache, last_only=False, attn_name=None):
                                                            cfg.dtype)
                 att = jnp.einsum("bhqd,bhkd->bhqk", q, k_eff).astype(
                     jnp.float32) / jnp.sqrt(hd)
-                att = jnp.where(mask[:, None], att, neg)
+                att = jnp.where(self.mask[:, None], att, self.neg)
                 att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
                 y = jnp.einsum("bhqk,bhkd->bhqd", att, v_eff)
+        if int8:
+            return y, (k_cache, v_cache, ks_cache, vs_cache)
+        return y, (k_cache, v_cache)
+
+    def advanced(self, planes):
+        """The cache dict after the pass: the written planes, every
+        frontier moved by S. ``dict(cache, ...)``, NOT a fresh literal, so
+        hierarchy keys (scale planes, prefix views) and an adapter's
+        ``aux_`` state survive the decode scan's cache threading."""
+        out = dict(self.cache, k=planes[0], v=planes[1],
+                   pos=self.pos + self.S)
+        if self.int8:
+            out["k_scale"], out["v_scale"] = planes[2], planes[3]
+        return out
+
+
+@hot_path
+def _forward(params, cfg, ids, cache, last_only=False, attn_name=None):
+    """ids [B, S], row b starting at cache['pos'][b]; returns
+    (logits [B, S, V] fp32, updated cache). S=prompt_len for prefill, S=1
+    inside the decode scan. Positions are PER ROW: each row embeds, masks
+    and writes its k/v against its own frontier, so rows at different
+    sequence lengths (the serving engine's slots) share one program.
+    ``last_only`` evaluates the LM head on the final position only (the
+    prefill path: sampling reads just that row, and a [B, Tp, vocab]
+    fp32 buffer would otherwise dominate prefill memory). ``attn_name``
+    names the attention kernel in a trace where the caller is not the
+    decode lane (``append_forward``: ``prefill_attn``).
+
+    The regions of a trace (``jax.named_scope``, under the caller's
+    ``prefill_lane`` / ``decode_scan``): ``embed``, then per layer ``attn``
+    (LayerNorm, qkv, attention, projection), ``kv_write`` and ``kv_view``
+    (``CacheAttention``, which owns the cache's layouts and kernels) and
+    ``mlp``, then ``lm_head``."""
+    B, S = ids.shape
+    nh, hd = cfg.n_head, cfg.n_embd // cfg.n_head
+    attend = CacheAttention(cfg, cache, S, attn_name)
+    eps = cfg.layer_norm_epsilon
+    wte = params["wte"].astype(cfg.dtype)
+    pe = params["wpe"].astype(cfg.dtype)[attend.q_pos]  # [B, S, C] gather
+    with jax.named_scope("embed"):
+        x = wte[ids] + pe
+    planes = attend.planes
+
+    for i in range(cfg.n_layer):
+        blk = params["h_{}".format(i)]
+        with jax.named_scope("attn"):
+            h = _ln(x, blk["ln_1"], eps)
+            qkv = _dense(h, blk["attn"]["c_attn"])
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
+            k = k.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
+            v = v.reshape(B, S, nh, hd).transpose(0, 2, 1, 3)
+        y, planes = attend(i, q, k, v, planes)
+        with jax.named_scope("attn"):
             y = y.transpose(0, 2, 1, 3).reshape(B, S, cfg.n_embd)
             x = x + _dense(y, blk["attn"]["c_proj"])
         with jax.named_scope("mlp"):
@@ -410,12 +460,7 @@ def _forward(params, cfg, ids, cache, last_only=False, attn_name=None):
         x = _ln(x, params["ln_f"], eps)
         logits = jnp.einsum("bsc,vc->bsv", x.astype(jnp.float32),
                             params["wte"].astype(jnp.float32))
-    # dict(cache, ...) — NOT a fresh literal — so hierarchy keys (scale
-    # planes, prefix views) survive the decode scan's cache threading.
-    out = dict(cache, k=k_cache, v=v_cache, pos=pos + S)
-    if int8:
-        out["k_scale"], out["v_scale"] = ks_cache, vs_cache
-    return logits, out
+    return logits, attend.advanced(planes)
 
 
 @hot_path

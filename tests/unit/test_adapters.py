@@ -31,6 +31,7 @@ import pytest
 
 from deepspeed_tpu.inference import InferenceConfig, InferenceEngine
 from deepspeed_tpu.inference.adapters import (
+    DecoderAdapter,
     GPT2Adapter,
     LongContextAdapter,
     ModelAdapter,
@@ -41,7 +42,7 @@ from deepspeed_tpu.inference.kv_pool import harvest_snapshot
 from deepspeed_tpu.parallel import mesh as mesh_lib
 from tests.unit.test_inference import make_model, prompts_of, seq_greedy
 
-KINDS = ("gpt2", "moe", "longcontext")
+KINDS = ("gpt2", "moe", "longcontext", "decoder")
 
 _ADAPTERS = {}
 
@@ -59,6 +60,11 @@ def adapter_of(kind):
                                        n_experts=4)
             params = a.init_params(jax.random.PRNGKey(0))
             _ADAPTERS[kind] = (a, params, 256)
+        elif kind == "decoder":
+            model = decoder_model()
+            a = DecoderAdapter.from_model(model, use_flash_decode=False)
+            params = model.init(jax.random.PRNGKey(0))["params"]
+            _ADAPTERS[kind] = (a, params, 256)
         else:
             cfg, model, params = make_model()
             if kind == "gpt2":
@@ -68,6 +74,16 @@ def adapter_of(kind):
                     model, threshold=96, block=8, num_local_blocks=2)
             _ADAPTERS[kind] = (a, params, cfg.vocab_size)
     return _ADAPTERS[kind]
+
+
+def decoder_model():
+    """The config-driven decoder block (OLMoE's) at a tiny size, float32."""
+    from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
+
+    return DecoderLM(DecoderConfig(
+        vocab_size=256, n_layer=2, n_head=2, head_dim=16, hidden_size=32,
+        n_positions=128, n_experts=4, experts_per_token=2, expert_width=32,
+        dtype=jnp.float32, initializer_range=0.15))
 
 
 def ids_of(vocab, n, seed=5):
@@ -305,13 +321,43 @@ def test_capture_restore_round_trip_excludes_aux(kind):
     batched = offload.capture_slots(pool, [0, 1])
     for name, val in rec.items():
         np.testing.assert_array_equal(batched[0][name], val)
-    if kind == "moe":
+    if kind in ("moe", "decoder"):
         # aux rides the harvest snapshot and survives restore untouched.
         assert "aux_moe_load" in restored
         snap = harvest_snapshot(restored)
         assert snap["aux_moe_load"].shape == (4,)
         np.testing.assert_array_equal(snap["aux_moe_load"],
                                       np.asarray(pool["aux_moe_load"]))
+
+
+# ------------------------------------- the adapter is told by the model
+
+
+@pytest.mark.parametrize("kind", ["decoder", "gpt2"])
+def test_init_inference_picks_the_adapter_from_the_models_class(kind):
+    """No ``adapter=`` argument: a DecoderLM is served by DecoderAdapter, a
+    GPT2LMHeadModel by GPT2Adapter as ever, through the same paged pool and
+    the one mixed-step program."""
+    import deepspeed_tpu as deepspeed
+
+    if kind == "decoder":
+        model = decoder_model()
+        params = model.init(jax.random.PRNGKey(0))["params"]
+        want = DecoderAdapter
+    else:
+        _, model, params = make_model()
+        want = GPT2Adapter
+    eng = deepspeed.init_inference(model=model, params=params, config={
+        "inference": {"max_slots": 2, "max_len": 64, "chunk_size": 4,
+                      "prefill_chunk": 8, "paged_kv": True,
+                      "kv_page_len": 16, "use_flash_decode": False}})
+    assert type(eng.adapter) is want
+    assert eng.adapter.gcfg.kv_page_len == 16
+    req = eng.submit(ids_of(256, 7)[0], max_new_tokens=5)
+    eng.run()
+    assert len(req.tokens) == 5 and eng.compile_count == 1
+    if kind == "decoder":
+        assert req.tokens == primitive_greedy("decoder", ids_of(256, 7)[0], 5)
 
 
 # ------------------------------------------------------- MoE specifics

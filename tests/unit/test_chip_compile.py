@@ -130,6 +130,36 @@ def _append(*args):
                         layer=LAYERS - 1)
 
 
+# OLMoE-1B-7B (serve-olmoe-decode-closed): 8 layers, 16 heads of 128, 32
+# slots x 17 pages of 128 + trash. The first head dim that is a whole lane
+# tile (GPT-2's 64 is half of one).
+O_LAYERS, O_SLOTS, O_HD = 8, 32, 128
+O_PAGES = O_SLOTS * (2048 + 128) // 128 + 1
+O_ARENA = ((O_LAYERS, O_PAGES, HEADS, PAGE, O_HD), BF16)
+
+
+def _olmoe_decode_args(rows, slots):
+    return [((slots, HEADS, rows, O_HD), BF16), O_ARENA, O_ARENA,
+            ((slots, O_PAGES // O_SLOTS), I32), ((slots,), I32)]
+
+
+def _olmoe_decode(name=None):
+    def run(q, k, v, tbl, pos):
+        return da.flash_decode_attention_paged(q, k, v, tbl, pos, name=name,
+                                               layer=O_LAYERS - 1)
+    return run
+
+
+def _olmoe_append_args(rows, slots):
+    new = ((slots, HEADS, rows, O_HD), BF16)
+    return [new, new, O_ARENA, O_ARENA,
+            ((slots, O_PAGES // O_SLOTS), I32), ((slots,), I32)]
+
+
+def _olmoe_append(k, v, ka, va, tbl, pos):
+    return da.kv_append((ka, va), (k, v), tbl, pos, layer=O_LAYERS - 1)
+
+
 def _dense_decode_args(rows, int8=False):
     plane = ((SLOTS, HEADS, T_KV, HD), I8 if int8 else BF16)
     scale = ((SLOTS, HEADS, T_KV), F32)
@@ -196,6 +226,14 @@ CASES = {
                                    _append_args(5, SLOTS, int8=True), {}),
     "kv_append_q8_lane_128_rows": (_append,
                                    _append_args(128, 1, int8=True), {}),
+    "olmoe_paged_decode_32_rows_d128": (_olmoe_decode(),
+                                        _olmoe_decode_args(1, O_SLOTS), {}),
+    "olmoe_prefill_attn_lane_128_rows_d128": (
+        _olmoe_decode("prefill_attn"), _olmoe_decode_args(128, 1), {}),
+    "olmoe_kv_append_32_rows_d128": (_olmoe_append,
+                                     _olmoe_append_args(1, O_SLOTS), {}),
+    "olmoe_kv_append_lane_128_rows_d128": (_olmoe_append,
+                                           _olmoe_append_args(128, 1), {}),
     "fused_layer_norm_fwd_bwd": (
         _fwd_bwd(lambda x, g, b: layer_norm.fused_layer_norm(x, g, b), 3),
         [LN_X, VEC, VEC], {}),
@@ -326,6 +364,41 @@ def _arena_shaped(lines, shapes):
     return found
 
 
+def _mixed_step_text(chip, adapter, params, pool, chunk, lane):
+    """Optimised HLO of the engine's mixed step, compiled for the described
+    chip from shapes alone."""
+    from deepspeed_tpu.inference import engine as engine_mod
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+
+    def scalar(dtype):
+        return jax.ShapeDtypeStruct((), dtype, sharding=chip)
+
+    def mixed_step(*args):
+        return engine_mod._mixed_step_program(*args)
+
+    return jax.jit(mixed_step, static_argnums=(1, 2, 3),
+                   donate_argnums=(4,)).lower(
+        on_chip(params), adapter, chunk, None, on_chip(pool),
+        jax.ShapeDtypeStruct((1, lane), I32, sharding=chip),
+        scalar(I32), scalar(I32), scalar(I32), scalar(jnp.bool_),
+        scalar(jnp.bool_), scalar(I32), scalar(I32), scalar(F32),
+        scalar(I32), scalar(jnp.uint32)).compile().as_text()
+
+
+def _scan_lines(comps):
+    """The instruction lines of everything the decode scan's ``while`` body
+    reaches, and the names of those computations."""
+    bodies = [m for lines in comps.values() for line in lines
+              if "decode_scan/while" in line and " while(" in line
+              for m in re.findall(r"body=%([\w.-]+)", line)]
+    assert len(bodies) == 1, bodies
+    scan = _reachable(comps, bodies[0])
+    return scan, [line for name in scan for line in comps[name]]
+
+
 def test_mixed_step_forms_no_layer_of_the_arena_in_the_decode_scan(
         chip, monkeypatch):
     """The engine's mixed step (355M widths, 4 layers, the cells' paged
@@ -337,7 +410,6 @@ def test_mixed_step_forms_no_layer_of_the_arena_in_the_decode_scan(
     and fails here: XLA gives the arena the scatter's layout and converts
     all of it around every kernel call.) Outside the scan the arenas meet
     exactly the layout copies of the step's entry and exit."""
-    from deepspeed_tpu.inference import engine as engine_mod
     from deepspeed_tpu.inference import kv_pool
     from deepspeed_tpu.inference.adapters.gpt2 import GPT2Adapter
     from deepspeed_tpu.inference.config import InferenceConfig
@@ -360,31 +432,10 @@ def test_mixed_step_forms_no_layer_of_the_arena_in_the_decode_scan(
         num_pages=PAGES - 1))
     assert pool["k"].shape == (n_layer,) + ARENA[0][1:]
 
-    def on_chip(tree):
-        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=chip), tree)
-
-    def scalar(dtype):
-        return jax.ShapeDtypeStruct((), dtype, sharding=chip)
-
-    def mixed_step(*args):
-        return engine_mod._mixed_step_program(*args)
-
-    text = jax.jit(mixed_step, static_argnums=(1, 2, 3),
-                   donate_argnums=(4,)).lower(
-        on_chip(params), adapter, chunk, None, on_chip(pool),
-        jax.ShapeDtypeStruct((1, lane), I32, sharding=chip),
-        scalar(I32), scalar(I32), scalar(I32), scalar(jnp.bool_),
-        scalar(jnp.bool_), scalar(I32), scalar(I32), scalar(F32),
-        scalar(I32), scalar(jnp.uint32)).compile().as_text()
+    text = _mixed_step_text(chip, adapter, params, pool, chunk, lane)
 
     comps = _computations(text)
-    bodies = [m for lines in comps.values() for line in lines
-              if "decode_scan/while" in line and " while(" in line
-              for m in re.findall(r"body=%([\w.-]+)", line)]
-    assert len(bodies) == 1, bodies
-    scan = _reachable(comps, bodies[0])
-    in_scan = [line for name in scan for line in comps[name]]
+    scan, in_scan = _scan_lines(comps)
     names = sorted(c.split(".")[0] for c in _kernel_calls(
         "\n".join(in_scan)))
     assert names == ["kv_append"] * n_layer + ["paged_decode"] * n_layer
@@ -399,3 +450,55 @@ def test_mixed_step_forms_no_layer_of_the_arena_in_the_decode_scan(
         [line for name, lines in comps.items() if name not in scan
          for line in lines], shapes)
     assert [op for _, op in outside] == ["copy"] * 6, outside
+
+
+def test_decoder_mixed_step_forms_no_layer_of_the_arena_in_its_decode_scan(
+        chip, monkeypatch):
+    """The same for the config-driven decoder block at OLMoE-1B-7B's widths
+    (2 of its layers; the cell's pool: 32 slots of 2048, page 128, chunk 16,
+    lane 128): in the decode scan the arenas meet ``kv_append`` and the
+    layer-indexed ``paged_decode`` and nothing else, and no layer of the
+    STACKED expert weights is copied out to feed a matmul (805 MB a layer:
+    the grouped matmul that lost the chip measurement wanted exactly that,
+    PERF.md section 6, PR 27)."""
+    from deepspeed_tpu.inference import kv_pool
+    from deepspeed_tpu.inference.adapters import DecoderAdapter
+    from deepspeed_tpu.inference.config import InferenceConfig
+    from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
+
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    n_layer, chunk, lane, experts, width = 2, 16, 128, 64, 1024
+    model = DecoderLM(DecoderConfig(
+        vocab_size=50304, n_layer=n_layer, n_head=HEADS, head_dim=O_HD,
+        hidden_size=2048, n_positions=4096, n_experts=experts,
+        experts_per_token=8, expert_width=width, dtype=BF16))
+    config = InferenceConfig.from_dict(dict(
+        max_slots=O_SLOTS, max_len=2048, chunk_size=chunk, paged_kv=True,
+        kv_page_len=PAGE, prefill_chunk=lane, use_flash_decode=True))
+    adapter = DecoderAdapter.from_model(model, use_flash_decode=True).bind(
+        config, None)
+    assert adapter.gcfg.kv_page_len == PAGE and adapter.gcfg.use_flash_decode
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0))["params"])
+    pool = jax.eval_shape(lambda: dict(kv_pool.init_pool(
+        adapter.cache_spec(), O_SLOTS, 2048, slack=lane, page_len=PAGE,
+        num_pages=O_PAGES - 1), **adapter.aux_state()))
+    assert pool["k"].shape == (n_layer,) + O_ARENA[0][1:]
+
+    comps = _computations(_mixed_step_text(chip, adapter, params, pool,
+                                           chunk, lane))
+    scan, in_scan = _scan_lines(comps)
+    names = sorted(c.split(".")[0] for c in _kernel_calls(
+        "\n".join(in_scan)))
+    assert names == ["kv_append"] * n_layer + ["paged_decode"] * n_layer
+    arena = ["[{},{},{},{},{}]".format(n_layer, O_PAGES, HEADS, PAGE, O_HD),
+             "[{},{},{},{}]".format(O_PAGES, HEADS, PAGE, O_HD)]
+    assert _arena_shaped(in_scan, arena) == []
+    # one layer of the stacked experts as a value of its own, anywhere (a
+    # slice INSIDE a matmul's fusion is an operand read in place, not a copy)
+    everywhere = [line for name, lines in comps.items()
+                  if not name.startswith("fused_computation")
+                  for line in lines]
+    one_layer = ["[{},2048,{}]".format(experts, 2 * width),
+                 "[{},{},2048]".format(experts, width)]
+    assert _arena_shaped(everywhere, one_layer) == []

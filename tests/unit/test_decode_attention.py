@@ -493,6 +493,39 @@ def test_layer_indexed_paged_decode_matches_reference(s, int8):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("s", [1, 128])
+def test_paged_decode_and_kv_append_at_both_head_dims_in_bf16(s, d):
+    """The serving cells' two head dims, in the type they serve in: GPT-2's
+    64 (half a lane tile) and OLMoE's 128 (a whole one). ``kv_append`` then
+    the layer-indexed paged kernel, one decode row and a 128-row lane,
+    against the scatter and the paged reference."""
+    rng = np.random.RandomState(d + s)
+    n_layer, b, h, n_lp, layer = 2, 2, 2, 3, 1
+    n_pages = b * n_lp + 1
+    tbl = jnp.asarray((1 + rng.permutation(n_pages - 1)).reshape(b, n_lp),
+                      jnp.int32)
+    pos = jnp.asarray([3, 2 * _PAGE - 1 if s == 1 else _PAGE + 5], jnp.int32)
+    ka, va = (jnp.asarray(rng.randn(n_layer, n_pages, h, _PAGE, d),
+                          jnp.bfloat16) for _ in range(2))
+    q, k, v = (jnp.asarray(rng.randn(b, h, s, d), jnp.bfloat16)
+               for _ in range(3))
+    ka2, va2 = kv_append((ka, va), (k, v), tbl, pos, layer)
+    for arena, new, got in ((ka, k, ka2), (va, v, va2)):
+        np.testing.assert_array_equal(
+            np.asarray(got.astype(jnp.float32)),
+            np.asarray(_scatter_reference(arena, new, tbl, pos, layer)
+                       .astype(jnp.float32)))
+    got = da.flash_decode_attention_paged(q, ka2, va2, tbl, pos, layer=layer)
+    want = da.decode_attention_paged_reference(
+        q.astype(jnp.float32), ka2[layer].astype(jnp.float32),
+        va2[layer].astype(jnp.float32), tbl, pos)
+    assert got.dtype == jnp.bfloat16
+    # bf16 keeps 8 bits: outputs of order 1, probabilities rounded to bf16
+    np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
+                               np.asarray(want), rtol=0, atol=3e-2)
+
+
 def test_layer_indexed_paged_decode_falls_back_on_small_pages():
     """A page that is no kernel block takes the gather + reference path,
     with the layer sliced there (the CPU test geometries)."""
