@@ -120,7 +120,7 @@ from deepspeed_tpu.inference.kv_pool import (
     slot_cache_view,
     write_slot_cache,
 )
-from deepspeed_tpu.inference.paging import PageAllocator
+from deepspeed_tpu.inference.paging import TRASH_PAGE, PageAllocator
 from deepspeed_tpu.inference.adapters import adapter_class_for
 from deepspeed_tpu.inference.scheduler import QueueFull, Scheduler
 from deepspeed_tpu.ops.transformer.kernels.attention import kernels_on_mesh
@@ -764,6 +764,8 @@ class InferenceEngine(object):
             self.telemetry.gauge("kv_pages_free").set_fn(pg.pages_free)
             self.telemetry.gauge("kv_page_fragmentation").set_fn(
                 lambda: pg.fragmentation(self._live_tokens()))
+            self.telemetry.gauge("kv_live_page_share").set_fn(
+                self._live_page_share)
         # Span-ring overflow as a live series: a truncated autopsy
         # (telemetry/autopsy.py hop_gaps) is detectable from the same
         # scrape that would have shown the alert, instead of silently
@@ -1282,6 +1284,21 @@ class InferenceEngine(object):
             self._pager.pages_for(int(req.prompt.size)
                                   + int(req.max_new_tokens) + self._slack),
             self._pager.pages_per_slot)
+
+    def _live_page_share(self):
+        """Live (row, page) pairs over ``max_slots x n_lp`` at the last
+        harvest: the share of the block table that is work for the paged
+        decode kernel, which steps over a row's pages up to its frontier
+        and over no page of a freed row (ops/transformer/kernels/
+        decode_attention.py). From the step's own snapshot and the host's
+        table; no transfer."""
+        snap, pg = self._last_snap, self._pager
+        if snap is None:
+            return 0.0
+        mapped = pg.table[:, 0] != TRASH_PAGE
+        pages = np.minimum(snap["pos"] // pg.page_len + 1,
+                           pg.pages_per_slot)
+        return float((pages * mapped).sum()) / pg.table.size
 
     def _live_tokens(self):
         """Tokens actually resident across running sessions — the

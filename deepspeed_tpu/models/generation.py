@@ -357,10 +357,11 @@ class CacheAttention(object):
                 # dequantizes in-block from codes + scales.
                 scale = 1.0 / float(hd) ** 0.5
                 if paged:
-                    # Block-table flash decode: the kernel's scalar-prefetch
-                    # index map resolves (row, block j) -> arena page, so
-                    # pages stream into VMEM straight from the table with
-                    # the same straddle-only masking as the dense kernel.
+                    # Block-table flash decode: the kernel steps the list
+                    # of live (row, page) pairs, a page of all heads a
+                    # step, so pages stream into VMEM straight from the
+                    # table with the same straddle-only masking as the
+                    # dense kernel and a freed row is not visited at all.
                     # It takes the arenas whole and the layer as part of
                     # the page's address.
                     if int8:

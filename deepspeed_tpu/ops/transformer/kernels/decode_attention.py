@@ -35,6 +35,16 @@ The cache plane length must be a multiple of ``BLOCK_MIN`` (128 lanes);
 unsupported shapes. Off-TPU the kernel runs in Pallas interpret mode, so
 CPU tests exercise the same code path (parity pinned by
 ``tests/unit/test_decode_attention.py``).
+
+WHAT A UNIT OF WORK IS. The dense kernels above (``decode_attn[_q8]``:
+``generate()``'s cache and the dense slot pool) step a grid of
+``(row, head, length block)``: one block of one head a step, past-frontier
+blocks clamped and skipped but still stepped. The PAGED kernels
+(``paged_decode[_q8]``, and the prefill lane's ``prefill_attn``: what the
+serving engine runs) step a WORK LIST instead: one unit is one page of ALL
+heads of one row, only a row's live pages (up to its frontier) are units,
+a freed row is no unit at all (its output is zeros), and the list's length
+is the grid. See the "Paged kernels" section below.
 """
 
 import functools
@@ -611,26 +621,59 @@ def flash_decode_attention_q8(q, k, v, k_scale, v_scale, pos, scale=None,
 # ---------------------------------------------------------------------------
 # Paged kernels (families "decode_attention_paged[_q8]") — block-table
 # flash decode over the paged KV pool's page ARENA (inference/kv_pool.py
-# paged layout). The arena is [P, H, page_len, D] per layer and each row's
-# logical plane is named by an int32 block table [B, n_lp]: logical block
-# j of row b lives in arena page ``tbl[b, j]``. KERNEL BLOCKS == PAGES:
-# block_k is page_len, so the only new machinery is the kv index map —
-# it rides a second scalar-prefetch operand (the table) and resolves
-# (b, j) -> arena page, with the SAME past-frontier clamp (a repeated
-# page index issues no new DMA) and the same straddle-only masking; the
-# kernel bodies are the dense bodies unchanged (global key positions are
-# j * page_len + lane, exactly as dense).
+# paged layout). The arena is [L, P, H, page_len, D] and each row's logical
+# plane is named by an int32 block table [B, n_lp]: logical block j of row
+# b lives in arena page ``tbl[b, j]``.
+#
+# THE UNIT OF WORK IS ONE PAGE OF ALL HEADS, AND ONLY LIVE PAGES ARE UNITS.
+# A grid step that brings one page of one head (16 or 32 KB) costs a
+# quarter of a microsecond, six times what the page's bytes do, and a
+# page's [H, page_len, D] block is contiguous in the arena (256 KB at
+# GPT-2 355M's shape, 512 KB at OLMoE's), so a step brings that whole
+# block, and the grid is not rows x heads x pages but a WORK LIST
+# (``_paged_units``): one entry for every live (row, page) pair, pages
+# ``0 .. (pos[b] + S - 1) // page_len`` of each row in order, and NO
+# entry for a row with no live page — a freed, frozen slot, told by its
+# first table entry being ``paging.TRASH_PAGE`` (which no live row's block
+# 0 can be), whose output the engine's scan discards: the kernel never
+# visits it and the launcher zeroes its output (a step for it would bring
+# its q block in and its output block out, a third of a chat call of
+# three live rows in sixteen). The list is made from ``pos`` and the
+# table by a few integer operations outside the kernel (identical in every
+# layer of a pass, so the compiler keeps one copy), rides scalar prefetch,
+# and its LENGTH IS THE GRID: a dynamic bound, so dead pages are not
+# stepped over, they do not exist. Pallas's own pipeline brings unit
+# t + 1's page in while unit t is attended, across rows too; q, the output
+# block and the float32 statistics stay put while the row does. (A loop
+# over pages inside the kernel with ``make_async_copy`` from an arena left
+# in ``pl.ANY`` was the other form tried: Mosaic refuses to slice an HBM
+# ref whose minor dim, GPT-2's 64, is under a lane tile, so it serves one
+# family of two; at 128 it was 7% faster a call. The same body on the
+# static ``B x n_lp`` grid was 12–14% slower, 2.3 times at three live rows
+# of sixteen. See PERF.md, PR 28.)
+#
+# The body attends all H heads of the page at once: a batched score
+# matmul, one online-softmax update of ``[H, S, 1]`` statistics and a
+# batched PV into the ``[H, S, D]`` float32 accumulator, with the dense
+# kernels' straddle-only masking (global key positions are
+# j * page_len + lane). The LAYER and the page are the block's address, so
+# no per-layer value of an arena is ever formed; the int8 family brings
+# the page's scales ``[H, page_len]`` the same way and applies them to the
+# scores and the probabilities (positions on the lanes of both), which is
+# the dequantise-then-attend arithmetic reassociated.
+#
+# VMEM, as reckoned for the scoped limit the call is given (v5e: 16 MiB;
+# the launcher plans into 12 MiB and leaves the rest to Mosaic's own
+# temporaries). Every block but the scratch is double-buffered by the
+# pipeline. At S = 128, H = 16, D = 128 in bf16: q and out 0.5 MB each
+# (2 MB), k and v 0.5 MB each (2 MB), accumulator 1 MB, statistics
+# 2 x 1 MB (a lane tile a row), scores and probabilities 1 MB each: 9 MB.
+# The decode scan's S = 1 (a 16-row tile) needs 3 MB. Only a shape past
+# the budget gets fewer heads a unit (the largest divisor of H that fits)
+# and an outer grid axis over head groups; no cell's does. Grouped-query
+# heads would put the queries of one key/value head beside S on the
+# sublane axis; nothing here assumes they do not.
 # ---------------------------------------------------------------------------
-
-def _decode_kernel_paged(pos_ref, tbl_ref, *rest, **kw):
-    # The table is consumed ENTIRELY by the index maps; the body math is
-    # the dense kernel's.
-    return _decode_kernel(pos_ref, *rest, **kw)
-
-
-def _decode_kernel_paged_q8(pos_ref, tbl_ref, *rest, **kw):
-    return _decode_kernel_q8(pos_ref, *rest, **kw)
-
 
 @hot_path
 def decode_attention_paged_reference(q, k, v, block_tbl, pos, scale=None):
@@ -695,122 +738,224 @@ def _whole_arena(layer, *arenas):
     return int(layer), arenas
 
 
-def _flash_decode_paged_pallas(q, k, v, tbl, pos, scale,
-                               name=None, layer=None):
+# Scoped VMEM the launcher plans a unit's blocks into (see the reckoning
+# above): three quarters of the v5e's 16 MiB default limit.
+_PAGED_VMEM_BUDGET = 12 * 2 ** 20
+
+
+def _paged_heads_per_unit(h, s_blk, page_len, d, q_dtype, kv_dtype):
+    """Heads one unit of the paged kernel attends: all ``h`` of the call
+    (a shard's, under tensor parallelism), or the largest divisor of ``h``
+    whose blocks stay inside ``_PAGED_VMEM_BUDGET``. From shapes and dtypes
+    alone; the minor dim of every block pads to a lane tile."""
+    def lanes(n):
+        return -(-n // 128) * 128
+
+    q_b, kv_b = jnp.dtype(q_dtype).itemsize, jnp.dtype(kv_dtype).itemsize
+    per_head = (2 * 2 * s_blk * lanes(d) * q_b          # q, out
+                + 2 * 2 * page_len * lanes(d) * kv_b    # k, v
+                + s_blk * lanes(d) * 4                  # accumulator
+                + 2 * s_blk * _STATS_LANES * 4          # m, l
+                + 2 * s_blk * lanes(page_len) * 4)      # scores, probs
+    if kv_b == 1:                    # the codes as matmul operands
+        per_head += 2 * page_len * lanes(d) * q_b
+    fit = _PAGED_VMEM_BUDGET // per_head
+    if h <= fit:
+        return h
+    # A group of int8 heads is the sublane dim of its scale block.
+    return max((g for g in range(1, h) if h % g == 0 and g <= fit
+                and (kv_b > 1 or g % 8 == 0)), default=h)
+
+
+def _paged_units(tbl, pos, s_len, page_len):
+    """The paged kernel's work list, from the table and the frontiers.
+
+    Returns ``(rows, js, pages, live, n)``: unit t attends logical page
+    ``js[t]`` of row ``rows[t]``, which is arena page ``pages[t]``; row b
+    has ``live[b]`` live pages and as many units, in order, so a freed row
+    (``live`` 0) has none; ``n`` units in all, and one (the last row's,
+    which then has no live page) where no row has any. The lists are
+    ``B * n_lp`` long and repeat the last unit past ``n``. Dense compares
+    and sums over ``[B * n_lp, B]``: one or two fusions, no loop."""
+    from deepspeed_tpu.inference.paging import TRASH_PAGE
+
+    b, n_lp = tbl.shape
+    last = _div(pos + (s_len - 1), page_len)
+    live = jnp.where(tbl[:, 0] == TRASH_PAGE, 0, jnp.minimum(last + 1, n_lp))
+    r = jnp.arange(b, dtype=jnp.int32)
+    ends = jnp.sum(jnp.where(r[None, :] <= r[:, None], live[None, :], 0),
+                   axis=1)                              # inclusive prefix sum
+    n = jnp.maximum(ends[b - 1], 1)
+    t = jnp.minimum(jnp.arange(b * n_lp, dtype=jnp.int32), n - 1)
+    before = t[:, None] >= ends[None, :]                # rows wholly before t
+    rows = jnp.minimum(jnp.sum(before.astype(jnp.int32), axis=1), b - 1)
+    js = t - jnp.sum(jnp.where(before, live[None, :], 0), axis=1)
+    pages = jnp.take(tbl.reshape(-1), rows * n_lp + js)
+    return rows, js, pages, live, n
+
+
+def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
+                  *refs, s_len, q8, single_kv):
+    """One grid step = one unit of ``_paged_units``: a page of all the
+    heads (of the group, where all do not fit) of one row."""
+    n_a = 4 if q8 else 2
+    k_ref, v_ref, *scale_refs = refs[:n_a]     # scales: the int8 family's
+    o_ref, stats = refs[n_a], refs[n_a + 1:]
+    page_len = k_ref.shape[1]
+    t = pl.program_id(1)
+    row, j = rows_ref[t], js_ref[t]
+    pos_b, n_live = pos_ref[row], live_ref[row]
+
+    def attend():
+        q = q_ref[0]                                   # [hb, s_blk, d]
+        k, v = k_ref[...], v_ref[...]                  # [hb, page_len, d]
+        if q8:
+            # The codes are exact in the query's dtype; a key's scale
+            # multiplies its score, a value's its probability.
+            k, v = k.astype(q.dtype), v.astype(o_ref.dtype)
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32,
+                                precision=_mxu_precision(q.dtype))
+        if q8:
+            s = s * scale_refs[0][...][:, None, :]
+
+        def straddling():
+            # Key col (global j*page_len + c) visible to query row i
+            # (global pos_b + i) iff k_pos <= q_pos. Padded query rows
+            # (i >= s_len) compute garbage the launcher slices off.
+            q_pos = pos_b + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            k_pos = j * page_len + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 2)
+            return jnp.where(k_pos <= q_pos, s, NEG_INF)
+
+        # Interior pages (every key visible to even the FIRST query row)
+        # skip the iota/compare/select pass.
+        s = jax.lax.cond((j + 1) * page_len - 1 <= pos_b,
+                         lambda: s, straddling)
+
+        def times_v(p, v):
+            return jax.lax.dot_general(p.astype(v.dtype), v,
+                                       (((2,), (1,)), ((0,), (0,))),
+                                       preferred_element_type=jnp.float32,
+                                       precision=_mxu_precision(v.dtype))
+
+        def pv_and_rowsum(p):
+            if q8:
+                # p is scaled by the values' scales before the matmul, so
+                # the row-sum is taken from p itself.
+                l = jnp.sum(p.astype(jnp.float32), axis=-1, keepdims=True)
+                scaled = p.astype(jnp.float32) * scale_refs[1][...][:, None, :]
+                return times_v(scaled, v), l
+            # p @ [v | 1]: the row-sum rides the PV matmul, as in
+            # ``_pv_rowsum``, and shares p's rounding with the numerator.
+            d = v.shape[2]
+            pv = times_v(p, jnp.concatenate(
+                [v, jnp.ones(v.shape[:2] + (1,), v.dtype)], axis=2))
+            return pv[:, :, :d], pv[:, :, d:d + 1]
+
+        if single_kv:
+            # One page a plane: direct softmax, no scratch, no rescale.
+            m = jnp.max(s, axis=-1, keepdims=True)
+            pv, l = pv_and_rowsum(_exp_lowp(s - m, o_ref.dtype))
+            o_ref[0] = (pv / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+            return
+        acc, m_s, l_s = stats
+
+        @pl.when(j == 0)
+        def _init():
+            acc[...] = jnp.zeros_like(acc)
+            m_s[...] = jnp.full_like(m_s, NEG_INF)
+            l_s[...] = jnp.zeros_like(l_s)
+
+        m_prev, l_prev = m_s[:, :, 0:1], l_s[:, :, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        pv, l_cur = pv_and_rowsum(_exp_lowp(s - m_new, o_ref.dtype))
+        m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
+        l_s[...] = jnp.broadcast_to(alpha * l_prev + l_cur, l_s.shape)
+        acc[...] = acc[...] * alpha + pv
+
+        @pl.when(j == n_live - 1)
+        def _finalize():
+            l = jnp.maximum(l_s[:, :, 0:1], 1e-30)
+            o_ref[0] = (acc[...] / l).astype(o_ref.dtype)
+
+    # Only a batch with no live row at all has a unit without a live page
+    # (the list is never empty); the launcher zeroes every dead row's output.
+    pl.when(n_live > 0)(attend)
+
+
+def _paged_launch(name, q, arenas, tbl, pos, scale, layer):
+    """The one launcher of both paged families: ``arenas`` is (k, v) or
+    (k, v, k_scale, v_scale), whole or one layer's (``layer`` None)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    layer, (k, v) = _whole_arena(layer, k, v)
+    layer, arenas = _whole_arena(layer, *arenas)
     b, h, s, d = q.shape
-    page_len = k.shape[3]
-    n_lp = tbl.shape[1]
+    page_len = arenas[0].shape[3]
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    pos = pos.astype(jnp.int32)
-    tbl = tbl.astype(jnp.int32)
     sub = _sublane(q.dtype)
     s_blk = -(-s // sub) * sub
     if s_blk != s:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, s_blk - s), (0, 0)))
+    hb = _paged_heads_per_unit(h, s_blk, page_len, d, q.dtype,
+                               arenas[0].dtype)
+    pos = pos.astype(jnp.int32)
+    rows, js, pages, live, n_units = _paged_units(tbl.astype(jnp.int32), pos,
+                                                  s, page_len)
+    units = (rows, js, pages, pos, live)      # the scalar-prefetch operands
 
-    def kv_index(b_, h_, j, pos_ref, tbl_ref):
-        # Logical block j of row b_ lives in arena page tbl[b_, j];
-        # past-frontier blocks clamp to the last useful LOGICAL block
-        # first, so the resolved PAGE repeats and issues no new DMA.
-        # The LAYER is part of the index too: the arena is never sliced
-        # into a per-layer value outside the kernel (a 38 MB copy a call
-        # at 355M), the squeezed leading block dim picks it in the DMA.
-        last = _div(pos_ref[b_] + (s - 1), page_len)
-        return (layer, tbl_ref[b_, jnp.minimum(j, last)], h_, 0, 0)
+    def q_index(g, t, rows_ref, *_):
+        return (rows_ref[t], g, 0, 0)
 
-    def q_index(b_, h_, j, pos_ref, tbl_ref):
-        return (b_, h_, 0, 0)
+    def page_spec(arena):
+        # [hb, page_len, d] of a row arena, [hb, page_len] of a scale arena.
+        zeros = (0,) * (arena.ndim - 3)
+        return pl.BlockSpec(
+            (None, None, hb) + arena.shape[3:],
+            lambda g, t, rows_ref, js_ref, pages_ref, *_:
+            (layer, pages_ref[t], g) + zeros)
 
+    single_kv = tbl.shape[1] == 1
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, h, n_lp),
-        in_specs=[
-            pl.BlockSpec((1, 1, s_blk, d), q_index),
-            pl.BlockSpec((None, 1, 1, page_len, d), kv_index),
-            pl.BlockSpec((None, 1, 1, page_len, d), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, 1, s_blk, d), q_index),
-        scratch_shapes=[] if n_lp == 1 else [
-            pltpu.VMEM((s_blk, d), jnp.float32),
-            pltpu.VMEM((s_blk, _STATS_LANES), jnp.float32),
-            pltpu.VMEM((s_blk, _STATS_LANES), jnp.float32),
+        num_scalar_prefetch=len(units),
+        # Head groups outermost: a row's units stay consecutive, so its
+        # output block and the statistics live from its first to its last.
+        grid=(h // hb, n_units),
+        in_specs=[pl.BlockSpec((1, hb, s_blk, d), q_index)]
+        + [page_spec(a) for a in arenas],
+        out_specs=pl.BlockSpec((1, hb, s_blk, d), q_index),
+        scratch_shapes=[] if single_kv else [
+            pltpu.VMEM((hb, s_blk, d), jnp.float32),
+            pltpu.VMEM((hb, s_blk, _STATS_LANES), jnp.float32),
+            pltpu.VMEM((hb, s_blk, _STATS_LANES), jnp.float32),
         ],
     )
     out = pallas_mode.kernel_call(
-        name or "paged_decode",
-        functools.partial(_decode_kernel_paged, s_len=s, block_k=page_len,
-                          single_kv=n_lp == 1),
+        name,
+        functools.partial(_paged_kernel, s_len=s, q8=len(arenas) == 4,
+                          single_kv=single_kv),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d), q.dtype),
-    )(pos, tbl, q, k, v)
+    )(*units, q, *arenas)
+    # A freed row has no unit, so the kernel never wrote its block: zeros,
+    # in a select that fuses into whatever reads the output.
+    out = jnp.where((live > 0)[:, None, None, None], out, 0)
     return out[:, :, :s] if s_blk != s else out
+
+
+def _flash_decode_paged_pallas(q, k, v, tbl, pos, scale,
+                               name=None, layer=None):
+    return _paged_launch(name or "paged_decode", q, (k, v), tbl, pos, scale,
+                         layer)
 
 
 def _flash_decode_paged_q8_pallas(q, k, v, k_scale, v_scale, tbl, pos,
                                   scale, name=None, layer=None):
-    from jax.experimental.pallas import tpu as pltpu
-
-    if layer is not None:
-        # The scale arenas ARE sliced to the layer: the kernel body wants
-        # a scale per key as a [page_len, 1] column, and a trailing unit
-        # dim on the whole [L, P, H, page_len] arena would pad every
-        # scale to a lane tile. The code arenas stay whole.
-        k_scale, v_scale = k_scale[layer], v_scale[layer]
-    layer, (k, v) = _whole_arena(layer, k, v)
-    b, h, s, d = q.shape
-    page_len = k.shape[3]
-    n_lp = tbl.shape[1]
-    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    pos = pos.astype(jnp.int32)
-    tbl = tbl.astype(jnp.int32)
-    k_scale = k_scale.astype(jnp.float32)[..., None]
-    v_scale = v_scale.astype(jnp.float32)[..., None]
-    sub = _sublane(q.dtype)
-    s_blk = -(-s // sub) * sub
-    if s_blk != s:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, s_blk - s), (0, 0)))
-
-    def page(b_, j, pos_ref, tbl_ref):
-        last = _div(pos_ref[b_] + (s - 1), page_len)
-        return tbl_ref[b_, jnp.minimum(j, last)]
-
-    def kv_index(b_, h_, j, pos_ref, tbl_ref):
-        return (layer, page(b_, j, pos_ref, tbl_ref), h_, 0, 0)
-
-    def scale_index(b_, h_, j, pos_ref, tbl_ref):
-        return (page(b_, j, pos_ref, tbl_ref), h_, 0, 0)
-
-    def q_index(b_, h_, j, pos_ref, tbl_ref):
-        return (b_, h_, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, h, n_lp),
-        in_specs=[
-            pl.BlockSpec((1, 1, s_blk, d), q_index),
-            pl.BlockSpec((None, 1, 1, page_len, d), kv_index),
-            pl.BlockSpec((None, 1, 1, page_len, d), kv_index),
-            pl.BlockSpec((1, 1, page_len, 1), scale_index),
-            pl.BlockSpec((1, 1, page_len, 1), scale_index),
-        ],
-        out_specs=pl.BlockSpec((1, 1, s_blk, d), q_index),
-        scratch_shapes=[] if n_lp == 1 else [
-            pltpu.VMEM((s_blk, d), jnp.float32),
-            pltpu.VMEM((s_blk, _STATS_LANES), jnp.float32),
-            pltpu.VMEM((s_blk, _STATS_LANES), jnp.float32),
-        ],
-    )
-    out = pallas_mode.kernel_call(
-        name or "paged_decode_q8",
-        functools.partial(_decode_kernel_paged_q8, s_len=s,
-                          block_k=page_len, single_kv=n_lp == 1),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d), q.dtype),
-    )(pos, tbl, q, k, v, k_scale, v_scale)
-    return out[:, :, :s] if s_blk != s else out
+    return _paged_launch(name or "paged_decode_q8", q,
+                         (k, v, k_scale.astype(jnp.float32),
+                          v_scale.astype(jnp.float32)),
+                         tbl, pos, scale, layer)
 
 
 @hot_path
@@ -834,10 +979,12 @@ def flash_decode_attention_paged(q, k, v, block_tbl, pos, scale=None,
       scale: score scale; default 1/sqrt(D).
       layer: static int, or None (see k, v).
 
-    block_k is page_len by construction (kernel blocks == pages), so
-    there is no autotuned tile here; page_len must be a multiple of
-    BLOCK_MIN for the kernel to engage, and other page sizes take the
-    gather + dense-reference fallback (same math).
+    block_k is page_len by construction (kernel blocks == pages, all
+    heads of a page a step), so there is no autotuned tile here;
+    page_len must be a multiple of BLOCK_MIN for the kernel to engage,
+    and other page sizes take the gather + dense-reference fallback
+    (same math). A row whose first table entry is the trash page (a
+    freed slot) is not attended: its output is zeros.
     Returns: [B, H, S, D] in q.dtype.
     """
     d = q.shape[-1]

@@ -230,6 +230,9 @@ CASES = {
                                         _olmoe_decode_args(1, O_SLOTS), {}),
     "olmoe_prefill_attn_lane_128_rows_d128": (
         _olmoe_decode("prefill_attn"), _olmoe_decode_args(128, 1), {}),
+    # Speculation's verify (k + 1 = 5 query rows a slot) at head dim 128.
+    "olmoe_paged_verify_5_rows_d128": (_olmoe_decode(),
+                                       _olmoe_decode_args(5, O_SLOTS), {}),
     "olmoe_kv_append_32_rows_d128": (_olmoe_append,
                                      _olmoe_append_args(1, O_SLOTS), {}),
     "olmoe_kv_append_lane_128_rows_d128": (_olmoe_append,

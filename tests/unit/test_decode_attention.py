@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.inference.paging import TRASH_PAGE as TRASH
 from deepspeed_tpu.models.generation import (
     _forward, as_gencfg, decode_step, generate, init_cache)
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
@@ -494,12 +495,12 @@ def test_layer_indexed_paged_decode_matches_reference(s, int8):
 
 
 @pytest.mark.parametrize("d", [64, 128])
-@pytest.mark.parametrize("s", [1, 128])
+@pytest.mark.parametrize("s", [1, 5, 128])
 def test_paged_decode_and_kv_append_at_both_head_dims_in_bf16(s, d):
     """The serving cells' two head dims, in the type they serve in: GPT-2's
     64 (half a lane tile) and OLMoE's 128 (a whole one). ``kv_append`` then
-    the layer-indexed paged kernel, one decode row and a 128-row lane,
-    against the scatter and the paged reference."""
+    the layer-indexed paged kernel, one decode row, a five-row verify and a
+    128-row lane, against the scatter and the paged reference."""
     rng = np.random.RandomState(d + s)
     n_layer, b, h, n_lp, layer = 2, 2, 2, 3, 1
     n_pages = b * n_lp + 1
@@ -524,6 +525,208 @@ def test_paged_decode_and_kv_append_at_both_head_dims_in_bf16(s, d):
     # bf16 keeps 8 bits: outputs of order 1, probabilities rounded to bf16
     np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
                                np.asarray(want), rtol=0, atol=3e-2)
+
+
+# -------------------------- paged arena: a page of all heads, live pages only
+#
+# The paged kernel's unit of work is a page of ALL heads and its grid is
+# the list of live (row, page) pairs (``_paged_units``). The cases below
+# run that body (interpret mode here) against the paged references; the
+# last tests hold the grid's shape, so the rows x heads x pages grid cannot
+# come back unseen on a CPU.
+
+
+def _paged_operands(d, s, pos, int8, dtype, shared=0, frozen=(), h=4,
+                    n_lp=3, n_layer=2, seed=0):
+    """q, arenas (k, v[, k_scale, v_scale]) WHOLE, table, frontiers.
+    ``shared``: the first ``shared`` pages of every live row are row 0's
+    (a prefix installed by reference). ``frozen`` rows: table all trash."""
+    rng = np.random.RandomState(seed)
+    b = len(pos)
+    n_pages = b * n_lp + 1
+    tbl = (1 + rng.permutation(n_pages - 1)).reshape(b, n_lp)
+    tbl[:, :shared] = tbl[0, :shared]
+    tbl[list(frozen)] = TRASH
+    q = jnp.asarray(rng.randn(b, h, s, d), dtype)
+    arenas = tuple(jnp.asarray(rng.randn(n_layer, n_pages, h, _PAGE, d),
+                               jnp.float32) for _ in range(2))
+    if int8:
+        (k, ks), (v, vs) = (da.quantize_kv(a) for a in arenas)
+        arenas = (k, v, ks, vs)
+    else:
+        arenas = tuple(a.astype(dtype) for a in arenas)
+    return (q, arenas, jnp.asarray(tbl, jnp.int32),
+            jnp.asarray(pos, jnp.int32))
+
+
+def _paged_kernel_and_reference(q, arenas, tbl, pos, layer):
+    if len(arenas) == 4:
+        got = da.flash_decode_attention_paged_q8(q, *arenas, tbl, pos,
+                                                 layer=layer)
+        want = da.decode_attention_paged_q8_reference(
+            q.astype(jnp.float32), *(a[layer] for a in arenas), tbl, pos)
+    else:
+        got = da.flash_decode_attention_paged(q, *arenas, tbl, pos,
+                                              layer=layer)
+        want = da.decode_attention_paged_reference(
+            q.astype(jnp.float32),
+            *(a[layer].astype(jnp.float32) for a in arenas), tbl, pos)
+    assert got.dtype == q.dtype and got.shape == q.shape
+    return np.asarray(got.astype(jnp.float32)), np.asarray(want)
+
+
+# Frontiers, for a plane of 3 pages of 128: page 0 only; the LAST row of a
+# page (s rows ending at 127 and at 255); the first row of the next; deep.
+def _frontiers(s):
+    return [0, 128 - s, 128, 256 - s, 3 * _PAGE - s]
+
+
+PAGED_CASES = {
+    # name: (d, S, int8, dtype, shared pages, frozen rows)
+    "decode_1_row_d64_bf16": (64, 1, False, jnp.bfloat16, 0, ()),
+    "decode_1_row_d128_bf16": (128, 1, False, jnp.bfloat16, 0, ()),
+    "verify_5_rows_d64_bf16": (64, 5, False, jnp.bfloat16, 0, ()),
+    "verify_5_rows_d128_bf16": (128, 5, False, jnp.bfloat16, 0, ()),
+    "lane_128_rows_d64_bf16": (64, 128, False, jnp.bfloat16, 0, ()),
+    "lane_128_rows_d128_bf16": (128, 128, False, jnp.bfloat16, 0, ()),
+    "decode_1_row_d64_int8": (64, 1, True, jnp.bfloat16, 0, ()),
+    "decode_1_row_d128_int8": (128, 1, True, jnp.bfloat16, 0, ()),
+    "verify_5_rows_d128_int8": (128, 5, True, jnp.bfloat16, 0, ()),
+    "lane_128_rows_d64_int8": (64, 128, True, jnp.bfloat16, 0, ()),
+    "decode_1_row_d64_float32": (64, 1, False, jnp.float32, 0, ()),
+    "verify_5_rows_d128_float32_int8": (128, 5, True, jnp.float32, 0, ()),
+    "shared_prefix_page_d64_bf16": (64, 1, False, jnp.bfloat16, 1, ()),
+    "shared_prefix_pages_d128_int8": (128, 5, True, jnp.bfloat16, 2, ()),
+    "frozen_rows_between_live_d64_bf16": (
+        64, 1, False, jnp.bfloat16, 0, (1, 3)),
+    "frozen_rows_first_and_last_d128_int8": (
+        128, 5, True, jnp.bfloat16, 0, (0, 4)),
+    "every_row_frozen_d64_bf16": (
+        64, 1, False, jnp.bfloat16, 0, (0, 1, 2, 3, 4)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PAGED_CASES))
+def test_paged_body_matches_the_paged_reference(name):
+    """A page of all heads a unit, live pages only: ragged frontiers (page
+    0 only, a page's last row, the next page's first, the plane's end),
+    shared prefix pages, frozen rows, both head dims, the three query
+    shapes, bf16 / float32 / int8. A frozen row's output is zeros."""
+    d, s, int8, dtype, shared, frozen = PAGED_CASES[name]
+    q, arenas, tbl, pos = _paged_operands(d, s, _frontiers(s), int8, dtype,
+                                          shared=shared, frozen=frozen)
+    got, want = _paged_kernel_and_reference(q, arenas, tbl, pos, layer=1)
+    live = [r for r in range(len(pos)) if r not in frozen]
+    # bf16 keeps 8 bits (outputs of order 1, probabilities rounded to it);
+    # float32 differs from the reference by the order of its sums only.
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
+    assert not got[list(frozen)].any()
+
+
+@pytest.mark.parametrize("int8", [False, True])
+@pytest.mark.parametrize("s", [1, 5])
+def test_frozen_rows_leave_live_rows_bit_for_bit(s, int8):
+    """Frozen rows (table all trash, ``pos`` pinned deep in the plane)
+    between live rows: the live rows are bit for bit what the same call
+    gives without the frozen ones."""
+    frozen = (0, 2, 3, 6)
+    pos = [300, 5, 383 - s, 200, 127, 128, 2 * _PAGE - s]
+    q, arenas, tbl, pos = _paged_operands(64, s, pos, int8, jnp.bfloat16,
+                                          frozen=frozen, seed=s)
+    live = np.asarray([r for r in range(len(pos)) if r not in frozen])
+    run = (da.flash_decode_attention_paged_q8 if int8
+           else da.flash_decode_attention_paged)
+    with_frozen = run(q, *arenas, tbl, pos, layer=0)
+    without = run(q[live], *arenas, tbl[live], pos[live], layer=0)
+    np.testing.assert_array_equal(
+        np.asarray(with_frozen.astype(jnp.float32))[live],
+        np.asarray(without.astype(jnp.float32)))
+    assert not np.asarray(with_frozen.astype(jnp.float32))[
+        list(frozen)].any()
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("int8", [False, True])
+def test_paged_grid_is_the_list_of_live_pairs_and_has_no_head_axis(int8):
+    """The launched call's grid is (head groups = 1, units): no axis over
+    heads, no axis over a row's ``n_lp`` pages, and the unit count is not a
+    static extent but the work list's length. The list itself holds each
+    live row's pages ``0 .. frontier page`` in order and nothing for a
+    frozen row: rows x pages brought in are the live pairs."""
+    frozen = (1, 4)
+    pos = [0, 700, 127, 128, 300, 3 * _PAGE - 1]
+    q, arenas, tbl, pos = _paged_operands(64, 1, pos, int8, jnp.bfloat16,
+                                          frozen=frozen, h=16)
+    run = (da.flash_decode_attention_paged_q8 if int8
+           else da.flash_decode_attention_paged)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: run(*a, layer=1))(q, *arenas, tbl, pos)
+    (call,) = _pallas_calls(jaxpr.jaxpr)
+    grid = call.params["grid_mapping"].grid
+    assert len(grid) == 2 and grid[0] == 1, grid
+    assert not isinstance(grid[1], int), grid          # a dynamic bound
+    # Blocks: q and the output hold all 16 heads of a row, an arena's block
+    # all 16 heads of one page.
+    shapes = [tuple(bm.block_shape) for bm in
+              call.params["grid_mapping"].block_mappings]
+    assert all(16 in [getattr(x, "block_size", None) for x in shape]
+               for shape in shapes), shapes
+
+    rows, js, pages, live, n = (np.asarray(x) for x in da._paged_units(
+        tbl, pos, 1, _PAGE))
+    want_live = [1, 0, 1, 2, 0, 3]
+    assert live.tolist() == want_live
+    pairs = [(r, j) for r, k in enumerate(want_live) for j in range(k)]
+    assert n == len(pairs) == 7              # the live pairs, nothing else
+    assert list(zip(rows[:n].tolist(), js[:n].tolist())) == pairs
+    tbl = np.asarray(tbl)
+    assert pages[:n].tolist() == [tbl[r, j] for r, j in pairs]
+    assert TRASH not in pages[:n].tolist()
+    # Past the end the list repeats its last unit (no new block).
+    assert set(zip(rows[n:].tolist(), js[n:].tolist())) == {pairs[-1]}
+    assert len(rows) == tbl.size
+    # No live row at all: one unit, a row without a live page, so the grid
+    # is never empty and the kernel attends nothing.
+    rows, js, pages, live, n = (np.asarray(x) for x in da._paged_units(
+        jnp.zeros_like(tbl), pos, 1, _PAGE))
+    assert n == 1 and not live.any() and pages[0] == TRASH
+    assert 0 <= rows[0] < len(pos) and js[0] == 0
+
+
+def test_paged_heads_per_unit_from_shapes_alone():
+    """All heads a unit at every cell's shape; fewer only where the blocks
+    would pass the VMEM the call plans into (a divisor of H, and a whole
+    sublane tile of scales for an int8 pool)."""
+    pick = da._paged_heads_per_unit
+    assert pick(16, 16, 128, 64, jnp.bfloat16, jnp.bfloat16) == 16
+    assert pick(16, 16, 128, 128, jnp.bfloat16, jnp.bfloat16) == 16
+    assert pick(16, 128, 128, 128, jnp.bfloat16, jnp.bfloat16) == 16
+    assert pick(16, 128, 128, 64, jnp.bfloat16, jnp.int8) == 16
+    assert pick(4, 16, 128, 128, jnp.bfloat16, jnp.bfloat16) == 4   # TP
+    few = pick(32, 512, 128, 128, jnp.bfloat16, jnp.bfloat16)
+    assert few < 32 and 32 % few == 0
+    few = pick(32, 256, 128, 128, jnp.bfloat16, jnp.int8)
+    assert few < 32 and 32 % few == 0 and few % 8 == 0
+
+
+def test_paged_head_groups_match_all_heads_at_once(monkeypatch):
+    """The fallback path (an outer grid axis over head groups) gives what
+    all heads a unit give, bit for bit."""
+    q, arenas, tbl, pos = _paged_operands(
+        64, 5, _frontiers(5), False, jnp.bfloat16, frozen=(2,), h=4)
+    whole = da.flash_decode_attention_paged(q, *arenas, tbl, pos, layer=1)
+    monkeypatch.setattr(da, "_paged_heads_per_unit", lambda h, *a: 2)
+    grouped = da.flash_decode_attention_paged(q, *arenas, tbl, pos, layer=1)
+    np.testing.assert_array_equal(np.asarray(whole.astype(jnp.float32)),
+                                  np.asarray(grouped.astype(jnp.float32)))
 
 
 def test_layer_indexed_paged_decode_falls_back_on_small_pages():

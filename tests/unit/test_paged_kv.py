@@ -494,10 +494,20 @@ def test_prometheus_exports_page_gauge_family():
     assert sample("ds_tpu_kv_pages_free") == st["pages_free"]
     assert 0.0 <= sample("ds_tpu_kv_page_fragmentation") <= 1.0
     assert sample("ds_tpu_kv_hbm_bytes") == eng.metrics()["kv_hbm_bytes"]
+    # The share of the block table that is work for the paged decode
+    # kernel: each mapped row's pages up to its frontier, over slots x n_lp.
+    pg, pos = eng._pager, eng._last_snap["pos"]
+    live = sum(min(int(pos[s]) // 8 + 1, pg.pages_per_slot)
+               for s in range(pg.num_slots) if pg.table[s, 0] != 0)
+    assert kinds["ds_tpu_kv_live_page_share"] == "gauge"
+    assert live > 0 and sample("ds_tpu_kv_live_page_share") == \
+        pytest.approx(live / pg.table.size)
     eng.run()
     _, drained = _parse_prom(eng.prometheus())
     assert [v for (n, _), v in drained.items()
             if n == "ds_tpu_kv_pages_in_use"][0] == 0
+    assert [v for (n, _), v in drained.items()
+            if n == "ds_tpu_kv_live_page_share"][0] == 0
 
 
 def test_pick_swap_victim_scores_live_pages():
