@@ -593,10 +593,9 @@ def _decode_attention_probe(engine, reps=10, s=1):
     return (time.time() - t0) / reps * 1e3, use_flash
 
 
-def _measure_serving(smoke=False, flash_decode=None, chunked_prefill=True,
-                     spec_decode=True, int8_kv=True, prefix_cache=True,
-                     host_offload=True, sparse_decode=True,
-                     expert_parallel=True, paged_kv=True):
+def _measure_serving(smoke=False, flash_decode=None, spec_decode=True,
+                     int8_kv=True, prefix_cache=True, host_offload=True,
+                     paged_kv=True):
     """Continuous-batching serving benchmark (deepspeed_tpu/inference/).
 
     A synthetic Poisson request stream plays against the slotted engine:
@@ -610,12 +609,9 @@ def _measure_serving(smoke=False, flash_decode=None, chunked_prefill=True,
     in-process mode). ``flash_decode`` forces the decode-attention path
     (None: the engine's default — the Pallas kernel on TPU);
     ``--no-flash-decode`` sets False for the einsum side of the kernel
-    A/B. ``chunked_prefill=False`` (``--no-chunked-prefill``) runs the
-    legacy whole-prompt-bucket prefill path — the A/B that shows chunked
-    prefill's TTFT-p99 win at equal-or-better tok/s. ``spec_decode``
+    A/B. ``spec_decode``
     enables n-gram speculative decoding (``--no-spec-decode`` for the
-    A/B; it also stays off on the legacy path, which has no speculation
-    lane); the stamped ``accepted_per_step_*`` / ``draft_accept_rate``
+    A/B); the stamped ``accepted_per_step_*`` / ``draft_accept_rate``
     metrics attribute any throughput delta to draft acceptance. The
     prompts are REPETITION-HEAVY (each tiles its own short phrase) — the
     workload where prompt-lookup drafting has matches to find; the
@@ -623,19 +619,9 @@ def _measure_serving(smoke=False, flash_decode=None, chunked_prefill=True,
     ``prefix_cache`` / ``host_offload`` enable the KV memory hierarchy
     (docs/INFERENCE.md); the ``--no-int8-kv`` / ``--no-prefix-cache`` /
     ``--no-host-offload`` A/Bs suffix the metric name so hierarchy-on
-    and hierarchy-off series never mix. The hierarchy rides the chunked
-    path only — the legacy A/B runs with it off. ``sparse_decode`` /
-    ``expert_parallel`` are the adapter-feature A/B arms
-    (``--no-sparse-decode`` / ``--no-expert-parallel``, suffixed
-    ``_nosparsedecode`` / ``_noexpertparallel``): both keys ride the
-    serving config into ``ModelAdapter.bind``, where adapters WITH the
-    feature honor them (LongContextAdapter drops its threshold,
-    MoEAdapter replicates its expert stacks) and the stock GPT-2
-    adapter ignores them — the flag records which arm produced the
-    artifact either way. ``paged_kv`` serves through the page-granular
-    KV pool (``--no-paged-kv`` for the dense-pool A/B, suffixed
-    ``_nopagedkv``); it rides the chunked path only — page mapping
-    advances at the mixed-step boundary."""
+    and hierarchy-off series never mix. ``paged_kv`` serves through the
+    page-granular KV pool (``--no-paged-kv`` for the dense-pool A/B,
+    suffixed ``_nopagedkv``)."""
     import jax
 
     import deepspeed_tpu as deepspeed
@@ -657,25 +643,19 @@ def _measure_serving(smoke=False, flash_decode=None, chunked_prefill=True,
         cfg = GPT2Config.tiny(dropout=0.0, use_flash_attention=False)
         n_req, rate = 10, 500.0
         serve_cfg = {"max_slots": 4, "max_len": 64, "chunk_size": 4,
-                     "prefill_buckets": (16,), "max_queue": n_req}
+                     "max_queue": n_req}
         prompt_lens, max_new = (4, 12), 8
     if flash_decode is not None:
         serve_cfg["use_flash_decode"] = flash_decode
-    serve_cfg["chunked_prefill"] = chunked_prefill
-    spec_on = bool(spec_decode and chunked_prefill)
+    spec_on = bool(spec_decode)
     serve_cfg["spec_decode"] = spec_on
-    # KV hierarchy (prefix cache / host offload require the chunked
-    # path, same gating as speculation; int8 is path-independent).
     int8_on = bool(int8_kv)
-    prefix_on = bool(prefix_cache and chunked_prefill)
-    offload_on = bool(host_offload and chunked_prefill)
+    prefix_on = bool(prefix_cache)
+    offload_on = bool(host_offload)
     serve_cfg["int8_kv"] = int8_on
     serve_cfg["prefix_cache"] = prefix_on
     serve_cfg["host_offload"] = offload_on
-    serve_cfg["sparse_decode"] = bool(sparse_decode)
-    serve_cfg["expert_parallel"] = bool(expert_parallel)
-    # Paged KV pool rides the chunked path only (config validation).
-    paged_on = bool(paged_kv and chunked_prefill)
+    paged_on = bool(paged_kv)
     serve_cfg["paged_kv"] = paged_on
     if paged_on and not on_tpu:
         # Smoke page quantum: small pages on the tiny plane so the
@@ -697,7 +677,7 @@ def _measure_serving(smoke=False, flash_decode=None, chunked_prefill=True,
         model=model, params=params, config={"inference": serve_cfg})
 
     # The stream: lengths from a SMALL set (each distinct length is one
-    # sequential-baseline compile; the engine itself buckets them).
+    # sequential-baseline compile; the engine takes any length).
     # Repetition-heavy content: each request tiles its OWN random phrase
     # to length — natural text repeats itself, uniform-random tokens
     # never do, and the n-gram drafter needs self-matches to draft from.
@@ -707,20 +687,15 @@ def _measure_serving(smoke=False, flash_decode=None, chunked_prefill=True,
                        -(-n // 8))[:n].astype(np.int32) for n in lens]
     arrivals = np.cumsum(rng.exponential(1.0 / rate, size=n_req))
 
-    # Warmup: chunked prefill compiles its ONE mixed-step program on the
-    # first request; the legacy path needs one request per distinct
-    # bucket to compile every prefill program + the decode program.
-    # mark_warm() freezes that compile total in the recompile detector
-    # (the chunked path self-warms, the legacy path can't — it has no
-    # way to know the bucket mix is complete) and metrics(reset=True)
-    # opens a fresh window, so the measured phase's counters, timers and
-    # latency percentiles carry NO warmup pollution — the windowed
-    # replacement for the old warm_* subtraction bookkeeping.
+    # Warmup: the engine compiles its ONE mixed-step program on the
+    # first request and the recompile detector warms itself after that
+    # step; metrics(reset=True) opens a fresh window, so the measured
+    # phase's counters, timers and latency percentiles carry NO warmup
+    # pollution.
     from deepspeed_tpu.telemetry import PROFILE_DIR_ENV, profile_window
 
     engine.generate([prompts[lens.index(n)] for n in sorted(set(lens))],
                     max_new_tokens=2)
-    engine.recompile_detector.mark_warm()
     engine.metrics(reset=True)
 
     t0 = time.time()
@@ -819,8 +794,6 @@ def _measure_serving(smoke=False, flash_decode=None, chunked_prefill=True,
         # A/B runs must not share a metric series with the default
         # (kernel-on) one.
         name += "_noflashdecode"
-    if not chunked_prefill:
-        name += "_nochunkedprefill"
     if not spec_decode:
         name += "_nospecdecode"
     if not int8_kv:
@@ -829,10 +802,6 @@ def _measure_serving(smoke=False, flash_decode=None, chunked_prefill=True,
         name += "_noprefixcache"
     if not host_offload:
         name += "_nohostoffload"
-    if not sparse_decode:
-        name += "_nosparsedecode"
-    if not expert_parallel:
-        name += "_noexpertparallel"
     if not paged_kv:
         name += "_nopagedkv"
     _note_trace(engine)
@@ -861,15 +830,12 @@ def _measure_serving(smoke=False, flash_decode=None, chunked_prefill=True,
             "recompiles_after_warmup": m["recompiles"],
             "max_slots": serve_cfg["max_slots"],
             "chunk_size": serve_cfg["chunk_size"],
-            "chunked_prefill": chunked_prefill,
-            "prefill_chunk": m["prefill_chunk"] if chunked_prefill else None,
+            "prefill_chunk": m["prefill_chunk"],
             "spec_decode": spec_on,
             "int8_kv": int8_on,
             "prefix_cache": prefix_on,
             "host_offload": offload_on,
             "adapter": m.get("adapter"),
-            "sparse_decode": bool(sparse_decode),
-            "expert_parallel": bool(expert_parallel),
             "paged": paged_on,
             "page_len": m.get("kv_page_len"),
             "kv_pages_total": m.get("kv_pages_total"),
@@ -901,19 +867,15 @@ def _measure_serving(smoke=False, flash_decode=None, chunked_prefill=True,
     }
 
 
-def main_serve(smoke=False, flash_decode=None, chunked_prefill=True,
-               spec_decode=True, int8_kv=True, prefix_cache=True,
-               host_offload=True, sparse_decode=True,
-               expert_parallel=True, paged_kv=True):
+def main_serve(smoke=False, flash_decode=None, spec_decode=True,
+               int8_kv=True, prefix_cache=True, host_offload=True,
+               paged_kv=True):
     if not smoke:
         _require_tpu_or_exit()
     _emit(_measure_serving(smoke=smoke, flash_decode=flash_decode,
-                           chunked_prefill=chunked_prefill,
                            spec_decode=spec_decode, int8_kv=int8_kv,
                            prefix_cache=prefix_cache,
                            host_offload=host_offload,
-                           sparse_decode=sparse_decode,
-                           expert_parallel=expert_parallel,
                            paged_kv=paged_kv))
     return 0
 
@@ -1861,8 +1823,6 @@ def main_sweep():
 def _dispatch(argv):
     # --no-flash-decode: the einsum side of the decode-kernel A/B
     # (default None lets the engine pick — the Pallas kernel on TPU).
-    # --no-chunked-prefill: the legacy whole-prompt-bucket prefill side
-    # of the chunked-prefill A/B (default True — the fused mixed step).
     # --no-spec-decode: the draft-free side of the speculative-decoding
     # A/B (default True — n-gram drafting on; metric suffixed
     # _nospecdecode so the series never mix).
@@ -1870,12 +1830,6 @@ def _dispatch(argv):
     # hierarchy-off sides of the KV-memory-hierarchy A/Bs (default True
     # each; metric suffixed _noint8kv / _noprefixcache / _nohostoffload
     # so the series never mix).
-    # --no-sparse-decode / --no-expert-parallel: the adapter-feature
-    # A/B arms (default True each; metric suffixed _nosparsedecode /
-    # _noexpertparallel so the series never mix). The keys ride the
-    # serving config into ModelAdapter.bind — adapters with the feature
-    # honor them, the stock GPT-2 adapter records the arm and ignores
-    # them (docs/ADAPTERS.md).
     # --no-prefix-affinity: the directory-off side of the fleet
     # prefix-affinity A/B (--fleet/--fleet-smoke only; metric suffixed
     # _noprefixaffinity) — per-replica caches stay on, fleet routing
@@ -1886,13 +1840,10 @@ def _dispatch(argv):
     # suffixed _nodisagg so the series never mix). Either flag routes to
     # the disagg benchmark instead of the failover one.
     flash_decode = False if "--no-flash-decode" in argv else None
-    chunked = "--no-chunked-prefill" not in argv
     spec = "--no-spec-decode" not in argv
     int8_kv = "--no-int8-kv" not in argv
     prefix_cache = "--no-prefix-cache" not in argv
     host_offload = "--no-host-offload" not in argv
-    sparse_decode = "--no-sparse-decode" not in argv
-    expert_parallel = "--no-expert-parallel" not in argv
     # --no-paged-kv: the dense-pool side of the paged-KV A/B (default
     # True — page-granular pool on; metric suffixed _nopagedkv so the
     # series never mix).
@@ -1928,19 +1879,15 @@ def _dispatch(argv):
         return main_sustained(smoke="--smoke" in argv)
     if "--serve-smoke" in argv:
         return main_serve(smoke=True, flash_decode=flash_decode,
-                          chunked_prefill=chunked, spec_decode=spec,
+                          spec_decode=spec,
                           int8_kv=int8_kv, prefix_cache=prefix_cache,
                           host_offload=host_offload,
-                          sparse_decode=sparse_decode,
-                          expert_parallel=expert_parallel,
                           paged_kv=paged_kv)
     if "--serve" in argv:
         return main_serve(flash_decode=flash_decode,
-                          chunked_prefill=chunked, spec_decode=spec,
+                          spec_decode=spec,
                           int8_kv=int8_kv, prefix_cache=prefix_cache,
                           host_offload=host_offload,
-                          sparse_decode=sparse_decode,
-                          expert_parallel=expert_parallel,
                           paged_kv=paged_kv)
     if "--sweep" in argv:
         return main_sweep()

@@ -40,7 +40,7 @@ def hot_path(fn):
 HOT_PATH_FUNCTIONS = {
     "deepspeed_tpu/inference/engine.py": frozenset({
         "_mixed_step_program", "_decode_chunk_program",
-        "_spec_decode_chunk_program", "_prefill_program", "_sample_rows",
+        "_spec_decode_chunk_program", "_sample_rows",
     }),
     "deepspeed_tpu/models/generation.py": frozenset({
         "_forward", "decode_step", "append_forward", "verify_forward",
@@ -70,7 +70,7 @@ SANCTIONED_SYNC_SITES = {
         "harvest_snapshot", "max_active_frontier", "free_slots",
     }),
     "deepspeed_tpu/inference/engine.py": frozenset({
-        "_step_chunked", "_step_legacy",
+        "_step_once",
     }),
 }
 

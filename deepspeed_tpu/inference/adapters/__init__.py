@@ -7,7 +7,6 @@ imports inside ``inference/`` confined to ``adapters/gpt2.py``.
 
 from deepspeed_tpu.inference.adapters.protocol import ModelAdapter
 from deepspeed_tpu.inference.adapters.gpt2 import GPT2Adapter
-from deepspeed_tpu.inference.adapters.moe import MoEAdapter, MoECfg
 from deepspeed_tpu.inference.adapters.longcontext import LongContextAdapter
 from deepspeed_tpu.inference.adapters.decoder import DecoderAdapter
 from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
@@ -24,5 +23,5 @@ def adapter_class_for(model):
     return GPT2Adapter
 
 
-__all__ = ["adapter_class_for", "ModelAdapter", "GPT2Adapter", "MoEAdapter",
-           "MoECfg", "LongContextAdapter", "DecoderAdapter"]
+__all__ = ["adapter_class_for", "ModelAdapter", "GPT2Adapter",
+           "LongContextAdapter", "DecoderAdapter"]

@@ -72,10 +72,6 @@ class LongContextAdapter(GPT2Adapter):
             adapter = dataclasses.replace(
                 adapter, mode="ring",
                 gcfg=adapter.gcfg._replace(sparse_threshold=0))
-        if config is not None and not getattr(config, "sparse_decode", True):
-            # A/B flag (bench --no-sparse-decode): plain dense decode.
-            adapter = dataclasses.replace(
-                adapter, gcfg=adapter.gcfg._replace(sparse_threshold=0))
         # Paged cache-spec variant — same stamp as GPT2Adapter.bind:
         # the einsum path gathers the arena back to logical planes
         # before the sparse mask applies, so block-sparse decode and

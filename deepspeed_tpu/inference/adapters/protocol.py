@@ -31,8 +31,9 @@ conformance kit every adapter must pass):
   frontier. This is what the fleet's crash-replay bit-identity invariant
   (RESILIENCE.md) rests on — replayed requests land in different slots
   next to different neighbors and must emit the same stream. An adapter
-  with cross-row coupling (e.g. MoE capacity dropping) must neutralize it
-  (see adapters/moe.py) or document that it breaks the invariant.
+  with cross-row coupling (e.g. expert capacity dropping) must neutralize
+  it (adapters/decoder.py routes exact top-k with no capacity, so nothing
+  couples rows) or document that it breaks the invariant.
 """
 
 
@@ -42,7 +43,7 @@ class ModelAdapter:
     Required surface: ``cache_spec`` / ``init_cache`` / ``prefill_append``
     / ``decode_step`` / ``verify_forward`` (plus the drafting pair for
     speculative decode). Optional hooks (``bind``, ``aux_state``,
-    ``observe``, ``param_shardings``) have inert defaults.
+    ``observe``) have inert defaults.
     """
 
     name = "adapter"
@@ -100,16 +101,17 @@ class ModelAdapter:
     # ------------------------------------------------------------------
     def bind(self, config, mesh=None):
         """Return the adapter specialized to an engine's InferenceConfig
-        and mesh (e.g. honor ``config.use_flash_decode`` /
-        ``config.sparse_decode`` / ``config.expert_parallel``, pick the
-        ring fallback when the mesh carries a 'seq' axis). Must return an
-        adapter — ``self`` when nothing changes."""
+        and mesh (e.g. honor ``config.use_flash_decode`` and
+        ``config.paged_kv``, pick the ring fallback when the mesh carries
+        a 'seq' axis). Must return an adapter — ``self`` when nothing
+        changes."""
         return self
 
     def aux_state(self):
         """Extra pool-resident model state: a dict of ``aux_``-prefixed
         arrays merged into the KV pool at build time and threaded through
-        every program (e.g. MoE per-expert load counters). NOT per-slot:
+        every program (e.g. DecoderAdapter's per-expert routed counts).
+        NOT per-slot:
         hierarchy capture/restore skips these keys."""
         return {}
 
@@ -118,10 +120,4 @@ class ModelAdapter:
         of pool state, including ``aux_`` keys) into a telemetry
         MetricsRegistry. Called once per engine step batch — keep it
         cheap and host-only."""
-        return None
-
-    def param_shardings(self, mesh, params):
-        """Optional NamedSharding pytree for ``params`` on ``mesh``; None
-        defers to the engine's default (zero_shardings stage 0 with the
-        standard tensor-parallel rules)."""
         return None

@@ -8,14 +8,12 @@ against the model's position budget at engine construction.
 Every field is a COMPILE-SHAPE knob or a host-side policy knob — nothing
 here varies per request (per-request sampling params travel as traced
 device values, see engine.py), which is what bounds the compile count:
-ONE mixed-step program under chunked prefill (the default), or one
-prefill program per prompt bucket + one decode-chunk program on the
-legacy path (``chunked_prefill=False``).
+ONE mixed-step program serves prefill chunks and decode together.
 """
 
 import dataclasses
 import os
-from typing import Optional, Tuple
+from typing import Optional
 
 # The JSON block under "inference" in ds_config (runtime/config.py reads
 # it with these defaults; InferenceConfig.from_dict consumes the result).
@@ -23,12 +21,10 @@ INFERENCE_DEFAULTS = {
     "max_slots": 8,
     "max_len": 512,
     "chunk_size": 16,
-    "prefill_buckets": None,
     "max_queue": 64,
     "eos_token_id": None,
     "max_new_tokens": 128,
     "use_flash_decode": None,
-    "chunked_prefill": True,
     "prefill_chunk": 32,
     "spec_decode": None,
     "spec_k": 4,
@@ -50,25 +46,10 @@ INFERENCE_DEFAULTS = {
     "swap_slots": 8,
     "hbm_budget_bytes": None,
     "role": "mixed",
-    "sparse_decode": True,
-    "expert_parallel": True,
     "paged_kv": False,
     "kv_page_len": 128,
     "kv_pages": None,
 }
-
-
-def default_buckets(max_len):
-    """Power-of-two prompt buckets up to ``max_len``: each admitted prompt
-    pads to the smallest covering bucket, so prefill compiles at most
-    log2(max_len) programs regardless of prompt-length mix."""
-    buckets = []
-    b = 16
-    while b < max_len:
-        buckets.append(b)
-        b *= 2
-    buckets.append(max_len)
-    return tuple(buckets)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,11 +64,6 @@ class InferenceConfig:
     # and eviction happen only at chunk boundaries: larger chunks amortize
     # dispatch, smaller chunks cut admission latency.
     chunk_size: int = 16
-    # Prompt-length buckets for prefill padding (sorted ascending). None
-    # derives power-of-two buckets from max_len. LEGACY-path only: under
-    # chunked_prefill there is no whole-prompt program to pad for and the
-    # table is inert.
-    prefill_buckets: Optional[Tuple[int, ...]] = None
     # Queued (not yet admitted) request cap — submit() raises QueueFull
     # beyond it. The backpressure boundary for upstream callers.
     max_queue: int = 64
@@ -107,11 +83,8 @@ class InferenceConfig:
     # Chunked prefill (Sarathi-style): prompts are consumed
     # ``prefill_chunk`` tokens at a time INSIDE the decode step program —
     # one mixed-batch program total, no per-bucket prefill compiles, no
-    # decode stall while a long prompt admits. False restores the legacy
-    # whole-prompt-per-bucket prefill path (the ``prefill_buckets`` table
-    # only applies there).
-    chunked_prefill: bool = True
-    # Prompt tokens consumed per engine step while a slot is prefilling.
+    # decode stall while a long prompt admits. ``prefill_chunk`` is the
+    # prompt tokens consumed per engine step while a slot is prefilling.
     # Larger chunks finish prefill in fewer steps (better TTFT for the
     # prefilling request); smaller chunks bound the extra latency each
     # step adds for already-decoding slots. Also the KV plane slack the
@@ -122,9 +95,8 @@ class InferenceConfig:
     # enables it engine-wide, False disables, None defers to the
     # DS_TPU_SPEC_DECODE env and then to OFF (opt-in: acceptance depends
     # on workload repetitiveness, and the verify pass widens every decode
-    # step from 1 to spec_k+1 query rows). Requires chunked_prefill —
-    # speculation rides the mixed-step program's decode lane. Per-request
-    # opt-out via submit(spec_decode=False) cohabits the same program.
+    # step from 1 to spec_k+1 query rows). Per-request opt-out via
+    # submit(spec_decode=False) cohabits the same program.
     spec_decode: Optional[bool] = None
     # Draft length K: each decode step verifies K drafted tokens plus the
     # frontier token in one K+1-row forward, emitting 1..K+1 tokens.
@@ -187,8 +159,7 @@ class InferenceConfig:
     # detects shared prefixes at admission and aliases the matched span
     # onto a read-only prefix plane — the slot's private plane only holds
     # the suffix, and prefill skips the aliased span entirely (the TTFT
-    # win). Requires chunked_prefill (the aliasing rides the mixed-step
-    # program's cache view).
+    # win).
     prefix_cache: bool = False
     # Read-only prefix plane rows (compile-shape: the gather dimension of
     # the prefix store). Refcounted; LRU-evicted when full.
@@ -201,7 +172,7 @@ class InferenceConfig:
     min_prefix_len: int = 8
     # Host offload: swap an idle session's KV slot (planes + scalars) to
     # host RAM via fixed-shape transfers and restore on resume, driven by
-    # the scheduler's ``swapped`` phase. Requires chunked_prefill.
+    # the scheduler's ``swapped`` phase.
     host_offload: bool = False
     # Max concurrently swapped-out sessions (bounds host RAM at
     # swap_slots * bytes-per-slot).
@@ -224,19 +195,8 @@ class InferenceConfig:
     # decode replica is the fallback that keeps zero-lost true). Both
     # non-mixed roles ride the mixed-step program (the prefill lane is
     # lax.cond-skipped when unused), so compile_count stays 1 either
-    # way. Requires chunked_prefill.
+    # way.
     role: str = "mixed"
-    # --- Model-adapter policy switches (inference/adapters/) ------------
-    # Honored by ``ModelAdapter.bind`` at engine construction; inert for
-    # adapters without the corresponding feature (GPT2Adapter ignores
-    # both). False disables LongContextAdapter's block-sparse decode
-    # window — attention stays dense at every position (the bench
-    # --no-sparse-decode A/B arm).
-    sparse_decode: bool = True
-    # False strips the expert-sharding TP rule so MoE expert stacks
-    # replicate instead of sharding over 'model' (the bench
-    # --no-expert-parallel A/B arm).
-    expert_parallel: bool = True
     # --- Paged KV cache (inference/paging.py + kv_pool.py) --------------
     # Store the KV plane as a shared PAGE ARENA [L, P, H, page_len, D]
     # plus a per-slot int32 block table [slots, plane_len/page_len]:
@@ -299,20 +259,6 @@ class InferenceConfig:
             raise ValueError("inference.replica_id must be >= 0 (or None "
                              "outside a fleet), got "
                              "{}".format(self.replica_id))
-        if self.spec_decode and not self.chunked_prefill:
-            raise ValueError(
-                "inference.spec_decode=True requires chunked_prefill: "
-                "speculation is fused into the mixed-step program's decode "
-                "lane (the legacy bucket path has no speculation lane)")
-        if self.prefix_cache and not self.chunked_prefill:
-            raise ValueError(
-                "inference.prefix_cache=True requires chunked_prefill: "
-                "prefix aliasing rides the mixed-step program's cache view "
-                "(the legacy bucket path prefills whole prompts)")
-        if self.host_offload and not self.chunked_prefill:
-            raise ValueError(
-                "inference.host_offload=True requires chunked_prefill: "
-                "swap decisions happen at the mixed-step admission boundary")
         if self.prefix_slots < 1:
             raise ValueError("inference.prefix_slots must be >= 1, got "
                              "{}".format(self.prefix_slots))
@@ -334,11 +280,6 @@ class InferenceConfig:
             raise ValueError(
                 "inference.role must be one of 'mixed'/'prefill'/'decode', "
                 "got {!r}".format(self.role))
-        if self.role != "mixed" and not self.chunked_prefill:
-            raise ValueError(
-                "inference.role={!r} requires chunked_prefill: the handoff "
-                "capture rides the mixed-step path (the legacy bucket path "
-                "has no step boundary to capture at)".format(self.role))
         if self.kv_page_len < 1:
             raise ValueError("inference.kv_page_len must be >= 1, got "
                              "{}".format(self.kv_page_len))
@@ -346,24 +287,10 @@ class InferenceConfig:
             raise ValueError("inference.kv_pages must be >= 1 (or None for "
                              "dense-parity capacity), got "
                              "{}".format(self.kv_pages))
-        if self.paged_kv and not self.chunked_prefill:
-            raise ValueError(
-                "inference.paged_kv=True requires chunked_prefill: page "
-                "mapping advances at the mixed-step boundary (the legacy "
-                "bucket path has no per-chunk frontier bookkeeping)")
         if self.hbm_budget_bytes is not None and self.hbm_budget_bytes <= 0:
             raise ValueError(
                 "inference.hbm_budget_bytes must be > 0 (or None for the "
                 "flat-pool baseline), got {}".format(self.hbm_budget_bytes))
-        buckets = self.prefill_buckets
-        if buckets is None:
-            buckets = default_buckets(self.max_len)
-        buckets = tuple(sorted(int(b) for b in buckets))
-        if not buckets or buckets[-1] > self.max_len:
-            raise ValueError(
-                "inference.prefill_buckets {} must be non-empty and <= "
-                "max_len={}".format(buckets, self.max_len))
-        object.__setattr__(self, "prefill_buckets", buckets)
 
     @classmethod
     def from_dict(cls, block):
@@ -376,32 +303,15 @@ class InferenceConfig:
             raise ValueError(
                 "unknown inference config key(s) {}; valid keys: {}".format(
                     sorted(unknown), sorted(INFERENCE_DEFAULTS)))
-        merged = dict(INFERENCE_DEFAULTS, **block)
-        if merged["prefill_buckets"] is not None:
-            merged["prefill_buckets"] = tuple(merged["prefill_buckets"])
-        return cls(**merged)
-
-    def bucket_for(self, prompt_len):
-        """Smallest prefill bucket covering ``prompt_len`` (ValueError when
-        the prompt exceeds every bucket)."""
-        for b in self.prefill_buckets:
-            if prompt_len <= b:
-                return b
-        raise ValueError(
-            "prompt of {} tokens exceeds the largest prefill bucket {} "
-            "(max_len={})".format(prompt_len, self.prefill_buckets[-1],
-                                  self.max_len))
+        return cls(**dict(INFERENCE_DEFAULTS, **block))
 
     def resolved_spec_decode(self):
         """The effective speculative-decoding switch: the explicit field
         wins; ``None`` defers to the ``DS_TPU_SPEC_DECODE`` env (any
-        value but ``0``/``false`` turns it on — the bench/driver hook),
-        and the env only applies where speculation CAN run (chunked
-        prefill); the final default is off."""
+        value but ``0``/``false`` turns it on — the bench/driver hook);
+        the final default is off."""
         if self.spec_decode is not None:
             return bool(self.spec_decode)
-        if not self.chunked_prefill:
-            return False
         env = os.environ.get("DS_TPU_SPEC_DECODE", "")
         if env:
             return env not in ("0", "false")

@@ -1,13 +1,14 @@
 """InferenceEngine — continuous-batching serving over the slotted KV pool.
 
-Chunked prefill (the default, Sarathi-Serve-style — Agrawal et al.,
-OSDI'24) serves every request mix with ONE jitted program:
+Chunked prefill (Sarathi-Serve-style — Agrawal et al., OSDI'24) serves
+every request mix with ONE jitted program:
 
 Every model computation goes through the ModelAdapter protocol
 (inference/adapters/protocol.py) — the engine never imports a model
 module (graftlint ADAPTER rule); the adapter instance IS the jit static
-argument, so GPT-2, MoE and long-context workloads each get their own
-single compiled program through identical engine code.
+argument, so GPT-2, expert (``DecoderLM``) and long-context workloads
+each get their own single compiled program through identical engine
+code.
 
 - MIXED STEP (one compile, ever): a PREFILL LANE appends one
   ``prefill_chunk``-token slice of ONE slot's prompt at its cursor
@@ -36,11 +37,6 @@ tokens the model itself would have chosen), and per-request opt-out
 (``submit(spec_decode=False)``) rides the same program via a traced
 per-slot flag that vetoes draft agreement.
 
-``chunked_prefill=False`` restores the legacy pair — PREFILL (one
-compile per prompt bucket: whole prompt at batch dim 1, decode stalled
-while it runs) + DECODE CHUNK — for A/B runs (`bench.py --serve
---no-chunked-prefill`).
-
 Inactive slots are frozen in every program — pos pinned, emissions
 masked — exactly the trick ``generate`` uses for early-EOS rows, so
 occupancy changes never change a program.
@@ -49,7 +45,7 @@ The host loop (``step()``) runs the Orca cycle at step boundaries:
 admit queued requests into free slots, feed the oldest prefilling
 slot's next prompt chunk, decode, harvest emitted tokens in ONE batched
 host sync, evict finished slots. Under greedy decoding the emitted
-tokens are token-identical to sequential ``generate`` calls — all paths
+tokens are token-identical to sequential ``generate`` calls — both
 drive the same adapter ``decode_step`` primitive.
 
 CRASH-ONLY serving (docs/RESILIENCE.md): the host-side request records
@@ -232,33 +228,6 @@ class _CounterBank(object):
 
 
 @hot_path
-def _prefill_program(params, adapter, pool, prompt, prompt_len, slot,
-                     max_new, eos_id, temp, top_k, seed):
-    """LEGACY path: admit one request into ``slot`` with a whole-prompt
-    pass. ``prompt`` is [1, bucket] (padded right; pad ids are arbitrary
-    — their logits are never read and their k/v writes sit beyond the
-    frontier). Returns (pool', first_token). The explicit ``pos``
-    install below overrides the append's own frontier advance, so the
-    adapter's prefill primitive serves both entry modes."""
-    with jax.named_scope("prefill_lane"):
-        cache = slot_cache_view(pool, slot, jnp.zeros((1,), jnp.int32))
-        logits, cache = adapter.prefill_append(params, prompt, cache)
-        last = logits[0, prompt_len - 1]                # true last row [V]
-        first = _sample_rows(last[None], temp[None], top_k[None],
-                             seed[None], prompt_len[None])[0]
-        pool = write_slot_cache(pool, slot, cache)
-    # The first token counts against the budget; a request can finish at
-    # admission (max_new==1, or its first token IS its EOS).
-    finished = (max_new <= 1) | ((eos_id >= 0) & (first == eos_id))
-    for name, val in (("pos", prompt_len), ("last_tok", first),
-                      ("active", ~finished), ("remaining", max_new - 1),
-                      ("eos", eos_id), ("temp", temp), ("top_k", top_k),
-                      ("seed", seed)):
-        pool[name] = pool[name].at[slot].set(val)
-    return pool, first
-
-
-@hot_path
 def _decode_chunk_program(params, adapter, chunk, pool):
     """Advance every ACTIVE slot ``chunk`` tokens in one scan. Returns
     (pool', tokens [chunk, slots], valid [chunk, slots]) — valid[t, s]
@@ -387,8 +356,7 @@ def _mixed_step_program(params, adapter, chunk, spec, pool, p_ids, p_slot,
     real) into slot ``p_slot``'s planes at frontier ``p_frontier``. When
     ``p_done`` marks the prompt's final slice, sample the first token
     and install the request's per-slot state (it starts decoding in
-    THIS step's decode lane — the same cadence as the legacy
-    admit-then-decode step). ``p_valid == 0`` means no prefill work and
+    THIS step's decode lane). ``p_valid == 0`` means no prefill work and
     the whole lane is skipped by ``lax.cond`` — an idle lane costs no
     FLOPs, so pure-decode steady state is unchanged.
 
@@ -431,9 +399,9 @@ def _mixed_step_program(params, adapter, chunk, spec, pool, p_ids, p_slot,
                              p_seed[None], (p_frontier + p_valid)[None])[0]
         pool = write_slot_cache(pool, p_slot, cache)
         # Mid-prefill slices only move the frontier; the final slice
-        # installs the full decode state (same fields as the legacy
-        # prefill). First token counts against the budget; a request can
-        # finish at admission (max_new==1, or its first token IS EOS).
+        # installs the full decode state. First token counts against the
+        # budget; a request can finish at admission (max_new==1, or its
+        # first token IS EOS).
         finished = (p_max_new <= 1) | ((p_eos >= 0) & (first == p_eos))
         for name, val in (("last_tok", first),
                           ("active", p_done & ~finished),
@@ -511,8 +479,9 @@ class InferenceEngine(object):
         # own class (``adapter_class_for``: a DecoderLM's DecoderAdapter,
         # else GPT-2's) over the model's config — the engine's
         # use_flash_decode wins over the model config's, None defers
-        # down the chain (model config, then on-TPU default). ``bind`` lets any adapter specialize to this
-        # engine's config and mesh (sparse/ring mode, expert parallelism).
+        # down the chain (model config, then on-TPU default). ``bind``
+        # lets any adapter specialize to this engine's config and mesh
+        # (the page quantum, sparse/ring mode).
         # The adapter IS the static arg of every jitted program, so the
         # model dispatch is baked at trace time — no per-call branching,
         # and the compile-count contract is per (engine, adapter).
@@ -554,7 +523,7 @@ class InferenceEngine(object):
         # Speculation raises the floor to spec_k+1: a verify writes
         # spec_k+1 k/v positions at the frontier and the ring takes the
         # spec_k+1 choices one past it.
-        slack = config.prefill_chunk if config.chunked_prefill else 0
+        slack = config.prefill_chunk
         if self._spec is not None:
             slack = max(slack, config.spec_k + 1)
         self._slack = slack
@@ -594,54 +563,34 @@ class InferenceEngine(object):
         self._tp = mesh is not None and mesh_lib.mp_size(mesh) > 1
         pool = self._build_pool()
         if self._tp:
-            # Adapter hook first (e.g. MoE's expert-parallel A/B picks
-            # its own TP rules); None falls back to the standard rules.
-            param_sh = self._adapter.param_shardings(mesh, params)
-            if param_sh is None:
-                param_sh, _, _ = mesh_lib.zero_shardings(mesh, params,
-                                                         stage=0)
+            param_sh, _, _ = mesh_lib.zero_shardings(mesh, params, stage=0)
             params = jax.tree_util.tree_map(jax.device_put, params, param_sh)
             pool_out = pool_shardings(mesh, pool, self._gcfg.n_head)
             rep = mesh_lib.replicated(mesh)
-            prefill_out = (pool_out, rep)
-            decode_out = (pool_out, rep, rep)
             mixed_out = (pool_out, rep, rep, rep)
         else:
-            prefill_out = decode_out = mixed_out = None
+            mixed_out = None
         self._params = params
         self._pool = pool
 
-        # Per-engine jit instances: their _cache_size() IS the compile
+        # Per-engine jit instance: its _cache_size() IS the compile
         # counter the zero-recompile guarantee is asserted against. The
-        # ``own`` wrapper gives each engine a distinct callable
-        # — jax's pjit cache is keyed on the underlying function, so two
-        # engines jitting the bare program would pool their cache entries
-        # and the counter would read other engines' compiles. Donating
-        # the pool threads one cache allocation through every program
-        # call instead of double-buffering gigabytes of k/v. All three
-        # wrappers exist on every engine (trace-free until called);
-        # chunked mode only ever calls _mixed, legacy only the other two.
-        def own(program, name):
-            # This engine's own callable, traced with the kernels launched
-            # shard-local over its mesh (no mesh: launched as they are),
-            # and named for what it is: a trace's ``hlo_module`` reads
-            # ``jit_<name>``.
-            def on_mesh(*args):
-                with kernels_on_mesh(mesh):
-                    return program(*args)
-            on_mesh.__name__ = on_mesh.__qualname__ = name
-            return on_mesh
+        # engine's own callable gives it a distinct jit cache — jax's
+        # pjit cache is keyed on the underlying function, so two engines
+        # jitting the bare program would pool their cache entries and
+        # the counter would read other engines' compiles. Donating the
+        # pool threads one cache allocation through every program call
+        # instead of double-buffering gigabytes of k/v.
+        def mixed_step(*args):
+            # Traced with the kernels launched shard-local over this
+            # engine's mesh (no mesh: launched as they are), and named
+            # for what it is: a trace's ``hlo_module`` reads
+            # ``jit_mixed_step``.
+            with kernels_on_mesh(mesh):
+                return _mixed_step_program(*args)
 
-        self._prefill = jax.jit(
-            own(_prefill_program, "prefill"), static_argnums=(1,),
-            donate_argnums=(2,), out_shardings=prefill_out)
-        self._decode = jax.jit(
-            own(_decode_chunk_program, "decode_chunk"),
-            static_argnums=(1, 2),
-            donate_argnums=(3,), out_shardings=decode_out)
         self._mixed = jax.jit(
-            own(_mixed_step_program, "mixed_step"),
-            static_argnums=(1, 2, 3),
+            mixed_step, static_argnums=(1, 2, 3),
             donate_argnums=(4,), out_shardings=mixed_out)
 
         # Perf X-ray (telemetry/xray.py): the compiled-program cost/
@@ -657,16 +606,12 @@ class InferenceEngine(object):
 
         # Recompile detection: the test-only compile_count contract as a
         # RUNTIME gauge. The mixed program auto-warms after its first
-        # step; the legacy path warms per exercised bucket, so the
-        # caller (bench's A/B warmup) calls mark_warm() explicitly. The
-        # xray identity hook makes the post-warm warning name the exact
-        # program (HLO fingerprint, old -> new shapes).
+        # step. The xray identity hook makes the post-warm warning name
+        # the exact program (HLO fingerprint, old -> new shapes).
         self.recompile_detector = RecompileDetector(
             self.telemetry,
             describe=self._xray.identity if self._xray is not None
             else None)
-        self.recompile_detector.watch("prefill", self._prefill)
-        self.recompile_detector.watch("decode_chunk", self._decode)
         self.recompile_detector.watch("mixed_step", self._mixed)
 
         self.timers = SynchronizedWallClockTimer(registry=self.telemetry)
@@ -1027,8 +972,6 @@ class InferenceEngine(object):
             max_new_tokens = self.config.max_new_tokens
         if max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
-        if not self.config.chunked_prefill:
-            self.config.bucket_for(prompt.size)  # raises when over-long
         if prompt.size + max_new_tokens > self.config.max_len:
             raise ValueError(
                 "prompt ({} tokens) + max_new_tokens ({}) exceeds "
@@ -1148,38 +1091,6 @@ class InferenceEngine(object):
                               .at[slot].set(False))
         return True
 
-    # ----------------------------------------------------- legacy admit
-
-    def _dispatch_prefill(self, req, slot):
-        """Dispatch one legacy whole-prompt prefill; returns the first
-        token as a DEVICE value — the host sync happens batched in
-        step() after every admission has been dispatched."""
-        bucket = self.config.bucket_for(req.prompt.size)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :req.prompt.size] = req.prompt
-        padded_d = jnp.asarray(padded)
-        n_d, slot_d = jnp.int32(req.prompt.size), jnp.int32(slot)
-        max_new_d = jnp.int32(req.max_new_tokens)
-        eos_d = jnp.int32(req.eos_token_id)
-        temp_d = jnp.float32(req.temperature)
-        top_k_d, seed_d = jnp.int32(req.top_k), jnp.uint32(req.seed)
-        if self._xray is not None:
-            # One stash per exercised bucket (bucket variety is the
-            # legacy path's EXPECTED compile shape, so only post-warm
-            # changes are tracked as recompiles).
-            self._xray.stash(
-                "prefill", self._prefill, self._params, self._adapter,
-                self._pool, padded_d, n_d, slot_d, max_new_d, eos_d,
-                temp_d, top_k_d, seed_d, donate=("pool",),
-                track_change=self.recompile_detector.warm)
-            self._xray.note("prefill", tokens=1)
-        self._pool, first = self._prefill(
-            self._params, self._adapter, self._pool, padded_d,
-            n_d, slot_d, max_new_d, eos_d, temp_d, top_k_d, seed_d)
-        self.counters["prefills"] += 1
-        self.counters["prefill_tokens"] += int(req.prompt.size)
-        return first
-
     def _harvest_first(self, req, first, done):
         """Record a request's first token (TTFT stamps HERE — at
         harvest, after the device sync — never at dispatch). On a
@@ -1218,14 +1129,12 @@ class InferenceEngine(object):
         done.append(req)
 
     def _observe_compiles(self):
-        """Step-boundary recompile check (three int reads). The mixed
+        """Step-boundary recompile check (one int read). The mixed
         program warms itself after its first step — its contract is ONE
-        compile ever, so anything later is a recompile worth paging on.
-        The legacy path compiles per exercised prompt bucket and cannot
-        self-warm; callers mark_warm() after their own warmup."""
+        compile ever, so anything later is a recompile worth paging on."""
         det = self.recompile_detector
         if not det.warm:
-            if self.config.chunked_prefill and det.total() >= 1:
+            if det.total() >= 1:
                 det.mark_warm()
             return
         det.observe()
@@ -1256,10 +1165,7 @@ class InferenceEngine(object):
                     time.sleep(stall)
                 with self.tracer.timed("inference/step",
                                        step=self._steps + 1):
-                    if self.config.chunked_prefill:
-                        done = self._step_chunked()
-                    else:
-                        done = self._step_legacy()
+                    done = self._step_once()
         except self._fatal as exc:
             done = self._recover(exc)
         else:
@@ -1851,7 +1757,7 @@ class InferenceEngine(object):
         self.counters["handoffs_in"] += 1
         return req
 
-    def _step_chunked(self):
+    def _step_once(self):
         done = []
         self._steps += 1
         step = self._steps
@@ -1898,8 +1804,8 @@ class InferenceEngine(object):
             top_k_d, seed_d = jnp.int32(top_k), jnp.uint32(seed)
             if self._xray is not None:
                 # Shapes-only capture (signature tuple + dict compare in
-                # the steady state). track_change only after warmup so the
-                # legacy of per-bucket variety never logs as a recompile.
+                # the steady state). track_change only after warmup: the
+                # first stash is the program's expected one compile.
                 self._xray.stash(
                     "mixed_step", self._mixed, self._params, self._adapter,
                     self.config.chunk_size, self._spec, self._pool, ids_d,
@@ -2030,71 +1936,6 @@ class InferenceEngine(object):
             self._observe_compiles()
         return done
 
-    def _step_legacy(self):
-        done = []
-        admitted = []
-        if self._injector is not None:
-            self._injector.maybe_raise()
-        self._steps += 1
-        step = self._steps
-        self.timers("inference/prefill").start()
-        with self.tracer.timed("inference/prefill", step=step):
-            for req, slot in self._scheduler.admissions():
-                # Dispatch EVERY prefill before the first host sync: N
-                # admissions pipeline on device instead of paying N
-                # dispatch->int(first) round-trips.
-                admitted.append((req, self._dispatch_prefill(req, slot)))
-            for req, first in admitted:
-                self._scheduler.advance_prefill(req, req.prompt.size)
-                self._harvest_first(req, int(first), done)
-        self.timers("inference/prefill").stop()
-
-        if self._scheduler.running:
-            self.timers("inference/decode").start()
-            if self._xray is not None:
-                self._xray.stash(
-                    "decode_chunk", self._decode, self._params,
-                    self._adapter, self.config.chunk_size, self._pool,
-                    donate=("pool",),
-                    track_change=self.recompile_detector.warm)
-            tok_before = self.counters["tokens_out"]
-            t_dispatch = time.perf_counter()
-            with self.tracer.timed("inference/decode_chunk", step=step):
-                self._pool, toks, valid = self._decode(
-                    self._params, self._adapter, self.config.chunk_size,
-                    self._pool)
-            t_harvest = time.perf_counter()
-            self.timers("inference/decode").stop()
-            with self.tracer.timed("inference/harvest", step=step):
-                toks = np.asarray(toks)
-                valid = np.asarray(valid)
-                snap = harvest_snapshot(self._pool)
-            if self._xray is not None:
-                self._xray.observe_step(
-                    "decode_chunk", t_harvest - t_dispatch,
-                    time.perf_counter() - t_harvest)
-            self._last_snap = snap
-            active = snap["active"]
-            self._adapter.observe(snap, self.telemetry)
-            if self._injector is not None:
-                toks = self._injector.corrupt_harvest(toks, valid)
-            self._check_harvest(toks, valid)
-            self.counters["chunks"] += 1
-            self.counters["occupied_slot_steps"] += int(valid.sum())
-            self.counters["slot_steps"] += valid.size
-            for slot, req in list(self._scheduler.running.items()):
-                emitted = toks[valid[:, slot], slot].tolist()
-                req.tokens.extend(emitted)
-                self.counters["tokens_out"] += len(emitted)
-                if not active[slot]:
-                    self._complete(req, done)
-            if self._xray is not None:
-                self._xray.note("decode_chunk",
-                                tokens=self.counters["tokens_out"]
-                                - tok_before)
-        self._observe_compiles()
-        return done
-
     @property
     def idle(self):
         """True when no request is queued or in a slot — the drive
@@ -2185,9 +2026,8 @@ class InferenceEngine(object):
     def compile_count(self):
         """Total compiled program count across every engine program — the
         number the zero-recompile-after-warmup guarantee is asserted on.
-        Chunked prefill: 1 after warmup (the mixed step), whatever the
-        prompt-length mix. Legacy: 1 decode chunk + one prefill per
-        prompt bucket exercised. CUMULATIVE — windows never reset it."""
+        1 after warmup (the mixed step), whatever the prompt-length
+        mix. CUMULATIVE — windows never reset it."""
         return self.recompile_detector.total()
 
     def _latency_percentiles(self):
@@ -2243,13 +2083,10 @@ class InferenceEngine(object):
                 "slots_prefilling").value),
             "compile_count": self.compile_count,
             "recompiles": int(self.recompile_detector.recompiles.value),
-            "prefill_seconds": self.timers(
-                "inference/prefill").elapsed(reset=reset),
             "decode_seconds": self.timers(
                 "inference/decode").elapsed(reset=reset),
             "adapter": self._adapter.name,
             "flash_decode": bool(self._gcfg.use_flash_decode),
-            "chunked_prefill": bool(self.config.chunked_prefill),
             "prefill_chunk": self.config.prefill_chunk,
             # Derived from the LAST step's harvest: a scrape (often a
             # foreign exporter thread) must never pay a device sync of
@@ -2392,12 +2229,11 @@ class InferenceEngine(object):
         (telemetry/xray.py): per-program HLO fingerprints, cost-model
         flops/bytes, the peak-HBM split, flops/bytes per token, the
         HBM ledger, and any post-warm recompile events — of the programs
-        this engine DISPATCHED (chunked mode: ``mixed_step``; legacy
-        mode: ``prefill`` per bucket and ``decode_chunk``), each analysed
-        from the shapes its step path stashed. First call pays the
-        one-time AOT lower+compile of each of those (off the steady
-        path; never grows a jit dispatch cache), and of nothing the
-        engine never ran. None when ``config.perf_xray`` is off."""
+        this engine DISPATCHED (``mixed_step``), each analysed from the
+        shapes its step path stashed. First call pays the one-time AOT
+        lower+compile of each of those (off the steady path; never
+        grows a jit dispatch cache), and of nothing the engine never
+        ran. None when ``config.perf_xray`` is off."""
         if self._xray is None:
             return None
         out = self._xray.to_json()

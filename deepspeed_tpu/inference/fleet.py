@@ -423,8 +423,8 @@ class ServingFleet(object):
         # Disaggregated serving: one role string per replica. Default —
         # every replica takes config.role (itself defaulting "mixed"),
         # so an undecorated fleet behaves exactly as before. Per-role
-        # field validation (and the chunked_prefill requirement) runs in
-        # InferenceConfig.__post_init__ via the per-replica replace().
+        # field validation runs in InferenceConfig.__post_init__ via the
+        # per-replica replace().
         if roles is None:
             roles = [config.role] * n_replicas
         roles = [str(r) for r in roles]

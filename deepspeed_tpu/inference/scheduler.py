@@ -13,8 +13,7 @@ next step" (FIFO among prefilling slots), and "when" (every step).
 
 Request phases: ``queued -> prefilling -> decoding -> done`` (or
 ``cancelled`` from any live phase, or ``expired`` from ``queued`` when
-a request's deadline passes before admission). The legacy whole-prompt
-prefill path passes through ``prefilling`` for exactly one engine step.
+a request's deadline passes before admission).
 
 Disaggregated serving (inference/fleet.py) adds one more live phase:
 ``handoff`` — the request finished prefill on a prefill-role replica,
@@ -137,7 +136,7 @@ class Request(object):
         self.slot = None
         self.phase = "queued"
         # Prompt tokens consumed so far (chunked prefill walks this to
-        # len(prompt); the legacy path jumps it there in one step).
+        # len(prompt)).
         self.cursor = 0
         self.submit_time = time.time()
         self.admit_time = None
@@ -339,11 +338,10 @@ class Scheduler(object):
     def admissions(self, gate=None):
         """FIFO: pop (request, slot) pairs for every free slot while the
         queue lasts, moving each request into the ``prefilling`` phase
-        (admit_time stamped — queue-wait ends here). BOTH engine paths
-        (legacy whole-prompt prefill and the chunked mixed step) admit
-        through this one method, so queue_wait_seconds is stamped at the
-        same point whichever program runs — the windowed queue-wait
-        curve is comparable across configs. Called by the engine ONLY at
+        (admit_time stamped — queue-wait ends here). Every admission
+        comes through this one method, so queue_wait_seconds is stamped
+        at one point — the windowed queue-wait curve is comparable
+        across configs. Called by the engine ONLY at
         step boundaries — the device programs never see a mid-step batch
         change. Expired-deadline requests are shed before slots are
         filled; a replayed request (recovery re-admission) keeps its

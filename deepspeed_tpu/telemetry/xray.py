@@ -24,9 +24,9 @@ Three pieces:
   and the donation map. A genuinely NEW signature under the same label
   is a program-identity change: ``RecompileDetector`` warnings and the
   autopsy both name it through ``identity()`` / ``recompile_dicts()``.
-  A signature seen before (warm prompt buckets alternating on the
-  legacy prefill path) only flips the active pointer — it is in the
-  jit cache already, so nothing accumulates and nothing logs.
+  A signature seen before (a label alternating between shapes it has
+  already compiled) only flips the active pointer — it is in the jit
+  cache already, so nothing accumulates and nothing logs.
 
 - Roofline gauges: per-program ``xray_mfu`` / ``xray_mbu`` /
   ``xray_roofline_ratio`` from cost-model flops ÷ sampled step wall
@@ -195,7 +195,7 @@ class _Stash(object):
     analysis later (``record`` is filled by materialize()). ``calls``/
     ``tokens`` accumulate the note() accounting for the steps this
     signature was active — cost attribution stays per-signature even
-    when a label cycles through several (legacy prefill buckets)."""
+    when a label cycles through several."""
 
     __slots__ = ("label", "sig", "jitted", "args", "kwargs", "donate",
                  "record", "calls", "tokens")
@@ -241,8 +241,8 @@ class ProgramRegistry(object):
         self._gauged = set()     # labels with published gauges
         self._analysis = {}      # (id(jitted), sig) -> analysis dict
         # Program-identity changes flagged by a call site (the engine
-        # passes track_change=detector.warm, so pre-warmup bucket
-        # accumulation never lands here; an already-seen signature
+        # passes track_change=detector.warm, so a program's expected
+        # first compile never lands here; an already-seen signature
         # never lands here either — it is in the jit cache, so a flip
         # back to it is not a recompile). Fingerprints fill lazily at
         # materialize() — the shapes are exact from the stash itself.
@@ -281,8 +281,8 @@ class ProgramRegistry(object):
         """Capture one call's program identity. Returns True when the
         label's ACTIVE signature changed (first stash included).
 
-        A signature already seen under this label (the legacy prefill
-        path alternating between warm prompt buckets) only switches the
+        A signature already seen under this label (a program
+        alternating between shapes it has compiled) only switches the
         active pointer: the program is in the jit cache, so nothing is
         appended and no recompile event is logged — only a genuinely
         NEW signature captures a stash, and only a new one with

@@ -20,8 +20,13 @@ the engine is model-blind and trusts exactly these properties:
    (global, not per-slot) is excluded from the record but preserved in
    the pool.
 
-Plus the adapter-specific pins: MoE expert gauges + expert-parallel
-serving on a 2-axis mesh, and the long-context parity/capacity pair.
+The five that touch a cache run on BOTH pools: the dense slotted pool
+and the paged arena every benchmark cell serves from (kinds
+``gpt2-paged`` / ``decoder-paged``: a paged cache at the primitive
+level, ``paged_kv=True`` in the engine, ``capture_slot_paged`` /
+``restore_slot_paged`` for the round-trip).
+
+Plus the adapter-specific pins: the long-context parity/capacity pair.
 """
 
 import jax
@@ -35,16 +40,24 @@ from deepspeed_tpu.inference.adapters import (
     GPT2Adapter,
     LongContextAdapter,
     ModelAdapter,
-    MoEAdapter,
 )
 from deepspeed_tpu.inference.kv_hierarchy import offload
 from deepspeed_tpu.inference.kv_pool import harvest_snapshot
 from deepspeed_tpu.parallel import mesh as mesh_lib
+from tests.unit.test_decoder import paged_cache
 from tests.unit.test_inference import make_model, prompts_of, seq_greedy
 
-KINDS = ("gpt2", "moe", "longcontext", "decoder")
+KINDS = ("gpt2", "longcontext", "decoder")
+# The cache-touching contract tests also run on the paged pool.
+PAGED = ("gpt2-paged", "decoder-paged")
+PAGE = 8
 
 _ADAPTERS = {}
+
+
+def is_paged(kind):
+    return kind.endswith("-paged")
+
 
 
 def adapter_of(kind):
@@ -54,12 +67,12 @@ def adapter_of(kind):
     so the battery exercises the adapter plumbing while its masks stay
     dense (the sparse regime has its own pins below)."""
     if kind not in _ADAPTERS:
-        if kind == "moe":
-            a = MoEAdapter.from_config(vocab_size=256, n_layer=2, n_head=2,
-                                       n_embd=32, n_positions=128,
-                                       n_experts=4)
-            params = a.init_params(jax.random.PRNGKey(0))
-            _ADAPTERS[kind] = (a, params, 256)
+        if is_paged(kind):
+            # The same weights, the adapter bound as a paged engine binds
+            # it (the page quantum is part of the static config).
+            a, params, vocab = adapter_of(kind.split("-")[0])
+            bound = a.bind(InferenceConfig(paged_kv=True, kv_page_len=PAGE))
+            _ADAPTERS[kind] = (bound, params, vocab)
         elif kind == "decoder":
             model = decoder_model()
             a = DecoderAdapter.from_model(model, use_flash_decode=False)
@@ -86,6 +99,16 @@ def decoder_model():
         dtype=jnp.float32, initializer_range=0.15))
 
 
+def cache_of(kind, rows, max_len):
+    """An empty cache for ``rows`` sequences on ``kind``'s pool: the
+    adapter's dense planes, or a page arena with a block table of the
+    row's own pages."""
+    adapter, _, _ = adapter_of(kind)
+    if is_paged(kind):
+        return paged_cache(adapter, rows, page=PAGE, max_len=max_len)
+    return adapter.init_cache(rows, max_len)
+
+
 def ids_of(vocab, n, seed=5):
     rng = np.random.RandomState(seed)
     return rng.randint(0, vocab, size=(1, n)).astype(np.int32)
@@ -107,7 +130,9 @@ _PRIM_REFS = {}
 def primitive_greedy(kind, prompt, max_new, plane_len=96):
     """Sequential single-request greedy reference built from the
     adapter's OWN primitives — the oracle the slotted engine must match
-    (per-row independence makes batch composition irrelevant)."""
+    (per-row independence makes batch composition irrelevant). Always
+    over the DENSE cache: a paged engine is held to the same streams."""
+    kind = kind.split("-")[0]
     key = (kind, tuple(int(t) for t in prompt), int(max_new))
     if key not in _PRIM_REFS:
         adapter, params, _ = adapter_of(kind)
@@ -128,6 +153,9 @@ def engine_of_kind(kind, **kw):
     kw.setdefault("chunk_size", 4)
     kw.setdefault("prefill_chunk", 8)
     kw.setdefault("use_flash_decode", False)
+    if is_paged(kind):
+        kw.setdefault("paged_kv", True)
+        kw.setdefault("kv_page_len", PAGE)
     return InferenceEngine(None, params, config=kw, adapter=adapter)
 
 
@@ -143,7 +171,6 @@ def test_protocol_required_surface_raises_unimplemented():
     # Optional hooks have working defaults.
     assert base.bind(None) is base
     assert base.aux_state() == {}
-    assert base.param_shardings(None, None) is None
     assert base.observe(None, None) is None
 
 
@@ -159,15 +186,15 @@ def test_adapter_is_hashable_static_arg(kind):
 # ------------------------------------------------- 1. chunk-vs-whole
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", KINDS + PAGED)
 def test_chunk_vs_whole_prefill_parity(kind):
     adapter, params, vocab = adapter_of(kind)
     ids = jnp.asarray(ids_of(vocab, 12))
 
-    whole = adapter.init_cache(1, 32)
+    whole = cache_of(kind, 1, 32)
     logits_w, whole = adapter.prefill_append(params, ids, whole)
 
-    chunked = adapter.init_cache(1, 32)
+    chunked = cache_of(kind, 1, 32)
     for lo in (0, 4, 8):
         logits_c, chunked = adapter.prefill_append(
             params, ids[:, lo:lo + 4], chunked)
@@ -187,12 +214,12 @@ def test_chunk_vs_whole_prefill_parity(kind):
 # --------------------------------------- 2. deep frontier + stale rule
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", KINDS + PAGED)
 def test_append_at_deep_frontier_with_n_valid(kind):
     adapter, params, vocab = adapter_of(kind)
     ids = jnp.asarray(ids_of(vocab, 28, seed=7))
 
-    clean = adapter.init_cache(1, 48)
+    clean = cache_of(kind, 1, 48)
     logits, clean = adapter.prefill_append(params, ids, clean)
     want, _ = greedy_decode(adapter, params,
                             int(jnp.argmax(logits[0, -1])), clean, 4)
@@ -201,7 +228,7 @@ def test_append_at_deep_frontier_with_n_valid(kind):
     # true continuation (n_valid=2) — positions 26/27 get k/v for
     # GARBAGE tokens past the frontier.
     garbage = jnp.asarray(ids_of(vocab, 2, seed=99))
-    staged = adapter.init_cache(1, 48)
+    staged = cache_of(kind, 1, 48)
     _, staged = adapter.prefill_append(params, ids[:, :24], staged)
     tail = jnp.concatenate([ids[:, 24:26], garbage], axis=1)
     _, staged = adapter.prefill_append(params, tail, staged,
@@ -218,13 +245,13 @@ def test_append_at_deep_frontier_with_n_valid(kind):
 # ------------------------------------- 3. verify rollback invisibility
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", KINDS + PAGED)
 def test_verify_rollback_is_invisible(kind):
     adapter, params, vocab = adapter_of(kind)
     ids = jnp.asarray(ids_of(vocab, 10, seed=3))
 
     def stream(speculate):
-        cache = adapter.init_cache(1, 32)
+        cache = cache_of(kind, 1, 32)
         logits, cache = adapter.prefill_append(params, ids, cache)
         tok = int(jnp.argmax(logits[0, -1]))
         toks = [tok]
@@ -252,7 +279,7 @@ def test_verify_rollback_is_invisible(kind):
 # ----------------------------------- 4. engine: one program, parity
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", KINDS + PAGED)
 def test_engine_mixed_workload_single_compile_and_parity(kind):
     """Mixed greedy/sampled, spec-on/spec-off requests trickling through
     the slotted engine: ONE compiled program, greedy streams match the
@@ -296,32 +323,48 @@ def test_engine_mixed_workload_single_compile_and_parity(kind):
 # ------------------------------------- 5. capture/restore round-trip
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", KINDS + PAGED)
 def test_capture_restore_round_trip_excludes_aux(kind):
     adapter, params, vocab = adapter_of(kind)
     eng = engine_of_kind(kind)
     for n in (6, 9):
-        eng.submit(ids_of(vocab, n, seed=n)[0], max_new_tokens=8)
+        # Budgets that outlast the two steps: both slots are live (a
+        # finished paged slot has given its pages back).
+        eng.submit(ids_of(vocab, n, seed=n)[0], max_new_tokens=16)
     eng.step()
     eng.step()
     pool = eng._pool
 
-    rec = offload.capture_slot(pool, 0)
-    assert not any(k.startswith("aux_") for k in rec), \
+    if is_paged(kind):
+        # A slot's record is its LIVE pages in logical order; it restores
+        # into whatever fresh physical pages the allocator hands out.
+        pager = eng._pager
+        rows = [pager.row_pages(s) for s in (0, 1)]
+        rec = offload.capture_slot_paged(pool, 0, rows[0])
+        assert rec["k"].shape[1] == len(rows[0]) >= 1
+        to = 2  # the free slot: slot 1's pages are live
+        fresh = pager.alloc_pages(len(rows[0]))
+        assert not set(fresh) & set(rows[0] + rows[1])
+        restored = offload.restore_slot_paged(pool, to, rec, fresh)
+        at = (slice(None), np.asarray(fresh))
+        batched = offload.capture_slots_paged(pool, [0, 1], rows)
+    else:
+        rec = offload.capture_slot(pool, 0)
+        to = 1
+        restored = offload.restore_slot(pool, to, rec)
+        at = (slice(None), to)
+        batched = offload.capture_slots(pool, [0, 1])
+    assert not any(k.startswith("aux_") or k == "block_tbl" for k in rec), \
         "global aux state must not be captured per-slot"
-    restored = offload.restore_slot(pool, 1, rec)
-    np.testing.assert_array_equal(np.asarray(restored["k"][:, 1]),
-                                  rec["k"])
-    np.testing.assert_array_equal(np.asarray(restored["v"][:, 1]),
-                                  rec["v"])
+    np.testing.assert_array_equal(np.asarray(restored["k"])[at], rec["k"])
+    np.testing.assert_array_equal(np.asarray(restored["v"])[at], rec["v"])
     for name in ("pos", "last_tok", "active", "toks"):
-        np.testing.assert_array_equal(np.asarray(restored[name][1]),
+        np.testing.assert_array_equal(np.asarray(restored[name][to]),
                                       rec[name])
     # Batched capture agrees with the per-slot form.
-    batched = offload.capture_slots(pool, [0, 1])
     for name, val in rec.items():
         np.testing.assert_array_equal(batched[0][name], val)
-    if kind in ("moe", "decoder"):
+    if kind.startswith("decoder"):
         # aux rides the harvest snapshot and survives restore untouched.
         assert "aux_moe_load" in restored
         snap = harvest_snapshot(restored)
@@ -358,82 +401,6 @@ def test_init_inference_picks_the_adapter_from_the_models_class(kind):
     assert len(req.tokens) == 5 and eng.compile_count == 1
     if kind == "decoder":
         assert req.tokens == primitive_greedy("decoder", ids_of(256, 7)[0], 5)
-
-
-# ------------------------------------------------------- MoE specifics
-
-
-def test_moe_expert_gauges_and_no_drops():
-    adapter, params, vocab = adapter_of("moe")
-    eng = engine_of_kind("moe")
-    for n in (6, 10, 7):
-        eng.submit(ids_of(vocab, n, seed=n)[0], max_new_tokens=6)
-    eng.run()
-    reg = eng.telemetry
-    load = [reg.gauge("moe_expert_load", expert=str(i)).value
-            for i in range(4)]
-    assert sum(load) > 0, "no expert dispatch was observed"
-    assert reg.gauge("moe_tokens_routed").value > 0
-    # capacity_factor=0 sentinel: capacity == tokens, nothing drops —
-    # the per-row independence the failover invariant rests on.
-    assert reg.gauge("moe_tokens_dropped").value == 0.0
-    assert reg.gauge("moe_drop_rate").value == 0.0
-    assert reg.gauge("moe_capacity_factor").value == 4.0
-    assert reg.gauge("moe_expert_load_imbalance").value >= 1.0
-    assert "moe_expert_load" in eng.prometheus()
-
-
-def test_moe_expert_parallel_two_axis_mesh(eight_devices):
-    """MoE serving over a dp×mp mesh: expert stacks shard over 'model'
-    (the DEFAULT_TP_RULES experts rule), tokens match the unsharded
-    engine exactly, one compiled program."""
-    adapter, params, vocab = adapter_of("moe")
-    mesh = mesh_lib.build_mesh(devices=jax.devices()[:4], num_dp=2,
-                               num_mp=2)
-    prompts = [ids_of(vocab, n, seed=n)[0] for n in (5, 8, 6)]
-
-    base = engine_of_kind("moe")
-    want = [base.submit(p, max_new_tokens=6) for p in prompts]
-    base.run()
-
-    eng = InferenceEngine(None, params,
-                          config={"max_slots": 3, "max_len": 64,
-                                  "chunk_size": 4, "prefill_chunk": 8},
-                          mesh=mesh, adapter=adapter)
-    got = [eng.submit(p, max_new_tokens=6) for p in prompts]
-    eng.run()
-    for w, g in zip(want, got):
-        assert g.tokens == w.tokens, "expert-parallel stream diverged"
-    spec = eng._params["h_0"]["experts"]["w1"].sharding.spec
-    assert spec[0] == mesh_lib.MODEL_AXIS
-    assert eng.compile_count == 1
-
-
-def test_moe_no_expert_parallel_flag_replicates_experts(eight_devices):
-    adapter, params, vocab = adapter_of("moe")
-    mesh = mesh_lib.build_mesh(devices=jax.devices()[:4], num_dp=2,
-                               num_mp=2)
-    eng = InferenceEngine(None, params,
-                          config={"max_slots": 2, "max_len": 64,
-                                  "chunk_size": 4, "prefill_chunk": 8,
-                                  "expert_parallel": False},
-                          mesh=mesh, adapter=adapter)
-    assert not eng.adapter.expert_parallel
-    spec = eng._params["h_0"]["experts"]["w1"].sharding.spec
-    assert not spec or spec[0] is None  # replicated, not expert-sharded
-    p = ids_of(vocab, 6)[0]
-    r = eng.submit(p, max_new_tokens=5)
-    eng.run()
-    assert r.tokens == primitive_greedy("moe", p, 5)
-
-
-def test_moe_rejects_hierarchy_tiers():
-    adapter, params, vocab = adapter_of("moe")
-    cache = adapter.init_cache(1, 16)
-    bad = dict(cache, k=cache["k"].astype(jnp.int8),
-               v=cache["v"].astype(jnp.int8))
-    with pytest.raises(ValueError, match="plain fp"):
-        adapter.prefill_append(params, jnp.asarray(ids_of(vocab, 4)), bad)
 
 
 # ----------------------------------------------- long-context specifics
@@ -496,26 +463,6 @@ def test_longcontext_capacity_pin_sparse_decode_with_host_offload():
         upto = max(0, 32 - len(p) - 4)  # stay clear of the boundary
         assert r.tokens[:upto] == seq_greedy(model, params, p, upto), \
             "below-threshold prefix diverged from dense"
-
-
-def test_longcontext_no_sparse_decode_flag_is_dense():
-    """--no-sparse-decode A/B arm: config.sparse_decode=False drops the
-    threshold at bind time, so even far-past-threshold streams are
-    bit-identical to the dense engine."""
-    cfg, model, params = make_model()
-    adapter = LongContextAdapter.from_model(model, threshold=16, block=8,
-                                            num_local_blocks=2)
-    lc = InferenceEngine(None, params,
-                         config={"max_slots": 2, "max_len": 64,
-                                 "chunk_size": 4, "prefill_chunk": 8,
-                                 "sparse_decode": False,
-                                 "use_flash_decode": False},
-                         adapter=adapter)
-    assert lc.adapter.threshold == 0  # bind stripped the sparse window
-    p = prompts_of(cfg, [7], seed=4)[0]
-    r = lc.submit(p, max_new_tokens=30)
-    lc.run()
-    assert r.tokens == seq_greedy(model, params, p, 30)
 
 
 def test_longcontext_ring_fallback_on_seq_mesh(eight_devices):

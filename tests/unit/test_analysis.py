@@ -198,7 +198,7 @@ def test_adapter_sanctions_gpt2_adapter_only():
     src = "from deepspeed_tpu.models import generation\n"
     gpt2 = "/x/deepspeed_tpu/inference/adapters/gpt2.py"
     assert analyze_source(gpt2, src) == []
-    other = "/x/deepspeed_tpu/inference/adapters/moe.py"
+    other = "/x/deepspeed_tpu/inference/adapters/longcontext.py"
     assert _rules_hit(analyze_source(other, src)) == {"ADAPTER"}
 
 

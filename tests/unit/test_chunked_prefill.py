@@ -2,13 +2,12 @@
 
 The contract under test:
 1. PARITY — greedy tokens under chunked prefill are bit-identical to
-   sequential ``models.generation.generate`` AND to the legacy
-   whole-prompt-bucket engine, for prompt lengths straddling every
-   chunk-boundary case (C-1, C, C+1, multiples, remainders).
+   sequential ``models.generation.generate``, for prompt lengths
+   straddling every chunk-boundary case (C-1, C, C+1, multiples,
+   remainders).
 2. ONE COMPILE — the documented compile-count constant: a mixed-length
    request stream compiles exactly ONE program, ever (the tier-1
-   compile-count regression guard). The legacy path's constant
-   (1 decode + one prefill per bucket exercised) is pinned alongside.
+   compile-count regression guard).
 3. SCHEDULER PHASES — the ``prefilling`` phase walks its cursor by the
    consumed chunk, FIFO among prefilling slots, and cancellation
    mid-prefill frees the slot for the next queued request.
@@ -75,9 +74,9 @@ def engine_of(model, params, **kw):
 
 
 def test_chunked_parity_across_ragged_lengths():
-    """Prompt lengths straddling every chunk-boundary case against BOTH
-    references (sequential generate and the legacy engine): C-1, C, C+1,
-    an exact multiple, a multiple+remainder, and a tiny prompt."""
+    """Prompt lengths straddling every chunk-boundary case against
+    sequential generate: C-1, C, C+1, an exact multiple, a
+    multiple+remainder, and a tiny prompt."""
     cfg, model, params = make_model()
     C = 8
     lens = [C - 1, C, C + 1, 2 * C, 2 * C + 3, 3]
@@ -88,17 +87,9 @@ def test_chunked_parity_across_ragged_lengths():
     reqs = [eng.submit(p, max_new_tokens=n) for p, n in zip(ps, news)]
     eng.run()
 
-    leg = engine_of(model, params, chunked_prefill=False,
-                    prefill_buckets=(16, 32, 64))
-    lreqs = [leg.submit(p, max_new_tokens=n) for p, n in zip(ps, news)]
-    leg.run()
-
-    for p, n, r, lr in zip(ps, news, reqs, lreqs):
-        want = seq_greedy(model, params, p, n)
-        assert r.tokens == want, \
+    for p, n, r in zip(ps, news, reqs):
+        assert r.tokens == seq_greedy(model, params, p, n), \
             "chunked tokens diverge from generate at len {}".format(len(p))
-        assert lr.tokens == want, \
-            "legacy tokens diverge from generate at len {}".format(len(p))
 
 
 def test_prefill_chunk_size_does_not_change_tokens():
@@ -137,12 +128,10 @@ def test_sampled_stream_independent_of_chunk_boundaries():
 
 
 def test_compile_count_regression_guard():
-    """Tier-1 regression guard on the documented constants: a canned
+    """Tier-1 regression guard on the documented constant: a canned
     mixed-length stream (short, boundary, long, trickled in while slots
-    churn) compiles exactly ONE chunked program; the same stream on the
-    legacy path compiles 1 decode + one prefill per bucket exercised.
-    A change to either constant is an API-contract change and must
-    update docs/INFERENCE.md."""
+    churn) compiles exactly ONE program. A change to the constant is an
+    API-contract change and must update docs/INFERENCE.md."""
     cfg, model, params = make_model()
     lens = [3, 7, 8, 9, 16, 33, 40, 5]
     news = [5, 4, 6, 3, 5, 4, 6, 5]
@@ -162,14 +151,6 @@ def test_compile_count_regression_guard():
         "(got {})".format(eng.compile_count)
     for r, n in zip(reqs, news):
         assert r.tokens == seq_greedy(model, params, r.prompt, n)
-
-    leg = engine_of(model, params, chunked_prefill=False,
-                    prefill_buckets=(16, 64))
-    for p, n in zip(ps, news):
-        leg.submit(p, max_new_tokens=n)
-    leg.run()
-    # Buckets exercised: 16 (lens<=16) and 64 (33, 40) -> 2 prefills + 1.
-    assert leg.compile_count == 3
 
 
 def test_mixed_sampling_params_never_recompile():

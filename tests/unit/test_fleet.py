@@ -649,47 +649,46 @@ def test_fleet_metrics_reset_brackets_like_a_lone_engine():
         fleet.close()
 
 
-# -------------------------------------------- failover: MoE adapter
+# -------------------------------------------- failover: expert model
 
 
 _MOE = {}
 
 
 def _moe_setup():
-    """Shared MoE adapter + params + mixed prompt set (vocab 256)."""
+    """Shared expert model (the tiny ``DecoderLM``: top-2 of 4 experts,
+    served by ``DecoderAdapter``) + params + mixed prompt set."""
     if "a" not in _MOE:
         import jax
 
-        from deepspeed_tpu.inference.adapters import MoEAdapter
-        a = MoEAdapter.from_config(vocab_size=256, n_layer=2, n_head=2,
-                                   n_embd=32, n_positions=128,
-                                   n_experts=4)
-        params = a.init_params(jax.random.PRNGKey(0))
+        from tests.unit.test_adapters import decoder_model
+        model = decoder_model()
+        params = model.init(jax.random.PRNGKey(0))["params"]
         rng = np.random.RandomState(11)
         prompts = [rng.randint(0, 256, size=(n,)).astype(np.int32)
                    for n in _MIX_LENS]
-        _MOE["a"] = (a, params, prompts)
+        _MOE["a"] = (model, params, prompts)
     return _MOE["a"]
 
 
 def test_moe_failover_invariant_mid_stream_kill():
-    """The GPT-2 failover invariant, re-pinned for the MoE adapter:
-    kill a replica mid-decode and every replayed stream is BIT-identical
-    to the fault-free single-engine run. This is only true because (a)
-    the positional fold_in(seed, pos) rng is per-row state that expert
-    routing cannot perturb, and (b) the adapter's capacity_factor=0
-    sentinel pins expert capacity == tokens, so no token's output ever
-    depends on which rows share its batch (a dropped-token MoE would
-    replay DIFFERENT tokens after failover — the invariant this test
-    exists to hold)."""
+    """The GPT-2 failover invariant, re-pinned for the expert model
+    (``DecoderAdapter``): kill a replica mid-decode and every replayed
+    stream is BIT-identical to the fault-free single-engine run. This is
+    only true because (a) the positional fold_in(seed, pos) rng is
+    per-row state that expert routing cannot perturb, and (b) routing is
+    exact top-k with no capacity, so no token's output ever depends on
+    which rows share its batch (a dropped-token MoE would replay
+    DIFFERENT tokens after failover — the invariant this test exists to
+    hold)."""
     from deepspeed_tpu.inference import InferenceEngine
-    adapter, params, prompts = _moe_setup()
+    model, params, prompts = _moe_setup()
     numerics = {"max_slots": 3, "max_len": 64, "chunk_size": 4,
                 "prefill_chunk": 8, "spec_decode": True, "spec_k": 2,
                 "spec_ngram": 2, "use_flash_decode": False}
 
-    ref_eng = InferenceEngine(None, params, config=dict(numerics),
-                              adapter=adapter)
+    ref_eng = InferenceEngine(model, params, config=dict(numerics))
+    assert ref_eng.adapter.name == "decoder"
     ref_reqs = [ref_eng.submit(p, **_mix_kw(i))
                 for i, p in enumerate(prompts)]
     ref_eng.run()
@@ -697,9 +696,8 @@ def test_moe_failover_invariant_mid_stream_kill():
 
     serve = dict(numerics, fault_injection=True, recovery_max_retries=0,
                  max_queue=32)
-    fleet = ServingFleet(None, params, n_replicas=2, config=serve,
-                         seed=0, start=False, window_seconds=0.05,
-                         adapter=adapter)
+    fleet = ServingFleet(model, params, n_replicas=2, config=serve,
+                         seed=0, start=False, window_seconds=0.05)
     try:
         frs = [fleet.submit(p, **_mix_kw(i))
                for i, p in enumerate(prompts)]
@@ -729,8 +727,9 @@ def test_moe_failover_invariant_mid_stream_kill():
         load = [v for (n, _lbl), v in samples.items()
                 if n == "ds_tpu_moe_expert_load"]
         assert load and sum(load) > 0
-        drops = [v for (n, _lbl), v in samples.items()
-                 if n == "ds_tpu_moe_tokens_dropped"]
-        assert drops and all(v == 0.0 for v in drops)
+        # Exact top-k has nothing to drop: every computed row routed.
+        routed = [v for (n, _lbl), v in samples.items()
+                  if n == "ds_tpu_moe_tokens_routed"]
+        assert routed and all(v > 0.0 for v in routed)
     finally:
         fleet.close()

@@ -54,7 +54,7 @@ def _shared_model():
 # prefix planes hold 16 positions, 4 rows, hits need >= 4 matched
 # tokens. max_slots=2 keeps replicas easy to saturate so routing spills.
 _SERVE = dict(max_slots=2, max_len=64, chunk_size=4, prefill_chunk=8,
-              max_queue=32, chunked_prefill=True, prefix_cache=True,
+              max_queue=32, prefix_cache=True,
               prefix_slots=4, prefix_len=16, min_prefix_len=4)
 
 

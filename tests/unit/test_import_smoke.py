@@ -1,12 +1,13 @@
-"""Import smoke for the graft modules the adapter stack now depends on.
+"""Import smoke for the graft modules the serving and MoE stacks depend on.
 
-MoEAdapter routes through ``moe/sharded_moe.py`` and LongContextAdapter
-builds its masks from ``ops/sparse_attention/sparsity_config.py`` — if
-either tree stops importing under the pinned jax, every adapter test
-downstream fails with a confusing collection error. Pin the imports
-directly (and the few public symbols the adapters actually touch) so a
-toolchain bump that breaks them fails HERE with the module name in the
-assertion, not three layers up.
+The training-side MoE layer routes through ``moe/sharded_moe.py`` and
+LongContextAdapter builds its masks from
+``ops/sparse_attention/sparsity_config.py`` — if either tree stops
+importing under the pinned jax, every test downstream fails with a
+confusing collection error. Pin the imports directly (and the few public
+symbols their callers actually touch) so a toolchain bump that breaks
+them fails HERE with the module name in the assertion, not three layers
+up.
 """
 
 import importlib
@@ -35,7 +36,7 @@ def test_module_imports(name):
 
 def test_sharded_moe_surface():
     from deepspeed_tpu.moe import sharded_moe
-    # The routing entry point MoEAdapter drives.
+    # The routing entry point the MoE layer drives.
     assert callable(sharded_moe.top1gating)
 
 

@@ -4,12 +4,10 @@ The contract under test, in order of importance:
 1. GREEDY PARITY — tokens out of the slotted engine are identical to
    sequential ``models.generation.generate`` calls, whatever the
    admission order or slot placement (ISSUE acceptance criterion).
-2. BOUNDED COMPILATION — after warmup (ONE mixed-step program under
-   chunked prefill, the default; one prefill per prompt bucket + one
-   decode chunk program on the legacy path), a changing request mix
-   causes ZERO recompiles, asserted on the engines' jit cache-miss
-   counters. (tests/unit/test_chunked_prefill.py holds the
-   chunked-specific compile-count regression guard.)
+2. BOUNDED COMPILATION — after warmup (ONE mixed-step program), a
+   changing request mix causes ZERO recompiles, asserted on the
+   engines' jit cache-miss counters. (tests/unit/test_chunked_prefill.py
+   holds the compile-count regression guard.)
 3. SCHEDULING — FIFO admission at chunk boundaries only, eviction on
    EOS/budget, QueueFull backpressure.
 4. TP SERVING — the same engine over a 'model'-axis mesh shards params
@@ -111,18 +109,29 @@ def test_scheduler_backpressure():
 # ---------------------------------------------------------------- config
 
 
-def test_inference_config_buckets_and_unknown_keys():
-    cfg = InferenceConfig(max_len=128)
-    assert cfg.prefill_buckets == (16, 32, 64, 128)
-    assert cfg.bucket_for(1) == 16 and cfg.bucket_for(17) == 32
-    with pytest.raises(ValueError, match="exceeds"):
-        cfg.bucket_for(129)
+def test_inference_config_unknown_keys_and_position_budget():
     with pytest.raises(ValueError, match="max_slot"):
         InferenceConfig.from_dict({"max_slot": 4})  # typo must be loud
-    with pytest.raises(ValueError, match="max_len"):
-        InferenceConfig(max_len=64, prefill_buckets=(16, 128))
+    with pytest.raises(ValueError, match="prefix_len"):
+        InferenceConfig(max_len=64, prefix_len=128)
     with pytest.raises(ValueError, match="n_positions"):
         InferenceConfig(max_len=512).validate_against_model(128)
+
+
+@pytest.mark.parametrize("key", ["chunked_prefill", "prefill_buckets",
+                                 "expert_parallel", "sparse_decode"])
+def test_removed_inference_keys_are_rejected_by_name(key):
+    """The keys that chose the legacy prefill step and the stand-in
+    adapter's policies are gone: a config that still carries one fails
+    loudly, naming it, through the constructor and the ds_config block
+    alike — never served with the key silently ignored."""
+    with pytest.raises(TypeError, match=key):
+        InferenceConfig(**{key: True})
+    with pytest.raises(ValueError, match=key):
+        InferenceConfig.from_dict({key: True})
+    with pytest.raises(ValueError, match=key):
+        deepspeed.DeepSpeedConfig(None, param_dict={
+            "train_batch_size": 8, "inference": {key: True}})
 
 
 def test_ds_config_inference_block_parses():
@@ -147,7 +156,6 @@ def engine_of(model, params, mesh=None, **kw):
     kw.setdefault("max_slots", 4)
     kw.setdefault("max_len", 64)
     kw.setdefault("chunk_size", 4)
-    kw.setdefault("prefill_buckets", (16,))
     return InferenceEngine(model, params, config=kw, mesh=mesh)
 
 
@@ -226,16 +234,12 @@ def test_metrics_reads_live_gauges_and_engine_idle():
     assert m["slots_prefilling"] == 0
 
 
-@pytest.mark.parametrize("chunked", [True, False])
-def test_queue_wait_stamped_at_admission_on_both_paths(chunked):
-    """Both engine paths admit through Scheduler.admissions(), so
-    queue_wait_seconds is populated with one observation per request
-    whichever program runs — the windowed queue-wait curve is
-    comparable across configs."""
+def test_queue_wait_stamped_at_admission():
+    """Every request admits through Scheduler.admissions(), so
+    queue_wait_seconds is populated with one observation per request —
+    the windowed queue-wait curve is comparable across configs."""
     cfg, model, params = make_model()
-    kw = {} if chunked else {"chunked_prefill": False,
-                             "prefill_buckets": (16,)}
-    eng = engine_of(model, params, max_slots=2, **kw)
+    eng = engine_of(model, params, max_slots=2)
     ps = prompts_of(cfg, [5, 6, 7, 8, 9], seed=6)
     reqs = [eng.submit(p, max_new_tokens=2) for p in ps]
     eng.run()
@@ -244,19 +248,6 @@ def test_queue_wait_stamped_at_admission_on_both_paths(chunked):
     hist = eng.telemetry.histogram("queue_wait_seconds")
     assert hist.count == len(ps)
     assert eng.metrics()["queue_wait_p99_ms"] is not None
-
-
-def test_second_bucket_compiles_once_then_stays():
-    # LEGACY path: the bucket table only applies with chunked prefill off.
-    cfg, model, params = make_model()
-    eng = engine_of(model, params, prefill_buckets=(8, 16),
-                    chunked_prefill=False)
-    eng.generate(prompts_of(cfg, [4]), max_new_tokens=2)
-    assert eng.compile_count == 2
-    eng.generate(prompts_of(cfg, [12]), max_new_tokens=2)  # new bucket
-    assert eng.compile_count == 3
-    eng.generate(prompts_of(cfg, [6, 13, 2]), max_new_tokens=5)
-    assert eng.compile_count == 3  # both buckets warm: no growth
 
 
 def test_eos_evicts_and_frees_slot():
@@ -293,18 +284,13 @@ def test_submit_validation_and_backpressure():
     eng = engine_of(model, params, max_slots=1, max_queue=2)
     with pytest.raises(ValueError, match="empty"):
         eng.submit([])
-    # Chunked prefill has no bucket ceiling — only max_len bounds it.
+    # Only max_len bounds a prompt.
     with pytest.raises(ValueError, match="max_len"):
         eng.submit(prompts_of(cfg, [10])[0], max_new_tokens=60)
     eng.submit(prompts_of(cfg, [17])[0], max_new_tokens=2)  # fine here
     eng.submit(prompts_of(cfg, [4])[0], max_new_tokens=2)
     with pytest.raises(QueueFull):
         eng.submit(prompts_of(cfg, [4])[0], max_new_tokens=2)
-    # Legacy path: prompts must also fit a prefill bucket.
-    leg = engine_of(model, params, max_slots=1, max_queue=2,
-                    chunked_prefill=False)
-    with pytest.raises(ValueError, match="bucket"):
-        leg.submit(prompts_of(cfg, [17])[0])  # over the only bucket (16)
 
 
 def test_sampled_decode_is_deterministic_per_seed():
@@ -331,7 +317,7 @@ def test_init_inference_facade():
         model=model, params=params,
         config={"train_batch_size": 8,
                 "inference": {"max_slots": 2, "max_len": 64,
-                              "chunk_size": 4, "prefill_buckets": [16]}})
+                              "chunk_size": 4}})
     assert isinstance(eng, InferenceEngine)
     assert eng.config.max_slots == 2
     out = eng.generate(prompts_of(cfg, [5]), max_new_tokens=4)

@@ -254,12 +254,15 @@ def test_recovery_replays_swapped_sessions_bit_identically():
 def test_all_three_tiers_mixed_spec_nonspec_one_compile():
     """The tier-1 smoke from the ISSUE: int8 + prefix cache + host
     offload together, on a mixed spec/non-spec chunked workload with a
-    50%-reuse shared system prompt. Three contracts on one engine run
-    (int8 waives bit-identity):
-    - ONE compiled program, zero recompiles;
-    - speculative acceptance through the int8 cache does not collapse
-      (the verify lane scores through quantized planes; corrupted
-      scores would drive acceptance to ~0 on repetition-heavy prompts);
+    50%-reuse shared system prompt. Contracts on one engine run (int8
+    waives bit-identity), each a count or a ratio of shapes:
+    - ONE compiled program, zero recompiles, every request done;
+    - every tier engaged: prefix hits, swap-outs and swap-ins all
+      counted;
+    - the verify lane scores through quantized planes and still accepts
+      drafts (corrupted scores would accept none; the speculating
+      requests decode long enough for the tiny model's loops to form,
+      so the count does not hang on one lucky draw);
     - the ISSUE capacity criterion: >= 1.8x concurrent sessions at a
       fixed simulated HBM budget (the flat fp pool's own footprint)."""
     cfg, model, params = _shared_model()
@@ -276,7 +279,8 @@ def test_all_three_tiers_mixed_spec_nonspec_one_compile():
                 head, rng.randint(0, cfg.vocab_size, size=(3 + i,))])
         else:
             p = np.tile(rng.randint(0, cfg.vocab_size, size=(4,)), 4)
-        reqs.append(eng.submit(p.astype(np.int32), max_new_tokens=5 + i,
+        reqs.append(eng.submit(p.astype(np.int32),
+                               max_new_tokens=5 + i + (16 if i % 2 else 0),
                                spec_decode=bool(i % 2)))
     eng.run()
     assert all(r.phase == "done" for r in reqs)
@@ -284,11 +288,12 @@ def test_all_three_tiers_mixed_spec_nonspec_one_compile():
     m = eng.metrics()
     assert m["int8_kv"] and m["prefix_cache"] and m["host_offload"]
     assert m["compile_count"] == 1 and m["recompiles"] == 0
-
-    assert m["draft_accept_rate"] is not None
-    assert m["draft_accept_rate"] > 0.02, \
+    assert m["prefix_hits"] >= 1
+    assert m["swap_outs"] >= 1 and m["swap_ins"] == m["swap_outs"]
+    # Occupied slot-steps that emitted more than the bonus token.
+    assert int(eng._accept_hist[2:].sum()) > 0, \
         "int8 KV collapsed speculative acceptance: {}".format(
-            m["draft_accept_rate"])
+            eng._accept_hist)
 
     h = eng._hier
     budget = h.flat_bytes_per_slot() * eng.config.max_slots

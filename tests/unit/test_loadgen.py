@@ -535,8 +535,9 @@ def test_report_prefix_section_counts_hits_and_misses():
 
 
 def test_report_adapter_section_moe_and_longcontext():
-    """The v6 ``adapter`` section: an MoE run carries the adapter name,
-    per-expert dispatch totals and the imbalance ratio; a long-context
+    """The v6 ``adapter`` section: an expert-model run (the tiny
+    ``DecoderLM``, top-2 of 4) carries the adapter name, per-expert
+    dispatch totals and the imbalance ratio; a long-context
     run carries the sparse threshold plus the EXACT fraction of
     generated tokens served past it (computed from the per-sample
     geometry); a plain GPT-2 run shows the name with empty tallies —
@@ -544,25 +545,23 @@ def test_report_adapter_section_moe_and_longcontext():
     import jax
 
     from deepspeed_tpu.inference import InferenceEngine
-    from deepspeed_tpu.inference.adapters import (LongContextAdapter,
-                                                  MoEAdapter)
+    from deepspeed_tpu.inference.adapters import LongContextAdapter
+    from tests.unit.test_adapters import decoder_model
 
-    moe = MoEAdapter.from_config(vocab_size=256, n_layer=2, n_head=2,
-                                 n_embd=32, n_positions=128, n_experts=4)
-    eng = InferenceEngine(None, moe.init_params(jax.random.PRNGKey(0)),
+    moe = decoder_model()
+    eng = InferenceEngine(moe, moe.init(jax.random.PRNGKey(0))["params"],
                           config={"max_slots": 4, "max_len": 64,
                                   "chunk_size": 4, "prefill_chunk": 8,
                                   "max_queue": 64,
-                                  "use_flash_decode": False},
-                          adapter=moe)
+                                  "use_flash_decode": False})
     _warm(eng)
     spec = _spec(seed=2, n_requests=8, rate=200.0, vocab_size=256)
     res = SustainedRunner(eng, spec, window_seconds=0.1,
                           max_steps=100_000).run()
-    assert res.adapter == "moe" and sum(res.expert_load) > 0
+    assert res.adapter == "decoder" and sum(res.expert_load) > 0
     rep = build_report(spec, res, SLO(ttft_p99_ms=1e4, itl_p99_ms=2e3))
     sec = rep["adapter"]
-    assert sec["adapter"] == "moe"
+    assert sec["adapter"] == "decoder"
     assert len(sec["expert_load"]) == 4
     assert sec["expert_load_imbalance"] >= 1.0
     assert sec["sparse_token_fraction"] is None  # no sparse threshold

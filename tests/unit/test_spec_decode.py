@@ -290,11 +290,6 @@ def test_verify_forward_matches_stepwise_decode_and_keeps_pos():
 # ------------------------------------------------------------------ config
 
 
-def test_config_spec_requires_chunked_prefill():
-    with pytest.raises(ValueError, match="chunked_prefill"):
-        InferenceConfig(spec_decode=True, chunked_prefill=False)
-
-
 @pytest.mark.parametrize("field,bad", [("spec_k", 0), ("spec_ngram", 0)])
 def test_config_spec_knobs_validated(field, bad):
     with pytest.raises(ValueError, match=field):
@@ -306,9 +301,6 @@ def test_config_env_resolution(monkeypatch):
     assert InferenceConfig().resolved_spec_decode() is False
     monkeypatch.setenv("DS_TPU_SPEC_DECODE", "1")
     assert InferenceConfig().resolved_spec_decode() is True
-    # The env only applies where speculation CAN run.
-    assert InferenceConfig(
-        chunked_prefill=False).resolved_spec_decode() is False
     # The explicit field always wins over the env.
     assert InferenceConfig(spec_decode=False).resolved_spec_decode() is False
     monkeypatch.setenv("DS_TPU_SPEC_DECODE", "0")

@@ -5,10 +5,13 @@ The contract under test:
    program set: same compile_count, zero post-warmup recompiles either
    way (annotations and spans are host-side; nothing telemetry does may
    perturb tracing).
-2. HOST OVERHEAD — the per-step host cost with spans + annotations +
-   registry enabled stays within 5% of telemetry-off on the CPU tier-1
-   path, measured as min-of-N over repeated identical step loops (min
-   discards scheduler noise; both sides run warm).
+2. A FIXED BUDGET OF HOST WORK — spans + annotations, and distributed
+   tracing with a ticking collector and alert rules on top, add a
+   counted number of ring events to a step and no registry write:
+   counts, which repeat on any CPU (what they cost in host time is a
+   device-side reading, ``*.host_ms_step`` in ``PERF.md``).
+3. PERF X-RAY — the observatory's per-step stash stays within 5% of
+   xray-off, best of ten PAIRED rounds (one clean round proves it).
 """
 
 import time
@@ -45,35 +48,70 @@ def _one_run(eng, prompt, steps):
     return dt
 
 
-def test_telemetry_adds_no_recompiles_and_bounded_host_overhead():
+def _count_registry_writes(monkeypatch):
+    """Count every ``Counter.inc`` / ``Gauge.set`` / ``Histogram.observe``
+    made anywhere in the process while the patch lives; returns the
+    one-element list the count accumulates in."""
+    from deepspeed_tpu.telemetry import registry
+
+    writes = [0]
+    for cls, method in ((registry.Counter, "inc"), (registry.Gauge, "set"),
+                        (registry.Histogram, "observe")):
+        def counted(self, *args, _inner=getattr(cls, method), **kw):
+            writes[0] += 1
+            return _inner(self, *args, **kw)
+        monkeypatch.setattr(cls, method, counted)
+    return writes
+
+
+def _one_counted_run(eng, prompt, steps, writes, trace=None, per_step=None):
+    """(registry writes, ring events) of ``steps`` steady decode steps:
+    one slot decoding in every one of them (the budget outlasts the
+    loop), ``per_step()`` called after each as a fleet replica's drive
+    loop calls its collector and alert rules."""
+    # The first step decodes a chunk too: a budget of steps + 1 of them
+    # and a little more is still open when the counted loop ends.
+    r = eng.submit(prompt,
+                   max_new_tokens=(steps + 1) * eng.config.chunk_size + 2,
+                   trace=trace)
+    eng.step()  # prefill + first token: outside the counted window
+    w0, e0 = writes[0], sum(eng.tracer.span_counts().values())
+    for _ in range(steps):
+        eng.step()
+        if per_step is not None:
+            per_step()
+    counted = (writes[0] - w0, sum(eng.tracer.span_counts().values()) - e0)
+    assert r.phase == "decoding"
+    while not r.done:
+        eng.step()
+    return counted
+
+
+def test_telemetry_adds_no_recompiles_and_bounded_host_overhead(monkeypatch):
     cfg, model, params = make_model()
     prompt = prompts_of(cfg, [6])[0]
+    steps = 12
 
     on = _steady_engine(model, params, telemetry=True)
     off = _steady_engine(model, params, telemetry=False)
     assert on.compile_count == off.compile_count == 1
 
-    # Interleaved min-of-N: alternating on/off runs exposes both sides
-    # to the same machine-wide noise; min discards scheduler hiccups.
-    _one_run(on, prompt, steps=12)   # loop warmup, untimed
-    _one_run(off, prompt, steps=12)
-    t_on = t_off = float("inf")
-    for _ in range(8):
-        t_on = min(t_on, _one_run(on, prompt, steps=12))
-        t_off = min(t_off, _one_run(off, prompt, steps=12))
+    writes = _count_registry_writes(monkeypatch)
+    w_on, e_on = _one_counted_run(on, prompt, steps, writes)
+    w_off, e_off = _one_counted_run(off, prompt, steps, writes)
 
-    # Identical program set, still zero recompiles after the timed runs.
+    # Identical program set, still zero recompiles after the runs.
     assert on.compile_count == off.compile_count == 1
     assert on.metrics()["recompiles"] == 0
     assert off.metrics()["recompiles"] == 0
 
-    # Host overhead bound. The tiny-model CPU step is dominated by jit
-    # dispatch (~ms); spans/annotations must stay in the noise. 5% is
-    # the budget the ISSUE sets; measured slack is far larger in
-    # practice, and min-of-N keeps CI machines from flaking it.
-    assert t_on <= t_off * 1.05, (
-        "telemetry-on steps {:.4f}s vs off {:.4f}s (> +5%)".format(
-            t_on, t_off))
+    # Host overhead bound, as the work telemetry adds to a steady decode
+    # step: the five step-phase spans and one ``request/chunk`` instant
+    # for the one emitting slot, each one ring append; the registry is
+    # written the same number of times either way (counters are the
+    # engine's own bookkeeping).
+    assert e_on == steps * (5 + 1) and e_off == 0
+    assert w_on == w_off > 0
 
     # The on-engine actually recorded: the comparison was not no-op
     # against no-op.
@@ -100,77 +138,70 @@ def test_telemetry_import_is_extras_free():
     assert w._writer is None and w._dead is False
 
 
-def _one_traced_run(eng, prompt, steps, tid, collector, alerts):
-    """Seconds for ``steps`` steady decode steps with the full PR-14
-    path active: a propagated fleet-style TraceContext stamping hops,
-    the collector ticking and the alert rules evaluating every step —
-    exactly what a fleet replica's drive loop pays."""
-    from deepspeed_tpu.telemetry import TraceContext
-
-    r = eng.submit(prompt, max_new_tokens=steps + 2,
-                   trace=TraceContext(tid, origin="fleet"))
-    eng.step()  # prefill + first token: outside the timed window
-    t0 = time.perf_counter()
-    for _ in range(steps):
-        eng.step()
-        collector.tick()
-        alerts.evaluate()
-    dt = time.perf_counter() - t0
-    while not r.done:
-        eng.step()
-    return dt
-
-
-def test_distributed_tracing_and_alerts_hold_the_overhead_gate():
-    """PR-14 gate: distributed tracing ON (propagated TraceContext with
-    hop stamping, flow-capable span ring) plus a ticking
-    TimeseriesCollector and per-step AlertManager evaluation, measured
+def test_distributed_tracing_and_alerts_hold_the_overhead_gate(monkeypatch):
+    """PR-14 gate, as counts: distributed tracing ON (propagated
+    TraceContext with hop stamping, flow-capable span ring) plus a
+    ticking TimeseriesCollector and per-step AlertManager evaluation,
     against telemetry fully off. Same compiled program set (1 program,
-    0 recompiles — tracing is host-side only) and the same <5% host
-    budget the engine-local gate pins."""
+    0 recompiles — tracing is host-side only), and what the path ADDS to
+    a steady decode step is a fixed budget: the five step-phase spans
+    and one ``request/chunk`` instant per emitting slot in the ring, and
+    not one registry write beyond what the engine makes with telemetry
+    off. (What that costs in host time is a latency and belongs to a
+    chip cell: PERF.md section 7.)"""
     from deepspeed_tpu.telemetry import AlertManager, TimeseriesCollector
-    from deepspeed_tpu.telemetry import default_rules
+    from deepspeed_tpu.telemetry import TraceContext, default_rules
     from deepspeed_tpu.telemetry.distributed import FLEET_TID_BASE
 
     cfg, model, params = make_model()
     prompt = prompts_of(cfg, [6])[0]
+    steps, per_window = 12, 4
 
     on = _steady_engine(model, params, telemetry=True)
     off = _steady_engine(model, params, telemetry=False)
-    # Window wide enough that most 12-step timed loops contain NO
-    # window close: the close (a full registry snapshot) then lands in
-    # the untimed prefill/drain stretches and min-of-N compares the
-    # true steady per-step cost, not snapshot scheduling luck.
-    collector = TimeseriesCollector(on.telemetry, window_seconds=0.25)
-    collector.start()
-    alerts = AlertManager(collector, default_rules())
     assert on.compile_count == off.compile_count == 1
 
-    _one_traced_run(on, prompt, 12, FLEET_TID_BASE, collector, alerts)
-    _one_run(off, prompt, steps=12)  # loop warmup, untimed
-    t_on = t_off = float("inf")
-    for i in range(8):
-        t_on = min(t_on, _one_traced_run(
-            on, prompt, 12, FLEET_TID_BASE + 1 + i, collector, alerts))
-        t_off = min(t_off, _one_run(off, prompt, steps=12))
+    # The collector's windows close on a clock the test advances: one
+    # second a step, so every ``per_window`` steps, whatever the CPU.
+    now = [0.0]
+    collector = TimeseriesCollector(on.telemetry, window_seconds=per_window,
+                                    clock=lambda: now[0])
+    collector.start()
+    alerts = AlertManager(collector, default_rules())
+
+    def drive_loop_hooks():
+        now[0] += 1.0
+        collector.tick()
+        alerts.evaluate()
+
+    writes = _count_registry_writes(monkeypatch)
+    w_on, e_on = _one_counted_run(
+        on, prompt, steps, writes,
+        trace=TraceContext(FLEET_TID_BASE, origin="fleet"),
+        per_step=drive_loop_hooks)
+    w_off, e_off = _one_counted_run(off, prompt, steps, writes)
 
     # Tracing + alerting changed NOTHING the compiler sees.
     assert on.compile_count == off.compile_count == 1
     assert on.metrics()["recompiles"] == 0
 
-    assert t_on <= t_off * 1.05, (
-        "distributed tracing+alerts on {:.4f}s vs off {:.4f}s "
-        "(> +5%)".format(t_on, t_off))
+    assert e_off == 0
+    assert e_on == steps * (5 + 1), (
+        "tracing put {} events in the ring over {} steps".format(
+            e_on, steps))
+    assert w_on == w_off > 0, (
+        "tracing+alerts made {} registry writes against {} with "
+        "telemetry off".format(w_on, w_off))
+    assert on.tracer.dropped == 0
 
     # The propagated context actually rode the hot path: the fleet-base
     # tid shows up hop-stamped in the ring, in order.
     hops = [ev["args"]["hop"] for ev in on.tracer.events()
-            if ev.get("tid") == FLEET_TID_BASE + 8]
-    assert hops == sorted(hops) and hops
-    # ...and the alert machinery genuinely evaluated closed windows.
-    collector.sample()
-    alerts.evaluate()
-    assert alerts.to_json()["windows_evaluated"] >= 1
+            if ev.get("tid") == FLEET_TID_BASE]
+    assert hops == sorted(hops) and len(hops) >= steps
+    # ...and the alert machinery evaluated every window the clock closed.
+    assert len(collector.windows()) == steps // per_window
+    assert alerts.to_json()["windows_evaluated"] == steps // per_window
 
 
 def test_perf_xray_holds_the_overhead_gate():

@@ -142,17 +142,6 @@ def test_spans_cost_no_compile(telemetry):
     assert (eng.tracer.span_counts() != {}) is telemetry
 
 
-def test_legacy_engine_names_its_three_phases(tmp_path):
-    cfg, model, params = make_model()
-    eng = engine_of(model, params, chunked_prefill=False)
-    eng.generate([prompts_of(cfg, [5])[0]], max_new_tokens=3)
-    counts = eng.tracer.span_counts()
-    for name in ("inference/step", "inference/prefill",
-                 "inference/decode_chunk", "inference/harvest"):
-        assert counts.get(name, 0) >= 1, name
-    assert not any(name.startswith("step/") for name in counts)
-
-
 # --------------------------------------------------------- training engine
 
 

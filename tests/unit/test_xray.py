@@ -448,8 +448,8 @@ def test_engine_perf_xray_analyses_only_dispatched_programs(monkeypatch):
     log = _Compilations(monkeypatch)
     out = eng.perf_xray()
     active = [p for p in out["programs"] if not p["superseded"]]
-    # Chunked mode dispatches one program, and the export compiles that
-    # one and nothing it never ran (no legacy prefill / decode_chunk).
+    # The engine dispatches one program, and the export compiles that
+    # one and nothing it never ran.
     assert {p["program"] for p in active} == {"mixed_step"}
     assert log.n == 1
     for p in active:
@@ -492,22 +492,6 @@ def test_engine_perf_xray_analyses_only_dispatched_programs(monkeypatch):
     op_names = xray.OP_NAMES["jit_mixed_step"].values()
     assert any("/decode_scan/" in n and "/kv_write/" in n for n in op_names)
     assert any("/prefill_lane/" in n for n in op_names)
-
-
-def test_legacy_engine_perf_xray_covers_its_two_programs():
-    from tests.unit.test_chunked_prefill import (
-        engine_of,
-        make_model,
-        prompts_of,
-    )
-
-    cfg, model, params = make_model()
-    eng = engine_of(model, params, chunked_prefill=False)
-    eng.generate([prompts_of(cfg, [5])[0]], max_new_tokens=3)
-    out = eng.perf_xray()
-    assert {p["program"] for p in out["programs"]
-            if not p["superseded"]} == {"prefill", "decode_chunk"}
-    assert out["decomposition"]["decode_chunk"]["samples"] >= 1
 
 
 def test_engine_perf_xray_off_is_none():
