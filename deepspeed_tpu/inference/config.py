@@ -198,7 +198,8 @@ class InferenceConfig:
     # way.
     role: str = "mixed"
     # --- Paged KV cache (inference/paging.py + kv_pool.py) --------------
-    # Store the KV plane as a shared PAGE ARENA [L, P, H, page_len, D]
+    # Store the KV plane as a shared PAGE ARENA [L, P, H/g, page_len, g*D]
+    # (g heads a 128-lane tile, from the head dim: inference/kv_pool.py)
     # plus a per-slot int32 block table [slots, plane_len/page_len]:
     # pages are allocated on demand as frontiers advance and freed at
     # release, so a slot only ever holds HBM proportional to its actual

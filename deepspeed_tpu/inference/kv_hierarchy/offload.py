@@ -103,12 +103,15 @@ def restore_slot(pool, slot, record):
 # ------------------------------------------------------- paged variants
 #
 # A PAGED pool (inference/kv_pool.py paged layout) keeps k/v as page
-# arenas [L, P, H, page_len, D]: a slot's device footprint is not a
+# arenas [L, P, H/g, page_len, g*D] (g heads a lane tile: kv_pool.py;
+# int8 scales [L, P, H, page_len]): a slot's device footprint is not a
 # contiguous plane slice but the set of physical pages its block-table
 # row names, so capture/restore take the explicit page list from the
 # PageAllocator. Records ship ONLY LIVE PAGES — a 100-token session in a
 # 2048-position plane moves ~1 page per layer, not the whole plane — as
-# [L, n_pages, H, page_len, D] stacks plus the same per-slot scalars as
+# [L, n_pages, ...] stacks with the arena's OWN trailing dims (pages are
+# indexed on axis 1 and nothing here looks inside one, so a record restores
+# into any pool of the same model) plus the same per-slot scalars as
 # the dense record. ``block_tbl`` never ships: it is host-owned derived
 # state the allocator rebuilds at restore (the record's page ORDER is
 # the row's logical order, which is all restore needs).

@@ -76,13 +76,36 @@ Layout invariants the flash-decode kernel
   advances by S); host code never writes ``pos`` directly, which is what
   makes ``max_active_frontier`` a safe work-bound hint between chunks.
 
-WHERE A PAGED POOL IS WRITTEN. The paged layout (``init_pool(page_len=)``)
-keeps ONE arena ``[layers, pages, heads, page_len, head_dim]`` per k and
-v. When ``page_len`` is a kernel block (a multiple of 128: what the chip
-serves), no program ever forms a per-layer value of it: the frontier
-rows are appended in place by the ``kv_append`` kernel and attention
-reads pages through the decode kernel's own index map, both addressing
-``arena[layer, block_tbl[slot, pos // page_len], head, pos % page_len]``
+THE PAGED ARENA IS STORED AS THE KERNELS READ IT. The paged layout
+(``init_pool(page_len=)``) keeps ONE arena per k and v,
+``[layers, pages, heads / g, page_len, g * head_dim]``: where the head dim
+does not fill a 128-lane tile, ``g = 128 // head_dim`` heads share one
+(``decode_attention.lane_pack``: GPT-2's heads of 64 give g = 2 and
+``[24, 145, 8, 128, 128]`` at 355M; a head dim of 128 gives g = 1 and
+``[L, P, H, page_len, 128]``, nothing packed). Head ``g * p + a`` of the
+model lives in lanes ``a * D .. a * D + D - 1`` of stored head ``p``; a
+head count ``g`` does not divide (gpt2-xl's 25) gets a zero head, and a
+model with fewer heads than a tile holds packs them all (``g`` = heads).
+The rule reads ``n_embd // n_head`` and ``n_head``, nothing else: no
+key, no flag. Why: a minor dim of 64 is padded to 128 lanes wherever the
+chip stores or moves it, so XLA kept such an arena page-length minor
+between steps and converted all of it (0.9 GB each of k and v) where a
+step entered and left, and the kernels moved twice the bytes they
+attended (PERF.md, PR 30). The int8 tier's scale arenas stay a scale a
+head of the MODEL and position, ``[L, P, g * ceil(H / g), page_len]``.
+Every paged pool of a model has this ONE shape, whatever its page size;
+whoever indexes pages (swap records, copy on write, handoff: axis 1)
+carries the trailing dims through, and whoever needs a head on its own
+(the XLA fallback for pages under a kernel block, a prefix record shipped
+in the dense format) goes through ``pack_heads`` / ``unpack_heads``. The
+dense slot pool and ``generate()``'s cache are not packed.
+
+WHERE A PAGED POOL IS WRITTEN. When ``page_len`` is a kernel block (a
+multiple of 128: what the chip serves), no program ever forms a
+per-layer value of the arena: the frontier rows are appended in place by
+the ``kv_append`` kernel and attention reads pages through the decode
+kernel's own index map, both addressing
+``arena[layer, block_tbl[slot, pos // page_len], head // g, pos % page_len]``
 (ops/transformer/kernels/decode_attention.py). The views below pass the
 arenas through untouched, so the donated buffer the step received is the
 buffer it returns. An append rewrites the frontier page of each row,
@@ -165,7 +188,9 @@ def init_pool(gcfg, num_slots, max_len, dtype=None, slack=0, hier=None,
       a stale pid inert, so -1 needs no special casing in the programs.
 
     ``page_len > 0`` selects the PAGED layout instead: ``k``/``v``
-    become a shared page arena ``[L, P, H, page_len, D]`` (physical
+    become a shared page arena ``[L, P, H / g, page_len, g * D]``, ``g``
+    heads a lane tile (module docstring; int8 scales
+    ``[L, P, g * ceil(H / g), page_len]``; physical
     page 0 is the reserved trash page — inference/paging.py) and the
     pool gains an int32 ``block_tbl`` [slots, plane_len / page_len]
     mapping each slot's logical pages to arena pages. ``num_pages``
@@ -184,14 +209,19 @@ def init_pool(gcfg, num_slots, max_len, dtype=None, slack=0, hier=None,
         n_lp = plane_len // page_len
         usable = num_pages if num_pages is not None else num_slots * n_lp
         P = usable + 1  # + the trash page at index 0
-        kv_shape = (gcfg.n_layer, P, gcfg.n_head, page_len, hd)
+        # Stored as the kernels read it: ``g`` heads share a lane tile
+        # (module docstring, THE PAGED ARENA), from these two shapes alone.
+        g = decode_attention.lane_pack(hd, gcfg.n_head)
+        hp = -(-gcfg.n_head // g)
+        kv_shape = (gcfg.n_layer, P, hp, page_len, g * hd)
         pool = {"k": jnp.zeros(kv_shape, kv_dtype),
                 "v": jnp.zeros(kv_shape, kv_dtype),
                 "block_tbl": jnp.zeros((num_slots, n_lp), jnp.int32),
                 "toks": jnp.zeros((num_slots, plane_len), jnp.int32)}
         if int8:
-            pool["k_scale"] = jnp.zeros(kv_shape[:-1], jnp.float32)
-            pool["v_scale"] = jnp.zeros(kv_shape[:-1], jnp.float32)
+            sc_shape = (gcfg.n_layer, P, hp * g, page_len)
+            pool["k_scale"] = jnp.zeros(sc_shape, jnp.float32)
+            pool["v_scale"] = jnp.zeros(sc_shape, jnp.float32)
         for name, ft, fill in _SLOT_FIELDS:
             pool[name] = jnp.full((num_slots,), fill, ft)
         return pool
@@ -412,13 +442,14 @@ def kv_spec(mesh, n_head):
     return mesh_lib.kv_cache_spec(mesh, n_head)
 
 
-def pool_shardings(mesh, pool, n_head):
+def pool_shardings(mesh, pool):
     """NamedSharding pytree matching ``pool``: k/v head-sharded over
-    'model', per-slot state replicated. Used both to place the initial
+    'model' (the heads the pool STORES on axis 2: packed ones in a paged
+    arena), per-slot state replicated. Used both to place the initial
     pool and to pin jitted programs' out_shardings (without the pin,
     GSPMD may silently replicate the cache on output and the memory
     saving evaporates — same lesson as the pipeline engine's opt state)."""
-    kv = NamedSharding(mesh, kv_spec(mesh, n_head))
+    kv = NamedSharding(mesh, kv_spec(mesh, pool["k"].shape[2]))
     rep = NamedSharding(mesh, P())
     # Prefix planes share the k/v rank/layout, so the same head-sharded
     # spec applies; scale planes are small — replicate them.
@@ -426,8 +457,8 @@ def pool_shardings(mesh, pool, n_head):
             for name in pool}
 
 
-def shard_pool(mesh, pool, n_head):
-    sh = pool_shardings(mesh, pool, n_head)
+def shard_pool(mesh, pool):
+    sh = pool_shardings(mesh, pool)
     return {name: jax.device_put(arr, sh[name]) for name, arr in pool.items()}
 
 

@@ -1,6 +1,8 @@
 """Host-side page allocator for the paged KV cache.
 
-The device truth is a fixed page ARENA ``[L, P, H, page_len, D]`` plus a
+The device truth is a fixed page ARENA ``[L, P, H / g, page_len, g * D]``
+(``g`` heads share a 128-lane tile where the head dim does not fill one:
+inference/kv_pool.py; a page is axis 1 whatever ``g`` is) plus a
 per-slot int32 block table ``[slots, plane_len / page_len]`` (see
 inference/kv_pool.py). Everything HERE is the host-side brain that
 decides which physical page backs which (slot, logical-page) pair:

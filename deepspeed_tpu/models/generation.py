@@ -172,8 +172,10 @@ class CacheAttention(object):
         self.int8 = cache["k"].dtype == jnp.int8
         self.has_prefix = "pk" in cache
         # PAGED dispatch (inference/kv_pool.py paged layout): a block table
-        # means k/v are a page ARENA [L, P, H, page_len, D] and row b's
-        # logical plane is the concatenation of its table's pages. Writes
+        # means k/v are a page ARENA [L, P, H/g, page_len, g*D] (``g`` heads
+        # share a lane tile: decode_attention.lane_pack, read back here from
+        # the arena's minor dim) and row b's logical plane is the
+        # concatenation of its table's pages. Writes
         # go through the table (an XLA scatter, or in place by kv_append);
         # reads gather through it (or hand the table and the whole arena to
         # the paged flash kernel). The gathered logical plane is
@@ -185,6 +187,7 @@ class CacheAttention(object):
             assert not self.has_prefix, "paged pools share prefixes via pages"
             self.tbl = tbl = cache["block_tbl"]        # [B, n_lp]
             self.page_len = page_len = cache["k"].shape[3]
+            self.pack = cache["k"].shape[4] // self.hd
             self.n_lp = n_lp = tbl.shape[1]
             self.max_len = n_lp * page_len             # logical plane len
             w_pos = pos[:, None] + jnp.arange(S)[None]  # [B, S]
@@ -258,11 +261,13 @@ class CacheAttention(object):
 
     def _write_rows(self, plane_l, new):
         if self.paged:
-            # Page arena [P, H, page_len, D] <- [B, H, S, D] scattered
-            # at (page, offset) through the block table. Distinct live
-            # positions map to distinct (page, offset) pairs (the table
-            # is injective per row outside the trash page), so the
-            # scatter is collision-free wherever it is ever read.
+            # Page arena [P, H/g, page_len, g*D] <- [B, H, S, D], regrouped
+            # as the arena stores heads and scattered at (page, offset)
+            # through the block table. Distinct live positions map to
+            # distinct (page, offset) pairs (the table is injective per
+            # row outside the trash page), so the scatter is
+            # collision-free wherever it is ever read.
+            new = decode_attention.pack_heads(new, self.pack)
             return plane_l.at[self.w_pg, :, self.w_off, :].set(
                 new.transpose(0, 2, 1, 3))
         # [B, H, T, D] cache plane <- [B, H, S, D] at each row's frontier
@@ -272,7 +277,9 @@ class CacheAttention(object):
 
     def _write_scale_rows(self, plane_l, new):
         if self.paged:
-            # Scale arena [P, H, page_len] <- [B, H, S] likewise.
+            # Scale arena [P, H, page_len] <- [B, H, S] likewise (a scale
+            # a head of the model; a zero head where g does not divide H).
+            new = decode_attention.pad_heads(new, plane_l.shape[1], 1)
             return plane_l.at[self.w_pg, :, self.w_off].set(
                 new.transpose(0, 2, 1))
         # [B, H, T] scale plane <- [B, H, S] at each row's frontier.
@@ -280,11 +287,10 @@ class CacheAttention(object):
             c, n, (0, p)))(plane_l, new, self.pos)
 
     def _gather_pages(self, arena_l):
-        # [P, H, page_len, ...] -> row-major logical planes
-        # [B, H, n_lp * page_len, ...] via one table gather.
-        g = jnp.take(arena_l, self.tbl, axis=0)        # [B, n_lp, H, p, ...]
-        g = jnp.moveaxis(g, 2, 1)                      # [B, H, n_lp, p, ...]
-        return g.reshape((g.shape[0], self.nh, self.max_len) + g.shape[4:])
+        # One layer of an arena -> row-major logical planes
+        # [B, H, n_lp * page_len, ...] via one table gather, ungrouped.
+        return decode_attention.gather_pages(arena_l, self.tbl, self.nh,
+                                             self.pack)
 
     def __call__(self, i, q, k, v, planes):
         """Layer ``i``'s attention: write ``k, v`` at the frontiers, read

@@ -86,6 +86,82 @@ def decode_supported(t_kv):
     return t_kv % BLOCK_MIN == 0
 
 
+# Lanes of one vector tile: what the minor dim of a stored arena fills.
+LANES = 128
+
+
+def lane_pack(d, h):
+    """Of a model's ``h`` heads of width ``d``, how many share one lane
+    tile in a PAGED arena: the arena is stored
+    ``[L, P, ceil(h / g), page_len, g * d]``, so its minor dim fills the
+    tile the chip stores and moves anyway (a minor dim of 64 is padded to
+    128 in VMEM, and XLA keeps such an arena page-length minor between
+    steps and converts all of it where a step enters and leaves). 1 where
+    ``d`` fills a tile or does not divide one; never more than ``h`` (a
+    tile wider than all the heads together would store zero heads). The
+    launchers read ``g`` back from shapes: ``arena.shape[-1] // d``."""
+    return min(LANES // d, h) if d < LANES and LANES % d == 0 else 1
+
+
+def pad_heads(x, heads, axis):
+    """``x`` with zero heads appended along ``axis`` up to ``heads`` (a
+    head count ``g`` does not divide; a scale arena's new values)."""
+    if x.shape[axis] == heads:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, heads - x.shape[axis])
+    return jnp.pad(x, widths)
+
+
+def pack_heads(x, g):
+    """``[..., H, T, D]`` rows as a packed arena holds them,
+    ``[..., ceil(H / g), T, g * D]``: head ``g * p + a`` in lanes
+    ``a * D .. a * D + D - 1`` of packed head ``p`` (a zero head pads an
+    ``H`` that ``g`` does not divide)."""
+    if g == 1:
+        return x
+    lead, (h, t, d) = x.shape[:-3], x.shape[-3:]
+    hp = -(-h // g)
+    x = pad_heads(x, hp * g, x.ndim - 3).reshape(lead + (hp, g, t, d))
+    return jnp.moveaxis(x, -3, -2).reshape(lead + (hp, t, g * d))
+
+
+def unpack_heads(x, g, h):
+    """The inverse of ``pack_heads``: ``[..., Hp, T, g * D]`` back to the
+    ``h`` heads ``[..., h, T, D]``."""
+    if g == 1:
+        return x
+    lead, (hp, t, gd) = x.shape[:-3], x.shape[-3:]
+    x = jnp.moveaxis(x.reshape(lead + (hp, t, g, gd // g)), -2, -3)
+    return x.reshape(lead + (hp * g, t, gd // g))[..., :h, :, :]
+
+
+def _pack_query(q, g):
+    """``[B, H, S, D]`` queries against a packed arena: block-diagonal
+    ``[B, ceil(H / g), g * S, g * D]``, head ``a`` of a group in rows
+    ``a * S .. a * S + S - 1`` and lanes ``a * D .. a * D + D - 1``, zeros
+    elsewhere: a zero lane adds exactly nothing to a row's fp32 scores."""
+    if g == 1:
+        return q
+    b, h, s, d = q.shape
+    hp = -(-h // g)
+    q = pad_heads(q, hp * g, 1).reshape(b, hp, g, s, 1, d)
+    own = jnp.eye(g, dtype=bool).reshape(1, 1, g, 1, g, 1)
+    return jnp.where(own, q, 0).reshape(b, hp, g * s, g * d)
+
+
+def _unpack_output(o, g, h):
+    """Each head's own rows and lanes out of the packed kernel's
+    ``[B, Hp, g * S, g * D]`` (the other lanes of a row attended another
+    head's values): ``[B, h, S, D]``."""
+    if g == 1:
+        return o
+    b, hp, gs, gd = o.shape
+    o = o.reshape(b, hp, g, gs // g, g, gd // g)
+    o = jnp.stack([o[:, :, a, :, a] for a in range(g)], axis=2)
+    return o.reshape(b, hp * g, gs // g, gd // g)[:, :h]
+
+
 def _sublane(dtype):
     """Mosaic's minimum second-minor tile extent: score tiles narrower than
     this are padded anyway, so the launcher pads the QUERY dim explicitly
@@ -544,8 +620,9 @@ def resolve_decode_block(q, k, block_k=None, v=None, pos=None, scales=None,
 # ---------------------------------------------------------------------------
 # On a mesh the kernels launch shard-local through attention.on_shards —
 # batch over 'data', heads over 'model', length and head-dim whole; pos is a
-# [B] vector split like the batch dim, a page arena [P, H, page_len, D]
-# splits its heads only. Without it XLA would refuse to partition the
+# [B] vector split like the batch dim, a page arena [P, H/g, page_len, g*D]
+# splits its (stored, packed) heads only, and the queries arrive packed to
+# match. Without it XLA would refuse to partition the
 # kernel (TPU) or replicate the whole kv pool into every shard.
 # ---------------------------------------------------------------------------
 
@@ -621,15 +698,31 @@ def flash_decode_attention_q8(q, k, v, k_scale, v_scale, pos, scale=None,
 # ---------------------------------------------------------------------------
 # Paged kernels (families "decode_attention_paged[_q8]") — block-table
 # flash decode over the paged KV pool's page ARENA (inference/kv_pool.py
-# paged layout). The arena is [L, P, H, page_len, D] and each row's logical
-# plane is named by an int32 block table [B, n_lp]: logical block j of row
-# b lives in arena page ``tbl[b, j]``.
+# paged layout). The arena is [L, P, H/g, page_len, g*D] and each row's
+# logical plane is named by an int32 block table [B, n_lp]: logical block j
+# of row b lives in arena page ``tbl[b, j]``.
+#
+# HEADS THAT DO NOT FILL A LANE TILE SHARE ONE (``lane_pack``, PR 30): the
+# pool stores ``g = 128 // D`` heads side by side on the 128 lanes, so GPT-2's
+# heads of 64 reach these kernels as 8 heads of width 128, the trailing shape
+# a head dim of 128 (g = 1: nothing below changes) always had. The launcher
+# makes the query block-diagonal, ``[B, H/g, g*S, g*D]`` (``_pack_query``:
+# head ``a`` of a group in rows ``a*S ..`` and lanes ``a*D ..``, zeros
+# elsewhere, which add exactly nothing to a float32 score), the body attends
+# ``H/g`` heads of a whole tile, and the launcher takes each head's rows and
+# lanes back out (``_unpack_output``; the other lanes of a row attended a
+# neighbour's values and are dropped). In the body a row's query position is
+# ``pos + row % S`` and, in the int8 family, its scales are those of the head
+# it belongs to (``row // S``): nothing else knows. For S = 1 the ``g`` rows
+# sit in the one sublane tile that held one real row and fifteen of padding:
+# half the matmuls at full contraction depth, and a page is 512 KB to the
+# DMA as it is to the algorithm (at minor dim 64 it was padded to 1 MB).
 #
 # THE UNIT OF WORK IS ONE PAGE OF ALL HEADS, AND ONLY LIVE PAGES ARE UNITS.
 # A grid step that brings one page of one head (16 or 32 KB) costs a
 # quarter of a microsecond, six times what the page's bytes do, and a
-# page's [H, page_len, D] block is contiguous in the arena (256 KB at
-# GPT-2 355M's shape, 512 KB at OLMoE's), so a step brings that whole
+# page's [H/g, page_len, g*D] block is contiguous in the arena (512 KB at
+# GPT-2 355M's shape and at OLMoE's), so a step brings that whole
 # block, and the grid is not rows x heads x pages but a WORK LIST
 # (``_paged_units``): one entry for every live (row, page) pair, pages
 # ``0 .. (pos[b] + S - 1) // page_len`` of each row in order, and NO
@@ -647,8 +740,9 @@ def flash_decode_attention_q8(q, k, v, k_scale, v_scale, pos, scale=None,
 # block and the float32 statistics stay put while the row does. (A loop
 # over pages inside the kernel with ``make_async_copy`` from an arena left
 # in ``pl.ANY`` was the other form tried: Mosaic refuses to slice an HBM
-# ref whose minor dim, GPT-2's 64, is under a lane tile, so it serves one
-# family of two; at 128 it was 7% faster a call. The same body on the
+# ref whose minor dim, GPT-2's 64 until PR 30 packed it, is under a lane
+# tile, so it served one family of two; at 128 it was 7% faster a call and
+# is open again (PERF.md section 7). The same body on the
 # static ``B x n_lp`` grid was 12–14% slower, 2.3 times at three live rows
 # of sixteen. See PERF.md, PR 28.)
 #
@@ -658,9 +752,10 @@ def flash_decode_attention_q8(q, k, v, k_scale, v_scale, pos, scale=None,
 # kernels' straddle-only masking (global key positions are
 # j * page_len + lane). The LAYER and the page are the block's address, so
 # no per-layer value of an arena is ever formed; the int8 family brings
-# the page's scales ``[H, page_len]`` the same way and applies them to the
-# scores and the probabilities (positions on the lanes of both), which is
-# the dequantise-then-attend arithmetic reassociated.
+# the page's scales ``[H, page_len]`` (a scale a head of the MODEL, never
+# packed) the same way and applies them to the scores and the probabilities
+# (positions on the lanes of both), which is the dequantise-then-attend
+# arithmetic reassociated.
 #
 # VMEM, as reckoned for the scoped limit the call is given (v5e: 16 MiB;
 # the launcher plans into 12 MiB and leaves the rest to Mosaic's own
@@ -675,6 +770,19 @@ def flash_decode_attention_q8(q, k, v, k_scale, v_scale, pos, scale=None,
 # sublane axis; nothing here assumes they do not.
 # ---------------------------------------------------------------------------
 
+def gather_pages(arena, block_tbl, h, g):
+    """Each row's pages as its dense logical plane: one layer's row arena
+    ``[P, Hp, page_len, g * D]`` (packed, ``lane_pack``) to
+    ``[B, h, n_lp * page_len, D]``, its scale arena ``[P, >= h, page_len]``
+    to ``[B, h, n_lp * page_len]``, through one table gather."""
+    x = jnp.take(arena, block_tbl, axis=0)         # [B, n_lp, Hp, p, ...]
+    x = jnp.moveaxis(x, 2, 1)                      # [B, Hp, n_lp, p, ...]
+    x = x.reshape(x.shape[:2] + (-1,) + x.shape[4:])
+    if x.ndim == 3:
+        return x[:, :h]
+    return unpack_heads(x, g, h)
+
+
 @hot_path
 def decode_attention_paged_reference(q, k, v, block_tbl, pos, scale=None):
     """Paged ground truth: gather each row's pages into its dense
@@ -682,19 +790,14 @@ def decode_attention_paged_reference(q, k, v, block_tbl, pos, scale=None):
     einsum (flag-off) path computes, so kernel-on and kernel-off paged
     serving agree bit-for-bit.
 
-    q: [B, H, S, D]; k, v: [P, H, page_len, D] page arenas;
-    block_tbl: [B, n_lp] int32; pos: [B] int32 frontiers."""
-    B, H = q.shape[0], q.shape[1]
-    page_len = k.shape[2]
-    T = block_tbl.shape[1] * page_len
-
-    def gather(arena):
-        g = jnp.take(arena, block_tbl, axis=0)     # [B, n_lp, H, p, ...]
-        g = jnp.moveaxis(g, 2, 1)                  # [B, H, n_lp, p, ...]
-        return g.reshape((B, H, T) + g.shape[4:])
-
-    return decode_attention_reference(q, gather(k), gather(v), pos,
-                                      scale=scale)
+    q: [B, H, S, D]; k, v: page arenas as the pool stores one layer of
+    them, [P, ceil(H / g), page_len, g * D] (``lane_pack``; [P, H,
+    page_len, D] is g = 1); block_tbl: [B, n_lp] int32; pos: [B] int32
+    frontiers."""
+    h, g = q.shape[1], k.shape[-1] // q.shape[-1]
+    return decode_attention_reference(
+        q, gather_pages(k, block_tbl, h, g), gather_pages(v, block_tbl, h, g),
+        pos, scale=scale)
 
 
 @hot_path
@@ -702,17 +805,10 @@ def decode_attention_paged_q8_reference(q, k, v, k_scale, v_scale,
                                         block_tbl, pos, scale=None):
     """int8 paged ground truth: gather codes AND scales through the
     table, dequantize, then the dense reference."""
-    B, H = q.shape[0], q.shape[1]
-    page_len = k.shape[2]
-    T = block_tbl.shape[1] * page_len
-
-    def gather(arena):
-        g = jnp.take(arena, block_tbl, axis=0)
-        g = jnp.moveaxis(g, 2, 1)
-        return g.reshape((B, H, T) + g.shape[4:])
-
-    kf = dequantize_kv(gather(k), gather(k_scale), q.dtype)
-    vf = dequantize_kv(gather(v), gather(v_scale), q.dtype)
+    h, g = q.shape[1], k.shape[-1] // q.shape[-1]
+    kf, vf = (dequantize_kv(gather_pages(c, block_tbl, h, g),
+                            gather_pages(sc, block_tbl, h, g), q.dtype)
+              for c, sc in ((k, k_scale), (v, v_scale)))
     return decode_attention_reference(q, kf, vf, pos, scale=scale)
 
 
@@ -730,9 +826,10 @@ def _rem(a, b):
 
 
 def _whole_arena(layer, *arenas):
-    """(layer, arenas) as the launchers index them: ``[L, P, H, ...]`` and
-    a static layer. ``layer`` None means the caller holds ONE layer's
-    ``[P, H, ...]`` arena; a leading unit dim is a bitcast, not a copy."""
+    """(layer, arenas) as the launchers index them: ``[L, P, heads, ...]``
+    and a static layer. ``layer`` None means the caller holds ONE layer's
+    ``[P, heads, ...]`` arena; a leading unit dim is a bitcast, not a
+    copy."""
     if layer is None:
         return 0, tuple(a[None] for a in arenas)
     return int(layer), arenas
@@ -743,13 +840,14 @@ def _whole_arena(layer, *arenas):
 _PAGED_VMEM_BUDGET = 12 * 2 ** 20
 
 
-def _paged_heads_per_unit(h, s_blk, page_len, d, q_dtype, kv_dtype):
+def _paged_heads_per_unit(h, s_blk, page_len, d, q_dtype, kv_dtype, pack=1):
     """Heads one unit of the paged kernel attends: all ``h`` of the call
-    (a shard's, under tensor parallelism), or the largest divisor of ``h``
-    whose blocks stay inside ``_PAGED_VMEM_BUDGET``. From shapes and dtypes
-    alone; the minor dim of every block pads to a lane tile."""
+    (a shard's, under tensor parallelism; packed heads of width ``d`` where
+    ``pack`` > 1), or the largest divisor of ``h`` whose blocks stay inside
+    ``_PAGED_VMEM_BUDGET``. From shapes and dtypes alone; the minor dim of
+    every block pads to a lane tile."""
     def lanes(n):
-        return -(-n // 128) * 128
+        return -(-n // LANES) * LANES
 
     q_b, kv_b = jnp.dtype(q_dtype).itemsize, jnp.dtype(kv_dtype).itemsize
     per_head = (2 * 2 * s_blk * lanes(d) * q_b          # q, out
@@ -762,9 +860,10 @@ def _paged_heads_per_unit(h, s_blk, page_len, d, q_dtype, kv_dtype):
     fit = _PAGED_VMEM_BUDGET // per_head
     if h <= fit:
         return h
-    # A group of int8 heads is the sublane dim of its scale block.
+    # A group of int8 heads is the sublane dim of its scale block, which
+    # holds a scale for each of a packed head's ``pack`` heads.
     return max((g for g in range(1, h) if h % g == 0 and g <= fit
-                and (kv_b > 1 or g % 8 == 0)), default=h)
+                and (kv_b > 1 or g * pack % 8 == 0)), default=h)
 
 
 def _paged_units(tbl, pos, s_len, page_len):
@@ -795,9 +894,12 @@ def _paged_units(tbl, pos, s_len, page_len):
 
 
 def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
-                  *refs, s_len, q8, single_kv):
+                  *refs, s_len, q8, single_kv, pack):
     """One grid step = one unit of ``_paged_units``: a page of all the
-    heads (of the group, where all do not fit) of one row."""
+    heads (of the group, where all do not fit) of one row. Against a packed
+    arena (``pack`` heads a lane tile) a head of the block is ``pack`` heads
+    of the model: query row ``r`` is row ``r % s_len`` of head
+    ``r // s_len`` of them (``_pack_query``)."""
     n_a = 4 if q8 else 2
     k_ref, v_ref, *scale_refs = refs[:n_a]     # scales: the int8 family's
     o_ref, stats = refs[n_a], refs[n_a + 1:]
@@ -816,14 +918,33 @@ def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
         s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32,
                                 precision=_mxu_precision(q.dtype))
+
+        def row_scales(ref):
+            # [hb * pack, page_len] scales, one a head of the model and
+            # key, as a score's [hb, s_blk, page_len]: a row takes the
+            # scales of the head it belongs to.
+            if pack == 1:
+                return ref[...][:, None, :]
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            out = ref[pl.ds(0, s.shape[0], stride=pack), :][:, None, :]
+            for a in range(1, pack):
+                out = jnp.where(
+                    row >= a * s_len,
+                    ref[pl.ds(a, s.shape[0], stride=pack), :][:, None, :],
+                    out)
+            return out
+
         if q8:
-            s = s * scale_refs[0][...][:, None, :]
+            s = s * row_scales(scale_refs[0])
 
         def straddling():
             # Key col (global j*page_len + c) visible to query row i
             # (global pos_b + i) iff k_pos <= q_pos. Padded query rows
             # (i >= s_len) compute garbage the launcher slices off.
-            q_pos = pos_b + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            if pack > 1:
+                row = _rem(row, s_len)
+            q_pos = pos_b + row
             k_pos = j * page_len + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 2)
             return jnp.where(k_pos <= q_pos, s, NEG_INF)
@@ -844,7 +965,7 @@ def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
                 # p is scaled by the values' scales before the matmul, so
                 # the row-sum is taken from p itself.
                 l = jnp.sum(p.astype(jnp.float32), axis=-1, keepdims=True)
-                scaled = p.astype(jnp.float32) * scale_refs[1][...][:, None, :]
+                scaled = p.astype(jnp.float32) * row_scales(scale_refs[1])
                 return times_v(scaled, v), l
             # p @ [v | 1]: the row-sum rides the PV matmul, as in
             # ``_pv_rowsum``, and shares p's rounding with the numerator.
@@ -885,21 +1006,25 @@ def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
     pl.when(n_live > 0)(attend)
 
 
-def _paged_launch(name, q, arenas, tbl, pos, scale, layer):
+def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1):
     """The one launcher of both paged families: ``arenas`` is (k, v) or
-    (k, v, k_scale, v_scale), whole or one layer's (``layer`` None)."""
+    (k, v, k_scale, v_scale), whole or one layer's (``layer`` None). With
+    ``pack`` > 1 the arenas are packed and ``q`` is ``_pack_query``'s:
+    ``[B, Hp, pack * S, pack * D]`` against ``[.., Hp, page_len, pack * D]``,
+    which the body attends as ``Hp`` heads of a whole lane tile."""
     from jax.experimental.pallas import tpu as pltpu
 
     layer, arenas = _whole_arena(layer, *arenas)
-    b, h, s, d = q.shape
+    b, h, n_rows, d = q.shape
+    s = n_rows // pack               # query positions a row of the batch
     page_len = arenas[0].shape[3]
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     sub = _sublane(q.dtype)
-    s_blk = -(-s // sub) * sub
-    if s_blk != s:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, s_blk - s), (0, 0)))
+    s_blk = -(-n_rows // sub) * sub
+    if s_blk != n_rows:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, s_blk - n_rows), (0, 0)))
     hb = _paged_heads_per_unit(h, s_blk, page_len, d, q.dtype,
-                               arenas[0].dtype)
+                               arenas[0].dtype, pack)
     pos = pos.astype(jnp.int32)
     rows, js, pages, live, n_units = _paged_units(tbl.astype(jnp.int32), pos,
                                                   s, page_len)
@@ -909,10 +1034,11 @@ def _paged_launch(name, q, arenas, tbl, pos, scale, layer):
         return (rows_ref[t], g, 0, 0)
 
     def page_spec(arena):
-        # [hb, page_len, d] of a row arena, [hb, page_len] of a scale arena.
+        # [hb, page_len, d] of a row arena, [hb * pack, page_len] of a
+        # scale arena (a scale a head of the model).
         zeros = (0,) * (arena.ndim - 3)
         return pl.BlockSpec(
-            (None, None, hb) + arena.shape[3:],
+            (None, None, hb * (arena.shape[2] // h)) + arena.shape[3:],
             lambda g, t, rows_ref, js_ref, pages_ref, *_:
             (layer, pages_ref[t], g) + zeros)
 
@@ -934,28 +1060,45 @@ def _paged_launch(name, q, arenas, tbl, pos, scale, layer):
     out = pallas_mode.kernel_call(
         name,
         functools.partial(_paged_kernel, s_len=s, q8=len(arenas) == 4,
-                          single_kv=single_kv),
+                          single_kv=single_kv, pack=pack),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d), q.dtype),
     )(*units, q, *arenas)
     # A freed row has no unit, so the kernel never wrote its block: zeros,
     # in a select that fuses into whatever reads the output.
     out = jnp.where((live > 0)[:, None, None, None], out, 0)
-    return out[:, :, :s] if s_blk != s else out
+    return out[:, :, :n_rows] if s_blk != n_rows else out
 
 
 def _flash_decode_paged_pallas(q, k, v, tbl, pos, scale,
-                               name=None, layer=None):
+                               name=None, layer=None, pack=1):
     return _paged_launch(name or "paged_decode", q, (k, v), tbl, pos, scale,
-                         layer)
+                         layer, pack)
 
 
 def _flash_decode_paged_q8_pallas(q, k, v, k_scale, v_scale, tbl, pos,
-                                  scale, name=None, layer=None):
+                                  scale, name=None, layer=None, pack=1):
     return _paged_launch(name or "paged_decode_q8", q,
                          (k, v, k_scale.astype(jnp.float32),
                           v_scale.astype(jnp.float32)),
-                         tbl, pos, scale, layer)
+                         tbl, pos, scale, layer, pack)
+
+
+def _paged_on_shards(launch, q, arenas, block_tbl, pos, scale, name, layer):
+    """Both paged families' way to their launcher: ``g`` heads a lane tile
+    is read from the shapes, the queries are packed to match the arena and
+    each head's output taken back out; on a mesh the PACKED heads are what
+    'model' splits, of the queries and of the arenas alike."""
+    h, g = q.shape[1], arenas[0].shape[-1] // q.shape[-1]
+    q = _pack_query(q, g)
+    arena = "-h" if layer is None else "--h"
+    out = on_shards(
+        functools.partial(launch, scale=float(scale), name=name, layer=layer,
+                          pack=g),
+        kernel_sharding(q.shape[0], q.shape[1]),
+        ("bh",) + (arena,) * len(arenas) + ("b", "b"), ("bh",))(
+            q, *arenas, block_tbl, pos)
+    return _unpack_output(out, g, h)
 
 
 @hot_path
@@ -967,12 +1110,14 @@ def flash_decode_attention_paged(q, k, v, block_tbl, pos, scale=None,
       q: [B, H, S, D] query rows at per-row frontiers ``pos``; each
         row's k/v for those positions must already be written into its
         pages (models/generation.py writes before attending).
-      k, v: the paged pool's arenas WHOLE, [L, P, H, page_len, D], with
-        ``layer`` naming the layer to attend — the serving path: the
-        kernel's index map picks the layer, so no per-layer value of the
-        arena is ever formed. With ``layer`` None, one layer's
-        [P, H, page_len, D] arena. Page 0 is the trash page freed rows
-        point at.
+      k, v: the paged pool's arenas WHOLE, as it stores them:
+        [L, P, ceil(H / g), page_len, g * D] with ``g = lane_pack(D, H)``
+        heads a lane tile ([L, P, H, page_len, D] at a head dim that fills
+        one; ``g`` is read back as ``k.shape[-1] // D``), with ``layer``
+        naming the layer to attend — the serving path: the kernel's index
+        map picks the layer, so no per-layer value of the arena is ever
+        formed. With ``layer`` None, one layer's [P, .., page_len, g * D]
+        arena. Page 0 is the trash page freed rows point at.
       block_tbl: [B, n_lp] int32 — row b's logical block j lives in
         arena page ``block_tbl[b, j]``.
       pos: [B] int32 per-row frontiers.
@@ -996,12 +1141,8 @@ def flash_decode_attention_paged(q, k, v, block_tbl, pos, scale=None,
             k, v = k[layer], v[layer]
         return decode_attention_paged_reference(q, k, v, block_tbl, pos,
                                                 scale=scale)
-    arena = "-h" if layer is None else "--h"
-    return on_shards(
-        functools.partial(_flash_decode_paged_pallas, scale=float(scale),
-                          name=name, layer=layer),
-        kernel_sharding(q.shape[0], q.shape[1]),
-        ("bh", arena, arena, "b", "b"), ("bh",))(q, k, v, block_tbl, pos)
+    return _paged_on_shards(_flash_decode_paged_pallas, q, (k, v), block_tbl,
+                            pos, scale, name, layer)
 
 
 @hot_path
@@ -1009,7 +1150,8 @@ def flash_decode_attention_paged_q8(q, k, v, k_scale, v_scale, block_tbl,
                                     pos, scale=None, name=None, layer=None):
     """int8 block-table flash decode: ``flash_decode_attention_paged``
     over int8 code arenas with fp32 per-(head, position) scale arenas
-    ([L, P, H, page_len] beside [L, P, H, page_len, D] codes and a static
+    ([L, P, g * ceil(H / g), page_len]: a scale a head of the model, never
+    packed, beside [L, P, ceil(H / g), page_len, g * D] codes and a static
     ``layer``, or one layer's with ``layer`` None), dequantizing in-block
     exactly like the dense q8 family."""
     d = q.shape[-1]
@@ -1022,13 +1164,9 @@ def flash_decode_attention_paged_q8(q, k, v, k_scale, v_scale, block_tbl,
                                       for a in (k, v, k_scale, v_scale))
         return decode_attention_paged_q8_reference(
             q, k, v, k_scale, v_scale, block_tbl, pos, scale=scale)
-    arena = "-h" if layer is None else "--h"
-    return on_shards(
-        functools.partial(_flash_decode_paged_q8_pallas, scale=float(scale),
-                          name=name, layer=layer),
-        kernel_sharding(q.shape[0], q.shape[1]),
-        ("bh", arena, arena, arena, arena, "b", "b"), ("bh",))(
-            q, k, v, k_scale, v_scale, block_tbl, pos)
+    return _paged_on_shards(_flash_decode_paged_q8_pallas, q,
+                            (k, v, k_scale, v_scale), block_tbl, pos, scale,
+                            name, layer)
 
 
 # ---------------------------------------------------------------------------
@@ -1039,8 +1177,10 @@ def flash_decode_attention_paged_q8(q, k, v, k_scale, v_scale, block_tbl,
 # convert back around every decode call. This kernel aliases the arena to
 # its output and rewrites only the frontier page(s) of each row, addressed
 # ``(layer, block_tbl[b, pos[b] // page_len + j], ..)`` by scalar prefetch:
-# the page's [H, page_len, D] block (for a one-row append only the
-# [H, 32, D] tile around the frontier) comes into VMEM, the new rows are
+# the page's [H/g, page_len, g*D] block (for a one-row append only the
+# [H/g, 32, g*D] tile around the frontier: 64 KB at GPT-2 355M's packed
+# shape, where the unpacked minor dim of 64 was padded to 128 KB) comes
+# into VMEM, the new rows, regrouped as the arena holds heads, are
 # selected in at their offsets, and the block goes back. Everything else
 # in the arena is never touched, so it keeps ONE layout — the decode
 # kernel's — from the step's entry to its exit.
@@ -1189,10 +1329,11 @@ def kv_append(arenas, new, block_tbl, pos, layer):
 
     Args:
       arenas: tuple of page arenas of one paged pool (k and v together,
-        at least), each WHOLE: [L, P, H, page_len, D] rows (bf16, or int8
-        codes) and, for an int8 pool, [L, P, H, page_len] fp32 scales.
-        Donate them (the serving step does): each comes back as the same
-        buffer.
+        at least), each WHOLE and as the pool stores it:
+        [L, P, ceil(H / g), page_len, g * D] rows (bf16, or int8 codes;
+        ``g = lane_pack(D, H)``) and, for an int8 pool,
+        [L, P, g * ceil(H / g), page_len] fp32 scales. Donate them (the
+        serving step does): each comes back as the same buffer.
       new: the matching tuple of new values, [B, H, S, D] per row arena
         and [B, H, S] per scale arena: row b's S positions
         ``pos[b] .. pos[b]+S-1`` (S = 1 in the decode scan, spec_k + 1 in
@@ -1201,8 +1342,8 @@ def kv_append(arenas, new, block_tbl, pos, layer):
       block_tbl: [B, n_lp] int32; pos: [B] int32 pre-write frontiers.
       layer: static int.
 
-    Bit for bit ``arena.at[layer, pg, :, off, :].set(new)`` with
-    ``pg, off`` through the table, for every position inside a row's
+    Bit for bit ``arena.at[layer, pg, :, off, :].set(pack_heads(new, g))``
+    with ``pg, off`` through the table, for every position inside a row's
     plane — except the trash page 0, which several frozen rows may share
     and nothing reads. ``page_len`` must be a kernel block
     (``decode_supported``): the branch of ``models/generation.py``
@@ -1211,6 +1352,13 @@ def kv_append(arenas, new, block_tbl, pos, layer):
     """
     page_len = arenas[0].shape[3]
     assert decode_supported(page_len) and len(arenas) > 1, page_len
+    # The new values in the arenas' stored shape (kilobytes): rows packed
+    # ``g`` heads a lane tile, scales a head of the model (a zero head
+    # where ``g`` does not divide the count), and the kernel below sees
+    # ``ceil(H / g)`` heads of a whole tile.
+    g = arenas[0].shape[-1] // new[0].shape[-1]
+    new = tuple(pack_heads(x, g) if a.ndim == 5 else
+                pad_heads(x, a.shape[2], 1) for x, a in zip(new, arenas))
     s = new[0].shape[2]
     b, h = new[0].shape[:2]
     # Lane-dim padding of the scales' new values cannot be made inside the

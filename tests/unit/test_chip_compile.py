@@ -91,18 +91,26 @@ def _paged_args(page_len, rows, int8=False):
             + [((SLOTS, n_lp), I32), ((SLOTS,), I32)])
 
 
-# The serving cells' paged pool: 24 layers, 16 slots x 9 pages + trash.
+# The serving cells' paged pool: 24 layers, 16 slots x 9 pages + trash. The
+# pool STORES it packed, g = 2 heads of 64 a lane tile (``da.lane_pack``):
+# [24, 145, 8, 128, 128], scales a head of the model. The unpacked form
+# (g = 1, the minor dim 64 padded to a tile on the chip) is what a caller
+# with an arena of its own may still hand the launchers.
 LAYERS, PAGES, PAGE = 24, SLOTS * (T_KV + 128) // 128 + 1, 128
 ARENA = ((LAYERS, PAGES, HEADS, PAGE, HD), BF16)
 ARENA_Q8 = ((LAYERS, PAGES, HEADS, PAGE, HD), I8)
 ARENA_SCALE = ((LAYERS, PAGES, HEADS, PAGE), F32)
+PACK = 128 // HD
+PACKED = ((LAYERS, PAGES, HEADS // PACK, PAGE, PACK * HD), BF16)
+PACKED_Q8 = (PACKED[0], I8)
 
 
-def _whole_arena_args(rows, slots, int8=False):
+def _whole_arena_args(rows, slots, int8=False, packed=False):
     """q, the arenas WHOLE, table, frontiers: what ``_forward`` hands the
     layer-indexed paged kernels."""
+    rows_arena, codes = (PACKED, PACKED_Q8) if packed else (ARENA, ARENA_Q8)
     return ([((slots, HEADS, rows, HD), BF16)]
-            + ([ARENA_Q8] * 2 + [ARENA_SCALE] * 2 if int8 else [ARENA] * 2)
+            + ([codes] * 2 + [ARENA_SCALE] * 2 if int8 else [rows_arena] * 2)
             + [((slots, PAGES // SLOTS), I32), ((slots,), I32)])
 
 
@@ -116,11 +124,12 @@ def _paged_whole_q8(q, k, v, ks, vs, tbl, pos):
                                               layer=LAYERS - 1)
 
 
-def _append_args(rows, slots, int8=False):
+def _append_args(rows, slots, int8=False, packed=False):
+    rows_arena, codes = (PACKED, PACKED_Q8) if packed else (ARENA, ARENA_Q8)
     new = ((slots, HEADS, rows, HD), I8 if int8 else BF16)
     new_scale = ((slots, HEADS, rows), F32)
     return ([new] * 2 + ([new_scale] * 2 if int8 else [])
-            + ([ARENA_Q8] * 2 + [ARENA_SCALE] * 2 if int8 else [ARENA] * 2)
+            + ([codes] * 2 + [ARENA_SCALE] * 2 if int8 else [rows_arena] * 2)
             + [((slots, PAGES // SLOTS), I32), ((slots,), I32)])
 
 
@@ -226,6 +235,29 @@ CASES = {
                                    _append_args(5, SLOTS, int8=True), {}),
     "kv_append_q8_lane_128_rows": (_append,
                                    _append_args(128, 1, int8=True), {}),
+    # The arena as the pool stores GPT-2's: two heads of 64 a lane tile.
+    # The decode scan's one row, speculation's verify (2 x 5 rows in one
+    # sublane tile), the prefill lane (2 x 128 rows), and the int8 tier.
+    "packed_paged_decode_1_row_d64": (
+        _paged_whole, _whole_arena_args(1, SLOTS, packed=True), {}),
+    "packed_paged_verify_5_rows_d64": (
+        _paged_whole, _whole_arena_args(5, SLOTS, packed=True), {}),
+    "packed_paged_lane_128_rows_d64": (
+        _paged_whole, _whole_arena_args(128, 1, packed=True), {}),
+    "packed_paged_q8_1_row_d64": (
+        _paged_whole_q8, _whole_arena_args(1, SLOTS, int8=True, packed=True),
+        {}),
+    "packed_paged_q8_verify_5_rows_d64": (
+        _paged_whole_q8, _whole_arena_args(5, SLOTS, int8=True, packed=True),
+        {}),
+    "packed_kv_append_1_row_d64": (
+        _append, _append_args(1, SLOTS, packed=True), {}),
+    "packed_kv_append_verify_5_rows_d64": (
+        _append, _append_args(5, SLOTS, packed=True), {}),
+    "packed_kv_append_lane_128_rows_d64": (
+        _append, _append_args(128, 1, packed=True), {}),
+    "packed_kv_append_q8_1_row_d64": (
+        _append, _append_args(1, SLOTS, int8=True, packed=True), {}),
     "olmoe_paged_decode_32_rows_d128": (_olmoe_decode(),
                                         _olmoe_decode_args(1, O_SLOTS), {}),
     "olmoe_prefill_attn_lane_128_rows_d128": (
@@ -382,8 +414,9 @@ def _mixed_step_text(chip, adapter, params, pool, chunk, lane):
     def mixed_step(*args):
         return engine_mod._mixed_step_program(*args)
 
-    return jax.jit(mixed_step, static_argnums=(1, 2, 3),
-                   donate_argnums=(4,)).lower(
+    return jax.jit(mixed_step, static_argnums=(1, 2, 3), donate_argnums=(4,),
+                   compiler_options=engine_mod.step_compiler_options("tpu")
+                   ).lower(
         on_chip(params), adapter, chunk, None, on_chip(pool),
         jax.ShapeDtypeStruct((1, lane), I32, sharding=chip),
         scalar(I32), scalar(I32), scalar(I32), scalar(jnp.bool_),
@@ -404,22 +437,29 @@ def _scan_lines(comps):
 
 def test_mixed_step_forms_no_layer_of_the_arena_in_the_decode_scan(
         chip, monkeypatch):
-    """The engine's mixed step (355M widths, 4 layers, the cells' paged
-    pool: 16 slots, page 128, chunk 16, prefill chunk 128) compiled for
-    the described chip: inside the decode scan's ``while`` body nothing
+    """The engine's mixed step (355M widths and all its 24 layers, the
+    cells' paged pool: 16 slots, page 128, chunk 16, prefill chunk 128;
+    a minute to compile) for the described chip, with the compiler options
+    the engine jits it with: inside the decode scan's ``while`` body nothing
     but the kernels has a result shaped like the arena or one layer of it
     — no slice, scatter, ``dynamic-update-slice`` or layout ``copy``. (A
     5-D XLA scatter in place of ``kv_append`` passes every parity test
     and fails here: XLA gives the arena the scatter's layout and converts
-    all of it around every kernel call.) Outside the scan the arenas meet
-    exactly the layout copies of the step's entry and exit."""
+    all of it around every kernel call.) Nor outside the scan: the pool
+    stores the arena as the kernels read it, two heads of 64 a lane tile
+    (``[L, P, 8, 128, 128]``), so the step no longer converts k and v
+    where it enters and leaves (six whole-arena ``copy`` until PR 30).
+    All 24 layers, because depth decides it: from some 18 layers on XLA's
+    copy elision runs out of its default allowance and leaves four
+    whole-arena copies around the lane's last ``kv_append``
+    (``engine.step_compiler_options``); at 4 layers this test saw none."""
     from deepspeed_tpu.inference import kv_pool
     from deepspeed_tpu.inference.adapters.gpt2 import GPT2Adapter
     from deepspeed_tpu.inference.config import InferenceConfig
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
 
     monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
-    n_layer, chunk, lane = 4, 16, 128
+    n_layer, chunk, lane = LAYERS, 16, 128
     model = GPT2LMHeadModel(GPT2Config(
         n_embd=HEADS * HD, n_layer=n_layer, n_head=HEADS, n_positions=T_KV,
         vocab_size=50257, dtype=BF16, dropout=0.0))
@@ -433,7 +473,8 @@ def test_mixed_step_forms_no_layer_of_the_arena_in_the_decode_scan(
     pool = jax.eval_shape(lambda: kv_pool.init_pool(
         adapter.cache_spec(), SLOTS, T_KV, slack=lane, page_len=PAGE,
         num_pages=PAGES - 1))
-    assert pool["k"].shape == (n_layer,) + ARENA[0][1:]
+    assert pool["k"].shape == (n_layer,) + PACKED[0][1:] == \
+        (n_layer, PAGES, HEADS // 2, PAGE, 128)
 
     text = _mixed_step_text(chip, adapter, params, pool, chunk, lane)
 
@@ -442,17 +483,17 @@ def test_mixed_step_forms_no_layer_of_the_arena_in_the_decode_scan(
     names = sorted(c.split(".")[0] for c in _kernel_calls(
         "\n".join(in_scan)))
     assert names == ["kv_append"] * n_layer + ["paged_decode"] * n_layer
-    shapes = ["[{},{},{},{},{}]".format(n_layer, PAGES, HEADS, PAGE, HD),
-              "[{},{},{},{}]".format(PAGES, HEADS, PAGE, HD)]
+    shapes = ["[{},{},{},{},{}]".format(n_layer, *PACKED[0][1:]),
+              "[{},{},{},{}]".format(*PACKED[0][1:]),
+              "[{},{},{},{},{}]".format(n_layer, *ARENA[0][1:]),
+              "[{},{},{},{}]".format(*ARENA[0][1:])]
     assert _arena_shaped(in_scan, shapes) == []
     # Outside it: the lane's cond and the scan's carry add nothing of their
-    # own. What is left converts k and v between the stored layout and the
-    # kernels' where the step enters (once in each branch of the lane's
-    # cond) and back where it leaves.
+    # own, and nothing converts the arena where the step enters or leaves.
     outside = _arena_shaped(
         [line for name, lines in comps.items() if name not in scan
          for line in lines], shapes)
-    assert [op for _, op in outside] == ["copy"] * 6, outside
+    assert outside == [], outside
 
 
 def test_decoder_mixed_step_forms_no_layer_of_the_arena_in_its_decode_scan(

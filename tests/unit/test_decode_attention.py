@@ -379,9 +379,30 @@ def test_q8_unsupported_length_falls_back_to_reference():
 _PAGE = 128
 
 
+def _stored(x, g, scales=False):
+    """Rows ``[..., H, T, D]`` as a paged pool stores them, ``g`` heads a
+    lane tile: ``[..., ceil(H / g), T, g * D]``, head ``g * p + a`` in lanes
+    ``a * D ..`` of packed head ``p`` (written out here without the
+    program's ``pack_heads``). ``scales`` ``[..., H, T]`` stay a head of
+    the model each and only gain the zero head of an ``H`` that ``g`` does
+    not divide."""
+    x = np.asarray(x)
+    h_axis = x.ndim - (2 if scales else 3)
+    hp = -(-x.shape[h_axis] // g)
+    widths = [(0, 0)] * x.ndim
+    widths[h_axis] = (0, hp * g - x.shape[h_axis])
+    x = np.pad(x, widths)
+    if scales:
+        return jnp.asarray(x)
+    return jnp.asarray(np.concatenate(
+        [np.take(x, np.arange(a, hp * g, g), axis=h_axis) for a in range(g)],
+        axis=-1))
+
+
 def _scatter_reference(arena, new, tbl, pos, layer):
     """What ``models/generation.py`` ``_forward`` did before the kernel:
-    ``arena.at[layer, pg, :, off, :].set(new)`` through the block table."""
+    ``arena.at[layer, pg, :, off, :].set(new)`` through the block table
+    (``arena`` and ``new`` in the same form: both stored, or both not)."""
     s = new.shape[2]
     w_pos = pos[:, None] + jnp.arange(s)[None]
     w_pg = tbl[jnp.arange(new.shape[0])[:, None],
@@ -394,8 +415,12 @@ def _scatter_reference(arena, new, tbl, pos, layer):
 
 
 def _append_case(s, pos, int8, layer, frozen=(), n_layer=3, h=2, d=8,
-                 n_lp=3, seed=0):
+                 n_lp=3, seed=0, stored=False):
+    """``stored``: the arenas in the shape ``init_pool`` gives a model of
+    ``h`` heads of ``d`` (``lane_pack`` heads a lane tile, a zero head where
+    that does not divide ``h``); the new values stay ``[B, H, S, D]``."""
     rng = np.random.RandomState(seed)
+    g = da.lane_pack(d, h) if stored else 1
     b = len(pos)
     n_pages = b * n_lp + 1
     tbl = (1 + rng.permutation(n_pages - 1)).reshape(b, n_lp)
@@ -416,20 +441,23 @@ def _append_case(s, pos, int8, layer, frozen=(), n_layer=3, h=2, d=8,
                                jnp.float32) for _ in range(2)]
         new += [jnp.asarray(rng.rand(b, h, s), jnp.float32)
                 for _ in range(2)]
+    arenas = [_stored(a, g, scales=a.ndim == 4) for a in arenas]
+    assert arenas[0].shape[2:] == (-(-h // g), _PAGE, g * d)
     got = jax.jit(lambda a, n: kv_append(tuple(a), tuple(n), tbl, pos,
                                          layer))(arenas, new)
     assert len(got) == len(arenas)
-    for arena, x, g in zip(arenas, new, got):
-        want = np.array(_scatter_reference(arena, x, tbl, pos, layer)
-                        .astype(jnp.float32))
-        g = np.array(g.astype(jnp.float32))
-        assert g.dtype == want.dtype and g.shape == want.shape
+    for arena, x, out in zip(arenas, new, got):
+        want = np.array(_scatter_reference(
+            arena, _stored(x, g, scales=x.ndim == 3), tbl, pos, layer)
+            .astype(jnp.float32))
+        out = np.array(out.astype(jnp.float32))
+        assert out.dtype == want.dtype and out.shape == want.shape
         # Bit for bit over the whole arena: every layer, every page. Only
         # the trash page's written layer is unchecked when frozen rows
         # share it (their order of arrival there is nobody's business).
         if frozen:
-            g[layer, 0], want[layer, 0] = 0, 0
-        np.testing.assert_array_equal(g, want)
+            out[layer, 0], want[layer, 0] = 0, 0
+        np.testing.assert_array_equal(out, want)
 
 
 APPEND_CASES = {
@@ -448,6 +476,31 @@ APPEND_CASES = {
         1, [5, 77, 200, 0, 129, 9], False, 1, (0, 3, 5)),
     "frozen_rows_share_the_trash_page_verify": (
         5, [5, 126, 200, 0, 129, 9], True, 2, (0, 3, 5)),
+    # The arena as the pool STORES it, (heads, head dim) after the frozen
+    # rows: g = 2 heads of 64 a lane tile, 4 of 32, 1 of 128 (nothing
+    # packed), and a head count g does not divide (gpt2-xl's 25 of 64: a
+    # zero head). Frontiers straddle a page; a freed row.
+    "stored_g2_one_row": (1, [0, 127, 128, 300], False, 1, (), 4, 64),
+    "stored_g2_verify_5_rows_straddling": (
+        5, [0, 125, 126, 251], False, 2, (), 4, 64),
+    "stored_g2_lane_128_rows_from_mid_page": (
+        128, [7, 100, 255], False, 0, (), 4, 64),
+    "stored_g2_int8_one_row": (1, [0, 127, 128, 300], True, 2, (), 4, 64),
+    "stored_g2_int8_verify_5_rows_straddling": (
+        5, [0, 125, 126, 251], True, 0, (), 4, 64),
+    "stored_g2_int8_lane_128_rows_from_mid_page": (
+        128, [7, 100, 255], True, 1, (), 4, 64),
+    "stored_g2_frozen_rows": (
+        1, [5, 77, 200, 0, 129, 9], False, 1, (0, 3, 5), 4, 64),
+    "stored_g4_one_row": (1, [0, 127, 128, 300], False, 0, (), 8, 32),
+    "stored_g4_int8_verify_5_rows_straddling": (
+        5, [0, 125, 126, 251], True, 1, (), 8, 32),
+    "stored_g1_one_row_d128": (1, [31, 32, 255, 383], False, 2, (), 2, 128),
+    "stored_25_heads_one_row": (1, [0, 127, 128], False, 1, (), 25, 64),
+    "stored_25_heads_int8_verify_5_rows": (
+        5, [0, 125, 251], True, 0, (), 25, 64),
+    "stored_5_heads_of_32_lane_40_rows": (
+        40, [100, 3, 250], False, 2, (), 5, 32),
 }
 
 
@@ -455,9 +508,12 @@ APPEND_CASES = {
 def test_kv_append_is_the_scatter_bit_for_bit(name):
     """The in-place append equals ``arena.at[layer, pg, :, off, :].set``
     over the whole arena — nothing else in it may change: other layers,
-    other pages, the rows of a frontier page below and above the write."""
-    s, pos, int8, layer, frozen = APPEND_CASES[name]
-    _append_case(s, pos, int8, layer, frozen=frozen)
+    other pages, the rows of a frontier page below and above the write.
+    On a stored (packed) arena the scatter is of the new values regrouped
+    as the arena holds heads; no lane of a neighbouring head may change."""
+    s, pos, int8, layer, frozen, *shape = APPEND_CASES[name]
+    kw = dict(zip(("h", "d"), shape), stored=bool(shape))
+    _append_case(s, pos, int8, layer, frozen=frozen, **kw)
 
 
 @pytest.mark.parametrize("int8", [False, True])
@@ -537,10 +593,12 @@ def test_paged_decode_and_kv_append_at_both_head_dims_in_bf16(s, d):
 
 
 def _paged_operands(d, s, pos, int8, dtype, shared=0, frozen=(), h=4,
-                    n_lp=3, n_layer=2, seed=0):
+                    n_lp=3, n_layer=2, seed=0, stored=False):
     """q, arenas (k, v[, k_scale, v_scale]) WHOLE, table, frontiers.
     ``shared``: the first ``shared`` pages of every live row are row 0's
-    (a prefix installed by reference). ``frozen`` rows: table all trash."""
+    (a prefix installed by reference). ``frozen`` rows: table all trash.
+    ``stored``: the arenas as ``init_pool`` shapes them for ``h`` heads of
+    ``d`` (``_stored``); without it one head a minor dim (g = 1)."""
     rng = np.random.RandomState(seed)
     b = len(pos)
     n_pages = b * n_lp + 1
@@ -555,6 +613,10 @@ def _paged_operands(d, s, pos, int8, dtype, shared=0, frozen=(), h=4,
         arenas = (k, v, ks, vs)
     else:
         arenas = tuple(a.astype(dtype) for a in arenas)
+    if stored:
+        g = da.lane_pack(d, h)
+        arenas = tuple(_stored(a, g, scales=a.ndim == 4) for a in arenas)
+        assert arenas[0].shape[2:] == (-(-h // g), _PAGE, g * d)
     return (q, arenas, jnp.asarray(tbl, jnp.int32),
             jnp.asarray(pos, jnp.int32))
 
@@ -603,6 +665,32 @@ PAGED_CASES = {
         128, 5, True, jnp.bfloat16, 0, (0, 4)),
     "every_row_frozen_d64_bf16": (
         64, 1, False, jnp.bfloat16, 0, (0, 1, 2, 3, 4)),
+    # The arena as the pool STORES it (the number of heads after the frozen
+    # rows): the query goes in block-diagonal, g heads' rows in one tile.
+    "stored_g2_decode_1_row_bf16": (64, 1, False, jnp.bfloat16, 0, (), 4),
+    "stored_g2_verify_5_rows_bf16": (64, 5, False, jnp.bfloat16, 0, (), 4),
+    "stored_g2_lane_128_rows_bf16": (64, 128, False, jnp.bfloat16, 0, (), 4),
+    "stored_g2_decode_1_row_int8": (64, 1, True, jnp.bfloat16, 0, (), 4),
+    "stored_g2_verify_5_rows_int8": (64, 5, True, jnp.bfloat16, 0, (), 4),
+    "stored_g2_lane_128_rows_int8": (64, 128, True, jnp.bfloat16, 0, (), 4),
+    "stored_g2_decode_1_row_float32": (64, 1, False, jnp.float32, 0, (), 4),
+    "stored_g2_verify_5_rows_float32_int8": (
+        64, 5, True, jnp.float32, 0, (), 4),
+    "stored_g2_shared_prefix_page_frozen_rows_bf16": (
+        64, 1, False, jnp.bfloat16, 1, (1, 3), 4),
+    "stored_g2_frozen_rows_first_and_last_int8": (
+        64, 5, True, jnp.bfloat16, 0, (0, 4), 4),
+    "stored_g4_decode_1_row_bf16": (32, 1, False, jnp.bfloat16, 0, (), 8),
+    "stored_g4_verify_5_rows_int8": (32, 5, True, jnp.bfloat16, 0, (), 8),
+    "stored_g4_lane_128_rows_float32": (32, 128, False, jnp.float32, 0, (), 8),
+    "stored_g1_decode_1_row_d128_bf16": (
+        128, 1, False, jnp.bfloat16, 0, (), 4),
+    "stored_25_heads_decode_1_row_bf16": (
+        64, 1, False, jnp.bfloat16, 0, (2,), 25),
+    "stored_25_heads_verify_5_rows_int8": (
+        64, 5, True, jnp.bfloat16, 0, (), 25),
+    "stored_5_heads_of_32_decode_1_row_float32": (
+        32, 1, False, jnp.float32, 0, (), 5),
 }
 
 
@@ -611,10 +699,14 @@ def test_paged_body_matches_the_paged_reference(name):
     """A page of all heads a unit, live pages only: ragged frontiers (page
     0 only, a page's last row, the next page's first, the plane's end),
     shared prefix pages, frozen rows, both head dims, the three query
-    shapes, bf16 / float32 / int8. A frozen row's output is zeros."""
-    d, s, int8, dtype, shared, frozen = PAGED_CASES[name]
+    shapes, bf16 / float32 / int8. A frozen row's output is zeros. The
+    ``stored_*`` cases hand the kernels the arena as the pool keeps it
+    (2 heads of 64 or 4 of 32 a lane tile, 25 heads with their zero head)
+    and the reference the same arena, ungrouped by its own gather."""
+    d, s, int8, dtype, shared, frozen, *h = PAGED_CASES[name]
+    kw = dict(h=h[0], stored=True) if h else {}
     q, arenas, tbl, pos = _paged_operands(d, s, _frontiers(s), int8, dtype,
-                                          shared=shared, frozen=frozen)
+                                          shared=shared, frozen=frozen, **kw)
     got, want = _paged_kernel_and_reference(q, arenas, tbl, pos, layer=1)
     live = [r for r in range(len(pos)) if r not in frozen]
     # bf16 keeps 8 bits (outputs of order 1, probabilities rounded to it);

@@ -44,8 +44,10 @@ CASES = {"float32": (2e-4, 1e-5), "bfloat16": (0.1, 1e-2)}
 def tiny_config(dtype, **kw):
     # initializer_range 0.15: at hidden 64 the published 0.02 gives logits
     # of 0.03 and a router that cannot tell experts apart.
+    kw.setdefault("n_head", 4)
+    kw.setdefault("head_dim", 16)
     return DecoderConfig(
-        vocab_size=256, n_layer=2, n_head=4, head_dim=16, hidden_size=64,
+        vocab_size=256, n_layer=2, hidden_size=64,
         n_positions=128, n_experts=8, experts_per_token=2, expert_width=32,
         dtype=jnp.dtype(dtype), initializer_range=0.15, **kw)
 
@@ -215,14 +217,27 @@ def test_the_cache_free_forward_is_the_served_one():
         np.asarray(whole(adapter, params, ids)), rtol=0, atol=1e-5)
 
 
-def test_the_interpreted_paged_kernels_serve_the_block_too():
+@pytest.mark.parametrize("n_head, head_dim, stored", [
+    (4, 16, (1, 64)),      # the tiny block: its four heads are one stored head
+    (4, 64, (2, 128)),     # GPT-2's head dim: g = 2 heads a lane tile
+    (8, 32, (2, 128)),     # g = 4
+    (2, 128, (2, 128)),    # OLMoE's head dim fills a tile: nothing is packed
+    (5, 64, (3, 128)),     # g does not divide the heads: a zero head
+])
+def test_the_interpreted_paged_kernels_serve_the_block_too(n_head, head_dim,
+                                                           stored):
     """The cache side is GPT-2's (``generation.CacheAttention``): with pages
     of a kernel block (128) and the flag on, ``kv_append`` writes the arena
-    in place and the layer-indexed paged kernel reads it, as on the chip."""
-    adapter, params, ids, _, _ = built("float32")
-    model = DecoderLM(adapter.gcfg)
+    in place and the layer-indexed paged kernel reads it, as on the chip.
+    The arena is stored ``[L, P, H / g, page_len, g * D]`` (``lane_pack``
+    heads a lane tile), and the block's logits do not know."""
+    _, _, ids, _, _ = built("float32")
+    model = DecoderLM(tiny_config("float32", n_head=n_head,
+                                  head_dim=head_dim))
+    params = jax.jit(lambda k: model.init(k)["params"])(jax.random.PRNGKey(0))
     flash = DecoderAdapter.from_model(model, use_flash_decode=True)
     cache = paged_cache(flash, 2, page=128, max_len=128)
+    assert cache["k"].shape[2:] == (stored[0], 128, stored[1])
     logits, cache = flash.prefill_append(params, ids[:2, :12], cache)
     more, _ = flash.decode_step(params, ids[:2, 12], cache)
     want = np.asarray(model.apply({"params": params}, ids[:2, :13]))

@@ -37,6 +37,37 @@ from tests.unit.test_inference import (
 from tests.unit.test_telemetry import _parse_prom
 
 
+# A paged pool STORES its arena ``[L, P, H / g, page_len, g * D]``, ``g`` heads
+# a lane tile (``da.lane_pack``). The shared tiny model's four heads of 16 are
+# ONE stored head; these have several, and a zero head where g does not
+# divide: name -> (n_embd, n_head, the arena's (H / g, g * D)).
+STORED = {"4_heads_of_16": (64, 4, (1, 64)),
+          "4_heads_of_64": (256, 4, (2, 128)),
+          "5_heads_of_64": (320, 5, (3, 128)),
+          "8_heads_of_32": (256, 8, (2, 128))}
+_STORED_MODELS = {}
+
+
+def stored_model(name):
+    """(cfg, model, params, stored head dims) of ``STORED[name]``."""
+    import jax
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+
+    if name not in _STORED_MODELS:
+        n_embd, n_head, dims = STORED[name]
+        if n_head * 16 == n_embd:
+            cfg, model, params = make_model()
+        else:
+            cfg = GPT2Config(n_embd=n_embd, n_layer=2, n_head=n_head,
+                             vocab_size=1024, n_positions=128, dropout=0.0,
+                             use_flash_attention=False, dtype=jnp.float32)
+            model = GPT2LMHeadModel(cfg)
+            params = model.init(jax.random.PRNGKey(0),
+                                jnp.zeros((2, 12), jnp.int32))["params"]
+        _STORED_MODELS[name] = (cfg, model, params, dims)
+    return _STORED_MODELS[name]
+
+
 def paged_engine_of(model, params, **kw):
     kw.setdefault("paged_kv", True)
     kw.setdefault("kv_page_len", 8)
@@ -270,16 +301,29 @@ def test_paged_flash_engine_streams_equal_the_dense_pools():
     assert paged.kv_page_stats()["pages_in_use"] == 0
 
 
-def test_paged_flash_engine_with_tensor_sharded_heads(eight_devices):
+@pytest.mark.parametrize("n_embd, n_head, mp, sharded", [
+    (256, 8, 2, True),      # heads of 32: g 4, 2 packed heads over mp 2
+    (64, 4, 4, False),      # heads of 16: ONE packed head, nothing to split
+])
+def test_paged_flash_engine_with_tensor_sharded_heads(
+        eight_devices, n_embd, n_head, mp, sharded):
     """The same branch over a mesh with a 'model' axis: both kernels
     launch shard-local on the arenas' head shards (the append with every
     row on every shard: no shard may keep a row's write to itself), and
-    the streams equal the unsharded dense pool's."""
+    the streams equal the unsharded dense pool's. What 'model' splits is
+    the heads the arena STORES, ``H / g`` packed ones; a model whose heads
+    all share one lane tile keeps the arena whole on every shard."""
     import jax
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
     from deepspeed_tpu.parallel import mesh as mesh_lib
 
-    cfg, model, params = make_model(n_positions=512)   # 4 heads over mp=4
-    mesh = mesh_lib.build_mesh(devices=jax.devices()[:4], num_mp=4,
+    cfg = GPT2Config(n_embd=n_embd, n_layer=2, n_head=n_head,
+                     vocab_size=1024, n_positions=512, dropout=0.0,
+                     use_flash_attention=False, dtype=jnp.float32)
+    model = GPT2LMHeadModel(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((2, 12), jnp.int32))["params"]
+    mesh = mesh_lib.build_mesh(devices=jax.devices()[:mp], num_mp=mp,
                                num_dp=1)
     prompts = prompts_of(cfg, [5, 130, 60])
 
@@ -292,7 +336,11 @@ def test_paged_flash_engine_with_tensor_sharded_heads(eight_devices):
 
     _, want = serve()
     paged, got = serve(mesh=mesh, paged_kv=True, kv_page_len=128)
-    assert paged._pool["k"].sharding.spec[2] == mesh_lib.MODEL_AXIS
+    g = paged.metrics()["kv_lane_pack"]
+    assert paged._pool["k"].shape[2:] == (n_head // g, 128, 128 if sharded
+                                          else 64)
+    spec = paged._pool["k"].sharding.spec
+    assert (len(spec) > 2 and spec[2] == mesh_lib.MODEL_AXIS) == sharded
     assert got == want, "tensor-sharded paged + flash streams diverged"
 
 
@@ -319,12 +367,15 @@ def test_spec_decode_rollback_across_page_boundary():
             "spec rollback across a page boundary corrupted the stream"
 
 
-def test_paged_int8_prefix_offload_tiers_compose():
+@pytest.mark.parametrize("heads", ["4_heads_of_16", "4_heads_of_64",
+                                   "5_heads_of_64"])
+def test_paged_int8_prefix_offload_tiers_compose(heads):
     """All three hierarchy tiers over the paged pool: int8 arenas (q8
     paged kernel family), COW prefix sharing, live-page swap records.
     int8 is not bit-identical to fp by design — the pin is dense-int8
-    == paged-int8, stream for stream."""
-    cfg, model, params = make_model()
+    == paged-int8, stream for stream. On a packed arena too: codes g heads
+    a lane tile, scales a head of the model (with its zero head)."""
+    cfg, model, params, dims = stored_model(heads)
     shared = prompts_of(cfg, [12], seed=9)[0]
     tails = prompts_of(cfg, [4, 5, 6], seed=10)
     prompts = [np.concatenate([shared, t]).astype(np.int32) for t in tails]
@@ -342,19 +393,26 @@ def test_paged_int8_prefix_offload_tiers_compose():
 
     dense, want = serve()
     paged, got = serve(paged_kv=True, kv_page_len=8)
+    g = paged.metrics()["kv_lane_pack"]
+    assert paged._pool["k"].shape[2:] == (dims[0], 8, dims[1])
+    assert paged._pool["k_scale"].shape[2:] == (dims[0] * g, 8)
     assert got == want, "paged int8+prefix+offload diverged from dense"
     assert paged.compile_count == dense.compile_count == 1
     assert paged.metrics()["prefix_hits"] == dense.metrics()["prefix_hits"]
 
 
-def test_cow_prefix_fork_divergence():
+@pytest.mark.parametrize("heads", ["4_heads_of_16", "4_heads_of_64",
+                                   "8_heads_of_32"])
+def test_cow_prefix_fork_divergence(heads):
     """TWO aliasers of one shared prefix admitted in the same round,
     then decoding divergent tails: full pages stay shared (one physical
     copy), each straddle page goes copy-on-write, and neither stream
     sees the other's writes. This exact two-wave shape caught a real
     bug (a stale device write cursor clobbering the shared page through
-    a fresh block table), so it is pinned bit-for-bit against dense."""
-    cfg, model, params = make_model()
+    a fresh block table), so it is pinned bit-for-bit against dense, on
+    packed arenas (g = 4 and 2 heads a lane tile) as well: a page copied
+    on write carries every head of its tile."""
+    cfg, model, params, dims = stored_model(heads)
     shared = prompts_of(cfg, [13], seed=17)[0]
     tails = prompts_of(cfg, [3, 6], seed=18)
     prompts = [np.concatenate([shared, t]).astype(np.int32) for t in tails]
@@ -373,6 +431,7 @@ def test_cow_prefix_fork_divergence():
 
     dense, want, dm = serve()
     paged, got, pm = serve(paged_kv=True, kv_page_len=4)
+    assert paged._pool["k"].shape[2:] == (dims[0], 4, dims[1])
     assert got == want, "COW fork diverged from dense"
     assert pm["prefix_hits"] == dm["prefix_hits"] >= 2
     assert pm["prefix_inserts"] == dm["prefix_inserts"]
@@ -381,6 +440,67 @@ def test_cow_prefix_fork_divergence():
     # row still legitimately pins pages (until eviction/reset).
     st = paged.kv_page_stats()
     assert 0 < st["pages_in_use"] < st["pages_total"]
+
+
+@pytest.mark.parametrize("heads", ["4_heads_of_64", "5_heads_of_64"])
+def test_packed_pool_page_stacks_and_prefix_records_round_trip(heads):
+    """What leaves a packed pool and comes back: a slot's page stack
+    (``capture_slot_paged`` / ``restore_slot_paged``: swap, preempt,
+    handoff) carries the arena's own trailing dims, so it restores bit for
+    bit into other pages; a prefix exported for another replica is the
+    DENSE record ``[L, H, span, D]`` a dense engine exports for the same
+    tokens, bit for bit, and a paged engine that adopts it serves the
+    next request of that prefix as the donor would."""
+    from deepspeed_tpu.inference.kv_hierarchy import offload
+
+    cfg, model, params, dims = stored_model(heads)
+    shared = prompts_of(cfg, [12], seed=9)[0]
+    tail = prompts_of(cfg, [5], seed=10)[0]
+    prompt = np.concatenate([shared, tail]).astype(np.int32)
+
+    def engine(**extra):
+        return engine_of(model, params, max_slots=2, prefill_chunk=8,
+                         prefix_cache=True, prefix_slots=2, min_prefix_len=4,
+                         **extra)
+
+    donor, dense = engine(paged_kv=True, kv_page_len=8), engine()
+    for eng in (donor, dense):
+        eng.submit(shared.astype(np.int32), max_new_tokens=3)
+        eng.run()
+    toks = [int(t) for t in shared]
+    (matched, record), (d_matched, d_record) = (
+        eng.export_prefix(toks) for eng in (donor, dense))
+    assert matched == d_matched and sorted(record) == sorted(d_record)
+    for name in record:
+        assert record[name].shape == d_record[name].shape
+        np.testing.assert_array_equal(record[name], d_record[name])
+    assert record["pk"].shape[1] == STORED[heads][1]      # every real head
+
+    acceptor = engine(paged_kv=True, kv_page_len=8)
+    assert acceptor.adopt_prefix(matched, record)
+    want = donor.submit(prompt, max_new_tokens=6)
+    donor.run()
+    got = acceptor.submit(prompt, max_new_tokens=6)
+    acceptor.run()
+    assert got.tokens == want.tokens
+    assert acceptor.metrics()["prefix_hits"] == 1
+
+    # A live slot's page stack, captured and restored into fresh pages.
+    live = donor.submit(prompt, max_new_tokens=40)
+    donor.step()
+    donor.step()
+    pager, pool = donor._pager, donor._pool
+    slot = next(s for s in range(2) if pager.row_pages(s))
+    pages = pager.row_pages(slot)
+    rec = offload.capture_slot_paged(pool, slot, pages)
+    assert rec["k"].shape[1:] == (len(pages), dims[0], 8, dims[1])
+    fresh = pager.alloc_pages(len(pages))
+    assert fresh and not set(fresh) & set(pages)
+    restored = offload.restore_slot_paged(pool, 1 - slot, rec, fresh)
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(
+            np.asarray(restored[name])[:, np.asarray(fresh)], rec[name])
+    assert not live.done
 
 
 # --------------------------------------------------------- capacity pin
@@ -502,6 +622,11 @@ def test_prometheus_exports_page_gauge_family():
     assert kinds["ds_tpu_kv_live_page_share"] == "gauge"
     assert live > 0 and sample("ds_tpu_kv_live_page_share") == \
         pytest.approx(live / pg.table.size)
+    # Heads a lane tile in the stored arena: the tiny model's four heads of
+    # 16 are one stored head of 64 lanes. Read back from the arena's shape.
+    assert kinds["ds_tpu_kv_lane_pack"] == "gauge"
+    assert sample("ds_tpu_kv_lane_pack") == eng.metrics()["kv_lane_pack"] \
+        == 4 == eng._pool["k"].shape[-1] // 16
     eng.run()
     _, drained = _parse_prom(eng.prometheus())
     assert [v for (n, _), v in drained.items()
