@@ -281,9 +281,12 @@ def run(run):
               and f.finish <= t_end and len(f.handle.tokens) == f.max_new]
         attempted, failed = len(due), len(due) - len(ok)
         ttft = [(f.first - f.due) * 1e3 for f in due if f.first is not None]
+        timed = [f for f in finished
+                 if f.first >= t0 and len(f.handle.tokens) > 1]
+        # A request whose tokens all reached the host in one step (outputs
+        # up to about chunk_size) has no gap to measure: counted, not 0.
         tpot = [(f.finish - f.first) / (len(f.handle.tokens) - 1) * 1e3
-                for f in finished
-                if f.first >= t0 and len(f.handle.tokens) > 1]
+                for f in timed if f.finish > f.first]
         late = [(f.submitted - f.due) * 1e3 for f in in_window]
         if ttft:
             values["serve_ttft_p50_ms"] = float(np.median(ttft))
@@ -293,6 +296,7 @@ def run(run):
             values["gen_late_p50_ms"] = float(np.median(late))
             values["gen_late_max_ms"] = float(np.max(late))
         counters.update(ttft_samples=len(ttft), tpot_samples=len(tpot),
+                        tpot_one_step=len(timed) - len(tpot),
                         offered=len(in_window))
     values["engine_step_ms"] = float(np.median(
         [(s[1] - s[0]) * 1e3 for s in steps]))

@@ -32,13 +32,18 @@ def test_traced_run_reports_per_layer_metrics_and_a_breakdown(
     tiny.check_traced(tiny.manifest(), standin)
 
 
+def _note(capsys, event):
+    """The run's last ``{"event": event, ...}`` line."""
+    return [json.loads(line) for line in capsys.readouterr().out.splitlines()
+            if '"event": "{}"'.format(event) in line][-1]
+
+
 @pytest.mark.parametrize("standin", tiny.cases("sharded"))
 def test_zero_cell_checks_that_the_moments_are_sharded(standin, capsys):
     result = tiny.run(tiny.manifest(), standin["cell"], trace=0)
     assert result["correct"] is True
     assert result["device"]["count"] >= standin["chips"] > 1
-    closed = [json.loads(line) for line in capsys.readouterr().out.splitlines()
-              if '"window_closed"' in line][-1]
+    closed = _note(capsys, "window_closed")
     assert closed["checks"]["moments_sharded"] is True
     assert closed["checks"]["moment_leaves_whole"] == 0
     assert closed["compiles_in_window"] == 0
@@ -73,6 +78,37 @@ def test_a_fifth_cell_is_only_new_files_and_entries(cpu_peaks):
     assert set(traced["metrics"]) == {"steps_counted"}
     assert traced["metrics"]["steps_counted"] == {
         "value": float(traced["attempted"]), "unit": "steps"}
+
+
+@pytest.mark.parametrize("traffic,some_gaps", [("tiny-open", True),
+                                               ("tiny-open-onestep", False)])
+def test_a_request_delivered_in_one_step_is_counted_not_read_as_zero(
+        traffic, some_gaps, capsys):
+    """``serve_tpot_p50_ms`` is over requests with a gap to measure: one
+    whose tokens all reached the host in one step (an output no longer than
+    about ``chunk_size``) is counted in ``tpot_one_step`` and left out of
+    the median. Where every request is such a one, no median is reported:
+    never a 0."""
+    manifest = tiny.manifest()
+    manifest["workloads"].append({
+        "name": "serve-tiny-gaps", "config": "gpt2-tiny", "traffic": traffic,
+        "chips": 1, "why": "tests"})
+    for metric in manifest["end_to_end"]:
+        if metric["name"] == "serve_tpot_p50_ms":
+            metric["workloads"].append("serve-tiny-gaps")
+    result = tiny.run(manifest, "serve-tiny-gaps", trace=0, seconds=2.0)
+    window = _note(capsys, "window")
+    assert result["correct"] is True and window["finished"] > 0
+    assert window["tpot_one_step"] >= 1
+    assert window["tpot_samples"] + window["tpot_one_step"] <= \
+        window["finished"]
+    if some_gaps:
+        assert window["tpot_samples"] >= 1
+        assert result["metrics"]["serve_tpot_p50_ms"]["value"] > 0
+    else:
+        assert window["tpot_samples"] == 0
+        assert window["tpot_one_step"] == window["finished"]
+        assert "serve_tpot_p50_ms" not in result["metrics"]
 
 
 def test_unknown_cell_is_refused():

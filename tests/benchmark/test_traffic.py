@@ -97,6 +97,11 @@ def test_a_fixed_schedule_is_replayed_with_seeded_jitter_and_new_tokens():
     assert not np.array_equal(reqs_a[0][0], reqs_b[0][0])
 
 
+def _mix(name):
+    return harness.load_json(os.path.join(
+        harness.ROOT, "benchmark/workloads", name + ".json"))
+
+
 def _digest(*arrays):
     h = hashlib.sha256()
     for a in arrays:
@@ -116,37 +121,56 @@ def test_the_four_mixes_draw_what_they_drew_before_sources_had_names(seed):
     for the benchmark's four mixes, called as the drivers call it, at the
     commit before token sources and arrival processes were found by name
     (PR 26): ``uniform`` and ``poisson`` are the same draws, so no cell's
-    inputs moved."""
+    inputs moved. ``chat-loaded`` took ``chat-open``'s place in PR 32 (a
+    rate some 35 times higher, a fresh schedule): its three streams were
+    recorded then."""
     want = harness.load_json(os.path.join(
         harness.ROOT, "tests/benchmark/traffic_recorded.json"))[str(seed)]
     vocab = 50257
-
-    def mix(name):
-        return harness.load_json(os.path.join(
-            harness.ROOT, "benchmark/workloads", name + ".json"))
-
-    closed = mix("decode-closed")
+    closed = _mix("decode-closed")
     reqs = traffic.requests(seed, 1, closed["request_pool"], closed, vocab)
     assert _requests_digest(reqs) == want["decode-closed"]["requests"]
     assert reqs[0][0][:4].tolist() == \
         want["decode-closed"]["first_prompt_head"]
-    chat = mix("chat-open")
+    chat = _mix("chat-loaded")
     for stream, seconds in ((0, chat["warmup_s"]), (1, 51),
                             (2, chat["trace_tail_s"])):
         due = traffic.arrivals(seed, stream, seconds, chat)
-        recorded = want["chat-open"][str(stream)]
+        recorded = want["chat-loaded"][str(stream)]
         assert len(due) == recorded["n"] and due[0] == recorded["first_due"]
         assert _digest(np.asarray(due, np.float64)) == recorded["arrivals"]
         assert _requests_digest(traffic.requests(
             seed, stream, len(due), chat, vocab)) == recorded["requests"]
     for name, chips in (("pretrain-t1024-b16", 1),
                         ("pretrain-t1024-b4-dp4", 4)):
-        train = mix(name)
+        train = _mix(name)
         batches = traffic.token_batches(
             seed, 2, train["batch_per_chip"] * chips, train["seq_len"],
             vocab, train)
         assert _digest(batches) == want[name]["batches"]
         assert batches[0, 0, :4].tolist() == want[name]["head"]
+
+
+@pytest.mark.parametrize("stream,at_least", [(1, 300), (2, 16)])
+def test_the_loaded_chat_mix_fills_its_window_alike_for_every_seed(
+        stream, at_least):
+    """The open cell's median is over hundreds of requests (17 made it
+    noise, PR 32), its traced tail holds enough for the request readers,
+    and every seed is offered the same multiset of lengths."""
+    chat = _mix("chat-loaded")
+    seconds = {1: 51, 2: chat["trace_tail_s"]}[stream]
+    drawn = []
+    for seed in (0, 5, 2147483999):
+        due = traffic.arrivals(seed, stream, seconds, chat)
+        reqs = traffic.requests(seed, stream, len(due), chat, 50257)
+        assert np.all(np.diff(due) >= 0) and due[0] >= 0
+        drawn.append((len(due), sorted(len(p) for p, _ in reqs),
+                      sorted(o for _, o in reqs), reqs[0][0][:8].tolist()))
+    assert drawn[0][0] >= at_least
+    assert drawn[0][:3] == drawn[1][:3] == drawn[2][:3]
+    assert drawn[0][3] != drawn[1][3]
+    # the replay's jitter stays under one mean gap
+    assert chat["arrival_jitter_s"] < 1.0 / chat["rate"]
 
 
 def test_an_unknown_source_is_refused():
