@@ -15,9 +15,23 @@ stamps ``use_flash_decode`` and ``kv_page_len`` into the static config.
 Routing is exact top-k with no capacity, so nothing is ever dropped and a
 row's logits depend on that row alone (the protocol's replay invariant).
 Per-expert routed counts ride the pool's ``aux_`` channel and ``observe``
-publishes ``moe_expert_load{expert=i}`` and ``moe_tokens_routed``. They
-count every row the program computes (idle slots decode garbage by design),
-so they read as the program's load, not as requests' tokens.
+publishes ``moe_experts_held``, ``moe_expert_load{expert=i}`` over the held
+experts, ``moe_tokens_routed`` and, for a chip's share of an expert-parallel
+layer, ``moe_tokens_absent`` (choices that fell on experts held elsewhere).
+They count every row the program computes (idle slots decode garbage by
+design), so they read as the program's load, not as requests' tokens.
+
+A HYBRID stack (``layer_types`` with ``mamba`` layers) carries a recurrent
+state a slot: ``cache_spec().slot_state`` names it, the pool allocates and
+threads it (``kv_pool.py``, A RECURRENT STATE A SLOT) and ``observe``
+publishes its size as ``ssm_state_bytes``. What the stale-cache rule gave
+the engine for free does not exist for it, so ``bind`` REFUSES, by the name
+of the mechanism, what would need a snapshot of the state: speculative
+decoding (a rejected draft has already moved the state), the prefix cache
+(a shared prefix needs the state at its end) and int8 planes (the state has
+no int8 form, and a pool quantised in part is not built). Host offload,
+preemption and handoff ship the state with the slot and resume it bit for
+bit.
 """
 
 import dataclasses
@@ -27,6 +41,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.analysis.annotations import hot_path
 from deepspeed_tpu.inference.adapters.gpt2 import GPT2Adapter
+from deepspeed_tpu.inference.kv_pool import slot_state_nbytes
 from deepspeed_tpu.models import decoder
 
 
@@ -41,18 +56,54 @@ class DecoderAdapter(GPT2Adapter):
         return cls(decoder.served_config(getattr(model, "config", model),
                                          use_flash_decode))
 
+    def cache_spec(self):
+        return decoder.cache_spec(self.gcfg)
+
+    @property
+    def recurrent(self):
+        """Does a row carry a state that has no position axis?"""
+        return bool(self.gcfg.mamba_layers)
+
+    def bind(self, config, mesh=None):
+        if config is not None and self.recurrent:
+            refused = (
+                ("speculative decoding (spec_decode)",
+                 config.resolved_spec_decode(),
+                 "a verify moves the recurrent state past a draft it may "
+                 "reject, and rollback needs a snapshot of the state a "
+                 "draft"),
+                ("the prefix cache (prefix_cache)", config.prefix_cache,
+                 "a shared prefix needs the recurrent state at its end, "
+                 "which no tier stores"),
+                ("int8 planes (int8_kv)", config.int8_kv,
+                 "the recurrent state has no int8 form and a pool "
+                 "quantised in part is not built"))
+            for what, asked, why in refused:
+                if asked:
+                    raise ValueError(
+                        "{} cannot serve a model with a recurrent state a "
+                        "slot ({} Mamba layers): {}".format(
+                            what, len(self.gcfg.mamba_layers), why))
+        return super().bind(config, mesh)
+
     def init_cache(self, batch, max_len, dtype=None):
-        return dict(super().init_cache(batch, max_len, dtype),
+        return dict(decoder.init_cache(self.gcfg, batch, max_len),
                     **self.aux_state())
 
     def aux_state(self):
-        return {"aux_moe_load": jnp.zeros((self.gcfg.n_experts,),
-                                          jnp.float32),
-                "aux_moe_routed": jnp.zeros((), jnp.float32)}
+        aux = {"aux_moe_load": jnp.zeros((self.gcfg.held[1],), jnp.float32),
+               "aux_moe_routed": jnp.zeros((), jnp.float32)}
+        if self.gcfg.experts_held is not None:
+            aux["aux_moe_absent"] = jnp.zeros((), jnp.float32)
+        return aux
 
     @hot_path
     def prefill_append(self, params, ids, cache, n_valid=None):
         pos0 = cache["pos"]
+        if n_valid is not None:
+            # pad columns must not move a recurrent state (keys hide them
+            # past the frontier; a state has no frontier)
+            cache = dict(cache, n_valid=n_valid)
         logits, cache = decoder.forward(params, self.gcfg, ids, cache,
                                         attn_name="prefill_attn")
         if n_valid is not None:
@@ -67,6 +118,10 @@ class DecoderAdapter(GPT2Adapter):
 
     @hot_path
     def verify_forward(self, params, ids, cache):
+        if self.recurrent:
+            raise NotImplementedError(
+                "verify_forward: scoring a draft moves the recurrent state, "
+                "and not advancing pos does not move it back")
         pos0 = cache["pos"]
         logits, cache = decoder.forward(params, self.gcfg, ids, cache)
         return logits, dict(cache, pos=pos0)
@@ -75,7 +130,16 @@ class DecoderAdapter(GPT2Adapter):
         load = snap.get("aux_moe_load")
         if load is None:
             return
+        first, held = self.gcfg.held
+        registry.gauge("moe_experts_held").set(held)
         for i, v in enumerate(load):
-            registry.gauge("moe_expert_load", expert=str(i)).set(float(v))
+            registry.gauge("moe_expert_load",
+                           expert=str(first + i)).set(float(v))
         registry.gauge("moe_tokens_routed").set(
             float(snap.get("aux_moe_routed", 0.0)))
+        if "aux_moe_absent" in snap:
+            registry.gauge("moe_tokens_absent").set(
+                float(snap["aux_moe_absent"]))
+        if self.recurrent:
+            registry.gauge("ssm_state_bytes").set(
+                len(snap["pos"]) * slot_state_nbytes(self.cache_spec()))

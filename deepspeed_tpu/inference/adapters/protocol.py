@@ -19,14 +19,38 @@ conformance kit every adapter must pass):
 - The cache is a dict of arrays with per-row frontier ``pos`` [B]; k/v
   planes are [layers, B, heads, plane_len, head_dim] so the KV pool,
   hierarchy (int8 / prefix tiers, host offload) and handoff machinery
-  compose unchanged. Extra model state MUST use ``aux_``-prefixed keys:
-  the pool threads them through every program, the hierarchy's
-  capture/restore skips them (they are not per-slot), and
+  compose unchanged. ``layers`` and ``heads`` are what the CACHE holds
+  (``cache_spec()``): the layers that hold keys and the heads a token
+  stores, which a hybrid or grouped-query model has fewer of than it has
+  layers and query heads. GLOBAL extra state (accumulators) MUST use
+  ``aux_``-prefixed keys: the pool threads them through every program,
+  the hierarchy's capture/restore skips them (they are not per-slot), and
   ``harvest_snapshot`` fetches them for ``observe``.
+- A model may also carry a RECURRENT STATE A ROW, with no position axis
+  (a state-space layer's state and its convolution's tail). It IS
+  per-slot: ``cache_spec().slot_state`` names it (``slot_``-prefixed
+  keys, ``(key, shape a row, dtype)``), the pool allocates it slot-major
+  and threads it through every program, ``slot_cache_view`` /
+  ``write_slot_cache`` carry one slot's slice through the prefill lane,
+  and the hierarchy's capture/restore and the handoff ship it with the
+  slot's scalars, so a preempted or migrated session resumes its own
+  state. The engine hands the model ``cache['n_valid']`` [B] beside it:
+  how many leading columns of each row are real, 0 for a row that must
+  not move (an idle slot, a slot still in the lane while the scan runs).
 - Positions past a row's frontier may hold garbage that is masked or
   overwritten before the frontier reaches them (the stale-cache rule) —
   this is what makes speculative rollback "don't advance pos" and chunked
-  prefill's pad columns free.
+  prefill's pad columns free. THE RULE HOLDS FOR KEY/VALUE PLANES ONLY.
+  A recurrent state has no frontier to hide garbage behind, so for a
+  model that carries one: a pad column and a row that is not decoding
+  must leave the state untouched (the model masks by ``n_valid``; an
+  idle slot may still decode garbage LOGITS, never garbage state); a row
+  whose frontier is 0 starts from a zero state, whatever its slot held
+  (slot reuse needs no reset from the host); and rollback is NOT "do not
+  advance pos", so what rests on that (``verify_forward``, and with it
+  speculative decoding; prefixes aliased below ``pbase``) is refused by
+  such an adapter's ``bind`` with an error that names the mechanism,
+  never served wrong.
 - Per-row INDEPENDENCE: row b's logits depend only on row b's tokens and
   frontier. This is what the fleet's crash-replay bit-identity invariant
   (RESILIENCE.md) rests on — replayed requests land in different slots
@@ -55,9 +79,11 @@ class ModelAdapter:
         """Hashable shape/dtype spec of the KV cache: an object with
         ``n_layer / n_head / n_embd / n_positions / dtype /
         layer_norm_epsilon / use_flash_decode`` attributes (the
-        ``_GenCfg`` shape the KV pool and mesh sharding helpers key on).
-        Must be stable for the adapter's lifetime — it is part of the
-        jit static key."""
+        ``_GenCfg`` shape the KV pool and mesh sharding helpers key on:
+        the layers that hold keys, the heads a token stores and their
+        width together) and, for a model with a recurrent state a row,
+        ``slot_state`` (module docstring). Must be stable for the
+        adapter's lifetime — it is part of the jit static key."""
         raise NotImplementedError
 
     def init_cache(self, batch, max_len, dtype=None):
@@ -68,7 +94,8 @@ class ModelAdapter:
     def prefill_append(self, params, ids, cache, n_valid=None):
         """Append ``ids`` [B, S] at each row's frontier (chunked-prefill
         primitive). ``n_valid`` [B] marks leading real columns; the
-        frontier advances by ``n_valid`` (default S). Returns
+        frontier advances by ``n_valid`` (default S), and a recurrent
+        state by exactly those columns. Returns
         (fp32 logits [B, S, V], advanced cache)."""
         raise NotImplementedError
 
@@ -80,7 +107,9 @@ class ModelAdapter:
     def verify_forward(self, params, ids, cache):
         """Score ``ids`` [B, S] at each row's frontier WITHOUT advancing
         it (speculative verify; rollback = not moving ``pos``). Returns
-        (fp32 logits [B, S, V], cache with pos unchanged)."""
+        (fp32 logits [B, S, V], cache with pos unchanged). An adapter
+        whose rows carry a recurrent state raises: scoring a draft moves
+        that state, and its ``bind`` refuses speculation."""
         raise NotImplementedError
 
     # ------------------------------------------------------------------

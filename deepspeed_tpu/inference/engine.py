@@ -710,7 +710,9 @@ class InferenceEngine(object):
         self.telemetry.gauge("kv_hbm_bytes").set_fn(
             lambda: pool_nbytes(self._pool))
         if self._xray is not None:
-            # HBM ledger: predicted (params + KV arena + largest
+            # HBM ledger: predicted (params + KV arena, which counts a
+            # model's recurrent state a slot: pool_nbytes sums every leaf
+            # of the pool + largest
             # program temp) vs live device.memory_stats() where the
             # backend has it. program_temp reads 0 until the first
             # xray export materializes — a scrape must never compile.

@@ -26,6 +26,7 @@ import dataclasses
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.kv_hierarchy.offload import HostSwapStore
+from deepspeed_tpu.inference.kv_pool import slot_state_nbytes
 from deepspeed_tpu.inference.kv_hierarchy.prefix_cache import PrefixStore
 
 
@@ -99,6 +100,9 @@ class KVHierarchy(object):
             hd * kv_itemsize * 2 + (8 if spec.int8 else 0))
         self._flat_per_pos_bytes = (gcfg.n_layer * gcfg.n_head
                                     * hd * self._fp_itemsize * 2)
+        # A recurrent state a slot (``cache_spec().slot_state``): a fixed
+        # size whatever the context, never quantised, swapped with the slot.
+        self._slot_state_bytes = slot_state_nbytes(gcfg)
 
         self.store = PrefixStore(spec.prefix_slots) if spec.prefix else None
         if self.store is not None and pager is not None:
@@ -293,10 +297,11 @@ class KVHierarchy(object):
     # ------------------------------------------------- byte accounting
 
     def bytes_per_slot(self):
-        return self._per_pos_bytes * self.plane_len
+        return self._per_pos_bytes * self.plane_len + self._slot_state_bytes
 
     def flat_bytes_per_slot(self):
-        return self._flat_per_pos_bytes * self.plane_len
+        return self._flat_per_pos_bytes * self.plane_len \
+            + self._slot_state_bytes
 
     def prefix_store_bytes(self):
         if self.store is None:

@@ -1,7 +1,9 @@
 """Host offload: fixed-shape slot capture/restore + the host swap store.
 
 A swap-out captures ONE slot's entire device footprint — KV plane slices,
-int8 scale slices when present, the token ring row, every per-slot
+int8 scale slices when present, the token ring row, a model's recurrent
+state a slot (``slot_*`` keys, slot-major like the scalars: a fixed-size
+slice whatever the context, ``kv_pool.py``), every per-slot
 scalar (including the prefix attachment fields), with ``active`` captured
 *before* the engine deactivates the slot so restore reactivates it — in a
 single batched ``jax.device_get``. Every captured array has a shape fixed

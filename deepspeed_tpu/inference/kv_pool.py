@@ -112,6 +112,25 @@ buffer it returns. An append rewrites the frontier page of each row,
 which is sound because a live frontier page belongs to one row; frozen
 rows all point at the trash page 0, which nothing reads.
 
+A RECURRENT STATE A SLOT. A model whose ``cache_spec()`` names
+``slot_state`` (``((key, shape a row, dtype), ...)``, keys ``slot_*``: the
+decoder block's Mamba-2 layers give a ``slot_ssm<j>`` [slots, N, H * P]
+float32 and a ``slot_conv<j>`` [slots, K - 1, conv width] a layer)
+holds, beside the k and v planes of the layers that DO hold keys (the planes
+are as deep as those layers only), state with NO position axis: a fixed size
+a slot whatever its context, slot-major so that a slot's share is one
+contiguous slice. It IS per-slot state, unlike an adapter's ``aux_`` keys:
+``cache_view`` hands it to the decode step whole with ``n_valid`` (1 for a
+slot that decodes, 0 for one that must not move: a free slot, a slot still
+in the prefill lane), ``slot_cache_view`` / ``write_slot_cache`` carry one
+slot's slice through the lane, and the hierarchy's capture and restore ship
+it with the slot's scalars (``arr[slot]``, like ``pos``). Nothing of the
+stale-cache rule applies to it: there is no position past the frontier to
+hide garbage in, so every program that touches it masks instead
+(``models/mamba2.py``), and what the rule gave for free (rollback by not
+advancing ``pos``, aliased prefixes) is refused for such a model
+(``adapters/decoder.py``).
+
 CRASH-ONLY: the pool is DISPOSABLE state (docs/RESILIENCE.md). The
 durable truth about every request lives host-side in the scheduler's
 records; on a fatal step error the engine throws the pool away and
@@ -224,7 +243,7 @@ def init_pool(gcfg, num_slots, max_len, dtype=None, slack=0, hier=None,
             pool["v_scale"] = jnp.zeros(sc_shape, jnp.float32)
         for name, ft, fill in _SLOT_FIELDS:
             pool[name] = jnp.full((num_slots,), fill, ft)
-        return pool
+        return _with_slot_state(pool, gcfg, num_slots)
     plane_len = plane_len_for(gcfg, max_len, slack)
     if getattr(gcfg, "use_flash_decode", False):
         assert decode_attention.decode_supported(plane_len), plane_len
@@ -251,7 +270,28 @@ def init_pool(gcfg, num_slots, max_len, dtype=None, slack=0, hier=None,
         pool["pbase"] = jnp.zeros((num_slots,), jnp.int32)
     for name, ft, fill in _SLOT_FIELDS:
         pool[name] = jnp.full((num_slots,), fill, ft)
+    return _with_slot_state(pool, gcfg, num_slots)
+
+
+def _with_slot_state(pool, gcfg, num_slots):
+    """``pool`` with the recurrent state ``gcfg.slot_state`` names, zeroed
+    (module docstring, A RECURRENT STATE A SLOT)."""
+    for name, shape, dtype in getattr(gcfg, "slot_state", ()):
+        assert name.startswith("slot_"), name
+        pool[name] = jnp.zeros((num_slots,) + tuple(shape), dtype)
     return pool
+
+
+def _slot_state(tree):
+    return [name for name in tree if name.startswith("slot_")]
+
+
+def slot_state_nbytes(gcfg):
+    """Bytes of recurrent state ONE slot holds under ``gcfg`` (0 for a model
+    whose rows carry keys only): a fixed size whatever the context."""
+    import numpy as np
+    return sum(int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+               for _, shape, dtype in getattr(gcfg, "slot_state", ()))
 
 
 def harvest_snapshot(pool):
@@ -286,8 +326,10 @@ def max_active_frontier(pool, snap=None):
 
 
 def pool_nbytes(pool):
-    """Total device bytes held by the pool (k/v planes dominate; the
-    per-slot scalars and the token ring are noise). The telemetry
+    """Total device bytes held by the pool: the k/v planes, a model's
+    recurrent state a slot (``slot_*``: most of the pool where nine layers
+    in ten hold no keys), the per-slot scalars and the token ring (noise).
+    The telemetry
     ``kv_pool_bytes`` gauge reads this — it is a static fact of the
     compiled shapes, so one number describes the whole run."""
     return int(sum(getattr(leaf, "nbytes", 0)
@@ -332,7 +374,17 @@ def cache_view(pool):
         # through whole — the forward reads and re-emits it.
         if name.startswith("aux_"):
             cache[name] = pool[name]
+    for name in _slot_state(pool):
+        # Recurrent state passes whole too (the slots ARE the rows), with
+        # the rows that may move it: only a slot that decodes.
+        cache[name] = pool[name]
+        cache["n_valid"] = pool["active"].astype(jnp.int32)
     return cache
+
+
+def _slot_state_view(pool, slot):
+    return {name: jax.lax.dynamic_slice_in_dim(pool[name], slot, 1, axis=0)
+            for name in _slot_state(pool)}
 
 
 @hot_path
@@ -355,7 +407,7 @@ def slot_cache_view(pool, slot, pos):
         for name in pool:
             if name.startswith("aux_"):
                 cache[name] = pool[name]
-        return cache
+        return dict(cache, **_slot_state_view(pool, slot))
     cache = {"k": jax.lax.dynamic_slice_in_dim(pool["k"], slot, 1, axis=1),
              "v": jax.lax.dynamic_slice_in_dim(pool["v"], slot, 1, axis=1),
              "pos": pos}
@@ -381,7 +433,7 @@ def slot_cache_view(pool, slot, pos):
         # whole, same as cache_view.
         if name.startswith("aux_"):
             cache[name] = pool[name]
-    return cache
+    return dict(cache, **_slot_state_view(pool, slot))
 
 
 @hot_path
@@ -406,7 +458,7 @@ def write_slot_cache(pool, slot, cache):
         for name in cache:
             if name.startswith("aux_"):
                 pool[name] = cache[name]
-        return pool
+        return _write_slot_state(pool, slot, cache)
     pool = dict(pool)
     for name in ("k", "v", "k_scale", "v_scale"):
         if name in pool:
@@ -416,6 +468,14 @@ def write_slot_cache(pool, slot, cache):
         # Global aux accumulators fold back whole (no slot indexing).
         if name.startswith("aux_"):
             pool[name] = cache[name]
+    return _write_slot_state(pool, slot, cache)
+
+
+def _write_slot_state(pool, slot, cache):
+    """The one slot's recurrent state back into its slice of the pool."""
+    for name in _slot_state(pool):
+        pool[name] = jax.lax.dynamic_update_slice_in_dim(
+            pool[name], cache[name], slot, axis=0)
     return pool
 
 
@@ -430,7 +490,7 @@ def fold_cache(pool, cache):
         upd["k_scale"] = cache["k_scale"]
         upd["v_scale"] = cache["v_scale"]
     for name in cache:
-        if name.startswith("aux_"):
+        if name.startswith(("aux_", "slot_")):
             upd[name] = cache[name]
     return dict(pool, **upd)
 

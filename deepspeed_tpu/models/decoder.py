@@ -7,6 +7,20 @@ that is its own matrix unless the configuration ties it. No bias anywhere.
 OLMoE-1B-7B (``model_type`` ``olmoe``) is this block at 16 layers, hidden
 2048, 16 heads of 128, 64 experts of width 1024, 8 a token.
 
+The same block with its optional pieces, each a field of the configuration
+that leaves a model without it lowering as before, is a HYBRID stack
+(Granite 4.0-H Small, ``model_type`` ``granitemoehybrid``): a static kind a
+layer (``layer_types``: ``attention`` or ``mamba``, the Mamba-2 mixer of
+``models/mamba2.py`` with a recurrent state a row in place of keys), fewer
+stored key/value heads than query heads (``n_kv_head``: query head ``j``
+reads stored head ``j // (n_head / n_kv_head)``), no rotary (``rope``
+False), a softmax scale of the configuration's own (``attn_scale``), the
+chip's share of the routed experts (``experts_held``: the router stays
+``n_experts`` wide, ``moe/routed.py``), a shared expert (``shared_width``)
+and Granite's multipliers (on the embedding, on every residual branch, under
+the logits). One path, not two: every piece is one static branch inside
+``forward``, so what OLMoE runs is what it ran.
+
 Like ``models/generation.py`` for GPT-2 this is a pure-functional program over
 a parameter tree, one ``forward`` for prefill, chunked prefill, decode and
 verify: rows sit at their own frontiers ``cache['pos']``, rotary angles come
@@ -24,11 +38,17 @@ leading axis, dense kernels ``[in, out]``::
     layers/ffn_norm [L, C]       layers/router [L, C, E]
     layers/w_gate_up [L, E, C, 2F]  (gate | up)       layers/w_down [L, E, F, C]
 
+A hybrid stack (``layer_types`` given) keeps under ``layers`` what EVERY
+layer has (the two norms, the router, the held experts ``[L, E_held, ..]``,
+``shared_gate_up [L, C, 2Fs]`` / ``shared_down [L, Fs, C]``) and stacks each
+kind of mixer over the layers of that kind: ``attn/wqkv [La, C, (H + 2 Hkv) D]``
+(+ the QK norms), ``attn/wo``; ``mamba/...`` [Lm, ..] (``mamba2.init_layer``).
+
 The regions of a trace (``jax.named_scope``, under the caller's
 ``prefill_lane`` / ``decode_scan``): ``embed``; per layer ``attn`` (norm, qkv,
 ``rope``, ``qk_norm``, attention, projection), ``kv_write``, ``kv_view``,
-``moe`` holding ``router``, ``dispatch``, ``experts``, ``combine``; then
-``lm_head``.
+or ``mamba`` (``mamba2.mixer``'s words); ``moe`` holding ``router``,
+``dispatch``, ``experts``, ``combine`` and ``shared``; then ``lm_head``.
 
 The layers are unrolled (a static ``layer=`` in the cache kernels' index
 maps), not scanned: eight of them compile in well under GPT-2's 24, and a
@@ -42,15 +62,16 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.analysis.annotations import hot_path
-from deepspeed_tpu.models import generation
+from deepspeed_tpu.models import generation, mamba2
 from deepspeed_tpu.moe import routed
 
 
 class DecoderConfig(typing.NamedTuple):
     """Hashable: the static argument of every jitted serving program. The
-    cache's shape is read from ``n_layer / n_head / n_embd / n_positions /
-    dtype`` (``ModelAdapter.cache_spec``); ``use_flash_decode`` and
-    ``kv_page_len`` are stamped by the adapter's ``bind``."""
+    cache's shape is ``cache_spec(cfg)`` (``ModelAdapter.cache_spec``);
+    ``use_flash_decode`` and ``kv_page_len`` are stamped by the adapter's
+    ``bind``. The fields from ``n_kv_head`` on are the optional pieces of
+    the module docstring, each off by default."""
 
     vocab_size: int
     n_layer: int
@@ -70,15 +91,80 @@ class DecoderConfig(typing.NamedTuple):
     initializer_range: float = 0.02
     use_flash_decode: typing.Optional[bool] = None
     kv_page_len: int = 0
+    n_kv_head: typing.Optional[int] = None     # None: one a query head
+    rope: bool = True
+    attn_scale: typing.Optional[float] = None  # None: 1 / sqrt(head_dim)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    shared_width: int = 0                      # 0: no shared expert
+    # (first, count) of the router's experts this chip holds; None: all
+    experts_held: typing.Optional[typing.Tuple[int, int]] = None
+    # "attention" | "mamba" a layer; None: attention everywhere
+    layer_types: typing.Optional[typing.Tuple[str, ...]] = None
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_state: int = 0
+    mamba_conv: int = 4
+    mamba_chunk: int = 256
 
     @property
     def n_embd(self):
-        """Width of one token's keys (and values) in the cache."""
+        """Width of one token's QUERIES; ``n_embd // n_head`` is the head
+        size wherever a cache is read."""
         return self.n_head * self.head_dim
+
+    @property
+    def n_kv(self):
+        """Key/value heads a token stores in an attention layer."""
+        return self.n_kv_head or self.n_head
+
+    @property
+    def kinds(self):
+        return self.layer_types or ("attention",) * self.n_layer
+
+    @property
+    def kv_layers(self):
+        """The layers that hold keys, in order: layer ``kv_layers[a]`` is
+        layer ``a`` of the cache's k and v planes."""
+        return tuple(i for i, k in enumerate(self.kinds) if k == "attention")
+
+    @property
+    def mamba_layers(self):
+        return tuple(i for i, k in enumerate(self.kinds) if k == "mamba")
+
+    @property
+    def held(self):
+        """(first, count) of the routed experts held here."""
+        return self.experts_held or (0, self.n_experts)
 
     @property
     def layer_norm_epsilon(self):
         return self.rms_norm_eps
+
+
+class CacheSpec(typing.NamedTuple):
+    """What a pool of this model holds (``ModelAdapter.cache_spec``): the k
+    and v planes are as deep as the layers that hold keys and as wide as the
+    heads a token STORES, and ``slot_state`` names the recurrent state a row
+    carries beside them (``mamba2.state_shapes``; empty without it)."""
+
+    n_layer: int
+    n_head: int
+    n_embd: int
+    n_positions: int
+    dtype: typing.Any
+    layer_norm_epsilon: float
+    use_flash_decode: typing.Optional[bool]
+    kv_page_len: int
+    slot_state: tuple = ()
+
+
+def cache_spec(cfg):
+    return CacheSpec(len(cfg.kv_layers), cfg.n_kv, cfg.n_kv * cfg.head_dim,
+                     cfg.n_positions, cfg.dtype, cfg.rms_norm_eps,
+                     cfg.use_flash_decode, cfg.kv_page_len,
+                     mamba2.state_shapes(cfg))
 
 
 def served_config(cfg, use_flash_decode=None):
@@ -93,32 +179,52 @@ def served_config(cfg, use_flash_decode=None):
 
 
 def init_params(key, cfg):
-    """Weights normal at ``initializer_range``, norms at 1, in ``cfg.dtype``.
-    A layer at a time (``lax.map`` over the layers' keys), so that the
-    largest value the generator holds is one layer's, not the stack's."""
-    c, qkv, e, f = cfg.hidden_size, cfg.n_embd, cfg.n_experts, \
-        cfg.expert_width
+    """Weights normal at ``initializer_range``, norms at 1, in ``cfg.dtype``;
+    a Mamba layer's as ``mamba2.init_layer``. A layer at a time (``lax.map``
+    over the layers' keys), so that the largest value the generator holds is
+    one layer's, not the stack's."""
+    c, e, f = cfg.hidden_size, cfg.held[1], cfg.expert_width
+    q_w, kv_w = cfg.n_embd, cfg.n_kv * cfg.head_dim
     k_embed, k_head, k_layers = jax.random.split(key, 3)
 
     def normal(k, shape):
         return cfg.initializer_range * jax.random.normal(k, shape, cfg.dtype)
 
+    def attention(k_qkv, k_out):
+        out = {"wqkv": normal(k_qkv, (c, q_w + 2 * kv_w)),
+               "wo": normal(k_out, (q_w, c))}
+        if cfg.qk_norm:
+            out["q_norm"] = jnp.ones((q_w,), cfg.dtype)
+            out["k_norm"] = jnp.ones((kv_w,), cfg.dtype)
+        return out
+
     def layer(k):
         ks = jax.random.split(k, 5)
-        return {"attn_norm": jnp.ones((c,), cfg.dtype),
-                "wqkv": normal(ks[0], (c, 3 * qkv)),
-                "q_norm": jnp.ones((qkv,), cfg.dtype),
-                "k_norm": jnp.ones((qkv,), cfg.dtype),
-                "wo": normal(ks[1], (qkv, c)),
-                "ffn_norm": jnp.ones((c,), cfg.dtype),
-                "router": normal(ks[2], (c, e)),
-                "w_gate_up": normal(ks[3], (e, c, 2 * f)),
-                "w_down": normal(ks[4], (e, f, c))}
+        out = {"attn_norm": jnp.ones((c,), cfg.dtype),
+               "ffn_norm": jnp.ones((c,), cfg.dtype),
+               "router": normal(ks[2], (c, cfg.n_experts)),
+               "w_gate_up": normal(ks[3], (e, c, 2 * f)),
+               "w_down": normal(ks[4], (e, f, c))}
+        if cfg.shared_width:
+            k1, k2 = jax.random.split(jax.random.fold_in(k, 5))
+            out["shared_gate_up"] = normal(k1, (c, 2 * cfg.shared_width))
+            out["shared_down"] = normal(k2, (cfg.shared_width, c))
+        if cfg.layer_types is None:
+            out.update(attention(ks[0], ks[1]))
+        return out
 
     params = {"embed": normal(k_embed, (cfg.vocab_size, c)),
               "layers": jax.lax.map(layer,
                                     jax.random.split(k_layers, cfg.n_layer)),
               "final_norm": jnp.ones((c,), cfg.dtype)}
+    if cfg.layer_types is not None:
+        params["attn"] = jax.lax.map(
+            lambda k: attention(*jax.random.split(k)), jax.random.split(
+                jax.random.fold_in(key, 3), len(cfg.kv_layers)))
+        if cfg.mamba_layers:
+            params["mamba"] = jax.lax.map(
+                lambda k: mamba2.init_layer(k, cfg), jax.random.split(
+                    jax.random.fold_in(key, 4), len(cfg.mamba_layers)))
     if not cfg.tie_word_embeddings:
         params["lm_head"] = normal(k_head, (c, cfg.vocab_size))
     return params
@@ -150,45 +256,73 @@ def _rope(x, cos, sin):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def block(layer, cfg, x, i, rope, attend, planes):
-    """One layer: ``x`` [B, S, C] -> (x, the cache planes with layer ``i``
-    written, tokens routed to each expert [E]). ``layer`` holds ONE layer's
-    parameters."""
+def _residual(cfg, x, branch):
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * cfg.residual_multiplier
+    return x + branch.astype(x.dtype)
+
+
+def attention(layer, cfg, x, i, rope, attend, planes):
+    """An attention layer's mixer: ``x`` [B, S, C] -> (x, the cache planes
+    with layer ``i`` OF THE CACHE written). ``rope`` None: no rotary."""
     b, s, c = x.shape
-    nh, hd, eps, dt = cfg.n_head, cfg.head_dim, cfg.rms_norm_eps, cfg.dtype
+    nh, nkv, hd, eps, dt = cfg.n_head, cfg.n_kv, cfg.head_dim, \
+        cfg.rms_norm_eps, cfg.dtype
     with jax.named_scope("attn"):
         h = _rms32(x, layer["attn_norm"], eps).astype(dt)
-        q, k, v = jnp.split(h @ layer["wqkv"].astype(dt), 3, axis=-1)
+        q, k, v = jnp.split(h @ layer["wqkv"].astype(dt),
+                            [nh * hd, (nh + nkv) * hd], axis=-1)
         if cfg.qk_norm:
             with jax.named_scope("qk_norm"):
                 # over the whole projected width, before the heads split
                 q = _rms32(q, layer["q_norm"], eps)
                 k = _rms32(k, layer["k_norm"], eps)
-        with jax.named_scope("rope"):
-            q = _rope(q.astype(jnp.float32).reshape(b, s, nh, hd), *rope)
-            k = _rope(k.astype(jnp.float32).reshape(b, s, nh, hd), *rope)
+        q, k = q.reshape(b, s, nh, hd), k.reshape(b, s, nkv, hd)
+        if rope is not None:
+            with jax.named_scope("rope"):
+                q = _rope(q.astype(jnp.float32), *rope)
+                k = _rope(k.astype(jnp.float32), *rope)
         q = q.astype(dt).transpose(0, 2, 1, 3)
         k = k.astype(dt).transpose(0, 2, 1, 3)
-        v = v.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
+        v = v.reshape(b, s, nkv, hd).transpose(0, 2, 1, 3)
     y, planes = attend(i, q, k, v, planes)
     with jax.named_scope("attn"):
         y = y.transpose(0, 2, 1, 3).reshape(b, s, nh * hd)
-        x = x + y @ layer["wo"].astype(dt)
+        x = _residual(cfg, x, y @ layer["wo"].astype(dt))
+    return x, planes
+
+
+def router_logits(n32, router):
+    """The router's logits [T, E] of the float32 normed stream ``n32``
+    [T, C]. The published router's softmax is float32; its matmul is too,
+    from the float32 norm: where a token's last kept and first cut weights
+    are close, bf16 logits would pick another expert than the model does."""
+    return jnp.dot(n32, router.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+
+
+def moe(layer, cfg, x):
+    """The feed-forward half of a layer: ``x`` [B, S, C] -> (x, tokens
+    routed to each HELD expert [E_held], choices that fell on experts held
+    elsewhere (a scalar; 0 for a model held whole))."""
+    b, s, c = x.shape
+    dt = cfg.dtype
+    first, held = cfg.held
     with jax.named_scope("moe"):
-        n32 = _rms32(x, layer["ffn_norm"], eps).reshape(b * s, c)
+        n32 = _rms32(x, layer["ffn_norm"], cfg.rms_norm_eps).reshape(b * s, c)
         with jax.named_scope("router"):
-            # The published router's softmax is float32; its matmul is too,
-            # from the float32 norm: where a token's 8th and 9th weights
-            # are close, bf16's rounding would choose for it.
-            logits = jnp.dot(n32, layer["router"].astype(jnp.float32),
-                             precision=jax.lax.Precision.HIGHEST)
+            logits = router_logits(n32, layer["router"])
             weights, experts = routed.route(logits, cfg.experts_per_token,
                                             cfg.norm_topk_prob)
-        gate, counts = routed.dispatch(weights, experts, cfg.n_experts)
+        gate, counts = routed.dispatch(weights, experts, held, first)
         out = routed.expert_ffn(n32.astype(dt), gate, layer["w_gate_up"],
                                 layer["w_down"])
-        x = x + out.reshape(b, s, c)
-    return x, planes, counts
+        if cfg.shared_width:
+            out = out + routed.shared_ffn(
+                n32.astype(dt), layer["shared_gate_up"], layer["shared_down"])
+        x = _residual(cfg, x, out.reshape(b, s, c))
+    absent = b * s * cfg.experts_per_token - jnp.sum(counts)
+    return x, counts, absent
 
 
 @hot_path
@@ -198,30 +332,77 @@ def forward(params, cfg, ids, cache, attn_name=None):
     ``generation._forward``. A cache that carries ``aux_moe_load`` /
     ``aux_moe_routed`` (the adapter's pool does) gets the routed counts
     added: every row the program computes counts, a pad column or an idle
-    slot too, so the gauges read the program's load."""
+    slot too, so the gauges read the program's load.
+
+    A model with Mamba layers reads and returns the rows' recurrent state
+    (``slot_ssm<j>`` / ``slot_conv<j>``) and ``cache['n_valid']`` [B]: how many
+    leading columns of each row are real, 0 for a row that must not move
+    (default: all ``S``). The key is consumed here."""
     s = ids.shape[1]
     dt = cfg.dtype
+    cache = dict(cache)
+    n_valid = cache.pop("n_valid", None)
     attend = generation.CacheAttention(cfg, cache, s, attn_name)
     with jax.named_scope("embed"):
         x = params["embed"].astype(dt)[ids]
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
     planes = attend.planes
-    rope = rope_angles(attend.q_pos, cfg.head_dim, cfg.rope_theta)
-    load = jnp.zeros((cfg.n_experts,), jnp.float32)
-    for i in range(cfg.n_layer):
+    rope = rope_angles(attend.q_pos, cfg.head_dim, cfg.rope_theta) \
+        if cfg.rope else None
+    state = {}
+    if n_valid is None:
+        n_valid = jnp.full(ids.shape[:1], s, jnp.int32)
+    load = jnp.zeros((cfg.held[1],), jnp.float32)
+    absent = jnp.zeros((), jnp.float32)
+    n_attn = n_mamba = 0
+    for i, kind in enumerate(cfg.kinds):
         layer = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
-        x, planes, counts = block(layer, cfg, x, i, rope, attend, planes)
-        load = load + counts
+        if kind == "mamba":
+            mix = jax.tree_util.tree_map(lambda a: a[n_mamba],
+                                         params["mamba"])
+            with jax.named_scope("mamba"):
+                h = _rms32(x, layer["attn_norm"], cfg.rms_norm_eps).astype(dt)
+                ssm, conv = mamba2.ssm_key(n_mamba), mamba2.conv_key(n_mamba)
+                h, state[ssm], state[conv] = mamba2.mixer(
+                    mix, cfg, h, cache[ssm], cache[conv], attend.pos,
+                    n_valid)
+                x = _residual(cfg, x, h)
+            n_mamba += 1
+        else:
+            if "attn" in params:
+                layer = dict(layer, **jax.tree_util.tree_map(
+                    lambda a: a[n_attn], params["attn"]))
+            x, planes = attention(layer, cfg, x, n_attn, rope, attend, planes)
+            n_attn += 1
+        x, counts, away = moe(layer, cfg, x)
+        load, absent = load + counts, absent + away
     with jax.named_scope("lm_head"):
         x = _rms32(x, params["final_norm"], cfg.rms_norm_eps).astype(dt)
         head = params["embed"].T if cfg.tie_word_embeddings \
             else params["lm_head"]
         logits = jnp.dot(x, head.astype(dt),
                          preferred_element_type=jnp.float32)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
     cache = attend.advanced(planes)
+    cache.update(state)
     if "aux_moe_load" in cache:
         cache["aux_moe_load"] = cache["aux_moe_load"] + load
         cache["aux_moe_routed"] = cache["aux_moe_routed"] + jnp.sum(load)
+    if "aux_moe_absent" in cache:
+        cache["aux_moe_absent"] = cache["aux_moe_absent"] + absent
     return logits, cache
+
+
+def init_cache(cfg, batch, max_len):
+    """A zeroed dense cache for ``batch`` rows: the planes of the layers
+    that hold keys, ``pos``, and the rows' recurrent state."""
+    spec = cache_spec(cfg)
+    cache = generation.init_cache(spec, batch, max_len)
+    for name, shape, dtype in spec.slot_state:
+        cache[name] = jnp.zeros((batch,) + tuple(shape), dtype)
+    return cache
 
 
 class DecoderLM(object):
@@ -237,5 +418,5 @@ class DecoderLM(object):
     def apply(self, variables, ids):
         """Float32 logits [B, T, V] of whole sequences ``ids`` [B, T]."""
         cfg = self.config._replace(use_flash_decode=False)
-        cache = generation.init_cache(cfg, ids.shape[0], ids.shape[1])
+        cache = init_cache(cfg, ids.shape[0], ids.shape[1])
         return forward(variables["params"], cfg, ids, cache)[0]

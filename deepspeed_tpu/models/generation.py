@@ -167,6 +167,15 @@ class CacheAttention(object):
         self.cfg, self.cache, self.S = cfg, cache, S
         self.attn_name = attn_name
         self.nh, self.hd = cfg.n_head, cfg.n_embd // cfg.n_head
+        # Grouped-query heads (``cfg.n_kv``, the decoder block's): the
+        # planes STORE ``nkv`` heads and query head j reads stored head
+        # ``j // rep``. The paged kernels put a stored head's ``rep``
+        # queries beside S on the sublane axis; every other path repeats
+        # the stored heads. ``attn_scale``: a softmax scale of the model's
+        # own in place of 1/sqrt(head_dim).
+        self.nkv = getattr(cfg, "n_kv", self.nh)
+        self.rep = self.nh // self.nkv
+        self.scale = getattr(cfg, "attn_scale", None)
         B = cache["pos"].shape[0]
         self.pos = pos = cache["pos"]                  # [B] row frontiers
         self.int8 = cache["k"].dtype == jnp.int8
@@ -289,7 +298,7 @@ class CacheAttention(object):
     def _gather_pages(self, arena_l):
         # One layer of an arena -> row-major logical planes
         # [B, H, n_lp * page_len, ...] via one table gather, ungrouped.
-        return decode_attention.gather_pages(arena_l, self.tbl, self.nh,
+        return decode_attention.gather_pages(arena_l, self.tbl, self.nkv,
                                              self.pack)
 
     def __call__(self, i, q, k, v, planes):
@@ -354,6 +363,12 @@ class CacheAttention(object):
                         psel_s, pad(cache["pk_scale"][i]), ks_eff)
                     vs_eff = jnp.where(
                         psel_s, pad(cache["pv_scale"][i]), vs_eff)
+            if self.rep > 1 and not (paged and use_flash):
+                k_eff, v_eff = (jnp.repeat(a, self.rep, axis=1)
+                                for a in (k_eff, v_eff))
+                if int8:
+                    ks_eff, vs_eff = (jnp.repeat(a, self.rep, axis=1)
+                                      for a in (ks_eff, vs_eff))
         with jax.named_scope("attn"):
             if use_flash:
                 # Fused QK-score + online softmax + PV over the cache plane,
@@ -361,7 +376,7 @@ class CacheAttention(object):
                 # cache was just written, so pos is the PRE-write frontier
                 # the kernel's mask convention expects. The q8 family
                 # dequantizes in-block from codes + scales.
-                scale = 1.0 / float(hd) ** 0.5
+                scale = self.scale or 1.0 / float(hd) ** 0.5
                 if paged:
                     # Block-table flash decode: the kernel steps the list
                     # of live (row, page) pairs, a page of all heads a
@@ -394,7 +409,9 @@ class CacheAttention(object):
                     v_eff = decode_attention.dequantize_kv(v_eff, vs_eff,
                                                            cfg.dtype)
                 att = jnp.einsum("bhqd,bhkd->bhqk", q, k_eff).astype(
-                    jnp.float32) / jnp.sqrt(hd)
+                    jnp.float32)
+                att = att / jnp.sqrt(hd) if self.scale is None \
+                    else att * self.scale
                 att = jnp.where(self.mask[:, None], att, self.neg)
                 att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
                 y = jnp.einsum("bhqk,bhkd->bhqd", att, v_eff)

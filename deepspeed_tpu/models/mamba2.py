@@ -1,0 +1,216 @@
+"""The Mamba-2 mixer (Dao & Gu 2024, "state-space duality") for serving.
+
+One layer, for a normed residual stream ``h`` [B, S, C], with ``H`` heads of
+``P`` channels (``W = H * P``), a state of ``N`` a channel and ONE group::
+
+    z | xBC | dt = split(h @ in_proj, [W, W + 2N, H])
+    xBC          = silu(causal depthwise conv1d(xBC, width K) + conv_b)
+    x | B | C    = split(xBC, [W, N, N])
+    dt           = softplus(dt + dt_bias)           A = -exp(A_log), a head
+    S_t          = exp(dt_t A) S_{t-1} + dt_t outer(B_t, x_t)     [N, W]
+    y_t          = C_t . S_t + D x_t
+    out          = (RMSNorm(y * silu(z)) * norm) @ out_proj   (gate BEFORE
+                                                    the norm, over all W)
+
+WHAT A ROW CARRIES BETWEEN CALLS, and nothing else, for Mamba layer ``j``:
+its ``S`` (``slot_ssm<j>`` ``[B, N, W]`` float32: the state dim on the
+sublanes, the heads' channels side by side on the lanes, so that a head's
+decay and its input are row vectors, ``B`` and ``C`` column vectors and ``y``
+a sum down the sublanes: plain vector work) and the last ``K - 1`` rows of
+``xBC`` BEFORE the convolution (``slot_conv<j>`` ``[B, K - 1, W + 2N]``, in
+the compute type). An array a layer of each, because a layer rewrites all of
+its own every token: a layer of a stacked array is a value of its own, which
+XLA copies out and back (the stacked state did not fit the chip, the stacked
+tails were re-laid layer-major and copied whole around every layer's update),
+while an array of its own in the scan's carry is updated where it lies.
+Unlike keys and values neither has a position axis: there is no "past the
+frontier" to hide garbage in, so
+
+- a pad column (``s >= n_valid[b]``) and a row that is not decoding
+  (``n_valid[b] == 0``) must leave both EXACTLY as they were: their ``dt`` is
+  zeroed (decay 1, input 0) and the convolution's tail is taken at
+  ``n_valid``, not at the end of the slice (a row at frontier 0 has nothing
+  to keep: it reads as zeros);
+- a row whose frontier is 0 starts from zeros whatever its slot holds, so a
+  slot is reused without a reset from the host;
+- nothing can be rolled back by not advancing ``pos``: speculation and
+  prefix sharing need a snapshot of the state and are refused for a model
+  that has it (``adapters/decoder.py``).
+
+ONE recurrence, three uses. ``ssd`` is the chunked ("SSD") form: inside a
+chunk the outputs are matmuls against a decay-masked ``C B^T``, between
+chunks the state is carried; the prefill lane and the cache-free ``apply``
+run it. ``step`` is the same recurrence for one token: the decode scan runs
+it, one fused read and write of the layer's state (at Granite 4.0-H Small's
+sizes 4.19 MB a slot and layer, 2.4 GB over 64 slots and 9 layers, every
+iteration: as much traffic as the attention of a dense model over a long
+cache). The chunked form in float32 at ``highest`` precision agrees with the
+token-by-token one to rounding, so a prompt's state does not depend on how
+it was chunked.
+
+Regions of a trace (``jax.named_scope``): ``mamba`` holding ``in_proj``,
+``conv``, ``ssm``, ``gate_norm``, ``out_proj``.
+"""
+
+import jax
+import jax.numpy as jnp
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def state_shapes(cfg):
+    """A row's recurrent state, as ``cache_spec().slot_state`` names it:
+    ``((key, shape a row, dtype), ...)``, empty for a model with no Mamba
+    layer."""
+    n_mamba = len(cfg.mamba_layers)
+    if not n_mamba:
+        return ()
+    w = cfg.mamba_heads * cfg.mamba_head_dim
+    tail = (cfg.mamba_conv - 1, w + 2 * cfg.mamba_state)
+    return tuple((ssm_key(j), (cfg.mamba_state, w), jnp.float32)
+                 for j in range(n_mamba)) \
+        + tuple((conv_key(j), tail, cfg.dtype) for j in range(n_mamba))
+
+
+def ssm_key(j):
+    return "slot_ssm{}".format(j)
+
+
+def conv_key(j):
+    return "slot_conv{}".format(j)
+
+
+def init_layer(key, cfg):
+    """One Mamba layer's parameters, by Mamba-2's conventional
+    initialisation: ``A`` uniform in 1..16, the step ``dt`` log-uniform in
+    0.001..0.1 (so a head remembers between one and a thousand tokens),
+    ``D`` and the norm at 1, the convolution as PyTorch's ``Conv1d`` default
+    (uniform at ``1 / sqrt(K)``), the projections normal at
+    ``initializer_range``."""
+    h, p, n, k = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state, \
+        cfg.mamba_conv
+    w, c, dt = h * p, cfg.hidden_size, cfg.dtype
+    ks = jax.random.split(key, 6)
+    step = jnp.exp(jax.random.uniform(ks[2], (h,), jnp.float32,
+                                      jnp.log(0.001), jnp.log(0.1)))
+    bound = 1.0 / k ** 0.5
+    return {
+        "in_proj": cfg.initializer_range * jax.random.normal(
+            ks[0], (c, 2 * w + 2 * n + h), dt),
+        "conv_w": jax.random.uniform(ks[1], (k, w + 2 * n), jnp.float32,
+                                     -bound, bound).astype(dt),
+        "conv_b": jax.random.uniform(ks[5], (w + 2 * n,), jnp.float32,
+                                     -bound, bound).astype(dt),
+        # the inverse of softplus at the drawn step
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "A_log": jnp.log(jax.random.uniform(ks[3], (h,), jnp.float32,
+                                            1.0, 16.0)),
+        "D": jnp.ones((h,), jnp.float32),
+        "norm": jnp.ones((w,), dt),
+        "out_proj": cfg.initializer_range * jax.random.normal(
+            ks[4], (w, c), dt),
+    }
+
+
+def causal_conv(xbc, tail, weight, bias, n_valid):
+    """``xbc`` [B, S, Cd] before the convolution, ``tail`` [B, K - 1, Cd]
+    the rows before it. Returns (``silu(conv + bias)`` [B, S, Cd] float32,
+    the tail after ``n_valid[b]`` of the S rows: pad rows never enter it)."""
+    k, s = weight.shape[0], xbc.shape[1]
+    full = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
+    full32, w32 = full.astype(jnp.float32), weight.astype(jnp.float32)
+    out = bias.astype(jnp.float32) + sum(
+        w32[j] * full32[:, j:j + s] for j in range(k))
+    new_tail = jax.vmap(lambda f, n: jax.lax.dynamic_slice_in_dim(
+        f, n, k - 1, axis=0))(full, n_valid)
+    return jax.nn.silu(out), new_tail.astype(tail.dtype)
+
+
+def ssd(x, dt, a, bmat, cmat, state, chunk):
+    """The recurrence over ``S`` tokens in chunks of ``chunk``.
+
+    x ``[B, S, H, P]``, dt ``[B, S, H]`` (after softplus, 0 where a column
+    must not move the state), a ``[H]`` (negative), bmat and cmat
+    ``[B, S, N]``, state ``[B, N, H * P]``; all float32. Returns
+    (y ``[B, S, H, P]`` without the ``D`` term, the state after)."""
+    b, s, h, p = x.shape
+    n = bmat.shape[-1]
+    state = state.reshape(b, n, h, p)
+    ys = []
+    for lo in range(0, s, chunk):
+        sl = slice(lo, min(lo + chunk, s))
+        xc, dtc, bc, cc = x[:, sl], dt[:, sl], bmat[:, sl], cmat[:, sl]
+        ln = xc.shape[1]
+        cum = jnp.cumsum(dtc * a, axis=1)                   # [B, L, H] <= 0
+        # decay from after token s to token t, for s <= t
+        seg = cum[:, :, None, :] - cum[:, None, :, :]       # [B, t, s, H]
+        causal = jnp.tril(jnp.ones((ln, ln), bool))[None, :, :, None]
+        decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
+        g = jnp.einsum("btn,bsn->bts", cc, bc, precision=_HIGHEST)
+        m = g[..., None] * decay * dtc[:, None, :, :]
+        y = jnp.einsum("btsh,bshp->bthp", m, xc, precision=_HIGHEST)
+        y = y + jnp.einsum("btn,bnhp->bthp", cc, state,
+                           precision=_HIGHEST) * jnp.exp(cum)[..., None]
+        rest = jnp.exp(cum[:, -1:, :] - cum) * dtc           # [B, s, H]
+        state = jnp.exp(cum[:, -1])[:, None, :, None] * state + jnp.einsum(
+            "bsn,bshp->bnhp", bc, rest[..., None] * xc, precision=_HIGHEST)
+        ys.append(y)
+    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=1)
+    return y, state.reshape(b, n, h * p)
+
+
+def step(x, dt, a, bvec, cvec, state):
+    """One token of the recurrence. x ``[B, H, P]``, dt ``[B, H]`` (0 for a
+    row that must not move: decay 1, input 0, the state exactly as it was),
+    bvec and cvec ``[B, N]``, state ``[B, N, H * P]``. Returns
+    (y ``[B, H, P]``, the state after)."""
+    b, h, p = x.shape
+    decay = jnp.repeat(jnp.exp(dt * a), p, axis=1)          # [B, W]
+    dtx = (dt[..., None] * x).reshape(b, h * p)
+    state = state * decay[:, None, :] + bvec[:, :, None] * dtx[:, None, :]
+    y = jnp.sum(state * cvec[:, :, None], axis=1)
+    return y.reshape(b, h, p), state
+
+
+def mixer(p, cfg, hid, ssm, tail, pos, n_valid):
+    """The mixer of one Mamba layer.
+
+    ``p`` the layer's parameters, ``hid`` [B, S, C] the normed stream,
+    ``ssm`` [B, N, W] and ``tail`` the rows' state and convolution tail of
+    this layer (module docstring), ``pos`` [B] the frontiers before this call,
+    ``n_valid`` [B] how many leading columns of each row are real (0: the
+    row does not move). Returns (out [B, S, C] in the compute type, ssm,
+    tail)."""
+    b, s, _ = hid.shape
+    h, hp, n = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state
+    w, dt_ = h * hp, cfg.dtype
+    with jax.named_scope("in_proj"):
+        proj = hid @ p["in_proj"].astype(dt_)
+        z, xbc, dt = jnp.split(proj, [w, 2 * w + 2 * n], axis=-1)
+    fresh = (pos == 0)[:, None, None]
+    with jax.named_scope("conv"):
+        tail = jnp.where(fresh, jnp.zeros_like(tail), tail)
+        xbc, tail = causal_conv(xbc, tail, p["conv_w"], p["conv_b"], n_valid)
+        x, bmat, cmat = jnp.split(xbc, [w, w + n], axis=-1)
+    with jax.named_scope("ssm"):
+        valid = jnp.arange(s)[None, :] < n_valid[:, None]
+        dt = jnp.where(valid[..., None], jax.nn.softplus(
+            dt.astype(jnp.float32) + p["dt_bias"]), 0.0)
+        a = -jnp.exp(p["A_log"])
+        x = x.reshape(b, s, h, hp)
+        state = jnp.where(fresh, 0.0, ssm.astype(jnp.float32))
+        if s == 1:
+            y, state = step(x[:, 0], dt[:, 0], a, bmat[:, 0], cmat[:, 0],
+                            state)
+            y = y[:, None]
+        else:
+            y, state = ssd(x, dt, a, bmat, cmat, state, cfg.mamba_chunk)
+        ssm = state.astype(ssm.dtype)
+        y = (y + p["D"][:, None] * x).reshape(b, s, w)
+    with jax.named_scope("gate_norm"):
+        y = y * jax.nn.silu(z.astype(jnp.float32))
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                              + cfg.rms_norm_eps)
+        y = (y * p["norm"].astype(jnp.float32)).astype(dt_)
+    with jax.named_scope("out_proj"):
+        return y @ p["out_proj"].astype(dt_), ssm, tail

@@ -765,9 +765,12 @@ def flash_decode_attention_q8(q, k, v, k_scale, v_scale, pos, scale=None,
 # 2 x 1 MB (a lane tile a row), scores and probabilities 1 MB each: 9 MB.
 # The decode scan's S = 1 (a 16-row tile) needs 3 MB. Only a shape past
 # the budget gets fewer heads a unit (the largest divisor of H that fits)
-# and an outer grid axis over head groups; no cell's does. Grouped-query
-# heads would put the queries of one key/value head beside S on the
-# sublane axis; nothing here assumes they do not.
+# and an outer grid axis over head groups; no cell's does. GROUPED-QUERY
+# HEADS (an arena that stores fewer heads than the queries have; Granite
+# 4.0-H's 32 over 8) put the ``rep`` query heads of one stored head beside S
+# on the sublane axis (``_paged_on_shards``): a page comes in once for all
+# of them, S = 1 fills 4 of a 16-row tile's rows where it filled one, and
+# the body knows only that row ``r`` sits at position ``r % S``.
 # ---------------------------------------------------------------------------
 
 def gather_pages(arena, block_tbl, h, g):
@@ -795,9 +798,11 @@ def decode_attention_paged_reference(q, k, v, block_tbl, pos, scale=None):
     page_len, D] is g = 1); block_tbl: [B, n_lp] int32; pos: [B] int32
     frontiers."""
     h, g = q.shape[1], k.shape[-1] // q.shape[-1]
-    return decode_attention_reference(
-        q, gather_pages(k, block_tbl, h, g), gather_pages(v, block_tbl, h, g),
-        pos, scale=scale)
+    rep = max(1, h // (k.shape[-3] * g))       # grouped-query heads
+    k, v = (gather_pages(a, block_tbl, h // rep, g) for a in (k, v))
+    if rep > 1:
+        k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
+    return decode_attention_reference(q, k, v, pos, scale=scale)
 
 
 @hot_path
@@ -894,12 +899,15 @@ def _paged_units(tbl, pos, s_len, page_len):
 
 
 def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
-                  *refs, s_len, q8, single_kv, pack):
+                  *refs, s_len, q8, single_kv, pack, rep=1):
     """One grid step = one unit of ``_paged_units``: a page of all the
     heads (of the group, where all do not fit) of one row. Against a packed
     arena (``pack`` heads a lane tile) a head of the block is ``pack`` heads
     of the model: query row ``r`` is row ``r % s_len`` of head
-    ``r // s_len`` of them (``_pack_query``)."""
+    ``r // s_len`` of them (``_pack_query``). Grouped-query heads put the
+    ``rep`` query heads of a stored head beside ``s_len`` the same way: a
+    stored head's rows are ``rep * s_len``, row ``r`` still at position
+    ``r % s_len``."""
     n_a = 4 if q8 else 2
     k_ref, v_ref, *scale_refs = refs[:n_a]     # scales: the int8 family's
     o_ref, stats = refs[n_a], refs[n_a + 1:]
@@ -929,7 +937,7 @@ def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
             out = ref[pl.ds(0, s.shape[0], stride=pack), :][:, None, :]
             for a in range(1, pack):
                 out = jnp.where(
-                    row >= a * s_len,
+                    row >= a * s_len * rep,
                     ref[pl.ds(a, s.shape[0], stride=pack), :][:, None, :],
                     out)
             return out
@@ -942,7 +950,7 @@ def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
             # (global pos_b + i) iff k_pos <= q_pos. Padded query rows
             # (i >= s_len) compute garbage the launcher slices off.
             row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            if pack > 1:
+            if pack * rep > 1:
                 row = _rem(row, s_len)
             q_pos = pos_b + row
             k_pos = j * page_len + jax.lax.broadcasted_iota(
@@ -1006,17 +1014,19 @@ def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
     pl.when(n_live > 0)(attend)
 
 
-def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1):
+def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1):
     """The one launcher of both paged families: ``arenas`` is (k, v) or
     (k, v, k_scale, v_scale), whole or one layer's (``layer`` None). With
     ``pack`` > 1 the arenas are packed and ``q`` is ``_pack_query``'s:
     ``[B, Hp, pack * S, pack * D]`` against ``[.., Hp, page_len, pack * D]``,
-    which the body attends as ``Hp`` heads of a whole lane tile."""
+    which the body attends as ``Hp`` heads of a whole lane tile. With
+    ``rep`` > 1 (grouped-query heads) ``q`` holds a stored head's ``rep``
+    query heads on its row axis, ``[B, Hkv, rep * S, D]``."""
     from jax.experimental.pallas import tpu as pltpu
 
     layer, arenas = _whole_arena(layer, *arenas)
     b, h, n_rows, d = q.shape
-    s = n_rows // pack               # query positions a row of the batch
+    s = n_rows // (pack * rep)       # query positions a row of the batch
     page_len = arenas[0].shape[3]
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     sub = _sublane(q.dtype)
@@ -1060,7 +1070,7 @@ def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1):
     out = pallas_mode.kernel_call(
         name,
         functools.partial(_paged_kernel, s_len=s, q8=len(arenas) == 4,
-                          single_kv=single_kv, pack=pack),
+                          single_kv=single_kv, pack=pack, rep=rep),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d), q.dtype),
     )(*units, q, *arenas)
@@ -1071,34 +1081,44 @@ def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1):
 
 
 def _flash_decode_paged_pallas(q, k, v, tbl, pos, scale,
-                               name=None, layer=None, pack=1):
+                               name=None, layer=None, pack=1, rep=1):
     return _paged_launch(name or "paged_decode", q, (k, v), tbl, pos, scale,
-                         layer, pack)
+                         layer, pack, rep)
 
 
 def _flash_decode_paged_q8_pallas(q, k, v, k_scale, v_scale, tbl, pos,
-                                  scale, name=None, layer=None, pack=1):
+                                  scale, name=None, layer=None, pack=1,
+                                  rep=1):
     return _paged_launch(name or "paged_decode_q8", q,
                          (k, v, k_scale.astype(jnp.float32),
                           v_scale.astype(jnp.float32)),
-                         tbl, pos, scale, layer, pack)
+                         tbl, pos, scale, layer, pack, rep)
 
 
 def _paged_on_shards(launch, q, arenas, block_tbl, pos, scale, name, layer):
     """Both paged families' way to their launcher: ``g`` heads a lane tile
     is read from the shapes, the queries are packed to match the arena and
     each head's output taken back out; on a mesh the PACKED heads are what
-    'model' splits, of the queries and of the arenas alike."""
-    h, g = q.shape[1], arenas[0].shape[-1] // q.shape[-1]
+    'model' splits, of the queries and of the arenas alike. GROUPED-QUERY
+    HEADS are read from the shapes too: an arena that stores fewer heads
+    than ``q`` has (``rep`` query heads a stored head, query head ``j``
+    reading stored head ``j // rep``) gets them as ``[B, Hkv, rep * S, D]``,
+    a stored head's queries beside ``S`` on the sublane axis, so a page is
+    brought in once for all of them."""
+    b, h, s_len, d = q.shape
+    g = arenas[0].shape[-1] // d
+    rep = max(1, h // (arenas[0].shape[-3] * g))
+    if rep > 1:
+        q = q.reshape(b, h // rep, rep * s_len, d)
     q = _pack_query(q, g)
     arena = "-h" if layer is None else "--h"
     out = on_shards(
         functools.partial(launch, scale=float(scale), name=name, layer=layer,
-                          pack=g),
+                          pack=g, rep=rep),
         kernel_sharding(q.shape[0], q.shape[1]),
         ("bh",) + (arena,) * len(arenas) + ("b", "b"), ("bh",))(
             q, *arenas, block_tbl, pos)
-    return _unpack_output(out, g, h)
+    return _unpack_output(out, g, h // rep).reshape(b, h, s_len, d)
 
 
 @hot_path

@@ -546,3 +546,76 @@ def test_decoder_mixed_step_forms_no_layer_of_the_arena_in_its_decode_scan(
     one_layer = ["[{},2048,{}]".format(experts, 2 * width),
                  "[{},{},2048]".format(experts, width)]
     assert _arena_shaped(everywhere, one_layer) == []
+
+
+def test_hybrid_mixed_step_updates_each_layers_state_where_it_lies(
+        chip, monkeypatch):
+    """The hybrid stack at Granite 4.0-H Small's widths and ALL TEN layers of
+    the cell's period (depth decides what XLA's copy elision leaves; the
+    cell's pool: 64 slots of 2304, page 128, chunk 16, lane 128; 36 of 72
+    experts held; some minutes to compile): in the decode scan each Mamba
+    layer's float32 state ``slot_ssm<j>`` [64, 128, 8192] is the result of
+    ONE fusion an iteration (the update, in place in the scan's carry) and of
+    nothing else: no ``copy``, no slice, no ``dynamic-update-slice``; and the
+    one attention layer's arena (8 STORED heads under 32 query heads) meets
+    ``kv_append`` and the grouped-query ``paged_decode`` only."""
+    from deepspeed_tpu.inference import kv_pool
+    from deepspeed_tpu.inference.adapters import DecoderAdapter
+    from deepspeed_tpu.inference.config import InferenceConfig
+    from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
+
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    slots, chunk, lane = 64, 16, 128
+    kinds = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+    model = DecoderLM(DecoderConfig(
+        vocab_size=50176, n_layer=len(kinds), n_head=32, head_dim=128,
+        hidden_size=4096, n_positions=131072, n_experts=72,
+        experts_per_token=10, expert_width=768, qk_norm=False,
+        norm_topk_prob=True, tie_word_embeddings=True, dtype=BF16,
+        n_kv_head=8, rope=False, attn_scale=1 / 128.0,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        logits_scaling=16.0, shared_width=1536, experts_held=(0, 36),
+        layer_types=kinds, mamba_heads=128, mamba_head_dim=64,
+        mamba_state=128))
+    config = InferenceConfig.from_dict(dict(
+        max_slots=slots, max_len=2304, chunk_size=chunk, paged_kv=True,
+        kv_page_len=PAGE, prefill_chunk=lane, use_flash_decode=True))
+    adapter = DecoderAdapter.from_model(model, use_flash_decode=True).bind(
+        config, None)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0))["params"])
+    pool = jax.eval_shape(lambda: dict(kv_pool.init_pool(
+        adapter.cache_spec(), slots, 2304, slack=lane, page_len=PAGE),
+        **adapter.aux_state()))
+    assert pool["k"].shape == (1, 64 * 19 + 1, 8, PAGE, 128)
+    assert all(pool["slot_ssm{}".format(j)].shape == (slots, 128, 8192)
+               for j in range(9))
+
+    text = _mixed_step_text(chip, adapter, params, pool, chunk, lane)
+    dump = os.environ.get("DS_TPU_HLO_DUMP")
+    if dump:
+        with open(dump, "w") as f:
+            f.write(text)
+    comps = _computations(text)
+    scan, in_scan = _scan_lines(comps)
+    names = sorted(c.split(".")[0] for c in _kernel_calls(
+        "\n".join(in_scan)))
+    assert names == ["kv_append", "paged_decode"]
+    # Whole instructions only: what a fusion computes inside itself never
+    # reaches memory.
+    def whole(names):
+        return [line for name in names
+                if not name.startswith("fused_computation")
+                for line in comps[name]]
+
+    state = ["f32[64,128,8192]"]
+    touched = _arena_shaped(whole(scan), state)
+    assert [op for _, op in touched] == ["fusion"] * 9, touched
+    arena = ["[1,1217,8,128,128]", "[1217,8,128,128]"]
+    assert _arena_shaped([line for lines in comps.values()
+                          for line in lines], arena) == []
+    # Outside the scan the lane slices ONE slot's state out of each layer's
+    # and writes it back where it lies (a fused dynamic-update-slice); no
+    # copy of a layer's state anywhere in the step.
+    outside = _arena_shaped(whole(set(comps) - set(scan)), state)
+    assert [op for _, op in outside] == ["fusion"] * 9, outside
