@@ -708,7 +708,7 @@ def _measure_serving(smoke=False, flash_decode=None, spec_decode=True,
                 reqs.append(engine.submit(prompts[submitted],
                                           max_new_tokens=max_new))
                 submitted += 1
-            if engine._scheduler.idle:
+            if engine.idle:
                 time.sleep(max(arrivals[submitted] - (time.time() - t0),
                                0.0))
                 continue

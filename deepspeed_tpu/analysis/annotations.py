@@ -70,7 +70,7 @@ SANCTIONED_SYNC_SITES = {
         "harvest_snapshot", "max_active_frontier", "free_slots",
     }),
     "deepspeed_tpu/inference/engine.py": frozenset({
-        "_step_once",
+        "_harvest_step",
     }),
 }
 

@@ -44,9 +44,13 @@ occupancy changes never change a program.
 The host loop (``step()``) runs the Orca cycle at step boundaries:
 admit queued requests into free slots, feed the oldest prefilling
 slot's next prompt chunk, decode, harvest emitted tokens in ONE batched
-host sync, evict finished slots. Under greedy decoding the emitted
-tokens are token-identical to sequential ``generate`` calls — both
-drive the same adapter ``decode_step`` primitive.
+host sync, evict finished slots. It keeps ONE STEP IN FLIGHT
+(``_step_once``): a call dispatches step N+1 first, while the chip runs
+step N, and then harvests N, so the host's scheduling and delivery run
+beside a device step and the chip never waits for them. Under greedy
+decoding the emitted tokens are token-identical to sequential
+``generate`` calls — both drive the same adapter ``decode_step``
+primitive.
 
 CRASH-ONLY serving (docs/RESILIENCE.md): the host-side request records
 are the durable truth and the device pool is disposable. A fatal step
@@ -71,6 +75,7 @@ match, and every program pins its out_shardings so the cache layout
 survives every step. One engine, sharded or not.
 """
 
+import collections
 import time
 
 import jax
@@ -114,6 +119,7 @@ from deepspeed_tpu.inference.kv_pool import (
     pool_shardings,
     shard_pool,
     slot_cache_view,
+    snapshot_of,
     write_slot_cache,
 )
 from deepspeed_tpu.inference.paging import TRASH_PAGE, PageAllocator
@@ -392,9 +398,13 @@ def _mixed_step_program(params, adapter, chunk, spec, pool, p_ids, p_slot,
     prompt-length and spec/non-spec mix, which is the whole
     compile-count contract.
 
-    Returns (pool', first_token, tokens, valid): the first token is -1
-    unless ``p_done``; tokens/valid are [chunk, slots] without
-    speculation, [chunk, slots, spec_k+1] with it.
+    Returns (pool', first_token, tokens, valid, snapshot): the first
+    token is -1 unless ``p_done``; tokens/valid are [chunk, slots] without
+    speculation, [chunk, slots, spec_k+1] with it; the snapshot is
+    ``kv_pool.snapshot_of(pool')``, the scalars a harvest reads, as outputs
+    of their own. Only ``pool`` is donated, so everything beside it stays
+    readable after the NEXT call has taken the pool: the engine dispatches
+    step N+1 before it harvests step N.
 
     In a trace the two lanes are the regions ``prefill_lane`` (its arena
     write-back ``prefill_lane/kv_write``, its kernel ``prefill_attn``) and
@@ -450,7 +460,16 @@ def _mixed_step_program(params, adapter, chunk, spec, pool, p_ids, p_slot,
     else:
         pool, toks, valid = _spec_decode_chunk_program(
             params, adapter, chunk, spec[0], spec[1], pool)
-    return pool, first, toks, valid
+    return pool, first, toks, valid, snapshot_of(pool)
+
+
+# One dispatched serving step whose results are still on the chip: what
+# ``_harvest_step`` needs to deliver them, as the host saw it when it
+# dispatched (``rows``: slot -> request of the decode lane, the lane's
+# request included once its prompt's last slice rode this step).
+_Flight = collections.namedtuple("_Flight", (
+    "step", "pf", "lane_slot", "n_valid", "p_done", "rows", "outputs",
+    "dispatch_s"))
 
 
 class InferenceEngine(object):
@@ -484,6 +503,7 @@ class InferenceEngine(object):
         # serialization as step() itself.
         "_handoff_outbox", "_handoff_enabled",
         "_steps",           # step number the spans carry; stepper-owned
+        "_flight",          # the dispatched, unharvested step; same owner
     })
 
     def __init__(self, model, params, config=None, mesh=None, adapter=None):
@@ -550,6 +570,15 @@ class InferenceEngine(object):
         # engine. The spec is part of the pool-shape contract, so it
         # must exist before _build_pool.
         hspec = spec_from_config(config)
+        # Steps kept in flight between two step() calls (_step_once). 1
+        # unless this engine was BUILT with a feature whose host decision
+        # reads the result of the step just dispatched: speculation (how
+        # many tokens a row emitted), the prefix and offload tiers (prefix
+        # publishing and swap victims read the pool after a harvest), the
+        # prefill role (it captures the slots a harvest found decoding).
+        self._depth = int(self._spec is None and not hspec.prefix
+                          and not hspec.offload and config.role != "prefill")
+        self._flight = None     # the _Flight dispatched and not harvested
         self._hier = None
         self._last_swap_out_s = None
         # Most recent step harvest (host arrays). metrics() derives its
@@ -585,7 +614,7 @@ class InferenceEngine(object):
             params = jax.tree_util.tree_map(jax.device_put, params, param_sh)
             pool_out = pool_shardings(mesh, pool)
             rep = mesh_lib.replicated(mesh)
-            mixed_out = (pool_out, rep, rep, rep)
+            mixed_out = (pool_out, rep, rep, rep, rep)
         else:
             mixed_out = None
         self._params = params
@@ -637,7 +666,8 @@ class InferenceEngine(object):
 
         self.timers = SynchronizedWallClockTimer(registry=self.telemetry)
         self.counters = _CounterBank(self.telemetry, (
-            "tokens_out", "chunks", "prefills", "prefill_tokens",
+            "tokens_out", "chunks", "steps_dispatched_ahead", "prefills",
+            "prefill_tokens",
             "requests_completed", "occupied_slot_steps", "slot_steps",
             # Resilience counters (docs/RESILIENCE.md). deadline_sheds
             # and faults_injected are get-or-create by name, so the
@@ -703,6 +733,12 @@ class InferenceEngine(object):
                         if r.phase == "prefilling"))
         self.telemetry.gauge("slot_occupancy").set_fn(
             self._scheduler.occupancy)
+        # Share of all dispatched steps that were dispatched while the one
+        # before was unharvested (1 - 1/steps in a steady run, 0 on an
+        # engine built at depth 0).
+        self.telemetry.gauge("steps_ahead_share").set_fn(
+            lambda: self.counters["steps_dispatched_ahead"]
+            / float(max(self._steps, 1)))
         self.telemetry.gauge("kv_pool_bytes").set_fn(
             lambda: pool_nbytes(self._pool))
         # Same footprint under the name the capacity dashboards key on:
@@ -902,7 +938,11 @@ class InferenceEngine(object):
         donated into the failed call, so device state is LOST by
         definition — rebuild it (same shapes: no recompile), requeue
         every in-flight request ahead of the queue, and rewrite each
-        for replay. Bounded: ``recovery_max_retries`` CONSECUTIVE
+        for replay. A step dispatched and not yet harvested is dropped
+        with the pool: none of its tokens reached a handle, the host's
+        records (tokens delivered so far) are what replay starts from, and
+        ``requeue_running`` resets the cursors and budgets dispatch had
+        moved ahead of them. Bounded: ``recovery_max_retries`` CONSECUTIVE
         failures (a clean step resets the streak) transition to dead
         and re-raise as EngineDeadError."""
         t0 = time.time()
@@ -929,6 +969,9 @@ class InferenceEngine(object):
                        self._recovery_streak)
         self._pool = self._build_pool()
         self._last_snap = None  # snapshot described the torn-down pool
+        self._flight = None
+        if self.timers("inference/decode").running:
+            self.timers("inference/decode").stop()
         if self._hier is not None:
             # The trie/refcounts/swap records all described the pool
             # that just died (requeue_running pulls SWAPPED sessions
@@ -1148,8 +1191,9 @@ class InferenceEngine(object):
         first) / (tokens - 1)) is one observation — the same statistic
         _latency_percentiles always reported, now windowed."""
         slot = req.slot
-        self._scheduler.complete(req.slot)
-        if self._pager is not None:
+        self._scheduler.complete(req)
+        if self._pager is not None and slot is not None:
+            # (None: ``_release`` freed slot and pages at dispatch.)
             self._free_slot_pages(slot, req.rid)
         if self._hier is not None:
             self._hier.on_release(req)
@@ -1174,9 +1218,18 @@ class InferenceEngine(object):
     # --------------------------------------------------------------- step
 
     def step(self):
-        """One step boundary: admit into free slots, advance prefill and
-        decode, harvest tokens, evict finished slots. Returns the
-        requests completed during this step.
+        """One step boundary: admit into free slots, dispatch the next
+        device step (prefill lane + decode lane), harvest the tokens of
+        the step dispatched a call ago, evict finished slots. Returns the
+        requests completed by the harvested step.
+
+        The engine keeps ONE step in flight (``_step_once``): when this
+        returns, the tokens of device step N are on their handles and step
+        N+1 is already running, scheduled on what the host could know
+        without N's result. So a handle lags the chip by one step, a
+        request submitted now enters step N+2, ``idle`` stays False until
+        the step in flight is harvested, and ``run`` / ``drain`` / ``close``
+        leave nothing in flight.
 
         The RESILIENCE envelope wraps the whole boundary: the watchdog
         times it (a step overrunning ``step_budget_s`` trips loudly from
@@ -1195,8 +1248,11 @@ class InferenceEngine(object):
             with self._watchdog:
                 if stall > 0:
                     time.sleep(stall)
-                with self.tracer.timed("inference/step",
-                                       step=self._steps + 1):
+                # The device step this call harvests: the one in flight, or
+                # the one it is about to dispatch itself.
+                with self.tracer.timed(
+                        "inference/step",
+                        step=self._steps + (self._flight is None)):
                     done = self._step_once()
         except self._fatal as exc:
             done = self._recover(exc)
@@ -1249,7 +1305,7 @@ class InferenceEngine(object):
                 total += int(r.prompt.size) + len(r.tokens)
         return total
 
-    def _ensure_paged_mappings(self, pf, n_valid, p_done):
+    def _ensure_paged_mappings(self, pf, n_valid, p_done, rows):
         """Step-boundary page mapping: back every position the coming
         mixed step can WRITE, then rebind the device block table iff the
         host copy changed (THE page-arena rebind — an eager host->device
@@ -1270,14 +1326,21 @@ class InferenceEngine(object):
                 # final slice — map its decode writes too.
                 upto += lookahead
             pager.ensure_mapped(pf.slot, upto)
-        for slot, req in self._scheduler.running.items():
-            if req.phase != "decoding":
-                continue
-            pos = int(req.prompt.size) + len(req.tokens)
-            pager.ensure_mapped(slot, pos + lookahead)
+        for slot, req in rows.items():
+            # Where the row stands when THIS step begins: by the budget's
+            # arithmetic (``sent``; the step in flight is counted, so the
+            # host's harvested tokens may lag it), or, under speculation,
+            # by the tokens harvested (nothing is in flight then).
+            ahead = req.sent if self._spec is None else len(req.tokens)
+            pager.ensure_mapped(slot, int(req.prompt.size) + ahead
+                                + lookahead)
         if pager.dirty:
+            # A COPY goes up: the upload may read the host buffer after
+            # this returns (or alias it outright on a CPU backend), and the
+            # allocator edits its table in place while the step that reads
+            # this one is still on the chip.
             self._pool = dict(self._pool,
-                              block_tbl=jnp.asarray(pager.table))
+                              block_tbl=jnp.asarray(pager.table.copy()))
             pager.dirty = False
 
     def _free_slot_pages(self, slot, rid):
@@ -1287,7 +1350,18 @@ class InferenceEngine(object):
         The DEVICE row is stale until the next step's rebind — safe,
         because every program call is preceded by _ensure_paged_mappings
         and freed pages cannot be re-granted and re-bound without that
-        same rebind shipping this row's zeroing too."""
+        same rebind shipping this row's zeroing too.
+
+        With a step in flight the table that step was dispatched with may
+        still map the row, and the step may still write through it (a
+        request released at dispatch decodes to its budget's end in it; a
+        cancelled or EOS-ended row writes at its frozen frontier). Safe for
+        the same reason, one step on: a freed page is granted again only by
+        a LATER step's ``_ensure_paged_mappings``, in that step's own copy
+        of the table, and the chip runs that step after the one in flight:
+        programs, and the eager uploads between them, execute in dispatch
+        order. No dispatched table ever holds a page in two rows
+        (tests/unit/test_step_in_flight.py holds both)."""
         self._pager.free_slot(slot)
         self._pager.release_reservation(rid)
 
@@ -1801,16 +1875,79 @@ class InferenceEngine(object):
         return req
 
     def _step_once(self):
+        """One ``step()``: keep one device step in flight.
+
+        DISPATCH FIRST: with step N on the chip (dispatched by the call
+        before), schedule and dispatch step N+1, and only then harvest and
+        deliver N. JAX dispatches asynchronously, so the chip finds N+1
+        queued when N ends, and ``inference/schedule``, ``inference/deliver``
+        and whatever the caller does between two ``step()`` calls run beside
+        a device step instead of between two. A call that finds nothing in
+        flight (the first, or the first after a lull) dispatches N itself
+        before N+1, so every call that has work returns one device step's
+        tokens, and waits for ONE device step, as before.
+
+        Nothing in ``schedule`` needs a device result: the prefill cursor
+        and a request's ``sent`` advance at dispatch by counts the host
+        chose, the pool's arrays are futures that the next program (or an
+        eager pin, a cancel's freeze, a handoff's restore) is queued
+        behind, and the step's own snapshot outputs (``kv_pool.
+        snapshot_of``) outlive the donated pool. What the host learns a
+        step late is an end by EOS: the slot is inactive on the chip
+        meanwhile, emits nothing in N+1, and is freed when N is harvested.
+        A request's tokens still reach its handle only at harvest, TTFT
+        stamps there, and the host's records stay the truth recovery
+        replays from: ``_recover`` drops the step in flight with the pool.
+
+        An engine BUILT with a feature whose host decision reads the result
+        of the step just dispatched (``_depth`` 0: speculation, the prefix
+        and offload tiers, the prefill role) harvests each step in the call
+        that dispatched it, through this same body."""
         done = []
-        self._steps += 1
-        step = self._steps
+        if self._flight is None:
+            self._flight = self._dispatch_step(ahead=False)
+        flight = self._flight
+        if flight is None:
+            return done  # nothing can decode and the lane has nothing
+        if self._injector is not None:
+            # A "raise" fault fires HERE, once a call that has a device
+            # step to dispatch or to harvest, in place of the next program
+            # call (or, when nothing is left to dispatch, of the harvest: a
+            # real XlaRuntimeError of an asynchronous dispatch surfaces
+            # there too) — the pool must be presumed donated-and-lost, the
+            # step in flight with it.
+            self._injector.maybe_raise()
+        # ``_flight`` names N until N+1 is safely dispatched: a fault in
+        # between leaves ``_recover`` one record to drop.
+        self._flight = self._dispatch_step(ahead=True) if self._depth \
+            else None
+        self._harvest_step(flight, done)
+        return done
+
+    def _dispatch_step(self, ahead):
+        """``inference/schedule`` and ``inference/mixed_step`` of the next
+        device step: admission, the lane's slice, page mapping, the upload
+        of the lane's arguments, the dispatch (which returns at once), and
+        the bookkeeping the host can do by arithmetic. ``ahead``: the step
+        before is still unharvested. Returns the ``_Flight`` to harvest, or
+        None when no slot decodes and the lane has nothing (no program
+        runs)."""
+        sched = self._scheduler
+        if not (sched.queue or sched.running or sched.swapped):
+            return None
+        step = self._steps + 1
         with self.tracer.timed("inference/schedule", step=step):
             offload = self._hier is not None and self._hier.spec.offload
             resumed = self._swap_in_ready() if offload else []
             self._admit()
             if offload:
                 self._maybe_swap_out(resumed)
-            pf = self._scheduler.next_prefill()
+            pf = sched.next_prefill()
+            rows = {slot: req
+                    for slot, req in sched.running.items()
+                    if req.phase == "decoding"}
+            if pf is None and not rows:
+                return None
             C = self.config.prefill_chunk
             ids = np.zeros((1, C), np.int32)
             if pf is not None:
@@ -1835,7 +1972,7 @@ class InferenceEngine(object):
                 # device block table if the host copy moved — the one
                 # host->device upload that makes freed rows' zeroing and
                 # fresh mappings visible atomically before the program runs.
-                self._ensure_paged_mappings(pf, n_valid, p_done)
+                self._ensure_paged_mappings(pf, n_valid, p_done, rows)
 
             # Device scalars built before the call so the xray stash sees
             # the exact argument structure the program is dispatched with.
@@ -1856,26 +1993,64 @@ class InferenceEngine(object):
                     max_new_d, eos_d, temp_d, top_k_d, seed_d,
                     donate=("pool",),
                     track_change=self.recompile_detector.warm)
-        if self._injector is not None:
-            # A "raise" fault fires HERE, in place of the program call —
-            # the pool must be presumed donated-and-lost, exactly like a
-            # real XlaRuntimeError out of the call below.
-            self._injector.maybe_raise()
-        self.timers("inference/decode").start()
-        tok_before = self.counters["tokens_out"]
-        decoding = sum(1 for r in self._scheduler.running.values()
-                       if r.phase == "decoding")
+        self._steps = step
+        if ahead:
+            self.counters["steps_dispatched_ahead"] += 1
+        timer = self.timers("inference/decode")
+        if not timer.running:
+            timer.start()
         t_dispatch = time.perf_counter()
         with self.tracer.timed("inference/mixed_step", step=step,
                                prefill_tokens=n_valid,
-                               active_slots=decoding):
-            self._pool, first, toks, valid = self._mixed(
+                               active_slots=len(rows)):
+            self._pool, first, toks, valid, snap = self._mixed(
                 self._params, self._adapter, self.config.chunk_size,
                 self._spec,
                 self._pool, ids_d, slot_d,
                 frontier_d, n_valid_d, p_done_d,
                 p_spec_d, max_new_d, eos_d,
                 temp_d, top_k_d, seed_d)
+        flight = _Flight(step, pf, slot, n_valid, p_done, rows,
+                         (first, toks, valid, snap),
+                         time.perf_counter() - t_dispatch)
+        # What the dispatched step does to the host's records is the
+        # host's own arithmetic, so the next step can be scheduled on it
+        # before this one is harvested: the cursor moves by the slice, a
+        # prompt's last slice makes its request a row of THIS step's
+        # decode lane (first token sent), and without speculation every
+        # row emits ``chunk_size`` tokens or what is left of its budget.
+        if pf is not None and sched.advance_prefill(pf, n_valid):
+            pf.sent = 1
+            rows[slot] = pf
+        if self._spec is None:
+            for req in rows.values():
+                req.sent = min(req.sent + self.config.chunk_size,
+                               req.max_new_tokens)
+                if self._depth and req.sent >= req.max_new_tokens:
+                    # Its budget runs out inside this step, EOS or not: on
+                    # the chip the slot is inactive when the step ends, so
+                    # the next step's admission may have it at once.
+                    self._release(req)
+        return flight
+
+    def _release(self, req):
+        """Free the slot and the pages of a request whose last tokens are
+        on the chip (``Scheduler.release``); ``_harvest_step`` completes it
+        from the flight's own rows."""
+        slot = req.slot
+        self._scheduler.release(req)
+        if self._pager is not None:
+            self._free_slot_pages(slot, req.rid)
+
+    def _harvest_step(self, flight, done):
+        """``inference/harvest`` and ``inference/deliver`` of a dispatched
+        step: block until its tokens are on the host, then hand them to the
+        requests THE FLIGHT names (the scheduler may have moved on by a
+        step: a slot released at dispatch can hold its next request
+        already). A request cancelled, or ended by EOS, since the dispatch
+        is ``done``: the step's tokens for it are dropped."""
+        step = flight.step
+        first, toks, valid, snap = flight.outputs
         t_harvest = time.perf_counter()
         # ONE batched host sync per step: tokens, validity, the per-slot
         # scalar snapshot (pos/active/last_tok in a single transfer) and
@@ -1883,21 +2058,27 @@ class InferenceEngine(object):
         with self.tracer.timed("inference/harvest", step=step):
             toks = np.asarray(toks)
             valid = np.asarray(valid)
-            snap = harvest_snapshot(self._pool)
+            snap = harvest_snapshot(snap)
         if self._xray is not None:
             # The split of a step that the two spans around dispatch and
             # harvest give on every step for nothing (the harvest blocks
             # anyway): host dispatch against device wait, and the
             # roofline gauges' measured step seconds. No sync of its own.
             self._xray.observe_step(
-                "mixed_step", t_harvest - t_dispatch,
+                "mixed_step", flight.dispatch_s,
                 time.perf_counter() - t_harvest)
         with self.tracer.timed("inference/deliver", step=step):
             self._last_snap = snap
             active = snap["active"]
             # Adapter gauges off the same host snapshot — no extra sync.
             self._adapter.observe(snap, self.telemetry)
-            self.timers("inference/decode").stop()
+            # One interval a device step: from the harvest before (or the
+            # dispatch, after a lull) to this one.
+            timer = self.timers("inference/decode")
+            timer.stop()
+            if self._flight is not None:
+                timer.start()
+            tok_before = self.counters["tokens_out"]
             if self._injector is not None:
                 toks = self._injector.corrupt_harvest(toks, valid)
             # Numerics gate: AFTER the device sync, BEFORE any token reaches
@@ -1929,21 +2110,23 @@ class InferenceEngine(object):
                         drafted=n_occ * self.config.spec_k,
                         accepted=int(valid.sum()))
 
+            pf = flight.pf
             if pf is not None:
-                self.counters["prefill_tokens"] += n_valid
-                if self._scheduler.advance_prefill(pf, n_valid):
+                self.counters["prefill_tokens"] += flight.n_valid
+                if flight.p_done and not pf.done:
                     self.counters["prefills"] += 1
                     if self._hier is not None:
                         # The slot's plane now holds the full prompt's k/v —
                         # publish a missed prefix into the shared store
                         # (eager copy; no compile).
                         self._pool = self._hier.on_prefill_done(self._pool, pf)
+                    self._scheduler.prefill_done(pf, flight.lane_slot)
                     self._harvest_first(pf, int(first), done)
 
             harvest_t = time.time()
-            for slot, req in list(self._scheduler.running.items()):
-                if req.phase != "decoding":
-                    continue  # mid-prefill slots emit nothing
+            for slot, req in flight.rows.items():
+                if req.done:
+                    continue  # cancelled, or ended by EOS a step ago
                 # Boolean-mask select flattens row-major — (step, lane) IS
                 # emission order.
                 emitted = toks[:, slot][valid[:, slot]].tolist()
@@ -1977,14 +2160,15 @@ class InferenceEngine(object):
                                 tokens=self.counters["tokens_out"]
                                 - tok_before)
             self._observe_compiles()
-        return done
 
     @property
     def idle(self):
-        """True when no request is queued or in a slot — the drive
-        loops (run(), the sustained-load runner) poll this instead of
-        reaching into the scheduler."""
-        return self._scheduler.idle
+        """True when no request is queued or in a slot AND no step is in
+        flight — the drive loops (run(), the sustained-load runner) poll
+        this instead of reaching into the scheduler, so they step once
+        more for a step still on the chip (every request it served ended
+        a step ago, by EOS or a cancel: its harvest delivers nothing)."""
+        return self._scheduler.idle and self._flight is None
 
     def run(self, max_steps=None, timeout_s=None):
         """Drive step() until queue and slots drain; returns completed
@@ -1996,7 +2180,7 @@ class InferenceEngine(object):
         out = []
         steps = 0
         t0 = time.time()
-        while not self._scheduler.idle:
+        while not self.idle:
             out.extend(self.step())
             steps += 1
             if max_steps is not None and steps >= max_steps:
@@ -2045,10 +2229,20 @@ class InferenceEngine(object):
         self._health.to("draining")
 
     def close(self):
-        """Release host-side resources: stop any armed watchdog timer.
-        Idempotent; the engine object stays readable (metrics, completed
-        requests) but must not step again. Device buffers are freed by
-        GC as usual — there is nothing to close on that side."""
+        """Release host-side resources: harvest the step in flight (its
+        tokens reach their handles; nothing new is dispatched) and stop
+        any armed watchdog timer. Idempotent; the engine object stays
+        readable (metrics, completed requests) but must not step again.
+        Device buffers are freed by GC as usual — there is nothing to
+        close on that side."""
+        flight, self._flight = self._flight, None
+        if flight is not None and self._health.state != "dead":
+            try:
+                self._harvest_step(flight, [])
+            except self._fatal as exc:
+                logger.warning(
+                    "inference.close: the step in flight failed (%s: %s); "
+                    "its tokens are dropped", type(exc).__name__, exc)
         self._watchdog.stop()
 
     def generate(self, prompts, **kw):
@@ -2112,6 +2306,12 @@ class InferenceEngine(object):
             "prefills": c.window("prefills"),
             "prefill_tokens": c.window("prefill_tokens"),
             "chunks": c.window("chunks"),
+            # Steps dispatched while the one before was unharvested, and
+            # their share of the window's steps (_step_once).
+            "steps_dispatched_ahead": c.window("steps_dispatched_ahead"),
+            "steps_ahead_share": min(
+                c.window("steps_dispatched_ahead")
+                / float(max(c.window("chunks"), 1)), 1.0),
             "tokens_per_sec": c.window("tokens_out") / wall,
             "slot_occupancy": (c.window("occupied_slot_steps") /
                                max(c.window("slot_steps"), 1)),

@@ -294,6 +294,18 @@ def slot_state_nbytes(gcfg):
                for _, shape, dtype in getattr(gcfg, "slot_state", ()))
 
 
+def snapshot_of(pool):
+    """The leaves of ``pool`` a harvest reads: every per-slot scalar the
+    host loop needs (``pos`` / ``active`` / ``last_tok``) and the adapter's
+    ``aux_`` accumulators. The serving step returns this beside its tokens
+    as outputs of their own (a few hundred bytes that are NOT donated on),
+    so they outlive the pool: the next step is dispatched, its pool
+    donated, before this step is harvested."""
+    names = ["pos", "active", "last_tok"]
+    names += [n for n in pool if n.startswith("aux_")]
+    return {n: pool[n] for n in names}
+
+
 def harvest_snapshot(pool):
     """ONE batched device->host transfer of every per-slot scalar the
     host loop reads at a harvest boundary: ``pos`` / ``active`` /
@@ -302,13 +314,15 @@ def harvest_snapshot(pool):
     paying its own sync (three round-trips per chunk collapse to one).
     Adapter ``aux_`` state (global accumulators, not per-slot) rides the
     same transfer so ``ModelAdapter.observe`` never pays its own sync.
-    The snapshot is a plain dict of numpy arrays — valid until the next
-    program call moves the pool."""
+    ``pool`` is a pool or the ``snapshot_of`` a step handed out. Read off a
+    POOL the leaves are valid until the next program call moves the pool
+    (it is donated), so the engine, which dispatches the next step first,
+    harvests the step's own ``snapshot_of`` outputs: those stay valid
+    until they are dropped. The result is a plain dict of numpy arrays."""
     import numpy as np
-    names = ["pos", "active", "last_tok"]
-    names += [n for n in pool if n.startswith("aux_")]
-    vals = jax.device_get([pool[n] for n in names])
-    return {n: np.asarray(v) for n, v in zip(names, vals)}
+    snap = snapshot_of(pool)
+    vals = jax.device_get(list(snap.values()))
+    return {n: np.asarray(v) for n, v in zip(snap, vals)}
 
 
 def max_active_frontier(pool, snap=None):

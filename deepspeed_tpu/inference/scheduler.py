@@ -26,6 +26,13 @@ expiry is QUEUE-side only, and a handoff was admitted long ago
 ("admitted work always finishes"); cancel() reaches it like any live
 phase.
 
+The engine keeps one step in flight (``InferenceEngine._step_once``), so
+the records here run AHEAD of the tokens harvested by what the host can
+know by arithmetic: ``cursor`` and ``sent`` move when a step is dispatched,
+and a request whose budget ends inside the step just dispatched gives up
+its slot at once (``release``): still ``decoding``, slotless, it waits in
+``landing`` for the harvest that completes it.
+
 Recovery (docs/RESILIENCE.md) adds one extra move: after a fatal step
 error the engine calls ``requeue_running()`` — every in-flight request
 returns to the FRONT of the queue in rid (= admission) order, to be
@@ -106,7 +113,7 @@ class Request(object):
                  "eos_token_id", "seed", "spec", "tokens", "slot", "phase",
                  "cursor", "submit_time", "admit_time", "first_token_time",
                  "finish_time", "deadline", "replays", "last_touch",
-                 "priority", "tenant", "trace")
+                 "priority", "tenant", "trace", "sent")
 
     def __init__(self, rid, prompt, max_new_tokens, temperature, top_k,
                  eos_token_id, seed, spec=False, deadline=None,
@@ -135,9 +142,16 @@ class Request(object):
         self.tokens = []
         self.slot = None
         self.phase = "queued"
-        # Prompt tokens consumed so far (chunked prefill walks this to
-        # len(prompt)).
+        # Prompt tokens DISPATCHED so far (chunked prefill walks this to
+        # len(prompt)); the engine moves it when it dispatches a slice, by
+        # a count it chose itself, not when the slice's step is harvested.
         self.cursor = 0
+        # Tokens of ``max_new_tokens`` that the steps dispatched for this
+        # admission will have emitted once they are all harvested, an end
+        # by EOS aside: ``len(tokens)`` lags it by the step in flight.
+        # Arithmetic on the budget, so it is kept only where a step's
+        # emission count is the host's to know (no speculation).
+        self.sent = 0
         self.submit_time = time.time()
         self.admit_time = None
         self.first_token_time = None
@@ -210,6 +224,11 @@ class Scheduler(object):
         # scheduler's responsibility (``idle`` counts it) until
         # finish_handoff hands the durable truth to the new owner.
         self.handoff = {}
+        # rid -> Request whose budget runs out inside a step that is
+        # dispatched and not yet harvested (``release``): slotless, phase
+        # still ``decoding``, its last tokens on the chip. ``idle`` counts
+        # it; ``complete`` or ``cancel`` ends it, recovery requeues it.
+        self.landing = {}
         self.completed = {}         # rid -> Request (incl. cancelled)
         self._ids = itertools.count()
         # Telemetry is strictly additive: tracer gets lifecycle spans,
@@ -365,6 +384,7 @@ class Scheduler(object):
             req.slot = slot
             req.phase = "prefilling"
             req.cursor = 0
+            req.sent = 0
             self.running[slot] = req
             pairs.append((req, slot))
             if not first_admission:
@@ -393,19 +413,25 @@ class Scheduler(object):
         return min(pf, key=lambda r: r.rid) if pf else None
 
     def advance_prefill(self, req, n):
-        """Record ``n`` prompt tokens consumed; returns True when the
-        prompt is exhausted (the request's first token was sampled this
-        step and it moves to ``decoding``)."""
+        """Record ``n`` prompt tokens DISPATCHED; returns True when the
+        prompt is exhausted (the step just dispatched samples the
+        request's first token and it moves to ``decoding``: it decodes in
+        that step's lane already). ``prefill_done`` closes the phase's
+        span when that step is harvested."""
         req.cursor += n
         if req.cursor >= req.prompt.size:
             req.phase = "decoding"
-            if self.tracer is not None:
-                self.tracer.span("request/prefill", req.admit_time,
-                                 tid=req.trace.tid, rid=req.rid,
-                                 hop=req.trace.hop(), slot=req.slot,
-                                 prompt_tokens=int(req.prompt.size))
             return True
         return False
+
+    def prefill_done(self, req, slot):
+        """The step that held the prompt's last slice was harvested: the
+        ``request/prefill`` span ends here, at the first token."""
+        if self.tracer is not None:
+            self.tracer.span("request/prefill", req.admit_time,
+                             tid=req.trace.tid, rid=req.rid,
+                             hop=req.trace.hop(), slot=slot,
+                             prompt_tokens=int(req.prompt.size))
 
     # ------------------------------------------------------ host offload
 
@@ -520,10 +546,24 @@ class Scheduler(object):
 
     # -------------------------------------------------------- completion
 
-    def complete(self, slot):
-        """Evict ``slot``: its request is finished, the slot is free for
-        the next admission round."""
-        req = self.running.pop(slot)
+    def release(self, req):
+        """Free ``req``'s slot for the next admission round BEFORE its last
+        tokens are harvested: the engine calls this right after it
+        dispatched the step inside which the request's budget runs out, so
+        the slot is certain to be inactive on the chip when that step ends
+        and the next step may prefill another request into it. The request
+        waits in ``landing`` for ``complete``."""
+        self.running.pop(req.slot)
+        req.slot = None
+        self.landing[req.rid] = req
+
+    def complete(self, req):
+        """``req`` is finished: its slot, unless ``release`` freed it
+        already, is free for the next admission round."""
+        if req.slot is None:
+            self.landing.pop(req.rid)
+        else:
+            self.running.pop(req.slot)
         req.finish_time = time.time()
         req.phase = "done"
         req.slot = None
@@ -570,6 +610,8 @@ class Scheduler(object):
             # placement commit re-checks the phase under the fleet lock
             # and aborts on the adopted copy (fleet._pump_handoffs).
             self.handoff.pop(req.rid, None)
+        elif req.slot is None:
+            self.landing.pop(req.rid)  # released: slot and pages are free
         else:
             self.running.pop(req.slot)
             req.slot = None
@@ -606,11 +648,15 @@ class Scheduler(object):
         HANDOFF requests deliberately stay put: their device state was
         already captured to host records that survive the pool rebuild
         untouched — the fleet's pump migrates or falls back regardless
-        of what happens to this replica's pool."""
+        of what happens to this replica's pool. Requests in ``landing``
+        requeue like running ones: the tokens they waited for were on the
+        pool that died."""
         reqs = sorted(list(self.running.values())
-                      + list(self.swapped.values()), key=lambda r: r.rid)
+                      + list(self.swapped.values())
+                      + list(self.landing.values()), key=lambda r: r.rid)
         self.running.clear()
         self.swapped.clear()
+        self.landing.clear()
         for req in reversed(reqs):
             req.slot = None
             req.phase = "queued"
@@ -626,8 +672,8 @@ class Scheduler(object):
 
     @property
     def idle(self):
-        return (not self.queue and not self.running
-                and not self.swapped and not self.handoff)
+        return (not self.queue and not self.running and not self.swapped
+                and not self.handoff and not self.landing)
 
     def occupancy(self):
         return len(self.running) / float(self.num_slots)

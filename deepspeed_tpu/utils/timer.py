@@ -53,6 +53,10 @@ class _Interval:
         self._t0 = None  # None <=> not running
         self.histogram = histogram
 
+    @property
+    def running(self):
+        return self._t0 is not None
+
     def start(self):
         if self._t0 is not None:
             raise RuntimeError("timer {!r} already started".format(self.name))

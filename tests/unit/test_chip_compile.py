@@ -401,7 +401,9 @@ def _arena_shaped(lines, shapes):
 
 def _mixed_step_text(chip, adapter, params, pool, chunk, lane):
     """Optimised HLO of the engine's mixed step, compiled for the described
-    chip from shapes alone."""
+    chip from shapes alone: ONE program, whose outputs beside the donated
+    pool include the harvest's snapshot (``kv_pool.snapshot_of``: the engine
+    reads it after the next step has taken the pool)."""
     from deepspeed_tpu.inference import engine as engine_mod
 
     def on_chip(tree):
@@ -414,14 +416,23 @@ def _mixed_step_text(chip, adapter, params, pool, chunk, lane):
     def mixed_step(*args):
         return engine_mod._mixed_step_program(*args)
 
-    return jax.jit(mixed_step, static_argnums=(1, 2, 3), donate_argnums=(4,),
-                   compiler_options=engine_mod.step_compiler_options("tpu")
-                   ).lower(
+    lowered = jax.jit(
+        mixed_step, static_argnums=(1, 2, 3), donate_argnums=(4,),
+        compiler_options=engine_mod.step_compiler_options("tpu")).lower(
         on_chip(params), adapter, chunk, None, on_chip(pool),
         jax.ShapeDtypeStruct((1, lane), I32, sharding=chip),
         scalar(I32), scalar(I32), scalar(I32), scalar(jnp.bool_),
         scalar(jnp.bool_), scalar(I32), scalar(I32), scalar(F32),
-        scalar(I32), scalar(jnp.uint32)).compile().as_text()
+        scalar(I32), scalar(jnp.uint32))
+    new_pool, _, _, _, snap = lowered.out_info
+    slots = pool["pos"].shape
+    assert set(snap) == {"pos", "active", "last_tok"} | {
+        name for name in pool if name.startswith("aux_")}
+    assert all(snap[name].shape == new_pool[name].shape for name in snap)
+    assert snap["pos"].shape == snap["active"].shape == slots
+    text = lowered.compile().as_text()
+    assert len(re.findall(r"^ENTRY ", text, re.M)) == 1
+    return text
 
 
 def _scan_lines(comps):

@@ -248,8 +248,8 @@ def test_cancel_edge_cases_boundary_double_and_after_complete():
     exact, short = prompts_of(cfg, [12, 6])    # 12 = 3 exact chunks
     a = eng.submit(exact, max_new_tokens=4)
     b = eng.submit(short, max_new_tokens=5)
-    eng.step()
-    assert a.phase == "prefilling" and a.cursor == 4   # exact boundary
+    eng.step()      # two slices dispatched, the second still in flight
+    assert a.phase == "prefilling" and a.cursor == 8   # exact boundary
     assert eng.cancel(a) is True
     assert eng.cancel(a) is False              # double-cancel: idempotent
     assert a.phase == "cancelled" and a.slot is None and a.tokens == []

@@ -255,20 +255,18 @@ def test_fleet_failover_autopsy_threaded_fleet():
     fleet = fleet_of(model, params, start=True, fault_injection=True,
                      recovery_max_retries=0)
     try:
-        frs = [fleet.submit(p, max_new_tokens=16) for p in prompts]
-        deadline_ok = False
-        for _ in range(4000):
-            if any(fr.replica_id == 0 and fr.tokens and not fr.done
-                   for fr in frs):
-                deadline_ok = True
-                break
-            time.sleep(0.001)
-        assert deadline_ok, "replica 0 never reached mid-stream"
-        fleet.inject_faults(FaultPlan(faults=(Fault("raise", step=0),)),
+        # Armed before the traffic, for replica 0's third step() call: by
+        # then its first requests have tokens on their handles and a dozen
+        # steps to go. (Arming from this thread once tokens show races the
+        # replica's thread for its lock, and the engine, which keeps a step
+        # in flight, can be through sixteen tokens before the lock is won.)
+        fleet.inject_faults(FaultPlan(faults=(Fault("raise", step=2),)),
                             replica=0)
+        frs = [fleet.submit(p, max_new_tokens=48) for p in prompts]
         assert fleet.wait_idle(timeout_s=120.0)
         moved = [fr for fr in frs if fr.failovers > 0]
         assert moved
+        assert any(fr._prior for fr in moved), "no failover was mid-stream"
         for fr in moved:
             a = fleet.explain(fr)
             hops = _hops_of(a)

@@ -81,17 +81,17 @@ def test_scheduler_fifo_admission_and_eviction():
     assert [r.rid for r in s.queue] == [2, 3]
     assert s.admissions() == []  # no free slots mid-flight
     # Evicting slot 0 frees exactly that slot for the next queued request.
-    s.complete(0)
+    s.complete(reqs[0])
     assert reqs[0].done and reqs[0].slot is None
     pairs = s.admissions()
     assert [(r.rid, slot) for r, slot in pairs] == [(2, 0)]
     assert s.occupancy() == 1.0
-    for slot in list(s.running):
-        s.complete(slot)
+    for req in list(s.running.values()):
+        s.complete(req)
     assert not s.idle  # rid 3 still queued
     pairs = s.admissions()
     assert [r.rid for r, _ in pairs] == [3]
-    s.complete(pairs[0][1])
+    s.complete(pairs[0][0])
     assert s.idle
 
 
@@ -207,22 +207,24 @@ def test_metrics_reads_live_gauges_and_engine_idle():
     public engine.idle mirrors the scheduler (the sustained-load runner
     polls it instead of reaching into _scheduler)."""
     cfg, model, params = make_model()
-    eng = engine_of(model, params, max_slots=2, max_queue=8)
+    eng = engine_of(model, params, max_slots=3, max_queue=8)
     assert eng.idle
-    ps = prompts_of(cfg, [5, 6, 7, 8])
+    ps = prompts_of(cfg, [5, 6, 7, 8, 9])
     for p in ps:
         # Budget long enough that nothing completes within the first
-        # mixed step (prefill emits 1 + one decode chunk).
-        eng.submit(p, max_new_tokens=12)
+        # mixed steps (prefill emits 1 + one decode chunk).
+        eng.submit(p, max_new_tokens=20)
     assert not eng.idle
     m = eng.metrics()
-    # 4 submitted, 0 admitted yet: all queued, nothing prefilling.
-    assert m["queue_depth"] == 4
+    # 5 submitted, 0 admitted yet: all queued, nothing prefilling.
+    assert m["queue_depth"] == 5
     assert m["slot_occupancy_now"] == 0.0 and m["slots_prefilling"] == 0
-    eng.step()  # admits into both slots, first mixed step
+    # Admits into all three slots; the call finds nothing in flight, so it
+    # dispatches TWO mixed steps (one is kept in flight) and harvests one.
+    eng.step()
     m = eng.metrics()
     assert m["queue_depth"] == 2 and m["slot_occupancy_now"] == 1.0
-    # One prefill lane per step: the second admitted request is still
+    # One prefill lane per step: the third admitted request is still
     # mid-prefill — visible on the live gauge.
     assert m["slots_prefilling"] == 1
     # The dict view and the Prometheus text can never disagree.

@@ -530,7 +530,11 @@ def test_capacity_pin_3x_long_context_sessions_at_fixed_hbm():
         peak = 0
         while not eng.idle:
             eng.step()
-            peak = max(peak, len(eng._scheduler.running))
+            # Sessions the pool serves at once: those in a slot and those
+            # whose last tokens are still on the chip, their slot given up
+            # when that step was dispatched.
+            peak = max(peak, len(eng._scheduler.running)
+                       + len(eng._scheduler.landing))
         return eng, reqs, peak
 
     # Dense baseline: 2 slots of 72-position plane = 144 KV positions.
@@ -567,9 +571,12 @@ def test_queue_full_pages_reason_and_retry_hint():
     eng = paged_engine_of(model, params, max_slots=4, max_queue=1,
                           kv_page_len=8, kv_pages=4)
     p = prompts_of(cfg, [8, 9, 10], seed=2)
-    eng.submit(p[0], max_new_tokens=8)
-    eng.step()                  # admit: reserves 3 of the 4 pages
-    eng.submit(p[1], max_new_tokens=8)          # queued head, needs 3 > 1
+    # A budget that outlasts the two steps the first call dispatches (one
+    # stays in flight): a request whose budget ends inside a dispatched
+    # step gives its pages back at once.
+    eng.submit(p[0], max_new_tokens=16)
+    eng.step()                  # admit: reserves all 4 pages
+    eng.submit(p[1], max_new_tokens=8)          # queued head, needs 4 > 0
     with pytest.raises(QueueFull) as ei:
         eng.submit(p[2], max_new_tokens=8)
     assert ei.value.reason == "pages"
