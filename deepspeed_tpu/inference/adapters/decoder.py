@@ -32,6 +32,16 @@ decoding (a rejected draft has already moved the state), the prefix cache
 no int8 form, and a pool quantised in part is not built). Host offload,
 preemption and handoff ship the state with the slot and resume it bit for
 bit.
+
+LATENT ATTENTION (``kv_lora_rank``; DeepSeek-V3's block) gives a cache that
+is not a k/v pair: ``cache_spec().latent`` says a token stores one head in
+one plane and no value plane (``kv_pool.py``, A LATENT CACHE), and
+``kv_latent_bytes_token`` publishes what a token holds over all layers. The
+stale-cache rule holds for it, so speculative decoding, preemption, host
+offload and handoff work as for any keys (tests/unit/test_mla.py); what
+``bind`` refuses by name is what has no one-plane form yet: int8 planes (a
+latent's 512 values and its rotary key want scales of their own) and the
+prefix cache (its records name a ``pk`` / ``pv`` pair).
 """
 
 import dataclasses
@@ -64,7 +74,28 @@ class DecoderAdapter(GPT2Adapter):
         """Does a row carry a state that has no position axis?"""
         return bool(self.gcfg.mamba_layers)
 
+    @property
+    def latent(self):
+        """Does a token cache one latent plane in place of keys and values?"""
+        return bool(self.gcfg.kv_lora_rank)
+
     def bind(self, config, mesh=None):
+        if config is not None and self.latent:
+            refused = (
+                ("int8 planes (int8_kv)", config.int8_kv,
+                 "the int8 tier quantises a k plane and a v plane a head, "
+                 "and a latent token is one plane whose compressed values "
+                 "and rotary key want scales of their own"),
+                ("the prefix cache (prefix_cache)", config.prefix_cache,
+                 "a shared prefix is stored and adopted as a pk / pv pair, "
+                 "and a latent pool has no value plane"))
+            for what, asked, why in refused:
+                if asked:
+                    raise ValueError(
+                        "{} cannot serve a latent-attention cache "
+                        "(kv_lora_rank {}, one plane of {} values a "
+                        "token): {}".format(what, self.gcfg.kv_lora_rank,
+                                            self.gcfg.latent_width, why))
         if config is not None and self.recurrent:
             refused = (
                 ("speculative decoding (spec_decode)",

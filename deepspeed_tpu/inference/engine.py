@@ -845,6 +845,12 @@ class InferenceEngine(object):
             pool = init_pool(self._gcfg, self.config.max_slots,
                              self.config.max_len, slack=self._slack,
                              hier=self._hier.spec if self._hier else None)
+        if getattr(self._gcfg, "latent", 0):
+            # A latent cache (kv_pool.py): bytes ONE token holds over all
+            # layers, read back from the one plane's shape.
+            k = pool["k"]
+            self.telemetry.gauge("kv_latent_bytes_token").set(
+                k.shape[0] * k.shape[2] * k.shape[4] * k.dtype.itemsize)
         aux = self._adapter.aux_state()
         if aux:
             # Adapter-owned pool state (``aux_`` keys): threaded through

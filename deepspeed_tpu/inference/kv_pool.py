@@ -131,6 +131,24 @@ hide garbage in, so every program that touches it masks instead
 advancing ``pos``, aliased prefixes) is refused for such a model
 (``adapters/decoder.py``).
 
+A LATENT CACHE. A model whose ``cache_spec()`` gives ``latent`` > 0 (latent
+attention: ``models/decoder.py`` ``mla``) stores ONE head a token,
+``n_embd`` wide (DeepSeek-V3: ``[c_kv (512) | k_r (64)]`` and zeros up to
+640, a whole number of lane tiles), whose first ``latent`` lanes are also
+the token's values: the pool holds the ``k`` plane ``[L, P, 1, page_len, W]``
+(dense: ``[L, slots, 1, plane_len, W]``) and NO ``v`` plane. Every view
+below names the planes the pool HAS (``_PLANES``), so the pager, the
+offload and handoff records (which walk the pool's keys) and the stale-cache
+rule carry over unchanged; ``kv_append`` appends to the one arena and the
+``latent_decode`` kernel reads it. Why 640 and not 576, or a 512 + 128 pair:
+a minor dim is stored and moved in whole 128-lane tiles, so 576 costs 640
+in HBM and in VMEM whatever the shape says, and left to itself XLA may keep
+such an arena page-length minor and convert it where a step enters and
+leaves (what PR 30 met at 64); a pair of planes would double the appends and
+the page fetches (a unit of the kernel is already smaller than its fixed
+cost) for the same bytes. The int8 and prefix tiers are refused for such a
+model (``adapters/decoder.py``).
+
 CRASH-ONLY: the pool is DISPOSABLE state (docs/RESILIENCE.md). The
 durable truth about every request lives host-side in the scheduler's
 records; on a fatal step error the engine throws the pool away and
@@ -223,6 +241,8 @@ def init_pool(gcfg, num_slots, max_len, dtype=None, slack=0, hier=None,
     hd = gcfg.n_embd // gcfg.n_head
     int8 = hier is not None and hier.int8
     kv_dtype = jnp.int8 if int8 else dtype
+    # module docstring, A LATENT CACHE: one plane, no ``v``
+    latent = bool(getattr(gcfg, "latent", 0))
     if page_len:
         plane_len = paged_plane_len(gcfg, max_len, slack, page_len)
         n_lp = plane_len // page_len
@@ -234,9 +254,10 @@ def init_pool(gcfg, num_slots, max_len, dtype=None, slack=0, hier=None,
         hp = -(-gcfg.n_head // g)
         kv_shape = (gcfg.n_layer, P, hp, page_len, g * hd)
         pool = {"k": jnp.zeros(kv_shape, kv_dtype),
-                "v": jnp.zeros(kv_shape, kv_dtype),
                 "block_tbl": jnp.zeros((num_slots, n_lp), jnp.int32),
                 "toks": jnp.zeros((num_slots, plane_len), jnp.int32)}
+        if not latent:
+            pool["v"] = jnp.zeros(kv_shape, kv_dtype)
         if int8:
             sc_shape = (gcfg.n_layer, P, hp * g, page_len)
             pool["k_scale"] = jnp.zeros(sc_shape, jnp.float32)
@@ -249,11 +270,12 @@ def init_pool(gcfg, num_slots, max_len, dtype=None, slack=0, hier=None,
         assert decode_attention.decode_supported(plane_len), plane_len
     kv_shape = (gcfg.n_layer, num_slots, gcfg.n_head, plane_len, hd)
     pool = {"k": jnp.zeros(kv_shape, kv_dtype),
-            "v": jnp.zeros(kv_shape, kv_dtype),
             # Token ring for n-gram self-drafting (module docstring) —
             # same length as the planes so ring writes share the slack
             # bound; int32 [slots, plane_len] is noise next to the k/v.
             "toks": jnp.zeros((num_slots, plane_len), jnp.int32)}
+    if not latent:
+        pool["v"] = jnp.zeros(kv_shape, kv_dtype)
     if int8:
         sc_shape = kv_shape[:-1]
         pool["k_scale"] = jnp.zeros(sc_shape, jnp.float32)
@@ -284,6 +306,15 @@ def _with_slot_state(pool, gcfg, num_slots):
 
 def _slot_state(tree):
     return [name for name in tree if name.startswith("slot_")]
+
+
+# The planes a pool may hold, in the order the views name them: a latent
+# cache has no ``v``, only the int8 tier has scales.
+_PLANES = ("k", "v", "k_scale", "v_scale")
+
+
+def _planes(pool):
+    return {name: pool[name] for name in _PLANES if name in pool}
 
 
 def slot_state_nbytes(gcfg):
@@ -369,12 +400,9 @@ def cache_view(pool):
     arenas STAY whole all the way down: ``kv_append`` writes the frontier
     rows in place and the decode kernel indexes the layer itself, so the
     buffer this view names is the buffer ``fold_cache`` gets back."""
-    cache = {"k": pool["k"], "v": pool["v"], "pos": pool["pos"]}
+    cache = dict(_planes(pool), pos=pool["pos"])
     if "block_tbl" in pool:
         cache["block_tbl"] = pool["block_tbl"]
-    if "k_scale" in pool:
-        cache["k_scale"] = pool["k_scale"]
-        cache["v_scale"] = pool["v_scale"]
     if "pid" in pool:
         row = jnp.clip(pool["pid"], 0, pool["pk"].shape[1] - 1)
         cache["pk"] = jnp.take(pool["pk"], row, axis=1)
@@ -412,24 +440,16 @@ def slot_cache_view(pool, slot, pos):
     PAGED pools carry the arenas whole (the scatter/gather indirection
     replaces the slot slice) with the one slot's block-table row."""
     if "block_tbl" in pool:
-        cache = {"k": pool["k"], "v": pool["v"], "pos": pos,
-                 "block_tbl": jax.lax.dynamic_slice_in_dim(
-                     pool["block_tbl"], slot, 1, axis=0)}
-        if "k_scale" in pool:
-            cache["k_scale"] = pool["k_scale"]
-            cache["v_scale"] = pool["v_scale"]
+        cache = dict(_planes(pool), pos=pos,
+                     block_tbl=jax.lax.dynamic_slice_in_dim(
+                         pool["block_tbl"], slot, 1, axis=0))
         for name in pool:
             if name.startswith("aux_"):
                 cache[name] = pool[name]
         return dict(cache, **_slot_state_view(pool, slot))
-    cache = {"k": jax.lax.dynamic_slice_in_dim(pool["k"], slot, 1, axis=1),
-             "v": jax.lax.dynamic_slice_in_dim(pool["v"], slot, 1, axis=1),
-             "pos": pos}
-    if "k_scale" in pool:
-        cache["k_scale"] = jax.lax.dynamic_slice_in_dim(
-            pool["k_scale"], slot, 1, axis=1)
-        cache["v_scale"] = jax.lax.dynamic_slice_in_dim(
-            pool["v_scale"], slot, 1, axis=1)
+    cache = {name: jax.lax.dynamic_slice_in_dim(plane, slot, 1, axis=1)
+             for name, plane in _planes(pool).items()}
+    cache["pos"] = pos
     if "pid" in pool:
         row = jnp.clip(jax.lax.dynamic_index_in_dim(
             pool["pid"], slot, keepdims=False), 0, pool["pk"].shape[1] - 1)
@@ -466,18 +486,16 @@ def write_slot_cache(pool, slot, cache):
     (inference/paging.py) and the device only reads it."""
     if "block_tbl" in pool:
         pool = dict(pool)
-        for name in ("k", "v", "k_scale", "v_scale"):
-            if name in pool:
-                pool[name] = cache[name]
+        for name in _planes(pool):
+            pool[name] = cache[name]
         for name in cache:
             if name.startswith("aux_"):
                 pool[name] = cache[name]
         return _write_slot_state(pool, slot, cache)
     pool = dict(pool)
-    for name in ("k", "v", "k_scale", "v_scale"):
-        if name in pool:
-            pool[name] = jax.lax.dynamic_update_slice_in_dim(
-                pool[name], cache[name], slot, axis=1)
+    for name in _planes(pool):
+        pool[name] = jax.lax.dynamic_update_slice_in_dim(
+            pool[name], cache[name], slot, axis=1)
     for name in cache:
         # Global aux accumulators fold back whole (no slot indexing).
         if name.startswith("aux_"):
@@ -499,10 +517,7 @@ def fold_cache(pool, cache):
     """Fold a full-batch ``cache_view`` cache back into the pool after a
     decode/verify step: k/v planes and scale planes. The gathered
     ``pk``/``pv`` views are DERIVED state and never fold back."""
-    upd = {"k": cache["k"], "v": cache["v"]}
-    if "k_scale" in pool:
-        upd["k_scale"] = cache["k_scale"]
-        upd["v_scale"] = cache["v_scale"]
+    upd = {name: cache[name] for name in _planes(pool)}
     for name in cache:
         if name.startswith(("aux_", "slot_")):
             upd[name] = cache[name]

@@ -21,6 +21,32 @@ and Granite's multipliers (on the embedding, on every residual branch, under
 the logits). One path, not two: every piece is one static branch inside
 ``forward``, so what OLMoE runs is what it ran.
 
+The same block again is DeepSeek-V3's (``model_type`` ``deepseek_v3``; R1
+and V3.1 share it), by four more fields that are off by default:
+``kv_lora_rank`` makes the mixer LATENT ATTENTION (``mla``): queries through
+a low-rank bottleneck, keys and values through ONE compressed latent a token
+plus one rotary key all heads share, so a token caches ``kv_lora_rank +
+qk_rope_dim`` values (576) in ONE plane and no value plane; ``rope_yarn``
+gives the rotary frequencies YaRN's ramp; ``dense_layers`` leading layers
+take a dense gated feed-forward of ``dense_width`` in place of experts; and
+``router_scoring`` ``"sigmoid"`` routes by ``routed.route_grouped``.
+
+THE ABSORBED FORM IS THE ONE PATH of latent attention, for the lane and the
+scan alike. Per head ``[k_nope_h | v_h] = c_kv W_kvb,h``, so
+``q_nope_h . k_nope_h(u) = (q_nope_h W_uk,h) . c_kv(u)`` and
+``sum_u p(u) v_h(u) = (sum_u p(u) c_kv(u)) W_uv,h``: each query head is
+carried INTO the latent (``w_uk`` [H, rank, nope]), scored against what the
+cache holds, ``[c_kv | k_r]``, as one stored head of width 576 whose first
+512 lanes are also its values, and carried back out (``w_uv`` [H, v, rank]).
+Nothing per head is ever cached or re-expanded. The expanded form
+(materialised ``k_nope`` and ``v``) is what the plain reference computes;
+``tests/unit/test_mla.py`` holds the two equal. The parameters hold
+``kv_b_proj`` as the two stacks ``w_uk`` / ``w_uv`` (the same numbers), and
+the rotary columns of ``wq_rope`` and ``wkv_a`` in HALVES order: the published
+checkpoint interleaves a pair's two lanes and its code de-interleaves every
+activation before rotating halves, which a loader does once, to the columns
+(a common permutation of q's and k's lanes changes no score).
+
 Like ``models/generation.py`` for GPT-2 this is a pure-functional program over
 a parameter tree, one ``forward`` for prefill, chunked prefill, decode and
 verify: rows sit at their own frontiers ``cache['pos']``, rotary angles come
@@ -43,12 +69,27 @@ layer has (the two norms, the router, the held experts ``[L, E_held, ..]``,
 ``shared_gate_up [L, C, 2Fs]`` / ``shared_down [L, Fs, C]``) and stacks each
 kind of mixer over the layers of that kind: ``attn/wqkv [La, C, (H + 2 Hkv) D]``
 (+ the QK norms), ``attn/wo``; ``mamba/...`` [Lm, ..] (``mamba2.init_layer``).
+A latent-attention stack (``kv_lora_rank`` given) keeps the two norms under
+``layers`` and stacks the rest by kind: ``mla/wq_a [L, C, Rq]``,
+``q_a_norm [L, Rq]``, ``wq_nope [L, H nope, Rq]``, ``wq_rope [L, H rope, Rq]``
+(``q_b_proj``'s nope and rotary columns of every head, ``[out, in]``),
+``wkv_a [L, C, R + rope]``, ``kv_a_norm [L, R]``, ``w_uk [L, H, R, nope]``, ``w_uv [L, H, v, R]``,
+``wo [L, H v, C]`` (the four stacks the absorbed form contracts a head at
+a time lie ``[out, in]``, contraction minor, as the step's matmuls read
+them: laid ``[in, out]`` the compiler transposes each whole stack every
+step, 0.9 GB of temporaries at DeepSeek-V3's widths; found by compiling for
+a described v5e); ``dense/w_gate_up [Ld, C, 2 Fd]``, ``w_down [Ld, Fd, C]``
+for the leading dense layers; ``moe/...`` [L - Ld, ..] what ``layers`` holds
+of an expert layer elsewhere, and ``router_bias [L - Ld, E]`` float32.
 
 The regions of a trace (``jax.named_scope``, under the caller's
 ``prefill_lane`` / ``decode_scan``): ``embed``; per layer ``attn`` (norm, qkv,
-``rope``, ``qk_norm``, attention, projection), ``kv_write``, ``kv_view``,
+``rope``, ``qk_norm``, attention, projection; latent attention: ``q_proj``,
+``kv_proj``, ``rope``, ``absorb`` (the two per-head products with
+``W_kvb``), ``o_proj``), ``kv_write``, ``kv_view``,
 or ``mamba`` (``mamba2.mixer``'s words); ``moe`` holding ``router``,
-``dispatch``, ``experts``, ``combine`` and ``shared``; then ``lm_head``.
+``dispatch``, ``experts``, ``combine`` and ``shared``, or ``mlp`` for a dense
+layer; then ``lm_head``.
 
 The layers are unrolled (a static ``layer=`` in the cache kernels' index
 maps), not scanned: eight of them compile in well under GPT-2's 24, and a
@@ -56,10 +97,12 @@ traced layer index would take a scalar-prefetch operand the kernels do not
 have (PERF.md section 6, PR 27).
 """
 
+import math
 import typing
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deepspeed_tpu.analysis.annotations import hot_path
 from deepspeed_tpu.models import generation, mamba2
@@ -107,6 +150,21 @@ class DecoderConfig(typing.NamedTuple):
     mamba_state: int = 0
     mamba_conv: int = 4
     mamba_chunk: int = 256
+    # latent attention (module docstring): 0 is attention by heads
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # (factor, original positions, beta_fast, beta_slow, mscale,
+    # mscale_all_dim) of YaRN's frequencies; None: theta ** (-2i / d)
+    rope_yarn: typing.Optional[typing.Tuple[float, ...]] = None
+    dense_layers: int = 0                      # leading layers, no experts
+    dense_width: int = 0
+    router_scoring: str = "softmax"            # | "sigmoid", group-limited
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling: float = 1.0
 
     @property
     def n_embd(self):
@@ -139,6 +197,27 @@ class DecoderConfig(typing.NamedTuple):
         return self.experts_held or (0, self.n_experts)
 
     @property
+    def softmax_scale(self):
+        """The softmax scale of latent attention: ``(nope + rope) ** -1/2``,
+        times YaRN's temperature squared (``mscale_all_dim``) where the
+        frequencies are YaRN's; ``attn_scale`` overrides."""
+        if self.attn_scale is not None:
+            return self.attn_scale
+        scale = float(self.qk_nope_dim + self.qk_rope_dim) ** -0.5
+        if self.rope_yarn is not None:
+            scale *= yarn_mscale(self.rope_yarn[0], self.rope_yarn[5]) ** 2
+        return scale
+
+    @property
+    def latent_width(self):
+        """Values a token STORES in a latent-attention layer, ``[c_kv | k_r]``
+        and zeros up to a whole number of 128-lane tiles (576 -> 640): the
+        chip stores and moves a minor dim in whole tiles whatever the shape
+        says (``kv_pool.py``, THE PAGED ARENA), so the pad costs no byte that
+        576 would save and the kernels get aligned blocks."""
+        return -(-(self.kv_lora_rank + self.qk_rope_dim) // 128) * 128
+
+    @property
     def layer_norm_epsilon(self):
         return self.rms_norm_eps
 
@@ -158,9 +237,14 @@ class CacheSpec(typing.NamedTuple):
     use_flash_decode: typing.Optional[bool]
     kv_page_len: int
     slot_state: tuple = ()
+    latent: int = 0
 
 
 def cache_spec(cfg):
+    if cfg.kv_lora_rank:
+        return CacheSpec(cfg.n_layer, 1, cfg.latent_width, cfg.n_positions,
+                         cfg.dtype, cfg.rms_norm_eps, cfg.use_flash_decode,
+                         cfg.kv_page_len, (), cfg.kv_lora_rank)
     return CacheSpec(len(cfg.kv_layers), cfg.n_kv, cfg.n_kv * cfg.head_dim,
                      cfg.n_positions, cfg.dtype, cfg.rms_norm_eps,
                      cfg.use_flash_decode, cfg.kv_page_len,
@@ -198,20 +282,53 @@ def init_params(key, cfg):
             out["k_norm"] = jnp.ones((kv_w,), cfg.dtype)
         return out
 
-    def layer(k):
+    def experts(k):
+        # the feed-forward half of a layer that has experts
         ks = jax.random.split(k, 5)
-        out = {"attn_norm": jnp.ones((c,), cfg.dtype),
-               "ffn_norm": jnp.ones((c,), cfg.dtype),
-               "router": normal(ks[2], (c, cfg.n_experts)),
+        out = {"router": normal(ks[2], (c, cfg.n_experts)),
                "w_gate_up": normal(ks[3], (e, c, 2 * f)),
                "w_down": normal(ks[4], (e, f, c))}
         if cfg.shared_width:
             k1, k2 = jax.random.split(jax.random.fold_in(k, 5))
             out["shared_gate_up"] = normal(k1, (c, 2 * cfg.shared_width))
             out["shared_down"] = normal(k2, (cfg.shared_width, c))
-        if cfg.layer_types is None:
+        if cfg.router_scoring == "sigmoid":
+            out["router_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
+        return out
+
+    def layer(k):
+        ks = jax.random.split(k, 5)
+        out = {"attn_norm": jnp.ones((c,), cfg.dtype),
+               "ffn_norm": jnp.ones((c,), cfg.dtype)}
+        if not cfg.dense_layers:
+            out.update(experts(k))
+        if cfg.layer_types is None and not cfg.kv_lora_rank:
             out.update(attention(ks[0], ks[1]))
         return out
+
+    def latent(k):
+        ks = jax.random.split(k, 6)
+        nh, r, rq = cfg.n_head, cfg.kv_lora_rank, cfg.q_lora_rank
+        dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        return {"wq_a": normal(ks[0], (c, rq)),
+                "q_a_norm": jnp.ones((rq,), cfg.dtype),
+                "wq_nope": normal(ks[1], (nh * dn, rq)),
+                "wq_rope": normal(jax.random.fold_in(ks[1], 1),
+                                  (nh * dr, rq)),
+                "wkv_a": normal(ks[2], (c, r + dr)),
+                "kv_a_norm": jnp.ones((r,), cfg.dtype),
+                "w_uk": normal(ks[3], (nh, r, dn)),
+                "w_uv": normal(ks[4], (nh, dv, r)),
+                "wo": normal(ks[5], (nh * dv, c))}
+
+    def dense(k):
+        k1, k2 = jax.random.split(k)
+        return {"w_gate_up": normal(k1, (c, 2 * cfg.dense_width)),
+                "w_down": normal(k2, (cfg.dense_width, c))}
+
+    def stacked(make, salt, n):
+        return jax.lax.map(make, jax.random.split(
+            jax.random.fold_in(key, salt), n))
 
     params = {"embed": normal(k_embed, (cfg.vocab_size, c)),
               "layers": jax.lax.map(layer,
@@ -225,6 +342,11 @@ def init_params(key, cfg):
             params["mamba"] = jax.lax.map(
                 lambda k: mamba2.init_layer(k, cfg), jax.random.split(
                     jax.random.fold_in(key, 4), len(cfg.mamba_layers)))
+    if cfg.kv_lora_rank:
+        params["mla"] = stacked(latent, 6, cfg.n_layer)
+    if cfg.dense_layers:
+        params["dense"] = stacked(dense, 7, cfg.dense_layers)
+        params["moe"] = stacked(experts, 8, cfg.n_layer - cfg.dense_layers)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = normal(k_head, (c, cfg.vocab_size))
     return params
@@ -237,13 +359,48 @@ def _rms32(x, scale, eps):
     return y * scale.astype(jnp.float32)
 
 
-def rope_angles(positions, head_dim, theta):
+def yarn_inv_freq(dim, theta, factor, original, beta_fast, beta_slow):
+    """YaRN's frequencies [dim / 2] (numpy float64 -> float32, static): pair
+    ``i`` turns at ``f_i = theta ** (-2i / dim)`` where it makes more than
+    ``beta_fast`` turns over the ``original`` positions, at ``f_i / factor``
+    where it makes fewer than ``beta_slow``, and on a linear ramp between
+    the two pair indices ``low = floor(d(beta_fast))`` and ``high =
+    ceil(d(beta_slow))``, ``d(r) = dim ln(original / (2 pi r)) /
+    (2 ln theta)``."""
+    def pair_of(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    f = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 0.001), 0.0, 1.0)
+    return (f * (1.0 - ramp) + f / factor * ramp).astype(np.float32)
+
+
+def yarn_mscale(factor, mscale):
+    """YaRN's attention temperature ``0.1 mscale ln(factor) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_angles(positions, head_dim, theta, yarn=None):
     """cos, sin ``[B, S, 1, head_dim / 2]`` float32 of ``positions`` [B, S]:
-    frequency ``theta ** (-2i / head_dim)`` for pair i."""
-    inv_freq = 1.0 / theta ** (
-        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+    frequency ``theta ** (-2i / head_dim)`` for pair i, or YaRN's
+    (``yarn``: ``DecoderConfig.rope_yarn``), whose cos and sin also carry
+    ``mscale / mscale_all_dim`` (1 as published for DeepSeek-V3)."""
+    if yarn is None:
+        inv_freq = 1.0 / theta ** (
+            jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+        mult = 1.0
+    else:
+        factor, original, fast, slow, mscale, mscale_all = yarn
+        inv_freq = jnp.asarray(yarn_inv_freq(head_dim, theta, factor,
+                                             original, fast, slow))
+        mult = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all)
     ang = positions.astype(jnp.float32)[..., None] * inv_freq
-    return jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
+    cos, sin = jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
+    return (cos, sin) if mult == 1.0 else (cos * mult, sin * mult)
 
 
 def _rope(x, cos, sin):
@@ -292,6 +449,69 @@ def attention(layer, cfg, x, i, rope, attend, planes):
     return x, planes
 
 
+def latent_token(layer, cfg, h, rope):
+    """What a token CACHES in a latent-attention layer, from the normed
+    stream ``h`` [B, S, C]: ``[c_kv | k_r | 0]`` [B, 1, S, W] in
+    ``cfg.dtype``, the compressed latent after its norm and the one rotary
+    key all heads share after its rotation."""
+    r, dt = cfg.kv_lora_rank, cfg.dtype
+    pad = cfg.latent_width - r - cfg.qk_rope_dim
+    with jax.named_scope("kv_proj"):
+        kv = h @ layer["wkv_a"].astype(dt)                    # [B, S, R + dr]
+        c_kv = _rms32(kv[..., :r], layer["kv_a_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("rope"):
+        k_r = _rope(kv[..., None, r:].astype(jnp.float32), *rope)[:, :, 0]
+    return jnp.pad(jnp.concatenate([c_kv, k_r], axis=-1),
+                   ((0, 0), (0, 0), (0, pad))).astype(dt)[:, None]
+
+
+def latent_mix(layer, cfg, h, i, rope, attend, planes):
+    """What a latent-attention layer ADDS to the stream, from the normed
+    stream ``h`` [B, S, C] in ``cfg.dtype``, in the ABSORBED form (module
+    docstring): (y [B, S, C], the cache's one plane with layer ``i``
+    written). What ``attend`` is handed: the queries carried into the
+    latent, ``[q_nope W_uk | q_rope | 0]`` [B, H, S, W], and
+    ``latent_token``'s stored head; it scales the scores by
+    ``cfg.softmax_scale`` and returns ``sum_u p(u) c_kv(u)``
+    [B, H, S, rank]."""
+    b, s, c = h.shape
+    nh, r, eps, dt = cfg.n_head, cfg.kv_lora_rank, cfg.rms_norm_eps, cfg.dtype
+    dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    with jax.named_scope("attn"):
+        with jax.named_scope("q_proj"):
+            c_q = _rms32(h @ layer["wq_a"].astype(dt), layer["q_a_norm"],
+                         eps).astype(dt)
+            q_n = jnp.einsum("bsr,nr->bsn", c_q, layer["wq_nope"].astype(
+                dt)).reshape(b, s, nh, dn)
+            q_r = jnp.einsum("bsr,nr->bsn", c_q, layer["wq_rope"].astype(
+                dt)).reshape(b, s, nh, dr)
+        k = latent_token(layer, cfg, h, rope)
+        with jax.named_scope("rope"):
+            q_r = _rope(q_r.astype(jnp.float32), *rope).astype(dt)
+        with jax.named_scope("absorb"):
+            q_lat = jnp.einsum("bshd,hrd->bhsr", q_n,
+                               layer["w_uk"].astype(dt))
+        q = jnp.pad(jnp.concatenate([q_lat, q_r.transpose(0, 2, 1, 3)], -1),
+                    ((0, 0),) * 3 + ((0, cfg.latent_width - r - dr),))
+    y, planes = attend(i, q, k, None, planes)
+    with jax.named_scope("attn"):
+        with jax.named_scope("absorb"):
+            y = jnp.einsum("bhsr,hdr->bshd", y, layer["w_uv"].astype(dt))
+        with jax.named_scope("o_proj"):
+            y = y.reshape(b, s, nh * dv) @ layer["wo"].astype(dt)
+    return y, planes
+
+
+def mla(layer, cfg, x, i, rope, attend, planes):
+    """A latent-attention layer's mixer: ``x`` [B, S, C] -> (x with
+    ``latent_mix`` of its norm added, the cache's plane)."""
+    with jax.named_scope("attn"):
+        h = _rms32(x, layer["attn_norm"], cfg.rms_norm_eps).astype(cfg.dtype)
+    y, planes = latent_mix(layer, cfg, h, i, rope, attend, planes)
+    with jax.named_scope("attn"), jax.named_scope("o_proj"):
+        return _residual(cfg, x, y), planes
+
+
 def router_logits(n32, router):
     """The router's logits [T, E] of the float32 normed stream ``n32``
     [T, C]. The published router's softmax is float32; its matmul is too,
@@ -312,8 +532,14 @@ def moe(layer, cfg, x):
         n32 = _rms32(x, layer["ffn_norm"], cfg.rms_norm_eps).reshape(b * s, c)
         with jax.named_scope("router"):
             logits = router_logits(n32, layer["router"])
-            weights, experts = routed.route(logits, cfg.experts_per_token,
-                                            cfg.norm_topk_prob)
+            if cfg.router_scoring == "sigmoid":
+                weights, experts = routed.route_grouped(
+                    logits, layer["router_bias"], cfg.experts_per_token,
+                    cfg.n_group, cfg.topk_group, cfg.routed_scaling,
+                    cfg.norm_topk_prob)
+            else:
+                weights, experts = routed.route(
+                    logits, cfg.experts_per_token, cfg.norm_topk_prob)
         gate, counts = routed.dispatch(weights, experts, held, first)
         out = routed.expert_ffn(n32.astype(dt), gate, layer["w_gate_up"],
                                 layer["w_down"])
@@ -323,6 +549,18 @@ def moe(layer, cfg, x):
         x = _residual(cfg, x, out.reshape(b, s, c))
     absent = b * s * cfg.experts_per_token - jnp.sum(counts)
     return x, counts, absent
+
+
+def dense_ffn(layer, cfg, x):
+    """A leading dense layer's feed-forward: the gated form at
+    ``dense_width``, every token (region ``mlp``)."""
+    f = cfg.dense_width
+    with jax.named_scope("mlp"):
+        h = _rms32(x, layer["ffn_norm"], cfg.rms_norm_eps).astype(cfg.dtype)
+        gu = h @ layer["w_gate_up"].astype(cfg.dtype)
+        out = (jax.nn.silu(gu[..., :f]) * gu[..., f:]) \
+            @ layer["w_down"].astype(cfg.dtype)
+        return _residual(cfg, x, out)
 
 
 @hot_path
@@ -348,8 +586,8 @@ def forward(params, cfg, ids, cache, attn_name=None):
         if cfg.embedding_multiplier != 1.0:
             x = x * cfg.embedding_multiplier
     planes = attend.planes
-    rope = rope_angles(attend.q_pos, cfg.head_dim, cfg.rope_theta) \
-        if cfg.rope else None
+    rope = rope_angles(attend.q_pos, cfg.qk_rope_dim or cfg.head_dim,
+                       cfg.rope_theta, cfg.rope_yarn) if cfg.rope else None
     state = {}
     if n_valid is None:
         n_valid = jnp.full(ids.shape[:1], s, jnp.int32)
@@ -370,11 +608,20 @@ def forward(params, cfg, ids, cache, attn_name=None):
                 x = _residual(cfg, x, h)
             n_mamba += 1
         else:
-            if "attn" in params:
+            tree = "mla" if cfg.kv_lora_rank else "attn"
+            if tree in params:
                 layer = dict(layer, **jax.tree_util.tree_map(
-                    lambda a: a[n_attn], params["attn"]))
-            x, planes = attention(layer, cfg, x, n_attn, rope, attend, planes)
+                    lambda a: a[n_attn], params[tree]))
+            x, planes = (mla if cfg.kv_lora_rank else attention)(
+                layer, cfg, x, n_attn, rope, attend, planes)
             n_attn += 1
+        if i < cfg.dense_layers:
+            x = dense_ffn(dict(layer, **jax.tree_util.tree_map(
+                lambda a: a[i], params["dense"])), cfg, x)
+            continue
+        if cfg.dense_layers:
+            layer = dict(layer, **jax.tree_util.tree_map(
+                lambda a: a[i - cfg.dense_layers], params["moe"]))
         x, counts, away = moe(layer, cfg, x)
         load, absent = load + counts, absent + away
     with jax.named_scope("lm_head"):

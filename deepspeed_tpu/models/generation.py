@@ -109,8 +109,11 @@ def init_cache(cfg, batch, max_len, dtype=None):
     dtype = dtype or cfg.dtype
     hd = cfg.n_embd // cfg.n_head
     shape = (cfg.n_layer, batch, cfg.n_head, max_len, hd)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
-            "pos": jnp.zeros((batch,), jnp.int32)}
+    cache = {"k": jnp.zeros(shape, dtype),
+             "pos": jnp.zeros((batch,), jnp.int32)}
+    if not getattr(cfg, "latent", 0):   # latent: a token's values are lanes
+        cache["v"] = jnp.zeros(shape, dtype)    # of its key, one plane
+    return cache
 
 
 def _ln(x, p, eps):
@@ -161,7 +164,18 @@ class CacheAttention(object):
     would have written (causality: position p's k/v depend only on
     tokens <= p, which match by construction), so aliased and private
     greedy streams are bit-identical. A plain cache hits neither branch
-    and lowers exactly as before."""
+    and lowers exactly as before.
+
+    A LATENT cache (``cfg.kv_lora_rank`` > 0: ``models/decoder.py`` ``mla``;
+    the cache then has no ``v`` key) holds ONE
+    plane, one stored head of width ``W`` a token whose first
+    ``cfg.kv_lora_rank`` lanes are also its values. Every query head reads
+    that one head; the call gets no ``v``, scales by ``cfg.softmax_scale``
+    and returns ``[B, H, S, kv_lora_rank]``. A paged pool of kernel-block pages
+    is written by the same ``kv_append`` (one arena) and read by the
+    ``latent_decode`` kernel; everything else takes the scatter, the gather
+    and two einsums. The int8 and prefix tiers have no latent form
+    (``DecoderAdapter.bind`` refuses them by name)."""
 
     def __init__(self, cfg, cache, S, attn_name=None):
         self.cfg, self.cache, self.S = cfg, cache, S
@@ -180,6 +194,11 @@ class CacheAttention(object):
         self.pos = pos = cache["pos"]                  # [B] row frontiers
         self.int8 = cache["k"].dtype == jnp.int8
         self.has_prefix = "pk" in cache
+        self.latent = bool(getattr(cfg, "kv_lora_rank", 0))
+        if self.latent:
+            assert not (self.int8 or self.has_prefix), \
+                "a latent cache has no int8 and no prefix tier"
+            self.hd, self.nkv, self.rep = cache["k"].shape[-1], 1, self.nh
         # PAGED dispatch (inference/kv_pool.py paged layout): a block table
         # means k/v are a page ARENA [L, P, H/g, page_len, g*D] (``g`` heads
         # share a lane tile: decode_attention.lane_pack, read back here from
@@ -216,7 +235,8 @@ class CacheAttention(object):
         # + einsum below.
         self.use_flash = cfg.use_flash_decode and \
             decode_attention.decode_supported(
-                self.page_len if self.paged else max_len)
+                self.page_len if self.paged else max_len) and \
+            (self.paged or not self.latent)    # no dense latent kernel
         sparse_thr = getattr(cfg, "sparse_threshold", 0)
         if sparse_thr and self.use_flash:
             raise ValueError(
@@ -255,9 +275,11 @@ class CacheAttention(object):
             self.psel = jnp.arange(max_len)[None, None, :, None] < \
                 pbase[:, None, None, None]             # [B, 1, T, 1]
             self.psel_s = self.psel[..., 0]            # [B, 1, T]
-        # What the layers thread: (k, v) or (k, v, k_scale, v_scale).
-        self.planes = (cache["k"], cache["v"]) + (
-            (cache["k_scale"], cache["v_scale"]) if self.int8 else ())
+        # What the layers thread: (k, v) or (k, v, k_scale, v_scale); the
+        # one plane of a latent cache.
+        self.planes = (cache["k"],) if self.latent else \
+            (cache["k"], cache["v"]) + (
+                (cache["k_scale"], cache["v_scale"]) if self.int8 else ())
 
     def _pad_prefix(self, p):
         # [B, H, prefix_len, ...] -> [B, H, max_len, ...]; the pad is
@@ -301,10 +323,40 @@ class CacheAttention(object):
         return decode_attention.gather_pages(arena_l, self.tbl, self.nkv,
                                              self.pack)
 
+    def _latent(self, i, q, k, planes):
+        """Layer ``i`` of a latent cache (class docstring): q [B, H, S, W],
+        k [B, 1, S, W] -> (y [B, H, S, rank], (the plane,))."""
+        cache, = planes
+        rank, scale = self.cfg.kv_lora_rank, self.cfg.softmax_scale
+        kernels = self.paged and self.use_flash
+        with jax.named_scope("kv_write"):
+            if kernels:
+                cache, = decode_attention.kv_append(
+                    (cache,), (k,), self.tbl, self.pos, layer=i)
+            else:
+                cache = cache.at[i].set(self._write_rows(cache[i], k))
+        if kernels:
+            with jax.named_scope("attn"):
+                return decode_attention.latent_decode(
+                    q, cache, self.tbl, self.pos, rank, scale=scale,
+                    name=self.attn_name, layer=i), (cache,)
+        with jax.named_scope("kv_view"):
+            k_eff = (self._gather_pages(cache[i]) if self.paged
+                     else cache[i])[:, 0]                  # [B, T, W]
+        with jax.named_scope("attn"):
+            att = jnp.einsum("bhqd,bkd->bhqk", q, k_eff).astype(
+                jnp.float32) * scale
+            att = jnp.where(self.mask[:, None], att, self.neg)
+            att = jax.nn.softmax(att, axis=-1).astype(self.cfg.dtype)
+            return jnp.einsum("bhqk,bkd->bhqd", att,
+                              k_eff[..., :rank]), (cache,)
+
     def __call__(self, i, q, k, v, planes):
         """Layer ``i``'s attention: write ``k, v`` at the frontiers, read
         the cache, attend. Returns (y [B, H, S, D], the planes with layer
         ``i`` written)."""
+        if self.latent:
+            return self._latent(i, q, k, planes)
         cfg, cache = self.cfg, self.cache
         int8, paged, use_flash = self.int8, self.paged, self.use_flash
         pos, hd = self.pos, self.hd
@@ -424,6 +476,8 @@ class CacheAttention(object):
         frontier moved by S. ``dict(cache, ...)``, NOT a fresh literal, so
         hierarchy keys (scale planes, prefix views) and an adapter's
         ``aux_`` state survive the decode scan's cache threading."""
+        if self.latent:
+            return dict(self.cache, k=planes[0], pos=self.pos + self.S)
         out = dict(self.cache, k=planes[0], v=planes[1],
                    pos=self.pos + self.S)
         if self.int8:

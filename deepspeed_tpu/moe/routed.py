@@ -11,7 +11,9 @@ emit the same stream).
 ``route`` is the published router: softmax in float32 over ALL experts, the
 ``k`` largest kept, renormalised only where the configuration says so
 (renormalised, it is the softmax over the kept logits alone: Granite's
-router). ``dispatch`` turns the choice into a gate over the experts THIS
+router). ``route_grouped`` is the DeepSeek-V3 router (``noaux_tc``): sigmoid
+scores, a selection bias, the choice limited to the best groups of experts.
+``dispatch`` turns the choice into a gate over the experts THIS
 chip holds and counts their load; ``expert_ffn`` is the gated feed-forward
 of the chosen experts, ``sum_j w[t, j] * down_e(silu(gate_e(x_t)) *
 up_e(x_t))`` with ``e = experts[t, j]``.
@@ -41,6 +43,39 @@ def route(logits, k, renormalise=False):
     if renormalise:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return weights, experts.astype(jnp.int32)
+
+
+def route_grouped(logits, bias, k, n_group, topk_group, scale=1.0,
+                  renormalise=True):
+    """The group-limited sigmoid router (DeepSeek-V3, ``topk_method``
+    ``noaux_tc``): ``[T, E]`` float32 logits and the selection bias ``[E]``
+    -> (weights ``[T, k]`` float32, experts ``[T, k]`` int32).
+
+    ``s = sigmoid(logits)`` scores every expert; ``s + bias`` CHOOSES and is
+    used for nothing else (the bias balances load without an auxiliary loss,
+    and must not reach the output). The ``E`` experts lie in ``n_group``
+    groups of ``E / n_group`` neighbours (a node's experts in the published
+    deployment); a group's score is the sum of its 2 largest ``s + bias``,
+    only the ``topk_group`` best groups stay eligible (the others' scores
+    are set to 0, as the published code does, so an eligible expert whose
+    biased score is negative can lose to a cut one: kept as published),
+    and of them the ``k`` largest are chosen. The weights are the chosen
+    experts' ``s`` WITHOUT the bias, divided by their sum where
+    ``renormalise`` (``norm_topk_prob``), times ``scale``
+    (``routed_scaling_factor``)."""
+    t, e = logits.shape
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    choose = scores + bias.astype(jnp.float32)
+    grouped = choose.reshape(t, n_group, e // n_group)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, best = jax.lax.top_k(group_score, topk_group)        # [T, topk_group]
+    eligible = jnp.any(best[:, :, None] == jnp.arange(n_group), axis=1)
+    choose = jnp.where(eligible[:, :, None], grouped, 0.0).reshape(t, e)
+    _, experts = jax.lax.top_k(choose, k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if renormalise:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return weights * scale, experts.astype(jnp.int32)
 
 
 def dispatch(weights, experts, held, first=0):

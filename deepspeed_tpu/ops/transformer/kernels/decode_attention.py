@@ -899,7 +899,7 @@ def _paged_units(tbl, pos, s_len, page_len):
 
 
 def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
-                  *refs, s_len, q8, single_kv, pack, rep=1):
+                  *refs, s_len, q8, single_kv, pack, rep=1, latent=0):
     """One grid step = one unit of ``_paged_units``: a page of all the
     heads (of the group, where all do not fit) of one row. Against a packed
     arena (``pack`` heads a lane tile) a head of the block is ``pack`` heads
@@ -907,9 +907,11 @@ def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
     ``r // s_len`` of them (``_pack_query``). Grouped-query heads put the
     ``rep`` query heads of a stored head beside ``s_len`` the same way: a
     stored head's rows are ``rep * s_len``, row ``r`` still at position
-    ``r % s_len``."""
-    n_a = 4 if q8 else 2
-    k_ref, v_ref, *scale_refs = refs[:n_a]     # scales: the int8 family's
+    ``r % s_len``. ``latent`` > 0 (``latent_decode``): ONE arena, whose page
+    is the keys and, in its first ``latent`` lanes, the values."""
+    n_a = 1 if latent else 4 if q8 else 2
+    k_ref, *more = refs[:n_a]
+    v_ref, scale_refs = (None, ()) if latent else (more[0], more[1:])
     o_ref, stats = refs[n_a], refs[n_a + 1:]
     page_len = k_ref.shape[1]
     t = pl.program_id(1)
@@ -918,7 +920,8 @@ def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
 
     def attend():
         q = q_ref[0]                                   # [hb, s_blk, d]
-        k, v = k_ref[...], v_ref[...]                  # [hb, page_len, d]
+        k = k_ref[...]                                 # [hb, page_len, d]
+        v = k[:, :, :latent] if latent else v_ref[...]
         if q8:
             # The codes are exact in the query's dtype; a key's scale
             # multiplies its score, a value's its probability.
@@ -975,6 +978,13 @@ def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
                 l = jnp.sum(p.astype(jnp.float32), axis=-1, keepdims=True)
                 scaled = p.astype(jnp.float32) * row_scales(scale_refs[1])
                 return times_v(scaled, v), l
+            if latent:
+                # the values are whole lane tiles already: the row-sum of
+                # the probabilities as the matmul sees them, and no
+                # ``[v | 1]`` a tile wider
+                return times_v(p, v), jnp.sum(
+                    p.astype(v.dtype).astype(jnp.float32), axis=-1,
+                    keepdims=True)
             # p @ [v | 1]: the row-sum rides the PV matmul, as in
             # ``_pv_rowsum``, and shares p's rounding with the numerator.
             d = v.shape[2]
@@ -1014,9 +1024,13 @@ def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
     pl.when(n_live > 0)(attend)
 
 
-def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1):
-    """The one launcher of both paged families: ``arenas`` is (k, v) or
-    (k, v, k_scale, v_scale), whole or one layer's (``layer`` None). With
+def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1,
+                  latent=0):
+    """The one launcher of the paged families: ``arenas`` is (k, v) or
+    (k, v, k_scale, v_scale), whole or one layer's (``layer`` None), or the
+    ONE arena of a latent cache (``latent`` > 0: its value width; ``q`` is
+    then ``[B, G, rows, W]``, ``G`` groups of query heads that all read the
+    arena's one stored head, a group a unit). With
     ``pack`` > 1 the arenas are packed and ``q`` is ``_pack_query``'s:
     ``[B, Hp, pack * S, pack * D]`` against ``[.., Hp, page_len, pack * D]``,
     which the body attends as ``Hp`` heads of a whole lane tile. With
@@ -1033,8 +1047,9 @@ def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1):
     s_blk = -(-n_rows // sub) * sub
     if s_blk != n_rows:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, s_blk - n_rows), (0, 0)))
-    hb = _paged_heads_per_unit(h, s_blk, page_len, d, q.dtype,
-                               arenas[0].dtype, pack)
+    hb = 1 if latent else _paged_heads_per_unit(
+        h, s_blk, page_len, d, q.dtype, arenas[0].dtype, pack)
+    d_out = latent or d
     pos = pos.astype(jnp.int32)
     rows, js, pages, live, n_units = _paged_units(tbl.astype(jnp.int32), pos,
                                                   s, page_len)
@@ -1047,6 +1062,11 @@ def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1):
         # [hb, page_len, d] of a row arena, [hb * pack, page_len] of a
         # scale arena (a scale a head of the model).
         zeros = (0,) * (arena.ndim - 3)
+        if latent:      # every group of query heads reads the one head
+            return pl.BlockSpec(
+                (None, None, 1) + arena.shape[3:],
+                lambda g, t, rows_ref, js_ref, pages_ref, *_:
+                (layer, pages_ref[t], 0) + zeros)
         return pl.BlockSpec(
             (None, None, hb * (arena.shape[2] // h)) + arena.shape[3:],
             lambda g, t, rows_ref, js_ref, pages_ref, *_:
@@ -1060,9 +1080,9 @@ def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1):
         grid=(h // hb, n_units),
         in_specs=[pl.BlockSpec((1, hb, s_blk, d), q_index)]
         + [page_spec(a) for a in arenas],
-        out_specs=pl.BlockSpec((1, hb, s_blk, d), q_index),
+        out_specs=pl.BlockSpec((1, hb, s_blk, d_out), q_index),
         scratch_shapes=[] if single_kv else [
-            pltpu.VMEM((hb, s_blk, d), jnp.float32),
+            pltpu.VMEM((hb, s_blk, d_out), jnp.float32),
             pltpu.VMEM((hb, s_blk, _STATS_LANES), jnp.float32),
             pltpu.VMEM((hb, s_blk, _STATS_LANES), jnp.float32),
         ],
@@ -1070,9 +1090,10 @@ def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1):
     out = pallas_mode.kernel_call(
         name,
         functools.partial(_paged_kernel, s_len=s, q8=len(arenas) == 4,
-                          single_kv=single_kv, pack=pack, rep=rep),
+                          single_kv=single_kv, pack=pack, rep=rep,
+                          latent=latent),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d_out), q.dtype),
     )(*units, q, *arenas)
     # A freed row has no unit, so the kernel never wrote its block: zeros,
     # in a select that fuses into whatever reads the output.
@@ -1187,6 +1208,86 @@ def flash_decode_attention_paged_q8(q, k, v, k_scale, v_scale, block_tbl,
     return _paged_on_shards(_flash_decode_paged_q8_pallas, q,
                             (k, v, k_scale, v_scale), block_tbl, pos, scale,
                             name, layer)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention over the paged pool (kernel "latent_decode"): the same
+# body and launcher on a cache that is not a k/v pair. A token stores ONE
+# head of width W (DeepSeek-V3: [c_kv (512) | k_r (64) | zeros to 640]) and
+# all H query heads read it, so the arena is ``[L, P, 1, page_len, W]`` and
+# there is no value arena: a page's first ``rank`` lanes ARE its values. To
+# the body this is grouped-query attention taken to its end: the ``H`` query
+# heads of the one stored head sit beside S on the sublane axis (H = 128 and
+# S = 1 make a full 128-row tile of the MXU where ``paged_decode`` has 1 to
+# 4 rows), the score contracts over W and the value product over the page's
+# first ``rank`` lanes, both whole lane tiles, so ``v`` is a lane-aligned
+# slice of the block ``k`` came in as and costs no copy. 2 x H x (W + rank)
+# FLOP for 2 W bytes a cached token: 242 FLOP a byte at 576 + 512 against
+# the v5e's 240, where ``paged_decode`` is 1. For a lane of S = 128 the
+# 16,384 rows would need 40 MB of VMEM, so the query heads go in GROUPS (the
+# launcher's outer grid axis, the largest divisor of H whose blocks fit
+# ``_PAGED_VMEM_BUDGET``: 8 heads at S = 128), each group a pass over the
+# row's pages.
+# ---------------------------------------------------------------------------
+
+def _latent_heads_per_unit(h, s_len, page_len, w, rank, dtype):
+    """Query heads one unit of ``latent_decode`` attends: the largest
+    divisor of ``h`` whose rows' blocks stay in ``_PAGED_VMEM_BUDGET`` (q
+    and out double-buffered, the float32 accumulator, statistics, scores
+    and probabilities; the page itself is small beside them)."""
+    b = jnp.dtype(dtype).itemsize
+    per_row = (2 * w * b + 2 * rank * b + rank * 4
+               + 2 * _STATS_LANES * 4 + 2 * page_len * 4)
+    fit = max((_PAGED_VMEM_BUDGET - 2 * page_len * w * b)
+              // (per_row * s_len), 1)
+    return max(g for g in range(1, h + 1) if h % g == 0 and g <= fit)
+
+
+def latent_decode_reference(q, arena, block_tbl, pos, rank, scale):
+    """Ground truth of ``latent_decode`` in ``jax.numpy``: gather each row's
+    pages of ONE layer's arena ``[P, 1, page_len, W]`` into its plane, score
+    every query head against it, softmax in float32 under the frontier
+    mask, and sum the plane's first ``rank`` lanes."""
+    plane = gather_pages(arena, block_tbl, 1, 1)           # [B, 1, T, W]
+    return decode_attention_reference(q, plane, plane[..., :rank], pos,
+                                      scale=scale)
+
+
+@hot_path
+def latent_decode(q, arena, block_tbl, pos, rank, scale, name=None,
+                  layer=None):
+    """Latent attention over a paged pool's ONE arena.
+
+    Args:
+      q: [B, H, S, W] queries carried into the latent (``models/decoder.py``
+        ``mla``: ``[q_nope W_uk | q_rope | 0]``), the row's S tokens already
+        appended (``kv_append``) at ``pos[b] ..``.
+      arena: [L, P, 1, page_len, W] with a static ``layer``, or one layer's
+        [P, 1, page_len, W] with ``layer`` None; page 0 is the trash page.
+      block_tbl: [B, n_lp] int32; pos: [B] int32 pre-write frontiers.
+      rank: the leading lanes of a stored token that are its values.
+      scale: on the scores (``DecoderConfig.softmax_scale``).
+      name: the kernel's name in a trace (``prefill_attn`` from the lane).
+
+    A page size that is no kernel block takes the gather + reference (same
+    math). Returns [B, H, S, rank] in q.dtype; zeros for a freed row."""
+    b, h, s_len, w = q.shape
+    page_len = arena.shape[-2]
+    if not decode_supported(page_len):
+        return latent_decode_reference(
+            q, arena if layer is None else arena[layer], block_tbl, pos,
+            rank, scale=scale)
+    hg = _latent_heads_per_unit(h, s_len, page_len, w, rank, q.dtype)
+
+    def launch(q, arena, tbl, pos):
+        return _paged_launch(name or "latent_decode", q, (arena,), tbl, pos,
+                             float(scale), layer, rep=hg, latent=int(rank))
+
+    out = on_shards(launch, kernel_sharding(b, 1),
+                    ("b", "-" if layer is None else "--", "b", "b"), ("b",))(
+                        q.reshape(b, h // hg, hg * s_len, w), arena,
+                        block_tbl, pos)
+    return out.reshape(b, h, s_len, rank)
 
 
 # ---------------------------------------------------------------------------
@@ -1348,8 +1449,9 @@ def kv_append(arenas, new, block_tbl, pos, layer):
     """Append each row's new k/v at its frontier, IN PLACE in the arenas.
 
     Args:
-      arenas: tuple of page arenas of one paged pool (k and v together,
-        at least), each WHOLE and as the pool stores it:
+      arenas: tuple of page arenas of one paged pool (k and v together;
+        the one arena of a latent cache), each WHOLE and as the pool stores
+        it:
         [L, P, ceil(H / g), page_len, g * D] rows (bf16, or int8 codes;
         ``g = lane_pack(D, H)``) and, for an int8 pool,
         [L, P, g * ceil(H / g), page_len] fp32 scales. Donate them (the
@@ -1371,7 +1473,7 @@ def kv_append(arenas, new, block_tbl, pos, layer):
     Returns the tuple of updated arenas.
     """
     page_len = arenas[0].shape[3]
-    assert decode_supported(page_len) and len(arenas) > 1, page_len
+    assert decode_supported(page_len), page_len
     # The new values in the arenas' stored shape (kilobytes): rows packed
     # ``g`` heads a lane tile, scales a head of the model (a zero head
     # where ``g`` does not divide the count), and the kernel below sees

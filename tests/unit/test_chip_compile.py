@@ -630,3 +630,63 @@ def test_hybrid_mixed_step_updates_each_layers_state_where_it_lies(
     # copy of a layer's state anywhere in the step.
     outside = _arena_shaped(whole(set(comps) - set(scan)), state)
     assert [op for _, op in outside] == ["fusion"] * 9, outside
+
+
+def test_latent_mixed_step_appends_and_attends_the_one_arena_in_place(
+        chip, monkeypatch):
+    """DeepSeek-V3's block at its published widths and the cell's 6 layers
+    (1 dense + 5 with 8 of 256 experts held; the cell's pool: 128 slots of
+    2944, page 128, chunk 16, lane 128; a minute or two to compile): the
+    latent cache is ONE arena ``[6, 3073, 1, 128, 640]`` (576 stored as 640:
+    at 576 the compiler adds two whole-arena copies and 3 GB of temp around
+    the kernels, which is how the width was decided). In the decode scan
+    it meets ``kv_append`` and ``latent_decode``, once a layer each and under
+    those names, and nothing else; no whole-arena value is formed anywhere
+    in the step; the lane's call of the same body is ``prefill_attn``; and
+    the step fits the chip beside its weights and pool."""
+    from deepspeed_tpu.inference import kv_pool
+    from deepspeed_tpu.inference.adapters import DecoderAdapter
+    from deepspeed_tpu.inference.config import InferenceConfig
+    from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
+
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    slots, chunk, lane, n_layer = 128, 16, 128, 6
+    model = DecoderLM(DecoderConfig(
+        vocab_size=16160, n_layer=n_layer, n_head=128, head_dim=192,
+        hidden_size=7168, n_positions=163840, n_experts=256,
+        experts_per_token=8, expert_width=2048, rms_norm_eps=1e-6,
+        qk_norm=False, norm_topk_prob=True, dtype=BF16, shared_width=2048,
+        experts_held=(0, 8), kv_lora_rank=512, q_lora_rank=1536,
+        qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+        rope_yarn=(40.0, 4096, 32.0, 1.0, 1.0, 1.0), dense_layers=1,
+        dense_width=18432, router_scoring="sigmoid", n_group=8, topk_group=4,
+        routed_scaling=2.5))
+    config = InferenceConfig.from_dict(dict(
+        max_slots=slots, max_len=2944, chunk_size=chunk, paged_kv=True,
+        kv_page_len=PAGE, prefill_chunk=lane, use_flash_decode=True))
+    adapter = DecoderAdapter.from_model(model, use_flash_decode=True).bind(
+        config, None)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0))["params"])
+    pool = jax.eval_shape(lambda: dict(kv_pool.init_pool(
+        adapter.cache_spec(), slots, 2944, slack=lane, page_len=PAGE),
+        **adapter.aux_state()))
+    assert "v" not in pool and pool["k"].shape == (6, 128 * 24 + 1, 1, PAGE,
+                                                   640)
+
+    text = _mixed_step_text(chip, adapter, params, pool, chunk, lane)
+    dump = os.environ.get("DS_TPU_HLO_DUMP")
+    if dump:
+        with open(dump, "w") as f:
+            f.write(text)
+    comps = _computations(text)
+    scan, in_scan = _scan_lines(comps)
+    names = sorted(c.split(".")[0] for c in _kernel_calls(
+        "\n".join(in_scan)))
+    assert names == ["kv_append"] * n_layer + ["latent_decode"] * n_layer
+    everywhere = sorted(c.split(".")[0] for c in _kernel_calls(text))
+    assert everywhere == ["kv_append"] * 2 * n_layer \
+        + ["latent_decode"] * n_layer + ["prefill_attn"] * n_layer
+    arena = ["[6,3073,1,128,640]", "[3073,1,128,640]"]
+    assert _arena_shaped([line for lines in comps.values()
+                          for line in lines], arena) == []

@@ -277,3 +277,37 @@ def test_contiguous_and_speculative_scan_names():
     assert {"decode_scan/draft", "decode_scan/sample",
             "decode_scan/kv_write"} <= regions
     assert kernels == {"prefill_attn", "decode_attn"}
+
+
+def test_latent_mixed_step_holds_the_latent_regions_and_kernel():
+    """Latent attention's step (DeepSeek-V3's block at a tiny size, pages of
+    a kernel block): the regions ``mla`` adds inside ``attn``, ``mlp`` for
+    the leading dense layer beside ``moe`` for the rest, and exactly two
+    kernels by name: the scan's ``latent_decode`` and the lane's call of
+    the same body, ``prefill_attn``; the one plane is appended in place
+    under ``kv_write`` and no ``kv_view`` is formed."""
+    from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
+
+    model = DecoderLM(DecoderConfig(
+        vocab_size=256, n_layer=2, n_head=4, head_dim=24, hidden_size=64,
+        n_positions=512, n_experts=16, experts_per_token=3, expert_width=32,
+        rms_norm_eps=1e-6, qk_norm=False, norm_topk_prob=True,
+        dtype=jnp.float32, shared_width=32, experts_held=(0, 8),
+        kv_lora_rank=32, q_lora_rank=24, qk_nope_dim=16, qk_rope_dim=8,
+        v_head_dim=16, rope_yarn=(40.0, 64, 32.0, 1.0, 1.0, 1.0),
+        dense_layers=1, dense_width=96, router_scoring="sigmoid", n_group=4,
+        topk_group=2, routed_scaling=2.5))
+    eng = InferenceEngine(model, model.init(jax.random.PRNGKey(0))["params"],
+                          config=dict(max_slots=2, max_len=256, chunk_size=2,
+                                      prefill_chunk=16, paged_kv=True,
+                                      kv_page_len=128, use_flash_decode=True))
+    op_names = _op_names(_lower_mixed(eng), "jit_mixed_step")
+    regions, kernels = _regions(op_names)
+    assert {"decode_scan/attn/" + w for w in
+            ("q_proj", "kv_proj", "rope", "absorb", "o_proj")} <= regions
+    assert {"prefill_lane/attn/absorb", "decode_scan/mlp",
+            "decode_scan/moe/router", "decode_scan/moe/experts",
+            "decode_scan/moe/shared", "decode_scan/kv_write",
+            "prefill_lane/kv_write"} <= regions
+    assert "decode_scan/kv_view" not in regions
+    assert kernels == {"prefill_attn", "latent_decode"}
