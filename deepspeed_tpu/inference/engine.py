@@ -770,6 +770,7 @@ class InferenceEngine(object):
                 lambda: pg.fragmentation(self._live_tokens()))
             self.telemetry.gauge("kv_live_page_share").set_fn(
                 self._live_page_share)
+            self.telemetry.gauge("kv_unit_fill").set_fn(self._unit_fill)
         # Span-ring overflow as a live series: a truncated autopsy
         # (telemetry/autopsy.py hop_gaps) is detectable from the same
         # scrape that would have shown the alert, instead of silently
@@ -841,6 +842,7 @@ class InferenceEngine(object):
                              page_len=self.config.kv_page_len,
                              num_pages=self._pager.total_pages)
             self.telemetry.gauge("kv_lane_pack").set(self._lane_pack(pool))
+            self.telemetry.gauge("kv_unit_pages").set(self._unit_pages(pool))
         else:
             pool = init_pool(self._gcfg, self.config.max_slots,
                              self.config.max_len, slack=self._slack,
@@ -868,6 +870,24 @@ class InferenceEngine(object):
         pool = self._pool if pool is None else pool
         return pool["k"].shape[-1] // (
             self._gcfg.n_embd // self._gcfg.n_head)
+
+    def _unit_pages(self, pool=None):
+        """K, the consecutive pages of a row that one unit of the decode
+        scan's paged kernel joins, as the launcher's own rule resolves it
+        for this pool's arenas and the model's query heads
+        (``decode_attention.unit_pages``: from shapes and dtypes alone; 1
+        for pages that are no kernel block, which take the gather). On the
+        WHOLE pool's shapes: a tensor-parallel shard's are its own."""
+        pool = self._pool if pool is None else pool
+        spec = self._gcfg
+        if not decode_attention.decode_supported(pool["k"].shape[3]):
+            return 1
+        arenas = [pool[name] for name in ("k", "v", "k_scale", "v_scale")
+                  if name in pool]
+        return decode_attention.unit_pages(
+            arenas, getattr(self._adapter, "gcfg", spec).n_head,
+            spec.n_embd // spec.n_head, pool["block_tbl"].shape[1],
+            spec.dtype, latent=getattr(spec, "latent", 0))
 
     def _on_stall(self, budget_s):
         """Watchdog trip — runs on the TIMER THREAD while the step is
@@ -1292,13 +1312,28 @@ class InferenceEngine(object):
         and over no page of a freed row (ops/transformer/kernels/
         decode_attention.py). From the step's own snapshot and the host's
         table; no transfer."""
+        return float(self._live_pages().sum()) / self._pager.table.size
+
+    def _live_pages(self):
+        """Each slot's live pages at the last harvest (up to its frontier;
+        none of a freed row's), as the paged kernel's work list counts
+        them."""
         snap, pg = self._last_snap, self._pager
         if snap is None:
-            return 0.0
+            return np.zeros((pg.table.shape[0],), np.int64)
         mapped = pg.table[:, 0] != TRASH_PAGE
-        pages = np.minimum(snap["pos"] // pg.page_len + 1,
-                           pg.pages_per_slot)
-        return float((pages * mapped).sum()) / pg.table.size
+        return np.minimum(snap["pos"] // pg.page_len + 1,
+                          pg.pages_per_slot) * mapped
+
+    def _unit_fill(self):
+        """Live pages over K x units at the last harvest (K:
+        ``_unit_pages``; a row's units are ``ceil(live / K)``): 1 minus it
+        is what the joined unit brings again and skips, a row's last unit
+        past its frontier. From the same snapshot as
+        ``kv_live_page_share``; 0 with no live page."""
+        live, k = self._live_pages(), self._unit_pages()
+        units = int((-(-live // k)).sum())
+        return float(live.sum()) / (k * units) if units else 0.0
 
     def _live_tokens(self):
         """Tokens actually resident across running sessions — the
@@ -2379,6 +2414,8 @@ class InferenceEngine(object):
             m.update({
                 "kv_page_len": pg.page_len,
                 "kv_lane_pack": self._lane_pack(),
+                "kv_unit_pages": self._unit_pages(),
+                "kv_unit_fill": round(self._unit_fill(), 4),
                 "kv_pages_total": pg.total_pages,
                 "kv_pages_in_use": pg.pages_in_use(),
                 "kv_pages_free": pg.pages_free(),

@@ -42,12 +42,14 @@ WHAT A UNIT OF WORK IS. The dense kernels above (``decode_attn[_q8]``:
 blocks clamped and skipped but still stepped. The PAGED kernels
 (``paged_decode[_q8]``, and the prefill lane's ``prefill_attn``: what the
 serving engine runs) step a WORK LIST instead: one unit is one page of ALL
-heads of one row, only a row's live pages (up to its frontier) are units,
-a freed row is no unit at all (its output is zeros), and the list's length
-is the grid. See the "Paged kernels" section below.
+heads of one row (a run of K consecutive pages where a page is small:
+``latent_decode``'s four), only a row's live pages (up to its frontier) are
+units, a freed row is no unit at all (its output is zeros), and the list's
+length is the grid. See the "Paged kernels" section below.
 """
 
 import functools
+import math
 import os
 
 import jax
@@ -746,6 +748,24 @@ def flash_decode_attention_q8(q, k, v, k_scale, v_scale, pos, scale=None,
 # static ``B x n_lp`` grid was 12–14% slower, 2.3 times at three live rows
 # of sixteen. See PERF.md, PR 28.)
 #
+# A UNIT IS K CONSECUTIVE LIVE PAGES OF ITS ROW WHERE A PAGE IS SMALL (PR 39;
+# ``_pages_per_unit``, from shapes: K = 1 for every k/v arena a cell runs, 4
+# for the latent cache's 164 KB page). What a grid step costs whatever it
+# holds (about half a microsecond: index maps, DMA descriptors, the scalar
+# reads, one online-softmax update and the rescale of the accumulator) is
+# then paid once for K pages: the arena is an operand K times, each with its
+# own page of the unit in its index map, so Pallas's pipeline still brings
+# unit t + 1 in while unit t is attended; row b has ``ceil(live[b] / K)``
+# units, and the slots of its last unit past its last live page name that
+# page again (safe to bring; never attended: the body takes a branch a count
+# of live pages, ``lax.switch``, so no matmul runs on a page that is not
+# there). The body attends the unit's live pages as one block of keys: a
+# score matmul a page, side by side on the lanes, ONE softmax update, a value
+# product a page summed into ONE rescale of the accumulator. The other form
+# timed, the arena in ``pl.ANY`` and a double-buffered ``make_async_copy`` of
+# the unit's live pages, ran within 2.5% of this one at every K (PERF.md, PR
+# 39) and is not kept.
+#
 # The body attends all H heads of the page at once: a batched score
 # matmul, one online-softmax update of ``[H, S, 1]`` statistics and a
 # batched PV into the ``[H, S, D]`` float32 accumulator, with the dense
@@ -845,23 +865,25 @@ def _whole_arena(layer, *arenas):
 _PAGED_VMEM_BUDGET = 12 * 2 ** 20
 
 
-def _paged_heads_per_unit(h, s_blk, page_len, d, q_dtype, kv_dtype, pack=1):
+def _paged_heads_per_unit(h, s_blk, page_len, d, q_dtype, kv_dtype, pack=1,
+                          k_pages=1):
     """Heads one unit of the paged kernel attends: all ``h`` of the call
     (a shard's, under tensor parallelism; packed heads of width ``d`` where
     ``pack`` > 1), or the largest divisor of ``h`` whose blocks stay inside
-    ``_PAGED_VMEM_BUDGET``. From shapes and dtypes alone; the minor dim of
-    every block pads to a lane tile."""
+    ``_PAGED_VMEM_BUDGET`` with ``k_pages`` pages a unit. From shapes and
+    dtypes alone; the minor dim of every block pads to a lane tile."""
     def lanes(n):
         return -(-n // LANES) * LANES
 
     q_b, kv_b = jnp.dtype(q_dtype).itemsize, jnp.dtype(kv_dtype).itemsize
+    keys = k_pages * page_len
     per_head = (2 * 2 * s_blk * lanes(d) * q_b          # q, out
-                + 2 * 2 * page_len * lanes(d) * kv_b    # k, v
+                + 2 * 2 * keys * lanes(d) * kv_b        # k, v
                 + s_blk * lanes(d) * 4                  # accumulator
                 + 2 * s_blk * _STATS_LANES * 4          # m, l
-                + 2 * s_blk * lanes(page_len) * 4)      # scores, probs
+                + 2 * s_blk * lanes(keys) * 4)          # scores, probs
     if kv_b == 1:                    # the codes as matmul operands
-        per_head += 2 * page_len * lanes(d) * q_b
+        per_head += 2 * keys * lanes(d) * q_b
     fit = _PAGED_VMEM_BUDGET // per_head
     if h <= fit:
         return h
@@ -871,37 +893,134 @@ def _paged_heads_per_unit(h, s_blk, page_len, d, q_dtype, kv_dtype, pack=1):
                 and (kv_b > 1 or g * pack % 8 == 0)), default=h)
 
 
-def _paged_units(tbl, pos, s_len, page_len):
+# A unit that already reads near its bound: one page of OLMoE's 16 heads of
+# 128, keys and values (1 MiB: 1.42 us against 1.28 us of stream). What a
+# grid step costs whatever it holds (index maps, DMA descriptors, a scalar
+# read of the lists, one online-softmax update and a rescale of the
+# accumulator) and the loading of a matmul's stationary tiles are paid a
+# UNIT, so a unit far under this size (a page of a latent cache's one
+# stored head: 164 KB) joins K consecutive pages of its row and stays under
+# it. 512 KB (GPT-2's, Granite's) would reach it exactly at K = 2 and stays
+# one page a unit.
+_UNIT_BYTES = 2 ** 20
+
+
+def _pages_per_unit(page_bytes, n_lp, fits):
+    """K, the consecutive logical pages of one row that one unit joins: the
+    largest of 1, 2, 4, 8 for which K pages' blocks (``page_bytes`` each,
+    all arenas together) stay under ``_UNIT_BYTES``, a row's table holds as
+    many, and ``fits(K)`` (the VMEM reckoning keeps the heads a unit has at
+    one page). From shapes and dtypes alone."""
+    return max(k for k in (1, 2, 4, 8) if k == 1 or (
+        k <= n_lp and k * page_bytes < _UNIT_BYTES and fits(k)))
+
+
+def _times(a, k):
+    """``a * k`` for a static ``k``; at 1 the value itself, so that a unit
+    of one page traces the program it always has."""
+    return a if k == 1 else a * k
+
+
+def _ceil_div(a, k):
+    return a if k == 1 else _div(a + (k - 1), k)
+
+
+def _paged_unit(h, n_rows, d, arenas, n_lp, q_dtype, pack=1, rep=1,
+                latent=0):
+    """What one unit of a paged call holds, ``(heads, pages)``, from the
+    launcher's shapes and dtypes alone: ``q`` is ``[B, h, n_rows, d]``,
+    ``arenas`` (anything with a shape and a dtype) whole,
+    ``[L, P, heads, page_len, ..]``. A latent call's unit is ONE group of
+    its query heads (``rep`` of them: ``_latent_heads_per_unit``'s, the
+    caller's); the other families' is ``_paged_heads_per_unit``'s. The
+    pages are ``_pages_per_unit``'s, and more than one only where ALL the
+    call's heads are one unit and stay one: a call whose heads go in groups
+    (a lane's) fills VMEM with its rows and has work enough a page."""
+    page_len = arenas[0].shape[3]
+    if latent:
+        def heads(k):
+            return _latent_heads_per_unit(h * rep, n_rows // rep, page_len,
+                                          d, latent, q_dtype, k)
+        hb, every = 1, h * rep
+    else:
+        sub = _sublane(q_dtype)
+
+        def heads(k):
+            return _paged_heads_per_unit(h, -(-n_rows // sub) * sub,
+                                         page_len, d, q_dtype,
+                                         arenas[0].dtype, pack, k)
+        hb, every = heads(1), h
+    # a page's block: the unit's heads of each arena (a latent cache's one)
+    page_bytes = sum(
+        (1 if latent else hb * (a.shape[2] // h)) * math.prod(a.shape[3:])
+        * jnp.dtype(a.dtype).itemsize for a in arenas)
+    return hb, _pages_per_unit(page_bytes, n_lp, lambda k: heads(k) == every)
+
+
+def unit_pages(arenas, heads, head_dim, n_lp, q_dtype, s_len=1, latent=0):
+    """K as the launchers resolve it for a pool's arenas (whole, as the pool
+    stores them: ``k, v[, k_scale, v_scale]``, or a latent cache's one) and
+    a call of ``s_len`` query positions a row over ``heads`` heads: what the
+    engine reports as ``kv_unit_pages``."""
+    page_len = arenas[0].shape[3]
+    if latent:
+        w = arenas[0].shape[-1]
+        hg = _latent_heads_per_unit(heads, s_len, page_len, w, latent,
+                                    q_dtype)
+        return _paged_unit(heads // hg, hg * s_len, w, arenas, n_lp, q_dtype,
+                           rep=hg, latent=latent)[1]
+    g = arenas[0].shape[-1] // head_dim
+    rep = max(1, heads // (arenas[0].shape[2] * g))
+    return _paged_unit(-(-(heads // rep) // g), g * rep * s_len,
+                       g * head_dim, arenas, n_lp, q_dtype, g, rep)[1]
+
+
+def _paged_units(tbl, pos, s_len, page_len, k_pages=1):
     """The paged kernel's work list, from the table and the frontiers.
 
-    Returns ``(rows, js, pages, live, n)``: unit t attends logical page
-    ``js[t]`` of row ``rows[t]``, which is arena page ``pages[t]``; row b
-    has ``live[b]`` live pages and as many units, in order, so a freed row
-    (``live`` 0) has none; ``n`` units in all, and one (the last row's,
-    which then has no live page) where no row has any. The lists are
-    ``B * n_lp`` long and repeat the last unit past ``n``. Dense compares
-    and sums over ``[B * n_lp, B]``: one or two fusions, no loop."""
+    Returns ``(rows, us, pages, live, n)``: unit t is the ``us[t]``-th of
+    row ``rows[t]`` and attends its logical pages ``K * us[t] ..
+    K * us[t] + K - 1`` (``K = k_pages``), which are arena pages
+    ``pages[K * t .. K * t + K - 1]``: a slot past the row's last live page
+    names that last live page again (safe to bring; the body attends a
+    unit's live pages only). Row b has ``live[b]`` live pages and
+    ``ceil(live[b] / K)`` units, in order, so a freed row (``live`` 0) has
+    none; ``n`` units in all, and one (the last row's, which then has no
+    live page) where no row has any. The lists are ``B * ceil(n_lp / K)``
+    units long and repeat the last unit past ``n``. Dense compares and sums
+    over ``[units, B]``: one or two fusions, no loop."""
     from deepspeed_tpu.inference.paging import TRASH_PAGE
 
     b, n_lp = tbl.shape
     last = _div(pos + (s_len - 1), page_len)
     live = jnp.where(tbl[:, 0] == TRASH_PAGE, 0, jnp.minimum(last + 1, n_lp))
+    units = _ceil_div(live, k_pages)
     r = jnp.arange(b, dtype=jnp.int32)
-    ends = jnp.sum(jnp.where(r[None, :] <= r[:, None], live[None, :], 0),
+    ends = jnp.sum(jnp.where(r[None, :] <= r[:, None], units[None, :], 0),
                    axis=1)                              # inclusive prefix sum
     n = jnp.maximum(ends[b - 1], 1)
-    t = jnp.minimum(jnp.arange(b * n_lp, dtype=jnp.int32), n - 1)
+    t = jnp.minimum(jnp.arange(b * -(-n_lp // k_pages), dtype=jnp.int32),
+                    n - 1)
     before = t[:, None] >= ends[None, :]                # rows wholly before t
     rows = jnp.minimum(jnp.sum(before.astype(jnp.int32), axis=1), b - 1)
-    js = t - jnp.sum(jnp.where(before, live[None, :], 0), axis=1)
-    pages = jnp.take(tbl.reshape(-1), rows * n_lp + js)
-    return rows, js, pages, live, n
+    us = t - jnp.sum(jnp.where(before, units[None, :], 0), axis=1)
+    if k_pages == 1:
+        return rows, us, jnp.take(tbl.reshape(-1), rows * n_lp + us), live, n
+    js = jnp.minimum(
+        us[:, None] * k_pages + jnp.arange(k_pages, dtype=jnp.int32),
+        jnp.maximum(jnp.take(live, rows) - 1, 0)[:, None])
+    pages = jnp.take(tbl.reshape(-1), rows[:, None] * n_lp + js)
+    return rows, us, pages.reshape(-1), live, n
 
 
-def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
-                  *refs, s_len, q8, single_kv, pack, rep=1, latent=0):
-    """One grid step = one unit of ``_paged_units``: a page of all the
-    heads (of the group, where all do not fit) of one row. Against a packed
+def _paged_kernel(rows_ref, us_ref, pages_ref, pos_ref, live_ref, q_ref,
+                  *refs, s_len, q8, single_kv, pack, rep=1, latent=0,
+                  k_pages=1):
+    """One grid step = one unit of ``_paged_units``: ``k_pages`` consecutive
+    pages of all the heads (of the group, where all do not fit) of one row,
+    a block each (``refs`` holds each arena's ``k_pages`` blocks in turn),
+    attended as ONE block of ``k_pages * page_len`` keys: one online-softmax
+    update and one rescale of the accumulator a unit. Against a packed
     arena (``pack`` heads a lane tile) a head of the block is ``pack`` heads
     of the model: query row ``r`` is row ``r % s_len`` of head
     ``r // s_len`` of them (``_pack_query``). Grouped-query heads put the
@@ -910,25 +1029,27 @@ def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
     ``r % s_len``. ``latent`` > 0 (``latent_decode``): ONE arena, whose page
     is the keys and, in its first ``latent`` lanes, the values."""
     n_a = 1 if latent else 4 if q8 else 2
-    k_ref, *more = refs[:n_a]
-    v_ref, scale_refs = (None, ()) if latent else (more[0], more[1:])
-    o_ref, stats = refs[n_a], refs[n_a + 1:]
-    page_len = k_ref.shape[1]
+    k_refs, *more = (refs[a * k_pages:(a + 1) * k_pages] for a in range(n_a))
+    v_refs, scale_refs = (None, ()) if latent else (more[0], more[1:])
+    o_ref, stats = refs[n_a * k_pages], refs[n_a * k_pages + 1:]
+    page_len = k_refs[0].shape[1]
     t = pl.program_id(1)
-    row, j = rows_ref[t], js_ref[t]
+    row, u = rows_ref[t], us_ref[t]
     pos_b, n_live = pos_ref[row], live_ref[row]
+    j = _times(u, k_pages)                      # the unit's first page
 
-    def attend():
+    def attend(n):
+        # the unit's first ``n`` pages, the live ones
         q = q_ref[0]                                   # [hb, s_blk, d]
-        k = k_ref[...]                                 # [hb, page_len, d]
-        v = k[:, :, :latent] if latent else v_ref[...]
+        ks = [ref[...] for ref in k_refs[:n]]          # [hb, page_len, d]
+        vs = [k[:, :, :latent] for k in ks] if latent \
+            else [ref[...] for ref in v_refs[:n]]
         if q8:
             # The codes are exact in the query's dtype; a key's scale
             # multiplies its score, a value's its probability.
-            k, v = k.astype(q.dtype), v.astype(o_ref.dtype)
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                                preferred_element_type=jnp.float32,
-                                precision=_mxu_precision(q.dtype))
+            ks = [k.astype(q.dtype) for k in ks]
+            vs = [v.astype(o_ref.dtype) for v in vs]
+        shape = q.shape[:2] + (page_len,)              # a page's scores
 
         def row_scales(ref):
             # [hb * pack, page_len] scales, one a head of the model and
@@ -936,17 +1057,24 @@ def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
             # scales of the head it belongs to.
             if pack == 1:
                 return ref[...][:, None, :]
-            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            out = ref[pl.ds(0, s.shape[0], stride=pack), :][:, None, :]
+            row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            out = ref[pl.ds(0, shape[0], stride=pack), :][:, None, :]
             for a in range(1, pack):
                 out = jnp.where(
                     row >= a * s_len * rep,
-                    ref[pl.ds(a, s.shape[0], stride=pack), :][:, None, :],
+                    ref[pl.ds(a, shape[0], stride=pack), :][:, None, :],
                     out)
             return out
 
-        if q8:
-            s = s * row_scales(scale_refs[0])
+        def scores(i):
+            s = jax.lax.dot_general(q, ks[i], (((2,), (2,)), ((0,), (0,))),
+                                    preferred_element_type=jnp.float32,
+                                    precision=_mxu_precision(q.dtype))
+            return s * row_scales(scale_refs[0][i]) if q8 else s
+
+        # The pages' scores side by side on the lanes, each a whole tile.
+        s = scores(0) if n == 1 else jnp.concatenate(
+            [scores(i) for i in range(n)], axis=2)
 
         def straddling():
             # Key col (global j*page_len + c) visible to query row i
@@ -962,7 +1090,7 @@ def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
 
         # Interior pages (every key visible to even the FIRST query row)
         # skip the iota/compare/select pass.
-        s = jax.lax.cond((j + 1) * page_len - 1 <= pos_b,
+        s = jax.lax.cond((j + n) * page_len - 1 <= pos_b,
                          lambda: s, straddling)
 
         def times_v(p, v):
@@ -971,25 +1099,36 @@ def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
                                        preferred_element_type=jnp.float32,
                                        precision=_mxu_precision(v.dtype))
 
+        def over_pages(p, product):
+            # the sum over the pages of ``product(p's lanes of page i,
+            # i)``: a page's keys are its own rows of the value product
+            if n == 1:
+                return product(p, 0)
+            return functools.reduce(jnp.add, [
+                product(p[:, :, i * page_len:(i + 1) * page_len], i)
+                for i in range(n)])
+
         def pv_and_rowsum(p):
             if q8:
                 # p is scaled by the values' scales before the matmul, so
                 # the row-sum is taken from p itself.
                 l = jnp.sum(p.astype(jnp.float32), axis=-1, keepdims=True)
-                scaled = p.astype(jnp.float32) * row_scales(scale_refs[1])
-                return times_v(scaled, v), l
+                return over_pages(p, lambda p_i, i: times_v(
+                    p_i.astype(jnp.float32) * row_scales(scale_refs[1][i]),
+                    vs[i])), l
             if latent:
                 # the values are whole lane tiles already: the row-sum of
                 # the probabilities as the matmul sees them, and no
                 # ``[v | 1]`` a tile wider
-                return times_v(p, v), jnp.sum(
-                    p.astype(v.dtype).astype(jnp.float32), axis=-1,
-                    keepdims=True)
+                return over_pages(p, lambda p_i, i: times_v(p_i, vs[i])), \
+                    jnp.sum(p.astype(vs[0].dtype).astype(jnp.float32),
+                            axis=-1, keepdims=True)
             # p @ [v | 1]: the row-sum rides the PV matmul, as in
             # ``_pv_rowsum``, and shares p's rounding with the numerator.
-            d = v.shape[2]
-            pv = times_v(p, jnp.concatenate(
-                [v, jnp.ones(v.shape[:2] + (1,), v.dtype)], axis=2))
+            d = vs[0].shape[2]
+            pv = over_pages(p, lambda p_i, i: times_v(p_i, jnp.concatenate(
+                [vs[i], jnp.ones(vs[i].shape[:2] + (1,), vs[i].dtype)],
+                axis=2)))
             return pv[:, :, :d], pv[:, :, d:d + 1]
 
         if single_kv:
@@ -1000,7 +1139,7 @@ def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
             return
         acc, m_s, l_s = stats
 
-        @pl.when(j == 0)
+        @pl.when(u == 0)
         def _init():
             acc[...] = jnp.zeros_like(acc)
             m_s[...] = jnp.full_like(m_s, NEG_INF)
@@ -1014,14 +1153,23 @@ def _paged_kernel(rows_ref, js_ref, pages_ref, pos_ref, live_ref, q_ref,
         l_s[...] = jnp.broadcast_to(alpha * l_prev + l_cur, l_s.shape)
         acc[...] = acc[...] * alpha + pv
 
-        @pl.when(j == n_live - 1)
+        @pl.when(u == _ceil_div(n_live, k_pages) - 1)
         def _finalize():
             l = jnp.maximum(l_s[:, :, 0:1], 1e-30)
             o_ref[0] = (acc[...] / l).astype(o_ref.dtype)
 
     # Only a batch with no live row at all has a unit without a live page
     # (the list is never empty); the launcher zeroes every dead row's output.
-    pl.when(n_live > 0)(attend)
+    @pl.when(n_live > 0)
+    def _some():
+        if k_pages == 1:
+            return attend(1)
+        # A row's last unit may hold fewer live pages than ``k_pages`` (the
+        # blocks past them hold its last live page again): a branch a count,
+        # so no matmul runs on a page that is not there.
+        jax.lax.switch(jnp.minimum(n_live - j, k_pages) - 1,
+                       [functools.partial(attend, n)
+                        for n in range(1, k_pages + 1)])
 
 
 def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1,
@@ -1035,7 +1183,8 @@ def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1,
     ``[B, Hp, pack * S, pack * D]`` against ``[.., Hp, page_len, pack * D]``,
     which the body attends as ``Hp`` heads of a whole lane tile. With
     ``rep`` > 1 (grouped-query heads) ``q`` holds a stored head's ``rep``
-    query heads on its row axis, ``[B, Hkv, rep * S, D]``."""
+    query heads on its row axis, ``[B, Hkv, rep * S, D]``. How many pages a
+    unit joins is ``_pages_per_unit``'s, from these shapes."""
     from jax.experimental.pallas import tpu as pltpu
 
     layer, arenas = _whole_arena(layer, *arenas)
@@ -1047,30 +1196,35 @@ def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1,
     s_blk = -(-n_rows // sub) * sub
     if s_blk != n_rows:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, s_blk - n_rows), (0, 0)))
-    hb = 1 if latent else _paged_heads_per_unit(
-        h, s_blk, page_len, d, q.dtype, arenas[0].dtype, pack)
+    hb, k_pages = _paged_unit(h, n_rows, d, arenas, tbl.shape[1], q.dtype,
+                              pack, rep, latent)
     d_out = latent or d
     pos = pos.astype(jnp.int32)
-    rows, js, pages, live, n_units = _paged_units(tbl.astype(jnp.int32), pos,
-                                                  s, page_len)
-    units = (rows, js, pages, pos, live)      # the scalar-prefetch operands
+    rows, us, pages, live, n_units = _paged_units(
+        tbl.astype(jnp.int32), pos, s, page_len, k_pages)
+    units = (rows, us, pages, pos, live)      # the scalar-prefetch operands
 
     def q_index(g, t, rows_ref, *_):
         return (rows_ref[t], g, 0, 0)
 
-    def page_spec(arena):
-        # [hb, page_len, d] of a row arena, [hb * pack, page_len] of a
-        # scale arena (a scale a head of the model).
+    def page_spec(arena, i):
+        # The unit's i-th page: [hb, page_len, d] of a row arena,
+        # [hb * pack, page_len] of a scale arena (a scale a head of the
+        # model).
         zeros = (0,) * (arena.ndim - 3)
+
+        def page(pages_ref, t):
+            return pages_ref[t if k_pages == 1 else t * k_pages + i]
+
         if latent:      # every group of query heads reads the one head
             return pl.BlockSpec(
                 (None, None, 1) + arena.shape[3:],
-                lambda g, t, rows_ref, js_ref, pages_ref, *_:
-                (layer, pages_ref[t], 0) + zeros)
+                lambda g, t, rows_ref, us_ref, pages_ref, *_:
+                (layer, page(pages_ref, t), 0) + zeros)
         return pl.BlockSpec(
             (None, None, hb * (arena.shape[2] // h)) + arena.shape[3:],
-            lambda g, t, rows_ref, js_ref, pages_ref, *_:
-            (layer, pages_ref[t], g) + zeros)
+            lambda g, t, rows_ref, us_ref, pages_ref, *_:
+            (layer, page(pages_ref, t), g) + zeros)
 
     single_kv = tbl.shape[1] == 1
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -1079,7 +1233,7 @@ def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1,
         # output block and the statistics live from its first to its last.
         grid=(h // hb, n_units),
         in_specs=[pl.BlockSpec((1, hb, s_blk, d), q_index)]
-        + [page_spec(a) for a in arenas],
+        + [page_spec(a, i) for a in arenas for i in range(k_pages)],
         out_specs=pl.BlockSpec((1, hb, s_blk, d_out), q_index),
         scratch_shapes=[] if single_kv else [
             pltpu.VMEM((hb, s_blk, d_out), jnp.float32),
@@ -1091,10 +1245,10 @@ def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1,
         name,
         functools.partial(_paged_kernel, s_len=s, q8=len(arenas) == 4,
                           single_kv=single_kv, pack=pack, rep=rep,
-                          latent=latent),
+                          latent=latent, k_pages=k_pages),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d_out), q.dtype),
-    )(*units, q, *arenas)
+    )(*units, q, *(a for a in arenas for _ in range(k_pages)))
     # A freed row has no unit, so the kernel never wrote its block: zeros,
     # in a select that fuses into whatever reads the output.
     out = jnp.where((live > 0)[:, None, None, None], out, 0)
@@ -1215,7 +1369,14 @@ def flash_decode_attention_paged_q8(q, k, v, k_scale, v_scale, block_tbl,
 # body and launcher on a cache that is not a k/v pair. A token stores ONE
 # head of width W (DeepSeek-V3: [c_kv (512) | k_r (64) | zeros to 640]) and
 # all H query heads read it, so the arena is ``[L, P, 1, page_len, W]`` and
-# there is no value arena: a page's first ``rank`` lanes ARE its values. To
+# there is no value arena: a page's first ``rank`` lanes ARE its values. A
+# UNIT IS A RUN OF UP TO FOUR CONSECUTIVE LIVE PAGES OF ONE ROW (PR 39): one
+# page of the one stored head is 164 KB, 0.2 us of stream and 0.19 us of
+# matmul under a grid step's half microsecond, so the decode scan's call
+# joins K = 4 (656 KB a unit, under the 1 MiB unit that reads near its bound;
+# ``_pages_per_unit``), a row of ten live pages is three units where it was
+# ten, and the kernel alone runs 0.64 ms a call where it ran 1.10 (K = 8:
+# 0.60, past the rule's size). To
 # the body this is grouped-query attention taken to its end: the ``H`` query
 # heads of the one stored head sit beside S on the sublane axis (H = 128 and
 # S = 1 make a full 128-row tile of the MXU where ``paged_decode`` has 1 to
@@ -1227,18 +1388,21 @@ def flash_decode_attention_paged_q8(q, k, v, k_scale, v_scale, block_tbl,
 # 16,384 rows would need 40 MB of VMEM, so the query heads go in GROUPS (the
 # launcher's outer grid axis, the largest divisor of H whose blocks fit
 # ``_PAGED_VMEM_BUDGET``: 8 heads at S = 128), each group a pass over the
-# row's pages.
+# row's pages, a page a unit (1,024 rows are work enough a page, and VMEM is
+# full of them).
 # ---------------------------------------------------------------------------
 
-def _latent_heads_per_unit(h, s_len, page_len, w, rank, dtype):
+def _latent_heads_per_unit(h, s_len, page_len, w, rank, dtype, k_pages=1):
     """Query heads one unit of ``latent_decode`` attends: the largest
     divisor of ``h`` whose rows' blocks stay in ``_PAGED_VMEM_BUDGET`` (q
     and out double-buffered, the float32 accumulator, statistics, scores
-    and probabilities; the page itself is small beside them)."""
+    and probabilities over the unit's ``k_pages`` pages; the pages
+    themselves, double-buffered, are small beside them)."""
     b = jnp.dtype(dtype).itemsize
+    keys = k_pages * page_len
     per_row = (2 * w * b + 2 * rank * b + rank * 4
-               + 2 * _STATS_LANES * 4 + 2 * page_len * 4)
-    fit = max((_PAGED_VMEM_BUDGET - 2 * page_len * w * b)
+               + 2 * _STATS_LANES * 4 + 2 * keys * 4)
+    fit = max((_PAGED_VMEM_BUDGET - 2 * keys * w * b)
               // (per_row * s_len), 1)
     return max(g for g in range(1, h + 1) if h % g == 0 and g <= fit)
 

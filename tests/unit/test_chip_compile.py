@@ -169,6 +169,25 @@ def _olmoe_append(k, v, ka, va, tbl, pos):
     return da.kv_append((ka, va), (k, v), tbl, pos, layer=O_LAYERS - 1)
 
 
+# DeepSeek-V3's latent cache (serve-dsv3-decode-closed): ONE arena of 640
+# stored lanes a token, 6 layers, 128 slots x 24 pages of 128 + trash; 128
+# query heads read its one stored head, values the first 512 lanes.
+D_SLOTS, D_HEADS, D_W, D_RANK = 128, 128, 640, 512
+D_ARENA = ((6, D_SLOTS * 24 + 1, 1, PAGE, D_W), BF16)
+
+
+def _latent_args(rows, slots):
+    return [((slots, D_HEADS, rows, D_W), BF16), D_ARENA,
+            ((slots, 24), I32), ((slots,), I32)]
+
+
+def _latent(name=None):
+    def run(q, arena, tbl, pos):
+        return da.latent_decode(q, arena, tbl, pos, D_RANK, 0.135, name=name,
+                                layer=5)
+    return run
+
+
 def _dense_decode_args(rows, int8=False):
     plane = ((SLOTS, HEADS, T_KV, HD), I8 if int8 else BF16)
     scale = ((SLOTS, HEADS, T_KV), F32)
@@ -269,6 +288,12 @@ CASES = {
                                      _olmoe_append_args(1, O_SLOTS), {}),
     "olmoe_kv_append_lane_128_rows_d128": (_olmoe_append,
                                            _olmoe_append_args(128, 1), {}),
+    # The decode scan's call (all 128 heads of a row one unit) and the lane's
+    # (S = 128: 8 heads a group) of the latent cache's kernel.
+    "latent_decode_128_slots_1_row": (_latent(), _latent_args(1, D_SLOTS),
+                                      {}),
+    "latent_prefill_attn_lane_128_rows": (
+        _latent("prefill_attn"), _latent_args(128, 1), {}),
     "fused_layer_norm_fwd_bwd": (
         _fwd_bwd(lambda x, g, b: layer_norm.fused_layer_norm(x, g, b), 3),
         [LN_X, VEC, VEC], {}),
@@ -297,6 +322,7 @@ CASES = {
 KERNEL_NAME = re.compile(
     r"^(flash_fwd|flash_bwd_fused|flash_bwd_dq|flash_bwd_dkv|decode_attn|"
     r"decode_attn_q8|paged_decode|paged_decode_q8|prefill_attn|kv_append|"
+    r"latent_decode|"
     r"sparse_attn_fwd|sparse_attn_bwd_fused|sparse_attn_bwd_dq|"
     r"sparse_attn_bwd_dkv|dropout_fwd|dropout_mask|bias_gelu|"
     r"layer_norm_fwd|attn_softmax)(\.\d+)?$")
@@ -347,6 +373,37 @@ def test_kernel_compiles_for_v5e(name, chip, monkeypatch):
     assert _kernel_calls(text) and all(
         KERNEL_NAME.match(c) for c in _kernel_calls(text)), \
         _kernel_calls(text)
+
+
+@pytest.mark.parametrize("name, pages", [
+    ("latent_decode_128_slots_1_row", 4),
+    ("latent_prefill_attn_lane_128_rows", 1),
+    ("packed_paged_decode_1_row_d64", 1),
+    ("packed_paged_lane_128_rows_d64", 1),
+    ("olmoe_paged_decode_32_rows_d128", 1),
+    ("olmoe_prefill_attn_lane_128_rows_d128", 1)])
+def test_pages_a_unit_in_the_compiled_kernel(name, pages, chip, monkeypatch):
+    """K, the pages one unit of a paged kernel joins, read off the compiled
+    call: each arena is an operand once a page of the unit. The latent
+    cache's decode call joins four 164 KB pages (inside its VMEM reckoning:
+    all 128 heads still one unit, and Mosaic's scoped limit, or the compile
+    fails) and its lane's call one; GPT-2's packed and OLMoE's calls are at
+    512 KB and 1 MB a page and stay at one, the program they always were."""
+    fn, shapes, _ = CASES[name]
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    call, = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    arena = "bf16[{}]{{".format(",".join(str(n) for n in shapes[1][0]))
+    operands = call.split("operand_layout_constraints={")[1]
+    assert operands.count(arena) == pages * shapes.count(shapes[1]), call
+    if name.startswith("latent"):
+        s_len = shapes[0][0][2]
+        assert da._latent_heads_per_unit(
+            D_HEADS, s_len, PAGE, D_W, D_RANK, BF16, pages) == \
+            da._latent_heads_per_unit(D_HEADS, s_len, PAGE, D_W, D_RANK, BF16)
 
 
 # ------------------------------------------------- the serving step itself

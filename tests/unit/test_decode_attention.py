@@ -811,9 +811,11 @@ def test_paged_heads_per_unit_from_shapes_alone():
 
 def test_paged_head_groups_match_all_heads_at_once(monkeypatch):
     """The fallback path (an outer grid axis over head groups) gives what
-    all heads a unit give, bit for bit."""
+    all heads a unit give, bit for bit, at the same pages a unit (one:
+    heads in groups never join pages, and pages joined round otherwise)."""
     q, arenas, tbl, pos = _paged_operands(
         64, 5, _frontiers(5), False, jnp.bfloat16, frozen=(2,), h=4)
+    monkeypatch.setattr(da, "_UNIT_BYTES", 0)
     whole = da.flash_decode_attention_paged(q, *arenas, tbl, pos, layer=1)
     monkeypatch.setattr(da, "_paged_heads_per_unit", lambda h, *a: 2)
     grouped = da.flash_decode_attention_paged(q, *arenas, tbl, pos, layer=1)

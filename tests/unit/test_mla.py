@@ -173,16 +173,34 @@ def latent_case(rows, heads, s, n_lp, frontiers, page=128, w=256, rank=128,
     return q, arena, tbl, jnp.asarray(frontiers, jnp.int32), rank
 
 
+def force_pages(monkeypatch, k, page_bytes=128 * 256 * 4):
+    """K pages a unit for ``latent_case``'s float32 pages, through the
+    rule's own constant (so the rule's other clauses still hold: never more
+    pages than a row's table has, one for a one-page table)."""
+    monkeypatch.setattr(da, "_UNIT_BYTES", k * page_bytes + 1)
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
 @pytest.mark.parametrize("n_lp, frontiers, s", [
-    (1, (0, 90, 127 - 4), 5), (3, (200, 17, 383), 1), (3, (130, 250, 5), 4)],
-    ids=["one_page", "three_pages_decode", "three_pages_chunk"])
-def test_latent_decode_interpreted_is_the_jnp_paged_reference(n_lp,
-                                                              frontiers, s):
+    (1, (0, 90, 127 - 4), 5), (3, (200, 17, 383), 1), (3, (130, 250, 5), 4),
+    (7, (128 * 5 + 3, 128 * 7 - 1, 300), 1),
+    (7, (126, 128 * 4 + 125, 128 * 6 + 60), 4)],
+    ids=["one_page", "three_pages_decode", "three_pages_chunk",
+         "seven_pages_decode", "seven_pages_chunk"])
+def test_latent_decode_interpreted_is_the_jnp_paged_reference(
+        n_lp, frontiers, s, k, monkeypatch):
     """The kernel body (Pallas interpreter) against gather + softmax in
-    ``jax.numpy``: one page a row (the direct softmax) and several (the
-    online one), frontiers that straddle a page, S = 1 and a chunk, and a
+    ``jax.numpy``, at ``k`` pages a unit: one page a row (the direct
+    softmax) and several (the online one), live pages that are no multiple
+    of ``k`` (6, 7 and 3 of seven), frontiers that straddle a page, inside a
+    unit's second page (at 4: 128 x 5 + 3) and its last (128 x 7 - 1), and a
+    chunk across two pages of one unit and of two, S = 1 and a chunk, and a
     layer picked out of the whole arena by the index map."""
+    force_pages(monkeypatch, k)
     q, arena, tbl, pos, rank = latent_case(3, 8, s, n_lp, frontiers)
+    assert da.unit_pages([arena], 8, None, n_lp, q.dtype, s_len=s,
+                         latent=rank) == max(
+                             j for j in (1, 2, 4) if j <= min(k, n_lp))
     got = da.latent_decode(q, arena, tbl, pos, rank, 0.3, layer=1)
     want = da.latent_decode_reference(q, arena[1], tbl, pos, rank, 0.3)
     assert got.shape == (3, 8, s, rank)
@@ -191,18 +209,82 @@ def test_latent_decode_interpreted_is_the_jnp_paged_reference(n_lp,
     np.testing.assert_array_equal(np.asarray(one), np.asarray(got))
 
 
-def test_latent_decode_skips_dead_pages_and_freed_rows():
-    """Pages past a row's frontier are no units (garbage there, NaN even,
-    changes nothing) and a freed row (its table at the trash page) is not
-    attended: zeros."""
-    q, arena, tbl, pos, rank = latent_case(3, 8, 1, 3, (140, 20, 0))
-    tbl = tbl.at[2].set(0)
-    want = da.latent_decode_reference(q[:2], arena[1], tbl[:2], pos[:2], rank,
-                                      0.3)
-    dead = arena.at[1, tbl[0, 2]].set(jnp.nan).at[1, tbl[1, 1:]].set(jnp.nan)
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_latent_decode_skips_dead_pages_and_freed_rows(k, monkeypatch):
+    """Pages past a row's frontier are attended by no unit, the dead pages
+    of a LIVE unit among them (row 0's third and fourth at 4 pages a unit,
+    row 2's last three of its second unit): garbage there, NaN even, changes
+    nothing. A freed row (its table at the trash page), here between live
+    rows, is not attended: zeros."""
+    force_pages(monkeypatch, k)
+    q, arena, tbl, pos, rank = latent_case(4, 8, 1, 7,
+                                           (140, 500, 128 * 4 + 9, 20))
+    tbl = tbl.at[1].set(0)
+    keep = np.asarray([0, 2, 3])
+    want = da.latent_decode_reference(q[keep], arena[1], tbl[keep], pos[keep],
+                                      rank, 0.3)
+    dead = arena.at[1, tbl[0, 2:]].set(jnp.nan).at[1, tbl[2, 5:]].set(
+        jnp.nan).at[1, tbl[3, 1:]].set(jnp.nan)
     got = da.latent_decode(q, dead, tbl, pos, rank, 0.3, layer=1)
-    np.testing.assert_allclose(np.asarray(got[:2]), np.asarray(want), **SAME)
-    assert float(jnp.abs(got[2]).max()) == 0.0
+    np.testing.assert_allclose(np.asarray(got[keep]), np.asarray(want),
+                               **SAME)
+    assert float(jnp.abs(got[1]).max()) == 0.0
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_the_work_list_holds_a_rows_live_pages_in_runs_of_k(k):
+    """``_paged_units``: ``ceil(live / k)`` units a row, in order, none for
+    a freed row; unit ``u`` of a row names its pages ``k u .. k u + k - 1``
+    and, past the last live one, that one again."""
+    n_lp = 7
+    tbl = np.asarray(1 + np.arange(5 * n_lp).reshape(5, n_lp), np.int32)
+    tbl[2] = 0                                    # freed, between live rows
+    pos = np.asarray([0, 128 * 3 - 1, 50, 128 * 7 - 1, 128 * 4], np.int32)
+    live = [1, 3, 0, 7, 5]
+    rows, us, pages, got_live, n = (np.asarray(x) for x in da._paged_units(
+        jnp.asarray(tbl), jnp.asarray(pos), 1, 128, k))
+    assert got_live.tolist() == live
+    want = [(b, u) for b in range(5) for u in range(-(-live[b] // k))]
+    assert int(n) == len(want) == sum(-(-x // k) for x in live)
+    assert rows.shape == us.shape == (5 * -(-n_lp // k),)
+    assert list(zip(rows[:n].tolist(), us[:n].tolist())) == want
+    assert (rows[n:] == want[-1][0]).all() and (us[n:] == want[-1][1]).all()
+    assert pages.shape == (rows.size * k,)
+    for t, (b, u) in enumerate(want):
+        assert pages[t * k:(t + 1) * k].tolist() == [
+            tbl[b, min(k * u + i, live[b] - 1)] for i in range(k)]
+    # no live row at all: one unit, the last row's, which the body skips
+    rows, us, pages, got_live, n = da._paged_units(
+        jnp.zeros((3, n_lp), jnp.int32), jnp.asarray(pos[:3]), 1, 128, k)
+    assert int(n) == 1 and int(rows[0]) == 2 and not np.asarray(
+        got_live).any() and not np.asarray(pages).any()
+
+
+def _arenas(shape, n=2, dtype=jnp.bfloat16):
+    return [jax.ShapeDtypeStruct(shape, dtype)] * n
+
+
+@pytest.mark.parametrize("name, arenas, heads, head_dim, n_lp, s, latent, k", [
+    # the cell's decode scan: 128 rows of 128 heads, 4 x 164 KB a unit
+    ("dsv3_decode", _arenas((6, 3073, 1, 128, 640), 1), 128, 192, 24, 1, 512,
+     4),
+    ("dsv3_verify", _arenas((6, 3073, 1, 128, 640), 1), 128, 192, 24, 5, 512,
+     4),
+    # its lane: 8 heads a group, 1,024 rows a unit, work enough a page
+    ("dsv3_lane", _arenas((6, 3073, 1, 128, 640), 1), 128, 192, 24, 128, 512,
+     1),
+    # every ``paged_decode`` shape of the benchmark: 512 KB or 1 MB a page
+    ("gpt2_decode", _arenas((24, 145, 8, 128, 128)), 16, 64, 9, 1, 0, 1),
+    ("gpt2_lane", _arenas((24, 145, 8, 128, 128)), 16, 64, 9, 128, 0, 1),
+    ("olmoe_decode", _arenas((8, 545, 16, 128, 128)), 16, 128, 17, 1, 0, 1),
+    ("olmoe_lane", _arenas((8, 545, 16, 128, 128)), 16, 128, 17, 128, 0, 1),
+    ("granite_decode", _arenas((1, 1217, 8, 128, 128)), 32, 128, 19, 1, 0, 1),
+    ("granite_lane", _arenas((1, 1217, 8, 128, 128)), 32, 128, 19, 128, 0, 1),
+], ids=lambda x: x if isinstance(x, str) else None)
+def test_pages_a_unit_come_from_the_shapes(name, arenas, heads, head_dim,
+                                           n_lp, s, latent, k):
+    assert da.unit_pages(arenas, heads, head_dim, n_lp, jnp.bfloat16,
+                         s_len=s, latent=latent) == k
 
 
 def test_a_lane_of_many_heads_goes_in_groups_of_heads():

@@ -634,12 +634,51 @@ def test_prometheus_exports_page_gauge_family():
     assert kinds["ds_tpu_kv_lane_pack"] == "gauge"
     assert sample("ds_tpu_kv_lane_pack") == eng.metrics()["kv_lane_pack"] \
         == 4 == eng._pool["k"].shape[-1] // 16
+    # Pages a unit of the paged kernel joins: one for pages of 8, which are
+    # no kernel block, so every unit is full.
+    assert kinds["ds_tpu_kv_unit_pages"] == kinds["ds_tpu_kv_unit_fill"] \
+        == "gauge"
+    assert sample("ds_tpu_kv_unit_pages") == 1 == \
+        eng.metrics()["kv_unit_pages"]
+    assert sample("ds_tpu_kv_unit_fill") == 1.0 == \
+        eng.metrics()["kv_unit_fill"]
     eng.run()
     _, drained = _parse_prom(eng.prometheus())
     assert [v for (n, _), v in drained.items()
             if n == "ds_tpu_kv_pages_in_use"][0] == 0
     assert [v for (n, _), v in drained.items()
             if n == "ds_tpu_kv_live_page_share"][0] == 0
+    assert [v for (n, _), v in drained.items()
+            if n == "ds_tpu_kv_unit_fill"][0] == 0
+
+
+def test_unit_gauges_say_how_far_pages_are_joined():
+    """``kv_unit_pages`` is K as the launcher's rule resolves it for the
+    pool's own arenas (here pages of 128 of ONE stored head of 64 float32
+    lanes, 64 KB of keys and values: the two pages a row's table holds),
+    and ``kv_unit_fill`` the live pages over K x units at the last harvest:
+    a row in its first page holds one live page of a unit of two."""
+    cfg, model, params = make_model()
+    eng = paged_engine_of(model, params, kv_page_len=128, max_len=128)
+    for p in prompts_of(cfg, [6, 9]):
+        eng.submit(p, max_new_tokens=6)
+    eng.step()
+    eng.step()
+    pool, m = eng._pool, eng.metrics()
+    n_lp = pool["block_tbl"].shape[1]
+    k = da.unit_pages([pool["k"], pool["v"]], cfg.n_head, 16, n_lp,
+                      pool["k"].dtype)
+    assert n_lp == 2 and k == 2 == m["kv_unit_pages"]
+    live = [int(p) // 128 + 1 for s, p in enumerate(eng._last_snap["pos"])
+            if eng._pager.table[s, 0] != 0]
+    assert live and set(live) == {1}
+    assert m["kv_unit_fill"] == sum(live) / (k * sum(
+        -(-x // k) for x in live)) == 0.5
+    _, samples = _parse_prom(eng.prometheus())
+    assert [v for (n, _), v in samples.items()
+            if n == "ds_tpu_kv_unit_pages"] == [2]
+    assert [v for (n, _), v in samples.items()
+            if n == "ds_tpu_kv_unit_fill"] == [0.5]
 
 
 def test_pick_swap_victim_scores_live_pages():
