@@ -667,7 +667,7 @@ class InferenceEngine(object):
         self.timers = SynchronizedWallClockTimer(registry=self.telemetry)
         self.counters = _CounterBank(self.telemetry, (
             "tokens_out", "chunks", "steps_dispatched_ahead", "prefills",
-            "prefill_tokens",
+            "prefill_tokens", "lane_steps",
             "requests_completed", "occupied_slot_steps", "slot_steps",
             # Resilience counters (docs/RESILIENCE.md). deadline_sheds
             # and faults_injected are get-or-create by name, so the
@@ -739,6 +739,16 @@ class InferenceEngine(object):
         self.telemetry.gauge("steps_ahead_share").set_fn(
             lambda: self.counters["steps_dispatched_ahead"]
             / float(max(self._steps, 1)))
+        # The one prefill lane's load, over all harvested steps: the share
+        # of them whose lane carried a slice, and how full those slices
+        # were (a prompt's last slice is as short as what is left of it).
+        self.telemetry.gauge("lane_busy_share").set_fn(
+            lambda: self.counters["lane_steps"]
+            / float(max(self.counters["chunks"], 1)))
+        self.telemetry.gauge("lane_fill").set_fn(
+            lambda: self.counters["prefill_tokens"]
+            / float(max(self.counters["lane_steps"], 1)
+                    * self.config.prefill_chunk))
         self.telemetry.gauge("kv_pool_bytes").set_fn(
             lambda: pool_nbytes(self._pool))
         # Same footprint under the name the capacity dashboards key on:
@@ -797,6 +807,12 @@ class InferenceEngine(object):
         self._ttft_hist = self.telemetry.histogram("ttft_seconds")
         self._itl_hist = self.telemetry.histogram("inter_token_seconds")
         self._qwait_hist = self.telemetry.histogram("queue_wait_seconds")
+        # The three parts of admit -> first token (Request.phase_ms),
+        # observed once a request beside ttft_seconds.
+        self._lane_wait_hist = self.telemetry.histogram("lane_wait_seconds")
+        self._lane_run_hist = self.telemetry.histogram("lane_run_seconds")
+        self._first_lag_hist = self.telemetry.histogram(
+            "first_token_lag_seconds")
         # Disaggregated serving (fleet roles). The role is a routing/
         # capture contract, not a program variant: every role runs the
         # same mixed-step program (the prefill lane cond-skips when
@@ -1192,20 +1208,25 @@ class InferenceEngine(object):
                               .at[slot].set(False))
         return True
 
-    def _harvest_first(self, req, first, done):
+    def _harvest_first(self, req, first, done, step):
         """Record a request's first token (TTFT stamps HERE — at
         harvest, after the device sync — never at dispatch). On a
         RECOVERY REPLAY the prefill lane's "first token" is really
         token m+1 of one continuous stream: it is appended like any
-        emission, but first_token_time/TTFT stamp only once — the
-        original first token's latency is the only TTFT truth."""
+        emission, but first_token_time/TTFT and its three parts stamp
+        only once — the original first token's latency is the only TTFT
+        truth. ``step``: the device step the token was harvested from."""
         req.tokens.append(first)
         if req.first_token_time is None:
             req.first_token_time = time.time()
             self._ttft_hist.observe(req.first_token_time - req.submit_time)
+            self._lane_wait_hist.observe(req.lane_time - req.admit_time)
+            self._lane_run_hist.observe(req.last_slice_time - req.lane_time)
+            self._first_lag_hist.observe(
+                req.first_token_time - req.last_slice_time)
             self.tracer.instant(
                 "request/first_token", tid=req.trace.tid, rid=req.rid,
-                hop=req.trace.hop(), **req.phase_ms())
+                hop=req.trace.hop(), step=step, **req.phase_ms())
         self.counters["tokens_out"] += 1
         if req.max_new_tokens <= 1 or \
                 (req.eos_token_id >= 0 and first == req.eos_token_id):
@@ -2040,6 +2061,31 @@ class InferenceEngine(object):
         timer = self.timers("inference/decode")
         if not timer.running:
             timer.start()
+        if pf is not None:
+            # The lane's stamps, on admit_time's clock and once a request
+            # (a recovery replay keeps the first, as admit_time does): a
+            # request's way to its first token is admit -> lane_time ->
+            # last_slice_time -> first token (Request.phase_ms).
+            now = time.time()
+            pf.slices += 1
+            if pf.lane_time is None:
+                pf.lane_time = now
+            if p_done and pf.last_slice_time is None:
+                pf.last_slice_time = now
+            # At most one slice a step, so two instants: ``step`` is the
+            # device step the slice rides (the ``inference/mixed_step``
+            # span's below), ``cursor`` where in the prompt it starts,
+            # ``slices`` its number since the admission.
+            phases = pf.phase_ms()
+            self.tracer.instant(
+                "request/slice", tid=pf.trace.tid, rid=pf.rid,
+                hop=pf.trace.hop(), slot=slot, step=step, tokens=n_valid,
+                cursor=frontier, slices=pf.slices, **phases)
+            if p_done:
+                self.tracer.instant(
+                    "request/last_slice", tid=pf.trace.tid, rid=pf.rid,
+                    hop=pf.trace.hop(), step=step, slices=pf.slices,
+                    **phases)
         t_dispatch = time.perf_counter()
         with self.tracer.timed("inference/mixed_step", step=step,
                                prefill_tokens=n_valid,
@@ -2154,6 +2200,7 @@ class InferenceEngine(object):
             pf = flight.pf
             if pf is not None:
                 self.counters["prefill_tokens"] += flight.n_valid
+                self.counters["lane_steps"] += 1
                 if flight.p_done and not pf.done:
                     self.counters["prefills"] += 1
                     if self._hier is not None:
@@ -2162,7 +2209,7 @@ class InferenceEngine(object):
                         # (eager copy; no compile).
                         self._pool = self._hier.on_prefill_done(self._pool, pf)
                     self._scheduler.prefill_done(pf, flight.lane_slot)
-                    self._harvest_first(pf, int(first), done)
+                    self._harvest_first(pf, int(first), done, step)
 
             harvest_t = time.time()
             for slot, req in flight.rows.items():
@@ -2314,8 +2361,10 @@ class InferenceEngine(object):
         bounded-reservoir histograms — windowed like everything else in
         metrics(), and the same series Prometheus exports as summary
         quantiles. TTFT is submit -> first harvested token; queue wait
-        submit -> admit; inter-token the mean gap per completed request
-        ((finish - first) / (tokens - 1))."""
+        submit -> admit; lane wait, lane run and first-token lag the three
+        parts of admit -> first token (``Request.phase_ms``); inter-token
+        the mean gap per completed request ((finish - first) /
+        (tokens - 1))."""
         def pct(h, p):
             v = h.percentile(p)
             return round(v * 1e3, 3) if v is not None else None
@@ -2327,6 +2376,12 @@ class InferenceEngine(object):
             "inter_token_p99_ms": pct(self._itl_hist, 99),
             "queue_wait_p50_ms": pct(self._qwait_hist, 50),
             "queue_wait_p99_ms": pct(self._qwait_hist, 99),
+            "lane_wait_p50_ms": pct(self._lane_wait_hist, 50),
+            "lane_wait_p99_ms": pct(self._lane_wait_hist, 99),
+            "lane_run_p50_ms": pct(self._lane_run_hist, 50),
+            "lane_run_p99_ms": pct(self._lane_run_hist, 99),
+            "first_token_lag_p50_ms": pct(self._first_lag_hist, 50),
+            "first_token_lag_p99_ms": pct(self._first_lag_hist, 99),
         }
 
     def metrics(self, reset=False):
@@ -2353,6 +2408,14 @@ class InferenceEngine(object):
             "steps_ahead_share": min(
                 c.window("steps_dispatched_ahead")
                 / float(max(c.window("chunks"), 1)), 1.0),
+            # The one prefill lane in the window: steps whose lane carried
+            # a slice, their share of the steps harvested, and how full
+            # those slices were.
+            "lane_steps": c.window("lane_steps"),
+            "lane_busy_share": c.window("lane_steps")
+            / float(max(c.window("chunks"), 1)),
+            "lane_fill": c.window("prefill_tokens") / float(
+                max(c.window("lane_steps"), 1) * self.config.prefill_chunk),
             "tokens_per_sec": c.window("tokens_out") / wall,
             "slot_occupancy": (c.window("occupied_slot_steps") /
                                max(c.window("slot_steps"), 1)),

@@ -106,12 +106,17 @@ class QueueFull(RuntimeError):
         self.reason = reason
 
 
+def _ms(earlier, later):
+    return round((later - earlier) * 1e3, 3)
+
+
 class Request(object):
     """One generation request and its accumulated output."""
 
     __slots__ = ("rid", "prompt", "max_new_tokens", "temperature", "top_k",
                  "eos_token_id", "seed", "spec", "tokens", "slot", "phase",
-                 "cursor", "submit_time", "admit_time", "first_token_time",
+                 "cursor", "submit_time", "admit_time", "lane_time",
+                 "last_slice_time", "slices", "first_token_time",
                  "finish_time", "deadline", "replays", "last_touch",
                  "priority", "tenant", "trace", "sent")
 
@@ -154,6 +159,16 @@ class Request(object):
         self.sent = 0
         self.submit_time = time.time()
         self.admit_time = None
+        # The way through the one prefill lane, on the clock of
+        # ``admit_time``: the dispatch of the prompt's FIRST slice, the
+        # dispatch of the slice that exhausts the prompt (the same instant
+        # for a one-slice prompt), and the slices dispatched since the
+        # admission. The engine stamps the two times once a request
+        # (``_dispatch_step``): a recovery replay keeps the first stamps, as
+        # ``admit_time`` does.
+        self.lane_time = None
+        self.last_slice_time = None
+        self.slices = 0
         self.first_token_time = None
         self.finish_time = None
         # Absolute wall-clock expiry (None: no deadline). Checked QUEUE-
@@ -187,16 +202,26 @@ class Request(object):
     def phase_ms(self):
         """The phases this request has left behind, in milliseconds, as
         arguments for its instants: ``queue_ms`` (submit -> admit) once
-        admitted, ``prefill_ms`` (admit -> first token) once it has one. A
-        reader of a trace that opened after a transition finds the phase
-        on any later instant of the request."""
+        admitted, ``prefill_ms`` (admit -> first token) once it has one,
+        and the three parts of ``prefill_ms`` as each becomes known:
+        ``lane_wait_ms`` (admit -> the dispatch of its first slice),
+        ``lane_run_ms`` (-> the dispatch of its last slice) and
+        ``first_lag_ms`` (-> first token at the host). A reader of a trace
+        that opened after a transition finds the phase on any later
+        instant of the request."""
         out = {}
-        if self.admit_time is not None:
-            out["queue_ms"] = round(
-                (self.admit_time - self.submit_time) * 1e3, 3)
-            if self.first_token_time is not None:
-                out["prefill_ms"] = round(
-                    (self.first_token_time - self.admit_time) * 1e3, 3)
+        if self.admit_time is None:
+            return out
+        out["queue_ms"] = _ms(self.submit_time, self.admit_time)
+        if self.lane_time is not None:
+            out["lane_wait_ms"] = _ms(self.admit_time, self.lane_time)
+            if self.last_slice_time is not None:
+                out["lane_run_ms"] = _ms(self.lane_time, self.last_slice_time)
+        if self.first_token_time is not None:
+            out["prefill_ms"] = _ms(self.admit_time, self.first_token_time)
+            if self.last_slice_time is not None:
+                out["first_lag_ms"] = _ms(self.last_slice_time,
+                                          self.first_token_time)
         return out
 
 
@@ -384,6 +409,7 @@ class Scheduler(object):
             req.slot = slot
             req.phase = "prefilling"
             req.cursor = 0
+            req.slices = 0
             req.sent = 0
             self.running[slot] = req
             pairs.append((req, slot))

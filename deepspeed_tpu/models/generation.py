@@ -711,8 +711,6 @@ def generate(model, params, prompt_ids, max_new_tokens, temperature=1.0,
     Returns [B, max_new_tokens] int32. Rows that emit ``eos_token_id``
     keep repeating it (fixed-length output; trim host-side).
     """
-    from deepspeed_tpu.telemetry import annotate
-
     cfg = as_gencfg(getattr(model, "config", model))
     assert max_new_tokens >= 1
     if rng is None:
@@ -722,6 +720,6 @@ def generate(model, params, prompt_ids, max_new_tokens, temperature=1.0,
         "prompt + new tokens exceed n_positions={}".format(cfg.n_positions)
     # Host-side profiler scope around the whole-batch dispatch: shows up
     # as one "generation.generate" block on a DS_TPU_PROFILE_DIR capture.
-    with annotate("generation.generate"):
+    with jax.profiler.TraceAnnotation("generation.generate"):
         return _generate_jit(params, cfg, prompt_ids, int(max_new_tokens),
                              float(temperature), top_k, rng, eos_token_id)

@@ -10,9 +10,8 @@ One dependency-free subsystem every engine emits into:
   snapshots in a bounded ring — the per-window TTFT/ITL/queue-depth
   curves the sustained-load harness (loadgen/) reports, exportable as
   Chrome counter events next to the span export.
-- ``RecompileDetector`` / ``annotate`` / ``profile_window``
-  (instrumentation.py): jit cache-miss detection as a live gauge,
-  ``jax.profiler.TraceAnnotation`` scoping, and the
+- ``RecompileDetector`` / ``profile_window`` (instrumentation.py): jit
+  cache-miss detection as a live gauge and the
   ``DS_TPU_PROFILE_DIR``-gated capture window.
 - ``prometheus_text`` / ``PrometheusEndpoint`` /
   ``TensorBoardScalarWriter`` (exporters.py): the read-side. The
@@ -58,7 +57,6 @@ from deepspeed_tpu.telemetry.exporters import (
 from deepspeed_tpu.telemetry.instrumentation import (
     PROFILE_DIR_ENV,
     RecompileDetector,
-    annotate,
     profile_window,
 )
 from deepspeed_tpu.telemetry.registry import (
@@ -89,7 +87,6 @@ __all__ = [
     "NullRecorder",
     "SpanRecorder",
     "RecompileDetector",
-    "annotate",
     "profile_window",
     "PROFILE_DIR_ENV",
     "prometheus_text",

@@ -1,6 +1,6 @@
 """JAX/XLA instrumentation: recompile detection + profiler hooks.
 
-Three pieces, all optional and all safe when jax is absent or old:
+Two pieces, both optional and both safe when jax is absent or old:
 
 - ``RecompileDetector``: turns the test-only ``compile_count == 1``
   contract into a RUNTIME gauge. Watches a set of jitted callables
@@ -9,11 +9,6 @@ Three pieces, all optional and all safe when jax is absent or old:
   miss as a RECOMPILE (counter + one warning log per event, naming the
   program that grew). A mixed serving workload is expected to hold
   recompiles at 0 forever — when it doesn't, the warning is the page.
-
-- ``annotate(name)``: ``jax.profiler.TraceAnnotation`` as a context
-  manager that degrades to a no-op off-jax — the named scopes show up
-  on the host track of a profiler capture (prefill lane, decode chunk,
-  harvest).
 
 - ``profile_window()``: a ``DS_TPU_PROFILE_DIR``-gated
   ``jax.profiler.trace`` capture. When the env var is unset (the
@@ -109,18 +104,6 @@ class RecompileDetector(object):
                         "shape changed%s", label, grew,
                         "" if grew == 1 else "s", self.total(), ident)
         return new_after_warm
-
-
-def annotate(name):
-    """``jax.profiler.TraceAnnotation(name)`` or a no-op context when
-    jax (or the API) is unavailable. Host-side scoping only — wrap the
-    DISPATCH of device work, not traced function bodies."""
-    try:
-        import jax
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
 
 
 _profile_active = [False]

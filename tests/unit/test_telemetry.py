@@ -44,7 +44,6 @@ from deepspeed_tpu.telemetry import (
     TensorBoardScalarWriter,
     TraceContext,
     TraceError,
-    annotate,
     merged_trace,
     profile_window,
     prometheus_digest,
@@ -511,13 +510,11 @@ def test_engine_telemetry_off_keeps_metrics_drops_spans():
         eng.write_trace("/tmp/never.json")
 
 
-# ------------------------------------------------- annotate/profile/degrade
+# ----------------------------------------------------------- profile/degrade
 
 
-def test_annotate_and_profile_window_noop_when_unset(monkeypatch):
+def test_profile_window_noop_when_unset(monkeypatch):
     monkeypatch.delenv("DS_TPU_PROFILE_DIR", raising=False)
-    with annotate("test/scope"):
-        pass
     with profile_window("x") as p:
         assert p is None
 
@@ -652,6 +649,11 @@ def _two_site_recorders():
     donor = SpanRecorder(capacity=64, clock=clock)
     acceptor = SpanRecorder(capacity=64, clock=clock)
     ctx = TraceContext(1_000_003, origin="fleet")
+    # The prefill-role donor's lane: every slice dispatched is a hop, the
+    # prompt's last one twice (docs/OBSERVABILITY.md, the hop table).
+    donor.instant("request/slice", tid=ctx.tid, hop=ctx.hop(), slices=1)
+    donor.instant("request/last_slice", tid=ctx.tid, hop=ctx.hop(),
+                  slices=1)
     donor.span("request/prefill", start=clock(), tid=ctx.tid,
                hop=ctx.hop())
     donor.instant("request/handoff", tid=ctx.tid, hop=ctx.hop(),
@@ -693,7 +695,7 @@ def test_merged_trace_flow_pairs_cross_pid_ts_sorted_at_parser_level():
     # Every request event rides the propagated tid, hop-stamped.
     hops = [e["args"]["hop"] for e in rows
             if e["ph"] in ("X", "i") and e["tid"] == 1_000_003]
-    assert sorted(hops) == list(range(5))
+    assert sorted(hops) == list(range(7))
 
 
 def test_validate_trace_rejects_malformed_traces():
