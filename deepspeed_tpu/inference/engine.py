@@ -859,6 +859,8 @@ class InferenceEngine(object):
                              num_pages=self._pager.total_pages)
             self.telemetry.gauge("kv_lane_pack").set(self._lane_pack(pool))
             self.telemetry.gauge("kv_unit_pages").set(self._unit_pages(pool))
+            self.telemetry.gauge("kv_append_unit_rows").set(
+                self._append_unit_rows(pool))
         else:
             pool = init_pool(self._gcfg, self.config.max_slots,
                              self.config.max_len, slack=self._slack,
@@ -904,6 +906,22 @@ class InferenceEngine(object):
             arenas, getattr(self._adapter, "gcfg", spec).n_head,
             spec.n_embd // spec.n_head, pool["block_tbl"].shape[1],
             spec.dtype, latent=getattr(spec, "latent", 0))
+
+    def _append_unit_rows(self, pool=None):
+        """R, the rows of the decode scan that one unit (grid step) of the
+        one-row ``kv_append`` walks, as the launcher's own rule resolves it
+        for this pool's arenas (``decode_attention.append_unit_rows``: from
+        shapes and dtypes alone; all ``max_slots`` where their tiles fit
+        VMEM, one launch a layer then). 0 where no launch walks its rows:
+        pages that are no kernel block (the scatter) and arenas whose minor
+        dim is not whole lane tiles (a page a row by block spec). On the
+        WHOLE pool's shapes, as ``_unit_pages``."""
+        pool = self._pool if pool is None else pool
+        if not decode_attention.decode_supported(pool["k"].shape[3]):
+            return 0
+        return decode_attention.append_unit_rows(
+            [pool[name] for name in ("k", "v", "k_scale", "v_scale")
+             if name in pool], pool["block_tbl"].shape[0])
 
     def _on_stall(self, budget_s):
         """Watchdog trip — runs on the TIMER THREAD while the step is
@@ -2479,6 +2497,7 @@ class InferenceEngine(object):
                 "kv_lane_pack": self._lane_pack(),
                 "kv_unit_pages": self._unit_pages(),
                 "kv_unit_fill": round(self._unit_fill(), 4),
+                "kv_append_unit_rows": self._append_unit_rows(),
                 "kv_pages_total": pg.total_pages,
                 "kv_pages_in_use": pg.pages_in_use(),
                 "kv_pages_free": pg.pages_free(),

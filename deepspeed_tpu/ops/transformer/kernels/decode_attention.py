@@ -1460,32 +1460,69 @@ def latent_decode(q, arena, block_tbl, pos, rank, scale, name=None,
 # out, scatters into the slice and updates it back: three arena-sized
 # passes for a few rows, and it hands the arena a layout Mosaic has to
 # convert back around every decode call. This kernel aliases the arena to
-# its output and rewrites only the frontier page(s) of each row, addressed
-# ``(layer, block_tbl[b, pos[b] // page_len + j], ..)`` by scalar prefetch:
-# the page's [H/g, page_len, g*D] block (for a one-row append only the
-# [H/g, 32, g*D] tile around the frontier: 64 KB at GPT-2 355M's packed
-# shape, where the unpacked minor dim of 64 was padded to 128 KB) comes
-# into VMEM, the new rows, regrouped as the arena holds heads, are
-# selected in at their offsets, and the block goes back. Everything else
-# in the arena is never touched, so it keeps ONE layout — the decode
-# kernel's — from the step's entry to its exit.
+# its output and rewrites only the frontier of each row, addressed
+# ``(layer, block_tbl[b, pos[b] // page_len + j], ..)`` through scalar
+# prefetch. Everything else in the arena is never touched, so it keeps ONE
+# layout — the decode kernel's — from the step's entry to its exit.
 #
-# Rewriting a whole page is sound because a live frontier page belongs to
-# ONE row (the block table is injective per row outside the trash page;
-# copy-on-write gives a prefix's straddle page a private copy before it
-# is written). Frozen rows share the trash page 0, which nothing reads.
+# SEVERAL ROWS A SLOT (a verify's ``spec_k + 1``, the lane's 128): the
+# frontier page(s) by block spec, a grid of ``(B, 2)``: the page's
+# [H/g, page_len, g*D] block comes into VMEM, the new rows, regrouped as the
+# arena holds heads, are rolled to their offsets and selected in, and the
+# block goes back.
+#
+# ONE ROW A SLOT (the decode scan, once a layer and iteration: 384 calls a
+# GPT-2 step): ONE launch walks its rows itself (PR 41). The arenas stay in
+# ``pl.ANY``; the LIVE rows (``tbl[b, 0] != TRASH_PAGE``, the test
+# ``_paged_units`` uses, and a frontier inside the plane) come as a list on
+# scalar prefetch (``_live_rows``: a few integer operations outside the
+# kernel, identical in every layer of a pass, so the compiler keeps one
+# copy), and the kernel's loops run over the list's length: a freed row
+# costs no copy and no branch. For every live row the kernel starts a
+# ``make_async_copy`` of the [H/g, 8, g*D] tile that holds the frontier into
+# the row's slot of a VMEM scratch, every row's read in flight together;
+# waits for them all; then, row by row, selects the new row in (all heads in
+# one 32-bit select, bit for bit the scatter) and starts the write-back; and
+# last waits for the write-backs. EIGHT rows because that is a whole tile of
+# the arena in HBM whatever its dtype (``T(8,128)(2,1)`` bf16, ``(4,1)``
+# int8: Mosaic refuses a slice of 2 or 4 and accepts 8, compiled for a
+# described v5e); a scale arena brings its [H, page_len] block. All rows are
+# one unit where their slots fit the VMEM budget (every cell's do), else the
+# grid is ``ceil(B / R)`` units of ``append_unit_rows``' R, from shapes and
+# dtypes alone. The form this took the place of gave each row a Pallas grid
+# step and a 32-row tile by block spec: a step costs about 0.6 us whatever
+# it holds, so 16 rows of GPT-2 355M took 12.2 us a call (9.0 with no live
+# row) where the walk takes 4.1 (1.8), OLMoE's 32 rows 33.2 -> 8.5,
+# Granite's 64 43.1 -> 10.2, DeepSeek's 128 48.6 -> 14.4 (kernel alone, TPU
+# v5 lite; PERF.md, PR 41, where the forms that lost are: a 32-row and a
+# 16-row tile, units of 4 to 32 rows, a loop over heads in place of one
+# select, the new values by the kernel's own copy, a ``pl.when`` a row in
+# place of the list, which cost 0.6 us a call and 12 s of set-up, and a wait
+# a row before its select, 0.2 us faster and no proof that the tile is whole).
+#
+# Rewriting a whole tile or page is sound because a live frontier page
+# belongs to ONE row (the block table is injective per row outside the trash
+# page; copy-on-write gives a prefix's straddle page a private copy before
+# it is written), so no two copies in flight meet. Frozen rows share the
+# trash page 0, which nothing reads.
 # ---------------------------------------------------------------------------
 
-# Rows of a page a ONE-row append brings in and writes back: the packed
-# sublane tile of the narrowest pool dtype (int8: 32 rows; bf16: 16).
+# Rows a SEVERAL-row append pads its new values to: the packed sublane tile
+# of the narrowest pool dtype (int8: 32 rows; bf16: 16).
 _APPEND_TILE = 32
 
+# Rows of a page around the frontier that a ONE-row append brings in and
+# writes back: one tile of the arena in HBM, whatever the dtype packs.
+_APPEND_ROWS = 8
 
-def _append_touched(s_len, page_len):
-    """Pages a write of ``s_len`` rows (at most one page's worth) can
-    touch from an arbitrary frontier."""
-    assert 1 <= s_len <= page_len, (s_len, page_len)
-    return 1 if s_len == 1 else 2
+
+def _append_walks(arenas):
+    """Whether a one-row append of these arenas can walk its rows: Mosaic
+    slices no HBM ref whose minor dim is not whole lane tiles (a head dim of
+    80 or 96, a caller's unpacked arena of 64; no pool ``lane_pack`` stores
+    at a head dim that divides or fills a tile). Such a call goes the
+    several-row way, a whole page a row by block spec."""
+    return all(len(a.shape) == 4 or a.shape[4] % LANES == 0 for a in arenas)
 
 
 def _append_page(pos_b, j, s_len, page_len, n_lp):
@@ -1499,6 +1536,12 @@ def _append_page(pos_b, j, s_len, page_len, n_lp):
                        n_lp - 1)
 
 
+def _wide(dtype):
+    """Selects run on 32-bit values (bf16 -> f32 and int8 -> int32 are
+    exact both ways): a packed row cannot be rolled by an odd count."""
+    return jnp.float32 if jnp.issubdtype(dtype, jnp.floating) else jnp.int32
+
+
 def _append_kernel(pos_ref, tbl_ref, *refs, n, s_len, page_len):
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1510,30 +1553,23 @@ def _append_kernel(pos_ref, tbl_ref, *refs, n, s_len, page_len):
 
     def place(new, axis, rows):
         # [.., s, ..] new values -> the block's ``rows`` positions along
-        # ``axis``: one row broadcasts; several are rolled by the
-        # frontier's offset (a row that lands on the NEXT page wraps to
-        # that page's start, which is where it belongs there).
+        # ``axis``, rolled by the frontier's offset (a row that lands on
+        # the NEXT page wraps to that page's start, which is where it
+        # belongs there).
         shape = list(new.shape)
-        if s_len == 1:
-            shape[axis] = rows
-            return jnp.broadcast_to(new, shape)
         if shape[axis] != rows:
             shape[axis] = rows - shape[axis]
             new = jnp.concatenate([new, jnp.zeros(shape, new.dtype)], axis)
         return pltpu.roll(new, off, axis)
 
     def select_in(new_ref, old_ref, out_ref):
-        # Selects run on 32-bit values (bf16 -> f32 and int8 -> int32 are
-        # exact both ways): a packed row cannot be rolled by an odd count.
-        wide = jnp.float32 if jnp.issubdtype(old_ref.dtype, jnp.floating) \
-            else jnp.int32
+        wide = _wide(old_ref.dtype)
         axis = 0 if len(old_ref.shape) == 4 else 1
         rows = old_ref.shape[2]
         # The block's position p holds plane position start + p; it takes
         # new row r = start + p - pos_b where 0 <= r < s_len and keeps
         # what it holds everywhere else.
-        start = lp * page_len + _div(off, rows) * rows
-        r = start - pos_b + jax.lax.broadcasted_iota(
+        r = lp * page_len - pos_b + jax.lax.broadcasted_iota(
             jnp.int32, old_ref.shape[-2:], axis)
         keep = (r < 0) | (r >= s_len)
 
@@ -1558,11 +1594,14 @@ def _append_kernel(pos_ref, tbl_ref, *refs, n, s_len, page_len):
 
 
 def _kv_append_pallas(pos, tbl, *ops, layer, s_len):
+    """Several rows a slot (and one, of an arena the walk cannot slice):
+    whole frontier pages by block spec."""
     from jax.experimental.pallas import tpu as pltpu
 
     n = len(ops) // 2
     news, arenas = ops[:n], ops[n:]
     page_len = arenas[0].shape[3]
+    assert 1 <= s_len <= page_len, (s_len, page_len)
     n_lp = tbl.shape[1]
     pos = pos.astype(jnp.int32)
     tbl = tbl.astype(jnp.int32)
@@ -1573,26 +1612,21 @@ def _kv_append_pallas(pos, tbl, *ops, layer, s_len):
                             lambda b_, j, pos_ref, tbl_ref: (b_,) + zeros)
 
     def arena_spec(arena):
-        # A row arena's block is [H, rows, D] of one page: the whole page,
-        # or for a one-row append only the tile that holds the frontier
-        # (16 times less to bring in and write back). A scale arena's is
-        # [H, page_len], positions on the lanes.
-        rows = _APPEND_TILE if s_len == 1 and arena.ndim == 5 else page_len
+        # A row arena's block is [H, page_len, D], one page; a scale
+        # arena's is [H, page_len], positions on the lanes.
+        tile = (0, 0) if arena.ndim == 5 else (0,)
 
         def index(b_, j, pos_ref, tbl_ref):
-            pos_b = pos_ref[b_]
-            lp = _append_page(pos_b, j, s_len, page_len, n_lp)
-            tile = (_div(_rem(pos_b, page_len), rows), 0) \
-                if arena.ndim == 5 else (0,)
+            lp = _append_page(pos_ref[b_], j, s_len, page_len, n_lp)
             return (layer, tbl_ref[b_, lp], 0) + tile
 
-        return pl.BlockSpec((None, 1, arena.shape[2], rows)
-                            + arena.shape[4:], index)
+        return pl.BlockSpec((None, 1) + arena.shape[2:], index)
 
     arena_specs = [arena_spec(a) for a in arenas]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(pos.shape[0], _append_touched(s_len, page_len)),
+        # a write of at most a page's rows touches two pages
+        grid=(pos.shape[0], 2),
         in_specs=[new_spec(x) for x in news] + arena_specs,
         out_specs=arena_specs,
     )
@@ -1605,6 +1639,178 @@ def _kv_append_pallas(pos, tbl, *ops, layer, s_len):
         # Operands count the two scalar-prefetch arguments.
         input_output_aliases={2 + n + i: i for i in range(n)},
     )(pos, tbl, *news, *arenas)
+    return tuple(out)
+
+
+def append_unit_rows(arenas, b):
+    """R, the rows one unit (grid step) of the one-row append walks, for a
+    call of ``b`` rows (the engine reports a pool's, for ``max_slots``, as
+    ``kv_append_unit_rows``): all ``b`` where their slots fit
+    ``_PAGED_VMEM_BUDGET``, else the largest power of two that does; 0 for
+    arenas no launch walks. From the arenas' shapes and dtypes alone
+    (anything with a shape and a dtype, whole). A row holds, an arena, its
+    [H/g, 8, g*D] tile (a scale arena's [H, page_len] block) and its new
+    values twice (the pipeline's two buffers; a head's row of them pads to
+    one 32-bit sublane)."""
+    if not _append_walks(arenas):
+        return 0
+
+    def lanes(n):
+        return -(-n // LANES) * LANES
+
+    row_bytes = 0
+    for a in arenas:
+        item = jnp.dtype(a.dtype).itemsize
+        if len(a.shape) == 5:
+            row_bytes += a.shape[2] * lanes(a.shape[4]) * (
+                _APPEND_ROWS * item + 2 * 4)
+        else:
+            heads = -(-a.shape[2] // 8) * 8
+            row_bytes += heads * (lanes(a.shape[3]) + 2 * LANES) * item
+    fit = max(1, _PAGED_VMEM_BUDGET // row_bytes)
+    return b if b <= fit else 2 ** (fit.bit_length() - 1)
+
+
+def _live_rows(tbl, pos, page_len):
+    """The one-row append's work list: ``(rows, ends)``, each ``[B]``. Row
+    b is LIVE where its table does not start on the trash page (a freed
+    row's is all trash page: the test ``_paged_units`` uses) and its
+    frontier lies inside its plane; ``ends[b]`` counts the live rows up to
+    and including b, and ``rows[t]`` is the t-th live row for
+    ``t < ends[B - 1]``. A dense compare and sum over ``[B, B]``, the same in
+    every layer of a pass, so the compiler keeps one copy."""
+    from deepspeed_tpu.inference.paging import TRASH_PAGE
+
+    b, n_lp = tbl.shape
+    live = (tbl[:, 0] != TRASH_PAGE) & (pos < n_lp * page_len)
+    r = jax.lax.iota(jnp.int32, b)
+    upto = jax.lax.le(r[None, :], r[:, None]) & live[None, :]
+    ends = jnp.sum(upto, axis=1, dtype=jnp.int32)
+    rows = jnp.sum(jax.lax.ge(r[:, None], ends[None, :]), axis=1,
+                   dtype=jnp.int32)
+    return jax.lax.min(rows, jnp.int32(b - 1)), ends
+
+
+def _append_walk_kernel(rows_ref, ends_ref, pos_ref, tbl_ref, *refs, n,
+                        layer, unit, page_len):
+    """One grid step = one unit: rows ``unit * u .. unit * u + unit - 1``,
+    of which it walks the live ones (``_live_rows``' list, so a dead row
+    costs no branch either). ``refs``: the unit's new values (VMEM blocks
+    ``[unit, H, 1, D]`` / ``[unit, H, 1]``), the arenas twice (input and
+    aliased output, both whole in HBM; the output is the one read and
+    written), a VMEM slot a row an arena, DMA semaphores ``[2, n]`` (reads,
+    write-backs)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    news, outs = refs[:n], refs[2 * n:3 * n]
+    slots, sem = refs[3 * n:4 * n], refs[4 * n]
+    n_rows = pos_ref.shape[0]
+    if unit == n_rows:
+        first, lo, hi = 0, 0, ends_ref[n_rows - 1]
+    else:
+        first = pl.program_id(0) * unit
+        lo = jnp.where(first > 0, ends_ref[jnp.maximum(first - 1, 0)], 0)
+        hi = ends_ref[jnp.minimum(first + unit, n_rows) - 1]
+
+    def tiles(page, start):
+        # what a row rewrites of each arena: the [H, 8, D] tile of its
+        # frontier page that holds the frontier; a scale arena's page
+        return [out.at[layer, page, :, pl.ds(start, _APPEND_ROWS), :]
+                if len(out.shape) == 5 else out.at[layer, page]
+                for out in outs]
+
+    def row(t):
+        b = rows_ref[t]
+        pos_b = pos_ref[b]
+        start = _div(_rem(pos_b, page_len), _APPEND_ROWS) * _APPEND_ROWS
+        return b - first, pos_b, tiles(
+            tbl_ref[b, _div(pos_b, page_len)],
+            pl.multiple_of(start, _APPEND_ROWS))
+
+    def bring(t, _):
+        i, _, hbm = row(t)
+        for a in range(n):
+            pltpu.make_async_copy(hbm[a], slots[a].at[i], sem.at[0, a]).start()
+
+    def place(t, _):
+        i, pos_b, hbm = row(t)
+        for a, slot in enumerate(slots):
+            # The slot's positions lie along axis 1 of ``slot[i]``: a
+            # tile's 8 on the sublanes of [H, 8, D], a scale block's
+            # page_len on the lanes of [H, page_len]. All heads in one
+            # select: looping over them was 1.5 to 2 times slower a call.
+            # (``lax``, not ``jnp``: the step traces this once a layer.)
+            wide, shape = _wide(slot.dtype), slot.shape[1:]
+            new = jax.lax.convert_element_type(news[a][i], wide)
+            here = jax.lax.eq(jax.lax.broadcasted_iota(jnp.int32, shape, 1),
+                              _rem(pos_b, shape[1]))
+            slot[i] = jax.lax.convert_element_type(jax.lax.select(
+                here,
+                jax.lax.broadcast_in_dim(new, shape, tuple(range(new.ndim))),
+                jax.lax.convert_element_type(slot[i], wide)), slot.dtype)
+            pltpu.make_async_copy(slot.at[i], hbm[a], sem.at[1, a]).start()
+
+    def landed(k):
+        # The copies of one direction and arena share ONE semaphore, which
+        # counts bytes: as many waits as copies, and only after the last of
+        # them is any slot known whole (a wait needs a copy's size and
+        # semaphore, not its address).
+        hbm = tiles(0, 0)
+
+        def wait(t, _):
+            for a in range(n):
+                pltpu.make_async_copy(hbm[a], slots[a].at[0],
+                                      sem.at[k, a]).wait()
+        return wait
+
+    # Loops over the live rows, not R copies of a body: the step holds this
+    # kernel once a layer, and its size is set-up time.
+    jax.lax.fori_loop(lo, hi, bring, None)
+    jax.lax.fori_loop(lo, hi, landed(0), None)
+    jax.lax.fori_loop(lo, hi, place, None)
+    jax.lax.fori_loop(lo, hi, landed(1), None)
+
+
+def _kv_append_walk(pos, tbl, *ops, layer):
+    """ONE row a slot: the launch walks its rows (the block above)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    n = len(ops) // 2
+    news, arenas = ops[:n], ops[n:]
+    b = pos.shape[0]
+    unit = append_unit_rows(arenas, b)
+    pos, tbl = pos.astype(jnp.int32), tbl.astype(jnp.int32)
+
+    def new_spec(new):
+        zeros = (0,) * (new.ndim - 1)
+        return pl.BlockSpec((unit,) + new.shape[1:],
+                            lambda u, *_: (u,) + zeros)
+
+    def slot(arena):
+        # a row's tile [H, 8, D] of a row arena, block [H, page_len] of a
+        # scale arena
+        tile = (_APPEND_ROWS,) + arena.shape[4:] if arena.ndim == 5 \
+            else arena.shape[3:]
+        return pltpu.VMEM((unit, arena.shape[2]) + tile, arena.dtype)
+
+    whole = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(-(-b // unit),),
+        in_specs=[new_spec(x) for x in news] + [whole] * n,
+        out_specs=[whole] * n,
+        scratch_shapes=[slot(a) for a in arenas]
+        + [pltpu.SemaphoreType.DMA((2, n))],
+    )
+    out = pallas_mode.kernel_call(
+        "kv_append",
+        functools.partial(_append_walk_kernel, n=n, layer=layer, unit=unit,
+                          page_len=arenas[0].shape[3]),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in arenas],
+        # Operands count the four scalar-prefetch arguments.
+        input_output_aliases={4 + n + i: i for i in range(n)},
+    )(*_live_rows(tbl, pos, arenas[0].shape[3]), pos, tbl, *news, *arenas)
     return tuple(out)
 
 
@@ -1647,27 +1853,31 @@ def kv_append(arenas, new, block_tbl, pos, layer):
                 pad_heads(x, a.shape[2], 1) for x, a in zip(new, arenas))
     s = new[0].shape[2]
     b, h = new[0].shape[:2]
-    # Lane-dim padding of the scales' new values cannot be made inside the
-    # kernel (an unaligned lane concatenate), so they arrive page-wide.
-    # Row values pad up to a packed sublane tile at most.
+    # One row a slot walks its rows in one launch and takes the new values
+    # as they are. A block-spec call pads them: lane-dim padding of the
+    # scales' new values cannot be made inside the kernel (an unaligned lane
+    # concatenate), so they arrive page-wide; row values pad up to a packed
+    # sublane tile at most.
     sub = _APPEND_TILE
     out = tuple(arenas)
     for lo in range(0, s, page_len):      # at most a page's rows a call
         n_rows = min(page_len, s - lo)
+        walk = n_rows == 1 and _append_walks(arenas)
         part = []
         for x, a in zip(new, arenas):
             x = jax.lax.slice_in_dim(x, lo, lo + n_rows, axis=2)
-            if n_rows > 1 and a.ndim == 4:
+            if not walk and a.ndim == 4:
                 x = jnp.pad(x, ((0, 0), (0, 0), (0, page_len - n_rows)))
-            elif n_rows > 1 and n_rows % sub:
+            elif not walk and n_rows % sub:
                 x = jnp.pad(x, ((0, 0), (0, 0),
                                 (0, sub - n_rows % sub), (0, 0)))
             part.append(x)
         specs = ("-", "-") + tuple("-h" for _ in part) \
             + tuple("--h" for _ in out)
-        out = on_shards(
-            functools.partial(_kv_append_pallas, layer=int(layer),
-                              s_len=n_rows),
-            kernel_sharding(b, h), specs, tuple("--h" for _ in out))(
-                pos + lo, block_tbl, *part, *out)
+        launch = functools.partial(_kv_append_walk, layer=int(layer)) \
+            if walk else functools.partial(
+                _kv_append_pallas, layer=int(layer), s_len=n_rows)
+        out = on_shards(launch, kernel_sharding(b, h), specs,
+                        tuple("--h" for _ in out))(
+                            pos + lo, block_tbl, *part, *out)
     return tuple(out)

@@ -188,6 +188,30 @@ def _latent(name=None):
     return run
 
 
+def _latent_append_args(rows, slots):
+    return [((slots, 1, rows, D_W), BF16), D_ARENA,
+            ((slots, 24), I32), ((slots,), I32)]
+
+
+def _latent_append(new, arena, tbl, pos):
+    return da.kv_append((arena,), (new,), tbl, pos, layer=5)
+
+
+# Granite 4.0-H's one attention layer of ten (serve-granite4h-decode-closed):
+# 8 stored heads of 128 under 32 query heads, 64 slots x 19 pages + trash.
+G_SLOTS = 64
+G_ARENA = ((1, G_SLOTS * 19 + 1, 8, PAGE, 128), BF16)
+
+
+def _granite_append_args(rows, slots):
+    new = ((slots, 8, rows, 128), BF16)
+    return [new, new, G_ARENA, G_ARENA, ((slots, 19), I32), ((slots,), I32)]
+
+
+def _granite_append(k, v, ka, va, tbl, pos):
+    return da.kv_append((ka, va), (k, v), tbl, pos, layer=0)
+
+
 def _dense_decode_args(rows, int8=False):
     plane = ((SLOTS, HEADS, T_KV, HD), I8 if int8 else BF16)
     scale = ((SLOTS, HEADS, T_KV), F32)
@@ -294,6 +318,13 @@ CASES = {
                                       {}),
     "latent_prefill_attn_lane_128_rows": (
         _latent("prefill_attn"), _latent_args(128, 1), {}),
+    # The decode scan's one row a slot at the two largest batches a cell
+    # runs: all 128 rows of the latent cache's one arena and all 64 of the
+    # grouped-query pool's 8 stored heads in ONE unit of the walk.
+    "latent_kv_append_128_slots_1_row": (
+        _latent_append, _latent_append_args(1, D_SLOTS), {}),
+    "granite_kv_append_64_rows_gqa_d128": (
+        _granite_append, _granite_append_args(1, G_SLOTS), {}),
     "fused_layer_norm_fwd_bwd": (
         _fwd_bwd(lambda x, g, b: layer_norm.fused_layer_norm(x, g, b), 3),
         [LN_X, VEC, VEC], {}),

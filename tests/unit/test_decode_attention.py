@@ -402,23 +402,29 @@ def _stored(x, g, scales=False):
 def _scatter_reference(arena, new, tbl, pos, layer):
     """What ``models/generation.py`` ``_forward`` did before the kernel:
     ``arena.at[layer, pg, :, off, :].set(new)`` through the block table
-    (``arena`` and ``new`` in the same form: both stored, or both not)."""
+    (``arena`` and ``new`` in the same form: both stored, or both not).
+    A position past the row's plane has no page and is dropped."""
     s = new.shape[2]
     w_pos = pos[:, None] + jnp.arange(s)[None]
     w_pg = tbl[jnp.arange(new.shape[0])[:, None],
                jnp.minimum(w_pos // _PAGE, tbl.shape[1] - 1)]
+    w_pg = jnp.where(w_pos < tbl.shape[1] * _PAGE, w_pg, arena.shape[1])
     w_off = w_pos % _PAGE
     if arena.ndim == 5:
         return arena.at[layer, w_pg, :, w_off, :].set(
-            new.transpose(0, 2, 1, 3))
-    return arena.at[layer, w_pg, :, w_off].set(new.transpose(0, 2, 1))
+            new.transpose(0, 2, 1, 3), mode="drop")
+    return arena.at[layer, w_pg, :, w_off].set(new.transpose(0, 2, 1),
+                                               mode="drop")
 
 
 def _append_case(s, pos, int8, layer, frozen=(), n_layer=3, h=2, d=8,
-                 n_lp=3, seed=0, stored=False):
+                 n_lp=3, seed=0, stored=False, planes=2, unit=None):
     """``stored``: the arenas in the shape ``init_pool`` gives a model of
     ``h`` heads of ``d`` (``lane_pack`` heads a lane tile, a zero head where
-    that does not divide ``h``); the new values stay ``[B, H, S, D]``."""
+    that does not divide ``h``); the new values stay ``[B, H, S, D]``.
+    ``planes`` 1: a latent cache's one arena. ``unit``: the rows a unit of
+    the one-row walk holds, where the rule would take them all (the VMEM
+    budget shrunk until ``append_unit_rows`` says so)."""
     rng = np.random.RandomState(seed)
     g = da.lane_pack(d, h) if stored else 1
     b = len(pos)
@@ -434,8 +440,8 @@ def _append_case(s, pos, int8, layer, frozen=(), n_layer=3, h=2, d=8,
             return jnp.asarray(rng.randint(-127, 128, shape), jnp.int8)
         return jnp.asarray(rng.randn(*shape), jnp.bfloat16)
 
-    arenas = [rows((n_layer, n_pages, h, _PAGE, d)) for _ in range(2)]
-    new = [rows((b, h, s, d)) for _ in range(2)]
+    arenas = [rows((n_layer, n_pages, h, _PAGE, d)) for _ in range(planes)]
+    new = [rows((b, h, s, d)) for _ in range(planes)]
     if int8:
         arenas += [jnp.asarray(rng.rand(n_layer, n_pages, h, _PAGE),
                                jnp.float32) for _ in range(2)]
@@ -443,8 +449,17 @@ def _append_case(s, pos, int8, layer, frozen=(), n_layer=3, h=2, d=8,
                 for _ in range(2)]
     arenas = [_stored(a, g, scales=a.ndim == 4) for a in arenas]
     assert arenas[0].shape[2:] == (-(-h // g), _PAGE, g * d)
-    got = jax.jit(lambda a, n: kv_append(tuple(a), tuple(n), tbl, pos,
-                                         layer))(arenas, new)
+    budget = da._PAGED_VMEM_BUDGET
+    if unit is not None:
+        assert s == 1 and da._append_walks(arenas)
+        while da.append_unit_rows(arenas, b) > unit:
+            da._PAGED_VMEM_BUDGET = da._PAGED_VMEM_BUDGET * 3 // 4
+        assert da.append_unit_rows(arenas, b) == unit
+    try:
+        got = jax.jit(lambda a, n: kv_append(tuple(a), tuple(n), tbl, pos,
+                                             layer))(arenas, new)
+    finally:
+        da._PAGED_VMEM_BUDGET = budget
     assert len(got) == len(arenas)
     for arena, x, out in zip(arenas, new, got):
         want = np.array(_scatter_reference(
@@ -501,6 +516,44 @@ APPEND_CASES = {
         5, [0, 125, 251], True, 0, (), 25, 64),
     "stored_5_heads_of_32_lane_40_rows": (
         40, [100, 3, 250], False, 2, (), 5, 32),
+    # The decode scan's one row a slot: ONE launch walks its rows (arenas
+    # whose minor dim is whole lane tiles; the cases above with 8 lanes go
+    # a page a row by block spec). After (heads, head dim): the rows a unit
+    # holds where not all, and the planes. Frontiers at offsets 0, 7, 8,
+    # 15, 16, 31 and 127 of a page: both sides of an 8-row tile's edge and
+    # of the 16- and 32-row tiles the form could have taken.
+    "walk_tile_edges_d64_g2": (
+        1, [0, 135, 264, 15, 144, 287, 127], False, 1, (), 4, 64),
+    "walk_tile_edges_d128": (
+        1, [128, 7, 8, 271, 16, 31, 383], False, 2, (), 2, 128),
+    "walk_tile_edges_int8_d64_g2": (
+        1, [0, 135, 264, 15, 144, 287, 127], True, 0, (), 4, 64),
+    "walk_tile_edges_int8_d128": (
+        1, [128, 7, 8, 271, 16, 31, 383], True, 1, (), 2, 128),
+    "walk_5_rows_in_units_of_4": (
+        1, [0, 15, 16, 31, 127], False, 1, (), 4, 64, 4),
+    "walk_5_rows_in_units_of_2_int8": (
+        1, [300, 15, 16, 31, 255], True, 2, (), 2, 128, 2),
+    "walk_frozen_rows_beside_live_in_one_unit": (
+        1, [5, 77, 200, 0, 129, 9], False, 1, (0, 3, 5), 4, 64, 4),
+    "walk_frozen_rows_beside_live_all_rows_a_unit_int8": (
+        1, [5, 77, 200, 0, 129, 9], True, 0, (1, 2), 4, 64),
+    "walk_no_live_row": (1, [5, 77, 200, 0], False, 1, (0, 1, 2, 3), 4, 64),
+    "walk_no_live_row_in_units_of_2": (
+        1, [5, 77, 200, 0, 31], False, 2, (0, 1, 2, 3, 4), 2, 128, 2),
+    "walk_a_frontier_past_the_plane_writes_nothing": (
+        1, [3 * 128, 77, 3 * 128 + 5], False, 1, (), 4, 64),
+    # Granite's grouped-query rows: 8 stored heads of 128 under 32 query
+    # heads (the append sees the stored heads only).
+    "walk_grouped_query_8_stored_heads_d128": (
+        1, [0, 127, 128, 300, 8], False, 0, (), 8, 128),
+    "walk_grouped_query_in_units_of_2": (
+        1, [0, 127, 128, 300, 8], False, 2, (), 8, 128, 2),
+    # DeepSeek's latent cache: ONE arena of one stored head, 640 lanes.
+    "walk_latent_one_arena_w640": (
+        1, [0, 7, 8, 127, 128, 300], False, 1, (2,), 1, 640, None, 1),
+    "walk_latent_one_arena_in_units_of_4": (
+        1, [0, 7, 8, 127, 128, 300], False, 0, (), 1, 640, 4, 1),
 }
 
 
@@ -512,8 +565,66 @@ def test_kv_append_is_the_scatter_bit_for_bit(name):
     On a stored (packed) arena the scatter is of the new values regrouped
     as the arena holds heads; no lane of a neighbouring head may change."""
     s, pos, int8, layer, frozen, *shape = APPEND_CASES[name]
-    kw = dict(zip(("h", "d"), shape), stored=bool(shape))
-    _append_case(s, pos, int8, layer, frozen=frozen, **kw)
+    kw = {key: value for key, value in zip(("h", "d", "unit", "planes"),
+                                           shape) if value is not None}
+    _append_case(s, pos, int8, layer, frozen=frozen, stored=bool(shape), **kw)
+
+
+def _shapes(shape, n=2, dtype=jnp.bfloat16):
+    return [jax.ShapeDtypeStruct(shape, dtype)] * n
+
+
+@pytest.mark.parametrize("name, arenas, slots, walks, unit", [
+    # the four serving cells' pools: every row of the scan in ONE unit
+    ("gpt2", _shapes((24, 145, 8, 128, 128)), 16, True, 16),
+    ("olmoe", _shapes((8, 545, 16, 128, 128)), 32, True, 32),
+    ("granite", _shapes((1, 1217, 8, 128, 128)), 64, True, 64),
+    ("dsv3_latent", _shapes((6, 3073, 1, 128, 640), 1), 128, True, 128),
+    # int8 codes beside a scale a head of the model: 8 rows all the same
+    ("gpt2_int8", _shapes((24, 145, 8, 128, 128), 2, jnp.int8)
+     + _shapes((24, 145, 16, 128), 2, jnp.float32), 16, True, 16),
+    # past the budget the rows go in units: 96 KB a row of OLMoE's shape
+    # fit 128 times in 12 MiB; 1.5 MB a row of a wide float32 pool 8 times
+    ("olmoe_512_slots", _shapes((8, 8193, 16, 128, 128)), 512, True, 128),
+    ("wide_float32_pool", _shapes((2, 65, 64, 128, 256), 2, jnp.float32),
+     12, True, 8),
+    # a minor dim that is not whole lane tiles: no slice of it in HBM
+    ("unpacked_d64", _shapes((24, 145, 16, 128, 64)), 16, False, 0),
+    ("head_dim_96", _shapes((4, 33, 12, 128, 96)), 8, False, 0),
+], ids=lambda x: x if isinstance(x, str) else None)
+def test_rows_a_unit_of_the_one_row_append_come_from_the_shapes(
+        name, arenas, slots, walks, unit):
+    """R, the rows one launch (or one grid step of it) of the one-row
+    ``kv_append`` walks, and the tile a row brings: 8 rows of a page, one
+    tile of the arena in HBM whatever the dtype packs. From shapes and
+    dtypes alone; what the engine reports as ``kv_append_unit_rows``."""
+    assert da._APPEND_ROWS == 8
+    assert da._append_walks(arenas) == walks
+    assert da.append_unit_rows(arenas, slots) == (unit if walks else 0)
+    # fewer rows than fit are all one unit, whatever their count
+    assert da.append_unit_rows(arenas, 5) == (5 if walks else 0)
+
+
+@pytest.mark.parametrize("name, first_pages, pos, rows", [
+    ("every_row_live", [3, 1, 2, 7], [0, 5, 383, 128], [0, 1, 2, 3]),
+    ("freed_rows_between", [0, 4, 0, 0, 2, 0], [9, 9, 9, 9, 9, 9], [1, 4]),
+    ("a_frontier_past_the_plane", [3, 1, 2], [384, 383, 500], [1]),
+    ("no_live_row", [0, 0, 0], [1, 2, 3], []),
+])
+def test_the_one_row_appends_list_of_live_rows(name, first_pages, pos, rows):
+    """``_live_rows``: the rows whose table does not start on the trash
+    page and whose frontier lies inside the plane (3 pages of 128 here), in
+    order, and how many there are up to each row: what the walk's loops run
+    over, so a freed row costs it nothing."""
+    tbl = np.zeros((len(pos), 3), np.int32)
+    tbl[:, 0] = first_pages
+    got, ends = (np.asarray(x) for x in da._live_rows(
+        jnp.asarray(tbl), jnp.asarray(pos, jnp.int32), _PAGE))
+    assert ends.dtype == got.dtype == np.int32
+    assert list(got[:ends[-1]]) == rows
+    assert list(ends) == [sum(r <= b for r in rows)
+                          for b in range(len(pos))]
+    assert got.max(initial=0) < len(pos)
 
 
 @pytest.mark.parametrize("int8", [False, True])

@@ -681,6 +681,32 @@ def test_unit_gauges_say_how_far_pages_are_joined():
             if n == "ds_tpu_kv_unit_fill"] == [0.5]
 
 
+@pytest.mark.parametrize("name, page_len, rows", [
+    # 128 stored lanes and pages of a kernel block: ONE launch walks all
+    # four slots' rows
+    ("4_heads_of_64", 128, 4), ("8_heads_of_32", 128, 4),
+    # 64 stored lanes: Mosaic slices no such arena, a page a row instead
+    ("4_heads_of_16", 128, 0),
+    # pages of 8 are no kernel block: the scatter
+    ("4_heads_of_64", 8, 0)])
+def test_append_unit_rows_gauge_is_the_launchers_own_rule(name, page_len,
+                                                          rows):
+    """``kv_append_unit_rows`` is R as ``decode_attention.append_unit_rows``
+    resolves it for the pool's own arenas and ``max_slots`` rows (0 where no
+    launch walks its rows), in ``metrics()`` and the export alike."""
+    cfg, model, params, _ = stored_model(name)
+    eng = paged_engine_of(model, params, kv_page_len=page_len, max_len=128)
+    pool = eng._pool
+    if page_len == 128:
+        assert da.append_unit_rows([pool["k"], pool["v"]],
+                                   pool["block_tbl"].shape[0]) == rows
+    assert eng.metrics()["kv_append_unit_rows"] == rows
+    kinds, samples = _parse_prom(eng.prometheus())
+    assert kinds["ds_tpu_kv_append_unit_rows"] == "gauge"
+    assert [v for (n, _), v in samples.items()
+            if n == "ds_tpu_kv_append_unit_rows"] == [rows]
+
+
 def test_pick_swap_victim_scores_live_pages():
     """Paged victim ordering: the session holding the most LIVE pages
     (true reclaim) loses, even when dense budget order says otherwise."""
