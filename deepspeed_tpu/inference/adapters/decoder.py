@@ -21,10 +21,12 @@ layer, ``moe_tokens_absent`` (choices that fell on experts held elsewhere).
 They count every row the program computes (idle slots decode garbage by
 design), so they read as the program's load, not as requests' tokens.
 
-A HYBRID stack (``layer_types`` with ``mamba`` layers) carries a recurrent
-state a slot: ``cache_spec().slot_state`` names it, the pool allocates and
-threads it (``kv_pool.py``, A RECURRENT STATE A SLOT) and ``observe``
-publishes its size as ``ssm_state_bytes``. What the stale-cache rule gave
+A HYBRID stack (``layer_types`` with ``mamba`` or ``kda`` layers) carries a
+recurrent state a slot: ``cache_spec().slot_state`` names it, the pool
+allocates and threads it (``kv_pool.py``, A RECURRENT STATE A SLOT) and
+``observe`` publishes its size as ``ssm_state_bytes`` (ONE gauge for any
+``slot_state``: a Mamba-2 layer's state-space state or a KDA layer's matrix
+state, each with its convolution tail). What the stale-cache rule gave
 the engine for free does not exist for it, so ``bind`` REFUSES, by the name
 of the mechanism, what would need a snapshot of the state: speculative
 decoding (a rejected draft has already moved the state), the prefix cache
@@ -42,6 +44,12 @@ offload and handoff work as for any keys (tests/unit/test_mla.py); what
 ``bind`` refuses by name is what has no one-plane form yet: int8 planes (a
 latent's 512 values and its rotary key want scales of their own) and the
 prefix cache (its records name a ``pk`` / ``pv`` pair).
+
+BOTH AT ONCE (Kimi Linear: a latent plane as deep as its MLA layers only,
+beside a KDA state a slot) gets both sets of refusals, each by its own
+mechanism's name: the latent plane's first (int8, prefix cache), then the
+state's (speculation; a configuration that asks for nothing the plane
+refuses still may not speculate).
 """
 
 import dataclasses
@@ -72,7 +80,7 @@ class DecoderAdapter(GPT2Adapter):
     @property
     def recurrent(self):
         """Does a row carry a state that has no position axis?"""
-        return bool(self.gcfg.mamba_layers)
+        return bool(self.cache_spec().slot_state)
 
     @property
     def latent(self):
@@ -113,8 +121,10 @@ class DecoderAdapter(GPT2Adapter):
                 if asked:
                     raise ValueError(
                         "{} cannot serve a model with a recurrent state a "
-                        "slot ({} Mamba layers): {}".format(
-                            what, len(self.gcfg.mamba_layers), why))
+                        "slot ({}): {}".format(what, ", ".join(
+                            "{} {} layers".format(self.gcfg.kinds.count(k), k)
+                            for k in decoder.RECURRENT
+                            if k in self.gcfg.kinds), why))
         return super().bind(config, mesh)
 
     def init_cache(self, batch, max_len, dtype=None):

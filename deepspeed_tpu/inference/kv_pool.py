@@ -115,7 +115,8 @@ rows all point at the trash page 0, which nothing reads.
 A RECURRENT STATE A SLOT. A model whose ``cache_spec()`` names
 ``slot_state`` (``((key, shape a row, dtype), ...)``, keys ``slot_*``: the
 decoder block's Mamba-2 layers give a ``slot_ssm<j>`` [slots, N, H * P]
-float32 and a ``slot_conv<j>`` [slots, K - 1, conv width] a layer)
+float32 and a ``slot_conv<j>`` [slots, K - 1, conv width] a layer, its KDA
+layers a ``slot_kda<j>`` and a ``slot_kdaconv<j>``)
 holds, beside the k and v planes of the layers that DO hold keys (the planes
 are as deep as those layers only), state with NO position axis: a fixed size
 a slot whatever its context, slot-major so that a slot's share is one
@@ -127,9 +128,9 @@ slot's slice through the lane, and the hierarchy's capture and restore ship
 it with the slot's scalars (``arr[slot]``, like ``pos``). Nothing of the
 stale-cache rule applies to it: there is no position past the frontier to
 hide garbage in, so every program that touches it masks instead
-(``models/mamba2.py``), and what the rule gave for free (rollback by not
-advancing ``pos``, aliased prefixes) is refused for such a model
-(``adapters/decoder.py``).
+(``models/mamba2.py``, ``models/kda.py``), and what the rule gave for free
+(rollback by not advancing ``pos``, aliased prefixes) is refused for such a
+model (``adapters/decoder.py``).
 
 A LATENT CACHE. A model whose ``cache_spec()`` gives ``latent`` > 0 (latent
 attention: ``models/decoder.py`` ``mla``) stores ONE head a token,
@@ -148,6 +149,16 @@ leaves (what PR 30 met at 64); a pair of planes would double the appends and
 the page fetches (a unit of the kernel is already smaller than its fixed
 cost) for the same bytes. The int8 and prefix tiers are refused for such a
 model (``adapters/decoder.py``).
+
+BOTH AT ONCE. ``latent`` and ``slot_state`` compose (Kimi Linear: a latent
+plane as deep as its MLA layers only, beside a ``slot_kda<j>``
+[slots, H, d_k, d_v] float32 matrix state and a ``slot_kdaconv<j>``
+[slots, K - 1, 3 H d] tail for KDA layer ``j``: three kinds of cache in one
+pool). Nothing here knows the pair: ``init_pool`` builds the one-plane arena
+and ``_with_slot_state`` adds whatever the spec names, the views hand over
+the planes the pool HAS and every ``slot_*`` key, and the hierarchy's
+records walk the pool's keys, so a captured slot ships its latent pages and
+its state together. Such a model gets both sets of refusals.
 
 CRASH-ONLY: the pool is DISPOSABLE state (docs/RESILIENCE.md). The
 durable truth about every request lives host-side in the scheduler's

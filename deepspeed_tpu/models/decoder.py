@@ -31,6 +31,19 @@ gives the rotary frequencies YaRN's ramp; ``dense_layers`` leading layers
 take a dense gated feed-forward of ``dense_width`` in place of experts; and
 ``router_scoring`` ``"sigmoid"`` routes by ``routed.route_grouped``.
 
+The same block once more is Kimi Linear's (``model_type`` ``kimi_linear``),
+by a third kind in ``layer_types`` and two values the fields above did not
+take: ``"kda"`` layers run Kimi Delta Attention (``models/kda.py``: a
+delta-rule recurrence with a decay a channel and a ``[d_k, d_v]`` float32
+state a head a row), three to one latent-attention layer WITHOUT positions
+(``rope`` False with ``kv_lora_rank`` set: the shared key's lanes and the
+queries' are carried unrotated) whose queries come straight from the stream
+(``q_lora_rank`` 0: no ``wq_a``, no ``q_a_norm``). Its cache is BOTH a latent
+plane, as deep as the MLA layers only (``kv_layers``), and a recurrent state
+a row (``cache_spec`` composes the two); its dense leading layer's mixer is
+a KDA layer. The kinds that carry a state a row are ``RECURRENT``: one
+branch of ``forward`` for Mamba-2 and KDA alike.
+
 THE ABSORBED FORM IS THE ONE PATH of latent attention, for the lane and the
 scan alike. Per head ``[k_nope_h | v_h] = c_kv W_kvb,h``, so
 ``q_nope_h . k_nope_h(u) = (q_nope_h W_uk,h) . c_kv(u)`` and
@@ -68,11 +81,14 @@ A hybrid stack (``layer_types`` given) keeps under ``layers`` what EVERY
 layer has (the two norms, the router, the held experts ``[L, E_held, ..]``,
 ``shared_gate_up [L, C, 2Fs]`` / ``shared_down [L, Fs, C]``) and stacks each
 kind of mixer over the layers of that kind: ``attn/wqkv [La, C, (H + 2 Hkv) D]``
-(+ the QK norms), ``attn/wo``; ``mamba/...`` [Lm, ..] (``mamba2.init_layer``).
+(+ the QK norms), ``attn/wo``; ``mamba/...`` [Lm, ..] (``mamba2.init_layer``);
+``kda/...`` [Lk, ..] (``kda.init_layer``).
 A latent-attention stack (``kv_lora_rank`` given) keeps the two norms under
-``layers`` and stacks the rest by kind: ``mla/wq_a [L, C, Rq]``,
+``layers`` and stacks the rest by kind (``L`` the layers of that kind):
+``mla/wq_a [L, C, Rq]``,
 ``q_a_norm [L, Rq]``, ``wq_nope [L, H nope, Rq]``, ``wq_rope [L, H rope, Rq]``
-(``q_b_proj``'s nope and rotary columns of every head, ``[out, in]``),
+(``q_b_proj``'s nope and rotary columns of every head, ``[out, in]``; from
+``C`` and without the first two where ``q_lora_rank`` is 0),
 ``wkv_a [L, C, R + rope]``, ``kv_a_norm [L, R]``, ``w_uk [L, H, R, nope]``, ``w_uv [L, H, v, R]``,
 ``wo [L, H v, C]`` (the four stacks the absorbed form contracts a head at
 a time lie ``[out, in]``, contraction minor, as the step's matmuls read
@@ -87,7 +103,8 @@ The regions of a trace (``jax.named_scope``, under the caller's
 ``rope``, ``qk_norm``, attention, projection; latent attention: ``q_proj``,
 ``kv_proj``, ``rope``, ``absorb`` (the two per-head products with
 ``W_kvb``), ``o_proj``), ``kv_write``, ``kv_view``,
-or ``mamba`` (``mamba2.mixer``'s words); ``moe`` holding ``router``,
+or ``mamba`` (``mamba2.mixer``'s words) or ``kda`` (``kda.mixer``'s);
+``moe`` holding ``router``,
 ``dispatch``, ``experts``, ``combine`` and ``shared``, or ``mlp`` for a dense
 layer; then ``lm_head``.
 
@@ -105,7 +122,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.analysis.annotations import hot_path
-from deepspeed_tpu.models import generation, mamba2
+from deepspeed_tpu.models import generation, kda, mamba2
 from deepspeed_tpu.moe import routed
 
 
@@ -143,7 +160,7 @@ class DecoderConfig(typing.NamedTuple):
     shared_width: int = 0                      # 0: no shared expert
     # (first, count) of the router's experts this chip holds; None: all
     experts_held: typing.Optional[typing.Tuple[int, int]] = None
-    # "attention" | "mamba" a layer; None: attention everywhere
+    # "attention" | "mamba" | "kda" a layer; None: attention everywhere
     layer_types: typing.Optional[typing.Tuple[str, ...]] = None
     mamba_heads: int = 0
     mamba_head_dim: int = 0
@@ -165,6 +182,10 @@ class DecoderConfig(typing.NamedTuple):
     n_group: int = 1
     topk_group: int = 1
     routed_scaling: float = 1.0
+    # Kimi Delta Attention (``models/kda.py``), where ``layer_types`` has it
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
 
     @property
     def n_embd(self):
@@ -183,13 +204,17 @@ class DecoderConfig(typing.NamedTuple):
 
     @property
     def kv_layers(self):
-        """The layers that hold keys, in order: layer ``kv_layers[a]`` is
-        layer ``a`` of the cache's k and v planes."""
+        """The layers that hold keys (or a latent), in order: layer
+        ``kv_layers[a]`` is layer ``a`` of the cache's planes."""
         return tuple(i for i, k in enumerate(self.kinds) if k == "attention")
 
     @property
     def mamba_layers(self):
         return tuple(i for i, k in enumerate(self.kinds) if k == "mamba")
+
+    @property
+    def kda_layers(self):
+        return tuple(i for i, k in enumerate(self.kinds) if k == "kda")
 
     @property
     def held(self):
@@ -222,11 +247,19 @@ class DecoderConfig(typing.NamedTuple):
         return self.rms_norm_eps
 
 
+# The kinds of layer whose mixer carries a recurrent state a row in place of
+# keys (``layer_types``; the region of a trace and the parameters' tree take
+# the kind's name), each a module with ``state_shapes``, ``state_keys``,
+# ``init_layer`` and ``mixer``.
+RECURRENT = {"mamba": mamba2, "kda": kda}
+
+
 class CacheSpec(typing.NamedTuple):
     """What a pool of this model holds (``ModelAdapter.cache_spec``): the k
-    and v planes are as deep as the layers that hold keys and as wide as the
-    heads a token STORES, and ``slot_state`` names the recurrent state a row
-    carries beside them (``mamba2.state_shapes``; empty without it)."""
+    and v planes (``latent``: the ONE plane) are as deep as the layers that
+    hold keys and as wide as the heads a token STORES, and ``slot_state``
+    names the recurrent state a row carries beside them
+    (``mamba2.state_shapes``, ``kda.state_shapes``; empty without it)."""
 
     n_layer: int
     n_head: int
@@ -241,14 +274,14 @@ class CacheSpec(typing.NamedTuple):
 
 
 def cache_spec(cfg):
-    if cfg.kv_lora_rank:
-        return CacheSpec(cfg.n_layer, 1, cfg.latent_width, cfg.n_positions,
-                         cfg.dtype, cfg.rms_norm_eps, cfg.use_flash_decode,
-                         cfg.kv_page_len, (), cfg.kv_lora_rank)
-    return CacheSpec(len(cfg.kv_layers), cfg.n_kv, cfg.n_kv * cfg.head_dim,
-                     cfg.n_positions, cfg.dtype, cfg.rms_norm_eps,
-                     cfg.use_flash_decode, cfg.kv_page_len,
-                     mamba2.state_shapes(cfg))
+    heads, width = (1, cfg.latent_width) if cfg.kv_lora_rank \
+        else (cfg.n_kv, cfg.n_kv * cfg.head_dim)
+    return CacheSpec(len(cfg.kv_layers), heads, width, cfg.n_positions,
+                     cfg.dtype, cfg.rms_norm_eps, cfg.use_flash_decode,
+                     cfg.kv_page_len,
+                     tuple(state for kind in RECURRENT.values()
+                           for state in kind.state_shapes(cfg)),
+                     cfg.kv_lora_rank)
 
 
 def served_config(cfg, use_flash_decode=None):
@@ -310,16 +343,18 @@ def init_params(key, cfg):
         ks = jax.random.split(k, 6)
         nh, r, rq = cfg.n_head, cfg.kv_lora_rank, cfg.q_lora_rank
         dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-        return {"wq_a": normal(ks[0], (c, rq)),
-                "q_a_norm": jnp.ones((rq,), cfg.dtype),
-                "wq_nope": normal(ks[1], (nh * dn, rq)),
-                "wq_rope": normal(jax.random.fold_in(ks[1], 1),
-                                  (nh * dr, rq)),
-                "wkv_a": normal(ks[2], (c, r + dr)),
-                "kv_a_norm": jnp.ones((r,), cfg.dtype),
-                "w_uk": normal(ks[3], (nh, r, dn)),
-                "w_uv": normal(ks[4], (nh, dv, r)),
-                "wo": normal(ks[5], (nh * dv, c))}
+        out = {"wq_nope": normal(ks[1], (nh * dn, rq or c)),
+               "wq_rope": normal(jax.random.fold_in(ks[1], 1),
+                                 (nh * dr, rq or c)),
+               "wkv_a": normal(ks[2], (c, r + dr)),
+               "kv_a_norm": jnp.ones((r,), cfg.dtype),
+               "w_uk": normal(ks[3], (nh, r, dn)),
+               "w_uv": normal(ks[4], (nh, dv, r)),
+               "wo": normal(ks[5], (nh * dv, c))}
+        if rq:      # 0: the queries come straight from the stream
+            out.update(wq_a=normal(ks[0], (c, rq)),
+                       q_a_norm=jnp.ones((rq,), cfg.dtype))
+        return out
 
     def dense(k):
         k1, k2 = jax.random.split(k)
@@ -334,16 +369,18 @@ def init_params(key, cfg):
               "layers": jax.lax.map(layer,
                                     jax.random.split(k_layers, cfg.n_layer)),
               "final_norm": jnp.ones((c,), cfg.dtype)}
-    if cfg.layer_types is not None:
+    if cfg.layer_types is not None and not cfg.kv_lora_rank:
         params["attn"] = jax.lax.map(
             lambda k: attention(*jax.random.split(k)), jax.random.split(
                 jax.random.fold_in(key, 3), len(cfg.kv_layers)))
-        if cfg.mamba_layers:
-            params["mamba"] = jax.lax.map(
-                lambda k: mamba2.init_layer(k, cfg), jax.random.split(
-                    jax.random.fold_in(key, 4), len(cfg.mamba_layers)))
+    if cfg.mamba_layers:
+        params["mamba"] = stacked(lambda k: mamba2.init_layer(k, cfg), 4,
+                                  len(cfg.mamba_layers))
+    if cfg.kda_layers:
+        params["kda"] = stacked(lambda k: kda.init_layer(k, cfg), 9,
+                                len(cfg.kda_layers))
     if cfg.kv_lora_rank:
-        params["mla"] = stacked(latent, 6, cfg.n_layer)
+        params["mla"] = stacked(latent, 6, len(cfg.kv_layers))
     if cfg.dense_layers:
         params["dense"] = stacked(dense, 7, cfg.dense_layers)
         params["moe"] = stacked(experts, 8, cfg.n_layer - cfg.dense_layers)
@@ -459,8 +496,10 @@ def latent_token(layer, cfg, h, rope):
     with jax.named_scope("kv_proj"):
         kv = h @ layer["wkv_a"].astype(dt)                    # [B, S, R + dr]
         c_kv = _rms32(kv[..., :r], layer["kv_a_norm"], cfg.rms_norm_eps)
-    with jax.named_scope("rope"):
-        k_r = _rope(kv[..., None, r:].astype(jnp.float32), *rope)[:, :, 0]
+    k_r = kv[..., r:].astype(jnp.float32)
+    if rope is not None:
+        with jax.named_scope("rope"):
+            k_r = _rope(k_r[:, :, None], *rope)[:, :, 0]
     return jnp.pad(jnp.concatenate([c_kv, k_r], axis=-1),
                    ((0, 0), (0, 0), (0, pad))).astype(dt)[:, None]
 
@@ -480,14 +519,15 @@ def latent_mix(layer, cfg, h, i, rope, attend, planes):
     with jax.named_scope("attn"):
         with jax.named_scope("q_proj"):
             c_q = _rms32(h @ layer["wq_a"].astype(dt), layer["q_a_norm"],
-                         eps).astype(dt)
+                         eps).astype(dt) if cfg.q_lora_rank else h
             q_n = jnp.einsum("bsr,nr->bsn", c_q, layer["wq_nope"].astype(
                 dt)).reshape(b, s, nh, dn)
             q_r = jnp.einsum("bsr,nr->bsn", c_q, layer["wq_rope"].astype(
                 dt)).reshape(b, s, nh, dr)
         k = latent_token(layer, cfg, h, rope)
-        with jax.named_scope("rope"):
-            q_r = _rope(q_r.astype(jnp.float32), *rope).astype(dt)
+        if rope is not None:
+            with jax.named_scope("rope"):
+                q_r = _rope(q_r.astype(jnp.float32), *rope).astype(dt)
         with jax.named_scope("absorb"):
             q_lat = jnp.einsum("bshd,hrd->bhsr", q_n,
                                layer["w_uk"].astype(dt))
@@ -572,10 +612,11 @@ def forward(params, cfg, ids, cache, attn_name=None):
     added: every row the program computes counts, a pad column or an idle
     slot too, so the gauges read the program's load.
 
-    A model with Mamba layers reads and returns the rows' recurrent state
-    (``slot_ssm<j>`` / ``slot_conv<j>``) and ``cache['n_valid']`` [B]: how many
-    leading columns of each row are real, 0 for a row that must not move
-    (default: all ``S``). The key is consumed here."""
+    A model with Mamba or KDA layers reads and returns the rows' recurrent
+    state (``slot_ssm<j>`` / ``slot_conv<j>``, ``slot_kda<j>`` /
+    ``slot_kdaconv<j>``) and ``cache['n_valid']`` [B]: how many leading
+    columns of each row are real, 0 for a row that must not move (default:
+    all ``S``). The key is consumed here."""
     s = ids.shape[1]
     dt = cfg.dtype
     cache = dict(cache)
@@ -593,20 +634,21 @@ def forward(params, cfg, ids, cache, attn_name=None):
         n_valid = jnp.full(ids.shape[:1], s, jnp.int32)
     load = jnp.zeros((cfg.held[1],), jnp.float32)
     absent = jnp.zeros((), jnp.float32)
-    n_attn = n_mamba = 0
+    n_attn = 0
+    n_recurrent = {kind: 0 for kind in RECURRENT}
     for i, kind in enumerate(cfg.kinds):
         layer = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
-        if kind == "mamba":
-            mix = jax.tree_util.tree_map(lambda a: a[n_mamba],
-                                         params["mamba"])
-            with jax.named_scope("mamba"):
+        if kind in RECURRENT:
+            j = n_recurrent[kind]
+            mix = jax.tree_util.tree_map(lambda a: a[j], params[kind])
+            with jax.named_scope(kind):
                 h = _rms32(x, layer["attn_norm"], cfg.rms_norm_eps).astype(dt)
-                ssm, conv = mamba2.ssm_key(n_mamba), mamba2.conv_key(n_mamba)
-                h, state[ssm], state[conv] = mamba2.mixer(
-                    mix, cfg, h, cache[ssm], cache[conv], attend.pos,
+                mat, conv = RECURRENT[kind].state_keys(j)
+                h, state[mat], state[conv] = RECURRENT[kind].mixer(
+                    mix, cfg, h, cache[mat], cache[conv], attend.pos,
                     n_valid)
                 x = _residual(cfg, x, h)
-            n_mamba += 1
+            n_recurrent[kind] += 1
         else:
             tree = "mla" if cfg.kv_lora_rank else "attn"
             if tree in params:

@@ -80,6 +80,10 @@ def conv_key(j):
     return "slot_conv{}".format(j)
 
 
+def state_keys(j):
+    return ssm_key(j), conv_key(j)
+
+
 def init_layer(key, cfg):
     """One Mamba layer's parameters, by Mamba-2's conventional
     initialisation: ``A`` uniform in 1..16, the step ``dt`` log-uniform in

@@ -778,3 +778,80 @@ def test_latent_mixed_step_appends_and_attends_the_one_arena_in_place(
     arena = ["[6,3073,1,128,640]", "[3073,1,128,640]"]
     assert _arena_shaped([line for lines in comps.values()
                           for line in lines], arena) == []
+
+
+def test_kimi_mixed_step_holds_three_caches_each_where_it_lies(
+        chip, monkeypatch):
+    """Kimi Linear's block at its published widths and the cell's 12 layers
+    (9 KDA, 3 MLA; 1 dense + 11 with 16 of 256 experts held; the cell's
+    pool: 128 slots of 2944, page 128, chunk 16, lane 128; under a minute to
+    compile): the latent plane is ONE arena ``[3, 3073, 1, 128, 640]``, as
+    deep as the MLA layers only, that meets ``kv_append`` and
+    ``latent_decode`` once an MLA layer in the decode scan and is formed whole
+    nowhere; each KDA layer's float32 state ``slot_kda<j>``
+    [128, 32, 128, 128] is in the scan the result of fusions alone (the
+    update, where it lies in the scan's carry): no ``copy``, no slice, no
+    ``dynamic-update-slice``; and the step fits the chip beside its weights
+    and pool."""
+    from deepspeed_tpu.inference import kv_pool
+    from deepspeed_tpu.inference.adapters import DecoderAdapter
+    from deepspeed_tpu.inference.config import InferenceConfig
+    from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
+
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    slots, chunk, lane = 128, 16, 128
+    kinds = ("kda", "kda", "kda", "attention") * 3
+    model = DecoderLM(DecoderConfig(
+        vocab_size=20480, n_layer=len(kinds), n_head=32, head_dim=192,
+        hidden_size=2304, n_positions=1048576, n_experts=256,
+        experts_per_token=8, expert_width=1024, qk_norm=False,
+        norm_topk_prob=True, dtype=BF16, rope=False, shared_width=1024,
+        experts_held=(0, 16), layer_types=kinds, kv_lora_rank=512,
+        q_lora_rank=0, qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+        dense_layers=1, dense_width=9216, router_scoring="sigmoid",
+        routed_scaling=2.446, kda_heads=32, kda_head_dim=128))
+    config = InferenceConfig.from_dict(dict(
+        max_slots=slots, max_len=2944, chunk_size=chunk, paged_kv=True,
+        kv_page_len=PAGE, prefill_chunk=lane, use_flash_decode=True))
+    adapter = DecoderAdapter.from_model(model, use_flash_decode=True).bind(
+        config, None)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0))["params"])
+    pool = jax.eval_shape(lambda: dict(kv_pool.init_pool(
+        adapter.cache_spec(), slots, 2944, slack=lane, page_len=PAGE),
+        **adapter.aux_state()))
+    assert "v" not in pool and pool["k"].shape == (3, 128 * 24 + 1, 1, PAGE,
+                                                   640)
+    assert all(pool["slot_kda{}".format(j)].shape == (slots, 32, 128, 128)
+               and pool["slot_kdaconv{}".format(j)].shape
+               == (slots, 3, 12288) for j in range(9))
+
+    text = _mixed_step_text(chip, adapter, params, pool, chunk, lane)
+    dump = os.environ.get("DS_TPU_HLO_DUMP")
+    if dump:
+        with open(dump, "w") as f:
+            f.write(text)
+    comps = _computations(text)
+    scan, in_scan = _scan_lines(comps)
+    names = sorted(c.split(".")[0] for c in _kernel_calls(
+        "\n".join(in_scan)))
+    assert names == ["kv_append"] * 3 + ["latent_decode"] * 3
+    everywhere = sorted(c.split(".")[0] for c in _kernel_calls(text))
+    assert everywhere == ["kv_append"] * 6 + ["latent_decode"] * 3 \
+        + ["prefill_attn"] * 3
+    arena = ["[3,3073,1,128,640]", "[3073,1,128,640]"]
+    assert _arena_shaped([line for lines in comps.values()
+                          for line in lines], arena) == []
+
+    # Whole instructions only: what a fusion computes inside itself never
+    # reaches memory.
+    def whole(names):
+        return [line for name in names
+                if not name.startswith("fused_computation")
+                for line in comps[name]]
+
+    state = ["f32[128,32,128,128]"]
+    touched = _arena_shaped(whole(scan), state)
+    assert touched and {op for _, op in touched} == {"fusion"}, touched
+    outside = _arena_shaped(whole(set(comps) - set(scan)), state)
+    assert {op for _, op in outside} <= {"fusion"}, outside

@@ -5,6 +5,13 @@ block, the OLMoE-shaped and the Granite-shaped decoder at tiny sizes, through
 the gather path (pages of 8) and through the interpreted kernels (pages of
 128). Latent attention, the dense layer, YaRN and the sigmoid router are
 static branches that are off for them, so nothing they lower may move.
+
+PR 42 (a linear-attention mixer, a latent plane in a subset of the layers
+beside a recurrent state) pins the same three again and a DeepSeek-shaped
+decoder with them (latent plane, a leading dense layer, the sigmoid grouped
+router over a share of the experts), as recorded at 5869b3c before its first
+edit (``parent_tokens_pr42.json``): the new kind of layer and the composed
+cache are static branches that are off for all four.
 """
 
 import json
@@ -19,8 +26,10 @@ from deepspeed_tpu.inference import InferenceEngine
 from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
 
-RECORDED = json.load(open(os.path.join(
-    os.path.dirname(__file__), "..", "fixtures", "parent_tokens_pr38.json")))
+FIXTURES = os.path.join(os.path.dirname(__file__), "..", "fixtures")
+RECORDED = {pr: json.load(open(os.path.join(
+    FIXTURES, "parent_tokens_{}.json".format(pr))))["tokens"]
+    for pr in ("pr38", "pr42")}
 
 OLMOE = DecoderConfig(
     vocab_size=256, n_layer=2, n_head=4, head_dim=16, hidden_size=64,
@@ -35,6 +44,15 @@ GRANITE = DecoderConfig(
     logits_scaling=4.0, shared_width=48, experts_held=(0, 4),
     layer_types=("mamba", "attention", "mamba", "mamba"), mamba_heads=4,
     mamba_head_dim=8, mamba_state=16, mamba_conv=4, mamba_chunk=8)
+DEEPSEEK = DecoderConfig(      # as tests/unit/test_mla.py sizes it
+    vocab_size=256, n_layer=3, n_head=4, head_dim=24, hidden_size=64,
+    n_positions=4096, n_experts=16, experts_per_token=3, expert_width=32,
+    rms_norm_eps=1e-6, qk_norm=False, norm_topk_prob=True,
+    dtype=jnp.float32, initializer_range=0.15, shared_width=32,
+    experts_held=(0, 8), kv_lora_rank=32, q_lora_rank=24, qk_nope_dim=16,
+    qk_rope_dim=8, v_head_dim=16, rope_yarn=(40.0, 64, 32.0, 1.0, 1.0, 1.0),
+    dense_layers=1, dense_width=96, router_scoring="sigmoid", n_group=4,
+    topk_group=2, routed_scaling=2.5)
 
 
 def built(family):
@@ -43,9 +61,13 @@ def built(family):
         model = GPT2LMHeadModel(cfg)
         return model, model.init(jax.random.PRNGKey(0), jnp.zeros(
             (1, 8), jnp.int32))["params"], cfg.vocab_size
-    cfg = {"olmoe": OLMOE, "granite": GRANITE}[family]
+    cfg = {"olmoe": OLMOE, "granite": GRANITE, "deepseek": DEEPSEEK}[family]
     model = DecoderLM(cfg)
     params = model.init(jax.random.PRNGKey(0))["params"]
+    if family == "deepseek":    # the selection bias drawn, not zero
+        shape = params["moe"]["router_bias"].shape
+        params["moe"] = dict(params["moe"], router_bias=0.1
+                             * jax.random.normal(jax.random.PRNGKey(38), shape))
     if family == "granite":     # as tests/unit/test_hybrid.py scales them
         params = dict(params, embed=params["embed"] * 0.2,
                       final_norm=params["final_norm"] * 25.0)
@@ -65,8 +87,9 @@ def serve(model, params, vocab, kernels):
     return [[int(t) for t in r.tokens] for r in reqs]
 
 
-@pytest.mark.parametrize("case", sorted(RECORDED["tokens"]))
-def test_the_served_tokens_are_the_parents(case):
+@pytest.mark.parametrize("pr, case", [
+    (pr, case) for pr in sorted(RECORDED) for case in sorted(RECORDED[pr])])
+def test_the_served_tokens_are_the_parents(pr, case):
     family, path = case.split(".")
     assert serve(*built(family), kernels=path == "kernels") == \
-        RECORDED["tokens"][case]
+        RECORDED[pr][case]
