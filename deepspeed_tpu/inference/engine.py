@@ -125,7 +125,8 @@ from deepspeed_tpu.inference.kv_pool import (
 from deepspeed_tpu.inference.paging import TRASH_PAGE, PageAllocator
 from deepspeed_tpu.inference.adapters import adapter_class_for
 from deepspeed_tpu.inference.scheduler import QueueFull, Scheduler
-from deepspeed_tpu.ops.transformer.kernels import decode_attention
+from deepspeed_tpu.models import kda
+from deepspeed_tpu.ops.transformer.kernels import decode_attention, kda_update
 from deepspeed_tpu.ops.transformer.kernels.attention import kernels_on_mesh
 from deepspeed_tpu.parallel import mesh as mesh_lib
 from deepspeed_tpu.telemetry import (
@@ -865,6 +866,8 @@ class InferenceEngine(object):
             pool = init_pool(self._gcfg, self.config.max_slots,
                              self.config.max_len, slack=self._slack,
                              hier=self._hier.spec if self._hier else None)
+        self.telemetry.gauge("kda_update_unit_heads").set(
+            self._kda_unit_heads(pool))
         if getattr(self._gcfg, "latent", 0):
             # A latent cache (kv_pool.py): bytes ONE token holds over all
             # layers, read back from the one plane's shape.
@@ -922,6 +925,18 @@ class InferenceEngine(object):
         return decode_attention.append_unit_rows(
             [pool[name] for name in ("k", "v", "k_scale", "v_scale")
              if name in pool], pool["block_tbl"].shape[0])
+
+    def _kda_unit_heads(self, pool=None):
+        """Hb, the heads of a row that one unit (grid step) of the decode
+        scan's ``kda_update`` holds, as the launcher's own rule resolves it
+        for this pool's ``slot_kda<j>`` (``kda_update.unit_heads``: from the
+        shape and the dtype alone). 0 where the plain form runs (a state
+        that is not whole tiles of float32) and for a model with no KDA
+        layer. On the WHOLE pool's shapes, as ``_unit_pages``."""
+        pool = self._pool if pool is None else pool
+        state = pool.get(kda.state_key(0))
+        return 0 if state is None else kda_update.unit_heads(
+            state.shape, state.dtype)
 
     def _on_stall(self, budget_s):
         """Watchdog trip — runs on the TIMER THREAD while the step is
@@ -2489,6 +2504,7 @@ class InferenceEngine(object):
             # dense planes (the A/B default) and no page gauges follow.
             "paged_kv": self._pager is not None,
             "kv_hbm_bytes": pool_nbytes(self._pool),
+            "kda_update_unit_heads": self._kda_unit_heads(),
         }
         if self._pager is not None:
             pg = self._pager

@@ -37,14 +37,25 @@ of ``mamba2.py``'s state hold, by the same means:
 - nothing is rolled back by not advancing ``pos``: speculation and prefix
   sharing are refused for a model that has it (``adapters/decoder.py``).
 
-ONE recurrence, two forms. ``step`` is one token, for the decode scan. It
-reads a layer's state ONCE for both products: with ``u = beta (v - S'^T k)``,
-``o = S^T q = S'^T q + (k . q) u``, so ``S'^T k`` and ``S'^T q`` are two sums
-over the same ``S'`` (two INDEPENDENT reductions one fusion can share), and
-``S = S' + k u^T`` is the one write; computed as published, ``S^T q`` waits
-for the write and reads the new state again. ``chunked`` is the lane's form
-over a slice of tokens, in sub-chunks of ``CHUNK``: with ``G_t`` the running
-sum of ``g`` inside a sub-chunk and ``U`` the rows ``u_t``,
+ONE recurrence, two forms. ``step`` is one token, for the decode scan. With
+``u = beta (v - S'^T k)``, ``o = S^T q = S'^T q + (k . q) u``, so ``S'^T k``
+and ``S'^T q`` are two sums over the same ``S'`` and ``S = S' + k u^T`` is
+the one write; computed as published, ``S^T q`` waits for the write and
+reads the new state again. It moves a layer's state ONCE, one read AND one
+write, where the Pallas kernel ``kda_update`` runs it
+(``ops/transformer/kernels/kda_update.py``: a row's unit of heads stays in
+VMEM between the sums and the write, and goes back to where it came from):
+a float32 state whose ``d_v`` is whole lane tiles and ``d_k`` whole sublane
+tiles, for any number of rows and heads, the frontier-0 select included (a
+flag a row, ``fresh``). Any other shape (the tiny configurations of
+``tests/``) runs the same algebra in plain ``jax.numpy`` (``step_plain``),
+which XLA compiles to a fusion that reads the state for the two sums and a
+second that reads it again for the write: the sums must end before the write
+can start, and XLA keeps no state between two fusions. One algorithm with a
+shape rule, reported as ``kda_update_unit_heads``; no option chooses.
+``chunked`` is the lane's form over a slice of tokens, in sub-chunks of
+``CHUNK``: with ``G_t`` the running sum of ``g`` inside a sub-chunk and
+``U`` the rows ``u_t``,
 
     (I + diag(beta) tril(A, -1)) U = diag(beta) (V - (K exp(G)) S_0)
     A[t, s] = sum_c k_t[c] k_s[c] exp(G_t[c] - G_s[c])
@@ -68,6 +79,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models import mamba2
+from deepspeed_tpu.ops.transformer.kernels import kda_update
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 # tokens a sub-chunk of the lane's form solves at once
@@ -137,12 +149,28 @@ def l2norm(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
 
 
-def step(q, k, v, g, beta, state):
-    """One token of the recurrence (module docstring: ONE read of the
-    state). q, k, v, g ``[B, H, d]``, beta ``[B, H]`` (``g`` and ``beta`` 0
-    for a row that must not move: the state exactly as it was), state
-    ``[B, H, d_k, d_v]``; all float32. Returns (o ``[B, H, d_v]``, the state
-    after)."""
+def step(q, k, v, g, beta, state, fresh=None):
+    """One token of the recurrence (module docstring: ONE read and ONE write
+    of the state where the kernel runs). q, k, v, g ``[B, H, d]``, beta
+    ``[B, H]`` (``g`` and ``beta`` 0 for a row that must not move: the state
+    exactly as it was), state ``[B, H, d_k, d_v]``; all float32. ``fresh``
+    ``[B]`` bool: a row that starts from zeros whatever ``state`` holds of
+    it (None: no row). Returns (o ``[B, H, d_v]``, the state after).
+
+    ONE algorithm with a shape rule (``kda_update.supported``): a float32
+    state whose ``d_v`` is whole lane tiles and ``d_k`` whole sublane tiles
+    takes the Pallas kernel ``kda_update``, in place, for any number of
+    rows and heads; any other shape runs ``step_plain``."""
+    if kda_update.supported(state.shape, state.dtype):
+        return kda_update.kda_update(q, k, v, g, beta, state, fresh)
+    return step_plain(q, k, v, g, beta, state, fresh)
+
+
+def step_plain(q, k, v, g, beta, state, fresh=None):
+    """``step`` in ``jax.numpy`` (what a shape the kernel does not take
+    runs, and the kernel's reference in the tests)."""
+    if fresh is not None:
+        state = jnp.where(fresh[:, None, None, None], 0.0, state)
     decayed = state * jnp.exp(g)[..., None]
     s_k = jnp.sum(decayed * k[..., None], axis=-2)
     s_q = jnp.sum(decayed * q[..., None], axis=-2)
@@ -250,13 +278,16 @@ def mixer(p, cfg, hid, state, tail, pos, n_valid):
     with jax.named_scope("gate"):
         g, beta, g_a = gates(p, cfg, hid, n_valid)
     with jax.named_scope("update"):
-        s32 = jnp.where(fresh[:, None, None, None], 0.0,
-                        state.astype(jnp.float32))
+        s32 = state.astype(jnp.float32)
         if s == 1:
-            o, s32 = step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s32)
+            # the frontier-0 select rides in ``step``: outside it is a pass
+            # over the state of its own
+            o, s32 = step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s32,
+                          fresh)
             o = o[:, None]
         else:
-            o, s32 = chunked(q, k, v, g, beta, s32)
+            o, s32 = chunked(q, k, v, g, beta, jnp.where(
+                fresh[:, None, None, None], 0.0, s32))
         state = s32.astype(state.dtype)
     with jax.named_scope("gate_norm"):
         o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)

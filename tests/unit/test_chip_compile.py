@@ -29,7 +29,7 @@ from deepspeed_tpu.ops.sparse_attention.kernels import (  # noqa: E402
     block_sparse_attention)
 from deepspeed_tpu.ops.transformer.kernels import (  # noqa: E402
     attention, decode_attention as da, fused_bias_dropout_residual, gelu,
-    layer_norm, softmax)
+    kda_update, layer_norm, softmax)
 from deepspeed_tpu.ops.transformer.kernels import (  # noqa: E402
     dropout as ds_dropout)
 
@@ -212,6 +212,14 @@ def _granite_append(k, v, ka, va, tbl, pos):
     return da.kv_append((ka, va), (k, v), tbl, pos, layer=0)
 
 
+def _kda_update_args(rows):
+    """Kimi Linear's 32 heads of 128: q, k, v, g [B, H, d], beta [B, H], the
+    float32 state [B, H, d_k, d_v], the frontier-0 flags [B]."""
+    return [((rows, 32, 128), F32)] * 4 + [
+        ((rows, 32), F32), ((rows, 32, 128, 128), F32),
+        ((rows,), jnp.bool_)]
+
+
 def _dense_decode_args(rows, int8=False):
     plane = ((SLOTS, HEADS, T_KV, HD), I8 if int8 else BF16)
     scale = ((SLOTS, HEADS, T_KV), F32)
@@ -325,6 +333,13 @@ CASES = {
         _latent_append, _latent_append_args(1, D_SLOTS), {}),
     "granite_kv_append_64_rows_gqa_d128": (
         _granite_append, _granite_append_args(1, G_SLOTS), {}),
+    # The one-token KDA update at the Kimi cell's pool (all 32 heads of a
+    # row one unit: 4 x 2 MiB of blocks) and at the batch of 1 the
+    # benchmark's state probe calls it with.
+    "kda_update_128_slots_32_heads": (kda_update.kda_update,
+                                      _kda_update_args(128), {}),
+    "kda_update_1_row_32_heads": (kda_update.kda_update,
+                                  _kda_update_args(1), {}),
     "fused_layer_norm_fwd_bwd": (
         _fwd_bwd(lambda x, g, b: layer_norm.fused_layer_norm(x, g, b), 3),
         [LN_X, VEC, VEC], {}),
@@ -353,7 +368,7 @@ CASES = {
 KERNEL_NAME = re.compile(
     r"^(flash_fwd|flash_bwd_fused|flash_bwd_dq|flash_bwd_dkv|decode_attn|"
     r"decode_attn_q8|paged_decode|paged_decode_q8|prefill_attn|kv_append|"
-    r"latent_decode|"
+    r"latent_decode|kda_update|"
     r"sparse_attn_fwd|sparse_attn_bwd_fused|sparse_attn_bwd_dq|"
     r"sparse_attn_bwd_dkv|dropout_fwd|dropout_mask|bias_gelu|"
     r"layer_norm_fwd|attn_softmax)(\.\d+)?$")
@@ -789,10 +804,12 @@ def test_kimi_mixed_step_holds_three_caches_each_where_it_lies(
     deep as the MLA layers only, that meets ``kv_append`` and
     ``latent_decode`` once an MLA layer in the decode scan and is formed whole
     nowhere; each KDA layer's float32 state ``slot_kda<j>``
-    [128, 32, 128, 128] is in the scan the result of fusions alone (the
-    update, where it lies in the scan's carry): no ``copy``, no slice, no
-    ``dynamic-update-slice``; and the step fits the chip beside its weights
-    and pool."""
+    [128, 32, 128, 128] is in the scan the result of its ``kda_update`` call
+    (aliased: updated where it lies in the scan's carry) and of NOTHING
+    else: no ``copy``, no slice, no ``dynamic-update-slice``, no fusion that
+    writes a whole state, and no fusion that reads one (the frontier-0
+    select and both sums are the kernel's); and the step fits the chip
+    beside its weights and pool."""
     from deepspeed_tpu.inference import kv_pool
     from deepspeed_tpu.inference.adapters import DecoderAdapter
     from deepspeed_tpu.inference.config import InferenceConfig
@@ -835,10 +852,11 @@ def test_kimi_mixed_step_holds_three_caches_each_where_it_lies(
     scan, in_scan = _scan_lines(comps)
     names = sorted(c.split(".")[0] for c in _kernel_calls(
         "\n".join(in_scan)))
-    assert names == ["kv_append"] * 3 + ["latent_decode"] * 3
+    assert names == ["kda_update"] * 9 + ["kv_append"] * 3 \
+        + ["latent_decode"] * 3
     everywhere = sorted(c.split(".")[0] for c in _kernel_calls(text))
-    assert everywhere == ["kv_append"] * 6 + ["latent_decode"] * 3 \
-        + ["prefill_attn"] * 3
+    assert everywhere == ["kda_update"] * 9 + ["kv_append"] * 6 \
+        + ["latent_decode"] * 3 + ["prefill_attn"] * 3
     arena = ["[3,3073,1,128,640]", "[3073,1,128,640]"]
     assert _arena_shaped([line for lines in comps.values()
                           for line in lines], arena) == []
@@ -850,8 +868,33 @@ def test_kimi_mixed_step_holds_three_caches_each_where_it_lies(
                 if not name.startswith("fused_computation")
                 for line in comps[name]]
 
-    state = ["f32[128,32,128,128]"]
-    touched = _arena_shaped(whole(scan), state)
-    assert touched and {op for _, op in touched} == {"fusion"}, touched
-    outside = _arena_shaped(whole(set(comps) - set(scan)), state)
+    state = "f32[128,32,128,128]"
+    in_scan = whole(scan)
+    # nothing in the scan but the kernel has a whole state for its result
+    assert _arena_shaped(in_scan, [state]) == []
+    updates = [line for line in in_scan
+               if 'custom_call_target="tpu_custom_call"' in line
+               and state in line]
+    assert len(updates) == 9 and all("kda_update" in line
+                                     for line in updates)
+    # each is aliased to its state operand (operand 5: three scalar
+    # prefetches, the unit's rows of decay | k | q, v, the state; output 1)
+    assert all(re.search(r"output_to_operand_aliasing=\{[^=]*\{1\}: \(5, "
+                         r"\{\}\)", line) for line in updates), updates
+    # and nothing else in the scan READS a whole state (the frontier-0
+    # select and both sums are the kernel's): a state's name is an operand
+    # of its kernel and of plumbing alone
+    held = {m.group(1) for m in (re.match(
+        r"(?:ROOT )?%([\w.-]+) = " + re.escape(state), line)
+        for line in in_scan) if m}
+    assert len(held) >= 18, held
+    readers = []
+    for line in in_scan:
+        m = re.match(r"(?:ROOT )?%[\w.-]+ = .*? ([\w-]+)\((.*?)\)", line)
+        if m and m.group(1) not in _PLUMBING and \
+                'custom_call_target="tpu_custom_call"' not in line and \
+                held & set(re.findall(r"%([\w.-]+)", m.group(2))):
+            readers.append(line[:200])
+    assert readers == [], readers
+    outside = _arena_shaped(whole(set(comps) - set(scan)), [state])
     assert {op for _, op in outside} <= {"fusion"}, outside

@@ -137,6 +137,196 @@ def test_a_channel_that_forgets_everything_overflows_nothing():
                                **SAME)
 
 
+# ---------------------------------------------- the one-token kernel
+# ``step`` at a shape that TAKES ``kda_update`` (a float32 state of whole
+# tiles: d 128), through the Pallas interpreter: the same sums in the same
+# order as the plain form, so the two agree to float32 rounding (measured:
+# bit for bit here) and both are the reference's token loop, far under the
+# benchmark's state probe (2e-3).
+
+KERNEL_D = 128
+CLOSE = dict(rtol=1e-5, atol=1e-5)
+
+
+def rel_err(got, want):
+    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+
+def one_token(batch, h, seed=0):
+    """One token's q, k, v, g, beta and a state of order 1, at d 128."""
+    q, k, v, g, beta = (x[:, 0] for x in recurrence_inputs(
+        1, h=h, d=KERNEL_D, seed=seed, batch=batch))
+    state = jax.random.normal(jax.random.PRNGKey(seed + 100),
+                              (batch, h, KERNEL_D, KERNEL_D))
+    return q, k, v, g, beta, state
+
+
+@pytest.mark.parametrize("shape, heads", [
+    ((128, 32, 128, 128), 32),        # the cell's pool: 4 x 2 MiB of blocks
+    ((1, 32, 128, 128), 32),          # the benchmark's state probe
+    ((4, 3, 128, 128), 3), ((2, 64, 128, 128), 32), ((2, 7, 256, 256), 7),
+    ((2, 96, 128, 256), 24), ((2, 4, 8, 128), 4),
+    # the plain form: a tiny d, lanes or sublanes that are no whole tile
+    ((3, 4, 16, 16), 0), ((2, 4, 128, 64), 0), ((2, 4, 12, 128), 0)])
+def test_the_shape_rule_decides_kernel_or_plain_form_and_the_unit(shape,
+                                                                   heads):
+    from deepspeed_tpu.ops.transformer.kernels import kda_update
+
+    assert kda_update.unit_heads(shape, jnp.float32) == heads
+    assert kda_update.supported(shape, jnp.float32) == (heads > 0)
+    # a state kept in bf16 is not the kernel's, whatever its shape
+    assert kda_update.unit_heads(shape, jnp.bfloat16) == 0
+
+
+def _calls_kernel(fn, *args):
+    return "kda_update" in jax.jit(fn).lower(*args).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("batch, h", [(1, 32), (1, 3), (4, 4), (3, 5)],
+                         ids=["probe_b1_h32", "b1_h3", "b4_h4", "b3_h5"])
+def test_step_through_the_kernel_is_the_plain_form(batch, h):
+    args = one_token(batch, h, seed=batch + h)
+    assert _calls_kernel(kda.step, *args)
+    assert not _calls_kernel(kda.step_plain, *args)
+    o, state = jax.jit(kda.step)(*args)
+    want_o, want_state = jax.jit(kda.step_plain)(*args)
+    assert rel_err(o, want_o) < 1e-6 and rel_err(state, want_state) < 1e-6
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o), **CLOSE)
+    np.testing.assert_allclose(np.asarray(state), np.asarray(want_state),
+                               **CLOSE)
+
+
+def test_on_a_mesh_every_shard_runs_the_kernel_on_whole_rows_and_heads():
+    """Under ``kernels_on_mesh`` (a 2x2 'data' x 'model' mesh) the launch is
+    shard-local with every operand whole: the pool keeps a slot's state
+    replicated, and the result is the plain form's on one device."""
+    from deepspeed_tpu.ops.transformer.kernels.attention import (
+        kernels_on_mesh)
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+
+    mesh = mesh_lib.build_mesh(devices=jax.devices()[:4], num_mp=2, num_dp=2)
+    args = one_token(2, 4, seed=11)
+    fresh = jnp.asarray([True, False])
+
+    def on_mesh(*a):
+        with kernels_on_mesh(mesh):
+            return kda.step(*a, fresh=fresh)
+
+    assert _calls_kernel(on_mesh, *args)
+    o, state = jax.jit(on_mesh)(*args)
+    want_o, want_state = kda.step_plain(*args, fresh=fresh)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o), **CLOSE)
+    np.testing.assert_allclose(np.asarray(state), np.asarray(want_state),
+                               **CLOSE)
+
+
+def test_a_tiny_d_runs_the_plain_form():
+    q, k, v, g, beta = (x[:, 0] for x in recurrence_inputs(1))
+    state = jnp.ones((2, 3, 16, 16))
+    assert not _calls_kernel(kda.step, q, k, v, g, beta, state)
+    o, after = kda.step(q, k, v, g, beta, state)
+    want_o, want = kda.step_plain(q, k, v, g, beta, state)
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(want_o))
+    np.testing.assert_array_equal(np.asarray(after), np.asarray(want))
+
+
+@pytest.mark.parametrize("batch, h, t", [(1, 4, 300), (3, 2, 200)],
+                         ids=["probe_b1", "scan_b3"])
+def test_the_kernel_in_a_scan_is_the_references_token_loop(batch, h, t):
+    """``t`` tokens a token at a time inside ``lax.scan`` with the state as
+    the carry (as the decode scan and the benchmark's state probe hold it):
+    outputs and the state after are the reference's."""
+    q, k, v, g, beta = recurrence_inputs(t, h=h, d=KERNEL_D, seed=7,
+                                         batch=batch)
+
+    @jax.jit
+    def run(q, k, v, g, beta):
+        def token(state, x):
+            o, state = kda.step(*x, state)
+            return state, o
+
+        state, o = jax.lax.scan(
+            token, jnp.zeros((batch, h, KERNEL_D, KERNEL_D)),
+            tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta)))
+        return jnp.moveaxis(o, 0, 1), state
+
+    assert "kda_update" in run.lower(q, k, v, g, beta).as_text(
+        debug_info=True)
+    o, state = run(q, k, v, g, beta)
+    want_o, want_s = zip(*(reference.delta_rule(*(x[b] for x in
+                                                  (q, k, v, g, beta)))
+                           for b in range(batch)))
+    assert rel_err(state, jnp.stack(want_s)) < 2e-5
+    assert rel_err(o, jnp.stack(want_o)) < 2e-5
+    np.testing.assert_allclose(np.asarray(state), np.asarray(
+        jnp.stack(want_s)), **SAME)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(jnp.stack(want_o)),
+                               **SAME)
+
+
+def test_idle_rows_stay_bit_identical_beside_moving_rows_in_one_call():
+    """``g = 0`` and ``beta = 0`` (what ``gates`` hands a row that is not
+    decoding): ``S * 1 + k * 0``, the state bit for bit, whatever the
+    row's q, k and v, beside rows that move."""
+    q, k, v, g, beta, state = one_token(4, 3, seed=3)
+    idle = jnp.asarray([False, True, False, True])
+    g = jnp.where(idle[:, None, None], 0.0, g)
+    beta = jnp.where(idle[:, None], 0.0, beta)
+    _, after = jax.jit(kda.step)(q, k, v, g, beta, state)
+    got, before = np.asarray(after), np.asarray(state)
+    for b in range(4):
+        if idle[b]:
+            assert got[b].tobytes() == before[b].tobytes()
+        else:
+            assert np.abs(got[b] - before[b]).max() > 1e-3
+
+
+def test_a_fresh_row_starts_from_zeros_with_its_slot_full_of_nan():
+    """The frontier-0 flag is a SELECT inside the kernel: a fresh row's
+    slot may hold anything, NaN included, and the rows beside it keep
+    theirs."""
+    q, k, v, g, beta, state = one_token(3, 4, seed=5)
+    fresh = jnp.asarray([False, True, False])
+    dirty = state.at[1].set(jnp.nan)
+    o, after = jax.jit(kda.step)(q, k, v, g, beta, dirty, fresh=fresh)
+    want_o, want = kda.step_plain(q, k, v, g, beta,
+                                  state.at[1].set(0.0))
+    assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(after).all())
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o), **CLOSE)
+    np.testing.assert_allclose(np.asarray(after), np.asarray(want), **CLOSE)
+    # a fresh row's state after one token is the rank-one write alone
+    np.testing.assert_allclose(
+        np.asarray(after[1]), np.asarray(
+            k[1][:, :, None] * (beta[1][:, None] * v[1])[:, None, :]),
+        **CLOSE)
+    # without the flag (the probe passes none) no row is fresh
+    _, kept = jax.jit(kda.step)(q, k, v, g, beta, state)
+    np.testing.assert_allclose(np.asarray(kept), np.asarray(
+        kda.step_plain(q, k, v, g, beta, state)[1]), **CLOSE)
+
+
+def test_the_mixer_hands_the_kernel_its_fresh_rows():
+    """``mixer`` at a kernel shape, one token: the frontier-0 select is the
+    kernel's (no ``select`` of a whole state outside it), a fresh row with
+    a slot full of NaN comes out as from zeros, an idle row keeps its
+    bits."""
+    cfg = CFG._replace(kda_heads=2, kda_head_dim=KERNEL_D)
+    p = kda.init_layer(jax.random.PRNGKey(0), cfg)
+    hid = jax.random.normal(jax.random.PRNGKey(1), (3, 1, cfg.hidden_size))
+    state = jax.random.normal(jax.random.PRNGKey(2),
+                              (3, 2, KERNEL_D, KERNEL_D))
+    tail = jnp.zeros((3, cfg.kda_conv - 1, 3 * 2 * KERNEL_D))
+    pos, n_valid = jnp.asarray([0, 5, 5]), jnp.asarray([1, 1, 0])
+    mix = jax.jit(lambda *a: kda.mixer(p, cfg, *a))
+    out, after, _ = mix(hid, state.at[0].set(jnp.nan), tail, pos, n_valid)
+    want_out, want, _ = mix(hid, state.at[0].set(0.0), tail, pos, n_valid)
+    assert bool(jnp.isfinite(out).all())
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want_out))
+    np.testing.assert_array_equal(np.asarray(after), np.asarray(want))
+    assert np.asarray(after[2]).tobytes() == np.asarray(state[2]).tobytes()
+    assert np.abs(np.asarray(after[1] - state[1])).max() > 1e-6
+
+
 def test_the_mixers_state_and_tails_are_the_references(model):
     """The whole mixer of a KDA layer on the reference's normed stream: what
     it adds to the stream, the state after the last token and the three rows

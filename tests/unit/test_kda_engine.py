@@ -7,13 +7,15 @@ restore of a latent page set WITH the state a slot.
 
 import jax
 import numpy as np
+import pytest
 
 from deepspeed_tpu.inference import InferenceEngine, kv_pool
 from deepspeed_tpu.inference.kv_hierarchy import offload
-from deepspeed_tpu.models import decoder
+from deepspeed_tpu.models import decoder, kda
 from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
 from tests.unit.test_kda import (  # noqa: F401  (``model`` is a fixture)
     CFG, STATE, alone, builder, engine, model, tokens)
+from tests.unit.test_telemetry import _parse_prom
 
 
 # -------------------------------------------------------------- the engine
@@ -94,6 +96,77 @@ def test_the_gauges_read_the_mix_at_three_mla_and_nine_kda_layers():
     assert whole.n_layer * whole.n_embd * 2 == 7 * 1280
     assert kv_pool.slot_state_nbytes(whole) == 20 * (32 * 128 * 128 * 4
                                                      + 3 * 12288 * 2)
+
+
+@pytest.mark.parametrize("name, heads", [
+    # a float32 state of whole tiles: all of a row's heads one unit
+    ("kda_2_heads_of_128", 2),
+    # the tiny configuration: the plain form
+    ("kda_4_heads_of_16", 0),
+    # no KDA layer at all
+    ("no_kda_layer", 0)])
+def test_update_unit_heads_gauge_is_the_launchers_own_rule(name, heads):
+    """``kda_update_unit_heads`` is Hb as ``kda_update.unit_heads`` resolves
+    it for the pool's own ``slot_kda<j>`` (0 where the plain form runs or
+    the model has no KDA layer), in ``metrics()`` and the export alike."""
+    from deepspeed_tpu.ops.transformer.kernels import kda_update
+
+    cfg = {
+        "kda_2_heads_of_128": CFG._replace(
+            n_layer=2, layer_types=("kda", "attention"), kda_heads=2,
+            kda_head_dim=128),
+        "kda_4_heads_of_16": CFG._replace(
+            n_layer=2, layer_types=("kda", "attention")),
+        "no_kda_layer": CFG._replace(
+            n_layer=2, layer_types=None, kda_heads=0, kda_head_dim=0),
+    }[name]
+    m = DecoderLM(cfg)
+    eng = engine((m, m.init(jax.random.PRNGKey(0))["params"]))
+    state = eng._pool.get("slot_kda0")
+    assert (state is None) == (name == "no_kda_layer")
+    if state is not None:
+        assert kda_update.unit_heads(state.shape, state.dtype) == heads
+    assert eng.metrics()["kda_update_unit_heads"] == heads
+    kinds, samples = _parse_prom(eng.prometheus())
+    assert kinds["ds_tpu_kda_update_unit_heads"] == "gauge"
+    assert [v for (n, _), v in samples.items()
+            if n == "ds_tpu_kda_update_unit_heads"] == [heads]
+
+
+def test_the_engine_serves_through_the_kernel_what_the_plain_form_serves(
+        monkeypatch):
+    """Two heads of 128 take ``kda_update`` in the decode scan (interpret
+    mode here); with the shape rule switched off IN THE TEST the plain form
+    serves the same tokens, alone or beside a late neighbour."""
+    from deepspeed_tpu.ops.transformer.kernels import kda_update
+
+    cfg = CFG._replace(n_layer=2, layer_types=("kda", "attention"),
+                       kda_heads=2, kda_head_dim=128)
+    m = DecoderLM(cfg)
+    params = m.init(jax.random.PRNGKey(0))["params"]
+    prompts = [tokens(n, seed=50 + n)[0] for n in (5, 11)]
+
+    def served():
+        eng = engine((m, params), max_slots=2)
+        reqs = [eng.submit(p, max_new_tokens=9) for p in prompts]
+        eng.run()
+        assert eng.compile_count == 1
+        return [r.tokens for r in reqs]
+
+    traced = []
+    for name, module in (("kernel", kda_update), ("plain", kda)):
+        fn = "kda_update" if name == "kernel" else "step_plain"
+
+        def counted(*a, _name=name, _fn=getattr(module, fn), **kw):
+            traced.append(_name)
+            return _fn(*a, **kw)
+
+        monkeypatch.setattr(module, fn, counted)
+    through_kernel = served()
+    assert set(traced) == {"kernel"}
+    monkeypatch.setattr(kda_update, "supported", lambda shape, dtype: False)
+    assert served() == through_kernel and all(through_kernel)
+    assert "plain" in traced
 
 
 def test_preempt_then_resume_continues_token_for_token(model):
