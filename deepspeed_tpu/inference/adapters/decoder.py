@@ -21,12 +21,17 @@ layer, ``moe_tokens_absent`` (choices that fell on experts held elsewhere).
 They count every row the program computes (idle slots decode garbage by
 design), so they read as the program's load, not as requests' tokens.
 
-A HYBRID stack (``layer_types`` with ``mamba`` or ``kda`` layers) carries a
-recurrent state a slot: ``cache_spec().slot_state`` names it, the pool
-allocates and threads it (``kv_pool.py``, A RECURRENT STATE A SLOT) and
-``observe`` publishes its size as ``ssm_state_bytes`` (ONE gauge for any
-``slot_state``: a Mamba-2 layer's state-space state or a KDA layer's matrix
-state, each with its convolution tail). What the stale-cache rule gave
+A HYBRID stack (``layer_types`` with ``mamba``, ``kda`` or ``shortconv``
+layers) carries a recurrent state a slot: ``cache_spec().slot_state`` names
+it, the pool allocates and threads it (``kv_pool.py``, A RECURRENT STATE A
+SLOT) and ``observe`` publishes its size as ``ssm_state_bytes`` (ONE gauge
+for any ``slot_state``: a Mamba-2 layer's state-space state or a KDA layer's
+matrix state, each with its convolution tail, 19 to 38 MB a slot; a gated
+short convolution's tail, the ONE array a layer of a kind with one state, 72
+KB a slot at LFM2-8B-A1B's nine layers). A small state is refused what a
+large one is: the refusals go by mechanism, not by size, and a two-row tail
+cheap enough to snapshot every step is the first candidate to lift the one on
+speculation (ROADMAP.md Reach A1). What the stale-cache rule gave
 the engine for free does not exist for it, so ``bind`` REFUSES, by the name
 of the mechanism, what would need a snapshot of the state: speculative
 decoding (a rejected draft has already moved the state), the prefix cache

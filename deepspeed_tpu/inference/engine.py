@@ -859,6 +859,8 @@ class InferenceEngine(object):
                              page_len=self.config.kv_page_len,
                              num_pages=self._pager.total_pages)
             self.telemetry.gauge("kv_lane_pack").set(self._lane_pack(pool))
+            self.telemetry.gauge("kv_query_group").set(
+                self._query_group(pool))
             self.telemetry.gauge("kv_unit_pages").set(self._unit_pages(pool))
             self.telemetry.gauge("kv_append_unit_rows").set(
                 self._append_unit_rows(pool))
@@ -891,6 +893,21 @@ class InferenceEngine(object):
         pool = self._pool if pool is None else pool
         return pool["k"].shape[-1] // (
             self._gcfg.n_embd // self._gcfg.n_head)
+
+    def _query_group(self, pool=None):
+        """``rep``, the query heads of the model that share one stored head
+        of the paged arena, as the paged launchers resolve it from shapes
+        (``decode_attention.query_group``: 4 for 32 query heads over 8
+        stored, packed or not; 1 where every query head stores its own, and
+        for a latent cache, whose one stored head every query reads by
+        another launcher)."""
+        pool = self._pool if pool is None else pool
+        spec = self._gcfg
+        if getattr(spec, "latent", 0):
+            return 1
+        return decode_attention.query_group(
+            pool["k"], getattr(self._adapter, "gcfg", spec).n_head,
+            spec.n_embd // spec.n_head)
 
     def _unit_pages(self, pool=None):
         """K, the consecutive pages of a row that one unit of the decode
@@ -2511,6 +2528,7 @@ class InferenceEngine(object):
             m.update({
                 "kv_page_len": pg.page_len,
                 "kv_lane_pack": self._lane_pack(),
+                "kv_query_group": self._query_group(),
                 "kv_unit_pages": self._unit_pages(),
                 "kv_unit_fill": round(self._unit_fill(), 4),
                 "kv_append_unit_rows": self._append_unit_rows(),

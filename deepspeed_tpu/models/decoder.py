@@ -44,6 +44,20 @@ a row (``cache_spec`` composes the two); its dense leading layer's mixer is
 a KDA layer. The kinds that carry a state a row are ``RECURRENT``: one
 branch of ``forward`` for Mamba-2 and KDA alike.
 
+And once more LFM2's (``model_type`` ``lfm2_moe``: LFM2-8B-A1B), by a fourth
+kind and one more value: ``"shortconv"`` layers run the GATED SHORT
+CONVOLUTION (``models/shortconv.py``: two gates around a depthwise causal
+convolution of width 3), whose ONLY state a row is its tail, two rows of the
+stream's width, so a recurrent kind carries as many arrays a layer as its
+``state_keys`` names and ``forward`` threads whatever they are; and
+``qk_norm`` ``"head"`` norms the queries and keys A HEAD (one weight
+``[head_dim]`` for all query heads, one for all key heads, over each head's
+lanes, before the rotation) where ``True`` is OLMoE's norm over the whole
+projected width. Its three attention layers in twelve are the first to be
+rotary, grouped-query and paged at once, at a head of 64: the pool packs two
+stored heads a lane tile while four query heads share each
+(``decode_attention.py``, ``lane_pack`` x grouped-query rows).
+
 THE ABSORBED FORM IS THE ONE PATH of latent attention, for the lane and the
 scan alike. Per head ``[k_nope_h | v_h] = c_kv W_kvb,h``, so
 ``q_nope_h . k_nope_h(u) = (q_nope_h W_uk,h) . c_kv(u)`` and
@@ -73,7 +87,7 @@ leading axis, dense kernels ``[in, out]``::
 
     embed [V, C]                 final_norm [C]       lm_head [C, V] (untied)
     layers/attn_norm [L, C]      layers/wqkv [L, C, 3*H*D]  (q | k | v)
-    layers/q_norm, k_norm [L, H*D]                    layers/wo [L, H*D, C]
+    layers/q_norm, k_norm [L, H*D] ([L, D]: a head)   layers/wo [L, H*D, C]
     layers/ffn_norm [L, C]       layers/router [L, C, E]
     layers/w_gate_up [L, E, C, 2F]  (gate | up)       layers/w_down [L, E, F, C]
 
@@ -82,7 +96,8 @@ layer has (the two norms, the router, the held experts ``[L, E_held, ..]``,
 ``shared_gate_up [L, C, 2Fs]`` / ``shared_down [L, Fs, C]``) and stacks each
 kind of mixer over the layers of that kind: ``attn/wqkv [La, C, (H + 2 Hkv) D]``
 (+ the QK norms), ``attn/wo``; ``mamba/...`` [Lm, ..] (``mamba2.init_layer``);
-``kda/...`` [Lk, ..] (``kda.init_layer``).
+``kda/...`` [Lk, ..] (``kda.init_layer``); ``shortconv/...`` [Lc, ..]
+(``shortconv.init_layer``).
 A latent-attention stack (``kv_lora_rank`` given) keeps the two norms under
 ``layers`` and stacks the rest by kind (``L`` the layers of that kind):
 ``mla/wq_a [L, C, Rq]``,
@@ -103,7 +118,8 @@ The regions of a trace (``jax.named_scope``, under the caller's
 ``rope``, ``qk_norm``, attention, projection; latent attention: ``q_proj``,
 ``kv_proj``, ``rope``, ``absorb`` (the two per-head products with
 ``W_kvb``), ``o_proj``), ``kv_write``, ``kv_view``,
-or ``mamba`` (``mamba2.mixer``'s words) or ``kda`` (``kda.mixer``'s);
+or ``mamba`` (``mamba2.mixer``'s words), ``kda`` (``kda.mixer``'s) or
+``shortconv`` (``shortconv.mixer``'s);
 ``moe`` holding ``router``,
 ``dispatch``, ``experts``, ``combine`` and ``shared``, or ``mlp`` for a dense
 layer; then ``lm_head``.
@@ -122,7 +138,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.analysis.annotations import hot_path
-from deepspeed_tpu.models import generation, kda, mamba2
+from deepspeed_tpu.models import generation, kda, mamba2, shortconv
 from deepspeed_tpu.moe import routed
 
 
@@ -144,7 +160,8 @@ class DecoderConfig(typing.NamedTuple):
     expert_width: int
     rms_norm_eps: float = 1e-5
     rope_theta: float = 10000.0
-    qk_norm: bool = True
+    # True: over the whole projected width; "head": over each head's lanes
+    qk_norm: typing.Union[bool, str] = True
     norm_topk_prob: bool = False
     tie_word_embeddings: bool = False
     dtype: typing.Any = jnp.bfloat16
@@ -160,7 +177,8 @@ class DecoderConfig(typing.NamedTuple):
     shared_width: int = 0                      # 0: no shared expert
     # (first, count) of the router's experts this chip holds; None: all
     experts_held: typing.Optional[typing.Tuple[int, int]] = None
-    # "attention" | "mamba" | "kda" a layer; None: attention everywhere
+    # "attention" | "mamba" | "kda" | "shortconv" a layer; None: attention
+    # everywhere
     layer_types: typing.Optional[typing.Tuple[str, ...]] = None
     mamba_heads: int = 0
     mamba_head_dim: int = 0
@@ -186,6 +204,10 @@ class DecoderConfig(typing.NamedTuple):
     kda_heads: int = 0
     kda_head_dim: int = 0
     kda_conv: int = 4
+    # The gated short convolution's width (``models/shortconv.py``). A field
+    # of its own: a kind's ``state_shapes`` reads its own width, and a stack
+    # may hold two kinds (Kimi's KDA at 4, LFM2's at 3).
+    shortconv_kernel: int = 3
 
     @property
     def n_embd(self):
@@ -215,6 +237,10 @@ class DecoderConfig(typing.NamedTuple):
     @property
     def kda_layers(self):
         return tuple(i for i, k in enumerate(self.kinds) if k == "kda")
+
+    @property
+    def shortconv_layers(self):
+        return tuple(i for i, k in enumerate(self.kinds) if k == "shortconv")
 
     @property
     def held(self):
@@ -249,9 +275,12 @@ class DecoderConfig(typing.NamedTuple):
 
 # The kinds of layer whose mixer carries a recurrent state a row in place of
 # keys (``layer_types``; the region of a trace and the parameters' tree take
-# the kind's name), each a module with ``state_shapes``, ``state_keys``,
-# ``init_layer`` and ``mixer``.
-RECURRENT = {"mamba": mamba2, "kda": kda}
+# the kind's name), each a module with ``state_shapes(cfg)``, ``state_keys(j)``
+# (the arrays layer ``j`` of the kind carries a row, ANY number of them: two
+# for Mamba-2 and KDA, a state and a tail; one for the short convolution),
+# ``init_layer(key, cfg)`` and ``mixer(p, cfg, h, *states, pos, n_valid)`` ->
+# ``(out, *states)``.
+RECURRENT = {"mamba": mamba2, "kda": kda, "shortconv": shortconv}
 
 
 class CacheSpec(typing.NamedTuple):
@@ -259,7 +288,7 @@ class CacheSpec(typing.NamedTuple):
     and v planes (``latent``: the ONE plane) are as deep as the layers that
     hold keys and as wide as the heads a token STORES, and ``slot_state``
     names the recurrent state a row carries beside them
-    (``mamba2.state_shapes``, ``kda.state_shapes``; empty without it)."""
+    (the ``state_shapes`` of every ``RECURRENT`` kind; empty without it)."""
 
     n_layer: int
     n_head: int
@@ -311,8 +340,11 @@ def init_params(key, cfg):
         out = {"wqkv": normal(k_qkv, (c, q_w + 2 * kv_w)),
                "wo": normal(k_out, (q_w, c))}
         if cfg.qk_norm:
-            out["q_norm"] = jnp.ones((q_w,), cfg.dtype)
-            out["k_norm"] = jnp.ones((kv_w,), cfg.dtype)
+            a_head = cfg.qk_norm == "head"
+            out["q_norm"] = jnp.ones((cfg.head_dim if a_head else q_w,),
+                                     cfg.dtype)
+            out["k_norm"] = jnp.ones((cfg.head_dim if a_head else kv_w,),
+                                     cfg.dtype)
         return out
 
     def experts(k):
@@ -373,12 +405,11 @@ def init_params(key, cfg):
         params["attn"] = jax.lax.map(
             lambda k: attention(*jax.random.split(k)), jax.random.split(
                 jax.random.fold_in(key, 3), len(cfg.kv_layers)))
-    if cfg.mamba_layers:
-        params["mamba"] = stacked(lambda k: mamba2.init_layer(k, cfg), 4,
-                                  len(cfg.mamba_layers))
-    if cfg.kda_layers:
-        params["kda"] = stacked(lambda k: kda.init_layer(k, cfg), 9,
-                                len(cfg.kda_layers))
+    for kind, salt in (("mamba", 4), ("kda", 9), ("shortconv", 10)):
+        if kind in cfg.kinds:
+            params[kind] = stacked(
+                lambda k, kind=kind: RECURRENT[kind].init_layer(k, cfg),
+                salt, cfg.kinds.count(kind))
     if cfg.kv_lora_rank:
         params["mla"] = stacked(latent, 6, len(cfg.kv_layers))
     if cfg.dense_layers:
@@ -456,19 +487,22 @@ def _residual(cfg, x, branch):
     return x + branch.astype(x.dtype)
 
 
-def attention(layer, cfg, x, i, rope, attend, planes):
-    """An attention layer's mixer: ``x`` [B, S, C] -> (x, the cache planes
-    with layer ``i`` OF THE CACHE written). ``rope`` None: no rotary."""
-    b, s, c = x.shape
+def attention_mix(layer, cfg, h, i, rope, attend, planes):
+    """What an attention layer ADDS to the stream, from the normed stream
+    ``h`` [B, S, C] in ``cfg.dtype``: (y [B, S, C], the cache planes with
+    layer ``i`` OF THE CACHE written). ``rope`` None: no rotary."""
+    b, s, c = h.shape
     nh, nkv, hd, eps, dt = cfg.n_head, cfg.n_kv, cfg.head_dim, \
         cfg.rms_norm_eps, cfg.dtype
     with jax.named_scope("attn"):
-        h = _rms32(x, layer["attn_norm"], eps).astype(dt)
         q, k, v = jnp.split(h @ layer["wqkv"].astype(dt),
                             [nh * hd, (nh + nkv) * hd], axis=-1)
+        if cfg.qk_norm == "head":
+            # over each head's lanes, one weight for all the heads; else
+            # over the whole projected width, before the heads split
+            q, k = q.reshape(b, s, nh, hd), k.reshape(b, s, nkv, hd)
         if cfg.qk_norm:
             with jax.named_scope("qk_norm"):
-                # over the whole projected width, before the heads split
                 q = _rms32(q, layer["q_norm"], eps)
                 k = _rms32(k, layer["k_norm"], eps)
         q, k = q.reshape(b, s, nh, hd), k.reshape(b, s, nkv, hd)
@@ -482,8 +516,17 @@ def attention(layer, cfg, x, i, rope, attend, planes):
     y, planes = attend(i, q, k, v, planes)
     with jax.named_scope("attn"):
         y = y.transpose(0, 2, 1, 3).reshape(b, s, nh * hd)
-        x = _residual(cfg, x, y @ layer["wo"].astype(dt))
-    return x, planes
+        return y @ layer["wo"].astype(dt), planes
+
+
+def attention(layer, cfg, x, i, rope, attend, planes):
+    """An attention layer's mixer: ``x`` [B, S, C] -> (x with
+    ``attention_mix`` of its norm added, the cache planes)."""
+    with jax.named_scope("attn"):
+        h = _rms32(x, layer["attn_norm"], cfg.rms_norm_eps).astype(cfg.dtype)
+    y, planes = attention_mix(layer, cfg, h, i, rope, attend, planes)
+    with jax.named_scope("attn"):
+        return _residual(cfg, x, y), planes
 
 
 def latent_token(layer, cfg, h, rope):
@@ -561,10 +604,12 @@ def router_logits(n32, router):
                    precision=jax.lax.Precision.HIGHEST)
 
 
-def moe(layer, cfg, x):
+def moe(layer, cfg, x, chosen=None):
     """The feed-forward half of a layer: ``x`` [B, S, C] -> (x, tokens
     routed to each HELD expert [E_held], choices that fell on experts held
-    elsewhere (a scalar; 0 for a model held whole))."""
+    elsewhere (a scalar; 0 for a model held whole)). ``chosen``: a list that
+    is given the experts each token keeps, [B, S, k] (``forward``'s
+    ``aux_moe_choice``)."""
     b, s, c = x.shape
     dt = cfg.dtype
     first, held = cfg.held
@@ -580,6 +625,8 @@ def moe(layer, cfg, x):
             else:
                 weights, experts = routed.route(
                     logits, cfg.experts_per_token, cfg.norm_topk_prob)
+        if chosen is not None:
+            chosen.append(experts.reshape(b, s, -1))
         gate, counts = routed.dispatch(weights, experts, held, first)
         out = routed.expert_ffn(n32.astype(dt), gate, layer["w_gate_up"],
                                 layer["w_down"])
@@ -591,16 +638,22 @@ def moe(layer, cfg, x):
     return x, counts, absent
 
 
-def dense_ffn(layer, cfg, x):
-    """A leading dense layer's feed-forward: the gated form at
-    ``dense_width``, every token (region ``mlp``)."""
+def dense_mix(layer, cfg, h):
+    """What a leading dense layer's feed-forward ADDS to the stream, from
+    the normed stream ``h`` [.., C] in ``cfg.dtype``: the gated form at
+    ``dense_width``, every token."""
     f = cfg.dense_width
+    gu = h @ layer["w_gate_up"].astype(cfg.dtype)
+    return (jax.nn.silu(gu[..., :f]) * gu[..., f:]) \
+        @ layer["w_down"].astype(cfg.dtype)
+
+
+def dense_ffn(layer, cfg, x):
+    """A leading dense layer's feed-forward: ``x`` with ``dense_mix`` of
+    its norm added (region ``mlp``)."""
     with jax.named_scope("mlp"):
         h = _rms32(x, layer["ffn_norm"], cfg.rms_norm_eps).astype(cfg.dtype)
-        gu = h @ layer["w_gate_up"].astype(cfg.dtype)
-        out = (jax.nn.silu(gu[..., :f]) * gu[..., f:]) \
-            @ layer["w_down"].astype(cfg.dtype)
-        return _residual(cfg, x, out)
+        return _residual(cfg, x, dense_mix(layer, cfg, h))
 
 
 @hot_path
@@ -612,11 +665,15 @@ def forward(params, cfg, ids, cache, attn_name=None):
     added: every row the program computes counts, a pad column or an idle
     slot too, so the gauges read the program's load.
 
-    A model with Mamba or KDA layers reads and returns the rows' recurrent
+    A model with ``RECURRENT`` layers reads and returns the rows' recurrent
     state (``slot_ssm<j>`` / ``slot_conv<j>``, ``slot_kda<j>`` /
-    ``slot_kdaconv<j>``) and ``cache['n_valid']`` [B]: how many leading
+    ``slot_kdaconv<j>``, ``slot_shortconv<j>``: what the kind's
+    ``state_keys`` names) and ``cache['n_valid']`` [B]: how many leading
     columns of each row are real, 0 for a row that must not move (default:
-    all ``S``). The key is consumed here."""
+    all ``S``). The key is consumed here. A cache that carries
+    ``aux_moe_choice`` (no pool does: a replay that asks which experts the
+    program keeps) gets it back as this call's choices, [expert layers, B,
+    S, k]."""
     s = ids.shape[1]
     dt = cfg.dtype
     cache = dict(cache)
@@ -634,6 +691,7 @@ def forward(params, cfg, ids, cache, attn_name=None):
         n_valid = jnp.full(ids.shape[:1], s, jnp.int32)
     load = jnp.zeros((cfg.held[1],), jnp.float32)
     absent = jnp.zeros((), jnp.float32)
+    chosen = [] if "aux_moe_choice" in cache else None
     n_attn = 0
     n_recurrent = {kind: 0 for kind in RECURRENT}
     for i, kind in enumerate(cfg.kinds):
@@ -643,10 +701,11 @@ def forward(params, cfg, ids, cache, attn_name=None):
             mix = jax.tree_util.tree_map(lambda a: a[j], params[kind])
             with jax.named_scope(kind):
                 h = _rms32(x, layer["attn_norm"], cfg.rms_norm_eps).astype(dt)
-                mat, conv = RECURRENT[kind].state_keys(j)
-                h, state[mat], state[conv] = RECURRENT[kind].mixer(
-                    mix, cfg, h, cache[mat], cache[conv], attend.pos,
+                keys = RECURRENT[kind].state_keys(j)
+                h, *after = RECURRENT[kind].mixer(
+                    mix, cfg, h, *(cache[k] for k in keys), attend.pos,
                     n_valid)
+                state.update(zip(keys, after))
                 x = _residual(cfg, x, h)
             n_recurrent[kind] += 1
         else:
@@ -664,7 +723,7 @@ def forward(params, cfg, ids, cache, attn_name=None):
         if cfg.dense_layers:
             layer = dict(layer, **jax.tree_util.tree_map(
                 lambda a: a[i - cfg.dense_layers], params["moe"]))
-        x, counts, away = moe(layer, cfg, x)
+        x, counts, away = moe(layer, cfg, x, chosen)
         load, absent = load + counts, absent + away
     with jax.named_scope("lm_head"):
         x = _rms32(x, params["final_norm"], cfg.rms_norm_eps).astype(dt)
@@ -681,6 +740,8 @@ def forward(params, cfg, ids, cache, attn_name=None):
         cache["aux_moe_routed"] = cache["aux_moe_routed"] + jnp.sum(load)
     if "aux_moe_absent" in cache:
         cache["aux_moe_absent"] = cache["aux_moe_absent"] + absent
+    if chosen is not None:
+        cache["aux_moe_choice"] = jnp.stack(chosen)
     return logits, cache
 
 

@@ -138,6 +138,17 @@ def unpack_heads(x, g, h):
     return x.reshape(lead + (hp * g, t, gd // g))[..., :h, :, :]
 
 
+def query_group(arena, heads, head_dim):
+    """``rep``, the query heads that share one STORED head, read back from a
+    k/v arena's shape ``[.., Hkv / g, page_len, g * D]`` for a model of
+    ``heads`` query heads of ``head_dim``: 1 where every query head stores a
+    key of its own, 4 for 32 heads over 8 stored, whether they lie one a
+    lane tile (``g`` 1, a head of 128) or two (``g`` 2, a head of 64). What
+    the launchers put beside ``S`` on the sublane axis, and what the engine
+    reports as ``kv_query_group``."""
+    return max(1, heads // (arena.shape[-3] * (arena.shape[-1] // head_dim)))
+
+
 def _pack_query(q, g):
     """``[B, H, S, D]`` queries against a packed arena: block-diagonal
     ``[B, ceil(H / g), g * S, g * D]``, head ``a`` of a group in rows
@@ -818,7 +829,7 @@ def decode_attention_paged_reference(q, k, v, block_tbl, pos, scale=None):
     page_len, D] is g = 1); block_tbl: [B, n_lp] int32; pos: [B] int32
     frontiers."""
     h, g = q.shape[1], k.shape[-1] // q.shape[-1]
-    rep = max(1, h // (k.shape[-3] * g))       # grouped-query heads
+    rep = query_group(k, h, q.shape[-1])
     k, v = (gather_pages(a, block_tbl, h // rep, g) for a in (k, v))
     if rep > 1:
         k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
@@ -970,7 +981,7 @@ def unit_pages(arenas, heads, head_dim, n_lp, q_dtype, s_len=1, latent=0):
         return _paged_unit(heads // hg, hg * s_len, w, arenas, n_lp, q_dtype,
                            rep=hg, latent=latent)[1]
     g = arenas[0].shape[-1] // head_dim
-    rep = max(1, heads // (arenas[0].shape[2] * g))
+    rep = query_group(arenas[0], heads, head_dim)
     return _paged_unit(-(-(heads // rep) // g), g * rep * s_len,
                        g * head_dim, arenas, n_lp, q_dtype, g, rep)[1]
 
@@ -1282,7 +1293,7 @@ def _paged_on_shards(launch, q, arenas, block_tbl, pos, scale, name, layer):
     brought in once for all of them."""
     b, h, s_len, d = q.shape
     g = arenas[0].shape[-1] // d
-    rep = max(1, h // (arenas[0].shape[-3] * g))
+    rep = query_group(arenas[0], h, d)
     if rep > 1:
         q = q.reshape(b, h // rep, rep * s_len, d)
     q = _pack_query(q, g)

@@ -212,6 +212,35 @@ def _granite_append(k, v, ka, va, tbl, pos):
     return da.kv_append((ka, va), (k, v), tbl, pos, layer=0)
 
 
+# LFM2-8B-A1B's three attention layers of twelve (serve-lfm2moe-decode-
+# closed): 8 stored heads of 64 under 32 query heads, so the arena packs
+# g = 2 stored heads a lane tile (4 tiles a page) while rep = 4 query heads
+# share each; 128 slots x 24 pages + trash. The first g = 2 x rep = 4.
+L_SLOTS, L_HEADS, L_KV, L_HD = 128, 32, 8, 64
+L_ARENA = ((3, L_SLOTS * 24 + 1, L_KV // 2, PAGE, 2 * L_HD), BF16)
+
+
+def _lfm2_decode_args(rows, slots):
+    return [((slots, L_HEADS, rows, L_HD), BF16), L_ARENA, L_ARENA,
+            ((slots, 24), I32), ((slots,), I32)]
+
+
+def _lfm2_decode(name=None):
+    def run(q, k, v, tbl, pos):
+        return da.flash_decode_attention_paged(q, k, v, tbl, pos, name=name,
+                                               layer=2)
+    return run
+
+
+def _lfm2_append_args(rows, slots):
+    new = ((slots, L_KV, rows, L_HD), BF16)
+    return [new, new, L_ARENA, L_ARENA, ((slots, 24), I32), ((slots,), I32)]
+
+
+def _lfm2_append(k, v, ka, va, tbl, pos):
+    return da.kv_append((ka, va), (k, v), tbl, pos, layer=2)
+
+
 def _kda_update_args(rows):
     """Kimi Linear's 32 heads of 128: q, k, v, g [B, H, d], beta [B, H], the
     float32 state [B, H, d_k, d_v], the frontier-0 flags [B]."""
@@ -333,6 +362,16 @@ CASES = {
         _latent_append, _latent_append_args(1, D_SLOTS), {}),
     "granite_kv_append_64_rows_gqa_d128": (
         _granite_append, _granite_append_args(1, G_SLOTS), {}),
+    # Grouped-query rows over lane-packed heads, g = 2 x rep = 4 (LFM2's 32
+    # over 8 of 64): the scan's call, the lane's, and both appends.
+    "lfm2_paged_decode_128_slots_g2_rep4": (
+        _lfm2_decode(), _lfm2_decode_args(1, L_SLOTS), {}),
+    "lfm2_prefill_attn_lane_128_rows_g2_rep4": (
+        _lfm2_decode("prefill_attn"), _lfm2_decode_args(128, 1), {}),
+    "lfm2_kv_append_128_slots_g2": (
+        _lfm2_append, _lfm2_append_args(1, L_SLOTS), {}),
+    "lfm2_kv_append_lane_128_rows_g2": (
+        _lfm2_append, _lfm2_append_args(128, 1), {}),
     # The one-token KDA update at the Kimi cell's pool (all 32 heads of a
     # row one unit: 4 x 2 MiB of blocks) and at the batch of 1 the
     # benchmark's state probe calls it with.
@@ -898,3 +937,62 @@ def test_kimi_mixed_step_holds_three_caches_each_where_it_lies(
     assert readers == [], readers
     outside = _arena_shaped(whole(set(comps) - set(scan)), [state])
     assert {op for _, op in outside} <= {"fusion"}, outside
+
+
+def test_lfm2_mixed_step_attends_packed_grouped_query_keys_in_place(
+        chip, monkeypatch):
+    """LFM2-8B-A1B's block at its published widths and the cell's 12 layers
+    (9 gated short convolutions, 3 attention; 1 dense + 11 with all 32
+    experts held; the cell's pool: 128 slots of 2944, page 128, chunk 16,
+    lane 128; half a minute to compile): the keys and values are two arenas
+    ``[3, 3073, 4, 128, 128]``, as deep as the attention layers only, 8
+    stored heads of 64 packed two a lane tile, that meet ``kv_append`` and
+    ``paged_decode`` (32 query heads, four a stored head) once an attention
+    layer in the decode scan and are formed whole nowhere; the lane's calls
+    are ``prefill_attn``; each conv layer's tail is its own
+    ``[128, 2, 2048]``; and the step's scratch is a small part of what the
+    chip has left beside 8.47 GB of weights and 2.4 GB of pool."""
+    from deepspeed_tpu.inference import kv_pool
+    from deepspeed_tpu.inference.adapters import DecoderAdapter
+    from deepspeed_tpu.inference.config import InferenceConfig
+    from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
+
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    slots, chunk, lane = 128, 16, 128
+    kinds = ("shortconv", "shortconv", "attention", "shortconv") * 3
+    model = DecoderLM(DecoderConfig(
+        vocab_size=65536, n_layer=len(kinds), n_head=L_HEADS, head_dim=L_HD,
+        hidden_size=2048, n_positions=128000, n_experts=32,
+        experts_per_token=4, expert_width=1792, rope_theta=1e6,
+        qk_norm="head", norm_topk_prob=True, tie_word_embeddings=True,
+        dtype=BF16, n_kv_head=L_KV, layer_types=kinds, dense_layers=1,
+        dense_width=7168, router_scoring="sigmoid"))
+    config = InferenceConfig.from_dict(dict(
+        max_slots=slots, max_len=2944, chunk_size=chunk, paged_kv=True,
+        kv_page_len=PAGE, prefill_chunk=lane, use_flash_decode=True))
+    adapter = DecoderAdapter.from_model(model, use_flash_decode=True).bind(
+        config, None)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0))["params"])
+    pool = jax.eval_shape(lambda: dict(kv_pool.init_pool(
+        adapter.cache_spec(), slots, 2944, slack=lane, page_len=PAGE),
+        **adapter.aux_state()))
+    assert pool["k"].shape == pool["v"].shape == L_ARENA[0]
+    assert all(pool["slot_shortconv{}".format(j)].shape == (slots, 2, 2048)
+               for j in range(9))
+    held = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves((params, pool)))
+    assert 10.8e9 < held < 11.0e9
+
+    text = _mixed_step_text(chip, adapter, params, pool, chunk, lane)
+    comps = _computations(text)
+    scan, in_scan = _scan_lines(comps)
+    names = sorted(c.split(".")[0] for c in _kernel_calls(
+        "\n".join(in_scan)))
+    assert names == ["kv_append"] * 3 + ["paged_decode"] * 3
+    everywhere = sorted(c.split(".")[0] for c in _kernel_calls(text))
+    assert everywhere == ["kv_append"] * 6 + ["paged_decode"] * 3 \
+        + ["prefill_attn"] * 3
+    arena = ["[3,3073,4,128,128]", "[3073,4,128,128]"]
+    assert _arena_shaped([line for lines in comps.values()
+                          for line in lines], arena) == []
