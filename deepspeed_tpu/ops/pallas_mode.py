@@ -6,6 +6,8 @@ compiler refuses raises — nothing gives way to interpret mode or to a
 mesh) the same kernel bodies run through the Pallas interpreter.
 """
 
+import functools
+
 import jax
 
 
@@ -30,3 +32,34 @@ def kernel_call(name, kernel, **kwargs):
         with jax.named_scope(name):
             return call(*args)
     return launch
+
+
+def shared_launch(*static_argnames):
+    """Decorator for a kernel's launcher: call sites with the same operand
+    shapes and the same static arguments share ONE trace and ONE lowering.
+
+    A model's layers are unrolled, and JAX traces a Pallas kernel's body
+    anew at every ``pallas_call`` site (no cache in 0.9.0), forward and
+    backward, on every run before the compile cache is asked: for a kernel
+    whose body is long that is seconds of a training cell's set-up. The
+    launcher becomes a ``jax.jit`` whose key holds the static arguments and
+    how ``kernel_call`` will launch (``interpret()``, decided here and
+    nowhere else). Everything else the trace depends on must be an operand
+    or a static argument: the launcher reads no environment variable and no
+    module global that may change."""
+    def decorate(fn):
+        def keyed(*args, _launch_mode, **kwargs):
+            del _launch_mode
+            return fn(*args, **kwargs)
+
+        # Its name, not ``wraps``: jit checks the static names against the
+        # signature, which ``__wrapped__`` would hand it the launcher's.
+        keyed.__name__ = keyed.__qualname__ = fn.__name__
+        jitted = jax.jit(keyed,
+                         static_argnames=static_argnames + ("_launch_mode",))
+
+        @functools.wraps(fn)
+        def launch(*args, **kwargs):
+            return jitted(*args, _launch_mode=interpret(), **kwargs)
+        return launch
+    return decorate

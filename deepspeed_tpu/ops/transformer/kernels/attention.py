@@ -9,31 +9,43 @@ different: one flash-style Pallas kernel keeps each score block in VMEM and
 never writes the [T, T] matrix to HBM — O(T) memory instead of O(T^2), and
 both GEMMs land on the MXU from the same kernel.
 
-Kernel structure (the part that makes it fast). The kernel is VPU-bound,
-not MXU-bound — at d=64 the score matrix has 16x more elements than the
-q/o blocks, so every elementwise pass over [bq, bk] fp32 scores costs more
-than the matmuls. The design therefore minimises score-matrix passes:
+Kernel structure (the part that makes it fast). At head dim 64 every
+product of the kernels has a contraction or an output 64 wide, half of the
+128-deep MXU, and at that pace the products ARE the time: two over the
+whole 1024 x 1024 square of a GPT-2 head are 2.7 us and the forward took
+3.0, five are 6.8 and the fused backward took 8.4 (v5e, PR 45: not
+VPU-bound, as this text said until then). So the design (a) never forms a
+score the causal mask empties and (b) keeps the elementwise passes over
+the scores, 16x the elements of the q/o blocks, few enough to hide:
+- the GRID block (``block_q`` x ``block_k``) is large, the whole sequence
+  at T 1024: a grid step costs 0.3-0.5 us, a tenth of a head's work.
+  Where the grid has several blocks a side, fully-masked blocks are
+  skipped by @pl.when and their index map clamps to the last useful block
+  (no new DMA for a repeated index); at the default tile there is ONE
+  block a head and nothing for the grid to skip;
+- INSIDE a block that holds the diagonal the kernels take strips of S
+  rows, each over the keys up to its own diagonal tile and no further
+  (``flash_subtile``; "Strips inside a diagonal grid block" below): 10 of
+  the 16 tiles of 256 x 256, 36 of the 64 of 128 x 128;
 - q is PRE-SCALED by 1/sqrt(d) outside the kernel ([T, d] pass instead of
   a [T, T] pass in every kernel);
-- the causal mask is a CONSTANT additive tril block passed as an input and
-  applied only to diagonal (straddling) blocks — fully-active blocks skip
-  masking entirely, fully-masked blocks are skipped by @pl.when and their
-  index map clamps to the last useful block (no new DMA for a repeated
-  index). Per-block iota/compare/select ladders only remain for the
-  uncommon block_q != block_k causal shapes;
+- the causal mask is a CONSTANT additive tril tile passed as an input and
+  added only to the tile that straddles the diagonal. Per-block
+  iota/compare/select ladders only remain for the uncommon
+  block_q != block_k causal shapes;
 - when the kv extent is a single block, the online-softmax machinery
   (running max/sum scratch, accumulator rescale) collapses to one direct
-  softmax with no scratch at all;
+  softmax a strip with no scratch at all;
 - the softmax ROW-SUM rides the PV matmul: p @ [v | 1] returns the context
   block and the row-sum from one MXU op, deleting a VPU reduce over
-  [bq, bk] (forward);
+  the scores (forward);
 - in the backward, the delta subtraction rides the dp matmul the same way:
   [dO | -delta] @ [V | 1]^T produces dp - delta directly (fp32 MXU
-  accumulation), deleting another [bq, bk] VPU pass;
-- in low-precision models the [bq, bk] exp runs in the model dtype (half
-  the vector elements per VPU op) and dp - delta is emitted in the model
-  dtype, so ds = p * dpd is a pure low-precision multiply; fp32 models
-  keep fully-fp32 intermediates (parity tests pin this);
+  accumulation), deleting another VPU pass;
+- in low-precision models the exp runs in the model dtype and dp - delta
+  is emitted in the model dtype, so ds = p * dpd is a pure low-precision
+  multiply; fp32 models keep fully-fp32 intermediates (parity tests pin
+  this);
 - matmul inputs stay in the model dtype (bf16) with fp32 MXU accumulation
   (preferred_element_type); softmax statistics and accumulators live in
   fp32 VMEM scratch across grid steps;
@@ -42,9 +54,11 @@ than the matmuls. The design therefore minimises score-matrix passes:
   [T, T] ds matrix.
 
 Forward: online-softmax accumulation over key/value blocks.
-Backward: standard two-pass flash backward (one kernel produces dq looping
-over kv blocks; one produces dk/dv looping over q blocks), using the saved
-per-row logsumexp; wired up with jax.custom_vjp.
+Backward: one fused pass (dq, dk, dv from one sweep, k/v resident in VMEM)
+where the resident set fits, else the standard two-pass flash backward (one
+kernel produces dq looping over kv blocks; one produces dk/dv looping over
+q blocks); both use the saved per-row logsumexp; wired up with
+jax.custom_vjp.
 
 Off-TPU the kernels run in Pallas interpret mode, so the CPU test mesh
 exercises the same code path (tests mirror reference
@@ -196,10 +210,13 @@ def _pv_rowsum(p, v_blk):
     return pv_ext[:, :d], pv_ext[:, d:d + 1]
 
 
-def _dp_minus_delta(do, v_blk, delta):
-    """[dO | -delta] @ [V | 1]^T on the MXU: the delta subtraction rides
-    the dp matmul (fp32 accumulation inside the MXU) instead of costing a
-    VPU pass over [bq, bk]. Low-precision models split the fp32 delta into
+def _dp_minus_delta_of(do, delta, dtype):
+    """``v_blk -> dp - delta`` for one block of query rows:
+    [dO | -delta] @ [V | 1]^T on the MXU, so that the delta subtraction
+    rides the dp matmul (fp32 accumulation inside the MXU) instead of
+    costing a VPU pass over the scores. The left operand is built HERE,
+    once for the rows; the returned function is called a run of keys.
+    Low-precision models split the fp32 delta into
     hi+lo model-dtype COLUMNS (~16 mantissa bits through the MXU): rows
     with concentrated attention have dp ~ delta and p ~ 1, so a single
     bf16 delta column's 2^-8 rounding would surface at full scale in
@@ -213,32 +230,38 @@ def _dp_minus_delta(do, v_blk, delta):
     when every dO element fits, and an inf hi column would turn the MXU
     accumulation into NaN — so fp16 keeps the classic fp32 subtract. fp32
     models ride an exact fp32 delta column (exact parity)."""
-    dtype = v_blk.dtype
+    dims = (((1,), (1,)), ((), ()))
+
+    def ones(v_blk, n):
+        return jnp.concatenate(
+            [v_blk, jnp.ones((v_blk.shape[0], n), dtype)], axis=1)
+
     if jnp.dtype(dtype) == jnp.bfloat16:
         d_hi = delta.astype(dtype)
         d_lo = (delta - d_hi.astype(jnp.float32)).astype(dtype)
         do_ext = jnp.concatenate([do.astype(dtype), -d_hi, -d_lo], axis=1)
-        ones = jnp.ones((v_blk.shape[0], 2), dtype)
-        v_ext = jnp.concatenate([v_blk, ones], axis=1)
         # Mosaic requires the MXU accumulator to be 32-bit (a bf16
         # preferred_element_type fails verification), so accumulate in
         # fp32 and cast on emit — same rounding contract: the cast error
         # is relative to the small difference, not to delta.
-        out = jax.lax.dot_general(do_ext, v_ext, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        return out.astype(dtype)
+        return lambda v_blk: jax.lax.dot_general(
+            do_ext, ones(v_blk, 2), dims,
+            preferred_element_type=jnp.float32).astype(dtype)
     if _is_lowp(dtype):  # fp16: unfused fp32 subtract (overflow-safe)
-        dp = jax.lax.dot_general(do.astype(dtype), v_blk,
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        return dp - delta
+        do = do.astype(dtype)
+        return lambda v_blk: jax.lax.dot_general(
+            do, v_blk, dims, preferred_element_type=jnp.float32) - delta
     do_ext = jnp.concatenate(
         [do.astype(dtype), (-delta).astype(dtype)], axis=1)
-    v_ext = jnp.concatenate(
-        [v_blk, jnp.ones((v_blk.shape[0], 1), dtype)], axis=1)
-    return jax.lax.dot_general(do_ext, v_ext, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32,
-                               precision=_mxu_precision(dtype))
+    return lambda v_blk: jax.lax.dot_general(
+        do_ext, ones(v_blk, 1), dims, preferred_element_type=jnp.float32,
+        precision=_mxu_precision(dtype))
+
+
+def _dp_minus_delta(do, v_blk, delta):
+    """dp - delta for one (query block, key block) pair: see
+    ``_dp_minus_delta_of``."""
+    return _dp_minus_delta_of(do, delta, v_blk.dtype)(v_blk)
 
 
 def _apply_causal(s, iq, j, block_q, block_k, tril_ref):
@@ -246,6 +269,8 @@ def _apply_causal(s, iq, j, block_q, block_k, tril_ref):
     diagonal block straddles the boundary, so the constant tril input is
     added under @pl.when; otherwise fall back to the iota ladder."""
     if tril_ref is not None:
+        if isinstance(iq, int) and isinstance(j, int):  # one block each way
+            return s + tril_ref[...] if iq == j else s
         return jax.lax.cond(iq == j, lambda: s + tril_ref[...], lambda: s)
     q_pos = iq * block_q + jax.lax.broadcasted_iota(
         jnp.int32, s.shape, 0)
@@ -255,11 +280,118 @@ def _apply_causal(s, iq, j, block_q, block_k, tril_ref):
 
 
 # ---------------------------------------------------------------------------
+# Strips inside a diagonal grid block
+#
+# A grid block is what ``resolve_block_sizes`` gives: the whole 1024 x 1024
+# of a GPT-2 head, ONE grid step (a grid step costs a tenth of a head's
+# work at T 1024). The block that holds the causal diagonal is taken in
+# STRIPS of S rows (``flash_subtile``): strip r attends the block's keys
+# ``[0, (r + 1) S)`` and no further, with one direct softmax over that
+# width, the constant ``tril`` added to its last S columns alone. Of the
+# block's n x n sub-tiles of side S, n (n + 1) / 2 are computed; the ones
+# above the diagonal are never formed. The strips are straight-line code,
+# NOT a loop over tiles: a loop iteration is a chain (product, row max,
+# exp, product) that cannot overlap the next, and on v5e it cost 0.3 us
+# whatever the tile held, a tenth of a head's whole forward (CHANGES.md,
+# PR 45: the walk as ``fori_loop``s ran 5.7 / 11.8 us a head, forward /
+# backward, at S 256 where the whole square takes 3.0 / 8.6). And the
+# strips' three stages (the products, the pointwise pass, the products
+# that consume it) are WRITTEN SKEWED, strip r's first products beside
+# strip r - 1's pointwise pass and strip r - 2's last products:
+# neighbours in program order that need different units and nothing of
+# each other, which is as far as the scheduler looks (strip after strip in
+# a line ran 3.07 / 5.47 us at S 128; skewed 2.23 / 4.70).
+# The forward and the fused backward share the rule; the split backward
+# kernels keep the grid's own blocks.
+# ---------------------------------------------------------------------------
+
+# The rows of a strip. Timed on v5e at the training cells' shapes, the
+# kernels alone (``tests/perf/attention_bench.py --subtile``; CHANGES.md,
+# PR 45): a head's forward / fused backward 2.23 / 4.70 us at 128 (36 of
+# the 64 tiles), 2.24 / 5.25 at 256 (10 of 16), 2.45 / 6.36 at 512 (3 of
+# 4), 3.04 / 8.6 with the block its own tile.
+_SUBTILE_SIDE = 128
+
+# What the rule resolved for the last call traced: gauges ``flash_subtile``
+# and ``flash_tiles_visited_share`` (runtime/engine.py).
+_last_walk = {"subtile": 0, "tiles_visited_share": 0.0}
+
+
+def flash_subtile(block_q, block_k, causal):
+    """S, the rows of a strip (the side of the sub-tiles) a diagonal grid
+    block is taken in; 0 where the block is its own tile (no causal mask:
+    nothing to skip; a block that is not square, that S does not divide, or
+    that is no larger than S). From the shapes alone: no switch, no table."""
+    s = _SUBTILE_SIDE
+    return s if causal and block_q == block_k and block_q % s == 0 \
+        and block_q > s else 0
+
+
+def tiles_visited(t_q, t_kv, sub_q, sub_k, causal):
+    """(visited, all) tiles of ``sub_q`` x ``sub_k`` in a call's
+    [t_q, t_kv] scores."""
+    n_r, n_c = t_q // sub_q, t_kv // sub_k
+    if not causal:
+        return n_r * n_c, n_r * n_c
+    return sum(min(_last_kv_block(r, sub_q, sub_k) + 1, n_c)
+               for r in range(n_r)), n_r * n_c
+
+
+def last_walk():
+    """{"subtile": S, "tiles_visited_share": visited / all} as the
+    launcher resolved them for the last flash call traced."""
+    return dict(_last_walk)
+
+
+def _ds(i, size, width=None):
+    """``[i * size, i * size + width)`` of a ref (``width`` = ``size``
+    where not given)."""
+    start = i * size if isinstance(i, int) else \
+        pl.multiple_of(i * size, size)
+    return pl.ds(start, width or size)
+
+
+def _loop(n, body, init):
+    """``fori_loop(0, n, body, init)``; a trip count that is the Python
+    number 1 is no loop."""
+    if isinstance(n, int) and n == 1:
+        return body(0, init)
+    return jax.lax.fori_loop(0, n, body, init)
+
+
+def _skewed(n, *stages):
+    """``stages[0](t)``, ``stages[1](t)``, ... for t in ``range(n)``, each t
+    in stage order, written so that item t's first stage stands beside item
+    t - 1's second and item t - 2's third (see above)."""
+    for t in range(n + len(stages) - 1):
+        for lag, stage in enumerate(stages):
+            if 0 <= t - lag < n:
+                stage(t - lag)
+
+
+def _mask_scores(s, kind, tril_ref, iq, j, block_q, block_k):
+    """What the causal mask does to the scores ``s`` of grid block
+    (iq, j), or of a strip of it. ``kind``: None (every score is live);
+    "tril" (a strip that ENDS on the diagonal: the constant is added to its
+    last columns); "block" (a whole block, wherever it lies: the constant
+    where it is the diagonal one, the ladder where blocks are not square)."""
+    if kind == "block":
+        return _apply_causal(s, iq, j, block_q, block_k, tril_ref)
+    if kind == "tril":
+        side = tril_ref.shape[0]
+        if s.shape[1] == side:
+            return s + tril_ref[...]
+        return jnp.concatenate(
+            [s[:, :-side], s[:, -side:] + tril_ref[...]], axis=1)
+    return s
+
+
+# ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(*refs, causal, block_q, block_k, has_mask, has_tril,
-                single_kv):
+def _fwd_kernel(*refs, causal, block_q, block_k, sub, has_mask, has_tril,
+                single_q, single_kv):
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     idx = 3
@@ -273,30 +405,81 @@ def _fwd_kernel(*refs, causal, block_q, block_k, has_mask, has_tril,
     o_ref, lse_ref = refs[idx:idx + 2]
     scratch = refs[idx + 2:]
 
-    iq = pl.program_id(2)
-    j = pl.program_id(3)
+    iq = 0 if single_q else pl.program_id(2)
+    j = 0 if single_kv else pl.program_id(3)
     n_kv = pl.num_programs(3)
+    prec = _mxu_precision(q_ref.dtype)
 
-    def scores():
-        s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
+    # Query rows ``rows`` over keys ``keys`` of the block, in three stages
+    # that hand each other values. One kv block: a direct softmax, no
+    # scratch, no rescale passes. Else the online-softmax update of the
+    # rows' statistics.
+    def scores(rows, keys, kind):
+        s = jax.lax.dot_general(q_ref[0, 0, rows], k_ref[0, 0, keys],
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32,
-                                precision=_mxu_precision(q_ref.dtype))
+                                precision=prec)
         if mask_ref is not None:
-            s = s + mask_ref[0][None, :]
-        if causal:
-            s = _apply_causal(s, iq, j, block_q, block_k, tril_ref)
-        return s
+            s = s + mask_ref[:, keys]
+        return _mask_scores(s, kind, tril_ref, iq, j, block_q, block_k)
+
+    def softmax(rows, s):
+        m = jnp.max(s, axis=-1, keepdims=True)
+        if not single_kv:
+            m = jnp.maximum(scratch[1][rows, 0:1], m)          # [rows, 1]
+        return m, _exp_lowp(s - m, o_ref.dtype)                # [rows, keys]
+
+    def output(rows, keys, m, p):
+        pv, l = _pv_rowsum(p, v_ref[0, 0, keys])
+        if single_kv:
+            l = jnp.maximum(l, 1e-30)
+            o_ref[0, 0, rows] = (pv / l).astype(o_ref.dtype)
+            lse_ref[0, 0, rows] = m + jnp.log(l)
+            return
+        acc, m_s, l_s = scratch
+        alpha = jnp.exp(m_s[rows, 0:1] - m)
+        l_s[rows] = jnp.broadcast_to(alpha * l_s[rows, 0:1] + l,
+                                     (rows.size, _STATS_LANES))
+        m_s[rows] = jnp.broadcast_to(m, (rows.size, _STATS_LANES))
+        acc[rows] = acc[rows] * alpha + pv
+
+    def attend(rows, keys, kind):
+        output(rows, keys, *softmax(rows, scores(rows, keys, kind)))
+
+    whole = pl.ds(0, block_q), pl.ds(0, block_k)
+
+    def strips():
+        n = block_q // sub
+        span = [(pl.ds(r * sub, sub), pl.ds(0, (r + 1) * sub))
+                for r in range(n)]
+        held = {}
+
+        def first(r):
+            held[r] = scores(*span[r], "tril")
+
+        def second(r):
+            held[r] = softmax(span[r][0], held[r])
+
+        def third(r):
+            output(*span[r], *held.pop(r))
+
+        _skewed(n, first, second, third)
+
+    if not causal:
+        def compute():
+            attend(*whole, None)
+    elif not sub:
+        def compute():
+            attend(*whole, "block")
+    elif single_q and single_kv:
+        compute = strips
+    else:
+        def compute():
+            pl.when(iq == j)(strips)
+            pl.when(j < iq)(lambda: attend(*whole, None))
 
     if single_kv:
-        # One kv block: direct softmax, no scratch, no rescale passes.
-        s = scores()
-        m = jnp.max(s, axis=-1, keepdims=True)
-        p = _exp_lowp(s - m, o_ref.dtype)
-        pv, l = _pv_rowsum(p, v_ref[0, 0])
-        l = jnp.maximum(l, 1e-30)
-        o_ref[0, 0] = (pv / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m + jnp.log(l)
+        compute()
         return
 
     acc, m_s, l_s = scratch
@@ -311,21 +494,7 @@ def _fwd_kernel(*refs, causal, block_q, block_k, has_mask, has_tril,
         active = j <= _last_kv_block(iq, block_q, block_k)
     else:
         active = j < n_kv
-
-    @pl.when(active)
-    def _compute():
-        s = scores()
-        m_prev = m_s[:, 0:1]                               # [bq, 1]
-        l_prev = l_s[:, 0:1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = _exp_lowp(s - m_new, o_ref.dtype)              # [bq, bk]
-        pv, l_cur = _pv_rowsum(p, v_ref[0, 0])
-        l_new = alpha * l_prev + l_cur
-        m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
-        l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
-        acc[...] = acc[...] * alpha + pv
+    pl.when(active)(compute)
 
     @pl.when(j == n_kv - 1)
     def _finalize():
@@ -335,14 +504,33 @@ def _fwd_kernel(*refs, causal, block_q, block_k, has_mask, has_tril,
 
 
 def _flash_fwd_pallas(q, k, v, mask, scale, causal, block_q, block_k):
+    t_q, t_kv = q.shape[2], k.shape[2]
+    block_q = min(block_q, t_q)
+    block_k = min(block_k, t_kv)
+    sub = flash_subtile(block_q, block_k, causal)
+    visited, tiles = tiles_visited(t_q, t_kv, sub or block_q, sub or block_k,
+                                   causal)
+    _last_walk.update(subtile=sub, tiles_visited_share=visited / tiles)
+    return _flash_fwd_launch(q, k, v, mask, scale=scale, causal=causal,
+                             block_q=block_q, block_k=block_k, sub=sub)
+
+
+# The launches are ``pallas_mode.shared_launch``es, so that a model's layers
+# share ONE trace and ONE lowering of a kernel: a GPT-2's 24 (48) layers are
+# unrolled, and the kernel's body (a strip is two dozen operations, a block
+# several strips) was traced anew at every call site, forward and backward,
+# on every run: seconds of a training cell's set-up. Everything the trace
+# depends on beside the operands is a static argument; nothing inside reads
+# the environment or a module global.
+@pallas_mode.shared_launch("scale", "causal", "block_q", "block_k", "sub")
+def _flash_fwd_launch(q, k, v, mask, *, scale, causal, block_q, block_k, sub):
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, t_q, d = q.shape
     t_kv = k.shape[2]
-    block_q = min(block_q, t_q)
-    block_k = min(block_k, t_kv)
+    n_q = pl.cdiv(t_q, block_q)
     n_kv = pl.cdiv(t_kv, block_k)
-    grid = (b, h, pl.cdiv(t_q, block_q), n_kv)
+    grid = (b, h, n_q, n_kv)
     # Pre-scale q: one [T, d] pass replaces a [T, T] pass per kernel.
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     use_tril = causal and block_q == block_k
@@ -368,16 +556,17 @@ def _flash_fwd_pallas(q, k, v, mask, scale, causal, block_q, block_k):
             block_k, lambda b_, h_, i, j: kv_index(b_, h_, i, j)[2]))
         args.append(_mask_operand(mask))
     if use_tril:
+        side = sub or block_q
         in_specs.append(
-            pl.BlockSpec((block_q, block_k), lambda b_, h_, i, j: (0, 0)))
-        args.append(_tril_block(block_q, block_k))
+            pl.BlockSpec((side, side), lambda b_, h_, i, j: (0, 0)))
+        args.append(_tril_block(side, side))
 
     o, lse = pallas_mode.kernel_call(
         "flash_fwd",
         functools.partial(_fwd_kernel, causal=causal,
-                          block_q=block_q, block_k=block_k,
+                          block_q=block_q, block_k=block_k, sub=sub,
                           has_mask=mask is not None, has_tril=use_tril,
-                          single_kv=single_kv),
+                          single_q=n_q == 1, single_kv=single_kv),
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -559,14 +748,19 @@ def _bwd_dkv_kernel(*refs, causal, block_q, block_k, has_mask, has_tril,
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_fused_kernel(*refs, scale, causal, block_q, block_k, has_mask,
-                      has_tril):
+def _bwd_fused_kernel(*refs, scale, causal, block_q, block_k, sub,
+                      has_mask, has_tril, single_q, diag_always):
     """One-pass backward: dq, dk, dv from a single sweep over (i, j) block
     pairs. The split kernels each recompute s, p and dO.V^T per pair —
     7 score-sized matmuls + 2 exp passes per pair total; this kernel does
     5 matmuls + 1 exp (the MXU-ideal count), with k/v resident in VMEM per
     (b, h) and full-length fp32 dk/dv accumulators in scratch. It also
-    reads k and v from HBM once per (b, h) instead of once per q block."""
+    reads k and v from HBM once per (b, h) instead of once per q block.
+    With ``sub`` the query block is taken in strips of ``sub`` rows (see
+    "Strips inside a diagonal grid block"): a strip meets the key blocks
+    before the diagonal one whole, and of the diagonal one the keys up to
+    its own diagonal tile. What is live at once is a strip's scores, not
+    the block's."""
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     idx = 3
@@ -581,10 +775,9 @@ def _bwd_fused_kernel(*refs, scale, causal, block_q, block_k, has_mask,
     dq_ref, dk_ref, dv_ref = refs[idx + 3:idx + 6]
     dk_acc, dv_acc = refs[idx + 6:idx + 8]
 
-    i = pl.program_id(2)
+    i = 0 if single_q else pl.program_id(2)
     n_q = pl.num_programs(2)
-    t_kv = k_ref.shape[2]
-    n_kv = t_kv // block_k
+    n_kv = k_ref.shape[2] // block_k
     d = q_ref.shape[-1]
     prec = _mxu_precision(q_ref.dtype)
 
@@ -593,44 +786,99 @@ def _bwd_fused_kernel(*refs, scale, causal, block_q, block_k, has_mask,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q_blk = q_ref[0, 0]
-    do_blk = do_ref[0, 0]
-    lse_blk = lse_ref[0, 0]
-    delta_blk = delta_ref[0, 0]
+    def stages(rows):
+        """The work of the block's query rows ``rows`` against a run of
+        keys, as three stages that hand each other values: ``products``
+        (scores and dp - delta, the MXU), ``pointwise`` (p and ds, the
+        VPU) and ``grads`` (dv and dk into the accumulators, dq onto what
+        it is given; the MXU again)."""
+        q_blk = q_ref[0, 0, rows]
+        do_blk = do_ref[0, 0, rows]
+        lse_blk = lse_ref[0, 0, rows]
+        dp_minus_delta = _dp_minus_delta_of(do_blk, delta_ref[0, 0, rows],
+                                            v_ref.dtype)
 
-    def body(j, dq_local):
-        kv = pl.ds(j * block_k, block_k)
-        k_blk = k_ref[0, 0, kv]
-        v_blk = v_ref[0, 0, kv]
-        s = jax.lax.dot_general(q_blk, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32,
-                                precision=prec)
-        if mask_ref is not None:
-            s = s + mask_ref[0, kv][None, :]
-        if causal:
-            s = _apply_causal(s, i, j, block_q, block_k, tril_ref)
-        # s <= lse mathematically; the clamp guards fully-masked rows
-        # (same contract as the split kernels).
-        p = _exp_lowp(jnp.minimum(s - lse_blk, 0.0), dq_ref.dtype)
-        dv_acc[kv] += jax.lax.dot_general(
-            p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=prec)
-        dpd = _dp_minus_delta(do_blk, v_blk, delta_blk)
-        ds = (p * dpd).astype(k_ref.dtype)
-        dk_acc[kv] += jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=prec)
-        return dq_local + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=prec)
+        def products(keys, kind, j):
+            s = jax.lax.dot_general(q_blk, k_ref[0, 0, keys],
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32,
+                                    precision=prec)
+            if mask_ref is not None:
+                s = s + mask_ref[:, keys]
+            return (_mask_scores(s, kind, tril_ref, i, j, block_q, block_k),
+                    dp_minus_delta(v_ref[0, 0, keys]))
 
-    if causal:
+        def pointwise(s, dpd):
+            # s <= lse mathematically; the clamp guards fully-masked rows
+            # (same contract as the split kernels).
+            p = _exp_lowp(jnp.minimum(s - lse_blk, 0.0), dq_ref.dtype)
+            return p.astype(do_blk.dtype), (p * dpd).astype(k_ref.dtype)
+
+        def grads(keys, p, ds, dq):
+            dv_acc[keys] += jax.lax.dot_general(
+                p, do_blk, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec)
+            dk_acc[keys] += jax.lax.dot_general(
+                ds, q_blk, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec)
+            return dq + jax.lax.dot_general(
+                ds, k_ref[0, 0, keys], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec)
+
+        return products, pointwise, grads
+
+    def over(st, n, keys_of, kind, dq):
+        """``dq`` carried through ``n`` key runs ``keys_of(j)``."""
+        products, pointwise, grads = st
+        return _loop(n, lambda j, dq: grads(
+            keys_of(j), *pointwise(*products(keys_of(j), kind, j)), dq), dq)
+
+    def write_dq(rows, dq):
+        dq_ref[0, 0, rows] = (dq * scale).astype(dq_ref.dtype)
+
+    def block_keys(j):
+        return _ds(j, block_k)
+
+    whole = pl.ds(0, block_q)
+    zeros = jnp.zeros((sub or block_q, d), jnp.float32)
+    if not causal:
+        write_dq(whole, over(stages(whole), n_kv, block_keys, None, zeros))
+    elif not sub:
         n_j = jnp.minimum(_last_kv_block(i, block_q, block_k) + 1, n_kv)
+        write_dq(whole, over(stages(whole), n_j, block_keys, "block", zeros))
     else:
-        n_j = n_kv
-    dq_local = jax.lax.fori_loop(
-        0, n_j, body, jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[0, 0] = (dq_local * scale).astype(dq_ref.dtype)
+        # Square blocks. A strip meets the key blocks before the diagonal
+        # one whole and unmasked, then the diagonal block up to its own
+        # diagonal tile. ``diag_always`` says from the shapes that every
+        # query block has its diagonal block among the keys; the strips'
+        # last runs are then written SKEWED, as the forward's.
+        n = block_q // sub
+        rows = [pl.ds(r * sub, sub) for r in range(n)]
+        keys = [_ds(i, block_k, (r + 1) * sub) for r in range(n)]
+        st = [stages(r) for r in rows]
+        dq = [zeros] * n
+        if not single_q:
+            n_before = i if diag_always else jnp.minimum(i, n_kv)
+            dq = [over(st[r], n_before, block_keys, None, zeros)
+                  for r in range(n)]
+        if diag_always:
+            held = {}
+
+            def first(r):
+                held[r] = st[r][0](keys[r], "tril", i)
+
+            def second(r):
+                held[r] = st[r][1](*held[r])
+
+            def third(r):
+                write_dq(rows[r], st[r][2](keys[r], *held.pop(r), dq[r]))
+
+            _skewed(n, first, second, third)
+        else:
+            n_diag = (i < n_kv).astype(jnp.int32)
+            for r in range(n):
+                write_dq(rows[r], over(st[r], n_diag, lambda _: keys[r],
+                                       "tril", dq[r]))
 
     @pl.when(i == n_q - 1)
     def _emit():
@@ -639,12 +887,13 @@ def _bwd_fused_kernel(*refs, scale, causal, block_q, block_k, has_mask,
 
 
 # Scoped-VMEM budget for the fused backward's TOTAL estimated footprint
-# (resident k/v/dk/dv + fp32 accumulators + live [block_q, block_k]
-# loop intermediates + double-buffered q-side blocks). The hardware
+# (resident k/v/dk/dv + fp32 accumulators + one strip's (or the whole
+# block's) live scores + double-buffered q-side blocks). The hardware
 # limit is ~16 MB/core; 12 MB leaves headroom for Mosaic's own stack
-# slop. Measured live (v5e, r5): 1024x1024 tiles stack-OOMed at 20.82 MB
-# vs the 16 MB limit — the old resident-only estimate missed the ~16 MB
-# of score-sized intermediates entirely. Overridable for experiments.
+# slop. Measured live (v5e, r5): with the whole 1024x1024 block live the
+# kernel stack-OOMed at 20.82 MB vs the 16 MB limit — the old resident-only
+# estimate missed the ~16 MB of score-sized intermediates entirely.
+# Overridable for experiments.
 _FUSED_BWD_VMEM_BUDGET = int(os.environ.get(
     "DS_TPU_FUSED_BWD_MAX_BYTES", 12 * 1024 * 1024))
 
@@ -660,37 +909,43 @@ _RESIDENT_BWD_VMEM_BUDGET = (
     if "DS_TPU_FUSED_BWD_MAX_BYTES" in os.environ else 6 * 1024 * 1024)
 
 
-def _fused_bwd_vmem_bytes(t_kv, d, dtype, block_q, block_k, causal):
+def _fused_bwd_vmem_bytes(t_kv, d, dtype, block_q, block_k, sub, causal):
     """Estimated scoped-VMEM footprint of one fused-backward program
     instance. Counts what the kernel actually keeps live (see
     _bwd_fused_kernel): resident k/v + dk/dv outputs (model dtype) and
-    two full-length fp32 accumulators; per-loop [block_q, block_k]
-    intermediates — s and dpd in fp32, p and ds in the model dtype —
-    plus the fp32 tril block when causal uses equal tiles; and the
-    double-buffered streamed q/do/dq blocks."""
+    two full-length fp32 accumulators; the scores of ONE strip of ``sub``
+    rows (of the whole ``block_q`` where the block is its own tile) over
+    ``block_k`` keys — s and dpd in fp32, p and ds in the model dtype —
+    plus the fp32 tril constant when causal blocks are square; and the
+    double-buffered streamed q/do/dq blocks with their two fp32 row
+    statistics (lse, delta: a [block_q, 1] column is padded to whole
+    128-lane tiles in VMEM)."""
     itemsize = jnp.dtype(dtype).itemsize
     resident = t_kv * d * (4 * itemsize + 2 * 4)
-    per_elem = 2 * 4 + 2 * itemsize + \
-        (4 if causal and block_q == block_k else 0)
-    streamed = 2 * 3 * block_q * d * itemsize
-    return resident + block_q * block_k * per_elem + streamed
+    side = sub or block_q
+    live = side * block_k * (2 * 4 + 2 * itemsize)
+    tril = 4 * side * side if causal and block_q == block_k else 0
+    streamed = 2 * block_q * (3 * d * itemsize + 2 * _STATS_LANES * 4)
+    return resident + live + tril + streamed
 
 
-def _fit_fused_bwd_tiles(t_kv, d, dtype, block_q, block_k, causal):
-    """Largest (block_q, block_k) <= the requested tiles whose estimated
-    footprint fits the budget, halving the larger side first (both sides
-    stay >= 128 and keep dividing the sequence since the requested tiles
-    do and only halving happens). None if nothing fits."""
+def _fit_fused_bwd_tiles(t_kv, d, dtype, block_q, block_k, sub, causal):
+    """(block_q, block_k, sub) <= the requested ones whose estimated
+    footprint fits the budget. A block that is its own tile is halved, the
+    larger side first (both sides stay >= 128 and keep dividing the
+    sequence since the requested tiles do and only halving happens); a
+    block taken in strips has nothing left to give up. None if nothing
+    fits."""
     bq, bk = block_q, block_k
-    while _fused_bwd_vmem_bytes(t_kv, d, dtype, bq, bk, causal) > \
+    while _fused_bwd_vmem_bytes(t_kv, d, dtype, bq, bk, sub, causal) > \
             _FUSED_BWD_VMEM_BUDGET:
-        if max(bq, bk) <= 128:
+        if sub or max(bq, bk) <= 128:
             return None
         if bq >= bk and bq > 128:
             bq //= 2
         else:
             bk //= 2
-    return bq, bk
+    return bq, bk, sub
 
 
 def _bwd_mode(t_kv, d, dtype):
@@ -708,8 +963,9 @@ def _bwd_mode(t_kv, d, dtype):
     return "split" if resident > _RESIDENT_BWD_VMEM_BUDGET else "fused"
 
 
-def _flash_bwd_fused_pallas(q, k, v, mask, delta, lse, do, scale, causal,
-                            block_q, block_k):
+@pallas_mode.shared_launch("scale", "causal", "block_q", "block_k", "sub")
+def _flash_bwd_fused_pallas(q, k, v, mask, delta, lse, do, *, scale, causal,
+                            block_q, block_k, sub):
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, t_q, d = q.shape
@@ -729,17 +985,19 @@ def _flash_bwd_fused_pallas(q, k, v, mask, delta, lse, do, scale, causal,
         in_specs.append(_mask_spec(t_kv, lambda b_, h_, i: 0))
         args.append(_mask_operand(mask))
     if use_tril:
+        side = sub or block_q
         in_specs.append(
-            pl.BlockSpec((block_q, block_k), lambda b_, h_, i: (0, 0)))
-        args.append(_tril_block(block_q, block_k))
+            pl.BlockSpec((side, side), lambda b_, h_, i: (0, 0)))
+        args.append(_tril_block(side, side))
     in_specs += [q_spec, row_spec, row_spec]
     args += [do, lse, delta]
 
     dq, dk, dv = pallas_mode.kernel_call(
         "flash_bwd_fused",
         functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
-                          has_mask=mask is not None, has_tril=use_tril),
+                          block_q=block_q, block_k=block_k, sub=sub,
+                          has_mask=mask is not None, has_tril=use_tril,
+                          single_q=n_q == 1, diag_always=t_q <= t_kv),
         grid=(b, h, n_q),
         in_specs=in_specs,
         out_specs=[q_spec, kv_full, kv_full],
@@ -772,21 +1030,21 @@ def _flash_bwd_pallas(q, k, v, mask, delta, lse, g, scale, causal, block_q,
     # saved lse); dk needs no correction, dq is rescaled on its output.
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     if _bwd_mode(t_kv, d, q.dtype) == "fused":
-        if os.environ.get("DS_TPU_FLASH_BWD") == "fused":
-            # Explicitly forced: honor the request AND its exact tiles —
-            # an A/B experiment must measure the configured tiling, not
-            # a silently substituted one.
-            return _flash_bwd_fused_pallas(q, k, v, mask, delta, lse, do,
-                                           scale, causal, block_q, block_k)
-        # The forward's (autotuned) tiles can be too big for the fused
-        # backward's larger live set — shrink just the backward's tiles
-        # to the VMEM fit rather than abandoning the one-pass kernel
-        # (measured live: 1024x1024 stack-OOMed the 16 MB scoped limit).
-        fit = _fit_fused_bwd_tiles(t_kv, d, q.dtype, block_q, block_k,
-                                   causal)
-        if fit is not None:
-            return _flash_bwd_fused_pallas(q, k, v, mask, delta, lse, do,
-                                           scale, causal, fit[0], fit[1])
+        tiles = block_q, block_k, flash_subtile(block_q, block_k, causal)
+        if os.environ.get("DS_TPU_FLASH_BWD") != "fused":
+            # A block that is its own tile (no causal mask to walk it by)
+            # can be too big for the fused backward's live set — shrink
+            # just the backward's tiles to the VMEM fit rather than
+            # abandoning the one-pass kernel (measured live: a 1024x1024
+            # tile stack-OOMed the 16 MB scoped limit). Explicitly forced,
+            # the request is honored WITH its exact tiles: an A/B
+            # experiment must measure the configured tiling, not a
+            # silently substituted one.
+            tiles = _fit_fused_bwd_tiles(t_kv, d, q.dtype, *tiles, causal)
+        if tiles is not None:
+            return _flash_bwd_fused_pallas(
+                q, k, v, mask, delta, lse, do, scale=scale, causal=causal,
+                block_q=tiles[0], block_k=tiles[1], sub=tiles[2])
     use_tril = causal and block_q == block_k
     tril = _tril_block(block_q, block_k) if use_tril else None
 
@@ -1150,9 +1408,13 @@ def flash_attention(q, k, v, mask=None, causal=False, scale=None,
         convention (csrc/transformer/softmax_kernels.cu attn_softmax).
       causal: apply a causal (autoregressive) mask.
       scale: score scale; default 1/sqrt(D).
-      block_q, block_k: VMEM tile sizes. Default (None) consults the
-        per-shape autotuner table (ops/autotuner.py); its fallback 1024x1024
-        was tuned on v5e (GPT-2 355M shapes, d=64).
+      block_q, block_k: the GRID block, the rows and keys of one grid
+        step. Default (None) consults the per-shape autotuner table
+        (ops/autotuner.py) and falls back to 1024x1024: on v5e a grid step
+        costs a tenth of a head's work at T 1024, d=64, so the block is the
+        whole sequence there. How a block is taken INSIDE the kernel (strips
+        to the causal diagonal, ``flash_subtile``) follows from the block
+        and is nobody's setting.
     Returns: [B, H, T, D] in q.dtype.
     """
     d = q.shape[-1]

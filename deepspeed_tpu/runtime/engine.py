@@ -38,6 +38,7 @@ import numpy as np
 
 from deepspeed_tpu.ops.adam.fused_adam import FusedAdam
 from deepspeed_tpu.ops.lamb.fused_lamb import FusedLamb
+from deepspeed_tpu.ops.transformer.kernels import attention as flash_kernels
 from deepspeed_tpu.ops.transformer.kernels.attention import kernels_on_mesh
 from deepspeed_tpu.parallel import mesh as mesh_lib
 from deepspeed_tpu.runtime import lr_schedules
@@ -232,6 +233,14 @@ class DeepSpeedEngine(object):
             lambda: self.skipped_steps)
         self.telemetry.gauge("lr").set_fn(
             lambda: (self.get_lr() if self.optimizer else [0.0])[0])
+        # What flash attention's launcher resolved from the shapes of the
+        # last call traced: S, the rows of the strips a diagonal block is
+        # taken in (0: the block is its own tile), and the share of the
+        # score tiles it computes (0.5625 at S 128 in a block of 1024).
+        self.telemetry.gauge("flash_subtile").set_fn(
+            lambda: flash_kernels.last_walk()["subtile"])
+        self.telemetry.gauge("flash_tiles_visited_share").set_fn(
+            lambda: flash_kernels.last_walk()["tiles_visited_share"])
         # Perf X-ray (telemetry/xray.py): train_batch's fused path
         # stashes each compiled step program's shape signature here
         # (microseconds; no compile). perf_xray() / the flops profiler

@@ -8,11 +8,18 @@ for all of them.
 
 Usage: python tests/perf/attention_bench.py [--seq 1024] [--batch 8]
        [--dense] [--blocks 1024,1024]
+       python tests/perf/attention_bench.py --subtile 0,128,256,512
+           (the kernels ALONE, by the device trace, at the training cells'
+           shapes: one line a shape and sub-tile side; 0 = the block is its
+           own tile, the kernels before the walk)
 """
 
 import argparse
+import glob
+import json
 import os
 import sys
+import tempfile
 import time
 
 import jax
@@ -23,10 +30,76 @@ import _platform
 
 _platform.setup()
 
+from deepspeed_tpu.ops.transformer.kernels import attention
 from deepspeed_tpu.ops.transformer.kernels.attention import (
     flash_attention, mha_reference)
 
 REPS = 20
+# The training cells' calls (`train-gpt2m-1chip`, a chip of
+# `train-gpt2xl-zero-dp4`) and BERT-large's: (b, h, t, d), causal.
+CELL_SHAPES = (((16, 16, 1024, 64), True), ((4, 25, 1024, 64), True),
+               ((8, 16, 512, 64), False))
+KERNELS = ("flash_fwd", "flash_bwd_fused", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+def kernel_us(fn, args, reps=5):
+    """{kernel name: (mean device microseconds a call, calls)} of the flash
+    kernels in ``reps`` runs of jitted ``fn``, read from the profiler's
+    trace of the first TPU's ``XLA Ops`` line: the kernel alone, no host,
+    no neighbouring XLA op."""
+    jitted = jax.jit(fn)
+    jax.block_until_ready(jitted(*args))
+    out_dir = tempfile.mkdtemp(prefix="attention_bench_")
+    with jax.profiler.trace(out_dir):
+        for _ in range(reps):
+            out = jitted(*args)
+        jax.block_until_ready(out)
+    times = {}
+    for path in glob.glob(os.path.join(out_dir, "**", "*.xplane.pb"),
+                          recursive=True):
+        data = jax.profiler.ProfileData.from_file(path)
+        for plane in data.planes:
+            if not plane.name.startswith("/device:TPU:0"):
+                continue
+            for line in plane.lines:
+                if line.name != "XLA Ops":
+                    continue
+                for ev in line.events:
+                    head = ev.name.split("=")[0]
+                    for k in KERNELS:
+                        if k in head:
+                            times.setdefault(k, []).append(ev.duration_ns)
+    return {k: (float(np.mean(v)) / 1e3, len(v)) for k, v in times.items()}
+
+
+def subtile_table(sides, dtype):
+    """Time the forward and the fused backward alone for each sub-tile
+    side at each of CELL_SHAPES; one JSON line each on stdout."""
+    rng = np.random.RandomState(0)
+    for (b, h, t, d), causal in CELL_SHAPES:
+        q, k, v = (jnp.asarray(rng.randn(b, h, t, d), dtype)
+                   for _ in range(3))
+        mask = None if causal else jnp.zeros((b, t), jnp.float32)
+
+        for side in sides if causal else sides[:1]:
+            # 0: a side no block reaches, so every block is its own tile.
+            attention._SUBTILE_SIDE = side or 1 << 30
+
+            def fwd_bwd(q, k, v):  # a function a side: jit caches by it
+                out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+                    q, k, v, mask=mask, causal=causal), q, k, v)
+                return out, vjp(out)
+
+            us = kernel_us(fwd_bwd, (q, k, v))
+            walk = attention.last_walk()
+            print(json.dumps({
+                "shape": [b, h, t, d], "causal": causal,
+                "dtype": jnp.dtype(dtype).name, "subtile": walk["subtile"],
+                "tiles_visited_share": walk["tiles_visited_share"],
+                "us_a_head": {k: round(us[k][0] / (b * h), 3)
+                              for k in sorted(us)},
+                "us_a_call": {k: round(us[k][0], 1) for k in sorted(us)},
+                "device": jax.devices()[0].device_kind}), flush=True)
 
 
 def time_fn(fn, *args):
@@ -67,7 +140,14 @@ def main():
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--bwd", default=None, choices=["auto", "fused", "split"],
                     help="flash backward path (sets DS_TPU_FLASH_BWD)")
+    ap.add_argument("--subtile", default=None,
+                    help="comma list of sub-tile sides to time the kernels "
+                         "alone at (0: the block is its own tile)")
     args = ap.parse_args()
+    if args.subtile:
+        subtile_table([int(x) for x in args.subtile.split(",")],
+                      jnp.dtype(args.dtype))
+        return 0
     if args.bwd:
         os.environ["DS_TPU_FLASH_BWD"] = args.bwd
 

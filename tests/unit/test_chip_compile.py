@@ -273,6 +273,16 @@ CASES = {
     "flash_fwd_bwd_auto": (_fwd_bwd(_flash_causal, 3), [QKV] * 3, {}),
     "flash_fwd_bwd_split": (_fwd_bwd(_flash_causal, 3), [QKV] * 3,
                             {"DS_TPU_FLASH_BWD": "split"}),
+    # The two training cells' calls: one block of 1024 x 1024 a head, taken
+    # in strips to the diagonal, forward and fused backward; and a sequence
+    # of two blocks a side (strips in the diagonal blocks, the block before
+    # them whole).
+    "flash_train_gpt2m_1chip_fwd_bwd": (
+        _fwd_bwd(_flash_causal, 3), [((16, 16, 1024, 64), BF16)] * 3, {}),
+    "flash_train_gpt2xl_dp4_fwd_bwd": (
+        _fwd_bwd(_flash_causal, 3), [((4, 25, 1024, 64), BF16)] * 3, {}),
+    "flash_t2048_two_blocks_fwd_bwd": (
+        _fwd_bwd(_flash_causal, 3), [((4, 16, 2048, 64), BF16)] * 3, {}),
     "flash_bert_masked_fwd_bwd": (
         _fwd_bwd(_flash_bert, 3),
         [((8, 16, 512, 64), BF16)] * 3 + [((8, 512), F32)], {}),
@@ -458,6 +468,26 @@ def test_kernel_compiles_for_v5e(name, chip, monkeypatch):
     assert _kernel_calls(text) and all(
         KERNEL_NAME.match(c) for c in _kernel_calls(text)), \
         _kernel_calls(text)
+
+
+@pytest.mark.parametrize("name", ["flash_train_gpt2m_1chip_fwd_bwd",
+                                  "flash_train_gpt2xl_dp4_fwd_bwd"])
+def test_training_cells_flash_calls_keep_their_names_and_take_strips(
+        name, chip, monkeypatch):
+    """The training cells' attention is ONE ``flash_fwd`` and ONE
+    ``flash_bwd_fused`` custom call (the names the three rooflines read),
+    and the launcher's rule gave them strips: S divides the block, fewer
+    tiles than the square are computed."""
+    fn, shapes, _ = CASES[name]
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in shapes]
+    calls = _kernel_calls(jax.jit(fn).lower(*args).compile().as_text())
+    assert sorted(c.split(".")[0] for c in calls) == \
+        ["flash_bwd_fused", "flash_fwd"]
+    walk = attention.last_walk()
+    n = 1024 // walk["subtile"]
+    assert n > 1 and walk["tiles_visited_share"] == (n + 1) / (2 * n)
 
 
 @pytest.mark.parametrize("name, pages", [

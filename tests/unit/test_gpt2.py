@@ -76,6 +76,34 @@ def test_gpt2_remat():
     assert np.isfinite(float(loss))
 
 
+def test_flash_strip_gauges_say_what_the_launcher_resolved():
+    """``flash_subtile`` / ``flash_tiles_visited_share``: the training
+    engine's gauges read what flash attention's launcher resolved from the
+    shapes of the last call traced: at T 256 the block is the sequence, the
+    rule takes it in two strips of 128 rows, 3 of its 4 tiles computed."""
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.transformer.kernels.attention import (
+        flash_attention)
+    from deepspeed_tpu.telemetry.exporters import prometheus_text
+
+    engine, _, _, _ = deepspeed.initialize(
+        model=GPT2LMHeadModel(GPT2Config.tiny()),
+        config_params={
+            "train_batch_size": 8,
+            "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+            "bf16": {"enabled": True},
+        })
+    x = jnp.ones((1, 2, 256, 16), jnp.float32)
+    assert np.isfinite(np.asarray(flash_attention(x, x, x, causal=True))).all()
+    snap = engine.telemetry.snapshot()
+    assert snap["flash_subtile"] == 128
+    assert snap["flash_tiles_visited_share"] == 0.75
+    text = prometheus_text(engine.telemetry)
+    assert "ds_tpu_flash_subtile" in text
+    assert "ds_tpu_flash_tiles_visited_share" in text
+
+
 def test_flash_attention_path_matches_dense():
     """cfg.use_flash_attention=True routes through the Pallas flash kernel and
     agrees with the dense XLA path (fwd loss + grads finite)."""

@@ -8,8 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.ops.transformer.kernels import attention
 from deepspeed_tpu.ops.transformer.kernels.attention import (
-    flash_attention, mha_reference)
+    flash_attention, flash_attention_with_lse, mha_reference)
 from deepspeed_tpu.ops.transformer.kernels.dropout import (
     dropout, fused_bias_dropout_residual)
 from deepspeed_tpu.ops.transformer.kernels.gelu import (
@@ -177,6 +178,119 @@ def test_flash_attention_fp16_loss_scaled_grads_finite():
     g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
     for a in g:
         assert np.isfinite(np.asarray(a, np.float32)).all()
+
+
+# dtype -> (forward rtol, atol, gradient rtol, atol) against the float32
+# dense reference.
+_WALK_TOL = {jnp.float32: (1e-4, 1e-4, 1e-3, 2e-4),
+             jnp.bfloat16: (5e-2, 2e-2, 1e-1, 5e-2),
+             jnp.float16: (1e-2, 5e-3, 5e-2, 2e-2)}
+
+
+@pytest.mark.parametrize("t_q,t_kv,blk,side,dtype,use_mask,with_lse", [
+    (512, 512, None, None, jnp.float32, False, False),  # 10 tiles of 16
+    (384, 384, None, None, jnp.float32, True, False),   # a mask skips none
+    (512, 512, None, 256, jnp.bfloat16, False, False),  # another side
+    (256, 256, None, None, jnp.float16, False, False),  # unfused dp - delta
+    (512, 512, 256, None, jnp.float32, False, False),   # 2 x 2 grid blocks
+    (256, 512, 256, None, jnp.float32, False, False),   # keys past the rows
+    (512, 256, 256, None, jnp.float32, False, False),   # rows past the keys
+    (256, 256, None, None, jnp.float32, False, True),   # a nonzero dlse
+])
+def test_flash_attention_takes_a_diagonal_block_in_strips(
+        monkeypatch, t_q, t_kv, blk, side, dtype, use_mask, with_lse):
+    """Several sub-tiles a grid block (the block is the whole sequence, as
+    in the training cells, or a square part of it): forward and gradients
+    against the dense reference. The tiles above the diagonal are never
+    formed, so what they would have held must be exactly nothing: keys past
+    the last query row get zero gradient, rows past the last key see every
+    key."""
+    if side:
+        monkeypatch.setattr(attention, "_SUBTILE_SIDE", side)
+    rng = np.random.RandomState(17)
+    b, h, d = 1, 2, 16
+    q = jnp.asarray(rng.randn(b, h, t_q, d), dtype)
+    k = jnp.asarray(rng.randn(b, h, t_kv, d), dtype)
+    v = jnp.asarray(rng.randn(b, h, t_kv, d), dtype)
+    mask = None
+    if use_mask:
+        mask = jnp.where(jnp.asarray(rng.rand(b, t_kv)) > 0.25, 0.0,
+                         -1e9).astype(jnp.float32)
+    w = jnp.asarray(rng.randn(b, h, t_q, 1), jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention_with_lse(q, k, v, mask=mask, causal=True,
+                                        block_q=blk, block_k=blk)
+
+    def dense(q, k, v):
+        return mha_reference(q, k, v, mask=mask, causal=True,
+                             return_lse=True)
+
+    def outputs_and_grads(fn, *qkv):
+        """(o, lse) and the loss's gradients from ONE program a side."""
+        def loss(q, k, v):
+            o, lse = fn(q, k, v)
+            out = jnp.sum(o.astype(jnp.float32) ** 2)
+            return (out + jnp.sum(lse * w) if with_lse else out), (o, lse)
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(*qkv)
+        return out, grads
+
+    rtol, atol, g_rtol, g_atol = _WALK_TOL[dtype]
+    (o, lse), g = outputs_and_grads(flash, q, k, v)
+    (o_ref, lse_ref), gr = outputs_and_grads(
+        dense, *(x.astype(jnp.float32) for x in (q, k, v)))
+    walk = attention.last_walk()
+    assert walk["subtile"] == (side or attention._SUBTILE_SIDE)
+    n = t_q // walk["subtile"], t_kv // walk["subtile"]
+    assert walk["tiles_visited_share"] == \
+        sum(min(r + 1, n[1]) for r in range(n[0])) / (n[0] * n[1])
+    np.testing.assert_allclose(np.asarray(o, np.float32), o_ref,
+                               rtol=rtol, atol=atol)
+    np.testing.assert_allclose(lse, lse_ref, rtol=rtol, atol=atol)
+    for a, b_ in zip(g, gr):
+        np.testing.assert_allclose(np.asarray(a, np.float32), b_,
+                                   rtol=g_rtol, atol=g_atol)
+    if t_kv > t_q:
+        assert not np.asarray(g[1][:, :, t_q:], np.float32).any()
+        assert not np.asarray(g[2][:, :, t_q:], np.float32).any()
+
+
+def test_flash_subtile_rule_for_the_training_cells():
+    """The rule itself, from shapes alone: the side S for the two training
+    cells' calls ([16, 16, 1024, 64] and [4, 25, 1024, 64]: one block of
+    1024 x 1024 a head), the tiles it visits, n (n + 1) / 2 of n x n, and
+    the fused backward's footprint with a STRIP's scores live, not the
+    block's: the whole 1024 rows fit one grid step under the budget."""
+    s = attention.flash_subtile(1024, 1024, True)
+    assert s == attention._SUBTILE_SIDE == 128
+    n = 1024 // s
+    assert attention.tiles_visited(1024, 1024, s, s, True) == \
+        (n * (n + 1) // 2, n * n)
+    assert attention.tiles_visited(1024, 1024, 1024, 1024, False) == (1, 1)
+    # Nothing to skip without a causal mask; a block no side divides, one
+    # no larger than the smallest side, or one that is not square, is its
+    # own tile.
+    assert attention.flash_subtile(512, 512, False) == 0
+    assert attention.flash_subtile(128, 128, True) == 0
+    assert attention.flash_subtile(96, 96, True) == 0
+    assert attention.flash_subtile(512, 1024, True) == 0
+    assert attention.flash_subtile(256, 256, True) == 128
+    assert attention.flash_subtile(192, 192, True) == 0
+    # The fused backward: a strip's scores, not the block's 12 B x 1M.
+    strip = attention._fused_bwd_vmem_bytes(1024, 64, jnp.bfloat16, 1024,
+                                            1024, s, True)
+    block = attention._fused_bwd_vmem_bytes(1024, 64, jnp.bfloat16, 1024,
+                                            1024, 0, True)
+    assert strip < attention._FUSED_BWD_VMEM_BUDGET < block
+    assert attention._fit_fused_bwd_tiles(
+        1024, 64, jnp.bfloat16, 1024, 1024, s, True) == (1024, 1024, s)
+    # A block that is its own tile still halves to fit, the larger side
+    # first (BERT-shaped calls at T 1024; T 512 fits whole).
+    assert attention._fit_fused_bwd_tiles(
+        1024, 64, jnp.bfloat16, 1024, 1024, 0, False) == (512, 1024, 0)
+    assert attention._fit_fused_bwd_tiles(
+        512, 64, jnp.bfloat16, 512, 512, 0, False) == (512, 512, 0)
 
 
 def test_flash_attention_ragged_fallback():
