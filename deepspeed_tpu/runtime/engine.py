@@ -21,15 +21,21 @@ execution model is JAX-first:
   default) or fp16 with full DynamicLossScaler semantics (overflow-skip,
   scale-window bookkeeping — reference fp16/fused_optimizer.py).
 - ``train_batch(batch)`` is the fused fast path: fwd+bwd+update in one XLA
-  program with donated buffers (benchmarks use this).
+  program with donated buffers (benchmarks use this). Where only the batch
+  is split (a mesh that splits 'data' alone, ZeRO 0-2) its forward and
+  backward run per chip inside one ``shard_map`` over 'data' and ZeRO's
+  collectives are written, not inferred (``_dp_value_and_grad``): the
+  optimizer's partition stays out of the model.
 """
 
+import gc
 import glob
 import hashlib
 import json
 import os
 import pickle
 import time
+import types
 from concurrent.futures import ThreadPoolExecutor
 
 import jax
@@ -65,6 +71,45 @@ from deepspeed_tpu.telemetry import (MetricsRegistry, ProgramRegistry,
                                      SpanRecorder, TensorBoardScalarWriter)
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer, ThroughputTimer
+
+
+_ROOMY = []
+
+
+def _traced_with_room(fn):
+    """``fn()``, for a call that traces a whole step: with the cyclic
+    collector paused, inside ONE frame of a megabyte.
+
+    Both are about what a trace of 48 layers does to CPython, not to JAX
+    (PERF.md, PR 46). CPython (3.11 on) keeps a thread's frames in chunks of
+    16 KB that it maps when a call crosses a chunk's end and UNMAPS when the
+    call returns; a trace binds 10^5 primitives, each some twenty frames up
+    and down at a depth of several hundred, and wherever a chunk's end lies
+    inside that stretch every bind pays an mmap, a page fault and a munmap.
+    Which depth is unlucky moves with every frame above it: the data-parallel
+    region's few frames took the dp4 step's trace from 15.9 to 33.0 s on the
+    cell's host, and a caller's nine dummy frames had moved GPT-2's from 4.6
+    to 15.2 s (PR 34). A chunk is as large as the frame that opens it needs,
+    rounded up to a power of two: a frame of 2^17 + 64 slots opens one of
+    2 MB and leaves the trace a megabyte in which no call crosses anything
+    (minor page faults of a 12-layer trace: 28,126 -> 2,411). The collector:
+    a trace allocates the step's whole jaxpr, none of it garbage until the
+    trace ends, and every pass over the oldest generation walks all of it
+    (11.8 -> 7.3 s on this sandbox); reference counting frees as before."""
+    if not _ROOMY:
+        def roomy(fn):
+            return fn()
+
+        spare = tuple("_{}".format(i) for i in range(2 ** 17 + 64))
+        _ROOMY.append(types.FunctionType(roomy.__code__.replace(
+            co_nlocals=1 + len(spare), co_varnames=("fn",) + spare), {}))
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        return _ROOMY[0](fn)
+    finally:
+        if was:
+            gc.enable()
 
 
 class _StreamedGrads:
@@ -241,6 +286,15 @@ class DeepSpeedEngine(object):
             lambda: flash_kernels.last_walk()["subtile"])
         self.telemetry.gauge("flash_tiles_visited_share").set_fn(
             lambda: flash_kernels.last_walk()["tiles_visited_share"])
+        # How the gradient leaves left the fused step last traced, where
+        # its forward and backward run per chip (_dp_value_and_grad): by
+        # psum_scatter onto ZeRO-2's partition, by psum. Both 0 where GSPMD
+        # partitions the step (one chip, stage 3, a model / pipe / seq mesh).
+        self._zero_leaves = (0, 0)
+        self.telemetry.gauge("zero_scatter_leaves").set_fn(
+            lambda: self._zero_leaves[0])
+        self.telemetry.gauge("zero_psum_leaves").set_fn(
+            lambda: self._zero_leaves[1])
         # Perf X-ray (telemetry/xray.py): train_batch's fused path
         # stashes each compiled step program's shape signature here
         # (microseconds; no compile). perf_xray() / the flops profiler
@@ -792,8 +846,9 @@ class DeepSpeedEngine(object):
         # ZeRO-2/3 semantics (reference stage2.py:675-738): gradients are
         # REDUCE-SCATTERED to their owner shard, never materialized
         # replicated. Enforced as a GSPMD constraint inside every grad-
-        # producing program; XLA lowers the cross-replica sum to
-        # reduce-scatter instead of all-reduce.
+        # producing program (XLA lowers the cross-replica sum to
+        # reduce-scatter instead of all-reduce), but for the fused step's
+        # data-parallel region, which writes the psum_scatter itself.
         self._grad_constraint = self.grad_sharding if stage >= 2 else None
         self._shardings_ready = True
 
@@ -2003,6 +2058,121 @@ class DeepSpeedEngine(object):
         return jax.jit(train_step, donate_argnums=(0, 1),
                        out_shardings=out_shardings)
 
+    def _dp_region_specs(self, batch):
+        """The PartitionSpec of every gradient leaf (``zero_shardings``'
+        own, read off ``grad_sharding``) where the fused step's forward and
+        backward run PER CHIP, else None. They do when all that is split is
+        the batch: the mesh splits only 'data', the parameters are whole
+        on every chip (ZeRO 0-2) and every leaf of ``batch`` splits over
+        'data'. Any other mesh, stage 3, a batch leaf that stays whole and
+        one chip keep the program GSPMD partitions."""
+        dp = mesh_lib.dp_size(self.mesh)
+        stage = self.zero_optimization_stage() \
+            if self.zero_optimization() else 0
+        rows = mesh_lib.P(mesh_lib.DATA_AXIS)
+        leaves = jax.tree_util.tree_leaves(batch)
+        if dp <= 1 or self.mesh.size != dp or stage > 2 or not leaves or \
+                any(mesh_lib.batch_partition_spec(x, dp) != rows
+                    for x in leaves):
+            return None
+        return jax.tree_util.tree_map(lambda sh: sh.spec, self.grad_sharding)
+
+    def _dp_value_and_grad(self, loss_fn, specs, params, args, rng):
+        """``(loss, grads)`` of ``loss_fn(params, args, rng)`` under data
+        parallelism, with ZeRO's collectives WRITTEN: one ``shard_map``
+        over 'data' in which each chip holds the whole (cast) ``params``
+        and its own rows of ``args``, so the optimizer's partition cannot
+        reach into the model (GSPMD propagated the tied table's
+        feature-split gradient into the LM head: a contraction-sharded
+        head over every chip's rows and an all-reduce of the float32
+        logits a chunk). The loss leaves as the mean over chips (equal
+        rows a chip: the global mean, and what the reference's ranks
+        compute); a gradient leaf whose spec names 'data' leaves through
+        ``psum_scatter`` onto that dim (ZeRO-2, reference
+        stage2.py:675-738), any other through ``psum``, in the dtype of
+        ``params``: differentiate with respect to the CAST parameters and
+        the cotangents cross the wire in the compute dtype."""
+        axis = mesh_lib.DATA_AXIS
+        dp = mesh_lib.dp_size(self.mesh)
+        is_spec = lambda x: isinstance(x, mesh_lib.P)
+        dims = [list(spec).index(axis) if axis in spec else None
+                for spec in jax.tree_util.tree_leaves(specs,
+                                                      is_leaf=is_spec)]
+        self._zero_leaves = (sum(d is not None for d in dims),
+                             sum(d is None for d in dims))
+
+        def spmd(params, largs, rng):
+            rng = jax.random.fold_in(rng, jax.lax.axis_index(axis))
+            # 1/dp of the chip's mean loss: the cotangents are then the
+            # global mean's from the start, and the sums below finish it.
+            loss, grads = jax.value_and_grad(
+                lambda p: loss_fn(p, largs, rng) / dp)(params)
+            with jax.named_scope("zero_reduce"):
+                leaves, treedef = jax.tree_util.tree_flatten(grads)
+                leaves = [
+                    jax.lax.psum(g, axis) if d is None else
+                    jax.lax.psum_scatter(g, axis, scatter_dimension=d,
+                                         tiled=True)
+                    for g, d in zip(leaves, dims)]
+                return jax.lax.psum(loss, axis), \
+                    jax.tree_util.tree_unflatten(treedef, leaves)
+
+        whole = mesh_lib.P()
+        rows = jax.tree_util.tree_map(
+            lambda _: mesh_lib.P(axis), args)
+        return jax.shard_map(
+            spmd, mesh=self.mesh, in_specs=(whole, rows, whole),
+            out_specs=(whole, specs), check_vma=False)(params, args, rng)
+
+    def _build_fused_step(self):
+        """The fused fwd+bwd+update program of ``train_batch``."""
+        module = self.module
+        cast = self._cast_to_compute
+        clip = self.gradient_clipping()
+        optimizer = self.optimizer
+        grad_constraint = self._grad_constraint
+        mesh = self.mesh
+
+        # Named for what it is: a trace's hlo_module reads
+        # jit_train_step. Its regions (jax.named_scope): the model's
+        # (embed, block/ln|attn|mlp, lm_head: models/gpt2.py),
+        # zero_reduce (the gradients' collectives, where they are
+        # written) and optimizer (gradient cast, clip, update).
+        def train_step(params, opt_state, args, rng, lr, beta1, beta2):
+            def loss_fn(cp, args, rng):
+                with kernels_on_mesh(mesh):
+                    return module.apply({"params": cp}, *args,
+                                        rngs={"dropout": rng})
+
+            # Decided by what this trace can see: mesh, stage, shapes.
+            specs = self._dp_region_specs(args)
+            if specs is None:
+                self._zero_leaves = (0, 0)
+                loss, grads = jax.value_and_grad(
+                    lambda p: loss_fn(cast(p), args, rng))(params)
+                if grad_constraint is not None:
+                    grads = jax.lax.with_sharding_constraint(
+                        grads, grad_constraint)
+            else:
+                loss, grads = self._dp_value_and_grad(
+                    loss_fn, specs, cast(params), args, rng)
+            with jax.named_scope("optimizer"):
+                grads = jax.tree_util.tree_map(
+                    lambda g: g.astype(jnp.float32), grads)
+                if clip > 0.0:
+                    grads, _ = clip_grad_norm_(grads, clip)
+                new_params, new_state = optimizer.update(
+                    params, grads, opt_state, lr=lr,
+                    betas=(beta1, beta2))
+            return loss, new_params, new_state
+
+        out_shardings = None
+        if self._shardings_ready:
+            out_shardings = (None, self.param_sharding,
+                             self.opt_state_sharding)
+        return jax.jit(train_step, donate_argnums=(0, 1),
+                       out_shardings=out_shardings)
+
     def train_batch(self, batch=None, data_iter=None):
         """Fused fwd+bwd+update in ONE jitted XLA program (donated buffers).
 
@@ -2056,46 +2226,9 @@ class DeepSpeedEngine(object):
                     frozen=key[2])
         else:
             key = len(inputs)
-        if key not in self._fused_step_cache:
-            module = self.module
-            cast = self._cast_to_compute
-            clip = self.gradient_clipping()
-            optimizer = self.optimizer
-            grad_constraint = self._grad_constraint
-            mesh = self.mesh
-
-            # Named for what it is: a trace's hlo_module reads
-            # jit_train_step. Its regions (jax.named_scope): the model's
-            # (embed, block/ln|attn|mlp, lm_head: models/gpt2.py) and
-            # optimizer (gradient cast, clip, update).
-            def train_step(params, opt_state, args, rng, lr, beta1, beta2):
-                def loss_fn(p):
-                    cp = cast(p)
-                    with kernels_on_mesh(mesh):
-                        return module.apply({"params": cp}, *args,
-                                            rngs={"dropout": rng})
-
-                loss, grads = jax.value_and_grad(loss_fn)(params)
-                if grad_constraint is not None:
-                    grads = jax.lax.with_sharding_constraint(
-                        grads, grad_constraint)
-                with jax.named_scope("optimizer"):
-                    grads = jax.tree_util.tree_map(
-                        lambda g: g.astype(jnp.float32), grads)
-                    if clip > 0.0:
-                        grads, _ = clip_grad_norm_(grads, clip)
-                    new_params, new_state = optimizer.update(
-                        params, grads, opt_state, lr=lr,
-                        betas=(beta1, beta2))
-                return loss, new_params, new_state
-
-            out_shardings = None
-            if self._shardings_ready:
-                out_shardings = (None, self.param_sharding,
-                                 self.opt_state_sharding)
-            self._fused_step_cache[key] = jax.jit(
-                train_step, donate_argnums=(0, 1),
-                out_shardings=out_shardings)
+        first = key not in self._fused_step_cache
+        if first:
+            self._fused_step_cache[key] = self._build_fused_step()
 
         self.tput_timer.start()
         group = self.optimizer.param_groups[0]
@@ -2111,11 +2244,19 @@ class DeepSpeedEngine(object):
                         self.params, self.opt_state, inputs, rng,
                         lr_d, b1_d, b2_d,
                         donate=("params", "opt_state"))
-        self.xray.note("fused_train_step[{}]".format(key),
-                       tokens=self.train_batch_size())
+        def dispatch():
+            return jitted(self.params, self.opt_state, inputs, rng, lr_d,
+                          b1_d, b2_d)
+
         with self.tracer.timed("train/dispatch"):
-            loss, self.params, self.opt_state = jitted(
-                self.params, self.opt_state, inputs, rng, lr_d, b1_d, b2_d)
+            loss, self.params, self.opt_state = \
+                _traced_with_room(dispatch) if first else dispatch()
+        # After the dispatch: the first one traces the step, and the trace
+        # is what counts the leaves.
+        self.xray.note("fused_train_step[{}]".format(key),
+                       tokens=self.train_batch_size(),
+                       zero_scatter_leaves=self._zero_leaves[0],
+                       zero_psum_leaves=self._zero_leaves[1])
         with self.tracer.timed("train/bookkeeping"):
             if self.lr_scheduler is not None:
                 self.lr_scheduler.step()

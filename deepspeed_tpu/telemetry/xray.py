@@ -198,7 +198,7 @@ class _Stash(object):
     when a label cycles through several."""
 
     __slots__ = ("label", "sig", "jitted", "args", "kwargs", "donate",
-                 "record", "calls", "tokens")
+                 "record", "calls", "tokens", "facts")
 
     def __init__(self, label, sig, jitted, args, kwargs, donate):
         self.label = label
@@ -210,6 +210,7 @@ class _Stash(object):
         self.record = None
         self.calls = 0
         self.tokens = 0
+        self.facts = {}
 
 
 def _public_event(ev):
@@ -356,15 +357,18 @@ class ProgramRegistry(object):
                     })
         return True
 
-    def note(self, label, tokens=0):
+    def note(self, label, tokens=0, **facts):
         """Per-step accounting against the label's ACTIVE signature:
         one call, ``tokens`` emitted — the per-record flops/token and
         bytes/token denominators. (Notes landing before any stash are
-        held and folded into the label's first stash.)"""
+        held and folded into the label's first stash.) ``facts`` are what
+        the caller knows of the program as it was traced (the training
+        step's ``zero_scatter_leaves``); the record carries the last."""
         stash = self._active.get(label)
         if stash is not None:
             stash.calls += 1
             stash.tokens += tokens
+            stash.facts.update(facts)
             return
         p = self._pending.get(label)
         if p is None:
@@ -632,6 +636,7 @@ class ProgramRegistry(object):
                 entry["superseded"] = stash is not active
                 entry["calls"] = stash.calls
                 entry["tokens"] = stash.tokens
+                entry.update(stash.facts)
                 if stash is active:
                     entry["sampled_step_seconds"] = self._step_s.get(
                         label)

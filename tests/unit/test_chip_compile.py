@@ -35,10 +35,10 @@ from deepspeed_tpu.ops.transformer.kernels import (  # noqa: E402
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described v5e chip, with the compilation cache off around the
-    module (a described-device executable is written to the cache but
-    cannot be read back without a chip)."""
+def host():
+    """The four described chips of a v5e 2x2 host, with the compilation
+    cache off around the module (a described-device executable is written
+    to the cache but cannot be read back without a chip)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
 
@@ -50,9 +50,15 @@ def chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield list(topo.devices)
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(host):
+    """One described v5e chip."""
+    return SingleDeviceSharding(host[0])
 
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
@@ -1026,3 +1032,67 @@ def test_lfm2_mixed_step_attends_packed_grouped_query_keys_in_place(
     arena = ["[3,3073,4,128,128]", "[3073,4,128,128]"]
     assert _arena_shaped([line for lines in comps.values()
                           for line in lines], arena) == []
+
+
+# ------------------------------------------------- the ZeRO-2 training step
+
+def test_zero2_step_keeps_the_partition_out_of_the_model(host, monkeypatch):
+    """The engine's ZeRO-2 fused step for the four described chips (a
+    1-layer, 256-wide GPT-2 with an odd vocabulary of 1001, 8 sequences of
+    256; 5 s): the optimizer's partition stays out of the model. The tied
+    table's gradient can only split by FEATURES, and GSPMD carried that
+    split into the LM head: ``f32[2048,1001] all-reduce`` of the logits a
+    chunk, every chip over every chip's rows, ``all-to-all``s around it
+    (149.5 ms of exposed collectives a step at GPT-2 XL on the chip, PR
+    46). Under the engine's data-parallel region the model holds no
+    collective, every matrix leaf that splits by rows leaves through a
+    reduce-scatter, and the flash kernels are in the program. The engine
+    is built on described devices, which hold no array: ``device_put`` is
+    the identity while it places its state, and the step is lowered from
+    shapes."""
+    import deepspeed_tpu as deepspeed
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+    from tests.unit.test_engine import (
+        SEQ, gradient_scatters, partition_leaks, tiny_gpt2, tiny_gpt2_params)
+
+    ids = jnp.zeros((8, SEQ), I32)
+    mesh = mesh_lib.build_mesh(devices=host)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "device_put", lambda x, *a, **k: x)
+        engine, _, _, _ = deepspeed.initialize(
+            model=tiny_gpt2(BF16), model_parameters=tiny_gpt2_params(BF16),
+            mesh=mesh,
+            config_params={"train_batch_size": 8,
+                           "optimizer": {"type": "AdamW",
+                                         "params": {"lr": 1e-4}},
+                           "bf16": {"enabled": True},
+                           "zero_optimization": {"stage": 2}})
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+
+    def described(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+            tree, shardings)
+
+    whole = mesh_lib.replicated(mesh)
+    rows = jax.ShapeDtypeStruct(ids.shape, I32,
+                                sharding=mesh_lib.batch_sharding(mesh))
+    scalar = jax.ShapeDtypeStruct((), F32, sharding=whole)
+    text = engine._build_fused_step().lower(
+        described(engine.params, engine.param_sharding),
+        described(engine.opt_state, engine.opt_state_sharding),
+        (rows, rows), jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=whole),
+        scalar, scalar, scalar).compile().as_text()
+
+    assert partition_leaks(text) == []
+    # (The table itself, split along its MINOR dim, is reduced whole in
+    # bf16 and sliced: the compiler scatters along a major dim only.)
+    matrices = [p for p in jax.tree_util.tree_leaves(engine.params)
+                if p.ndim == 2 and p.shape[0] % len(host) == 0]
+    assert len(matrices) == 5
+    assert len(gradient_scatters(text)) >= len(matrices)
+    assert sorted(c.split(".")[0] for c in _kernel_calls(text)) == \
+        ["flash_bwd_fused", "flash_fwd"]
+    assert engine._zero_leaves == (len(jax.tree_util.tree_leaves(
+        engine.params)), 0)
+

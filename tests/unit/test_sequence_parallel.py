@@ -12,6 +12,27 @@ from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
 from deepspeed_tpu.parallel import mesh as mesh_lib
 
 
+_PARAMS = {}
+
+
+def _fresh(key, model, *probe):
+    """The model's parameters from ONE jitted init a ``key``, a fresh copy
+    an engine: serial and sequence-parallel configurations hold the same
+    tree, and an engine left to initialise itself at its first forward
+    does so operation by operation (seconds a tiny model)."""
+    import jax.numpy as jnp
+    if key not in _PARAMS:
+        _PARAMS[key] = jax.jit(lambda: model.init(
+            jax.random.PRNGKey(0), *probe)["params"])()
+    return jax.tree_util.tree_map(jnp.array, _PARAMS[key])
+
+
+def _gpt2_params():
+    import jax.numpy as jnp
+    return _fresh("gpt2", GPT2LMHeadModel(GPT2Config.tiny(
+        dropout=0.0, use_flash_attention=False)), jnp.zeros((1, 8), jnp.int32))
+
+
 def _train(config_extra=None, sp_axis=None, steps=5, batch=4, seq=32,
            lr=1e-2):
     cfg = GPT2Config.tiny(dropout=0.0, use_flash_attention=True,
@@ -22,7 +43,8 @@ def _train(config_extra=None, sp_axis=None, steps=5, batch=4, seq=32,
         "optimizer": {"type": "AdamW", "params": {"lr": lr}},
     }
     config.update(config_extra or {})
-    engine, _, _, _ = deepspeed.initialize(model=model, config_params=config)
+    engine, _, _, _ = deepspeed.initialize(
+        model=model, model_parameters=_gpt2_params(), config_params=config)
     rng = np.random.RandomState(0)
     ids = rng.randint(0, cfg.vocab_size, size=(batch, seq))
     losses = []
@@ -110,7 +132,7 @@ def test_sp_ulysses_mode_matches_serial():
                           sequence_parallel_mode="ulysses")
     model = GPT2LMHeadModel(cfg)
     engine, _, _, _ = deepspeed.initialize(
-        model=model,
+        model=model, model_parameters=_gpt2_params(),
         config_params={
             "train_batch_size": 8,
             "optimizer": {"type": "AdamW", "params": {"lr": 1e-2}},
@@ -173,15 +195,10 @@ def test_sp_pg_correctness_check_passes():
     scale/reduction bugs at the step they occur)."""
     from deepspeed_tpu.runtime import engine as engine_mod
 
-    cfg = GPT2Config.tiny(dropout=0.0, sequence_parallel_axis="seq")
-    engine, _, _, _ = deepspeed.initialize(
-        model=GPT2LMHeadModel(cfg),
-        config_params={
-            "train_batch_size": 8,
-            "optimizer": {"type": "AdamW", "params": {"lr": 1e-2}},
-            "sequence_parallel": {"enabled": True, "size": 8},
-        })
-    ids = np.random.RandomState(0).randint(0, cfg.vocab_size, size=(8, 32))
+    # The check reads the engine (a forward, no step): the shared sp=8
+    # baseline engine and its compiled program serve, whatever it trained.
+    engine, _ = _baseline(True, steps=5, batch=8)
+    ids = np.random.RandomState(0).randint(0, 1024, size=(8, 32))
     engine_mod.pg_correctness_test = True
     try:
         loss = engine(ids, ids)  # raises if sharded grads diverge
@@ -193,15 +210,10 @@ def test_sp_pg_correctness_check_passes():
 def test_sp_rejects_indivisible_token_dim():
     """A token dim not divisible by sp must raise — silent down-sharding
     would run the SP model paths on a wrong decomposition."""
-    cfg = GPT2Config.tiny(dropout=0.0, sequence_parallel_axis="seq")
-    engine, _, _, _ = deepspeed.initialize(
-        model=GPT2LMHeadModel(cfg),
-        config_params={
-            "train_batch_size": 8,
-            "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
-            "sequence_parallel": {"enabled": True, "size": 8},
-        })
-    ids = np.random.RandomState(0).randint(0, cfg.vocab_size, size=(8, 33))
+    # Raised while the call is traced, before anything runs: the shared
+    # sp=8 baseline engine serves.
+    engine, _ = _baseline(True, steps=5, batch=8)
+    ids = np.random.RandomState(0).randint(0, 1024, size=(8, 33))
     with pytest.raises(ValueError, match="not\\s+divisible by sp"):
         engine(ids, ids)
 
@@ -212,7 +224,7 @@ def test_sp_composes_with_fp16_and_grad_accumulation():
     shard_map program and must compose with it."""
     cfg = GPT2Config.tiny(dropout=0.0, sequence_parallel_axis="seq")
     engine, _, _, _ = deepspeed.initialize(
-        model=GPT2LMHeadModel(cfg),
+        model=GPT2LMHeadModel(cfg), model_parameters=_gpt2_params(),
         config_params={
             "train_batch_size": 8,
             "train_micro_batch_size_per_gpu": 4,
@@ -276,14 +288,19 @@ def test_bert_sp_loss_matches_serial():
         }
         if sp:
             config["sequence_parallel"] = {"enabled": True, "size": 8}
-        engine, _, _, _ = deepspeed.initialize(
-            model=BertForPreTraining(cfg), config_params=config)
         rng = np.random.RandomState(0)
         ids = rng.randint(0, cfg.vocab_size, size=(8, 32))
         attn_mask = (rng.rand(8, 32) > 0.1).astype(np.int32)
         attn_mask[:, 0] = 1  # keep [CLS]
         labels = np.where(rng.rand(8, 32) < 0.15, ids, -1)
         nsp = rng.randint(0, 2, size=(8,))
+        model = BertForPreTraining(cfg)
+        engine, _, _, _ = deepspeed.initialize(
+            model=model, config_params=config,
+            model_parameters=_fresh(
+                "bert", model, jnp.asarray(ids[:1]),
+                jnp.asarray(attn_mask[:1]), None, jnp.asarray(labels[:1]),
+                jnp.asarray(nsp[:1])))
         losses = []
         for _ in range(3):
             loss = engine(ids, jnp.asarray(attn_mask), None,
@@ -306,16 +323,19 @@ def test_bert_sp_rejects_fused_layer():
 
     cfg = BertConfig.tiny(use_fused_layer=True,
                           sequence_parallel_axis="seq")
+    ids = np.random.RandomState(0).randint(0, cfg.vocab_size, size=(8, 32))
+    labels = np.full((8, 32), -1)
+    labels[:, ::4] = 1
+    model = BertForPreTraining(cfg)
     engine, _, _, _ = deepspeed.initialize(
-        model=BertForPreTraining(cfg),
+        model=model,
+        model_parameters=_fresh("bert-fused", model, jnp.asarray(ids[:1]),
+                                None, None, jnp.asarray(labels[:1]), None),
         config_params={
             "train_batch_size": 8,
             "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
             "sequence_parallel": {"enabled": True, "size": 8},
         })
-    ids = np.random.RandomState(0).randint(0, cfg.vocab_size, size=(8, 32))
-    labels = np.full((8, 32), -1)
-    labels[:, ::4] = 1
     with pytest.raises(ValueError, match="use_fused_layer"):
         engine(ids, None, None, jnp.asarray(labels), None)
 
@@ -335,6 +355,6 @@ def test_sp_eval_loss_matches_train_function():
         engine.train()
 
     serial_model = GPT2LMHeadModel(GPT2Config.tiny(dropout=0.0))
-    serial_loss = float(serial_model.apply(
+    serial_loss = float(jax.jit(serial_model.apply)(
         {"params": jax.device_get(engine.params)}, ids, ids))
     np.testing.assert_allclose(sp_loss, serial_loss, rtol=2e-4, atol=2e-4)
