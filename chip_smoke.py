@@ -68,7 +68,7 @@ class Sizes:
 FULL = Sizes(
     gpt2="gpt2_medium", batch=8, seq=1024, train_steps=4,
     layer_hidden=1024, layer_heads=16, layer_seq=512, layer_batch=8,
-    # bench.py's TPU serving settings: 16 slots x 1024, paged KV on, flash
+    # The GPT-2 serving cells' shape: 16 slots x 1024, paged KV on, flash
     # decode left to default_flash_decode().
     serve={"max_slots": 16, "max_len": 1024, "chunk_size": 16,
            "max_queue": 64, "paged_kv": True},

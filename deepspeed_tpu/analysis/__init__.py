@@ -1,7 +1,7 @@
 """graftlint: JAX-contract static analyzer + fleet race detector.
 
 Stdlib-``ast`` only — no new dependencies, safe to import from anywhere
-(including conftest and bench). Entry points:
+(including conftest and bin/lint.sh). Entry points:
 
 - CLI: ``python -m deepspeed_tpu.analysis [paths] [--baseline F]
   [--format text|json]`` (see ``__main__``).

@@ -119,7 +119,7 @@ class InferenceConfig:
     # Perf X-ray (telemetry/xray.py): the compiled-program cost/memory
     # observatory. On (the default), every program call site stashes
     # its shape signature (tens of microseconds, no device touch) and
-    # export paths — perf_xray(), bench artifacts — pay the one-time
+    # export paths — perf_xray(), a traced benchmark run — pay the one-time
     # AOT lower+compile that reads XLA's cost/memory model. Off, no
     # stash, no ledger, no roofline gauges.
     perf_xray: bool = True
@@ -309,7 +309,7 @@ class InferenceConfig:
     def resolved_spec_decode(self):
         """The effective speculative-decoding switch: the explicit field
         wins; ``None`` defers to the ``DS_TPU_SPEC_DECODE`` env (any
-        value but ``0``/``false`` turns it on — the bench/driver hook);
+        value but ``0``/``false`` turns it on; only tests set it today);
         the final default is off."""
         if self.spec_decode is not None:
             return bool(self.spec_decode)

@@ -646,7 +646,7 @@ class InferenceEngine(object):
 
         # Perf X-ray (telemetry/xray.py): the compiled-program cost/
         # memory observatory. Step paths stash shape signatures only
-        # (no device touch); export paths — perf_xray(), bench — pay
+        # (no device touch); export paths — perf_xray() itself — pay
         # the one-time AOT lower+compile, which never touches a jit
         # wrapper's dispatch cache and so cannot read as a recompile.
         self._xray = None
@@ -2440,8 +2440,8 @@ class InferenceEngine(object):
         ``reset=True`` additionally OPENS A NEW WINDOW after reading:
         counters, latency/phase histograms, spec accept stats and the
         wall clock all restart, so two successive metrics(reset=True)
-        calls bracket exactly the work between them — how bench's A/B
-        phases isolate warmup from the measured run. ``compile_count``
+        calls bracket exactly the work between them — how a caller
+        isolates warmup from the measured run. ``compile_count``
         and ``recompiles`` are cumulative facts and never reset."""
         now = time.time()
         wall = max(now - self._window_t0, 1e-9)
@@ -2564,8 +2564,8 @@ class InferenceEngine(object):
         if self._hier is not None:
             h = self._hier
             m.update({
-                # Tier switches (stamped into bench results for A/B
-                # attribution) + the capacity story: what a slot costs,
+                # Tier switches (so a reading names the tiers it ran
+                # with) + the capacity story: what a slot costs,
                 # what aliasing saves, and how many sessions the budget
                 # effectively carries (docs/INFERENCE.md).
                 "int8_kv": h.spec.int8,
@@ -2609,8 +2609,8 @@ class InferenceEngine(object):
         return prometheus_text(self.telemetry)
 
     def telemetry_snapshot(self):
-        """The compact observability fingerprint bench stamps into its
-        JSON: the Prometheus snapshot's sha256 + sample-line count,
+        """The compact observability fingerprint (tests are its only
+        caller): the Prometheus snapshot's sha256 + sample-line count,
         exact per-name span counts (ring-wrap-proof), and the
         cumulative compile/recompile facts."""
         sha, lines = prometheus_digest(self.telemetry)

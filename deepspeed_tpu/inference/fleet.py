@@ -1496,7 +1496,7 @@ class ServingFleet(object):
         ``metrics(reset=True)``), never against the per-engine counter
         windows — those belong to the collector and are clobbered on
         every tick. Two successive metrics(reset=True) calls therefore
-        bracket exactly the work between them (how bench scrubs
+        bracket exactly the work between them (how a caller scrubs
         warmup), fleet and single-engine runs alike; with no reset the
         values are since-construction, including dead replicas'
         history."""
@@ -1538,7 +1538,7 @@ class ServingFleet(object):
         keyed by rid — the fleet face of the compiled-program
         observatory. The roofline/HBM GAUGES already flow through the
         merged registry with ``replica`` labels; this is the artifact-
-        shaped view bench and the regression gate consume. Replicas
+        shaped view the regression gate consumes (tests only). Replicas
         with perf_xray off (or failed) contribute None."""
         out = {}
         for rep in self.replicas:
@@ -1553,7 +1553,7 @@ class ServingFleet(object):
 
     def prefix_hit_rate(self):
         """Fleet-wide prefix hit rate (hits / probes, 0.0 when no
-        probes) — the bench A/B's headline number."""
+        probes) — what ``metrics()`` reports as ``prefix_hit_rate``."""
         c = self.counters
         hits = c["prefix_hits"] if "prefix_hits" in c else 0
         misses = c["prefix_misses"] if "prefix_misses" in c else 0

@@ -19,10 +19,10 @@ asks it the questions that matter under LOAD:
   sweep, and the noise-aware A/B gate whose thresholds come from each
   run's own per-window variance.
 
-``bench.py --sustained`` wires the whole stack end to end (a ``--smoke``
-variant runs on CPU in CI) and ``bench.py --chaos-smoke`` does the same
-with one injected fatal step fault, asserting the recovery invariant;
-docs/BENCHMARKING.md is the methodology page.
+``tests/unit/test_loadgen.py`` wires the whole stack end to end on a tiny
+CPU engine, and again with one injected fatal step fault, asserting the
+recovery invariant. Tests are this package's only callers: the repo's
+benchmark (``benchmark/``) drives the engines with its own drivers.
 """
 
 from deepspeed_tpu.loadgen.report import (

@@ -7,8 +7,8 @@ want back (heavy-tail lognormal / Zipf mixes — production length
 distributions are long-tailed, and a harness that offers uniform
 lengths never sees the head-of-line effects the tail causes), and what
 the prompt tokens actually are (repetition-heavy phrase tiling by
-default, so n-gram speculative drafting has self-matches to find — the
-same choice ``bench.py --serve`` makes).
+default, so n-gram speculative drafting has self-matches to find, as
+prompts that quote themselves do).
 
 Everything is FULLY DETERMINISTIC per ``seed``: two calls to
 ``spec.requests()`` — on different days, different machines — produce
@@ -252,7 +252,7 @@ class WorkloadSpec:
         phrase draw). Deterministic per ``seed`` like every spec —
         same-seeded calls produce byte-identical streams. Tests override
         geometry down (prefix_tokens, prompt bounds) to fit tiny-engine
-        max_len; the defaults fit the serve-bench engine."""
+        max_len; the defaults fit a 16-slot x 1024 engine."""
         params = dict(
             arrival="poisson",
             rate=8.0,
@@ -288,7 +288,7 @@ class WorkloadSpec:
         arrives faster than prefill drains measures only the queue.
         Output budgets stay modest (summarization shape: huge context
         in, short answer out). Tests override geometry down to fit
-        tiny-engine max_len; the defaults fit the serve-bench engine."""
+        tiny-engine max_len; the defaults fit a 16-slot x 1024 engine."""
         params = dict(
             arrival="poisson",
             rate=1.0,

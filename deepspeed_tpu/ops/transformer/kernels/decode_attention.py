@@ -547,7 +547,7 @@ def _autotuned_block(shape, dtype, cands, default, arrays=None,
     """Consult the autotuner for a decode block size. ``arrays`` (operand
     concrete values: q, k, v for the fp family; q, codes, codes, scales,
     scales for q8) enables an online sweep under DS_TPU_AUTOTUNE; without
-    them (traced engine calls, bench stamping) only the bundled/user
+    them (traced engine calls, ``planned_block_k``) only the bundled/user
     tables are consulted. The sweep times the WORST-CASE frontier
     (pos = t - s: every block active) so the tuned tile is the one the
     end of a long generation runs on."""
@@ -587,7 +587,7 @@ def _autotuned_block(shape, dtype, cands, default, arrays=None,
 
 def planned_block_k(b, h, s, t_kv, d, dtype):
     """Table-or-default block_k for a decode shape WITHOUT running a sweep
-    (bench stamping / observability). None when the kernel cannot take the
+    (observability; tests are its only caller). None when it cannot take the
     shape at all."""
     if not decode_supported(t_kv):
         return None

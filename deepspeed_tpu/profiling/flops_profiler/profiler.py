@@ -9,8 +9,8 @@ has two sources of truth:
 - **exact program cost**: ``observe(jitted_fn, *args)`` is a thin client of
   the perf-xray ProgramRegistry (telemetry/xray.py — the one place that
   does AOT lower+compile and reads ``Compiled.cost_analysis()``), so the
-  profiler's totals, the engine's roofline gauges, and bench's perf_xray
-  artifact section all come from the same records. The cost covers the real
+  profiler's totals, the engine's roofline gauges, and the ``perf_xray()``
+  section a report carries all come from the same records. The cost covers the real
   training program the engine ran — backward pass and fusion effects
   included, which the reference's functional-level MAC counting cannot see;
 - **per-module breakdown**: flax's interpreter-mode tabulation

@@ -408,8 +408,8 @@ class PipelineEngine(DeepSpeedEngine):
         """Pre-compiled (forward, backward) pair for the training path.
 
         Calling ``jax.vjp`` eagerly per micro-batch re-traces the stage on
-        every ForwardPass (measured ~3 ms of host time per instruction on
-        tests/perf/pipe_dispatch_profile.py) and the returned closure then
+        every ForwardPass (~3 ms of host time per instruction when it was
+        profiled, round 2, CPU) and the returned closure then
         executes the transposed jaxpr op-by-op on every BackwardPass —
         host-bound dispatch that caps pipeline MFU. Instead both
         directions are compiled ONCE per stage: the forward is the plain

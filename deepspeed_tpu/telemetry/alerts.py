@@ -5,7 +5,7 @@ the different question "are we burning error budget fast enough that
 the SLO will be gone before a human looks?". ``AlertRule`` encodes that
 as data and ``AlertManager`` evaluates every rule once per closed
 window — no extra sampling thread, no second clock: the collector's
-windows (the same records bench and loadgen report) are the only input.
+windows (the same records a loadgen report carries) are the only input.
 
 Three rule kinds cover the serving stack's failure shapes:
 
@@ -209,7 +209,7 @@ def default_rules(ttft_budget_s=1.0, itl_budget_s=0.25, objective=0.95,
     burn, queue saturation, breaker-opens, handoff-fallback rate, and
     HBM pressure (the xray ledger's 0..1 fill gauge; it reads 0 when
     capacity is unknown — a CPU round can never fire it). Every knob
-    has a keyword so bench and tests can tighten them into firing
+    has a keyword so a caller or a test can tighten them into firing
     range without inventing rule syntax."""
     return [
         AlertRule("ttft_burn", "burn_rate", "ttft_seconds",

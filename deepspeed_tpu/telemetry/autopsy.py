@@ -23,7 +23,7 @@ into the structured answer an operator actually asks for:
   events are missing from every gathered ring. A non-empty list means
   the autopsy is INCOMPLETE (ring overflow — check the
   ``trace_spans_dropped`` counter), and the failover-chain assertions
-  in bench refuse to pass on it.
+  of tests/unit/test_distributed_trace.py refuse to pass on it.
 
 ``FrontDoor.explain(hid)`` / ``fleet.explain(fid)`` /
 ``engine.explain(rid)`` are thin wrappers: resolve the handle to its

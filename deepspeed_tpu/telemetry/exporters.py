@@ -101,7 +101,7 @@ def prometheus_text(registry):
 
 def prometheus_digest(registry):
     """(sha256-hex, line count) of the snapshot — the cheap fingerprint
-    bench stamps into its JSON so a reviewer can tell two runs exported
+    of ``engine.telemetry_snapshot()``, which tells that two runs exported
     identical metric SHAPES without shipping the whole text."""
     text = prometheus_text(registry)
     return (hashlib.sha256(text.encode()).hexdigest(),

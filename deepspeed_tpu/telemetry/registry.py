@@ -1,7 +1,7 @@
 """Metrics registry — counters, gauges, bounded-reservoir histograms.
 
 The one telemetry surface the training engine, the serving engine and
-bench all emit into (the reference ships SynchronizedWallClockTimer /
+the fleet all emit into (the reference ships SynchronizedWallClockTimer /
 ThroughputTimer / tensorboard_* config keys as separate ad-hoc sinks;
 here every number lands in ONE registry and the exporters — Prometheus
 text, TensorBoard scalars, Chrome traces — read it back out).

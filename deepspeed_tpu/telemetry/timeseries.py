@@ -26,7 +26,7 @@ Design constraints, matching the rest of the telemetry package:
   a 5-window-long GC pause shows up as one 5x-duration window with its
   real (degraded) stats — which is the honest shape of a stall.
 
-Export: ``windows()`` / ``to_json()`` for the bench report, and
+Export: ``windows()`` / ``to_json()`` for the loadgen report, and
 ``chrome_counter_events()`` — Chrome trace "C" (counter) events that
 load into Perfetto alongside the SpanRecorder's span export, so the
 queue-depth curve sits under the request tracks that caused it.

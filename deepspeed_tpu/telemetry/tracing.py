@@ -18,7 +18,7 @@ export shapes read the same ring:
 The ring is a ``collections.deque(maxlen=capacity)``: memory is bounded
 whatever the run length, and the newest events win (a flight recorder
 keeps the crash, not the boot). Span counts per name are tracked
-EXACTLY (counters, not ring occupancy) so bench can report how many
+EXACTLY (counters, not ring occupancy) so a reader can tell how many
 spans each phase emitted even after the ring wrapped.
 
 ONE CALL, TWO SINKS. ``timed(name, **args)`` and ``instant(name, **args)``

@@ -92,7 +92,7 @@ RECOMPILE_EVENT_CAP = 64
 
 # Peak compute / memory bandwidth per chip, keyed by the string the
 # device reports as ``jax.devices()[0].device_kind``. This is the ONE
-# peaks table: the roofline gauges here and bench.py's MFU both read it.
+# peaks table: the roofline gauges here and benchmark/costs.py read it.
 # A device kind that is not in the table is an error where a utilization
 # is asked for (``device_peaks``) — no chip is given another chip's row.
 DEVICE_PEAKS = {

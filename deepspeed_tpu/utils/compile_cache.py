@@ -4,7 +4,7 @@ The cache key includes the directory, so a directory that moves never
 hits: the path is either the one ``JAX_COMPILATION_CACHE_DIR`` names (JAX
 reads that variable itself, so nothing is set here) or one fixed path
 inside the checkout — never one built from a temporary name, a process id
-or the time. The root scripts (``chip_smoke.py``, ``bench.py``) call
+or the time. ``chip_smoke.py`` and the benchmark's drivers call
 ``place_compile_cache()`` before their first compile; ``tests/conftest.py``
 keeps the cache off.
 """

@@ -28,7 +28,7 @@ def create_logger(name=None, level=logging.INFO):
     if not logger_.handlers:
         formatter = logging.Formatter(
             "[%(asctime)s] [%(levelname)s] [%(name)s] %(message)s")
-        # stderr, so programmatic stdout (e.g. bench.py's JSON line) stays clean
+        # stderr, so programmatic stdout (a benchmark run's result line) stays clean
         handler = logging.StreamHandler(stream=sys.stderr)
         handler.setLevel(level)
         handler.setFormatter(formatter)

@@ -1,8 +1,8 @@
 """Flash-attention kernel microbenchmark — per-layer fwd+bwd time at GPT-2
 shapes, vs the dense-XLA path and the MXU-ideal bound.
 
-Feeds the component table in docs/PERF.md (the TPU analogue of the
-reference's csrc/transformer timer sweep). Timing scans REPS steps inside
+The TPU analogue of the reference's csrc/transformer timer sweep; PR 45's
+readings of it are in the root PERF.md, section 6. Timing scans REPS steps inside
 one jit and fetches a scalar, so dispatch is amortized and the fetch waits
 for all of them.
 

@@ -1,4 +1,4 @@
-"""Re-sweep the kernel tile autotuner on the bench shapes and refresh the
+"""Re-sweep the kernel tile autotuner on the GPT-2 shapes and refresh the
 bundled table.
 
 The bundled table (`deepspeed_tpu/ops/autotune_table.json`) was swept
@@ -50,12 +50,12 @@ from deepspeed_tpu.ops.transformer.kernels.decode_attention import (
     decode_signature, flash_decode_attention, flash_decode_attention_q8,
     quantize_kv)
 
-# (batch, seq) grid — matches bench.py --sweep; heads/dim are GPT-2
+# (batch, seq) grid; heads/dim are GPT-2
 # medium's (the autotune signature keys on the full shape).
 DEFAULT_SHAPES = "b8t1024,b12t1024,b16t1024,b4t2048,b8t2048,b2t4096,b4t4096"
 
-# (slots[, q_len], cache plane len) decode grid — bench.py --serve runs
-# 16 slots at a 1024-position pool; the longer planes cover larger
+# (slots[, q_len], cache plane len) decode grid — the GPT-2 serving
+# cells run 16 slots at a 1024-position pool; the longer planes cover larger
 # serving configs. No sNN means s=1 (the decode scan's query shape);
 # the b1sNN entries are the chunked-prefill APPEND shapes — the engine's
 # mixed step appends a [1, prefill_chunk] prompt slice through the same

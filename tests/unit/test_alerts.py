@@ -16,8 +16,8 @@ The contract under test:
 
 Windows are hand-driven (manual clocks everywhere) — no sleeps, no
 timing sensitivity; the fleet-integration path (a rule firing under a
-real saturating load and auto-dumping) lives in bench.py --fleet-smoke
-and tests/unit/test_distributed_trace.py.
+real saturating load and auto-dumping) lives in
+tests/unit/test_distributed_trace.py.
 """
 
 import pytest

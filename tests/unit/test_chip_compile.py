@@ -269,7 +269,7 @@ def _sparse(q, k, v):
     return block_sparse_attention(q, k, v, np.asarray(layout), 64)
 
 
-SPARSE_QKV = ((2, 16, 4096, 64), BF16)   # bench.py's BERT-large sparse shape
+SPARSE_QKV = ((2, 16, 4096, 64), BF16)   # the BERT-large sparse shape
 LN_X = ((8, 512, 1024), BF16)
 VEC = ((1024,), F32)
 

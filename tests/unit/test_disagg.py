@@ -348,11 +348,13 @@ def _ab_model():
     return _AB_MODEL["m"]
 
 
-def _ab_run(roles):
-    """One warmed run of a 24-request burst; returns (result, report,
-    steps) where ``steps[replica]`` lists ``(prefill_tokens,
+def _ab_run(roles, **stream):
+    """One warmed run of a 24-request stream (one burst unless
+    ``stream`` says otherwise); returns (result, report, steps,
+    handoffs_in) where ``steps[replica]`` lists ``(prefill_tokens,
     active_slots)`` of every ``inference/mixed_step`` span that replica
-    recorded, warmup included. Long prompts against a small prefill
+    recorded, warmup included, and ``handoffs_in`` counts the planes the
+    decode side adopted during the run. Long prompts against a small prefill
     chunk give every prompt eight prefill steps; all 24 arriving at once
     puts several prompts on every replica that takes prompts, so prefill
     chunks and decoding slots must share steps wherever one replica
@@ -361,11 +363,12 @@ def _ab_run(roles):
     serve_cfg = {"max_slots": 4, "max_len": 128, "chunk_size": 2,
                  "prefill_chunk": 8, "max_queue": 128,
                  "trace_ring": 1 << 16}
-    spec = WorkloadSpec(arrival="burst", burst_size=24, n_requests=24,
-                        prompt_dist="fixed", prompt_mean=64,
-                        prompt_max=64, output_dist="fixed",
-                        output_mean=32, output_max=32,
-                        vocab_size=cfg.vocab_size, seed=23)
+    spec_kw = dict(arrival="burst", burst_size=24, n_requests=24,
+                   prompt_dist="fixed", prompt_mean=64, prompt_max=64,
+                   output_dist="fixed", output_mean=32, output_max=32,
+                   vocab_size=cfg.vocab_size, seed=23)
+    spec_kw.update(stream)
+    spec = WorkloadSpec(**spec_kw)
     fleet = ServingFleet(model, params, n_replicas=3, config=serve_cfg,
                          window_seconds=0.1, seed=0, roles=roles,
                          idle_wait_s=0.002)
@@ -378,9 +381,12 @@ def _ab_run(roles):
         assert fleet.wait_idle(timeout_s=300.0)
         assert all(c == 1 for c in fleet.compile_counts.values())
         fleet.metrics(reset=True)
+        adopted = int(fleet.counters["handoffs_in"])
         runner = SustainedRunner(fleet, spec, window_seconds=0.1,
                                  max_steps=500_000)
         result = runner.run()
+        adopted = int(fleet.counters["handoffs_in"]) - adopted
+        assert fleet.health == "healthy"
         report = build_report(spec, result,
                               SLO(ttft_p99_ms=30000.0, itl_p99_ms=10000.0))
         assert result.requests_lost == 0 and result.shed == 0
@@ -394,7 +400,7 @@ def _ab_run(roles):
                 (e["args"]["prefill_tokens"], e["args"]["active_slots"])
                 for e in tracer.events()
                 if e["name"] == "inference/mixed_step"]
-        return result, report, steps
+        return result, report, steps, adopted
     finally:
         fleet.close()
 
@@ -407,8 +413,8 @@ def test_disagg_decode_steps_never_carry_a_prefill_chunk():
     runs steps that carry both — the interference disaggregation
     removes. (What that does to decode ITL p99 is a latency and belongs
     to a chip cell: PERF.md section 7.)"""
-    on_res, on_rep, on_steps = _ab_run(("prefill", "decode", "decode"))
-    off_res, off_rep, off_steps = _ab_run(None)
+    on_res, on_rep, on_steps, _ = _ab_run(("prefill", "decode", "decode"))
+    off_res, off_rep, off_steps, _ = _ab_run(None)
     assert all(on_steps[r] for r in (0, 1, 2))
     assert all(active == 0 for _, active in on_steps[0])
     # Every prompt token, the six warmup prompts' included, went through
@@ -436,33 +442,23 @@ def test_disagg_decode_steps_never_carry_a_prefill_chunk():
     assert off_rep["disagg"]["handoffs"] == 0
 
 
-# ------------------------------------------------- bench end to end
+# ------------------------------------------- an open-loop stream, by counts
 
 
-def test_bench_disagg_smoke_report():
-    """bench's --fleet-smoke --disagg path in-process: the 1 prefill +
-    2 decode CPU run stamps ITL percentiles + handoff counters and
-    asserts its own soundness (zero lost, no fallbacks, one compile per
-    replica)."""
-    import importlib.util
-    import json
-    import os
-
-    path = os.path.join(os.path.dirname(__file__), "..", "..", "bench.py")
-    spec = importlib.util.spec_from_file_location("ds_bench_disagg", path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    result = bench._measure_disagg(smoke=True, disagg=True)
-    json.dumps(result)                        # the emitted line is JSON
-    assert result["metric"] == "gpt2_tiny_smoke_disagg_decode_itl_p99_ms"
-    assert result["value"] > 0
-    extra = result["extra"]
-    assert extra["disagg"] is True
-    assert extra["roles"] == ["prefill", "decode", "decode"]
-    assert extra["requests_lost"] == 0
-    assert extra["handoffs"] == extra["handoffs_in"] == 24
-    assert extra["handoff_fallbacks"] == 0
-    assert extra["handoff_bytes_shipped"] > 0
-    assert extra["compile_counts"] == {"0": 1, "1": 1, "2": 1}
-    assert extra["disagg_report"]["handoffs"] == 24
+def test_disagg_poisson_stream_hands_every_request_off_once():
+    """Where the A/B above offers one burst, arrivals here are spread
+    over the run (Poisson at 60 a second, 32-token prompts, 24-token
+    answers), so hand-offs leave the prefill replica while earlier
+    planes are still being adopted and decoded. Every one of the 24
+    requests leaves once and is adopted once (out == in), none falls
+    back to a re-prefill, none is lost, and each replica is still on
+    its one program (``_ab_run`` holds that, and the fleet's health at
+    exit)."""
+    res, rep, steps, adopted = _ab_run(
+        ("prefill", "decode", "decode"), arrival="poisson", rate=60.0,
+        prompt_mean=32, prompt_max=48, output_mean=24, output_max=24)
+    assert res.submitted == res.completed == 24
+    assert res.handoffs == adopted == 24
+    assert res.handoff_fallbacks == 0 and res.handoff_bytes_shipped > 0
+    assert rep["disagg"]["handoffs"] == 24
+    assert all(n == 0 for r in (1, 2) for n, _ in steps[r])

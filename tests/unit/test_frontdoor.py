@@ -20,13 +20,17 @@ The contract under test:
    hypothetical latency arrival still meets headroom * budget.
 5. OBSERVABILITY — per-class/per-tenant counters in metrics() and in
    the Prometheus exposition (parser-level, labelled).
-6. ACCEPTANCE — bench's --frontdoor-smoke A/B in-process: front door
-   ON holds the interactive p99 TTFT budget while batch saturates
-   (zero lost, compile_count 1); the SAME workload with the front door
-   OFF violates it (head-of-line FIFO burial).
+6. ACCEPTANCE — one mixed-tenant flood, counted in engine steps under
+   a clock that moves only when the engine does: behind the front door
+   every interactive request has its first token within a stated
+   number of steps while batch saturates (zero lost, compile_count 1);
+   the SAME workload on the bare engine leaves the first interactive
+   request behind the whole flood (head-of-line FIFO burial).
 """
 
 import collections
+import math
+import time
 
 import pytest
 
@@ -40,6 +44,12 @@ from deepspeed_tpu.inference import (
 )
 from deepspeed_tpu.inference.frontdoor import AdmissionController, TokenBucket
 from deepspeed_tpu.inference.scheduler import RETRY_AFTER_CAP_S
+from deepspeed_tpu.loadgen import (
+    SLO,
+    SustainedRunner,
+    WorkloadSpec,
+    build_report,
+)
 from tests.unit.test_chunked_prefill import (
     engine_of,
     make_model,
@@ -610,43 +620,129 @@ def test_stream_for_existing_handle_and_context_manager():
 # ----------------------------------------------------------- acceptance
 
 
-def _load_bench(tag):
-    import importlib.util
-    import os
+class _StepClock(object):
+    """Time that moves only when the engine steps (one tick a step) or
+    the runner sleeps to its next arrival (whole ticks, rounded up: a
+    float remainder would never be slept off). Under it the flood's
+    arrival schedule, the front door's rates and the engine's stamps
+    are all functions of the step count, on any machine."""
 
-    path = os.path.join(os.path.dirname(__file__), "..", "..", "bench.py")
-    spec = importlib.util.spec_from_file_location(tag, path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    return bench
+    TICK_S = 0.01
+
+    def __init__(self):
+        self.ticks = 0
+
+    def __call__(self):
+        return self.ticks * self.TICK_S
+
+    def sleep(self, dt):
+        self.ticks += max(1, int(math.ceil(dt / self.TICK_S)))
 
 
-def test_bench_frontdoor_smoke_ab_acceptance():
-    """THE acceptance gate: the mixed-tenant workload through the front
-    door holds the interactive p99 TTFT budget while batch saturates
-    (zero lost, one compile) — and the SAME offered load with the front
-    door OFF violates that budget (FIFO head-of-line burial), proving
-    the budget is earned by the front door, not by slack."""
-    import json
+class _StepCounting(object):
+    """The runner's target, passed through: counts ``step()`` calls,
+    and notes at which count each request was submitted and at which
+    its first token had come."""
 
-    bench = _load_bench("ds_bench_frontdoor")
-    on = bench._measure_frontdoor(smoke=True)     # self-asserts the bar
-    json.dumps(on)
-    e = on["extra"]
-    budget = e["budget_ms"]
-    assert e["interactive_ttft_p99_ms"] <= budget
-    assert e["requests_lost"] == 0 and e["compile_count"] == 1
-    rep = e["frontdoor_report"]
-    assert rep["classes"]["interactive"]["slo_attainment"] == 1.0
-    assert rep["classes"]["batch"]["completed"] > 0
+    def __init__(self, target, clock):
+        self._target = target
+        self._clock = clock
+        self.steps = 0
+        self.rows = []
+
+    def __getattr__(self, name):
+        return getattr(self._target, name)
+
+    def submit(self, prompt, **kw):
+        handle = self._target.submit(prompt, **kw)
+        self.rows.append({"priority": kw["priority"], "handle": handle,
+                          "submitted": self.steps, "first_token": None})
+        return handle
+
+    def step(self):
+        out = self._target.step()
+        self.steps += 1
+        self._clock.ticks += 1
+        for row in self.rows:
+            if row["first_token"] is None and row["handle"].tokens:
+                row["first_token"] = self.steps
+        return out
+
+
+# A batch request is 32 tokens at 4 a step: a slot comes free within 8
+# steps. Behind the front door an interactive request waits for that,
+# for its own prefill, and for what the gate let into the engine's
+# queue ahead of it: within 32 steps in all (26 is the most this flood
+# shows). Bare, the first one waits out the flood: 428 steps.
+_FLOOD_FIRST_TOKEN_STEPS = 32
+
+
+@pytest.mark.parametrize("frontdoor", [True, False],
+                         ids=["frontdoor", "bare_engine"])
+def test_flood_interactive_first_token_by_steps(frontdoor, monkeypatch):
+    """THE acceptance A/B, in engine steps: two tenants each flood two
+    slots with 60 batch requests of 32 tokens while 8 interactive
+    requests of 3 tokens trickle in. Through the front door every
+    interactive request has its first token within
+    ``_FLOOD_FIRST_TOKEN_STEPS`` of its submission and overtakes batch
+    work submitted before it; the SAME offered load on the bare engine
+    leaves the first interactive request behind the whole flood. Either
+    way nothing is lost and the engine stays on its one program."""
+    cfg, model, params = make_model()
+    eng = engine_of(model, params, max_slots=2, max_queue=256,
+                    host_offload=True, swap_slots=8)
+    eng.generate([prompts_of(cfg, [8])[0]], max_new_tokens=2)
+    eng.recompile_detector.mark_warm()
+    eng.metrics(reset=True)
+    clock = _StepClock()
+    # The engine stamps with time.time(): give it the same clock, so
+    # the front door's predictor never subtracts one clock from another.
+    monkeypatch.setattr(time, "time", clock)
+    spec = WorkloadSpec.mixed_tenants(
+        tenants=("tenant_a", "tenant_b"), seed=29,
+        interactive_rate=2.0, interactive_n=8,
+        batch_rate=200.0, batch_ramp_from=200.0, batch_n=60,
+        prompt_dist="lognormal", prompt_mean=6, prompt_min=2,
+        prompt_max=10,
+        interactive_overrides={"output_dist": "fixed", "output_mean": 3},
+        batch_overrides={"output_dist": "fixed", "output_mean": 32},
+        vocab_size=cfg.vocab_size)
+    target = eng
+    if frontdoor:
+        target = FrontDoor(eng, FrontDoorConfig(
+            classes=(
+                PriorityClass("interactive", ttft_budget_ms=1000.0,
+                              weight=4.0, shed_on_budget=False),
+                PriorityClass("batch", weight=1.0, preemptible=True),
+            ),
+            tenants=(TenantPolicy("tenant_a"), TenantPolicy("tenant_b")),
+            batch_headroom=0.25), clock=clock, sleep=clock.sleep)
+    counted = _StepCounting(target, clock)
+    res = SustainedRunner(counted, spec, window_seconds=0.25,
+                          max_steps=100_000, clock=clock,
+                          sleep=clock.sleep).run()
+    no_budget = SLO(ttft_p99_ms=None, itl_p99_ms=None)
+    rep = build_report(spec, res, no_budget,
+                       class_slos={"interactive": no_budget,
+                                   "batch": no_budget})["frontdoor"]
+
+    assert res.requests_lost == 0 and res.shed == 0
+    assert target.metrics()["compile_count"] == 1
+    assert rep["classes"]["batch"]["completed"] == 120
+    assert rep["classes"]["interactive"]["completed"] == 16
     assert set(rep["tenants"]) == {"tenant_a", "tenant_b"}
 
-    off = bench._measure_frontdoor(smoke=True, frontdoor=False)
-    json.dumps(off)
-    oe = off["extra"]
-    assert off["metric"].endswith("_nofrontdoor_interactive_ttft_p99_ms")
-    assert oe["requests_lost"] == 0 and oe["compile_count"] == 1
-    # The violation the A/B exists to show.
-    assert oe["interactive_ttft_p99_ms"] > budget
-    orep = oe["frontdoor_report"]
-    assert orep["classes"]["interactive"]["slo_attainment"] < 1.0
+    batch = [r for r in counted.rows if r["priority"] == "batch"]
+    inter = [r for r in counted.rows if r["priority"] == "interactive"]
+    waits = [r["first_token"] - r["submitted"] for r in inter]
+    # The first interactive request arrives with the whole flood
+    # already submitted: how much of it is served a first token first?
+    first = inter[0]
+    assert all(b["submitted"] <= first["submitted"] for b in batch)
+    ahead = sum(1 for b in batch if b["first_token"] <= first["first_token"])
+    if frontdoor:
+        assert max(waits) <= _FLOOD_FIRST_TOKEN_STEPS
+        assert ahead < len(batch) // 4
+    else:
+        assert waits[0] > 10 * _FLOOD_FIRST_TOKEN_STEPS
+        assert ahead == len(batch)

@@ -12,14 +12,15 @@ The contract under test:
 4. GATE — the noise-aware regression gate passes an A/A (identical
    reports) and FAILS an injected 2x TTFT slowdown and a throughput
    drop, in the regression direction only (improvements never flag).
-5. END TO END — bench's --sustained --smoke path produces the promised
-   report schema: >= 3 windows carrying TTFT/ITL percentiles, queue
-   depth, slot occupancy; a non-null max sustainable rate; a passing
-   A/A self-check (the ISSUE acceptance criteria).
+5. END TO END — a run, a saturation sweep and the gate on one warm
+   engine produce the promised report schema: >= 3 windows carrying
+   TTFT/ITL percentiles, queue depth, slot occupancy; a non-null max
+   sustainable rate; a passing A/A self-check.
 6. CHAOS — the runner arms a FaultPlan mid-run, the engine recovers,
    and the report's ``chaos`` section shows requests_lost == 0 with a
    finite recovery time and the SLO attainment split during/outside
-   recovery; bench's --chaos-smoke path asserts the same in-process
+   recovery; the rebuild keeps the one compiled program and the
+   interrupted request's autopsy reads lost-then-replayed
    (tests/unit/test_resilience.py owns the bit-identity half of the
    recovery invariant).
 """
@@ -626,42 +627,70 @@ def test_saturation_sweep_reports_knee():
     assert flags == [(8, True), (16, True), (24, False)]
 
 
-# ------------------------------------------------------- bench end to end
+# --------------------------------------------- the report, whole, by shape
 
 
-def test_bench_sustained_smoke_report():
-    """The ISSUE acceptance criteria, asserted on bench's own smoke
-    path in-process: >= 3 windows each carrying TTFT/ITL percentiles,
-    queue depth and slot occupancy; a non-null max sustainable rate; a
-    passing A/A gate self-check."""
-    import importlib.util
-    import os
+def test_sustained_report_windows_sweep_and_gate_self_check():
+    """One warm engine (int8 cache, prefix cache, host offload on)
+    through everything a sustained report is made of: a 48-request
+    Poisson run with an alert manager riding the runner's collector,
+    a three-rate saturation sweep on the same engine, the engine's
+    cost model, and the gate held against the report itself. Budgets
+    are off (``None``), so attainment is the share that completed and
+    nothing here turns on how fast this machine is."""
+    from deepspeed_tpu.telemetry import AlertManager, default_rules
 
-    path = os.path.join(os.path.dirname(__file__), "..", "..", "bench.py")
-    spec = importlib.util.spec_from_file_location("ds_bench_sust", path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
+    cfg, model, params = make_model()
+    engine = engine_of(model, params, max_slots=4, max_queue=64,
+                       int8_kv=True, host_offload=True, prefix_cache=True,
+                       prefix_slots=4, prefix_len=16, min_prefix_len=4)
+    _warm(engine)
+    slo = SLO(ttft_p99_ms=None, itl_p99_ms=None)
+    base = dict(arrival="poisson", rate=60.0, n_requests=48,
+                prompt_dist="lognormal", output_dist="lognormal",
+                output_min=2, prefix_pool=2, prefix_tokens=8,
+                vocab_size=cfg.vocab_size, seed=17)
+    fired = []
 
-    result = bench._measure_sustained(smoke=True)
-    json.dumps(result)                        # the emitted line is JSON
-    assert result["unit"] == "tokens/s/chip"
-    assert result["value"] > 0
-    rep = result["extra"]["sustained"]
+    def run_spec(**kw):
+        spec = _spec(**dict(base, **kw))
+        runner = SustainedRunner(engine, spec, window_seconds=0.1,
+                                 max_steps=500_000)
+        runner.alerts = AlertManager(
+            runner.collector, default_rules(queue_saturation=64))
+        res = runner.run()
+        assert res.alerts_fired == runner.alerts.fired()
+        fired.extend(res.alerts_fired)
+        return build_report(spec, res, slo, platform="cpu")
+
+    rep = run_spec()
+    rep["saturation"] = saturation_sweep(
+        lambda rate: run_spec(rate=rate, n_requests=16,
+                              seed=int(rate) + 1000),
+        (30.0, 60.0, 120.0), attainment_floor=0.5)
+    rep["perf_xray"] = engine.perf_xray()
+    gate = regression_gate(rep, rep)
+    json.dumps(rep)
+
     assert rep["schema_version"] == 7
-    wins = rep["timeseries"]["windows"]
-    carrying = [w for w in wins
+    carrying = [w for w in rep["timeseries"]["windows"]
                 if w["ttft_p99_ms"] is not None
                 and w["itl_p99_ms"] is not None
                 and w["queue_depth"] is not None
                 and w["slot_occupancy"] is not None]
     assert len(carrying) >= 3
-    assert all(w["ttft_p50_ms"] <= w["ttft_p99_ms"] for w in carrying)
-    assert rep["saturation"]["max_sustainable_rate"] is not None
-    assert rep["gate_self_check"]["pass"]
+    assert all(w["ttft_p50_ms"] <= w["ttft_p99_ms"]
+               and w["itl_p50_ms"] <= w["itl_p99_ms"] for w in carrying)
+    assert rep["saturation"]["max_sustainable_rate"] == 120.0
+    assert [s["shed"] for s in rep["saturation"]["rates"]] == [0, 0, 0]
+    assert gate["pass"] and gate["perf_xray"]["pass"]
     # The workload echo + context make the report self-describing.
-    assert rep["workload"]["seed"] == rep["context"]["seed"]
-    assert rep["aggregate"]["completed"] == rep["slo"]["requests"] - \
-        rep["slo"]["shed"]
+    assert rep["workload"]["seed"] == rep["context"]["seed"] == 17
+    assert rep["aggregate"]["completed"] == \
+        rep["slo"]["requests"] - rep["slo"]["shed"] == 48
+    assert engine.metrics()["compile_count"] == 1
+    assert not [f for f in fired if f["rule"] == "queue_saturation"]
+    engine.close()
 
 
 # ----------------------------------------------------------------- chaos
@@ -728,29 +757,48 @@ def test_chaos_section_empty_on_fault_free_run():
     assert chaos["slo_attainment_during_recovery"] is None
 
 
-def test_bench_chaos_smoke_report():
-    """bench.py --chaos-smoke in-process: the run itself asserts the
-    recovery invariant (fault fired, >= 1 recovery, zero lost, compile
-    count unchanged); here we check the emitted JSON shape on top."""
-    import importlib.util
-    import os
+def test_chaos_recovery_keeps_the_program_and_tells_the_request_story():
+    """What the chaos run above does not hold: the rebuild after the
+    fault reuses the ONE compiled program, the report's context echoes
+    the fault plan it was given, and a request the fault interrupted
+    reads in its autopsy as lost-then-replayed, finished, with no gap
+    in its hops."""
+    from deepspeed_tpu.inference import Fault, FaultPlan
+    from deepspeed_tpu.telemetry import build_autopsy
 
-    path = os.path.join(os.path.dirname(__file__), "..", "..", "bench.py")
-    spec = importlib.util.spec_from_file_location("ds_bench_chaos", path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    result = bench._measure_chaos(smoke=True)
-    json.dumps(result)
-    assert result["unit"] == "s"
-    assert result["value"] >= 0
-    extra = result["extra"]
-    assert extra["requests_lost"] == 0
-    assert extra["recoveries"] >= 1 and extra["faults_injected"] >= 1
-    rep = extra["chaos_report"]
+    cfg, model, params = make_model()
+    engine = engine_of(model, params, max_slots=4, max_queue=64,
+                       fault_injection=True)
+    _warm(engine)
+    spec = _spec(arrival="poisson", rate=60.0, n_requests=32,
+                 prompt_dist="lognormal", output_dist="lognormal",
+                 output_mean=8, output_min=4, vocab_size=cfg.vocab_size,
+                 seed=23)
+    plan = FaultPlan(faults=(Fault("raise", step=2),))
+    res = SustainedRunner(engine, spec, window_seconds=0.1,
+                          max_steps=500_000, chaos_plan=plan,
+                          chaos_after_s=0.05).run()
+    rep = build_report(
+        spec, res, SLO(ttft_p99_ms=None, itl_p99_ms=None), platform="cpu",
+        extra={"fault_plan": [[f.kind, f.step] for f in plan.faults]})
+    json.dumps(rep)
     assert rep["schema_version"] == 7
-    assert rep["chaos"]["requests_lost"] == 0
-    assert rep["context"]["fault_plan"]["faults"][0]["kind"] == "raise"
+    assert rep["context"]["fault_plan"] == [["raise", 2]]
+    chaos = rep["chaos"]
+    assert chaos["faults_injected"] == 1 and chaos["recoveries"] >= 1
+    assert chaos["requests_lost"] == 0 and res.completed == 32
+    assert sum(r["replayed"] for r in chaos["recovery_intervals"]) >= 1
+    assert engine.health == "healthy" and engine.idle
+    assert engine.metrics()["compile_count"] == 1
+
+    replayed = sorted({ev["tid"] for ev in engine.tracer.events()
+                       if ev["name"] == "request/replayed"})
+    assert replayed
+    story = build_autopsy(engine.trace_recorders(), replayed[0])
+    assert story["replays"] >= 1 and story["hop_gaps"] == []
+    assert story["terminal"]["cause"] == "done"
+    assert story["terminal"]["lost_then_replayed"]
+    engine.close()
 
 
 @pytest.mark.slow
