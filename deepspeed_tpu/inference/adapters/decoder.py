@@ -19,14 +19,21 @@ publishes ``moe_experts_held``, ``moe_expert_load{expert=i}`` over the held
 experts, ``moe_tokens_routed`` and, for a chip's share of an expert-parallel
 layer, ``moe_tokens_absent`` (choices that fell on experts held elsewhere).
 They count every row the program computes (idle slots decode garbage by
-design), so they read as the program's load, not as requests' tokens.
+design), so they read as the program's load, not as requests' tokens. A
+STACK WITHOUT EXPERTS (``expert_layers`` 0: Jamba2-3B, every feed-forward
+dense) gets no ``aux_moe_*`` channel in its pool and publishes NO ``moe_*``
+gauge: a gauge that read 0 experts held would be a wrong reading, not an
+absent one.
 
-A HYBRID stack (``layer_types`` with ``mamba``, ``kda`` or ``shortconv``
-layers) carries a recurrent state a slot: ``cache_spec().slot_state`` names
+A HYBRID stack (``layer_types`` with ``mamba``, ``kda``, ``shortconv`` or
+``mamba1`` layers) carries a recurrent state a slot:
+``cache_spec().slot_state`` names
 it, the pool allocates and threads it (``kv_pool.py``, A RECURRENT STATE A
 SLOT) and ``observe`` publishes its size as ``ssm_state_bytes`` (ONE gauge
 for any ``slot_state``: a Mamba-2 layer's state-space state or a KDA layer's
-matrix state, each with its convolution tail, 19 to 38 MB a slot; a gated
+matrix state, each with its convolution tail, 19 to 38 MB a slot; a Mamba-1
+layer's ``[16, 5120]`` float32 state and its three-row tail, 9.3 MB a slot
+over Jamba2-3B's 26; a gated
 short convolution's tail, the ONE array a layer of a kind with one state, 72
 KB a slot at LFM2-8B-A1B's nine layers). A small state is refused what a
 large one is: the refusals go by mechanism, not by size, and a two-row tail
@@ -137,6 +144,8 @@ class DecoderAdapter(GPT2Adapter):
                     **self.aux_state())
 
     def aux_state(self):
+        if not self.gcfg.expert_layers:     # nothing routes: no channel
+            return {}
         aux = {"aux_moe_load": jnp.zeros((self.gcfg.held[1],), jnp.float32),
                "aux_moe_routed": jnp.zeros((), jnp.float32)}
         if self.gcfg.experts_held is not None:
@@ -174,18 +183,17 @@ class DecoderAdapter(GPT2Adapter):
 
     def observe(self, snap, registry):
         load = snap.get("aux_moe_load")
-        if load is None:
-            return
-        first, held = self.gcfg.held
-        registry.gauge("moe_experts_held").set(held)
-        for i, v in enumerate(load):
-            registry.gauge("moe_expert_load",
-                           expert=str(first + i)).set(float(v))
-        registry.gauge("moe_tokens_routed").set(
-            float(snap.get("aux_moe_routed", 0.0)))
-        if "aux_moe_absent" in snap:
-            registry.gauge("moe_tokens_absent").set(
-                float(snap["aux_moe_absent"]))
+        if load is not None:
+            first, held = self.gcfg.held
+            registry.gauge("moe_experts_held").set(held)
+            for i, v in enumerate(load):
+                registry.gauge("moe_expert_load",
+                               expert=str(first + i)).set(float(v))
+            registry.gauge("moe_tokens_routed").set(
+                float(snap.get("aux_moe_routed", 0.0)))
+            if "aux_moe_absent" in snap:
+                registry.gauge("moe_tokens_absent").set(
+                    float(snap["aux_moe_absent"]))
         if self.recurrent:
             registry.gauge("ssm_state_bytes").set(
                 len(snap["pos"]) * slot_state_nbytes(self.cache_spec()))

@@ -117,8 +117,10 @@ A RECURRENT STATE A SLOT. A model whose ``cache_spec()`` names
 decoder block's Mamba-2 layers give a ``slot_ssm<j>`` [slots, N, H * P]
 float32 and a ``slot_conv<j>`` [slots, K - 1, conv width] a layer, its KDA
 layers a ``slot_kda<j>`` and a ``slot_kdaconv<j>``, its gated short
-convolutions ONE array a layer, the tail ``slot_shortconv<j>`` [slots, 2, C]:
-a kind names as many as it carries, and nothing here counts them)
+convolutions ONE array a layer, the tail ``slot_shortconv<j>`` [slots, 2, C],
+its Mamba-1 layers a ``slot_sel<j>`` [slots, N, W] float32 (N = 16 on the
+sublanes, the channels on the lanes) and a ``slot_selconv<j>`` [slots, K - 1,
+W]: a kind names as many as it carries, and nothing here counts them)
 holds, beside the k and v planes of the layers that DO hold keys (the planes
 are as deep as those layers only), state with NO position axis: a fixed size
 a slot whatever its context, slot-major so that a slot's share is one
@@ -130,7 +132,8 @@ slot's slice through the lane, and the hierarchy's capture and restore ship
 it with the slot's scalars (``arr[slot]``, like ``pos``). Nothing of the
 stale-cache rule applies to it: there is no position past the frontier to
 hide garbage in, so every program that touches it masks instead
-(``models/mamba2.py``, ``models/kda.py``, ``models/shortconv.py``), and what
+(``models/mamba2.py``, ``models/kda.py``, ``models/shortconv.py``,
+``models/mamba1.py``), and what
 the rule gave for free
 (rollback by not advancing ``pos``, aliased prefixes) is refused for such a
 model (``adapters/decoder.py``).

@@ -58,6 +58,26 @@ rotary, grouped-query and paged at once, at a head of 64: the pool packs two
 stored heads a lane tile while four query heads share each
 (``decode_attention.py``, ``lane_pack`` x grouped-query rows).
 
+And a fifth time Jamba's (``model_type`` ``jamba``: AI21-Jamba2-3B), by a
+fifth kind and two values of fields that exist: ``"mamba1"`` layers run the
+Mamba-1 SELECTIVE SCAN (``models/mamba1.py``: a decay a channel AND a state
+index, a step a channel through a bottleneck of rank ``mamba_dt_rank``, ``B``
+and ``C`` from a projection of the convolved stream, three inner RMSNorms, no
+heads, no gated norm and no matmul form for a prompt; ``mamba_state`` and
+``mamba_conv`` are the fields Mamba-2 reads, ``mamba_expand`` and
+``mamba_dt_rank`` its own); ``dense_layers == n_layer`` is A STACK WITHOUT
+EXPERTS (``expert_layers`` 0: every layer's feed-forward is ``dense_ffn``,
+the tree has no ``moe`` and no router, and the adapter hands its pool no
+``aux_moe_*`` for ``forward`` to count into; ``n_experts``, ``experts_per_token`` and ``expert_width``
+are then read by nothing); ``residual_fp32`` keeps the residual stream in
+float32 (``stream_dtype``: the type a branch is added in, a stack 28 layers
+deep);
+and ``n_kv_head`` 1 is MULTI-QUERY attention, all
+``n_head`` query heads over ONE stored head, with ``rope`` False (20 over one
+of 128: the paged kernels put the 20 beside ``S`` on the sublane axis, and a
+page of one head is small enough that a unit of the decode scan's call joins
+eight, ``decode_attention.py`` ``_pages_per_unit``).
+
 THE ABSORBED FORM IS THE ONE PATH of latent attention, for the lane and the
 scan alike. Per head ``[k_nope_h | v_h] = c_kv W_kvb,h``, so
 ``q_nope_h . k_nope_h(u) = (q_nope_h W_uk,h) . c_kv(u)`` and
@@ -97,7 +117,7 @@ layer has (the two norms, the router, the held experts ``[L, E_held, ..]``,
 kind of mixer over the layers of that kind: ``attn/wqkv [La, C, (H + 2 Hkv) D]``
 (+ the QK norms), ``attn/wo``; ``mamba/...`` [Lm, ..] (``mamba2.init_layer``);
 ``kda/...`` [Lk, ..] (``kda.init_layer``); ``shortconv/...`` [Lc, ..]
-(``shortconv.init_layer``).
+(``shortconv.init_layer``); ``mamba1/...`` [Ls, ..] (``mamba1.init_layer``).
 A latent-attention stack (``kv_lora_rank`` given) keeps the two norms under
 ``layers`` and stacks the rest by kind (``L`` the layers of that kind):
 ``mla/wq_a [L, C, Rq]``,
@@ -111,15 +131,16 @@ them: laid ``[in, out]`` the compiler transposes each whole stack every
 step, 0.9 GB of temporaries at DeepSeek-V3's widths; found by compiling for
 a described v5e); ``dense/w_gate_up [Ld, C, 2 Fd]``, ``w_down [Ld, Fd, C]``
 for the leading dense layers; ``moe/...`` [L - Ld, ..] what ``layers`` holds
-of an expert layer elsewhere, and ``router_bias [L - Ld, E]`` float32.
+of an expert layer elsewhere, and ``router_bias [L - Ld, E]`` float32 (no
+``moe`` at all where ``Ld == L``).
 
 The regions of a trace (``jax.named_scope``, under the caller's
 ``prefill_lane`` / ``decode_scan``): ``embed``; per layer ``attn`` (norm, qkv,
 ``rope``, ``qk_norm``, attention, projection; latent attention: ``q_proj``,
 ``kv_proj``, ``rope``, ``absorb`` (the two per-head products with
 ``W_kvb``), ``o_proj``), ``kv_write``, ``kv_view``,
-or ``mamba`` (``mamba2.mixer``'s words), ``kda`` (``kda.mixer``'s) or
-``shortconv`` (``shortconv.mixer``'s);
+or ``mamba`` (``mamba2.mixer``'s words), ``kda`` (``kda.mixer``'s),
+``shortconv`` (``shortconv.mixer``'s) or ``mamba1`` (``mamba1.mixer``'s);
 ``moe`` holding ``router``,
 ``dispatch``, ``experts``, ``combine`` and ``shared``, or ``mlp`` for a dense
 layer; then ``lm_head``.
@@ -138,7 +159,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.analysis.annotations import hot_path
-from deepspeed_tpu.models import generation, kda, mamba2, shortconv
+from deepspeed_tpu.models import generation, kda, mamba1, mamba2, shortconv
 from deepspeed_tpu.moe import routed
 
 
@@ -177,8 +198,8 @@ class DecoderConfig(typing.NamedTuple):
     shared_width: int = 0                      # 0: no shared expert
     # (first, count) of the router's experts this chip holds; None: all
     experts_held: typing.Optional[typing.Tuple[int, int]] = None
-    # "attention" | "mamba" | "kda" | "shortconv" a layer; None: attention
-    # everywhere
+    # "attention" | "mamba" | "kda" | "shortconv" | "mamba1" a layer; None:
+    # attention everywhere
     layer_types: typing.Optional[typing.Tuple[str, ...]] = None
     mamba_heads: int = 0
     mamba_head_dim: int = 0
@@ -208,6 +229,25 @@ class DecoderConfig(typing.NamedTuple):
     # of its own: a kind's ``state_shapes`` reads its own width, and a stack
     # may hold two kinds (Kimi's KDA at 4, LFM2's at 3).
     shortconv_kernel: int = 3
+    # The Mamba-1 selective scan (``models/mamba1.py``), where ``layer_types``
+    # has it: ``mamba_expand * hidden_size`` channels and the rank of the
+    # step's bottleneck. ``mamba_state`` and ``mamba_conv`` are Mamba-2's
+    # fields: no stack holds both kinds.
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    # The residual stream in float32 (``stream_dtype``; Mamba's reference
+    # code calls it ``residual_in_fp32``): matrices and matmul inputs stay
+    # in ``dtype``. A stack tens of layers deep rounds a ``dtype`` stream
+    # twice a layer. False: the stream is in ``dtype``.
+    residual_fp32: bool = False
+
+    @property
+    def stream_dtype(self):
+        """The type of the residual stream, and so of the sums a dense
+        feed-forward forms for it: its two matmuls emit in this type
+        (``dense_mix``), and every branch is cast to it as it is added
+        (``_residual``)."""
+        return jnp.dtype(jnp.float32 if self.residual_fp32 else self.dtype)
 
     @property
     def n_embd(self):
@@ -241,6 +281,17 @@ class DecoderConfig(typing.NamedTuple):
     @property
     def shortconv_layers(self):
         return tuple(i for i, k in enumerate(self.kinds) if k == "shortconv")
+
+    @property
+    def mamba1_layers(self):
+        return tuple(i for i, k in enumerate(self.kinds) if k == "mamba1")
+
+    @property
+    def expert_layers(self):
+        """How many layers route to experts: every one past the leading
+        ``dense_layers``. 0 (``dense_layers == n_layer``) is a stack WITHOUT
+        experts: no ``moe`` tree, no router, no ``aux_moe_*``."""
+        return self.n_layer - self.dense_layers
 
     @property
     def held(self):
@@ -277,10 +328,11 @@ class DecoderConfig(typing.NamedTuple):
 # keys (``layer_types``; the region of a trace and the parameters' tree take
 # the kind's name), each a module with ``state_shapes(cfg)``, ``state_keys(j)``
 # (the arrays layer ``j`` of the kind carries a row, ANY number of them: two
-# for Mamba-2 and KDA, a state and a tail; one for the short convolution),
-# ``init_layer(key, cfg)`` and ``mixer(p, cfg, h, *states, pos, n_valid)`` ->
-# ``(out, *states)``.
-RECURRENT = {"mamba": mamba2, "kda": kda, "shortconv": shortconv}
+# for Mamba-2, KDA and Mamba-1, a state and a tail; one for the short
+# convolution), ``init_layer(key, cfg)`` and ``mixer(p, cfg, h, *states, pos,
+# n_valid)`` -> ``(out, *states)``.
+RECURRENT = {"mamba": mamba2, "kda": kda, "shortconv": shortconv,
+             "mamba1": mamba1}
 
 
 class CacheSpec(typing.NamedTuple):
@@ -405,7 +457,8 @@ def init_params(key, cfg):
         params["attn"] = jax.lax.map(
             lambda k: attention(*jax.random.split(k)), jax.random.split(
                 jax.random.fold_in(key, 3), len(cfg.kv_layers)))
-    for kind, salt in (("mamba", 4), ("kda", 9), ("shortconv", 10)):
+    for kind, salt in (("mamba", 4), ("kda", 9), ("shortconv", 10),
+                       ("mamba1", 11)):
         if kind in cfg.kinds:
             params[kind] = stacked(
                 lambda k, kind=kind: RECURRENT[kind].init_layer(k, cfg),
@@ -414,7 +467,8 @@ def init_params(key, cfg):
         params["mla"] = stacked(latent, 6, len(cfg.kv_layers))
     if cfg.dense_layers:
         params["dense"] = stacked(dense, 7, cfg.dense_layers)
-        params["moe"] = stacked(experts, 8, cfg.n_layer - cfg.dense_layers)
+        if cfg.expert_layers:
+            params["moe"] = stacked(experts, 8, cfg.expert_layers)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = normal(k_head, (c, cfg.vocab_size))
     return params
@@ -639,13 +693,17 @@ def moe(layer, cfg, x, chosen=None):
 
 
 def dense_mix(layer, cfg, h):
-    """What a leading dense layer's feed-forward ADDS to the stream, from
-    the normed stream ``h`` [.., C] in ``cfg.dtype``: the gated form at
-    ``dense_width``, every token."""
+    """What a dense layer's feed-forward ADDS to the stream, from the normed
+    stream ``h`` [.., C] in ``cfg.dtype``: the gated form at ``dense_width``,
+    every token. Both matmuls take ``cfg.dtype`` and emit the stream's type
+    (``cfg.stream_dtype``), so the gated product is formed in it."""
     f = cfg.dense_width
-    gu = h @ layer["w_gate_up"].astype(cfg.dtype)
-    return (jax.nn.silu(gu[..., :f]) * gu[..., f:]) \
-        @ layer["w_down"].astype(cfg.dtype)
+    gu = jnp.matmul(h, layer["w_gate_up"].astype(cfg.dtype),
+                    preferred_element_type=cfg.stream_dtype)
+    return jnp.matmul(
+        (jax.nn.silu(gu[..., :f]) * gu[..., f:]).astype(cfg.dtype),
+        layer["w_down"].astype(cfg.dtype),
+        preferred_element_type=cfg.stream_dtype)
 
 
 def dense_ffn(layer, cfg, x):
@@ -667,7 +725,8 @@ def forward(params, cfg, ids, cache, attn_name=None):
 
     A model with ``RECURRENT`` layers reads and returns the rows' recurrent
     state (``slot_ssm<j>`` / ``slot_conv<j>``, ``slot_kda<j>`` /
-    ``slot_kdaconv<j>``, ``slot_shortconv<j>``: what the kind's
+    ``slot_kdaconv<j>``, ``slot_shortconv<j>``, ``slot_sel<j>`` /
+    ``slot_selconv<j>``: what the kind's
     ``state_keys`` names) and ``cache['n_valid']`` [B]: how many leading
     columns of each row are real, 0 for a row that must not move (default:
     all ``S``). The key is consumed here. A cache that carries
@@ -683,6 +742,7 @@ def forward(params, cfg, ids, cache, attn_name=None):
         x = params["embed"].astype(dt)[ids]
         if cfg.embedding_multiplier != 1.0:
             x = x * cfg.embedding_multiplier
+        x = x.astype(cfg.stream_dtype)
     planes = attend.planes
     rope = rope_angles(attend.q_pos, cfg.qk_rope_dim or cfg.head_dim,
                        cfg.rope_theta, cfg.rope_yarn) if cfg.rope else None

@@ -760,8 +760,11 @@ def flash_decode_attention_q8(q, k, v, k_scale, v_scale, pos, scale=None,
 # of sixteen. See PERF.md, PR 28.)
 #
 # A UNIT IS K CONSECUTIVE LIVE PAGES OF ITS ROW WHERE A PAGE IS SMALL (PR 39;
-# ``_pages_per_unit``, from shapes: K = 1 for every k/v arena a cell runs, 4
-# for the latent cache's 164 KB page). What a grid step costs whatever it
+# ``_pages_per_unit``, from shapes: K = 1 for the k/v arenas of GPT-2, OLMoE,
+# Granite and LFM2, whose page of all stored heads is 512 KB or more; 4 for
+# the latent cache's 164 KB page; 8 for a MULTI-QUERY pair, ONE stored head of
+# 128, 64 KB a page for k and v together, in the decode scan's call, and 2 in
+# the lane's, whose 20 x 128 query rows leave VMEM no room for more). What a grid step costs whatever it
 # holds (about half a microsecond: index maps, DMA descriptors, the scalar
 # reads, one online-softmax update and the rescale of the accumulator) is
 # then paid once for K pages: the arena is an operand K times, each with its
@@ -900,8 +903,15 @@ def _paged_heads_per_unit(h, s_blk, page_len, d, q_dtype, kv_dtype, pack=1,
         return h
     # A group of int8 heads is the sublane dim of its scale block, which
     # holds a scale for each of a packed head's ``pack`` heads.
-    return max((g for g in range(1, h) if h % g == 0 and g <= fit
-                and (kv_b > 1 or g * pack % 8 == 0)), default=h)
+    groups = [g for g in range(1, h) if h % g == 0 and g <= fit
+              and (kv_b > 1 or g * pack % 8 == 0)]
+    if groups:
+        return max(groups)
+    # No group fits (ONE stored head under multi-query rows: 20 x 128 query
+    # rows in the lane at eight pages a unit): at one page a unit the heads
+    # are the unit whatever it takes; at more, 0 says so, and
+    # ``_pages_per_unit`` joins fewer pages.
+    return h if k_pages == 1 else 0
 
 
 # A unit that already reads near its bound: one page of OLMoE's 16 heads of

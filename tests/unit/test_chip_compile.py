@@ -247,6 +247,36 @@ def _lfm2_append(k, v, ka, va, tbl, pos):
     return da.kv_append((ka, va), (k, v), tbl, pos, layer=2)
 
 
+# Jamba2-3B's attention (the cell serve-jamba2-decode-closed): MULTI-QUERY,
+# 20 query heads over ONE stored head of 128 (g = 1, rep = 20), 2 layers that
+# hold keys; 128 slots x 23 pages + trash. A page of the one head is 32 KB an
+# arena: the scan's call joins eight (K > 1 on a k/v PAIR for the first
+# time), the lane's 20 x 128 rows leave room for two.
+J_SLOTS, J_HEADS, J_HD = 128, 20, 128
+J_ARENA = ((2, J_SLOTS * 23 + 1, 1, PAGE, J_HD), BF16)
+
+
+def _jamba_decode_args(rows, slots):
+    return [((slots, J_HEADS, rows, J_HD), BF16), J_ARENA, J_ARENA,
+            ((slots, 23), I32), ((slots,), I32)]
+
+
+def _jamba_decode(name=None):
+    def run(q, k, v, tbl, pos):
+        return da.flash_decode_attention_paged(q, k, v, tbl, pos, name=name,
+                                               layer=1)
+    return run
+
+
+def _jamba_append_args(rows, slots):
+    new = ((slots, 1, rows, J_HD), BF16)
+    return [new, new, J_ARENA, J_ARENA, ((slots, 23), I32), ((slots,), I32)]
+
+
+def _jamba_append(k, v, ka, va, tbl, pos):
+    return da.kv_append((ka, va), (k, v), tbl, pos, layer=1)
+
+
 def _kda_update_args(rows):
     """Kimi Linear's 32 heads of 128: q, k, v, g [B, H, d], beta [B, H], the
     float32 state [B, H, d_k, d_v], the frontier-0 flags [B]."""
@@ -388,6 +418,16 @@ CASES = {
         _lfm2_append, _lfm2_append_args(1, L_SLOTS), {}),
     "lfm2_kv_append_lane_128_rows_g2": (
         _lfm2_append, _lfm2_append_args(128, 1), {}),
+    # Multi-query rows, rep = 20 over ONE stored head of 128 (Jamba2-3B's):
+    # the scan's call at eight pages a unit, the lane's at two, both appends.
+    "jamba_paged_decode_128_slots_rep20": (
+        _jamba_decode(), _jamba_decode_args(1, J_SLOTS), {}),
+    "jamba_prefill_attn_lane_128_rows_rep20": (
+        _jamba_decode("prefill_attn"), _jamba_decode_args(128, 1), {}),
+    "jamba_kv_append_128_slots_1_head": (
+        _jamba_append, _jamba_append_args(1, J_SLOTS), {}),
+    "jamba_kv_append_lane_128_rows_1_head": (
+        _jamba_append, _jamba_append_args(128, 1), {}),
     # The one-token KDA update at the Kimi cell's pool (all 32 heads of a
     # row one unit: 4 x 2 MiB of blocks) and at the batch of 1 the
     # benchmark's state probe calls it with.
@@ -502,14 +542,19 @@ def test_training_cells_flash_calls_keep_their_names_and_take_strips(
     ("packed_paged_decode_1_row_d64", 1),
     ("packed_paged_lane_128_rows_d64", 1),
     ("olmoe_paged_decode_32_rows_d128", 1),
-    ("olmoe_prefill_attn_lane_128_rows_d128", 1)])
+    ("olmoe_prefill_attn_lane_128_rows_d128", 1),
+    ("jamba_paged_decode_128_slots_rep20", 8),
+    ("jamba_prefill_attn_lane_128_rows_rep20", 2)])
 def test_pages_a_unit_in_the_compiled_kernel(name, pages, chip, monkeypatch):
     """K, the pages one unit of a paged kernel joins, read off the compiled
     call: each arena is an operand once a page of the unit. The latent
     cache's decode call joins four 164 KB pages (inside its VMEM reckoning:
     all 128 heads still one unit, and Mosaic's scoped limit, or the compile
     fails) and its lane's call one; GPT-2's packed and OLMoE's calls are at
-    512 KB and 1 MB a page and stay at one, the program they always were."""
+    512 KB and 1 MB a page and stay at one, the program they always were; a
+    multi-query pair (Jamba2-3B's ONE stored head of 128, 64 KB a page for k
+    and v) joins eight in the scan's call, K > 1 on a k/v pair, and two in
+    the lane's, whose 2,560 query rows fill VMEM."""
     fn, shapes, _ = CASES[name]
     monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)

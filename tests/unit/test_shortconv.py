@@ -202,11 +202,11 @@ def test_a_pad_column_never_enters_the_tail():
 
 
 def test_a_recurrent_kind_names_any_number_of_states():
-    """Mamba-2 and KDA carry a state and a tail a layer, the short
+    """Mamba-2, KDA and Mamba-1 carry a state and a tail a layer, the short
     convolution its tail alone; ``cache_spec`` names them all."""
     assert {kind: len(module.state_keys(0))
             for kind, module in decoder.RECURRENT.items()} == \
-        {"mamba": 2, "kda": 2, "shortconv": 1}
+        {"mamba": 2, "kda": 2, "shortconv": 1, "mamba1": 2}
     spec = decoder.cache_spec(CFG)
     assert [s[0] for s in spec.slot_state] == list(STATE)
     assert all(shape == (2, 512) for _, shape, _ in spec.slot_state)
