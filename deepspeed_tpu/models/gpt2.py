@@ -98,6 +98,56 @@ def _sp_axis(cfg):
     return active_sp_axis(getattr(cfg, "sequence_parallel_axis", None))
 
 
+@jax.custom_vjp
+def _row_major_grad(w):
+    """``w``, its gradient held to ``w``'s own row-major layout. The
+    compiler is free to hand a weight's gradient over column-major where
+    that makes a rearrangement of its columns free, and ZeRO's
+    ``psum_scatter`` of a leaf by ROWS then lowers to an all-reduce and a
+    slice (twice the bytes over the wires: the c_attn leaves of GPT-2 XL
+    on four chips, PR 49)."""
+    return w
+
+
+def _row_major_grad_fwd(w):
+    return w, None
+
+
+def _row_major_grad_bwd(_, g):
+    from jax.experimental.layout import Layout, with_layout_constraint
+    return (with_layout_constraint(
+        g, Layout(major_to_minor=tuple(range(g.ndim)))),)
+
+
+_row_major_grad.defvjp(_row_major_grad_fwd, _row_major_grad_bwd)
+
+
+class _TiledQKV(nn.Dense):
+    """``c_attn`` for the flash kernels' packed operand: ``nn.Dense``'s
+    parameters under ``nn.Dense``'s names (the ``[C, 3C]`` kernel and the
+    bias, q | k | v a head after a head: the tree every checkpoint and the
+    serving engine hold) and its arithmetic, with the OUTPUT's columns
+    arranged a lane tile at a time (``attention.tile_qkv``). What is
+    arranged is the weight, 6 MB a layer at GPT-2 355M, at trace time; the
+    32 MB activations around attention are then never transposed."""
+    heads: int = 1
+
+    @nn.compact
+    def __call__(self, x):
+        from deepspeed_tpu.ops.transformer.kernels.attention import tile_qkv
+        kernel = self.param("kernel", self.kernel_init,
+                            (x.shape[-1], self.features), self.param_dtype)
+        bias = self.param("bias", self.bias_init, (self.features,),
+                          self.param_dtype)
+        x, kernel, bias = nn.dtypes.promote_dtype(x, kernel, bias,
+                                                  dtype=self.dtype)
+        d = self.features // (3 * self.heads)
+        kernel = _row_major_grad(kernel)
+        kernel, bias = (tile_qkv(w, self.heads, d) for w in (kernel, bias))
+        return jax.lax.dot_general(
+            x, kernel, (((x.ndim - 1,), (0,)), ((), ()))) + bias
+
+
 class CausalSelfAttention(nn.Module):
     config: GPT2Config
 
@@ -106,6 +156,26 @@ class CausalSelfAttention(nn.Module):
         cfg = self.config
         B, T, C = x.shape
         nh, hd = cfg.n_head, C // cfg.n_head
+        sp = _sp_axis(cfg)
+
+        if cfg.use_flash_attention and sp is None:
+            from deepspeed_tpu.ops.transformer.kernels.attention import (
+                flash_attention, packed_heads)
+            if packed_heads(nh, hd):
+                # The Pallas flash kernels on c_attn's output AS IT COMES,
+                # ``packed_heads`` heads a 128-lane tile (two at head dim
+                # 64), and their output straight into c_proj: no head split,
+                # no transpose, forward or backward
+                # (ops/transformer/kernels/attention.py, "Two operand
+                # layouts"). Where the heads do not fill the last tile
+                # (GPT-2 XL's 25) its dead lanes are dropped here.
+                qkv = _TiledQKV(3 * C, dtype=cfg.dtype, heads=nh,
+                                name="c_attn")(x)
+                y = flash_attention(qkv, heads=nh, head_dim=hd, causal=True)
+                y = nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
+                y = nn.Dense(C, dtype=cfg.dtype, name="c_proj")(y[..., :C])
+                return nn.Dropout(cfg.dropout)(y,
+                                               deterministic=deterministic)
 
         # One fused QKV GEMM (MXU-friendly: [B*T, C] x [C, 3C]).
         qkv = nn.Dense(3 * C, dtype=cfg.dtype, name="c_attn")(x)
@@ -114,7 +184,6 @@ class CausalSelfAttention(nn.Module):
         k = k.reshape(B, T, nh, hd).transpose(0, 2, 1, 3)
         v = v.reshape(B, T, nh, hd).transpose(0, 2, 1, 3)
 
-        sp = _sp_axis(cfg)
         if sp is not None:
             # Sequence-parallel: q/k/v hold this shard's tokens; attend
             # globally via the k/v ring (causality handled at block level)
@@ -125,11 +194,10 @@ class CausalSelfAttention(nn.Module):
             y = sp_attn(q, k, v, axis_name=sp, causal=True)
             y = nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
         elif cfg.use_flash_attention:
-            # Pallas flash kernel: O(T) memory, both GEMMs MXU-resident
-            # (ops/transformer/kernels/attention.py). Attention-prob dropout
-            # moves to the context output (flash never materializes probs).
-            from deepspeed_tpu.ops.transformer.kernels.attention import (
-                flash_attention)
+            # The head-major entry of the same kernels: a head dim the
+            # packed layout cannot hold, or a 'model' axis that would cut a
+            # lane tile. Attention-prob dropout moves to the context output
+            # (flash never materializes probs).
             y = flash_attention(q, k, v, causal=True)
             y = nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
         else:

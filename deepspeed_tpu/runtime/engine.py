@@ -286,6 +286,11 @@ class DeepSpeedEngine(object):
             lambda: flash_kernels.last_walk()["subtile"])
         self.telemetry.gauge("flash_tiles_visited_share").set_fn(
             lambda: flash_kernels.last_walk()["tiles_visited_share"])
+        # ... and the heads a 128-lane tile where that call took the
+        # projection's own [B, T, lanes] layout (2 at head dim 64); 0
+        # where the head-major [B, H, T, d] entry ran.
+        self.telemetry.gauge("flash_lane_pack").set_fn(
+            lambda: flash_kernels.last_walk()["lane_pack"])
         # How the gradient leaves left the fused step last traced, where
         # its forward and backward run per chip (_dp_value_and_grad): by
         # psum_scatter onto ZeRO-2's partition, by psum. Both 0 where GSPMD

@@ -74,32 +74,43 @@ def kernel_us(fn, args, reps=5):
 
 def subtile_table(sides, dtype):
     """Time the forward and the fused backward alone for each sub-tile
-    side at each of CELL_SHAPES; one JSON line each on stdout."""
+    side at each of CELL_SHAPES, head-major and (where the shape packs)
+    in the projection's own layout, the fused tile-arranged operand
+    ``CausalSelfAttention`` hands the kernels; one JSON line each on
+    stdout."""
     rng = np.random.RandomState(0)
     for (b, h, t, d), causal in CELL_SHAPES:
         q, k, v = (jnp.asarray(rng.randn(b, h, t, d), dtype)
                    for _ in range(3))
         mask = None if causal else jnp.zeros((b, t), jnp.float32)
+        qkv = attention.tile_qkv(jnp.concatenate(
+            [x.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+             for x in (q, k, v)], axis=-1), h, d)
 
         for side in sides if causal else sides[:1]:
             # 0: a side no block reaches, so every block is its own tile.
             attention._SUBTILE_SIDE = side or 1 << 30
 
-            def fwd_bwd(q, k, v):  # a function a side: jit caches by it
-                out, vjp = jax.vjp(lambda q, k, v: flash_attention(
-                    q, k, v, mask=mask, causal=causal), q, k, v)
+            def fwd_bwd(*ops):  # a function a side: jit caches by it
+                out, vjp = jax.vjp(lambda *ops: flash_attention(
+                    *ops, mask=mask, causal=causal, heads=h, head_dim=d),
+                    *ops)
                 return out, vjp(out)
 
-            us = kernel_us(fwd_bwd, (q, k, v))
-            walk = attention.last_walk()
-            print(json.dumps({
-                "shape": [b, h, t, d], "causal": causal,
-                "dtype": jnp.dtype(dtype).name, "subtile": walk["subtile"],
-                "tiles_visited_share": walk["tiles_visited_share"],
-                "us_a_head": {k: round(us[k][0] / (b * h), 3)
-                              for k in sorted(us)},
-                "us_a_call": {k: round(us[k][0], 1) for k in sorted(us)},
-                "device": jax.devices()[0].device_kind}), flush=True)
+            for layout, ops in (("head_major", (q, k, v)),
+                                ("packed", (qkv,))):
+                us = kernel_us(fwd_bwd, ops)
+                walk = attention.last_walk()
+                print(json.dumps({
+                    "shape": [b, h, t, d], "causal": causal,
+                    "layout": layout, "lane_pack": walk["lane_pack"],
+                    "dtype": jnp.dtype(dtype).name,
+                    "subtile": walk["subtile"],
+                    "tiles_visited_share": walk["tiles_visited_share"],
+                    "us_a_head": {k: round(us[k][0] / (b * h), 3)
+                                  for k in sorted(us)},
+                    "us_a_call": {k: round(us[k][0], 1) for k in sorted(us)},
+                    "device": jax.devices()[0].device_kind}), flush=True)
 
 
 def time_fn(fn, *args):

@@ -88,6 +88,17 @@ def _flash_bert(q, k, v, mask):
     return attention.flash_attention(q, k, v, mask=mask)
 
 
+def _flash_packed(heads):
+    """The call of ``CausalSelfAttention``'s flash branch: c_attn's output
+    as it comes, ``[B, T, tiles x 3 x 128]`` (two heads of 64 a lane tile,
+    tile p's q | k | v side by side), under the region's name."""
+    def attend(qkv):
+        with jax.named_scope("attn"):
+            return attention.flash_attention(qkv, heads=heads, head_dim=HD,
+                                             causal=True)
+    return attend
+
+
 def _paged_args(page_len, rows, int8=False):
     n_lp = T_KV // page_len
     arena = ((SLOTS * n_lp + 1, HEADS, page_len, HD), I8 if int8 else BF16)
@@ -309,16 +320,23 @@ CASES = {
     "flash_fwd_bwd_auto": (_fwd_bwd(_flash_causal, 3), [QKV] * 3, {}),
     "flash_fwd_bwd_split": (_fwd_bwd(_flash_causal, 3), [QKV] * 3,
                             {"DS_TPU_FLASH_BWD": "split"}),
-    # The two training cells' calls: one block of 1024 x 1024 a head, taken
-    # in strips to the diagonal, forward and fused backward; and a sequence
-    # of two blocks a side (strips in the diagonal blocks, the block before
-    # them whole).
+    # The two training cells' calls, in the projection's own layout (PR
+    # 49): one block of 1024 x 1024 a head, two heads a grid step, taken in
+    # strips to the diagonal, forward and fused backward (GPT-2 XL's 25
+    # heads are 12.5 tiles: 13, the last half dead); a sequence of two
+    # blocks a side (strips in the diagonal blocks, the block before them
+    # whole), head-major and packed; and the packed split backward.
     "flash_train_gpt2m_1chip_fwd_bwd": (
-        _fwd_bwd(_flash_causal, 3), [((16, 16, 1024, 64), BF16)] * 3, {}),
+        _fwd_bwd(_flash_packed(16), 1), [((16, 1024, 8 * 384), BF16)], {}),
     "flash_train_gpt2xl_dp4_fwd_bwd": (
-        _fwd_bwd(_flash_causal, 3), [((4, 25, 1024, 64), BF16)] * 3, {}),
+        _fwd_bwd(_flash_packed(25), 1), [((4, 1024, 13 * 384), BF16)], {}),
     "flash_t2048_two_blocks_fwd_bwd": (
         _fwd_bwd(_flash_causal, 3), [((4, 16, 2048, 64), BF16)] * 3, {}),
+    "flash_packed_t2048_two_blocks_fwd_bwd": (
+        _fwd_bwd(_flash_packed(25), 1), [((2, 2048, 13 * 384), BF16)], {}),
+    "flash_packed_fwd_bwd_split": (
+        _fwd_bwd(_flash_packed(25), 1), [((2, 1024, 13 * 384), BF16)],
+        {"DS_TPU_FLASH_BWD": "split"}),
     "flash_bert_masked_fwd_bwd": (
         _fwd_bwd(_flash_bert, 3),
         [((8, 16, 512, 64), BF16)] * 3 + [((8, 512), F32)], {}),
@@ -522,18 +540,27 @@ def test_training_cells_flash_calls_keep_their_names_and_take_strips(
         name, chip, monkeypatch):
     """The training cells' attention is ONE ``flash_fwd`` and ONE
     ``flash_bwd_fused`` custom call (the names the three rooflines read),
-    and the launcher's rule gave them strips: S divides the block, fewer
-    tiles than the square are computed."""
+    the launcher's rule gave them strips (S divides the block, fewer tiles
+    than the square are computed) and two heads a lane tile, and the
+    program the chip's compiler makes of the call holds NO ``copy`` and no
+    ``transpose`` under the attention region: the kernels read c_attn's
+    output and write c_proj's input where they lie."""
     fn, shapes, _ = CASES[name]
     monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
             for shape, dtype in shapes]
-    calls = _kernel_calls(jax.jit(fn).lower(*args).compile().as_text())
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = _kernel_calls(text)
     assert sorted(c.split(".")[0] for c in calls) == \
         ["flash_bwd_fused", "flash_fwd"]
     walk = attention.last_walk()
     n = 1024 // walk["subtile"]
     assert n > 1 and walk["tiles_visited_share"] == (n + 1) / (2 * n)
+    assert walk["lane_pack"] == 2
+    under = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r'op_name="[^"]*attn[/)]', line)]
+    assert under and not [line for line in under
+                          if re.search(r" (copy|transpose)\(", line)], under
 
 
 @pytest.mark.parametrize("name, pages", [

@@ -256,6 +256,165 @@ def test_flash_attention_takes_a_diagonal_block_in_strips(
         assert not np.asarray(g[2][:, :, t_q:], np.float32).any()
 
 
+def _packed_cases():
+    """(heads, d, t, dtype, causal, use_mask): GPT-2 355M's heads (two a
+    lane tile), GPT-2 XL's (12.5 tiles: the last half dead), head dim 128
+    (a head a tile); T 1024 takes the block in eight strips, T 256 causal
+    in two, not causal whole."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    cases = []
+    for heads, d in ((16, 64), (25, 64), (4, 128)):
+        cases += [(heads, d, 256, f32, True, False),
+                  (heads, d, 256, f32, False, True),
+                  (heads, d, 256, f32, True, True),
+                  (heads, d, 256, bf16, True, False),
+                  (heads, d, 256, bf16, False, True),
+                  (heads, d, 256, bf16, False, False),
+                  (heads, d, 1024, bf16, True, False)]
+    return cases + [(25, 64, 1024, f32, True, True)]
+
+
+def _flat(x):
+    """[B, H, T, d] as a projection emits it, [B, T, H x d]."""
+    b, h, t, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+
+
+def _fused_operand(q, k, v, plant=jnp.nan):
+    """Head-major q, k, v as the ONE packed operand ``CausalSelfAttention``
+    hands the kernels, ``plant`` written where a half-empty tile's dead
+    lanes lie (nothing may be read from there)."""
+    h, d = q.shape[1], q.shape[3]
+    g = attention.lane_pack(d, h)
+    x = attention.tile_qkv(
+        jnp.concatenate([_flat(q), _flat(k), _flat(v)], axis=-1), h, d)
+    lane = jnp.arange(x.shape[-1])
+    dead = (lane // (3 * g * d) == -(-h // g) - 1) & \
+        (lane % (g * d) >= (g - (-h % g)) * d)
+    return jnp.where(dead, plant, x)
+
+
+@pytest.mark.parametrize("heads,d,t,dtype,causal,use_mask", _packed_cases())
+def test_flash_attention_packed_layout(heads, d, t, dtype, causal, use_mask):
+    """The kernels on the projection's own ``[B, T, lanes]`` layout,
+    ``lane_pack`` heads a 128-lane tile, q, k and v one fused tile-arranged
+    operand: output, log-sum-exp and the three gradients (which come back
+    through ``tile_qkv``'s own transpose: ONE ``dqkv`` from the kernel)
+    against the dense reference on head-major copies of the same values,
+    with NaN where GPT-2 XL's thirteenth tile is dead."""
+    rng = np.random.RandomState(49)
+    b = 1
+    q, k, v = (jnp.asarray(rng.randn(b, heads, t, d), dtype)
+               for _ in range(3))
+    mask = None
+    if use_mask:
+        mask = jnp.where(jnp.asarray(rng.rand(b, t)) > 0.25, 0.0,
+                         -1e9).astype(jnp.float32)
+    w = jnp.asarray(rng.randn(b, heads, t, 1), jnp.float32)
+
+    def packed(q, k, v):
+        o, lse = flash_attention_with_lse(
+            _fused_operand(q, k, v), mask=mask, causal=causal, heads=heads,
+            head_dim=d)
+        o = o[..., :heads * d].reshape(b, t, heads, d)
+        return o.transpose(0, 2, 1, 3), lse[:, :heads]
+
+    def dense(q, k, v):
+        return mha_reference(q, k, v, mask=mask, causal=causal,
+                             return_lse=True)
+
+    def outputs_and_grads(fn, *qkv):
+        def loss(q, k, v):
+            o, lse = fn(q, k, v)
+            return jnp.sum(o.astype(jnp.float32) ** 2) + jnp.sum(lse * w), \
+                (o, lse)
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(*qkv)
+        return out, grads
+
+    rtol, atol, g_rtol, g_atol = _WALK_TOL[dtype]
+    (o, lse), g = outputs_and_grads(packed, q, k, v)
+    walk = attention.last_walk()
+    assert walk["lane_pack"] == 128 // d
+    assert walk["subtile"] == (128 if causal else 0)
+    (o_ref, lse_ref), gr = outputs_and_grads(
+        dense, *(x.astype(jnp.float32) for x in (q, k, v)))
+    np.testing.assert_allclose(np.asarray(o, np.float32), o_ref,
+                               rtol=rtol, atol=atol)
+    np.testing.assert_allclose(lse, lse_ref, rtol=rtol, atol=atol)
+    for a, b_ in zip(g, gr):
+        np.testing.assert_allclose(np.asarray(a, np.float32), b_,
+                                   rtol=g_rtol, atol=g_atol)
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_flash_attention_packed_is_head_major_to_the_bit_at_one_head_a_tile(
+        fused):
+    """At head dim 128 a tile holds ONE head, nothing is selected, and the
+    packed launches run the head-major body on other block specs: the
+    output and the gradients (the kernels' own delta among them) are the
+    head-major entry's bit for bit, from three packed arrays and from the
+    fused one."""
+    rng = np.random.RandomState(7)
+    b, h, t, d = 1, 3, 256, 128
+    q, k, v = (jnp.asarray(rng.randn(b, h, t, d), jnp.bfloat16)
+               for _ in range(3))
+
+    def unflat(x):
+        return x.reshape(b, t, h, d).transpose(0, 2, 1, 3)
+
+    def head_major(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    def packed(q, k, v):
+        ops = (_fused_operand(q, k, v),) if fused else \
+            (_flat(q), _flat(k), _flat(v))
+        return unflat(flash_attention(*ops, causal=True, heads=h,
+                                      head_dim=d))
+
+    def run(fn):
+        out, vjp = jax.vjp(fn, q, k, v)
+        return (out,) + vjp(jnp.ones_like(out))
+
+    for a, b_ in zip(run(head_major), run(packed)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b_, np.float32))
+    assert attention.last_walk()["lane_pack"] == 1
+
+
+def test_flash_attention_packed_shards_whole_tiles_over_a_mesh():
+    """Under ``kernels_on_mesh`` the packed entry splits the batch over
+    'data' and its LANE dim over 'model', whole tiles a shard (the row
+    statistics by heads beside them), and ``packed_heads`` sends a caller
+    whose heads a 'model' axis would cut through a tile to the head-major
+    entry: decided from the mesh at trace time."""
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+    rng = np.random.RandomState(3)
+    b, h, t, d = 2, 4, 256, 64
+    qkv = attention.tile_qkv(rand(rng, b, t, 3 * h * d), h, d)
+
+    def loss(x):
+        return jnp.sum(flash_attention(x, heads=h, head_dim=d,
+                                       causal=True) ** 2)
+
+    def on_mesh(x):
+        with attention.kernels_on_mesh(mesh):
+            assert attention.packed_heads(h, d) == 2      # 2 tiles, 2 shards
+            assert attention.packed_heads(2, d) == 0      # 1 tile, 2 shards
+            assert attention.packed_heads(h, 80) == 0     # no such tile
+            return loss(x)
+
+    assert attention.packed_heads(2, d) == 2              # no mesh: raw
+    want = jax.value_and_grad(loss)(qkv)
+    step = jax.jit(jax.value_and_grad(on_mesh))
+    got = step(qkv)
+    assert "manual_computation" in step.lower(qkv).as_text()  # shard_map
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+    np.testing.assert_allclose(got[1], want[1], rtol=1e-5, atol=1e-6)
+
+
 def test_flash_subtile_rule_for_the_training_cells():
     """The rule itself, from shapes alone: the side S for the two training
     cells' calls ([16, 16, 1024, 64] and [4, 25, 1024, 64]: one block of
@@ -301,6 +460,27 @@ def test_flash_attention_ragged_fallback():
     o = flash_attention(q, k, v, causal=True, block_q=32, block_k=32)
     ref = mha_reference(q, k, v, causal=True)
     np.testing.assert_allclose(o, ref, rtol=1e-4, atol=1e-4)
+
+
+def test_flash_attention_packed_ragged_fallback():
+    """A packed operand whose length the block does not divide takes the
+    jnp path too (on head-major copies), and answers in its own layout:
+    the output's dead lanes zero, a row of statistics a stored head."""
+    rng = np.random.RandomState(5)
+    b, h, t, d = 1, 5, 96, 64
+    q, k, v = (rand(rng, b, h, t, d) for _ in range(3))
+    qkv = _fused_operand(q, k, v, plant=0.0)
+    o, lse = flash_attention_with_lse(qkv, heads=h, head_dim=d, causal=True,
+                                      block_q=64, block_k=64)
+    ref, lse_ref = mha_reference(q, k, v, causal=True, return_lse=True)
+    assert o.shape == (b, t, 3 * 128) and lse.shape == (b, 6, t, 1)
+    np.testing.assert_allclose(o[..., :h * d], _flat(ref), rtol=1e-4,
+                               atol=1e-4)
+    np.testing.assert_allclose(lse[:, :h], lse_ref, rtol=1e-4, atol=1e-4)
+    assert not np.asarray(o[..., h * d:]).any()
+    grad = jax.grad(lambda x: jnp.sum(flash_attention(
+        x, heads=h, head_dim=d, causal=True, block_q=64, block_k=64) ** 2))
+    assert np.isfinite(np.asarray(grad(qkv))).all()
 
 
 @pytest.mark.parametrize("shape", [(64, 256), (2, 32, 128)])
