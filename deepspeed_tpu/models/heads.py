@@ -17,6 +17,17 @@ eagerly alongside the loss (dW accumulated in fp32 across chunks — tighter
 than autodiff's model-dtype accumulation) and the custom_vjp backward is
 just a scalar-rescale replay of the stored gradients. Undifferentiated
 callers (eval) take the primal path and pay 1 GEMM, nothing eager.
+
+Passes over a logits-sized array (a chunk of 2048 tokens x 50257 ids is
+412 MB in fp32, and the three GEMMs are 3.2 ms of a v5e's MXU). The eager
+loop WRITES one such array a chunk, the fp32 logits, and reads it three
+times: the log-sum-exp, and the prologues of the dW and dx GEMMs, where the
+compiler forms ``(softmax - onehot(label)) * valid`` on the fly (the
+label's -1 is an iota compare, the result is cast to the GEMM dtype in
+registers): 4.1 ms a chunk at C = 1024 on the chip. Taking the -1 by a
+scatter-add instead (``dl.at[rows, label].add(-valid)``, cheaper on a CPU)
+made the chip write an fp32 dl, relay it flat for the scatter, cast it and
+relay it back: five more arrays of that size written a chunk, 8.2 ms.
 """
 
 import functools
@@ -54,17 +65,18 @@ def _chunked_xe_total(dtype, xc, w, lc, vc, bias_f):
 
 
 def _chunked_xe_total_fwd(dtype, xc, w, lc, vc, bias_f):
-    n_chunks, chunk, c = xc.shape
-
     def step(dw_acc, args):
         xi, li_, vi = args
         logits = _logits(xi, w, bias_f, dtype)
         loss, lse = _chunk_loss(logits, li_, vi)
         # dlogits of the summed loss: (softmax - onehot(label)) on
-        # supervised rows, 0 elsewhere. Scatter-add touches `chunk`
-        # elements — cheaper than a [chunk, V] one-hot compare pass.
-        dl = jnp.exp(logits - lse[:, None]) * vi[:, None]
-        dl = dl.at[(jnp.arange(chunk), li_)].add(-vi)
+        # supervised rows, 0 elsewhere, as ONE elementwise expression of
+        # the logits, the label's -1 an iota compare and not a scatter-add
+        # (module docstring): the compiler fuses it into the prologue of
+        # both GEMMs below and, with a bias, into db's reduce.
+        p = jnp.exp(logits - lse[:, None])
+        hit = jax.lax.broadcasted_iota(jnp.int32, p.shape, 1) == li_[:, None]
+        dl = jnp.where(hit, p - 1.0, p) * vi[:, None]
         dl_cast = dl.astype(dtype)
         dx = jax.lax.dot_general(dl_cast, w, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)

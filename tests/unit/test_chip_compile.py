@@ -685,12 +685,18 @@ def _mixed_step_text(chip, adapter, params, pool, chunk, lane):
     return text
 
 
+def _while_bodies(comps, within=""):
+    """The body computations' names of the ``while``s whose line holds
+    ``within``."""
+    return [m for lines in comps.values() for line in lines
+            if within in line and " while(" in line
+            for m in re.findall(r"body=%([\w.-]+)", line)]
+
+
 def _scan_lines(comps):
     """The instruction lines of everything the decode scan's ``while`` body
     reaches, and the names of those computations."""
-    bodies = [m for lines in comps.values() for line in lines
-              if "decode_scan/while" in line and " while(" in line
-              for m in re.findall(r"body=%([\w.-]+)", line)]
+    bodies = _while_bodies(comps, "decode_scan/while")
     assert len(bodies) == 1, bodies
     scan = _reachable(comps, bodies[0])
     return scan, [line for name in scan for line in comps[name]]
@@ -1168,3 +1174,42 @@ def test_zero2_step_keeps_the_partition_out_of_the_model(host, monkeypatch):
     assert engine._zero_leaves == (len(jax.tree_util.tree_leaves(
         engine.params)), 0)
 
+
+# ------------------------------------------------------- the LM head's loop
+
+@pytest.mark.parametrize("sequences, width", [(16, 1024), (4, 1600)])
+def test_eager_head_keeps_one_logits_sized_array_a_chunk(sequences, width,
+                                                         chip):
+    """``value_and_grad`` of the eager head alone at a chip's share of the
+    two training cells (16 x 1,023 tokens at GPT-2 355M's width, 4 x 1,023
+    at GPT-2 XL's; chunks of 2,048 against 50,257 ids; 10 s each). The chunk
+    loop writes ONE array the size of a chunk's logits, the float32 logits
+    themselves: the softmax gradient is formed inside both gradient matmuls'
+    fusions. The parent's scatter-add of the label's -1 left a float32 ``dl``
+    (``multiply_bitcast_fusion``), two ``reshape``s to a flat array and back
+    and a ``convert_element_type`` of that size in the loop (33 ms of the
+    one-chip cell's 303 ms step, PR 50), and 1,028 / 1,005 MB of temporaries
+    where a chunk's logits and the bf16 table are 515 / 573."""
+    from deepspeed_tpu.models.heads import chunked_tied_softmax_xent
+
+    vocab, chunk = 50257, 2048
+
+    def loss(x, table, labels):
+        return chunked_tied_softmax_xent(x, table, labels, BF16,
+                                         chunk=chunk, impl="eager")
+
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in (((sequences, 1023, width), BF16),
+                                 ((vocab, width), F32),
+                                 ((sequences, 1023), I32))]
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        *args).compile()
+    comps = _computations(compiled.as_text())
+    body, = _while_bodies(comps)
+    either_way = ["[2048,50257]", "[50257,2048]", "[102926336]"]
+    logits_sized = _arena_shaped(comps[body], either_way)
+    assert [op for _, op in logits_sized] == ["fusion"], logits_sized
+    assert _arena_shaped(comps[body], ["f32[2048,50257]"]) == logits_sized
+    logits_and_table = chunk * vocab * 4 + vocab * width * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        logits_and_table + (32 << 20)

@@ -36,6 +36,9 @@ def dense_reference(x, wte, labels, bias=None, ignore_index=None,
 
 
 def make_inputs(n_tokens=96, c=32, v=128, seed=0, ignore_frac=0.0):
+    """Random inputs; the stream's first two and last two labels are id 0
+    and id v - 1 (the ends of the head's iota compare), wherever the chunks'
+    boundaries fall."""
     rng = np.random.RandomState(seed)
     b, t = 4, n_tokens // 4
     x = jnp.asarray(rng.randn(b, t, c), jnp.float32) * 0.3
@@ -44,6 +47,8 @@ def make_inputs(n_tokens=96, c=32, v=128, seed=0, ignore_frac=0.0):
     if ignore_frac:
         mask = rng.rand(b, t) < ignore_frac
         labels = np.where(mask, -1, labels)
+    labels[0, :2] = 0, v - 1
+    labels[-1, -2:] = 0, v - 1
     return x, wte, jnp.asarray(labels)
 
 
@@ -51,12 +56,13 @@ def make_inputs(n_tokens=96, c=32, v=128, seed=0, ignore_frac=0.0):
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
                                        (jnp.bfloat16, 4e-2)])
 @pytest.mark.parametrize("chunk", [2048, 32, 40])  # single / multi / padded
-def test_loss_and_grads_match_dense(dtype, tol, chunk, impl):
+@pytest.mark.parametrize("vocab", [128, 131])  # whole lane tiles / a ragged one
+def test_loss_and_grads_match_dense(dtype, tol, chunk, impl, vocab):
     """Both head implementations (eager 3-GEMM custom_vjp, remat 4-GEMM
     autodiff) must match the dense spec in loss AND grads, in both the
     fp32 and bf16 regimes (the remat path's model-dtype dW accumulation
     differs most from the eager fp32 accumulator in bf16)."""
-    x, wte, labels = make_inputs()
+    x, wte, labels = make_inputs(v=vocab)
 
     def ours(x, w):
         return chunked_tied_softmax_xent(x, w, labels, dtype, chunk=chunk,
@@ -86,22 +92,47 @@ def test_head_impl_env_and_validation(monkeypatch):
         chunked_tied_softmax_xent(x, wte, labels, jnp.float32, impl="nope")
 
 
-def test_ignore_index_and_bias_match_dense():
-    x, wte, labels = make_inputs(ignore_frac=0.3)
-    bias = jnp.asarray(np.random.RandomState(7).randn(128), jnp.float32)
+# name: (vocab, chunk, ignore_index, GEMM dtype). The eager head takes the
+# label's -1 by a compare against EVERY row's label id, so the rows that are
+# not supervised are the cases: an ignored row's id is clamped to 0 (or, with
+# a non-negative ``ignore_index``, IS a column), a padded row's is 0 and its
+# logits are the bias alone.
+BIAS_CASES = {
+    "ignored_rows": (128, 32, -1, jnp.float32),
+    "padded_and_ignored_rows": (128, 40, -1, jnp.float32),
+    "ignored_id_is_a_column": (128, 40, 5, jnp.float32),
+    "ragged_vocab": (131, 40, -1, jnp.float32),
+    "bf16": (128, 40, -1, jnp.bfloat16),
+    "bf16_ragged_vocab_one_chunk": (131, 2048, 7, jnp.bfloat16),
+}
+BIAS_TOL = {jnp.float32: (1e-5, 2e-5), jnp.bfloat16: (4e-2, 4e-2)}  # loss, grads
+
+
+@pytest.mark.parametrize("case", sorted(BIAS_CASES))
+def test_ignore_index_and_bias_match_dense(case):
+    """dx, dW and db of the eager head against plain autodiff of the dense
+    loss, with the decoder bias and rows that carry no loss."""
+    vocab, chunk, ignore, dtype = BIAS_CASES[case]
+    loss_tol, tol = BIAS_TOL[dtype]
+    x, wte, labels = make_inputs(v=vocab, ignore_frac=0.3)
+    if ignore != -1:
+        labels = jnp.where(labels == -1, ignore, labels)
+    assert int(jnp.sum(labels == ignore)) > 10
+    bias = jnp.asarray(np.random.RandomState(7).randn(vocab), jnp.float32)
 
     def ours(x, w, b_):
-        return chunked_tied_softmax_xent(x, w, labels, jnp.float32,
-                                         chunk=32, bias=b_, ignore_index=-1)
+        return chunked_tied_softmax_xent(x, w, labels, dtype, chunk=chunk,
+                                         bias=b_, ignore_index=ignore,
+                                         impl="eager")
 
     def ref(x, w, b_):
-        return dense_reference(x, w, labels, bias=b_, ignore_index=-1)
+        return dense_reference(x, w, labels, bias=b_, ignore_index=ignore)
 
     (lo, go) = jax.value_and_grad(ours, argnums=(0, 1, 2))(x, wte, bias)
     (lr, gr) = jax.value_and_grad(ref, argnums=(0, 1, 2))(x, wte, bias)
-    assert abs(float(lo) - float(lr)) < 1e-5
+    assert abs(float(lo) - float(lr)) < loss_tol
     for a, b in zip(go, gr):
-        assert float(jnp.abs(a - b).max()) < 2e-5
+        assert float(jnp.abs(a.astype(jnp.float32) - b).max()) < tol
 
 
 def test_all_ignored_is_finite_zero():
