@@ -57,6 +57,16 @@ offload and handoff work as for any keys (tests/unit/test_mla.py); what
 latent's 512 values and its rotary key want scales of their own) and the
 prefix cache (its records name a ``pk`` / ``pv`` pair).
 
+GENERATION BY DIFFUSION OVER BLOCKS (``block_length`` > 1;
+SDAR-30B-A3B-Chat's, at 4): the adapter says the block length, the mask id and
+what a pass is (``block_pass``: the block's positions at the frontier, keys
+written, ``pos`` not moved), and the engine picks its decode scan from the
+block length alone. ``bind`` refuses by name what cannot compose yet:
+speculation, the prefix tiers, int8 planes, and with them host offload and the
+prefill / decode roles (a slot's open block has no snapshot form); it holds
+``kv_page_len`` and ``prefill_chunk`` to whole blocks and ``denoising_steps``
+to a divisor of the block.
+
 BOTH AT ONCE (Kimi Linear: a latent plane as deep as its MLA layers only,
 beside a KDA state a slot) gets both sets of refusals, each by its own
 mechanism's name: the latent plane's first (int8, prefix cache), then the
@@ -99,7 +109,77 @@ class DecoderAdapter(GPT2Adapter):
         """Does a token cache one latent plane in place of keys and values?"""
         return bool(self.gcfg.kv_lora_rank)
 
+    @property
+    def block_length(self):
+        """Positions a block of the model's generation holds (1: it makes
+        its tokens one a pass)."""
+        return self.gcfg.block_length
+
+    @property
+    def mask_token_id(self):
+        return self.gcfg.mask_token_id
+
+    def _refuse_for_blocks(self, config):
+        """What cannot compose yet with generation by diffusion over blocks,
+        each by the name of its mechanism."""
+        c = self.gcfg
+        if c.mask_token_id is None or not 0 <= c.mask_token_id < c.vocab_size:
+            raise ValueError(
+                "block_length {} needs mask_token_id inside the vocabulary, "
+                "got {!r}".format(c.block_length, c.mask_token_id))
+        if self.recurrent or self.latent:
+            raise ValueError(
+                "generation by diffusion over blocks (block_length {}) is "
+                "built for keys and values a head: a pass over a block "
+                "rewrites its positions, which a recurrent state cannot "
+                "take back and the latent kernel masks causally".format(
+                    c.block_length))
+        if getattr(config, "paged_kv", False) \
+                and config.kv_page_len % c.block_length:
+            raise ValueError(
+                "kv_page_len {} must hold whole blocks of {} positions: a "
+                "block never straddles a page".format(
+                    config.kv_page_len, c.block_length))
+        if config.prefill_chunk % c.block_length:
+            raise ValueError(
+                "prefill_chunk {} must hold whole blocks of {} positions: a "
+                "prompt is prefilled block by block".format(
+                    config.prefill_chunk, c.block_length))
+        steps = config.denoising_steps
+        if steps is not None and (steps < 1 or c.block_length % steps):
+            raise ValueError(
+                "denoising_steps {} must divide block_length {}".format(
+                    steps, c.block_length))
+        refused = (
+            ("speculative decoding (spec_decode)",
+             config.resolved_spec_decode(),
+             "a verify scores drafted NEXT tokens under the causal rule, "
+             "and a block's positions are filled in no order a draft could "
+             "run ahead of"),
+            ("the prefix cache (prefix_cache)", config.prefix_cache,
+             "a shared prefix ends where a request's prompt does, inside a "
+             "block whose other positions the next request fills "
+             "differently, and the tiers keep no block boundary"),
+            ("int8 planes (int8_kv)", config.int8_kv,
+             "a block's keys are rewritten every pass until its commit, and "
+             "the int8 kernels mask causally"),
+            ("host offload (host_offload)", config.host_offload,
+             "a slot swapped out inside a block would leave the block's "
+             "tokens and which of them are masked behind: the tiers "
+             "snapshot keys, not a block"),
+            ("the prefill and decode roles (role)", config.role != "mixed",
+             "a handoff captures a slot after its prompt, and the block "
+             "its prompt's tail opened is not in the record"))
+        for what, asked, why in refused:
+            if asked:
+                raise ValueError(
+                    "{} cannot serve a model that generates by diffusion "
+                    "over blocks (block_length {}): {}".format(
+                        what, c.block_length, why))
+
     def bind(self, config, mesh=None):
+        if config is not None and self.block_length > 1:
+            self._refuse_for_blocks(config)
         if config is not None and self.latent:
             refused = (
                 ("int8 planes (int8_kv)", config.int8_kv,
@@ -170,6 +250,12 @@ class DecoderAdapter(GPT2Adapter):
         logits, cache = decoder.forward(params, self.gcfg, tok[:, None],
                                         cache)
         return logits[:, 0], cache
+
+    @hot_path
+    def block_pass(self, params, ids, cache):
+        pos0 = cache["pos"]
+        logits, cache = decoder.forward(params, self.gcfg, ids, cache)
+        return logits, dict(cache, pos=pos0)
 
     @hot_path
     def verify_forward(self, params, ids, cache):

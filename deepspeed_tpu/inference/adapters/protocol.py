@@ -113,6 +113,28 @@ class ModelAdapter:
         raise NotImplementedError
 
     # ------------------------------------------------------------------
+    # generation by diffusion over blocks
+    # ------------------------------------------------------------------
+    # What the engine needs to know of a model whose tokens are NOT made
+    # one a pass: its block length (1: next-token, and nothing below is
+    # read), the id a still-masked position carries, and a pass. The
+    # engine picks its decode scan by ``block_length`` alone
+    # (``engine._diffusion_chunk_program`` past 1).
+    block_length = 1
+    mask_token_id = None
+
+    def block_pass(self, params, ids, cache):
+        """One pass over a block: ``ids`` [B, block_length] at each row's
+        frontier, which is the block's first position (a masked position
+        carries ``mask_token_id``), every position of the block seeing the
+        others and all earlier blocks. Keys are written at ``[pos, pos +
+        block_length)`` and ``pos`` is returned UNCHANGED: the engine moves
+        it by a whole block once the block's tokens are final. Returns
+        (fp32 logits [B, block_length, V], read AT each position, no
+        shift; the cache)."""
+        raise NotImplementedError
+
+    # ------------------------------------------------------------------
     # drafting surface (speculative decode)
     # ------------------------------------------------------------------
     def ngram_draft(self, toks, pos, n, k):

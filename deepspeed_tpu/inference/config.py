@@ -49,6 +49,7 @@ INFERENCE_DEFAULTS = {
     "paged_kv": False,
     "kv_page_len": 128,
     "kv_pages": None,
+    "denoising_steps": None,
 }
 
 
@@ -223,6 +224,14 @@ class InferenceConfig:
     # (plane_len / page_len) pages, i.e. the same bytes — set it lower
     # to pin HBM and let page-aware admission carry more sessions.
     kv_pages: Optional[int] = None
+    # --- Generation by diffusion over blocks ----------------------------
+    # Read only for a model whose adapter says ``block_length`` > 1
+    # (docs/INFERENCE.md): the denoising passes a block gets before its
+    # commit pass, a request's quality knob (``submit(denoising_steps=)``
+    # overrides it). It must divide the block length: a pass unmasks
+    # ``block_length / denoising_steps`` positions. None: the block length
+    # itself, one token a pass.
+    denoising_steps: Optional[int] = None
 
     def __post_init__(self):
         if self.max_slots < 1:

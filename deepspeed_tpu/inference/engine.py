@@ -75,6 +75,7 @@ match, and every program pins its out_shardings so the cache layout
 survives every step. One engine, sharded or not.
 """
 
+import bisect
 import collections
 import time
 
@@ -354,6 +355,111 @@ def _spec_decode_chunk_program(params, adapter, chunk, spec_k, spec_ngram,
     return pool, toks, valid
 
 
+@hot_path
+def _diffusion_chunk_program(params, adapter, chunk, pool):
+    """The decode lane of a model that generates by DIFFUSION OVER BLOCKS
+    (``adapter.block_length`` = L > 1): ``chunk`` passes in one scan, where a
+    pass over a slot does NOT yield one token. A slot's frontier ``pos`` is
+    its open block's first position; the pool carries the block beside it:
+    ``blk_tok`` [slots, L] (the tokens known so far), ``blk_mask`` (which
+    positions are still masked: a boolean beside the ids, so an id that
+    equals the mask id is just a token), ``blk_pass`` (passes the block has
+    had), ``blk_steps`` (S, the request's denoising passes a block) and
+    ``remaining``, here the positions from the block's first to the
+    request's last.
+
+    A pass (``adapter.block_pass``) runs the block's L positions, a masked
+    one as the mask id, against the cache of all earlier blocks and against
+    each other, writes their keys at ``[pos, pos + L)`` and leaves ``pos``.
+    A DENOISING pass (something is masked) reads the logits AT every masked
+    position (no shift; the mask id left out of the argmax), takes the
+    argmax and its softmax probability in float32, and unmasks the ``L / S``
+    most confident masked positions (all that are left if fewer; ties to
+    the lower position): ``low_confidence_static``, the only rule built. A
+    COMMIT pass (nothing was masked) has just written the keys of the
+    finished tokens, which are the ones the cache keeps: the slot moves on
+    by L to a block that is all masked, or ends. So a block costs
+    ``ceil(masked / (L / S)) + 1`` passes whatever its tokens are, and the
+    host knows by arithmetic what a dispatched step will have committed
+    (``InferenceEngine._advance_blocks``).
+
+    Returns (pool', tokens [chunk, slots, L], passes [chunk, slots, L] int8):
+    ``passes[t, s, i]`` > 0 marks position i of slot s's block as unmasked
+    in iteration t, the token ``tokens[t, s, i]``, in pass
+    ``passes[t, s, i] - 1`` of its block; a position past the request's last
+    (a last block cut short) is unmasked and never emitted. The pool's
+    ``aux_diffusion_passes`` / ``aux_diffusion_commits`` come back as THIS
+    call's counts: live (slot, iteration) places and commit passes.
+
+    In a trace the scan is ``decode_scan`` as the other two, and inside it
+    the model's regions and ``unmask``: the confidence over the vocabulary at
+    ``slots x L`` rows, the top ``L / S`` and the scatter into the block."""
+    length, mask_id = adapter.block_length, adapter.mask_token_id
+    lane = jnp.arange(length, dtype=jnp.int32)[None]
+
+    def step(pool, _):
+        live, pos = pool["active"], pool["pos"]
+        tok, masked, left = pool["blk_tok"], pool["blk_mask"], \
+            pool["remaining"]
+        logits, cache = adapter.block_pass(
+            params, jnp.where(masked, mask_id, tok), cache_view(pool))
+        with jax.named_scope("unmask"):
+            logits = jnp.where(
+                jnp.arange(logits.shape[-1]) == mask_id, _neg(), logits)
+            top = jnp.max(logits, axis=-1)
+            choice = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            # softmax(logits)[choice], float32
+            conf = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]), axis=-1)
+            conf = jnp.where(masked, conf, -1.0)
+            # ahead[r, i, j]: masked position j is unmasked before i
+            ahead = (conf[:, None, :] > conf[:, :, None]) | (
+                (conf[:, None, :] == conf[:, :, None])
+                & (lane[:, None, :] < lane[:, :, None]))
+            rank = jnp.sum(ahead & masked[:, None, :], axis=2)
+            chosen = masked & live[:, None] & (
+                rank < (length // pool["blk_steps"])[:, None])
+            commit = live & ~jnp.any(masked, axis=1)
+            left_after = jnp.where(commit, left - length, left)
+            emit = chosen & (lane < left[:, None])
+            in_pass = jnp.where(emit, pool["blk_pass"][:, None] + 1, 0)
+        pool = dict(
+            fold_cache(pool, cache),
+            pos=jnp.where(commit, pos + length, pos),
+            blk_tok=jnp.where(chosen, choice, tok),
+            # a committed slot moves on to a block that is all masked
+            blk_mask=(masked & ~chosen) | commit[:, None],
+            blk_pass=jnp.where(commit, 0, pool["blk_pass"]
+                               + live.astype(jnp.int32)),
+            remaining=left_after,
+            active=live & ~(commit & (left_after <= 0)),
+            aux_diffusion_passes=pool["aux_diffusion_passes"]
+            + jnp.sum(live.astype(jnp.int32)),
+            aux_diffusion_commits=pool["aux_diffusion_commits"]
+            + jnp.sum(commit.astype(jnp.int32)))
+        return pool, (jnp.where(emit, choice, -1), in_pass.astype(jnp.int8))
+
+    zero = jnp.zeros((), jnp.int32)
+    pool = dict(pool, aux_diffusion_passes=zero, aux_diffusion_commits=zero)
+    with jax.named_scope("decode_scan"):
+        pool, (toks, passes) = jax.lax.scan(step, pool, None, length=chunk)
+    return pool, toks, passes
+
+
+def block_state(slots, length):
+    """What a pool holds beside its keys for a model that generates by
+    diffusion over blocks of ``length``: the open block a slot carries
+    (``_diffusion_chunk_program``; ``blk_steps``: its request's denoising
+    passes a block, installed with the block by the lane's last slice) and
+    the scan's two counts of a step, which ride the snapshot as ``aux_``
+    state does."""
+    return {"blk_tok": jnp.zeros((slots, length), jnp.int32),
+            "blk_mask": jnp.ones((slots, length), jnp.bool_),
+            "blk_pass": jnp.zeros((slots,), jnp.int32),
+            "blk_steps": jnp.ones((slots,), jnp.int32),
+            "aux_diffusion_passes": jnp.zeros((), jnp.int32),
+            "aux_diffusion_commits": jnp.zeros((), jnp.int32)}
+
+
 def step_compiler_options(platform):
     """Compiler options the serving step is jitted with for ``platform``.
 
@@ -401,7 +507,12 @@ def _mixed_step_program(params, adapter, chunk, spec, pool, p_ids, p_slot,
 
     Returns (pool', first_token, tokens, valid, snapshot): the first
     token is -1 unless ``p_done``; tokens/valid are [chunk, slots] without
-    speculation, [chunk, slots, spec_k+1] with it; the snapshot is
+    speculation, [chunk, slots, spec_k+1] with it; for a model that
+    generates by diffusion over blocks (``adapter.block_length`` > 1: the
+    lane is ``_block_lane`` and the scan ``_diffusion_chunk_program``, both
+    chosen by the adapter's block length at trace time) the first token is
+    always -1, tokens are [chunk, slots, block] and ``valid`` holds the
+    pass a token was unmasked in, plus one; the snapshot is
     ``kv_pool.snapshot_of(pool')``, the scalars a harvest reads, as outputs
     of their own. Only ``pool`` is donated, so everything beside it stays
     readable after the NEXT call has taken the pool: the engine dispatches
@@ -412,6 +523,36 @@ def _mixed_step_program(params, adapter, chunk, spec, pool, p_ids, p_slot,
     ``decode_scan``.
     """
     C = p_ids.shape[1]
+    block = getattr(adapter, "block_length", 1)
+
+    def _block_lane(pool):
+        # The lane of a model that generates by diffusion over blocks: the
+        # slice is whole blocks of the prompt (``p_valid`` of them real, 0
+        # where the prompt is shorter than a block), no token is sampled,
+        # and the prompt's last slice opens the first generated block: what
+        # is left of the prompt (``known`` tokens, riding the slice's columns
+        # right after the real ones) beside masked positions. Such a model is
+        # served greedily and to its budget (``submit`` refuses a top-k and a
+        # stop token), so the lane's two arguments for them carry what it
+        # needs instead, at no argument and no eager write more: ``p_top_k``
+        # the request's denoising passes a block, ``p_eos`` how many tokens
+        # of its prompt open the block. ``remaining`` counts positions from
+        # that block's first to the request's last.
+        cache = slot_cache_view(pool, p_slot, p_frontier[None])
+        _, cache = adapter.prefill_append(
+            params, p_ids, cache, n_valid=p_valid[None])
+        pool = write_slot_cache(pool, p_slot, cache)
+        known = p_eos
+        opened = jax.lax.dynamic_slice(p_ids[0], (p_valid,), (block,))
+        for name, val in (("blk_tok", opened),
+                          ("blk_mask", jnp.arange(block) >= known),
+                          ("blk_pass", jnp.int32(0)),
+                          ("blk_steps", p_top_k), ("active", p_done),
+                          ("remaining", known + p_max_new)):
+            pool[name] = pool[name].at[p_slot].set(
+                jnp.where(p_done, val, pool[name][p_slot]))
+        pool["pos"] = pool["pos"].at[p_slot].set(p_frontier + p_valid)
+        return pool, jnp.int32(-1)
 
     def _lane(pool):
         # slot_cache_view carries the hierarchy along: scale-plane
@@ -453,9 +594,17 @@ def _mixed_step_program(params, adapter, chunk, spec, pool, p_ids, p_slot,
         return pool, jnp.where(p_done, first, jnp.int32(-1))
 
     with jax.named_scope("prefill_lane"):
-        pool, first = jax.lax.cond(
-            p_valid > 0, _lane, lambda pool: (pool, jnp.int32(-1)), pool)
-    if spec is None:
+        if block > 1:
+            pool, first = jax.lax.cond(
+                (p_valid > 0) | p_done, _block_lane,
+                lambda pool: (pool, jnp.int32(-1)), pool)
+        else:
+            pool, first = jax.lax.cond(
+                p_valid > 0, _lane, lambda pool: (pool, jnp.int32(-1)), pool)
+    if block > 1:
+        pool, toks, valid = _diffusion_chunk_program(params, adapter, chunk,
+                                                     pool)
+    elif spec is None:
         pool, toks, valid = _decode_chunk_program(params, adapter, chunk,
                                                   pool)
     else:
@@ -565,6 +714,20 @@ class InferenceEngine(object):
         slack = config.prefill_chunk
         if self._spec is not None:
             slack = max(slack, config.spec_k + 1)
+        # What the adapter says of HOW the model makes its tokens: a block
+        # length past 1 is generation by diffusion over blocks, and picks the
+        # lane and the scan of the one program (``_mixed_step_program``).
+        # ``_lookahead``: the positions past where a row stands at a step's
+        # start that the step's decode lane can write. A block takes two
+        # passes at the least, so a scan of ``chunk_size`` passes commits at
+        # most half as many blocks and writes one more.
+        self._block = int(getattr(self._adapter, "block_length", 1))
+        if self._block > 1:
+            self._lookahead = self._block * (config.chunk_size // 2 + 1)
+            slack = max(slack, self._lookahead)
+        else:
+            self._lookahead = config.chunk_size * (
+                config.spec_k + 1 if self._spec is not None else 1)
         self._slack = slack
         # KV memory hierarchy (inference/kv_hierarchy): None when every
         # tier is off — the flat pool, bit-for-bit the pre-hierarchy
@@ -666,7 +829,14 @@ class InferenceEngine(object):
         self.recompile_detector.watch("mixed_step", self._mixed)
 
         self.timers = SynchronizedWallClockTimer(registry=self.telemetry)
-        self.counters = _CounterBank(self.telemetry, (
+        self.counters = _CounterBank(self.telemetry, ((
+            # Generation by diffusion over blocks (docs/OBSERVABILITY.md):
+            # live (slot, iteration) places of the scan, those of them that
+            # were commit passes, tokens delivered by an unmasking, blocks
+            # committed. Registered for such a model only.
+            "diffusion_passes", "diffusion_commit_passes",
+            "diffusion_tokens_unmasked", "diffusion_blocks_committed")
+            if self._block > 1 else ()) + (
             "tokens_out", "chunks", "steps_dispatched_ahead", "prefills",
             "prefill_tokens", "lane_steps",
             "requests_completed", "occupied_slot_steps", "slot_steps",
@@ -834,7 +1004,12 @@ class InferenceEngine(object):
         # whatever the run length; metrics() derives mean/p50/p99 and
         # the draft acceptance rate from it. ``_accept_base`` is the
         # window floor metrics(reset=True) advances.
-        self._accept_hist = np.zeros(config.spec_k + 2, np.int64)
+        # For a model that generates by diffusion over blocks the same pair
+        # holds the tokens a LIVE pass delivered (index = count, 0..block: a
+        # commit pass delivers none).
+        self._accept_hist = np.zeros(
+            self._block + 1 if self._block > 1 else config.spec_k + 2,
+            np.int64)
         self._accept_base = np.zeros_like(self._accept_hist)
         self._t0 = time.time()
         self._window_t0 = self._t0
@@ -876,6 +1051,9 @@ class InferenceEngine(object):
             k = pool["k"]
             self.telemetry.gauge("kv_latent_bytes_token").set(
                 k.shape[0] * k.shape[2] * k.shape[4] * k.dtype.itemsize)
+        if self._block > 1:
+            pool = dict(pool, **block_state(self.config.max_slots,
+                                            self._block))
         aux = self._adapter.aux_state()
         if aux:
             # Adapter-owned pool state (``aux_`` keys): threaded through
@@ -1018,12 +1196,22 @@ class InferenceEngine(object):
         max_new, so the admission-time max_len bound still holds.
         Mid-prefill requests (m == 0) simply replay their prompt."""
         for req in reqs:
+            if req.open_lanes:
+                # Generation by diffusion over blocks: the tokens of a block
+                # that was open when the pool died go back (the one place a
+                # handle's tokens shrink), so that the replayed prompt ends
+                # on a block's boundary and the block is made again whole.
+                del req.tokens[-len(req.open_lanes):]
+                del req.passes[-len(req.open_lanes):]
+                req.open_lanes = []
             m = len(req.tokens)
             if m == 0:
                 continue
+            # (a replay after a replay: the prompt holds the earlier ones)
             req.prompt = np.concatenate(
-                [req.prompt, np.asarray(req.tokens, np.int32)])
-            req.max_new_tokens -= m
+                [req.prompt, np.asarray(req.tokens[req.replayed:], np.int32)])
+            req.max_new_tokens -= m - req.replayed
+            req.replayed = m
 
     def _recover(self, exc):
         """Crash-only recovery from a fatal step error: the pool was
@@ -1098,7 +1286,8 @@ class InferenceEngine(object):
 
     def submit(self, prompt, max_new_tokens=None, temperature=0.0,
                top_k=None, eos_token_id=None, seed=0, spec_decode=None,
-               deadline_ms=None, priority=None, tenant=None, trace=None):
+               deadline_ms=None, priority=None, tenant=None, trace=None,
+               denoising_steps=None):
         """Queue one request; returns its Request handle. Raises
         scheduler.QueueFull past ``max_queue`` pending requests
         (backpressure — structured with queue_depth + a retry_after_s
@@ -1118,7 +1307,13 @@ class InferenceEngine(object):
         that class's OWN retry_after_s hint. ``trace``: a propagated
         telemetry.distributed.TraceContext — the fleet / front door pass
         the one they minted so every hop of the request rides one Chrome
-        tid; None mints a local context (tid = rid, as ever)."""
+        tid; None mints a local context (tid = rid, as ever).
+        ``denoising_steps``: for a model that generates by diffusion over
+        blocks, the denoising passes a block of this request gets before
+        its commit (None: ``inference.denoising_steps``, else the block
+        length); it must divide the block length. Such a model is served
+        greedily and to its budget: a temperature, a top-k or a stop token
+        is refused by name."""
         if not self._health.accepting:
             if self._health.state == "dead":
                 raise EngineDeadError(
@@ -1158,6 +1353,27 @@ class InferenceEngine(object):
                         self.config.kv_page_len, self._pager.total_pages))
         if eos_token_id is None:
             eos_token_id = self.config.eos_token_id
+        if self._block == 1:
+            if denoising_steps is not None:
+                raise ValueError(
+                    "submit(denoising_steps=) on a model that makes its "
+                    "tokens one a pass (block_length 1)")
+        else:
+            if denoising_steps is None:
+                denoising_steps = self.config.denoising_steps or self._block
+            denoising_steps = int(denoising_steps)
+            if denoising_steps < 1 or self._block % denoising_steps:
+                raise ValueError(
+                    "denoising_steps {} must divide block_length {}".format(
+                        denoising_steps, self._block))
+            if temperature or top_k or eos_token_id is not None:
+                raise ValueError(
+                    "a model that generates by diffusion over blocks is "
+                    "served greedily to its budget: temperature, top_k and "
+                    "eos_token_id are not built (a position is unmasked by "
+                    "its argmax's confidence, and a block's tokens are "
+                    "delivered as they are unmasked, so a stop inside a "
+                    "block would take tokens back)")
         if spec_decode and self._spec is None:
             raise ValueError(
                 "submit(spec_decode=True) on an engine without speculation; "
@@ -1178,7 +1394,7 @@ class InferenceEngine(object):
                 int(seed),
                 spec=self._spec is not None and spec_decode is not False,
                 deadline=deadline, priority=priority, tenant=tenant,
-                trace=trace)
+                trace=trace, denoising_steps=denoising_steps)
         except QueueFull as exc:
             raise self._augment_queue_full(exc) from None
 
@@ -1267,20 +1483,59 @@ class InferenceEngine(object):
         only once — the original first token's latency is the only TTFT
         truth. ``step``: the device step the token was harvested from."""
         req.tokens.append(first)
-        if req.first_token_time is None:
-            req.first_token_time = time.time()
-            self._ttft_hist.observe(req.first_token_time - req.submit_time)
-            self._lane_wait_hist.observe(req.lane_time - req.admit_time)
-            self._lane_run_hist.observe(req.last_slice_time - req.lane_time)
-            self._first_lag_hist.observe(
-                req.first_token_time - req.last_slice_time)
-            self.tracer.instant(
-                "request/first_token", tid=req.trace.tid, rid=req.rid,
-                hop=req.trace.hop(), step=step, **req.phase_ms())
+        self._stamp_first_token(req, step)
         self.counters["tokens_out"] += 1
         if req.max_new_tokens <= 1 or \
                 (req.eos_token_id >= 0 and first == req.eos_token_id):
             self._complete(req, done)
+
+    def _stamp_first_token(self, req, step):
+        """TTFT and its three parts, once a request: at the harvest that
+        put its first token on the handle (the lane's sampled token; for a
+        model that generates by diffusion over blocks, the first
+        unmasking's)."""
+        if req.first_token_time is not None:
+            return
+        req.first_token_time = time.time()
+        self._ttft_hist.observe(req.first_token_time - req.submit_time)
+        self._lane_wait_hist.observe(req.lane_time - req.admit_time)
+        self._lane_run_hist.observe(req.last_slice_time - req.lane_time)
+        self._first_lag_hist.observe(
+            req.first_token_time - req.last_slice_time)
+        self.tracer.instant(
+            "request/first_token", tid=req.trace.tid, rid=req.rid,
+            hop=req.trace.hop(), step=step, **req.phase_ms())
+
+    def _deliver_unmasked(self, req, toks, passes, step):
+        """Hand ``req`` what a step's scan unmasked of its blocks: ``toks``
+        / ``passes`` [chunk, block] as ``_diffusion_chunk_program`` returns
+        them for its slot. A block's positions are unmasked in no order, and
+        a handle's ``tokens`` read in the order of positions at every moment:
+        a token is INSERTED among its open block's (``req.open_lanes``: the
+        positions of the block that the tail of ``tokens`` holds), with the
+        pass it was unmasked in beside it (``req.passes``, a byte a token).
+        A block is closed by arithmetic: once it holds every position it has
+        before the request's end. Returns the tokens delivered."""
+        length, p = self._block, int(req.prompt.size)
+        # tokens a recovery's replay folded into the prompt (``p`` holds
+        # them too) stay on the handle, before this admission's
+        before = req.replayed
+        delivered = []
+        for t in np.flatnonzero(passes.any(axis=1)):
+            closed = len(req.tokens) - len(req.open_lanes)
+            first = (p + closed - before) // length * length
+            for i in np.flatnonzero(passes[t]).tolist():
+                at = bisect.bisect_left(req.open_lanes, i)
+                req.open_lanes.insert(at, i)
+                req.tokens.insert(closed + at, int(toks[t, i]))
+                req.passes.insert(closed + at, int(passes[t, i]) - 1)
+                delivered.append(int(toks[t, i]))
+            if len(req.tokens) - before == min(
+                    first + length, p + req.max_new_tokens) - p:
+                req.open_lanes = []
+        if delivered:
+            self._stamp_first_token(req, step)
+        return delivered
 
     def _complete(self, req, done):
         """Evict ``req``'s slot and fold its latency into the
@@ -1426,11 +1681,10 @@ class InferenceEngine(object):
         (the table's unmapped entries are 0), so lookahead only needs to
         cover positions a later read can see: the decode lane advances
         each active slot at most chunk (or chunk * (spec_k+1) with
-        speculation) positions, the prefill lane n_valid positions at
-        the cursor."""
+        speculation; ``_lookahead``) positions, the prefill lane n_valid
+        positions at the cursor."""
         pager = self._pager
-        lookahead = self.config.chunk_size * (
-            (self.config.spec_k + 1) if self._spec is not None else 1)
+        lookahead = self._lookahead
         if pf is not None:
             upto = int(pf.cursor) + int(n_valid)
             if p_done:
@@ -2062,11 +2316,34 @@ class InferenceEngine(object):
                 return None
             C = self.config.prefill_chunk
             ids = np.zeros((1, C), np.int32)
-            if pf is not None:
+            advance = 0
+            if pf is not None and self._block > 1:
+                # Whole blocks of the prompt go through the lane; what is
+                # left of it (fewer tokens than a block) opens the first
+                # generated block, and rides the last slice's columns right
+                # after the real ones. A slice that is full has no room for
+                # them: one more, of no real column, is then the last.
+                cur = pf.cursor
+                tail = int(pf.prompt.size) % self._block
+                body = int(pf.prompt.size) - tail
+                n = int(min(C, body - cur))
+                ids[0, :n] = pf.prompt[cur:cur + n]
+                p_done = cur + n >= body and (not tail or n + tail <= C)
+                if p_done:
+                    ids[0, n:n + tail] = pf.prompt[body:]
+                slot, frontier, n_valid = pf.slot, cur, n
+                advance = n + (tail if p_done else 0)
+                p_spec = False
+                # ``_block_lane``: the two arguments a greedy model served
+                # to its budget has no use for carry the block's
+                max_new, eos = pf.max_new_tokens, tail
+                temp, top_k, seed = 0.0, pf.denoising_steps, pf.seed
+            elif pf is not None:
                 cur = pf.cursor
                 n = int(min(C, pf.prompt.size - cur))
                 ids[0, :n] = pf.prompt[cur:cur + n]
                 slot, frontier, n_valid = pf.slot, cur, n
+                advance = n
                 p_done = cur + n >= pf.prompt.size
                 p_spec = pf.spec
                 max_new, eos = pf.max_new_tokens, pf.eos_token_id
@@ -2155,20 +2432,50 @@ class InferenceEngine(object):
         # before this one is harvested: the cursor moves by the slice, a
         # prompt's last slice makes its request a row of THIS step's
         # decode lane (first token sent), and without speculation every
-        # row emits ``chunk_size`` tokens or what is left of its budget.
-        if pf is not None and sched.advance_prefill(pf, n_valid):
-            pf.sent = 1
+        # row emits ``chunk_size`` tokens or what is left of its budget
+        # (generation by diffusion over blocks: it commits the blocks whose
+        # passes fit, ``_advance_blocks``).
+        if pf is not None and sched.advance_prefill(pf, advance):
+            pf.sent = int(self._block == 1)
             rows[slot] = pf
         if self._spec is None:
             for req in rows.values():
-                req.sent = min(req.sent + self.config.chunk_size,
-                               req.max_new_tokens)
+                if self._block > 1:
+                    self._advance_blocks(req)
+                else:
+                    req.sent = min(req.sent + self.config.chunk_size,
+                                   req.max_new_tokens)
                 if self._depth and req.sent >= req.max_new_tokens:
                     # Its budget runs out inside this step, EOS or not: on
                     # the chip the slot is inactive when the step ends, so
                     # the next step's admission may have it at once.
                     self._release(req)
         return flight
+
+    def _advance_blocks(self, req):
+        """The host's arithmetic on a row of ``_diffusion_chunk_program``
+        for the step just dispatched: a block of ``m`` masked positions
+        takes ``ceil(m / (block / S)) + 1`` passes whatever its tokens turn
+        out to be, so ``req.sent`` (the tokens of the blocks whose commit
+        pass is dispatched, of ``max_new_tokens`` at the most) and
+        ``req.block_passes`` (the passes the open block has had) move by
+        ``chunk_size`` passes here, before the step is harvested. ``p +
+        sent`` is then the open block's first position, which is what the
+        page mapping reads, and ``sent == max_new_tokens`` says the slot is
+        inactive when the step ends."""
+        length = self._block
+        a_pass = length // req.denoising_steps
+        left = self.config.chunk_size
+        while left and req.sent < req.max_new_tokens:
+            masked = length - (int(req.prompt.size) % length
+                               if req.sent == 0 else 0)
+            need = -(-masked // a_pass) + 1 - req.block_passes
+            took = min(need, left)
+            left -= took
+            req.block_passes += took
+            if took == need:
+                req.sent = min(req.sent + masked, req.max_new_tokens)
+                req.block_passes = 0
 
     def _release(self, req):
         """Free the slot and the pages of a request whose last tokens are
@@ -2221,6 +2528,10 @@ class InferenceEngine(object):
             # Numerics gate: AFTER the device sync, BEFORE any token reaches
             # a request — a garbage harvest is discarded whole, which is
             # what keeps replay recovery bit-identical.
+            passes = None
+            if self._block > 1:
+                # ``valid`` came as the pass a token was unmasked in, plus 1
+                passes, valid = valid, valid > 0
             self._check_harvest(toks, valid)
             self.counters["chunks"] += 1
             if toks.ndim == 2:
@@ -2230,8 +2541,26 @@ class InferenceEngine(object):
                 toks = toks[:, :, None]
                 valid = valid[:, :, None]
             occupied = valid.any(axis=2)
-            self.counters["occupied_slot_steps"] += int(occupied.sum())
             self.counters["slot_steps"] += occupied.size
+            if passes is not None:
+                # A LIVE slot an iteration is occupied, a commit pass too,
+                # whatever it delivered: the scan's own count of them.
+                live = int(snap["aux_diffusion_passes"])
+                commits = int(snap["aux_diffusion_commits"])
+                self.counters["occupied_slot_steps"] += live
+                self.counters["diffusion_passes"] += live
+                self.counters["diffusion_commit_passes"] += commits
+                self.counters["diffusion_blocks_committed"] += commits
+                self.counters["diffusion_tokens_unmasked"] += int(valid.sum())
+                hist = np.bincount(valid.sum(axis=2)[occupied],
+                                   minlength=self._accept_hist.size)
+                hist[0] = live - int(occupied.sum())
+                self._accept_hist += hist
+                for k, count in enumerate(self._accept_hist):
+                    self.telemetry.gauge("diffusion_unmasked_per_pass",
+                                         tokens=str(k)).set(int(count))
+            else:
+                self.counters["occupied_slot_steps"] += int(occupied.sum())
             if self._spec is not None:
                 self._accept_hist += np.bincount(
                     valid.sum(axis=2)[occupied],
@@ -2259,7 +2588,8 @@ class InferenceEngine(object):
                         # (eager copy; no compile).
                         self._pool = self._hier.on_prefill_done(self._pool, pf)
                     self._scheduler.prefill_done(pf, flight.lane_slot)
-                    self._harvest_first(pf, int(first), done, step)
+                    if passes is None:
+                        self._harvest_first(pf, int(first), done, step)
 
             harvest_t = time.time()
             for slot, req in flight.rows.items():
@@ -2267,8 +2597,12 @@ class InferenceEngine(object):
                     continue  # cancelled, or ended by EOS a step ago
                 # Boolean-mask select flattens row-major — (step, lane) IS
                 # emission order.
-                emitted = toks[:, slot][valid[:, slot]].tolist()
-                req.tokens.extend(emitted)
+                if passes is None:
+                    emitted = toks[:, slot][valid[:, slot]].tolist()
+                    req.tokens.extend(emitted)
+                else:
+                    emitted = self._deliver_unmasked(
+                        req, toks[:, slot], passes[:, slot], step)
                 self.counters["tokens_out"] += len(emitted)
                 if emitted:
                     # Progress stamp the idle-aware swap-victim policy
@@ -2560,6 +2894,30 @@ class InferenceEngine(object):
                 "draft_accept_rate": (
                     round(float((acc - 1).sum()) / (self.config.spec_k * n),
                           4) if n else None),
+            })
+        if self._block > 1:
+            # Generation by diffusion over blocks: the window's passes (a
+            # live slot an iteration), what they delivered, and the tokens a
+            # pass delivered as a histogram (index = count; a commit pass
+            # delivers none).
+            live = c.window("diffusion_passes")
+            m.update({
+                "block_length": self._block,
+                "diffusion_passes": live,
+                "diffusion_commit_passes":
+                    c.window("diffusion_commit_passes"),
+                "diffusion_tokens_unmasked":
+                    c.window("diffusion_tokens_unmasked"),
+                "diffusion_blocks_committed":
+                    c.window("diffusion_blocks_committed"),
+                "tokens_per_pass": round(
+                    c.window("diffusion_tokens_unmasked")
+                    / float(max(live, 1)), 4),
+                "commit_pass_share": round(
+                    c.window("diffusion_commit_passes")
+                    / float(max(live, 1)), 4),
+                "unmasked_per_pass_hist":
+                    (self._accept_hist - self._accept_base).tolist(),
             })
         if self._hier is not None:
             h = self._hier

@@ -29,7 +29,9 @@ phase.
 The engine keeps one step in flight (``InferenceEngine._step_once``), so
 the records here run AHEAD of the tokens harvested by what the host can
 know by arithmetic: ``cursor`` and ``sent`` move when a step is dispatched,
-and a request whose budget ends inside the step just dispatched gives up
+and a request whose budget ends inside the step just dispatched (for a model
+that generates by diffusion over blocks: whose last block's commit pass is in
+it, which at a fixed number of passes a block is arithmetic too) gives up
 its slot at once (``release``): still ``decoding``, slotless, it waits in
 ``landing`` for the harvest that completes it.
 
@@ -118,11 +120,13 @@ class Request(object):
                  "cursor", "submit_time", "admit_time", "lane_time",
                  "last_slice_time", "slices", "first_token_time",
                  "finish_time", "deadline", "replays", "last_touch",
-                 "priority", "tenant", "trace", "sent")
+                 "priority", "tenant", "trace", "sent", "denoising_steps",
+                 "passes", "open_lanes", "block_passes", "replayed")
 
     def __init__(self, rid, prompt, max_new_tokens, temperature, top_k,
                  eos_token_id, seed, spec=False, deadline=None,
-                 priority=None, tenant=None, trace=None):
+                 priority=None, tenant=None, trace=None,
+                 denoising_steps=None):
         self.rid = rid
         # Propagated trace identity (telemetry/distributed.py): the
         # Chrome tid every lifecycle event rides plus the shared hop
@@ -157,6 +161,21 @@ class Request(object):
         # Arithmetic on the budget, so it is kept only where a step's
         # emission count is the host's to know (no speculation).
         self.sent = 0
+        # Generation by diffusion over blocks (None, and the three below
+        # unused, for a model that makes its tokens one a pass). A pass over
+        # a slot yields no token, or several, of a block's positions in no
+        # order: ``denoising_steps`` is the request's passes a block before
+        # its commit; ``passes[i]`` the pass of its block in which
+        # ``tokens[i]`` was unmasked (a byte a token); ``open_lanes`` the
+        # positions, within the block still open, of the tokens at the tail
+        # of ``tokens`` (which reads in position order at every moment, so a
+        # delivery INSERTS there); ``block_passes`` the passes DISPATCHED for
+        # the open block, the host's arithmetic beside ``sent``, which then
+        # counts the tokens of the blocks whose commit pass is dispatched.
+        self.denoising_steps = denoising_steps
+        self.passes = bytearray()
+        self.open_lanes = []
+        self.block_passes = 0
         self.submit_time = time.time()
         self.admit_time = None
         # The way through the one prefill lane, on the clock of
@@ -181,6 +200,10 @@ class Request(object):
         # emitted stream stays one stream across replays — tokens only
         # ever grow.
         self.replays = 0
+        # Tokens of ``tokens`` that recovery's replays have folded into
+        # ``prompt`` (``engine._replay_requests``): the handle keeps them,
+        # and this admission's tokens come after them.
+        self.replayed = 0
         # Wall clock of the last PROGRESS this request made (submit,
         # then each step that emitted it tokens — the engine stamps at
         # harvest). The swap-victim policy reads it: staleness here
@@ -330,12 +353,13 @@ class Scheduler(object):
 
     def submit(self, prompt, max_new_tokens, temperature, top_k,
                eos_token_id, seed, spec=False, deadline=None,
-               priority=None, tenant=None, trace=None):
+               priority=None, tenant=None, trace=None, denoising_steps=None):
         if len(self.queue) >= self.max_queue:
             raise self.queue_full_error(priority=priority, tenant=tenant)
         req = Request(next(self._ids), prompt, max_new_tokens, temperature,
                       top_k, eos_token_id, seed, spec, deadline=deadline,
-                      priority=priority, tenant=tenant, trace=trace)
+                      priority=priority, tenant=tenant, trace=trace,
+                      denoising_steps=denoising_steps)
         if deadline is not None:
             self._has_deadlines = True
         self.queue.append(req)
@@ -411,6 +435,7 @@ class Scheduler(object):
             req.cursor = 0
             req.slices = 0
             req.sent = 0
+            req.block_passes = 0
             self.running[slot] = req
             pairs.append((req, slot))
             if not first_admission:

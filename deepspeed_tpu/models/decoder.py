@@ -78,6 +78,21 @@ of 128: the paged kernels put the 20 beside ``S`` on the sublane axis, and a
 page of one head is small enough that a unit of the decode scan's call joins
 eight, ``decode_attention.py`` ``_pages_per_unit``).
 
+And a sixth time SDAR's (``model_type`` ``sdar_moe``: SDAR-30B-A3B-Chat), by
+NO new piece of the block (32 query heads over 4 stored heads of 128,
+``qk_norm`` ``"head"``, 128 experts of 768, 8 a token, renormalised) and two
+fields that say how its tokens are made: ``block_length`` and
+``mask_token_id``. It generates by DIFFUSION OVER BLOCKS: a block of
+``block_length`` absolute positions is passed over repeatedly, its still-masked
+positions carrying the mask id's embedding, every position of the block seeing
+every other and all earlier blocks (the one visibility rule,
+``decode_attention.visible_upto``); the logits are read AT a masked position
+(no shift). ``forward`` knows none of this beyond the rule: a pass is ``ids``
+[B, block_length] at a block's start, written at ``[pos, pos + block_length)``
+with ``pos`` left where it was (the adapter's ``block_pass``), and which
+positions are unmasked when is the serving engine's scan
+(``inference/engine.py`` ``_diffusion_chunk_program``).
+
 THE ABSORBED FORM IS THE ONE PATH of latent attention, for the lane and the
 scan alike. Per head ``[k_nope_h | v_h] = c_kv W_kvb,h``, so
 ``q_nope_h . k_nope_h(u) = (q_nope_h W_uk,h) . c_kv(u)`` and
@@ -240,6 +255,16 @@ class DecoderConfig(typing.NamedTuple):
     # in ``dtype``. A stack tens of layers deep rounds a ``dtype`` stream
     # twice a layer. False: the stream is in ``dtype``.
     residual_fp32: bool = False
+    # What the model IS, not how it is served: it generates by DIFFUSION OVER
+    # BLOCKS of ``block_length`` positions (module docstring). A key at
+    # absolute position ``j`` is seen by a query at ``i`` iff ``j <= (i //
+    # block_length + 1) * block_length - 1``: causal across blocks, both ways
+    # inside one (``decode_attention.visible_upto``, the one expression
+    # wherever a mask is formed). 1 is next-token generation, whose rule that
+    # then IS the causal one. ``mask_token_id``: the id whose embedding a
+    # still-masked position carries.
+    block_length: int = 1
+    mask_token_id: typing.Optional[int] = None
 
     @property
     def stream_dtype(self):

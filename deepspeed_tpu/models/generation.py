@@ -190,6 +190,10 @@ class CacheAttention(object):
         self.nkv = getattr(cfg, "n_kv", self.nh)
         self.rep = self.nh // self.nkv
         self.scale = getattr(cfg, "attn_scale", None)
+        # A model that generates by diffusion over blocks (the decoder
+        # block's ``block_length``; 1: next-token) sees a whole block of
+        # positions both ways: ``decode_attention.visible_upto``.
+        self.block = getattr(cfg, "block_length", 1)
         B = cache["pos"].shape[0]
         self.pos = pos = cache["pos"]                  # [B] row frontiers
         self.int8 = cache["k"].dtype == jnp.int8
@@ -237,6 +241,12 @@ class CacheAttention(object):
             decode_attention.decode_supported(
                 self.page_len if self.paged else max_len) and \
             (self.paged or not self.latent)    # no dense latent kernel
+        if self.block > 1 and self.use_flash and (
+                not self.paged or self.int8 or self.latent):
+            raise ValueError(
+                "block visibility (block_length {}) is built into the einsum "
+                "path and the paged decode kernel; the dense, int8 and "
+                "latent kernels mask causally".format(self.block))
         sparse_thr = getattr(cfg, "sparse_threshold", 0)
         if sparse_thr and self.use_flash:
             raise ValueError(
@@ -250,7 +260,8 @@ class CacheAttention(object):
             # by the same comparison (they hold zeros, or a stale request's
             # k/v, which decode overwrites before the frontier reaches
             # them).
-            mask = k_pos[None, None, :] <= self.q_pos[:, :, None]
+            mask = k_pos[None, None, :] <= decode_attention.visible_upto(
+                self.q_pos, self.block)[:, :, None]
             if sparse_thr:
                 # Long-context composition: rows whose query position
                 # crossed the threshold see only the block-sparse layout;
@@ -445,7 +456,8 @@ class CacheAttention(object):
                     else:
                         y = decode_attention.flash_decode_attention_paged(
                             q, k_cache, v_cache, self.tbl, pos,
-                            scale=scale, name=self.attn_name, layer=i)
+                            scale=scale, name=self.attn_name, layer=i,
+                            block=self.block)
                 elif int8:
                     y = decode_attention.flash_decode_attention_q8(
                         q, k_eff, v_eff, ks_eff, vs_eff, pos,

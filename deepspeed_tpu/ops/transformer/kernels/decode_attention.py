@@ -233,8 +233,24 @@ def dequantize_kv(codes, scale, dtype=jnp.float32):
 # softmax) so flag-off and fallback paths are the SAME math.
 # ---------------------------------------------------------------------------
 
+def visible_upto(q_pos, block=1):
+    """The last key position a query at absolute position ``q_pos`` sees: its
+    own where ``block`` is 1 (the causal rule, and then the value itself: a
+    next-token model traces what it always has), else the last position of
+    its block of ``block`` absolute positions, ``(q_pos // block + 1) * block
+    - 1``: causal across blocks, both ways inside one (a model that generates
+    by diffusion over blocks, ``models/decoder.py`` ``block_length``). THE ONE
+    EXPRESSION wherever a mask is formed: the einsum path
+    (``generation.CacheAttention``), the references here and the paged
+    kernel's straddle mask (the decode scan's and ``prefill_attn`` alike).
+    ``block`` is static; ``q_pos`` is never negative."""
+    if block == 1:
+        return q_pos
+    return (_div(q_pos, block) + 1) * block - 1
+
+
 @hot_path
-def decode_attention_reference(q, k, v, pos, scale=None):
+def decode_attention_reference(q, k, v, pos, scale=None, block=1):
     """q: [B, H, S, D] query rows, row b starting at global position
     ``pos[b]`` (its k/v already written at ``pos[b] .. pos[b]+S-1``);
     k, v: [B, H, T, D] cache planes; pos: [B] int32 frontiers.
@@ -246,7 +262,8 @@ def decode_attention_reference(q, k, v, pos, scale=None):
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     prec = _mxu_precision(q.dtype)
     q_pos = pos[:, None] + jnp.arange(S)[None]               # [B, S]
-    mask = jnp.arange(T)[None, None, :] <= q_pos[:, :, None]  # [B, S, T]
+    mask = jnp.arange(T)[None, None, :] <= \
+        visible_upto(q_pos, block)[:, :, None]                # [B, S, T]
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32), precision=prec) * scale
     s = jnp.where(mask[:, None], s, jnp.finfo(jnp.float32).min)
@@ -821,7 +838,8 @@ def gather_pages(arena, block_tbl, h, g):
 
 
 @hot_path
-def decode_attention_paged_reference(q, k, v, block_tbl, pos, scale=None):
+def decode_attention_paged_reference(q, k, v, block_tbl, pos, scale=None,
+                                     block=1):
     """Paged ground truth: gather each row's pages into its dense
     logical plane, then the dense reference — the same math the engine's
     einsum (flag-off) path computes, so kernel-on and kernel-off paged
@@ -836,7 +854,7 @@ def decode_attention_paged_reference(q, k, v, block_tbl, pos, scale=None):
     k, v = (gather_pages(a, block_tbl, h // rep, g) for a in (k, v))
     if rep > 1:
         k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
-    return decode_attention_reference(q, k, v, pos, scale=scale)
+    return decode_attention_reference(q, k, v, pos, scale=scale, block=block)
 
 
 @hot_path
@@ -1036,7 +1054,7 @@ def _paged_units(tbl, pos, s_len, page_len, k_pages=1):
 
 def _paged_kernel(rows_ref, us_ref, pages_ref, pos_ref, live_ref, q_ref,
                   *refs, s_len, q8, single_kv, pack, rep=1, latent=0,
-                  k_pages=1):
+                  k_pages=1, block=1):
     """One grid step = one unit of ``_paged_units``: ``k_pages`` consecutive
     pages of all the heads (of the group, where all do not fit) of one row,
     a block each (``refs`` holds each arena's ``k_pages`` blocks in turn),
@@ -1048,7 +1066,10 @@ def _paged_kernel(rows_ref, us_ref, pages_ref, pos_ref, live_ref, q_ref,
     ``rep`` query heads of a stored head beside ``s_len`` the same way: a
     stored head's rows are ``rep * s_len``, row ``r`` still at position
     ``r % s_len``. ``latent`` > 0 (``latent_decode``): ONE arena, whose page
-    is the keys and, in its first ``latent`` lanes, the values."""
+    is the keys and, in its first ``latent`` lanes, the values. ``block``:
+    ``visible_upto``'s (1: causal); the rows of a call start at a block's
+    first position and a block never straddles a page, so the live pages and
+    the interior ones are the causal rule's."""
     n_a = 1 if latent else 4 if q8 else 2
     k_refs, *more = (refs[a * k_pages:(a + 1) * k_pages] for a in range(n_a))
     v_refs, scale_refs = (None, ()) if latent else (more[0], more[1:])
@@ -1107,7 +1128,7 @@ def _paged_kernel(rows_ref, us_ref, pages_ref, pos_ref, live_ref, q_ref,
             q_pos = pos_b + row
             k_pos = j * page_len + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 2)
-            return jnp.where(k_pos <= q_pos, s, NEG_INF)
+            return jnp.where(k_pos <= visible_upto(q_pos, block), s, NEG_INF)
 
         # Interior pages (every key visible to even the FIRST query row)
         # skip the iota/compare/select pass.
@@ -1194,7 +1215,7 @@ def _paged_kernel(rows_ref, us_ref, pages_ref, pos_ref, live_ref, q_ref,
 
 
 def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1,
-                  latent=0):
+                  latent=0, block=1):
     """The one launcher of the paged families: ``arenas`` is (k, v) or
     (k, v, k_scale, v_scale), whole or one layer's (``layer`` None), or the
     ONE arena of a latent cache (``latent`` > 0: its value width; ``q`` is
@@ -1266,7 +1287,7 @@ def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1,
         name,
         functools.partial(_paged_kernel, s_len=s, q8=len(arenas) == 4,
                           single_kv=single_kv, pack=pack, rep=rep,
-                          latent=latent, k_pages=k_pages),
+                          latent=latent, k_pages=k_pages, block=block),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d_out), q.dtype),
     )(*units, q, *(a for a in arenas for _ in range(k_pages)))
@@ -1277,9 +1298,9 @@ def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1,
 
 
 def _flash_decode_paged_pallas(q, k, v, tbl, pos, scale,
-                               name=None, layer=None, pack=1, rep=1):
+                               name=None, layer=None, pack=1, rep=1, block=1):
     return _paged_launch(name or "paged_decode", q, (k, v), tbl, pos, scale,
-                         layer, pack, rep)
+                         layer, pack, rep, block=block)
 
 
 def _flash_decode_paged_q8_pallas(q, k, v, k_scale, v_scale, tbl, pos,
@@ -1291,7 +1312,8 @@ def _flash_decode_paged_q8_pallas(q, k, v, k_scale, v_scale, tbl, pos,
                          tbl, pos, scale, layer, pack, rep)
 
 
-def _paged_on_shards(launch, q, arenas, block_tbl, pos, scale, name, layer):
+def _paged_on_shards(launch, q, arenas, block_tbl, pos, scale, name, layer,
+                     **static):
     """Both paged families' way to their launcher: ``g`` heads a lane tile
     is read from the shapes, the queries are packed to match the arena and
     each head's output taken back out; on a mesh the PACKED heads are what
@@ -1310,7 +1332,7 @@ def _paged_on_shards(launch, q, arenas, block_tbl, pos, scale, name, layer):
     arena = "-h" if layer is None else "--h"
     out = on_shards(
         functools.partial(launch, scale=float(scale), name=name, layer=layer,
-                          pack=g, rep=rep),
+                          pack=g, rep=rep, **static),
         kernel_sharding(q.shape[0], q.shape[1]),
         ("bh",) + (arena,) * len(arenas) + ("b", "b"), ("bh",))(
             q, *arenas, block_tbl, pos)
@@ -1319,7 +1341,7 @@ def _paged_on_shards(launch, q, arenas, block_tbl, pos, scale, name, layer):
 
 @hot_path
 def flash_decode_attention_paged(q, k, v, block_tbl, pos, scale=None,
-                                 name=None, layer=None):
+                                 name=None, layer=None, block=1):
     """Block-table flash decode over a page arena.
 
     Args:
@@ -1339,6 +1361,9 @@ def flash_decode_attention_paged(q, k, v, block_tbl, pos, scale=None,
       pos: [B] int32 per-row frontiers.
       scale: score scale; default 1/sqrt(D).
       layer: static int, or None (see k, v).
+      block: static; ``visible_upto``'s block of positions (1: causal). Past
+        1 every ``pos`` is a block's first position, ``S`` and the page
+        length whole blocks.
 
     block_k is page_len by construction (kernel blocks == pages, all
     heads of a page a step), so there is no autotuned tile here;
@@ -1356,9 +1381,9 @@ def flash_decode_attention_paged(q, k, v, block_tbl, pos, scale=None,
         if layer is not None:
             k, v = k[layer], v[layer]
         return decode_attention_paged_reference(q, k, v, block_tbl, pos,
-                                                scale=scale)
+                                                scale=scale, block=block)
     return _paged_on_shards(_flash_decode_paged_pallas, q, (k, v), block_tbl,
-                            pos, scale, name, layer)
+                            pos, scale, name, layer, block=block)
 
 
 @hot_path

@@ -114,10 +114,22 @@ def ids_of(vocab, n, seed=5):
     return rng.randint(0, vocab, size=(1, n)).astype(np.int32)
 
 
+_COMPILED = {}
+
+
+def compiled(adapter, name):
+    """``adapter.<name>`` under ONE ``jax.jit`` an adapter, shared by every
+    case that only calls it: run eagerly, a two-layer forward dispatches (and,
+    a shape, compiles) some hundred small operations a call."""
+    if (adapter, name) not in _COMPILED:
+        _COMPILED[adapter, name] = jax.jit(getattr(adapter, name))
+    return _COMPILED[adapter, name]
+
+
 def greedy_decode(adapter, params, tok, cache, steps):
     out = []
     for _ in range(steps):
-        logits, cache = adapter.decode_step(
+        logits, cache = compiled(adapter, "decode_step")(
             params, jnp.asarray([tok], jnp.int32), cache)
         tok = int(jnp.argmax(logits[0]))
         out.append(tok)
@@ -217,10 +229,11 @@ def test_chunk_vs_whole_prefill_parity(kind):
 @pytest.mark.parametrize("kind", KINDS + PAGED)
 def test_append_at_deep_frontier_with_n_valid(kind):
     adapter, params, vocab = adapter_of(kind)
+    append = compiled(adapter, "prefill_append")
     ids = jnp.asarray(ids_of(vocab, 28, seed=7))
 
     clean = cache_of(kind, 1, 48)
-    logits, clean = adapter.prefill_append(params, ids, clean)
+    logits, clean = append(params, ids, clean)
     want, _ = greedy_decode(adapter, params,
                             int(jnp.argmax(logits[0, -1])), clean, 4)
 
@@ -229,14 +242,14 @@ def test_append_at_deep_frontier_with_n_valid(kind):
     # GARBAGE tokens past the frontier.
     garbage = jnp.asarray(ids_of(vocab, 2, seed=99))
     staged = cache_of(kind, 1, 48)
-    _, staged = adapter.prefill_append(params, ids[:, :24], staged)
+    _, staged = append(params, ids[:, :24], staged)
     tail = jnp.concatenate([ids[:, 24:26], garbage], axis=1)
-    _, staged = adapter.prefill_append(params, tail, staged,
+    _, staged = append(params, tail, staged,
                                        n_valid=jnp.asarray([2]))
     assert int(staged["pos"][0]) == 26, "n_valid must override the advance"
     # The true continuation overwrites the stale positions before any
     # query can attend them — the garbage must be invisible.
-    logits, staged = adapter.prefill_append(params, ids[:, 26:28], staged)
+    logits, staged = append(params, ids[:, 26:28], staged)
     got, _ = greedy_decode(adapter, params,
                            int(jnp.argmax(logits[0, -1])), staged, 4)
     assert got == want, "stale frontier write leaked into the stream"

@@ -948,6 +948,59 @@ def test_latent_mixed_step_appends_and_attends_the_one_arena_in_place(
                           for line in lines], arena) == []
 
 
+def test_diffusion_step_attends_a_block_at_32_rows_a_stored_head(
+        chip, monkeypatch):
+    """The mixed step of a model that generates by diffusion over blocks of 4
+    (SDAR-30B-A3B-Chat's attention at its published widths: 32 query heads
+    over 4 stored heads of 128; ONE layer and 8 experts, the depth and width
+    at which the property first shows; the cell's pool: 64 slots of 2304,
+    page 128, chunk 16, lane 128; seconds to compile): Mosaic takes
+    ``paged_decode`` with the block visibility rule in its straddle mask at
+    ``rep x block`` = 32 query rows a stored head and ``kv_append`` at 4 rows
+    a slot; the scan is the diffusion scan (a region ``unmask``), its arenas
+    meet those two kernels and nothing else, and the lane's kernel is
+    ``prefill_attn`` under the same rule."""
+    from deepspeed_tpu.inference import engine as engine_mod, kv_pool
+    from deepspeed_tpu.inference.adapters import DecoderAdapter
+    from deepspeed_tpu.inference.config import InferenceConfig
+    from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
+
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    slots, chunk, lane, block = 64, 16, 128, 4
+    model = DecoderLM(DecoderConfig(
+        vocab_size=151936, n_layer=1, n_head=32, head_dim=128,
+        hidden_size=2048, n_positions=32768, n_experts=8,
+        experts_per_token=2, expert_width=768, rms_norm_eps=1e-6,
+        rope_theta=1e6, qk_norm="head", norm_topk_prob=True, dtype=BF16,
+        n_kv_head=4, block_length=block, mask_token_id=151669))
+    config = InferenceConfig.from_dict(dict(
+        max_slots=slots, max_len=2304, chunk_size=chunk, paged_kv=True,
+        kv_page_len=PAGE, prefill_chunk=lane, use_flash_decode=True,
+        denoising_steps=2))
+    adapter = DecoderAdapter.from_model(model, use_flash_decode=True).bind(
+        config, None)
+    assert adapter.block_length == block
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0))["params"])
+    pool = jax.eval_shape(lambda: dict(
+        kv_pool.init_pool(adapter.cache_spec(), slots, 2304, slack=lane,
+                          page_len=PAGE),
+        **engine_mod.block_state(slots, block), **adapter.aux_state()))
+    assert pool["k"].shape == (1, 64 * 19 + 1, 4, PAGE, 128)
+
+    text = _mixed_step_text(chip, adapter, params, pool, chunk, lane)
+    comps = _computations(text)
+    scan, in_scan = _scan_lines(comps)
+    assert any("/unmask/" in line for line in in_scan)
+    names = sorted(c.split(".")[0] for c in _kernel_calls(
+        "\n".join(in_scan)))
+    assert names == ["kv_append", "paged_decode"]
+    everywhere = sorted(c.split(".")[0] for c in _kernel_calls(text))
+    assert everywhere == ["kv_append"] * 2 + ["paged_decode", "prefill_attn"]
+    arena = ["[1,1217,4,128,128]", "[1217,4,128,128]"]
+    assert _arena_shaped(in_scan, arena) == []
+
+
 def test_kimi_mixed_step_holds_three_caches_each_where_it_lies(
         chip, monkeypatch):
     """Kimi Linear's block at its published widths and the cell's 12 layers

@@ -827,6 +827,34 @@ def test_paged_body_matches_the_paged_reference(name):
     assert not got[list(frozen)].any()
 
 
+@pytest.mark.parametrize("s, dtype", [(4, jnp.float32), (4, jnp.bfloat16),
+                                      (128, jnp.bfloat16)])
+def test_paged_body_under_block_visibility(s, dtype):
+    """``visible_upto`` in the paged body's straddle mask (a model that
+    generates by diffusion over blocks of 4): 8 query heads over 2 stored
+    heads of 128, so ``rep x S`` = 16 rows of a pass (512 of a lane's slice)
+    share one read of a row's pages, at frontiers that start a block (a
+    page's last block, the next page's first, the plane's end). The body
+    against the gather reference under the same rule, which is NOT the
+    causal one: a query sees the positions of its block after it."""
+    rng = np.random.RandomState(3)
+    pos = [0, 128 - s, 128, 256 - s, 3 * _PAGE - s]
+    q, arenas, tbl, pos = _paged_operands(128, s, pos, False, dtype, h=2)
+    q = jnp.asarray(rng.randn(len(pos), 8, s, 128), dtype)
+    got = da.flash_decode_attention_paged(q, *arenas, tbl, pos, layer=1,
+                                          block=4)
+    want, causal = (da.decode_attention_paged_reference(
+        q.astype(jnp.float32), *(a[1].astype(jnp.float32) for a in arenas),
+        tbl, pos, block=b) for b in (4, 1))
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
+                               np.asarray(want), rtol=tol, atol=tol)
+    assert np.abs(np.asarray(want) - np.asarray(causal)).max() > 0.1
+    # the last position of a block sees what the causal rule shows it
+    np.testing.assert_allclose(np.asarray(want)[:, :, 3::4],
+                               np.asarray(causal)[:, :, 3::4], atol=1e-6)
+
+
 @pytest.mark.parametrize("int8", [False, True])
 @pytest.mark.parametrize("s", [1, 5])
 def test_frozen_rows_leave_live_rows_bit_for_bit(s, int8):

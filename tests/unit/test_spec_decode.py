@@ -21,6 +21,7 @@ The contract under test:
    submit() guard, and the KV-plane slack floor.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -263,15 +264,19 @@ def test_verify_forward_matches_stepwise_decode_and_keeps_pos():
     gcfg = as_gencfg(cfg, use_flash_decode=False)
     prompt = prompts_of(cfg, [6])[0]
     cache = init_cache(gcfg, 1, 32)
-    logits, cache = _forward(params, gcfg, jnp.asarray(prompt)[None], cache)
+    # each primitive under one jit: eagerly a forward is some hundred small
+    # dispatches, each compiled for its shape
+    forward, step, verify = (jax.jit(f, static_argnums=1) for f in (
+        _forward, decode_step, verify_forward))
+    logits, cache = forward(params, gcfg, jnp.asarray(prompt)[None], cache)
     t0 = jnp.argmax(logits[0, -1]).astype(jnp.int32)
 
-    l0, seq = decode_step(params, gcfg, t0[None], cache)
+    l0, seq = step(params, gcfg, t0[None], cache)
     t1 = jnp.argmax(l0[0]).astype(jnp.int32)
-    l1, seq = decode_step(params, gcfg, t1[None], seq)
+    l1, seq = step(params, gcfg, t1[None], seq)
 
     ids = jnp.stack([t0, t1])[None]                    # [1, 2]
-    vlog, ver = verify_forward(params, gcfg, ids, cache)
+    vlog, ver = verify(params, gcfg, ids, cache)
     np.testing.assert_allclose(np.asarray(vlog[0, 0]), np.asarray(l0[0]),
                                rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(vlog[0, 1]), np.asarray(l1[0]),
