@@ -906,6 +906,23 @@ class DeepSpeedEngine(object):
 
     # --------------------------------------------------------------- forward
 
+    def _lazy_init(self, inputs, kwargs):
+        """No parameters were given: initialise them from the sharded batch's
+        shapes (flax idiom; the reference gets params from the constructed
+        torch module instead), as ONE compiled program. The host tier's
+        optimizer builds its own state (``_init_offload``)."""
+        static_kwargs, traced_kwargs = self._split_kwargs(kwargs)
+        rngs = {"params": self._next_rng(), "dropout": self._next_rng()}
+
+        def init(rngs, inputs, traced_kwargs):
+            return self.module.init(rngs, *inputs, **traced_kwargs,
+                                    **static_kwargs)["params"]
+
+        self.params = jax.jit(init)(rngs, inputs, traced_kwargs)
+        if self.optimizer is not None and not self._offload_mode():
+            self.opt_state = self.optimizer.init_state(self.params)
+        self._setup_shardings()
+
     def _split_kwargs(self, kwargs):
         """Traced (numeric) vs static (bool/str/None) kwargs for jit caching."""
         static, traced = {}, {}
@@ -1308,16 +1325,7 @@ class DeepSpeedEngine(object):
         inputs = mesh_lib.shard_batch(self.mesh, inputs)
 
         if self.params is None:
-            # Lazy init from batch shapes (flax idiom; the reference gets
-            # params from the constructed torch module instead).
-            init_kwargs = {k: v for k, v in kwargs.items()}
-            variables = self.module.init(
-                {"params": self._next_rng(), "dropout": self._next_rng()},
-                *inputs, **init_kwargs)
-            self.params = variables["params"]
-            if self.optimizer is not None and not self._offload_mode():
-                self.opt_state = self.optimizer.init_state(self.params)
-            self._setup_shardings()
+            self._lazy_init(inputs, kwargs)
 
         if self.training:
             self.tput_timer.start()
@@ -2214,12 +2222,7 @@ class DeepSpeedEngine(object):
             inputs = mesh_lib.shard_batch(self.mesh, inputs)
 
         if self.params is None:
-            variables = self.module.init(
-                {"params": self._next_rng(), "dropout": self._next_rng()},
-                *inputs)
-            self.params = variables["params"]
-            self.opt_state = self.optimizer.init_state(self.params)
-            self._setup_shardings()
+            self._lazy_init(inputs, {})
 
         if self._onebit_spmd_eligible():
             # The 1-bit hot path keys on the phase: the compressed

@@ -12,6 +12,8 @@ the earliest hook pytest gives us.
 """
 
 import os
+import signal
+import threading
 
 # Set here as well as by the tier-1 command, so that a bare `pytest` on a
 # machine with a chip still runs on the CPU and leaves the chip alone; the
@@ -42,6 +44,47 @@ def eight_devices():
     if len(devices) < 8:
         pytest.skip("needs 8 virtual devices")
     return devices
+
+
+# Each test's own wall-clock limit, in seconds: five times the slowest case of
+# PR 52's two whole runs (the GPT-2 step's compile at 24 layers in
+# test_chip_compile.py: 107 s in one, 69 in the other, beside five other
+# workers) on a machine a tenth slower, and no less than 300. A test that
+# waits on a thread, a queue or a poll that never comes fails by name after
+# that long and the run goes on; without it the wait takes the run and every
+# test behind it. (A signal is delivered between two bytecodes: it does not
+# interrupt a compile inside XLA, it ends a wait in Python; and a case that
+# outlasts the limit inside XLA fails when it comes back, so the limit stays
+# well over the slowest compile.)
+TEST_LIMIT_S = 600.0
+
+
+def pytest_addoption(parser):
+    parser.addoption(
+        "--test-limit", type=float, default=TEST_LIMIT_S,
+        help="seconds a test's call may take before it fails (0: no limit)")
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_call(item):
+    limit = item.config.getoption("--test-limit")
+    if limit <= 0 or not hasattr(signal, "setitimer") or \
+            threading.current_thread() is not threading.main_thread():
+        yield
+        return
+
+    def expired(signum, frame):
+        pytest.fail("test limit: {} ran over its {:g} s "
+                    "(tests/conftest.py, --test-limit)".format(
+                        item.nodeid, limit))
+
+    before = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, before)
 
 
 def pytest_configure(config):

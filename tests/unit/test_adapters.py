@@ -44,6 +44,7 @@ from deepspeed_tpu.inference.adapters import (
 from deepspeed_tpu.inference.kv_hierarchy import offload
 from deepspeed_tpu.inference.kv_pool import harvest_snapshot
 from deepspeed_tpu.parallel import mesh as mesh_lib
+from tests.unit.compiled import compiled
 from tests.unit.test_decoder import paged_cache
 from tests.unit.test_inference import make_model, prompts_of, seq_greedy
 
@@ -114,18 +115,6 @@ def ids_of(vocab, n, seed=5):
     return rng.randint(0, vocab, size=(1, n)).astype(np.int32)
 
 
-_COMPILED = {}
-
-
-def compiled(adapter, name):
-    """``adapter.<name>`` under ONE ``jax.jit`` an adapter, shared by every
-    case that only calls it: run eagerly, a two-layer forward dispatches (and,
-    a shape, compiles) some hundred small operations a call."""
-    if (adapter, name) not in _COMPILED:
-        _COMPILED[adapter, name] = jax.jit(getattr(adapter, name))
-    return _COMPILED[adapter, name]
-
-
 def greedy_decode(adapter, params, tok, cache, steps):
     out = []
     for _ in range(steps):
@@ -150,7 +139,7 @@ def primitive_greedy(kind, prompt, max_new, plane_len=96):
         adapter, params, _ = adapter_of(kind)
         cache = adapter.init_cache(1, plane_len)
         ids = jnp.asarray(np.asarray(prompt)[None].astype(np.int32))
-        logits, cache = adapter.prefill_append(params, ids, cache)
+        logits, cache = compiled(adapter, "prefill_append")(params, ids, cache)
         tok = int(jnp.argmax(logits[0, -1]))
         toks = [tok]
         more, _ = greedy_decode(adapter, params, tok, cache, max_new - 1)
@@ -204,11 +193,11 @@ def test_chunk_vs_whole_prefill_parity(kind):
     ids = jnp.asarray(ids_of(vocab, 12))
 
     whole = cache_of(kind, 1, 32)
-    logits_w, whole = adapter.prefill_append(params, ids, whole)
+    logits_w, whole = compiled(adapter, "prefill_append")(params, ids, whole)
 
     chunked = cache_of(kind, 1, 32)
     for lo in (0, 4, 8):
-        logits_c, chunked = adapter.prefill_append(
+        logits_c, chunked = compiled(adapter, "prefill_append")(
             params, ids[:, lo:lo + 4], chunked)
 
     assert int(whole["pos"][0]) == int(chunked["pos"][0]) == 12
@@ -265,7 +254,7 @@ def test_verify_rollback_is_invisible(kind):
 
     def stream(speculate):
         cache = cache_of(kind, 1, 32)
-        logits, cache = adapter.prefill_append(params, ids, cache)
+        logits, cache = compiled(adapter, "prefill_append")(params, ids, cache)
         tok = int(jnp.argmax(logits[0, -1]))
         toks = [tok]
         head, cache = greedy_decode(adapter, params, tok, cache, 2)
@@ -278,7 +267,8 @@ def test_verify_rollback_is_invisible(kind):
             draft = jnp.asarray(
                 [[toks[-1]] + ids_of(vocab, 2, seed=42)[0].tolist()],
                 jnp.int32)
-            vlogits, cache = adapter.verify_forward(params, draft, cache)
+            vlogits, cache = compiled(adapter, "verify_forward")(
+                params, draft, cache)
             assert vlogits.shape[1] == 3
             assert int(cache["pos"][0]) == pos0, \
                 "verify_forward must not advance the frontier"

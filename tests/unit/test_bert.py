@@ -19,8 +19,8 @@ def test_bert_model_shapes():
     cfg = BertConfig.tiny()
     model = BertModel(cfg)
     ids = jnp.asarray(_ids())
-    params = model.init(jax.random.PRNGKey(0), ids)
-    seq, pooled, wte = model.apply(params, ids)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)
+    seq, pooled, wte = jax.jit(model.apply)(params, ids)
     assert seq.shape == (2, 32, cfg.hidden_size)
     assert pooled.shape == (2, cfg.hidden_size)
     assert wte.shape == (cfg.vocab_size, cfg.hidden_size)
@@ -40,12 +40,13 @@ def test_bert_attention_mask_zeroes_padding_influence():
     ids = jnp.asarray(_ids())
     mask = jnp.asarray(np.concatenate(
         [np.ones((2, 24)), np.zeros((2, 8))], axis=1))
-    params = model.init(jax.random.PRNGKey(0), ids, mask)
-    seq1, _, _ = model.apply(params, ids, mask)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids, mask)
+    apply = jax.jit(model.apply)
+    seq1, _, _ = apply(params, ids, mask)
     # changing the masked-out tokens must not change unmasked outputs
     ids2 = jnp.asarray(np.concatenate(
         [np.asarray(ids)[:, :24], _ids(2, 8, seed=9)[:, :8]], axis=1))
-    seq2, _, _ = model.apply(params, ids2, mask)
+    seq2, _, _ = apply(params, ids2, mask)
     np.testing.assert_allclose(np.asarray(seq1[:, :24], np.float32),
                                np.asarray(seq2[:, :24], np.float32),
                                rtol=2e-2, atol=2e-2)
@@ -125,12 +126,13 @@ def test_bert_sparse_attention_mask_zeroes_padding_influence():
     ids = rng.randint(0, cfg.vocab_size, size=(2, 32))
     mask = np.ones((2, 32), np.int32)
     mask[:, 24:] = 0  # last 8 positions are padding
-    params = model.init(jax.random.PRNGKey(0), jnp.asarray(ids),
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), jnp.asarray(ids),
                         jnp.asarray(mask))
-    seq1, _, _ = model.apply(params, jnp.asarray(ids), jnp.asarray(mask))
+    apply = jax.jit(model.apply)
+    seq1, _, _ = apply(params, jnp.asarray(ids), jnp.asarray(mask))
     ids2 = ids.copy()
     ids2[:, 24:] = rng.randint(0, cfg.vocab_size, size=(2, 8))
-    seq2, _, _ = model.apply(params, jnp.asarray(ids2), jnp.asarray(mask))
+    seq2, _, _ = apply(params, jnp.asarray(ids2), jnp.asarray(mask))
     np.testing.assert_allclose(
         np.asarray(seq1[:, :24], np.float32),
         np.asarray(seq2[:, :24], np.float32), atol=1e-5,
@@ -208,8 +210,23 @@ def test_pipe_p2p_roundtrip():
     p2p.barrier(0)
 
 
-def test_tensorboard_events(tmp_path):
+def test_tensorboard_events(tmp_path, monkeypatch):
+    """The engine's scalars reach an event file through
+    ``TensorBoardScalarWriter``. The writer it asks for by name,
+    ``torch.utils.tensorboard.SummaryWriter``, takes 20 s to import here
+    (``torch``, and TensorFlow's stubs behind ``tensorboard``): the case hands
+    it ``tensorboardX``'s, the same surface writing the same files, under
+    that name, and falls back on the real one where that is missing."""
+    import importlib.util
+    import sys
+    import types
+
     from deepspeed_tpu.models.simple import SimpleModel
+    if importlib.util.find_spec("tensorboardX") is not None:
+        import tensorboardX
+        monkeypatch.setitem(
+            sys.modules, "torch.utils.tensorboard", types.SimpleNamespace(
+                SummaryWriter=tensorboardX.SummaryWriter))
     engine, _, _, _ = deepspeed.initialize(
         model=SimpleModel(hidden_dim=8),
         config_params={
@@ -235,8 +252,8 @@ def test_plain_bert_layer_path():
                           attention_probs_dropout_prob=0.0)
     model = BertModel(cfg)
     ids = jnp.asarray(_ids())
-    params = model.init(jax.random.PRNGKey(0), ids)
-    seq, pooled, _ = model.apply(params, ids)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)
+    seq, pooled, _ = jax.jit(model.apply)(params, ids)
     assert seq.shape == (2, 32, cfg.hidden_size)
     assert np.all(np.isfinite(np.asarray(seq, np.float32)))
 

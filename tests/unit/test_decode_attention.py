@@ -4,6 +4,7 @@ through the decode-step program in models/generation.py. Off-TPU the
 Pallas kernel runs in interpret mode, so these tests exercise the real
 kernel body (masking, online-softmax rescale, block clamping) on CPU."""
 
+import functools
 import os
 
 import jax
@@ -21,6 +22,23 @@ from deepspeed_tpu.ops.transformer.kernels.decode_attention import (
     decode_supported, dequantize_kv, flash_decode_attention,
     flash_decode_attention_q8, kv_append, pad_cache_len, planned_block_k,
     quantize_kv, resolve_decode_block)
+
+
+# The generation primitives under ONE ``jax.jit`` each (the configuration is
+# static): the cases below are about what they compute with the kernel on
+# and off, not about calling them operation by operation.
+forward = jax.jit(_forward, static_argnums=1, static_argnames="last_only")
+step = jax.jit(decode_step, static_argnums=1)
+# and the paged kernels' launchers with the references beside them, where a
+# case only calls them (the launcher's work list and the reference's gather
+# are dozens of small operations); ``layer`` and ``block`` are static
+paged = jax.jit(da.flash_decode_attention_paged,
+                static_argnames=("layer", "block"))
+paged_q8 = jax.jit(da.flash_decode_attention_paged_q8,
+                   static_argnames=("layer",))
+paged_reference = jax.jit(da.decode_attention_paged_reference,
+                          static_argnames=("block",))
+paged_q8_reference = jax.jit(da.decode_attention_paged_q8_reference)
 
 
 def qkv(rng, b, h, s, t, d, dtype=jnp.float32):
@@ -101,16 +119,18 @@ def test_append_forward_flag_parity():
     model = GPT2LMHeadModel(cfg)
     rng = np.random.RandomState(7)
     ids = rng.randint(0, cfg.vocab_size, size=(1, 12)).astype(np.int32)
-    params = model.init(jax.random.PRNGKey(0), jnp.asarray(ids))["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.asarray(ids))["params"]
     chunk = rng.randint(0, cfg.vocab_size, size=(1, 8)).astype(np.int32)
 
     outs = {}
+    append = jax.jit(append_forward, static_argnums=1)
     for flash in (False, True):
         g = as_gencfg(cfg, use_flash_decode=flash)
         cache = init_cache(g, 1, 128)  # kernel quantum so flash engages
-        _, cache = _forward(params, g, jnp.asarray(ids), cache)
-        logits, cache = append_forward(params, g, jnp.asarray(chunk), cache,
-                                       n_valid=jnp.asarray([5]))
+        _, cache = forward(params, g, jnp.asarray(ids), cache)
+        logits, cache = append(params, g, jnp.asarray(chunk), cache,
+                               n_valid=jnp.asarray([5]))
         assert int(cache["pos"][0]) == 12 + 5
         outs[flash] = np.asarray(logits)[0, :5]
     np.testing.assert_allclose(outs[True], outs[False],
@@ -201,13 +221,16 @@ def test_planned_block_k_table_or_default():
 # ------------------------------------------- decode-step program parity
 
 
+@functools.lru_cache(maxsize=None)
 def tiny_model(seed=0):
+    """One compiled init a seed: no case writes the parameters."""
     cfg = GPT2Config.tiny(dropout=0.0, dtype=jnp.float32,
                           use_flash_attention=False)
     model = GPT2LMHeadModel(cfg)
     ids = np.random.RandomState(seed).randint(0, cfg.vocab_size,
                                               size=(3, 12))
-    params = model.init(jax.random.PRNGKey(0), jnp.asarray(ids))["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.asarray(ids))["params"]
     return cfg, model, params, ids
 
 
@@ -227,7 +250,7 @@ def test_decode_step_flag_parity_ragged():
         # Ragged frontiers incl. 0 and max_len-1: both paths read the
         # same (zero) cache planes, so parity is deterministic.
         cache["pos"] = jnp.asarray([0, 7, 120], jnp.int32)
-        logits, cache2 = decode_step(params, gcfg, tok, cache)
+        logits, cache2 = step(params, gcfg, tok, cache)
         assert (np.asarray(cache2["pos"]) == [1, 8, 121]).all()
         outs.append(np.asarray(logits))
     np.testing.assert_allclose(outs[0], outs[1], rtol=2e-4, atol=2e-4)
@@ -241,8 +264,8 @@ def test_prefill_forward_flag_parity():
     for flag in (True, False):
         gcfg = as_gencfg(cfg, use_flash_decode=flag)
         cache = init_cache(gcfg, 3, 128)
-        logits, _ = _forward(params, gcfg, jnp.asarray(ids), cache,
-                             last_only=True)
+        logits, _ = forward(params, gcfg, jnp.asarray(ids), cache,
+                            last_only=True)
         outs.append(np.asarray(logits))
     np.testing.assert_allclose(outs[0], outs[1], rtol=2e-4, atol=2e-4)
 
@@ -260,8 +283,9 @@ def test_decode_step_multiblock_env(monkeypatch):
             monkeypatch.setenv("DS_TPU_FLASH_DECODE_BLOCK", env)
         cache = init_cache(as_gencfg(cfg, use_flash_decode=True), 3, 128)
         cache["pos"] = jnp.asarray([0, 65, 127], jnp.int32)
-        logits, _ = decode_step(params, as_gencfg(cfg, use_flash_decode=True),
-                                tok, cache)
+        # a trace an override: the block is read where the step is traced
+        logits, _ = jax.jit(decode_step, static_argnums=1)(
+            params, as_gencfg(cfg, use_flash_decode=True), tok, cache)
         outs.append(np.asarray(logits))
     np.testing.assert_allclose(outs[0], outs[1], rtol=2e-4, atol=2e-4)
 
@@ -644,19 +668,15 @@ def test_layer_indexed_paged_decode_matches_reference(s, int8):
     vf = jnp.asarray(rng.randn(n_layer, n_pages, h, _PAGE, d), jnp.float32)
     if int8:
         (k, ks), (v, vs) = da.quantize_kv(kf), da.quantize_kv(vf)
-        got = da.flash_decode_attention_paged_q8(q, k, v, ks, vs, tbl, pos,
-                                                 layer=layer)
-        sliced = da.flash_decode_attention_paged_q8(
-            q, k[layer], v[layer], ks[layer], vs[layer], tbl, pos)
-        want = da.decode_attention_paged_q8_reference(
-            q, k[layer], v[layer], ks[layer], vs[layer], tbl, pos)
+        got = paged_q8(q, k, v, ks, vs, tbl, pos, layer=layer)
+        sliced = paged_q8(q, k[layer], v[layer], ks[layer], vs[layer], tbl,
+                          pos)
+        want = paged_q8_reference(q, k[layer], v[layer], ks[layer],
+                                  vs[layer], tbl, pos)
     else:
-        got = da.flash_decode_attention_paged(q, kf, vf, tbl, pos,
-                                              layer=layer)
-        sliced = da.flash_decode_attention_paged(q, kf[layer], vf[layer],
-                                                 tbl, pos)
-        want = da.decode_attention_paged_reference(q, kf[layer], vf[layer],
-                                                   tbl, pos)
+        got = paged(q, kf, vf, tbl, pos, layer=layer)
+        sliced = paged(q, kf[layer], vf[layer], tbl, pos)
+        want = paged_reference(q, kf[layer], vf[layer], tbl, pos)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(sliced))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
@@ -678,14 +698,15 @@ def test_paged_decode_and_kv_append_at_both_head_dims_in_bf16(s, d):
                           jnp.bfloat16) for _ in range(2))
     q, k, v = (jnp.asarray(rng.randn(b, h, s, d), jnp.bfloat16)
                for _ in range(3))
-    ka2, va2 = kv_append((ka, va), (k, v), tbl, pos, layer)
+    ka2, va2 = jax.jit(kv_append, static_argnums=4)(
+        (ka, va), (k, v), tbl, pos, layer)
     for arena, new, got in ((ka, k, ka2), (va, v, va2)):
         np.testing.assert_array_equal(
             np.asarray(got.astype(jnp.float32)),
             np.asarray(_scatter_reference(arena, new, tbl, pos, layer)
                        .astype(jnp.float32)))
-    got = da.flash_decode_attention_paged(q, ka2, va2, tbl, pos, layer=layer)
-    want = da.decode_attention_paged_reference(
+    got = paged(q, ka2, va2, tbl, pos, layer=layer)
+    want = paged_reference(
         q.astype(jnp.float32), ka2[layer].astype(jnp.float32),
         va2[layer].astype(jnp.float32), tbl, pos)
     assert got.dtype == jnp.bfloat16
@@ -734,14 +755,12 @@ def _paged_operands(d, s, pos, int8, dtype, shared=0, frozen=(), h=4,
 
 def _paged_kernel_and_reference(q, arenas, tbl, pos, layer):
     if len(arenas) == 4:
-        got = da.flash_decode_attention_paged_q8(q, *arenas, tbl, pos,
-                                                 layer=layer)
-        want = da.decode_attention_paged_q8_reference(
+        got = paged_q8(q, *arenas, tbl, pos, layer=layer)
+        want = paged_q8_reference(
             q.astype(jnp.float32), *(a[layer] for a in arenas), tbl, pos)
     else:
-        got = da.flash_decode_attention_paged(q, *arenas, tbl, pos,
-                                              layer=layer)
-        want = da.decode_attention_paged_reference(
+        got = paged(q, *arenas, tbl, pos, layer=layer)
+        want = paged_reference(
             q.astype(jnp.float32),
             *(a[layer].astype(jnp.float32) for a in arenas), tbl, pos)
     assert got.dtype == q.dtype and got.shape == q.shape
@@ -841,9 +860,8 @@ def test_paged_body_under_block_visibility(s, dtype):
     pos = [0, 128 - s, 128, 256 - s, 3 * _PAGE - s]
     q, arenas, tbl, pos = _paged_operands(128, s, pos, False, dtype, h=2)
     q = jnp.asarray(rng.randn(len(pos), 8, s, 128), dtype)
-    got = da.flash_decode_attention_paged(q, *arenas, tbl, pos, layer=1,
-                                          block=4)
-    want, causal = (da.decode_attention_paged_reference(
+    got = paged(q, *arenas, tbl, pos, layer=1, block=4)
+    want, causal = (paged_reference(
         q.astype(jnp.float32), *(a[1].astype(jnp.float32) for a in arenas),
         tbl, pos, block=b) for b in (4, 1))
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
@@ -866,8 +884,7 @@ def test_frozen_rows_leave_live_rows_bit_for_bit(s, int8):
     q, arenas, tbl, pos = _paged_operands(64, s, pos, int8, jnp.bfloat16,
                                           frozen=frozen, seed=s)
     live = np.asarray([r for r in range(len(pos)) if r not in frozen])
-    run = (da.flash_decode_attention_paged_q8 if int8
-           else da.flash_decode_attention_paged)
+    run = paged_q8 if int8 else paged
     with_frozen = run(q, *arenas, tbl, pos, layer=0)
     without = run(q[live], *arenas, tbl[live], pos[live], layer=0)
     np.testing.assert_array_equal(

@@ -32,6 +32,7 @@ from deepspeed_tpu.inference import kv_pool
 from deepspeed_tpu.inference.adapters import DecoderAdapter
 from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
 from deepspeed_tpu.moe import routed
+from tests.unit.compiled import compiled
 
 B, T, PAGE = 12, 20, 16
 DECODE_FROM = 5     # prefill_then_decode: tokens from here on are decoded
@@ -85,25 +86,27 @@ def paged_cache(adapter, rows, page=PAGE, max_len=64):
 
 
 def whole(adapter, params, ids):
-    return adapter.prefill_append(params, ids, paged_cache(adapter,
-                                                           ids.shape[0]))[0]
+    return compiled(adapter, "prefill_append")(
+        params, ids, paged_cache(adapter, ids.shape[0]))[0]
 
 
 def chunked(adapter, params, ids):
     cache, out = paged_cache(adapter, ids.shape[0]), []
     for lo in range(0, ids.shape[1], 4):
-        logits, cache = adapter.prefill_append(params, ids[:, lo:lo + 4],
-                                               cache)
+        logits, cache = compiled(adapter, "prefill_append")(
+            params, ids[:, lo:lo + 4], cache)
         out.append(logits)
     return jnp.concatenate(out, axis=1)
 
 
 def prefill_then_decode(adapter, params, ids):
     cache = paged_cache(adapter, ids.shape[0])
-    logits, cache = adapter.prefill_append(params, ids[:, :DECODE_FROM], cache)
+    logits, cache = compiled(adapter, "prefill_append")(
+        params, ids[:, :DECODE_FROM], cache)
     out = [logits]
     for t in range(DECODE_FROM, ids.shape[1]):
-        logits, cache = adapter.decode_step(params, ids[:, t], cache)
+        logits, cache = compiled(adapter, "decode_step")(
+            params, ids[:, t], cache)
         out.append(logits[:, None])
     return jnp.concatenate(out, axis=1)
 
@@ -213,7 +216,7 @@ def test_the_cache_free_forward_is_the_served_one():
     adapter, params, ids, _, _ = built("float32")
     model = DecoderLM(adapter.gcfg)
     np.testing.assert_allclose(
-        np.asarray(model.apply({"params": params}, ids)),
+        np.asarray(jax.jit(model.apply)({"params": params}, ids)),
         np.asarray(whole(adapter, params, ids)), rtol=0, atol=1e-5)
 
 
@@ -238,9 +241,11 @@ def test_the_interpreted_paged_kernels_serve_the_block_too(n_head, head_dim,
     flash = DecoderAdapter.from_model(model, use_flash_decode=True)
     cache = paged_cache(flash, 2, page=128, max_len=128)
     assert cache["k"].shape[2:] == (stored[0], 128, stored[1])
-    logits, cache = flash.prefill_append(params, ids[:2, :12], cache)
-    more, _ = flash.decode_step(params, ids[:2, 12], cache)
-    want = np.asarray(model.apply({"params": params}, ids[:2, :13]))
+    logits, cache = compiled(flash, "prefill_append")(
+        params, ids[:2, :12], cache)
+    more, _ = compiled(flash, "decode_step")(params, ids[:2, 12], cache)
+    want = np.asarray(jax.jit(model.apply)({"params": params},
+                                           ids[:2, :13]))
     np.testing.assert_allclose(np.asarray(logits), want[:, :12], rtol=0,
                                atol=1e-4)
     np.testing.assert_allclose(np.asarray(more), want[:, 12], rtol=0,

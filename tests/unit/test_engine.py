@@ -494,6 +494,94 @@ def test_a_new_steps_trace_gets_a_megabyte_frame_and_no_collector():
     assert gc.isenabled()
 
 
+_EAGER_INITS = {}
+
+
+def _lazy_cases():
+    import jax.numpy as jnp
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+    ids = np.random.RandomState(0).randint(0, 97, size=(8, 16))
+    return {
+        "simple": (lambda: SimpleModel(hidden_dim=16), random_batch()),
+        "gpt2": (lambda: GPT2LMHeadModel(GPT2Config(
+            n_embd=32, n_layer=1, n_head=2, n_positions=16, vocab_size=97,
+            dropout=0.0, dtype=jnp.float32, use_flash_attention=False)),
+            (ids, ids)),
+    }
+
+
+@pytest.mark.parametrize("entry", ["forward", "train_batch"])
+@pytest.mark.parametrize("offload", [False, True], ids=["device", "offload"])
+@pytest.mark.parametrize("name", ["simple", "gpt2"])
+def test_an_engine_given_no_parameters_initialises_them_as_the_eager_init(
+        name, offload, entry, eight_devices):
+    """``_lazy_init`` (ONE compiled ``module.init``, for ``forward`` and
+    ``train_batch`` alike) against the eager init each of them carried: the
+    same two keys in the same order, the same tree, the shardings the ZeRO
+    stage gives it, values within float32 rounding, and the optimizer's state
+    where the eager paths put it. The host tier (``cpu_offload``) keeps its
+    own: ``forward`` leaves ``opt_state`` alone there, and ``train_batch``
+    never reached its own copy there (it hands such a step to ``forward`` /
+    ``backward`` / ``step`` before it looks at the parameters), so it leaves
+    it alone too."""
+    make, batch = _lazy_cases()[name]
+    zero = {"stage": 2, "cpu_offload": True} if offload else {"stage": 2}
+    engine, _, _, _ = deepspeed.initialize(
+        model=make(), config_params=base_config(
+            zero_optimization=zero, bf16={"enabled": True}))
+    assert engine.params is None and engine.opt_state is None
+    assert engine._offload_mode() == offload
+
+    rng, key_params = jax.random.split(engine._rng)
+    rng, key_dropout = jax.random.split(rng)
+    inputs = mesh_lib.shard_batch(
+        engine.mesh, tuple(jax.numpy.asarray(x) for x in batch))
+    # the eager init, operation by operation: once a model and pair of keys
+    drawn = (name, np.asarray(jax.random.key_data(engine._rng)).tobytes())
+    if drawn not in _EAGER_INITS:
+        _EAGER_INITS[drawn] = make().init(
+            {"params": key_params, "dropout": key_dropout}, *inputs)["params"]
+    want = _EAGER_INITS[drawn]
+    shardings, _, _ = mesh_lib.zero_shardings(engine.mesh, want, 2)
+
+    if entry == "forward":
+        engine(*batch)
+    else:
+        engine.train_batch(batch=batch)
+    if entry == "forward" or offload:
+        # the next key drawn after the two of the init is the step's
+        assert np.array_equal(jax.random.key_data(engine._rng),
+                              jax.random.key_data(
+                                  jax.random.split(rng)[0]))
+
+    got = engine.params
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(want)
+    flat = jax.tree_util.tree_leaves_with_path
+    for (path, a), (_, b), (_, sh) in zip(flat(got), flat(want),
+                                          flat(shardings)):
+        assert a.shape == b.shape, path
+        assert a.sharding.is_equivalent_to(sh, a.ndim), (path, a.sharding)
+        if entry == "forward":
+            # no step has moved them yet (nor has the host tier's handed
+            # the device its copy in the compute dtype)
+            assert a.dtype == b.dtype, path
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-7, err_msg=str(path))
+    if offload and entry == "forward":
+        assert engine.opt_state is None
+    elif offload:
+        # the host tier's own, made by its first step: nothing on the device
+        assert not any(isinstance(leaf, jax.Array) for leaf in
+                       jax.tree_util.tree_leaves(engine.opt_state))
+    else:
+        assert set(engine.opt_state) >= {"step", "exp_avg", "exp_avg_sq"}
+        assert jax.tree_util.tree_structure(engine.opt_state["exp_avg"]) \
+            == jax.tree_util.tree_structure(want)
+        assert int(engine.opt_state["step"]) == \
+            (0 if entry == "forward" else 1)
+
+
 def test_train_batch_fused_path():
     model = SimpleModel(hidden_dim=16)
     engine, _, _, _ = deepspeed.initialize(

@@ -4,6 +4,8 @@ the trained param tree, so these tests are the contract that keeps the
 two in lockstep: prefill logits vs model.apply, cached greedy decode vs
 a no-cache argmax loop, EOS freezing, and sampling determinism."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,14 +16,22 @@ from deepspeed_tpu.models.generation import _GenCfg
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
 
 
+@functools.lru_cache(maxsize=None)
 def make(dtype=jnp.float32, flash=False, seed=0):
+    """One compiled init a configuration: no case writes its parameters."""
     cfg = GPT2Config.tiny(dropout=0.0, dtype=dtype,
                           use_flash_attention=flash)
     model = GPT2LMHeadModel(cfg)
     rng = np.random.RandomState(seed)
     ids = rng.randint(0, cfg.vocab_size, size=(2, 12))
-    params = model.init(jax.random.PRNGKey(0), jnp.asarray(ids))["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.asarray(ids))["params"]
     return cfg, model, params, ids
+
+
+# The primitives under ONE ``jax.jit`` each (the configuration is static): no
+# case here is about calling them operation by operation.
+forward = jax.jit(_forward, static_argnums=1)
 
 
 def gencfg(cfg):
@@ -32,10 +42,11 @@ def gencfg(cfg):
 @pytest.mark.parametrize("flash", [False, True])
 def test_prefill_logits_match_training_forward(flash):
     cfg, model, params, ids = make(flash=flash)
-    train_logits = model.apply({"params": params}, jnp.asarray(ids))
+    train_logits = jax.jit(model.apply)({"params": params},
+                                        jnp.asarray(ids))
     cache = init_cache(gencfg(cfg), 2, ids.shape[1])
-    gen_logits, cache = _forward(params, gencfg(cfg), jnp.asarray(ids),
-                                 cache)
+    gen_logits, cache = forward(params, gencfg(cfg), jnp.asarray(ids),
+                                cache)
     np.testing.assert_allclose(np.asarray(gen_logits),
                                np.asarray(train_logits),
                                rtol=2e-4, atol=2e-4)
@@ -51,8 +62,9 @@ def test_cached_greedy_matches_no_cache_loop():
 
     seq = jnp.asarray(ids)
     want = []
+    apply = jax.jit(model.apply)    # a compile a length, not a dispatch an op
     for _ in range(steps):
-        logits = model.apply({"params": params}, seq)
+        logits = apply({"params": params}, seq)
         nxt = jnp.argmax(logits[:, -1].astype(jnp.float32), axis=-1)
         want.append(np.asarray(nxt))
         seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
@@ -105,16 +117,17 @@ def test_append_forward_chunked_matches_whole_prefill():
     plane = T + C                   # slack so pad-column writes never clamp
 
     ref_cache = init_cache(g, 1, plane)
-    ref_logits, ref_cache = _forward(params, g, jnp.asarray(ids), ref_cache)
+    ref_logits, ref_cache = forward(params, g, jnp.asarray(ids), ref_cache)
 
     cache = init_cache(g, 1, plane)
     got = []
+    append = jax.jit(append_forward, static_argnums=1)
     for s in range(0, T, C):
         n = min(C, T - s)
         sl = np.zeros((1, C), np.int32)
         sl[0, :n] = ids[0, s:s + n]
-        logits, cache = append_forward(params, g, jnp.asarray(sl), cache,
-                                       n_valid=jnp.asarray([n]))
+        logits, cache = append(params, g, jnp.asarray(sl), cache,
+                               n_valid=jnp.asarray([n]))
         got.append(np.asarray(logits)[0, :n])  # pad-row logits are garbage
         assert int(cache["pos"][0]) == s + n  # frontier moved by n, not C
 

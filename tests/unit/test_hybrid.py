@@ -27,6 +27,7 @@ from deepspeed_tpu.models import decoder, mamba2
 from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
 from deepspeed_tpu.moe import routed
 from deepspeed_tpu.ops.transformer.kernels import decode_attention as da
+from tests.unit.compiled import compiled, served_alone
 
 builder = harness.load_by_name("model_builders", "granitemoehybrid")
 
@@ -48,8 +49,8 @@ def model():
     m = DecoderLM(CFG)
     # the token table at 0.01 and the last norm at 25, as the benchmark's
     # builder scales its random weights: logits that spread about 1
-    return m, builder.rescaled(m.init(jax.random.PRNGKey(0))["params"],
-                               0.01 / CFG.initializer_range, 25.0)
+    params = jax.jit(m.init)(jax.random.PRNGKey(0))["params"]
+    return m, builder.rescaled(params, 0.01 / CFG.initializer_range, 25.0)
 
 
 @pytest.fixture(scope="module")
@@ -70,11 +71,7 @@ def engine(model, **kw):
 
 
 def alone(model, prompt, n, **kw):
-    eng = engine(model, **kw)
-    req = eng.submit(prompt, max_new_tokens=n)
-    eng.run()
-    assert eng.compile_count == 1
-    return req.tokens
+    return served_alone(engine, model, prompt, n, **kw)
 
 
 # ------------------------------------------------- against the reference
@@ -83,7 +80,7 @@ def alone(model, prompt, n, **kw):
 def test_the_cache_free_pass_is_the_reference(model):
     ids = tokens(20, rows=2)
     want = builder.reference_logits(model[1], ids, CFG)
-    got = model[0].apply({"params": model[1]}, jnp.asarray(ids))
+    got = jax.jit(model[0].apply)({"params": model[1]}, jnp.asarray(ids))
     assert want.std() > 0.5          # logits that could tell a token apart
     np.testing.assert_allclose(np.asarray(got), want, **TOL)
 
@@ -102,13 +99,13 @@ def test_unequal_prefill_chunks_then_decode_are_the_references_one_pass(
     got = []
     for lo, hi, pad in ((0, 7, 0), (7, 12, 0), (12, 14, 2)):
         chunk = np.concatenate([ids[:, lo:hi], tokens(pad, seed=9)], axis=1)
-        logits, cache = adapter.prefill_append(
+        logits, cache = compiled(adapter, "prefill_append")(
             model[1], jnp.asarray(chunk), cache,
             n_valid=jnp.asarray([hi - lo]))
         got.append(np.asarray(logits[0, :hi - lo]))
     assert int(cache["pos"][0]) == 14
     for t in range(14, 20):
-        logits, cache = adapter.decode_step(
+        logits, cache = compiled(adapter, "decode_step")(
             model[1], jnp.asarray(ids[:, t]), cache)
         got.append(np.asarray(logits))
     np.testing.assert_allclose(np.concatenate(got), want, **TOL)
@@ -121,7 +118,8 @@ def test_a_prompts_state_does_not_depend_on_how_it_was_chunked(model,
     def state(cuts):
         cache = adapter.init_cache(1, 32)
         for lo, hi in zip((0,) + cuts, cuts + (19,)):
-            _, cache = adapter.prefill_append(model[1], ids[:, lo:hi], cache)
+            _, cache = compiled(adapter, "prefill_append")(
+                model[1], ids[:, lo:hi], cache)
         return {k: np.asarray(v) for k, v in cache.items()
                 if k.startswith("slot_")}
 
@@ -140,9 +138,8 @@ def test_the_mixers_state_is_the_references(model):
     h = jax.random.normal(jax.random.PRNGKey(3), (1, 21, CFG.hidden_size))
     ssm = jnp.zeros((1, CFG.mamba_state, 32))
     tail = jnp.zeros((1, 3, 32 + 2 * CFG.mamba_state))
-    out, ssm, _ = mamba2.mixer(p, CFG, h, ssm, tail,
-                               jnp.zeros((1,), jnp.int32),
-                               jnp.asarray([21]))
+    out, ssm, _ = jax.jit(mamba2.mixer, static_argnums=1)(
+        p, CFG, h, ssm, tail, jnp.zeros((1,), jnp.int32), jnp.asarray([21]))
     want, state = reference.mamba(h[0], p, CFG.mamba_heads, CFG.mamba_state,
                                   CFG.rms_norm_eps, with_state=True)
     np.testing.assert_allclose(np.asarray(out[0]), np.asarray(want), **TOL)
@@ -158,13 +155,14 @@ def test_the_mixers_state_is_the_references(model):
 def test_pad_columns_and_idle_rows_leave_the_state_untouched(model, adapter):
     ids = jnp.asarray(tokens(12, seed=4, rows=2))
     cache = adapter.init_cache(2, 32)
-    _, cache = adapter.prefill_append(model[1], ids[:, :8], cache)
+    append = compiled(adapter, "prefill_append")
+    _, cache = append(model[1], ids[:, :8], cache)
     before = {k: np.asarray(v) for k, v in cache.items()}
     # row 0 appends 4 real columns, row 1 none (all four are padding)
-    _, after = adapter.prefill_append(model[1], ids[:, 8:], cache,
-                                      n_valid=jnp.asarray([4, 0]))
+    _, after = append(model[1], ids[:, 8:], cache,
+                      n_valid=jnp.asarray([4, 0]))
     # and a decode step in which only row 0 is live
-    _, after = adapter.decode_step(
+    _, after = compiled(adapter, "decode_step")(
         model[1], ids[:, 0], dict(after, n_valid=jnp.asarray([1, 0])))
     for name in ("slot_ssm0", "slot_ssm2", "slot_conv0", "slot_conv2"):
         got = np.asarray(after[name])
@@ -204,8 +202,10 @@ def test_a_reused_slot_gives_the_stream_it_gives_alone(model):
     b = eng.submit(second, max_new_tokens=7)
     eng.run()
     assert eng.compile_count == 1
-    assert a.tokens == alone(model, first, 7)
-    assert b.tokens == alone(model, second, 7)      # no reset from the host
+    # each against an engine of its own that has served nothing before it;
+    # no reset from the host
+    assert a.tokens == alone(model, first, 7, fresh=True)
+    assert b.tokens == alone(model, second, 7, fresh=True)
 
 
 def test_a_row_prefilling_beside_rows_that_decode_is_not_disturbed(model):

@@ -50,8 +50,8 @@ def make_model(seed=0, **kw):
         model = GPT2LMHeadModel(cfg)
         ids = np.random.RandomState(seed).randint(0, cfg.vocab_size,
                                                   size=(2, 12))
-        params = model.init(jax.random.PRNGKey(0),
-                            jnp.asarray(ids))["params"]
+        params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                     jnp.asarray(ids))["params"]
         _MODELS[key] = (cfg, model, params)
     return _MODELS[key]
 

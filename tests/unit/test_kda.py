@@ -28,6 +28,7 @@ from deepspeed_tpu.inference import InferenceEngine, kv_pool
 from deepspeed_tpu.inference.adapters import DecoderAdapter
 from deepspeed_tpu.models import decoder, kda
 from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
+from tests.unit.compiled import served_alone
 
 builder = harness.load_by_name("model_builders", "kimi_linear")
 
@@ -51,7 +52,8 @@ def model():
     key = jax.random.PRNGKey(0)
     # the selection bias drawn, not zero: choosing with it and weighting
     # without it then differ
-    return m, builder.shared.rescaled(m.init(key)["params"], key, 1.0, 0.1)
+    return m, builder.shared.rescaled(jax.jit(m.init)(key)["params"], key, 1.0,
+                                      0.1)
 
 
 @pytest.fixture(scope="module")
@@ -72,11 +74,7 @@ def engine(model, **kw):
 
 
 def alone(model, prompt, n, **kw):
-    eng = engine(model, **kw)
-    req = eng.submit(prompt, max_new_tokens=n)
-    eng.run()
-    assert eng.compile_count == 1
-    return req.tokens
+    return served_alone(engine, model, prompt, n, **kw)
 
 
 def recurrence_inputs(t, h=3, d=16, seed=0, batch=2):

@@ -30,6 +30,7 @@ from deepspeed_tpu.inference.adapters import DecoderAdapter
 from deepspeed_tpu.models import decoder, mamba1
 from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
 from deepspeed_tpu.ops.transformer.kernels import decode_attention as da
+from tests.unit.compiled import compiled
 from tests.unit.test_telemetry import _parse_prom
 
 builder = harness.load_by_name("model_builders", "jamba")
@@ -63,7 +64,7 @@ def unruly(p, key):
 def model():
     m = DecoderLM(CFG)
     key = jax.random.PRNGKey(0)
-    params = builder.rescaled(m.init(key)["params"], 1.0, 0.5)
+    params = builder.rescaled(jax.jit(m.init)(key)["params"], 1.0, 0.5)
     params["mamba1"] = unruly(params["mamba1"], key)
     return m, params
 
@@ -319,8 +320,8 @@ def test_prefill_then_paged_decode_is_the_full_forward_pass(model, adapter):
         np.arange(len(req.tokens)), req.tokens])) <= 1e-3
     # and the logits themselves, through the adapter's own two calls
     cache = adapter.init_cache(1, 32)
-    logits, cache = adapter.prefill_append(model[1], jnp.asarray(ids[:, :13]),
-                                           cache)
+    logits, cache = compiled(adapter, "prefill_append")(
+        model[1], jnp.asarray(ids[:, :13]), cache)
     out = [logits[0]]
     decode = jax.jit(adapter.decode_step)
     for i in range(13, 23):

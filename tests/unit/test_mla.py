@@ -29,6 +29,7 @@ from deepspeed_tpu.inference.kv_hierarchy import offload
 from deepspeed_tpu.models import decoder
 from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
 from deepspeed_tpu.ops.transformer.kernels import decode_attention as da
+from tests.unit.compiled import compiled, served_alone
 
 builder = harness.load_by_name("model_builders", "deepseek_v3")
 
@@ -52,7 +53,7 @@ def model():
     key = jax.random.PRNGKey(0)
     # the selection bias drawn, not zero: choosing with it and weighting
     # without it then differ
-    return m, builder.rescaled(m.init(key)["params"], key, 1.0, 0.1)
+    return m, builder.rescaled(jax.jit(m.init)(key)["params"], key, 1.0, 0.1)
 
 
 @pytest.fixture(scope="module")
@@ -73,11 +74,7 @@ def engine(model, **kw):
 
 
 def alone(model, prompt, n, **kw):
-    eng = engine(model, **kw)
-    req = eng.submit(prompt, max_new_tokens=n)
-    eng.run()
-    assert eng.compile_count == 1
-    return req.tokens
+    return served_alone(engine, model, prompt, n, **kw)
 
 
 def paged_cache(adapter, rows, page=PAGE, max_len=64):
@@ -98,7 +95,8 @@ def test_the_absorbed_form_is_the_expanded_one(model):
     the cached latent; the reference materialises k_nope and v a head."""
     ids = tokens(40, rows=2)
     want = builder.reference_logits(model[1], ids, CFG)
-    got = np.asarray(model[0].apply({"params": model[1]}, jnp.asarray(ids)))
+    got = np.asarray(jax.jit(model[0].apply)({"params": model[1]},
+                                             jnp.asarray(ids)))
     assert want.std() > 1.0          # logits of order 1, so TOL means it
     np.testing.assert_allclose(got, want, **TOL)
 
@@ -114,12 +112,13 @@ def test_prefill_then_decode_through_the_paged_latent_pool_is_the_reference(
     want = builder.reference_logits(model[1], ids, CFG)
     cache, out, lo = paged_cache(adapter, 2), [], 0
     for n in chunks:
-        logits, cache = adapter.prefill_append(model[1], ids[:, lo:lo + n],
-                                               cache)
+        logits, cache = compiled(adapter, "prefill_append")(
+            model[1], ids[:, lo:lo + n], cache)
         out.append(logits)
         lo += n
     for t in range(lo, ids.shape[1]):
-        logits, cache = adapter.decode_step(model[1], ids[:, t], cache)
+        logits, cache = compiled(adapter, "decode_step")(
+            model[1], ids[:, t], cache)
         out.append(logits[:, None])
     np.testing.assert_allclose(np.asarray(jnp.concatenate(out, axis=1)),
                                want, **TOL)

@@ -62,8 +62,9 @@ def stored_model(name):
                              vocab_size=1024, n_positions=128, dropout=0.0,
                              use_flash_attention=False, dtype=jnp.float32)
             model = GPT2LMHeadModel(cfg)
-            params = model.init(jax.random.PRNGKey(0),
-                                jnp.zeros((2, 12), jnp.int32))["params"]
+            params = jax.jit(model.init)(
+                jax.random.PRNGKey(0),
+                jnp.zeros((2, 12), jnp.int32))["params"]
         _STORED_MODELS[name] = (cfg, model, params, dims)
     return _STORED_MODELS[name]
 

@@ -1,6 +1,6 @@
 """The families the benchmark already serves emit, token for token, what they
 emitted on the parent of PR 38 (``tests/fixtures/parent_tokens_pr38.json``,
-recorded at e8ffd98 BEFORE the first edit by this file's ``serve``): GPT-2's
+recorded at e8ffd98 BEFORE the first edit by this file's ``served``): GPT-2's
 block, the OLMoE-shaped and the Granite-shaped decoder at tiny sizes, through
 the gather path (pages of 8) and through the interpreted kernels (pages of
 128). Latent attention, the dense layer, YaRN and the sigmoid router are
@@ -14,6 +14,7 @@ edit (``parent_tokens_pr42.json``): the new kind of layer and the composed
 cache are static branches that are off for all four.
 """
 
+import functools
 import json
 import os
 
@@ -59,11 +60,11 @@ def built(family):
     if family == "gpt2":
         cfg = GPT2Config.tiny()
         model = GPT2LMHeadModel(cfg)
-        return model, model.init(jax.random.PRNGKey(0), jnp.zeros(
+        return model, jax.jit(model.init)(jax.random.PRNGKey(0), jnp.zeros(
             (1, 8), jnp.int32))["params"], cfg.vocab_size
     cfg = {"olmoe": OLMOE, "granite": GRANITE, "deepseek": DEEPSEEK}[family]
     model = DecoderLM(cfg)
-    params = model.init(jax.random.PRNGKey(0))["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))["params"]
     if family == "deepseek":    # the selection bias drawn, not zero
         shape = params["moe"]["router_bias"].shape
         params["moe"] = dict(params["moe"], router_bias=0.1
@@ -74,7 +75,11 @@ def built(family):
     return model, params, cfg.vocab_size
 
 
-def serve(model, params, vocab, kernels):
+@functools.lru_cache(maxsize=None)
+def served(family, kernels):
+    """What ``family`` serves through one path: the same run whichever
+    recording it is held to (three families are in both)."""
+    model, params, vocab = built(family)
     eng = InferenceEngine(model, params, config=dict(
         max_slots=3, max_len=128 if kernels else 64, chunk_size=4,
         prefill_chunk=8, use_flash_decode=kernels, paged_kv=True,
@@ -91,5 +96,4 @@ def serve(model, params, vocab, kernels):
     (pr, case) for pr in sorted(RECORDED) for case in sorted(RECORDED[pr])])
 def test_the_served_tokens_are_the_parents(pr, case):
     family, path = case.split(".")
-    assert serve(*built(family), kernels=path == "kernels") == \
-        RECORDED[pr][case]
+    assert served(family, path == "kernels") == RECORDED[pr][case]

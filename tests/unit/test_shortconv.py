@@ -29,6 +29,7 @@ from deepspeed_tpu.inference.adapters import DecoderAdapter
 from deepspeed_tpu.models import decoder, shortconv
 from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
 from deepspeed_tpu.ops.transformer.kernels import decode_attention as da
+from tests.unit.compiled import compiled, served_alone
 from tests.unit.test_telemetry import _parse_prom
 
 builder = harness.load_by_name("model_builders", "lfm2_moe")
@@ -53,7 +54,8 @@ def model():
     # the selection bias drawn, not zero: choosing with it and weighting
     # without it then differ; the norms a head not at 1: a norm over the
     # whole width with the same numbers would then differ
-    params = builder.rescaled(m.init(key)["params"], key, 1.0, 0.6, 0.1)
+    params = builder.rescaled(jax.jit(m.init)(key)["params"], key, 1.0, 0.6,
+                              0.1)
     attn = params["attn"]
     params["attn"] = dict(
         attn, q_norm=1.0 + 0.3 * jax.random.normal(key, attn["q_norm"].shape),
@@ -80,11 +82,7 @@ def engine(model, **kw):
 
 
 def alone(model, prompt, n, **kw):
-    eng = engine(model, **kw)
-    req = eng.submit(prompt, max_new_tokens=n)
-    eng.run()
-    assert eng.compile_count == 1
-    return req.tokens
+    return served_alone(engine, model, prompt, n, **kw)
 
 
 def layer(dtype=jnp.float32, seed=0, c=64):
@@ -327,12 +325,12 @@ def test_prefill_then_paged_decode_is_the_full_forward_pass(model, adapter):
         np.arange(len(req.tokens)), req.tokens])) <= 1e-3
     # and the logits themselves, through the adapter's own two calls
     cache = adapter.init_cache(1, 32)
-    logits, cache = adapter.prefill_append(model[1], jnp.asarray(ids[:, :13]),
-                                           cache)
+    logits, cache = compiled(adapter, "prefill_append")(
+        model[1], jnp.asarray(ids[:, :13]), cache)
     out = [logits[0]]
     for i in range(13, 29):
-        step, cache = adapter.decode_step(model[1], jnp.asarray(ids[:, i]),
-                                          cache)
+        step, cache = compiled(adapter, "decode_step")(
+            model[1], jnp.asarray(ids[:, i]), cache)
         out.append(step)
     np.testing.assert_allclose(np.asarray(jnp.concatenate(out)), want, **TOL)
 
@@ -356,7 +354,8 @@ def test_a_reused_slot_gives_the_stream_it_gives_alone(model):
     b = eng.submit(second, max_new_tokens=7)
     eng.run()
     assert eng.compile_count == 1 and a.tokens
-    assert b.tokens == alone(model, second, 7)      # no reset from the host
+    # against an engine of its own; no reset from the host
+    assert b.tokens == alone(model, second, 7, fresh=True)
 
 
 def test_the_kernel_path_serves_what_the_gather_path_serves(model):

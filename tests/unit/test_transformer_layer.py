@@ -23,7 +23,7 @@ def make_layer(batch, seq, hidden, heads, pre_ln, dtype=jnp.float32,
     layer = DeepSpeedTransformerLayer(cfg)
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.randn(batch, seq, hidden), jnp.float32)
-    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)["params"]
     return layer, cfg, params, x
 
 
@@ -103,7 +103,7 @@ def test_dropout_training_mode_stochastic():
     layer = DeepSpeedTransformerLayer(cfg)
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.randn(b, t, h), jnp.float32)
-    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)["params"]
     train_out = layer.apply({"params": params}, x, deterministic=False)
     eval_out = layer.apply({"params": params}, x, deterministic=True)
     assert not np.allclose(np.asarray(train_out), np.asarray(eval_out))
