@@ -8,6 +8,10 @@ API façade mirrors reference deepspeed/__init__.py: ``initialize()`` returns
 of NCCL/torch.distributed).
 """
 
+import time as _time
+
+_import_started = _time.time()  # ``setup/import`` begins (its last line ends it)
+
 from deepspeed_tpu import moe  # noqa: F401
 from deepspeed_tpu import ops  # noqa: F401
 from deepspeed_tpu.runtime.activation_checkpointing import checkpointing  # noqa: F401
@@ -160,3 +164,13 @@ def add_config_arguments(parser):
     (reference __init__.py:193-206)."""
     parser = _add_core_arguments(parser)
     return parser
+
+
+# The process's record of its own start-up (docs/OBSERVABILITY.md): the
+# import above as ``setup/import``, and from here on every program JAX
+# traces, lowers and compiles, by name.
+from deepspeed_tpu.telemetry import (install_compile_listeners as _listen,
+                                     process_recorder as _process_recorder)
+
+_process_recorder().span("setup/import", _import_started)
+_listen()

@@ -137,8 +137,13 @@ from deepspeed_tpu.telemetry import (
     ProgramRegistry,
     RecompileDetector,
     SpanRecorder,
+    count_compiles_into,
+    mark_ready,
+    process_recorder,
     prometheus_digest,
     prometheus_text,
+    startup_summary,
+    write_merged_trace,
 )
 from deepspeed_tpu.telemetry.autopsy import build_autopsy
 from deepspeed_tpu.utils.logging import logger
@@ -653,367 +658,377 @@ class InferenceEngine(object):
         # serialization as step() itself.
         "_handoff_outbox", "_handoff_enabled",
         "_steps",           # step number the spans carry; stepper-owned
+        "_first_step_began",  # when the first dispatch began; same owner
         "_flight",          # the dispatched, unharvested step; same owner
     })
 
     def __init__(self, model, params, config=None, mesh=None, adapter=None):
-        if config is None:
-            config = InferenceConfig()
-        elif isinstance(config, dict):
-            config = InferenceConfig.from_dict(config)
-        self.config = config
-        # The engine<->model boundary is the ModelAdapter protocol
-        # (inference/adapters): None builds the adapter of the model's
-        # own class (``adapter_class_for``: a DecoderLM's DecoderAdapter,
-        # else GPT-2's) over the model's config — the engine's
-        # use_flash_decode wins over the model config's, None defers
-        # down the chain (model config, then on-TPU default). ``bind``
-        # lets any adapter specialize to this engine's config and mesh
-        # (the page quantum, sparse/ring mode).
-        # The adapter IS the static arg of every jitted program, so the
-        # model dispatch is baked at trace time — no per-call branching,
-        # and the compile-count contract is per (engine, adapter).
-        if adapter is None:
-            adapter = adapter_class_for(model).from_model(
-                model, use_flash_decode=config.use_flash_decode)
-        self._adapter = adapter.bind(config, mesh)
-        # The adapter's cache spec drives every shape downstream: pool
-        # planes, hierarchy sizing, mesh sharding, admission validation.
-        self._gcfg = self._adapter.cache_spec()
-        config.validate_against_model(self._gcfg.n_positions)
-        self.mesh = mesh
+        # The whole constructor is ``setup/engine_init`` of the process's
+        # record of its start-up (docs/OBSERVABILITY.md).
+        with process_recorder().timed("setup/engine_init",
+                                      engine="inference"):
+            if config is None:
+                config = InferenceConfig()
+            elif isinstance(config, dict):
+                config = InferenceConfig.from_dict(config)
+            self.config = config
+            # The engine<->model boundary is the ModelAdapter protocol
+            # (inference/adapters): None builds the adapter of the model's
+            # own class (``adapter_class_for``: a DecoderLM's DecoderAdapter,
+            # else GPT-2's) over the model's config — the engine's
+            # use_flash_decode wins over the model config's, None defers
+            # down the chain (model config, then on-TPU default). ``bind``
+            # lets any adapter specialize to this engine's config and mesh
+            # (the page quantum, sparse/ring mode).
+            # The adapter IS the static arg of every jitted program, so the
+            # model dispatch is baked at trace time — no per-call branching,
+            # and the compile-count contract is per (engine, adapter).
+            if adapter is None:
+                adapter = adapter_class_for(model).from_model(
+                    model, use_flash_decode=config.use_flash_decode)
+            self._adapter = adapter.bind(config, mesh)
+            # The adapter's cache spec drives every shape downstream: pool
+            # planes, hierarchy sizing, mesh sharding, admission validation.
+            self._gcfg = self._adapter.cache_spec()
+            config.validate_against_model(self._gcfg.n_positions)
+            self.mesh = mesh
 
-        # Telemetry. The metrics REGISTRY is always real — counters are
-        # the engine's own bookkeeping (one float add each) and
-        # metrics() must be correct either way. ``telemetry=False``
-        # disables only the optional layers: trace spans (NullRecorder)
-        # and profiler annotations.
-        labels = {"engine": "inference"}
-        if config.replica_id is not None:
-            labels["replica"] = str(config.replica_id)
-        self.telemetry = MetricsRegistry(**labels)
-        self.tracer = (SpanRecorder(capacity=config.trace_ring)
-                       if config.telemetry else NullRecorder())
-        self._scheduler = Scheduler(
-            config.max_slots, config.max_queue,
-            tracer=self.tracer if config.telemetry else None,
-            registry=self.telemetry, replica_id=config.replica_id)
+            # Telemetry. The metrics REGISTRY is always real — counters are
+            # the engine's own bookkeeping (one float add each) and
+            # metrics() must be correct either way. ``telemetry=False``
+            # disables only the optional layers: trace spans (NullRecorder)
+            # and profiler annotations.
+            labels = {"engine": "inference"}
+            if config.replica_id is not None:
+                labels["replica"] = str(config.replica_id)
+            self.telemetry = MetricsRegistry(**labels)
+            count_compiles_into(self.telemetry)
+            self.tracer = (SpanRecorder(capacity=config.trace_ring)
+                           if config.telemetry else NullRecorder())
+            self._scheduler = Scheduler(
+                config.max_slots, config.max_queue,
+                tracer=self.tracer if config.telemetry else None,
+                registry=self.telemetry, replica_id=config.replica_id)
 
-        # Engine-lifetime speculation constant: (spec_k, spec_ngram) or
-        # None. STATIC — it rides the jit static args, so the spec
-        # dispatch is baked into the one mixed-step compile.
-        self._spec = ((config.spec_k, config.spec_ngram)
-                      if config.resolved_spec_decode() else None)
+            # Engine-lifetime speculation constant: (spec_k, spec_ngram) or
+            # None. STATIC — it rides the jit static args, so the spec
+            # dispatch is baked into the one mixed-step compile.
+            self._spec = ((config.spec_k, config.spec_ngram)
+                          if config.resolved_spec_decode() else None)
 
-        # Chunked prefill appends up to prefill_chunk positions at a
-        # frontier that can sit as deep as max_len-1 — the plane carries
-        # that much slack so the write never clamps (kv_pool docstring).
-        # Speculation raises the floor to spec_k+1: a verify writes
-        # spec_k+1 k/v positions at the frontier and the ring takes the
-        # spec_k+1 choices one past it.
-        slack = config.prefill_chunk
-        if self._spec is not None:
-            slack = max(slack, config.spec_k + 1)
-        # What the adapter says of HOW the model makes its tokens: a block
-        # length past 1 is generation by diffusion over blocks, and picks the
-        # lane and the scan of the one program (``_mixed_step_program``).
-        # ``_lookahead``: the positions past where a row stands at a step's
-        # start that the step's decode lane can write. A block takes two
-        # passes at the least, so a scan of ``chunk_size`` passes commits at
-        # most half as many blocks and writes one more.
-        self._block = int(getattr(self._adapter, "block_length", 1))
-        if self._block > 1:
-            self._lookahead = self._block * (config.chunk_size // 2 + 1)
-            slack = max(slack, self._lookahead)
-        else:
-            self._lookahead = config.chunk_size * (
-                config.spec_k + 1 if self._spec is not None else 1)
-        self._slack = slack
-        # KV memory hierarchy (inference/kv_hierarchy): None when every
-        # tier is off — the flat pool, bit-for-bit the pre-hierarchy
-        # engine. The spec is part of the pool-shape contract, so it
-        # must exist before _build_pool.
-        hspec = spec_from_config(config)
-        # Steps kept in flight between two step() calls (_step_once). 1
-        # unless this engine was BUILT with a feature whose host decision
-        # reads the result of the step just dispatched: speculation (how
-        # many tokens a row emitted), the prefix and offload tiers (prefix
-        # publishing and swap victims read the pool after a harvest), the
-        # prefill role (it captures the slots a harvest found decoding).
-        self._depth = int(self._spec is None and not hspec.prefix
-                          and not hspec.offload and config.role != "prefill")
-        self._flight = None     # the _Flight dispatched and not harvested
-        self._hier = None
-        self._last_swap_out_s = None
-        # Most recent step harvest (host arrays). metrics() derives its
-        # frontier hint from this instead of paying a fresh device sync
-        # per scrape; None until the first step and across pool rebuilds.
-        self._last_snap = None
-        # Paged KV pool (``inference.paged_kv``): plane storage becomes
-        # a shared page arena + per-slot block tables (kv_pool paged
-        # layout), and this host-side allocator owns page lifetime —
-        # mapping at the step boundary, refcounted prefix sharing,
-        # page-aware admission. None keeps the dense slotted pool,
-        # bit-for-bit the pre-paging engine (the A/B default).
-        self._pager = None
-        if config.paged_kv:
-            p_len = paged_plane_len(self._gcfg, config.max_len, slack,
-                                    config.kv_page_len)
-            n_lp = p_len // config.kv_page_len
-            usable = config.kv_pages or config.max_slots * n_lp
-            self._pager = PageAllocator(config.max_slots, n_lp, usable,
+            # Chunked prefill appends up to prefill_chunk positions at a
+            # frontier that can sit as deep as max_len-1 — the plane carries
+            # that much slack so the write never clamps (kv_pool docstring).
+            # Speculation raises the floor to spec_k+1: a verify writes
+            # spec_k+1 k/v positions at the frontier and the ring takes the
+            # spec_k+1 choices one past it.
+            slack = config.prefill_chunk
+            if self._spec is not None:
+                slack = max(slack, config.spec_k + 1)
+            # What the adapter says of HOW the model makes its tokens: a block
+            # length past 1 is generation by diffusion over blocks, and picks the
+            # lane and the scan of the one program (``_mixed_step_program``).
+            # ``_lookahead``: the positions past where a row stands at a step's
+            # start that the step's decode lane can write. A block takes two
+            # passes at the least, so a scan of ``chunk_size`` passes commits at
+            # most half as many blocks and writes one more.
+            self._block = int(getattr(self._adapter, "block_length", 1))
+            if self._block > 1:
+                self._lookahead = self._block * (config.chunk_size // 2 + 1)
+                slack = max(slack, self._lookahead)
+            else:
+                self._lookahead = config.chunk_size * (
+                    config.spec_k + 1 if self._spec is not None else 1)
+            self._slack = slack
+            # KV memory hierarchy (inference/kv_hierarchy): None when every
+            # tier is off — the flat pool, bit-for-bit the pre-hierarchy
+            # engine. The spec is part of the pool-shape contract, so it
+            # must exist before _build_pool.
+            hspec = spec_from_config(config)
+            # Steps kept in flight between two step() calls (_step_once). 1
+            # unless this engine was BUILT with a feature whose host decision
+            # reads the result of the step just dispatched: speculation (how
+            # many tokens a row emitted), the prefix and offload tiers (prefix
+            # publishing and swap victims read the pool after a harvest), the
+            # prefill role (it captures the slots a harvest found decoding).
+            self._depth = int(self._spec is None and not hspec.prefix
+                              and not hspec.offload and config.role != "prefill")
+            self._flight = None     # the _Flight dispatched and not harvested
+            self._hier = None
+            self._last_swap_out_s = None
+            # Most recent step harvest (host arrays). metrics() derives its
+            # frontier hint from this instead of paying a fresh device sync
+            # per scrape; None until the first step and across pool rebuilds.
+            self._last_snap = None
+            # Paged KV pool (``inference.paged_kv``): plane storage becomes
+            # a shared page arena + per-slot block tables (kv_pool paged
+            # layout), and this host-side allocator owns page lifetime —
+            # mapping at the step boundary, refcounted prefix sharing,
+            # page-aware admission. None keeps the dense slotted pool,
+            # bit-for-bit the pre-paging engine (the A/B default).
+            self._pager = None
+            if config.paged_kv:
+                p_len = paged_plane_len(self._gcfg, config.max_len, slack,
                                         config.kv_page_len)
-            plane_len = p_len
-        else:
-            plane_len = plane_len_for(self._gcfg, config.max_len, slack)
-        if hspec.enabled:
-            self._hier = KVHierarchy(
-                hspec, self._gcfg, plane_len,
-                config.max_slots, config.hbm_budget_bytes,
-                pager=self._pager)
-        self._tp = mesh is not None and mesh_lib.mp_size(mesh) > 1
-        pool = self._build_pool()
-        if self._tp:
-            param_sh, _, _ = mesh_lib.zero_shardings(mesh, params, stage=0)
-            params = jax.tree_util.tree_map(jax.device_put, params, param_sh)
-            pool_out = pool_shardings(mesh, pool)
-            rep = mesh_lib.replicated(mesh)
-            mixed_out = (pool_out, rep, rep, rep, rep)
-        else:
-            mixed_out = None
-        self._params = params
-        self._pool = pool
+                n_lp = p_len // config.kv_page_len
+                usable = config.kv_pages or config.max_slots * n_lp
+                self._pager = PageAllocator(config.max_slots, n_lp, usable,
+                                            config.kv_page_len)
+                plane_len = p_len
+            else:
+                plane_len = plane_len_for(self._gcfg, config.max_len, slack)
+            if hspec.enabled:
+                self._hier = KVHierarchy(
+                    hspec, self._gcfg, plane_len,
+                    config.max_slots, config.hbm_budget_bytes,
+                    pager=self._pager)
+            self._tp = mesh is not None and mesh_lib.mp_size(mesh) > 1
+            with process_recorder().timed("setup/pool"):
+                pool = self._build_pool()
+            if self._tp:
+                with process_recorder().timed("setup/params"):
+                    param_sh, _, _ = mesh_lib.zero_shardings(
+                        mesh, params, stage=0)
+                    params = jax.tree_util.tree_map(
+                        jax.device_put, params, param_sh)
+                pool_out = pool_shardings(mesh, pool)
+                rep = mesh_lib.replicated(mesh)
+                mixed_out = (pool_out, rep, rep, rep, rep)
+            else:
+                mixed_out = None
+            self._params = params
+            self._pool = pool
 
-        # Per-engine jit instance: its _cache_size() IS the compile
-        # counter the zero-recompile guarantee is asserted against. The
-        # engine's own callable gives it a distinct jit cache — jax's
-        # pjit cache is keyed on the underlying function, so two engines
-        # jitting the bare program would pool their cache entries and
-        # the counter would read other engines' compiles. Donating the
-        # pool threads one cache allocation through every program call
-        # instead of double-buffering gigabytes of k/v.
-        def mixed_step(*args):
-            # Traced with the kernels launched shard-local over this
-            # engine's mesh (no mesh: launched as they are), and named
-            # for what it is: a trace's ``hlo_module`` reads
-            # ``jit_mixed_step``.
-            with kernels_on_mesh(mesh):
-                return _mixed_step_program(*args)
+            # Per-engine jit instance: its _cache_size() IS the compile
+            # counter the zero-recompile guarantee is asserted against. The
+            # engine's own callable gives it a distinct jit cache — jax's
+            # pjit cache is keyed on the underlying function, so two engines
+            # jitting the bare program would pool their cache entries and
+            # the counter would read other engines' compiles. Donating the
+            # pool threads one cache allocation through every program call
+            # instead of double-buffering gigabytes of k/v.
+            def mixed_step(*args):
+                # Traced with the kernels launched shard-local over this
+                # engine's mesh (no mesh: launched as they are), and named
+                # for what it is: a trace's ``hlo_module`` reads
+                # ``jit_mixed_step``.
+                with kernels_on_mesh(mesh):
+                    return _mixed_step_program(*args)
 
-        platform = (mesh.devices.flat[0] if mesh is not None
-                    else jax.devices()[0]).platform
-        self._mixed = jax.jit(
-            mixed_step, static_argnums=(1, 2, 3),
-            donate_argnums=(4,), out_shardings=mixed_out,
-            compiler_options=step_compiler_options(platform))
+            platform = (mesh.devices.flat[0] if mesh is not None
+                        else jax.devices()[0]).platform
+            self._mixed = jax.jit(
+                mixed_step, static_argnums=(1, 2, 3),
+                donate_argnums=(4,), out_shardings=mixed_out,
+                compiler_options=step_compiler_options(platform))
 
-        # Perf X-ray (telemetry/xray.py): the compiled-program cost/
-        # memory observatory. Step paths stash shape signatures only
-        # (no device touch); export paths — perf_xray() itself — pay
-        # the one-time AOT lower+compile, which never touches a jit
-        # wrapper's dispatch cache and so cannot read as a recompile.
-        self._xray = None
-        self._ledger = None
-        if config.perf_xray:
-            self._xray = ProgramRegistry(
-                self.telemetry, platform=jax.default_backend())
+            # Perf X-ray (telemetry/xray.py): the compiled-program cost/
+            # memory observatory. Step paths stash shape signatures only
+            # (no device touch); export paths — perf_xray() itself — pay
+            # the one-time AOT lower+compile, which never touches a jit
+            # wrapper's dispatch cache and so cannot read as a recompile.
+            self._xray = None
+            self._ledger = None
+            if config.perf_xray:
+                self._xray = ProgramRegistry(
+                    self.telemetry, platform=jax.default_backend())
 
-        # Recompile detection: the test-only compile_count contract as a
-        # RUNTIME gauge. The mixed program auto-warms after its first
-        # step. The xray identity hook makes the post-warm warning name
-        # the exact program (HLO fingerprint, old -> new shapes).
-        self.recompile_detector = RecompileDetector(
-            self.telemetry,
-            describe=self._xray.identity if self._xray is not None
-            else None)
-        self.recompile_detector.watch("mixed_step", self._mixed)
+            # Recompile detection: the test-only compile_count contract as a
+            # RUNTIME gauge. The mixed program auto-warms after its first
+            # step. The xray identity hook makes the post-warm warning name
+            # the exact program (HLO fingerprint, old -> new shapes).
+            self.recompile_detector = RecompileDetector(
+                self.telemetry,
+                describe=self._xray.identity if self._xray is not None
+                else None)
+            self.recompile_detector.watch("mixed_step", self._mixed)
 
-        self.timers = SynchronizedWallClockTimer(registry=self.telemetry)
-        self.counters = _CounterBank(self.telemetry, ((
-            # Generation by diffusion over blocks (docs/OBSERVABILITY.md):
-            # live (slot, iteration) places of the scan, those of them that
-            # were commit passes, tokens delivered by an unmasking, blocks
-            # committed. Registered for such a model only.
-            "diffusion_passes", "diffusion_commit_passes",
-            "diffusion_tokens_unmasked", "diffusion_blocks_committed")
-            if self._block > 1 else ()) + (
-            "tokens_out", "chunks", "steps_dispatched_ahead", "prefills",
-            "prefill_tokens", "lane_steps",
-            "requests_completed", "occupied_slot_steps", "slot_steps",
-            # Resilience counters (docs/RESILIENCE.md). deadline_sheds
-            # and faults_injected are get-or-create by name, so the
-            # scheduler's and injector's handles are these same objects.
-            "faults_injected", "recoveries", "requests_replayed",
-            "deadline_sheds", "step_stalls",
-            # KV-hierarchy counters (docs/OBSERVABILITY.md) — zero
-            # forever on a flat-pool engine.
-            "prefix_hits", "prefix_misses", "prefix_inserts",
-            "prefix_evictions", "swap_outs", "swap_ins",
-            # Front-door priority preemption (inference/frontdoor):
-            # batch sessions parked in the swapped phase to protect an
-            # interactive TTFT budget, and their later resumes. Zero
-            # forever without a front door driving this engine.
-            "preemptions", "preempt_resumes",
-            # Fleet-prefix counters (docs/INFERENCE.md): planes adopted
-            # from peer replicas, host bytes those shipments moved, and
-            # requests the fleet routed here FOR a cached prefix. The
-            # fleet increments the latter; a standalone engine keeps
-            # them at zero.
-            "prefix_adoptions", "prefix_bytes_shipped",
-            "affinity_routed",
-            # Disaggregated prefill/decode (docs/INFERENCE.md):
-            # ``handoffs`` counts captures on a prefill-role donor,
-            # ``handoffs_in`` adoptions on a decode acceptor,
-            # ``handoff_fallbacks`` migrations that re-prefilled on a
-            # survivor instead, ``handoff_bytes_shipped`` the host bytes
-            # the captured records moved. Zero forever outside a
-            # role-typed fleet.
-            "handoffs", "handoffs_in", "handoff_fallbacks",
-            "handoff_bytes_shipped"))
-        if self._hier is not None:
-            # The hierarchy increments hits/misses/inserts itself; hand
-            # it the bank so those land in the same registry counters.
-            self._hier.counters = self.counters
-        # Resilience: health machine (exports the ``health_state`` live
-        # gauge), step watchdog, recovery bookkeeping. The fault
-        # injector stays None unless inject_faults() arms one — every
-        # hot-path hook is a single ``is not None`` test when off.
-        self._health = HealthState(self.telemetry)
-        self._watchdog = StepWatchdog(config.step_budget_s, self._on_stall)
-        self._injector = None
-        self._fatal = fatal_step_errors()
-        self._recovery_streak = 0
-        self._recovery_seconds = self.telemetry.histogram("recovery_seconds")
-        # One record per recovery: absolute t_start/t_end, duration,
-        # error, replay count — the chaos loadgen's SLO-impact windows.
-        self.recovery_log = []
-        # Front-door priority preemption: rids HELD in the swapped
-        # phase (resume-first swap-in skips them until released), and
-        # rids whose eventual swap-in should count as a preempt_resume
-        # rather than a plain swap_in. Mutated in place only — same
-        # external serialization as every engine entry.
-        self._preempt_hold = set()
-        self._preempted_rids = set()
-        # Live gauges: sampled at read (scrape) time, zero hot-path cost.
-        self.telemetry.gauge("queue_depth").set_fn(
-            lambda: len(self._scheduler.queue))
-        self.telemetry.gauge("slots_running").set_fn(
-            lambda: len(self._scheduler.running))
-        self.telemetry.gauge("slots_prefilling").set_fn(
-            lambda: sum(1 for r in self._scheduler.running.values()
-                        if r.phase == "prefilling"))
-        self.telemetry.gauge("slot_occupancy").set_fn(
-            self._scheduler.occupancy)
-        # Share of all dispatched steps that were dispatched while the one
-        # before was unharvested (1 - 1/steps in a steady run, 0 on an
-        # engine built at depth 0).
-        self.telemetry.gauge("steps_ahead_share").set_fn(
-            lambda: self.counters["steps_dispatched_ahead"]
-            / float(max(self._steps, 1)))
-        # The one prefill lane's load, over all harvested steps: the share
-        # of them whose lane carried a slice, and how full those slices
-        # were (a prompt's last slice is as short as what is left of it).
-        self.telemetry.gauge("lane_busy_share").set_fn(
-            lambda: self.counters["lane_steps"]
-            / float(max(self.counters["chunks"], 1)))
-        self.telemetry.gauge("lane_fill").set_fn(
-            lambda: self.counters["prefill_tokens"]
-            / float(max(self.counters["lane_steps"], 1)
-                    * self.config.prefill_chunk))
-        self.telemetry.gauge("kv_pool_bytes").set_fn(
-            lambda: pool_nbytes(self._pool))
-        # Same footprint under the name the capacity dashboards key on:
-        # the one HBM number the paged-vs-dense capacity pin compares.
-        self.telemetry.gauge("kv_hbm_bytes").set_fn(
-            lambda: pool_nbytes(self._pool))
-        if self._xray is not None:
-            # HBM ledger: predicted (params + KV arena, which counts a
-            # model's recurrent state a slot: pool_nbytes sums every leaf
-            # of the pool + largest
-            # program temp) vs live device.memory_stats() where the
-            # backend has it. program_temp reads 0 until the first
-            # xray export materializes — a scrape must never compile.
-            self._ledger = HBMLedger(
-                self.telemetry, capacity_bytes=config.hbm_budget_bytes)
-            params_bytes = sum(
-                int(getattr(leaf, "nbytes", 0))
-                for leaf in jax.tree_util.tree_leaves(self._params))
-            self._ledger.set_component("params", params_bytes)
-            self._ledger.set_component(
-                "kv_arena", lambda: pool_nbytes(self._pool))
-            self._ledger.set_component(
-                "program_temp", self._xray.max_temp_bytes)
-        if self._pager is not None:
-            pg = self._pager
-            self.telemetry.gauge("kv_pages_in_use").set_fn(pg.pages_in_use)
-            self.telemetry.gauge("kv_pages_free").set_fn(pg.pages_free)
-            self.telemetry.gauge("kv_page_fragmentation").set_fn(
-                lambda: pg.fragmentation(self._live_tokens()))
-            self.telemetry.gauge("kv_live_page_share").set_fn(
-                self._live_page_share)
-            self.telemetry.gauge("kv_unit_fill").set_fn(self._unit_fill)
-        # Span-ring overflow as a live series: a truncated autopsy
-        # (telemetry/autopsy.py hop_gaps) is detectable from the same
-        # scrape that would have shown the alert, instead of silently
-        # incomplete. Reads 0 forever with telemetry off (NullRecorder).
-        self.telemetry.gauge("trace_spans_dropped").set_fn(
-            lambda: self.tracer.dropped)
-        if self._hier is not None:
-            h = self._hier
-            self.telemetry.gauge("prefix_hit_rate").set_fn(h.hit_rate)
-            self.telemetry.gauge("kv_bytes_aliased").set_fn(
-                h.bytes_aliased_live)
-            self.telemetry.gauge("kv_bytes_per_slot").set_fn(
-                h.bytes_per_slot)
-            self.telemetry.gauge("effective_slots").set_fn(
-                h.effective_slots)
-            self.telemetry.gauge("slots_swapped").set_fn(
-                lambda: len(self._scheduler.swapped))
-            self._swap_out_hist = self.telemetry.histogram(
-                "swap_out_seconds")
-            self._swap_in_hist = self.telemetry.histogram(
-                "swap_in_seconds")
-        # Latency histograms (queue_wait_seconds lives in the scheduler;
-        # same registry object — get-or-create is by name).
-        self._ttft_hist = self.telemetry.histogram("ttft_seconds")
-        self._itl_hist = self.telemetry.histogram("inter_token_seconds")
-        self._qwait_hist = self.telemetry.histogram("queue_wait_seconds")
-        # The three parts of admit -> first token (Request.phase_ms),
-        # observed once a request beside ttft_seconds.
-        self._lane_wait_hist = self.telemetry.histogram("lane_wait_seconds")
-        self._lane_run_hist = self.telemetry.histogram("lane_run_seconds")
-        self._first_lag_hist = self.telemetry.histogram(
-            "first_token_lag_seconds")
-        # Disaggregated serving (fleet roles). The role is a routing/
-        # capture contract, not a program variant: every role runs the
-        # same mixed-step program (the prefill lane cond-skips when
-        # unused), so compile_count stays 1 per replica whatever the
-        # role. ``_handoff_outbox`` holds (req, record, t_capture)
-        # triples between a prefill-role step's capture and the fleet
-        # pump's drain; the latency histogram spans capture -> adopt
-        # (the pump observes it — on the donor's registry, so the
-        # migration cost is attributed to the replica that sheds it).
-        self.role = config.role
-        self._handoff_enabled = config.role == "prefill"
-        self._handoff_outbox = []
-        self._handoff_latency_hist = self.telemetry.histogram(
-            "handoff_latency_seconds")
-        # accepted-tokens-per-occupied-slot-step histogram (index =
-        # count, 1..spec_k+1; index 0 stays empty — an occupied step
-        # always emits at least the bonus token). Bounded memory
-        # whatever the run length; metrics() derives mean/p50/p99 and
-        # the draft acceptance rate from it. ``_accept_base`` is the
-        # window floor metrics(reset=True) advances.
-        # For a model that generates by diffusion over blocks the same pair
-        # holds the tokens a LIVE pass delivered (index = count, 0..block: a
-        # commit pass delivers none).
-        self._accept_hist = np.zeros(
-            self._block + 1 if self._block > 1 else config.spec_k + 2,
-            np.int64)
-        self._accept_base = np.zeros_like(self._accept_hist)
-        self._t0 = time.time()
-        self._window_t0 = self._t0
-        self._steps = 0
+            self.timers = SynchronizedWallClockTimer(registry=self.telemetry)
+            self.counters = _CounterBank(self.telemetry, ((
+                # Generation by diffusion over blocks (docs/OBSERVABILITY.md):
+                # live (slot, iteration) places of the scan, those of them that
+                # were commit passes, tokens delivered by an unmasking, blocks
+                # committed. Registered for such a model only.
+                "diffusion_passes", "diffusion_commit_passes",
+                "diffusion_tokens_unmasked", "diffusion_blocks_committed")
+                if self._block > 1 else ()) + (
+                "tokens_out", "chunks", "steps_dispatched_ahead", "prefills",
+                "prefill_tokens", "lane_steps",
+                "requests_completed", "occupied_slot_steps", "slot_steps",
+                # Resilience counters (docs/RESILIENCE.md). deadline_sheds
+                # and faults_injected are get-or-create by name, so the
+                # scheduler's and injector's handles are these same objects.
+                "faults_injected", "recoveries", "requests_replayed",
+                "deadline_sheds", "step_stalls",
+                # KV-hierarchy counters (docs/OBSERVABILITY.md) — zero
+                # forever on a flat-pool engine.
+                "prefix_hits", "prefix_misses", "prefix_inserts",
+                "prefix_evictions", "swap_outs", "swap_ins",
+                # Front-door priority preemption (inference/frontdoor):
+                # batch sessions parked in the swapped phase to protect an
+                # interactive TTFT budget, and their later resumes. Zero
+                # forever without a front door driving this engine.
+                "preemptions", "preempt_resumes",
+                # Fleet-prefix counters (docs/INFERENCE.md): planes adopted
+                # from peer replicas, host bytes those shipments moved, and
+                # requests the fleet routed here FOR a cached prefix. The
+                # fleet increments the latter; a standalone engine keeps
+                # them at zero.
+                "prefix_adoptions", "prefix_bytes_shipped",
+                "affinity_routed",
+                # Disaggregated prefill/decode (docs/INFERENCE.md):
+                # ``handoffs`` counts captures on a prefill-role donor,
+                # ``handoffs_in`` adoptions on a decode acceptor,
+                # ``handoff_fallbacks`` migrations that re-prefilled on a
+                # survivor instead, ``handoff_bytes_shipped`` the host bytes
+                # the captured records moved. Zero forever outside a
+                # role-typed fleet.
+                "handoffs", "handoffs_in", "handoff_fallbacks",
+                "handoff_bytes_shipped"))
+            if self._hier is not None:
+                # The hierarchy increments hits/misses/inserts itself; hand
+                # it the bank so those land in the same registry counters.
+                self._hier.counters = self.counters
+            # Resilience: health machine (exports the ``health_state`` live
+            # gauge), step watchdog, recovery bookkeeping. The fault
+            # injector stays None unless inject_faults() arms one — every
+            # hot-path hook is a single ``is not None`` test when off.
+            self._health = HealthState(self.telemetry)
+            self._watchdog = StepWatchdog(config.step_budget_s, self._on_stall)
+            self._injector = None
+            self._fatal = fatal_step_errors()
+            self._recovery_streak = 0
+            self._recovery_seconds = self.telemetry.histogram("recovery_seconds")
+            # One record per recovery: absolute t_start/t_end, duration,
+            # error, replay count — the chaos loadgen's SLO-impact windows.
+            self.recovery_log = []
+            # Front-door priority preemption: rids HELD in the swapped
+            # phase (resume-first swap-in skips them until released), and
+            # rids whose eventual swap-in should count as a preempt_resume
+            # rather than a plain swap_in. Mutated in place only — same
+            # external serialization as every engine entry.
+            self._preempt_hold = set()
+            self._preempted_rids = set()
+            # Live gauges: sampled at read (scrape) time, zero hot-path cost.
+            self.telemetry.gauge("queue_depth").set_fn(
+                lambda: len(self._scheduler.queue))
+            self.telemetry.gauge("slots_running").set_fn(
+                lambda: len(self._scheduler.running))
+            self.telemetry.gauge("slots_prefilling").set_fn(
+                lambda: sum(1 for r in self._scheduler.running.values()
+                            if r.phase == "prefilling"))
+            self.telemetry.gauge("slot_occupancy").set_fn(
+                self._scheduler.occupancy)
+            # Share of all dispatched steps that were dispatched while the one
+            # before was unharvested (1 - 1/steps in a steady run, 0 on an
+            # engine built at depth 0).
+            self.telemetry.gauge("steps_ahead_share").set_fn(
+                lambda: self.counters["steps_dispatched_ahead"]
+                / float(max(self._steps, 1)))
+            # The one prefill lane's load, over all harvested steps: the share
+            # of them whose lane carried a slice, and how full those slices
+            # were (a prompt's last slice is as short as what is left of it).
+            self.telemetry.gauge("lane_busy_share").set_fn(
+                lambda: self.counters["lane_steps"]
+                / float(max(self.counters["chunks"], 1)))
+            self.telemetry.gauge("lane_fill").set_fn(
+                lambda: self.counters["prefill_tokens"]
+                / float(max(self.counters["lane_steps"], 1)
+                        * self.config.prefill_chunk))
+            self.telemetry.gauge("kv_pool_bytes").set_fn(
+                lambda: pool_nbytes(self._pool))
+            # Same footprint under the name the capacity dashboards key on:
+            # the one HBM number the paged-vs-dense capacity pin compares.
+            self.telemetry.gauge("kv_hbm_bytes").set_fn(
+                lambda: pool_nbytes(self._pool))
+            if self._xray is not None:
+                # HBM ledger: predicted (params + KV arena, which counts a
+                # model's recurrent state a slot: pool_nbytes sums every leaf
+                # of the pool + largest
+                # program temp) vs live device.memory_stats() where the
+                # backend has it. program_temp reads 0 until the first
+                # xray export materializes — a scrape must never compile.
+                self._ledger = HBMLedger(
+                    self.telemetry, capacity_bytes=config.hbm_budget_bytes)
+                params_bytes = sum(
+                    int(getattr(leaf, "nbytes", 0))
+                    for leaf in jax.tree_util.tree_leaves(self._params))
+                self._ledger.set_component("params", params_bytes)
+                self._ledger.set_component(
+                    "kv_arena", lambda: pool_nbytes(self._pool))
+                self._ledger.set_component(
+                    "program_temp", self._xray.max_temp_bytes)
+            if self._pager is not None:
+                pg = self._pager
+                self.telemetry.gauge("kv_pages_in_use").set_fn(pg.pages_in_use)
+                self.telemetry.gauge("kv_pages_free").set_fn(pg.pages_free)
+                self.telemetry.gauge("kv_page_fragmentation").set_fn(
+                    lambda: pg.fragmentation(self._live_tokens()))
+                self.telemetry.gauge("kv_live_page_share").set_fn(
+                    self._live_page_share)
+                self.telemetry.gauge("kv_unit_fill").set_fn(self._unit_fill)
+            # Span-ring overflow as a live series: a truncated autopsy
+            # (telemetry/autopsy.py hop_gaps) is detectable from the same
+            # scrape that would have shown the alert, instead of silently
+            # incomplete. Reads 0 forever with telemetry off (NullRecorder).
+            self.telemetry.gauge("trace_spans_dropped").set_fn(
+                lambda: self.tracer.dropped)
+            if self._hier is not None:
+                h = self._hier
+                self.telemetry.gauge("prefix_hit_rate").set_fn(h.hit_rate)
+                self.telemetry.gauge("kv_bytes_aliased").set_fn(
+                    h.bytes_aliased_live)
+                self.telemetry.gauge("kv_bytes_per_slot").set_fn(
+                    h.bytes_per_slot)
+                self.telemetry.gauge("effective_slots").set_fn(
+                    h.effective_slots)
+                self.telemetry.gauge("slots_swapped").set_fn(
+                    lambda: len(self._scheduler.swapped))
+                self._swap_out_hist = self.telemetry.histogram(
+                    "swap_out_seconds")
+                self._swap_in_hist = self.telemetry.histogram(
+                    "swap_in_seconds")
+            # Latency histograms (queue_wait_seconds lives in the scheduler;
+            # same registry object — get-or-create is by name).
+            self._ttft_hist = self.telemetry.histogram("ttft_seconds")
+            self._itl_hist = self.telemetry.histogram("inter_token_seconds")
+            self._qwait_hist = self.telemetry.histogram("queue_wait_seconds")
+            # The three parts of admit -> first token (Request.phase_ms),
+            # observed once a request beside ttft_seconds.
+            self._lane_wait_hist = self.telemetry.histogram("lane_wait_seconds")
+            self._lane_run_hist = self.telemetry.histogram("lane_run_seconds")
+            self._first_lag_hist = self.telemetry.histogram(
+                "first_token_lag_seconds")
+            # Disaggregated serving (fleet roles). The role is a routing/
+            # capture contract, not a program variant: every role runs the
+            # same mixed-step program (the prefill lane cond-skips when
+            # unused), so compile_count stays 1 per replica whatever the
+            # role. ``_handoff_outbox`` holds (req, record, t_capture)
+            # triples between a prefill-role step's capture and the fleet
+            # pump's drain; the latency histogram spans capture -> adopt
+            # (the pump observes it — on the donor's registry, so the
+            # migration cost is attributed to the replica that sheds it).
+            self.role = config.role
+            self._handoff_enabled = config.role == "prefill"
+            self._handoff_outbox = []
+            self._handoff_latency_hist = self.telemetry.histogram(
+                "handoff_latency_seconds")
+            # accepted-tokens-per-occupied-slot-step histogram (index =
+            # count, 1..spec_k+1; index 0 stays empty — an occupied step
+            # always emits at least the bonus token). Bounded memory
+            # whatever the run length; metrics() derives mean/p50/p99 and
+            # the draft acceptance rate from it. ``_accept_base`` is the
+            # window floor metrics(reset=True) advances.
+            # For a model that generates by diffusion over blocks the same pair
+            # holds the tokens a LIVE pass delivered (index = count, 0..block: a
+            # commit pass delivers none).
+            self._accept_hist = np.zeros(
+                self._block + 1 if self._block > 1 else config.spec_k + 2,
+                np.int64)
+            self._accept_base = np.zeros_like(self._accept_hist)
+            self._t0 = time.time()
+            self._window_t0 = self._t0
+            self._steps = 0
 
     # --------------------------------------------------------- resilience
 
@@ -1564,6 +1579,13 @@ class InferenceEngine(object):
         if not det.warm:
             if det.total() >= 1:
                 det.mark_warm()
+                # The mixed program has been traced, lowered, compiled or
+                # loaded, and has run once (``setup/first_step``, after the
+                # fact): it can serve.
+                process_recorder().span(
+                    "setup/first_step", self._first_step_began,
+                    engine="inference")
+                mark_ready("inference")
             return
         det.observe()
 
@@ -2302,6 +2324,14 @@ class InferenceEngine(object):
         if not (sched.queue or sched.running or sched.swapped):
             return None
         step = self._steps + 1
+        if step == 1:
+            # ``setup/first_step`` begins (``_observe_compiles`` ends it). A
+            # stamp and no span object: ``step()``, ``_step_once`` and this
+            # function are on the stack while the one program is traced and
+            # lowered, and ONE more local or ``with`` item in any of them
+            # moves every frame beneath (PERF.md, PR 53: +6.5 s of warm
+            # set-up in the closed GPT-2 cell from one local in ``step()``).
+            self._first_step_began = time.time()
         with self.tracer.timed("inference/schedule", step=step):
             offload = self._hier is not None and self._hier.spec.offload
             resumed = self._swap_in_ready() if offload else []
@@ -2716,6 +2746,9 @@ class InferenceEngine(object):
                     "inference.close: the step in flight failed (%s: %s); "
                     "its tokens are dropped", type(exc).__name__, exc)
         self._watchdog.stop()
+        # A process that runs several engines in turn tells one engine's
+        # start-up from the next by this.
+        process_recorder().instant("engine/closed", engine="inference")
 
     def generate(self, prompts, **kw):
         """Batch convenience: submit every prompt, run to completion,
@@ -2951,6 +2984,8 @@ class InferenceEngine(object):
                 "affinity_routed": c.window("affinity_routed"),
             })
         m.update(self._latency_percentiles())
+        # The PROCESS's way to ready (telemetry.startup_summary).
+        m["startup"] = startup_summary()
         if reset:
             self.telemetry.reset_window()
             self._accept_base = self._accept_hist.copy()
@@ -3004,9 +3039,14 @@ class InferenceEngine(object):
 
     def write_trace(self, path):
         """Dump the flight ring as a Chrome trace-event JSON file
-        (Perfetto / chrome://tracing loadable). Raises when telemetry
-        is off — an empty file would read as 'nothing happened'."""
-        return self.tracer.write_chrome_trace(path)
+        (Perfetto / chrome://tracing loadable), with the process
+        recorder's events (the start-up before the first request) under a
+        ``pid`` of their own. Raises when telemetry is off — a file
+        without a request would read as 'nothing happened'."""
+        if isinstance(self.tracer, NullRecorder):
+            raise RuntimeError("telemetry is disabled: no trace to write")
+        return write_merged_trace(
+            path, dict(self.trace_recorders(), process=process_recorder()))
 
     def trace_recorders(self):
         """This engine's span recorders as the label -> recorder map

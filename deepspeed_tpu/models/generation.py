@@ -731,7 +731,7 @@ def generate(model, params, prompt_ids, max_new_tokens, temperature=1.0,
     assert prompt_ids.shape[1] + max_new_tokens <= cfg.n_positions, \
         "prompt + new tokens exceed n_positions={}".format(cfg.n_positions)
     # Host-side profiler scope around the whole-batch dispatch: shows up
-    # as one "generation.generate" block on a DS_TPU_PROFILE_DIR capture.
+    # as one "generation.generate" block on a ``jax.profiler`` capture.
     with jax.profiler.TraceAnnotation("generation.generate"):
         return _generate_jit(params, cfg, prompt_ids, int(max_new_tokens),
                              float(temperature), top_k, rng, eos_token_id)

@@ -68,7 +68,9 @@ from deepspeed_tpu.runtime.utils import (
 )
 from deepspeed_tpu.runtime.utils import global_norm as utils_global_norm
 from deepspeed_tpu.telemetry import (MetricsRegistry, ProgramRegistry,
-                                     SpanRecorder, TensorBoardScalarWriter)
+                                     SpanRecorder, TensorBoardScalarWriter,
+                                     count_compiles_into, mark_ready,
+                                     process_recorder)
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 
@@ -176,181 +178,191 @@ class DeepSpeedEngine(object):
                  dont_change_device=False,
                  mesh=None,
                  seed=1234):
-        self.client_optimizer = optimizer
-        self.client_model_parameters = model_parameters
-        self.client_lr_scheduler = lr_scheduler
-        self.training_data = training_data
-        self.collate_fn = collate_fn
-        self.mpu = mpu
-        self.global_steps = 0
-        self.global_samples = 0
-        self.micro_steps = 0
-        self.skipped_steps = 0
-        self.gradient_average = True
-        # API-parity flag (reference engine.py:369-372 reads it to skip the
-        # dense allreduce). On the TPU jit path gradient reduction is a GSPMD
-        # sharding decision made at trace time, so this flag is informational:
-        # OnebitAdam flips it at the freeze boundary so user scripts that
-        # consult it (as with the reference) observe the same transition.
-        self.enable_backward_allreduce = True
-        self.warn_unscaled_loss = True
-        self.progressive_layer_drop = None
-        self.dist_backend = "xla-ici"
+        # The whole constructor is ``setup/engine_init`` of the process's
+        # record of its start-up (docs/OBSERVABILITY.md).
+        with process_recorder().timed("setup/engine_init",
+                                      engine="training"):
+            self.client_optimizer = optimizer
+            self.client_model_parameters = model_parameters
+            self.client_lr_scheduler = lr_scheduler
+            self.training_data = training_data
+            self.collate_fn = collate_fn
+            self.mpu = mpu
+            self.global_steps = 0
+            self.global_samples = 0
+            self.micro_steps = 0
+            self.skipped_steps = 0
+            self.gradient_average = True
+            # API-parity flag (reference engine.py:369-372 reads it to skip the
+            # dense allreduce). On the TPU jit path gradient reduction is a GSPMD
+            # sharding decision made at trace time, so this flag is informational:
+            # OnebitAdam flips it at the freeze boundary so user scripts that
+            # consult it (as with the reference) observe the same transition.
+            self.enable_backward_allreduce = True
+            self.warn_unscaled_loss = True
+            self.progressive_layer_drop = None
+            self.dist_backend = "xla-ici"
 
-        # Device mesh: the TPU-native replacement for process groups.
-        self.mesh = mesh if mesh is not None else mesh_lib.build_mesh()
-        self.dp_world_size = self._config_world_size()
-        self.mp_world_size = mesh_lib.mp_size(self.mesh)
-        self.world_size = self.dp_world_size
-        self.global_rank = 0
-        self.local_rank = getattr(args, "local_rank", 0) if args else 0
+            # Device mesh: the TPU-native replacement for process groups.
+            self.mesh = mesh if mesh is not None else mesh_lib.build_mesh()
+            self.dp_world_size = self._config_world_size()
+            self.mp_world_size = mesh_lib.mp_size(self.mesh)
+            self.world_size = self.dp_world_size
+            self.global_rank = 0
+            self.local_rank = getattr(args, "local_rank", 0) if args else 0
 
-        # Sequence parallelism reshapes the mesh (dp x sp), which feeds the
-        # batch triangle (train = micro * gas * dp) — peek at the raw config
-        # BEFORE the full parse validates batch sizes.
-        sp_enabled, sp_size = self._peek_sequence_parallel(args, config_params)
-        if sp_enabled:
-            self._setup_sequence_parallel_mesh(mesh, sp_size)
+            # Sequence parallelism reshapes the mesh (dp x sp), which feeds the
+            # batch triangle (train = micro * gas * dp) — peek at the raw config
+            # BEFORE the full parse validates batch sizes.
+            sp_enabled, sp_size = self._peek_sequence_parallel(args, config_params)
+            if sp_enabled:
+                self._setup_sequence_parallel_mesh(mesh, sp_size)
 
-        self._config = self._configure_with_arguments(args, config_params)
-        self._do_args_sanity_check(args)
+            self._config = self._configure_with_arguments(args, config_params)
+            self._do_args_sanity_check(args)
 
-        self.module = model
-        self.training = True
+            self.module = model
+            self.training = True
 
-        # RNG: pure threefry keys replace the reference's CUDA RNG tracker.
-        self._rng = jax.random.PRNGKey(seed)
+            # RNG: pure threefry keys replace the reference's CUDA RNG tracker.
+            self._rng = jax.random.PRNGKey(seed)
 
-        # Precision policy (fp32 master params always).
-        if self.amp_enabled():
-            # The reference hands `amp: {...}` to apex.amp.initialize
-            # (reference engine.py:569-575). The TPU-native cast policy
-            # that matches apex O1/O2 semantics — mixed-precision compute
-            # against fp32 master weights, no loss scaling required — is
-            # bf16 compute, which this engine already implements; amp maps
-            # onto it. Like the reference, amp is mutually exclusive with
-            # the explicit fp16/bf16 blocks.
-            if self.fp16_enabled() or self.bfloat16_enabled():
-                raise ValueError(
-                    "amp is mutually exclusive with the fp16/bf16 config "
-                    "blocks (reference semantics); enable exactly one")
-            opt_level = dict(self.amp_params() or {}).get("opt_level", "O1")
-            if opt_level not in ("O0", "O1", "O2", "O3"):
-                raise ValueError("unknown amp opt_level {!r}".format(opt_level))
-            log_dist("amp enabled (opt_level {}): mapped to the bf16 "
-                     "mixed-precision policy (bf16 compute, fp32 master "
-                     "params)".format(opt_level), ranks=[0])
-            self.compute_dtype = (jnp.float32 if opt_level == "O0"
-                                  else jnp.bfloat16)
-        elif self.fp16_enabled():
-            self.compute_dtype = jnp.float16
-        elif self.bfloat16_enabled():
-            self.compute_dtype = jnp.bfloat16
-        else:
-            self.compute_dtype = jnp.float32
+            # Precision policy (fp32 master params always).
+            if self.amp_enabled():
+                # The reference hands `amp: {...}` to apex.amp.initialize
+                # (reference engine.py:569-575). The TPU-native cast policy
+                # that matches apex O1/O2 semantics — mixed-precision compute
+                # against fp32 master weights, no loss scaling required — is
+                # bf16 compute, which this engine already implements; amp maps
+                # onto it. Like the reference, amp is mutually exclusive with
+                # the explicit fp16/bf16 blocks.
+                if self.fp16_enabled() or self.bfloat16_enabled():
+                    raise ValueError(
+                        "amp is mutually exclusive with the fp16/bf16 config "
+                        "blocks (reference semantics); enable exactly one")
+                opt_level = dict(self.amp_params() or {}).get("opt_level", "O1")
+                if opt_level not in ("O0", "O1", "O2", "O3"):
+                    raise ValueError("unknown amp opt_level {!r}".format(opt_level))
+                log_dist("amp enabled (opt_level {}): mapped to the bf16 "
+                         "mixed-precision policy (bf16 compute, fp32 master "
+                         "params)".format(opt_level), ranks=[0])
+                self.compute_dtype = (jnp.float32 if opt_level == "O0"
+                                      else jnp.bfloat16)
+            elif self.fp16_enabled():
+                self.compute_dtype = jnp.float16
+            elif self.bfloat16_enabled():
+                self.compute_dtype = jnp.bfloat16
+            else:
+                self.compute_dtype = jnp.float32
 
-        # Telemetry registry (telemetry/): the wall_clock_breakdown
-        # timers observe their phase durations into it as timer_seconds
-        # histograms, the throughput timer exposes a live
-        # samples_per_sec gauge, and the step/sample/lr trackers below
-        # read the engine's own state at scrape time. Exporters
-        # (Prometheus text, the TensorBoard scalar writer behind the
-        # tensorboard_* config keys) read the same registry.
-        self.telemetry = MetricsRegistry(engine="training")
-        # The span recorder the serving engine has (telemetry/tracing.py):
-        # one call writes the ring and the profiler's trace under one name.
-        # train_batch leaves train/step > train/shard_batch, train/dispatch,
-        # train/bookkeeping; the three-call path train/forward,
-        # train/backward, train/update. No span syncs the device.
-        self.tracer = SpanRecorder()
-        self.timers = SynchronizedWallClockTimer(registry=self.telemetry)
-        self.tput_timer = ThroughputTimer(
-            batch_size=self.train_micro_batch_size_per_gpu(),
-            num_workers=self.dp_world_size,
-            steps_per_output=self.steps_per_print(),
-            monitor_memory=False,
-            registry=self.telemetry)
-        self.telemetry.gauge("global_steps").set_fn(
-            lambda: self.global_steps)
-        self.telemetry.gauge("global_samples").set_fn(
-            lambda: self.global_samples)
-        self.telemetry.gauge("skipped_steps").set_fn(
-            lambda: self.skipped_steps)
-        self.telemetry.gauge("lr").set_fn(
-            lambda: (self.get_lr() if self.optimizer else [0.0])[0])
-        # What flash attention's launcher resolved from the shapes of the
-        # last call traced: S, the rows of the strips a diagonal block is
-        # taken in (0: the block is its own tile), and the share of the
-        # score tiles it computes (0.5625 at S 128 in a block of 1024).
-        self.telemetry.gauge("flash_subtile").set_fn(
-            lambda: flash_kernels.last_walk()["subtile"])
-        self.telemetry.gauge("flash_tiles_visited_share").set_fn(
-            lambda: flash_kernels.last_walk()["tiles_visited_share"])
-        # ... and the heads a 128-lane tile where that call took the
-        # projection's own [B, T, lanes] layout (2 at head dim 64); 0
-        # where the head-major [B, H, T, d] entry ran.
-        self.telemetry.gauge("flash_lane_pack").set_fn(
-            lambda: flash_kernels.last_walk()["lane_pack"])
-        # How the gradient leaves left the fused step last traced, where
-        # its forward and backward run per chip (_dp_value_and_grad): by
-        # psum_scatter onto ZeRO-2's partition, by psum. Both 0 where GSPMD
-        # partitions the step (one chip, stage 3, a model / pipe / seq mesh).
-        self._zero_leaves = (0, 0)
-        self.telemetry.gauge("zero_scatter_leaves").set_fn(
-            lambda: self._zero_leaves[0])
-        self.telemetry.gauge("zero_psum_leaves").set_fn(
-            lambda: self._zero_leaves[1])
-        # Perf X-ray (telemetry/xray.py): train_batch's fused path
-        # stashes each compiled step program's shape signature here
-        # (microseconds; no compile). perf_xray() / the flops profiler
-        # materialize the cost/memory records on demand.
-        self.xray = ProgramRegistry(self.telemetry,
-                                    platform=jax.default_backend())
+            # Telemetry registry (telemetry/): the wall_clock_breakdown
+            # timers observe their phase durations into it as timer_seconds
+            # histograms, the throughput timer exposes a live
+            # samples_per_sec gauge, and the step/sample/lr trackers below
+            # read the engine's own state at scrape time. Exporters
+            # (Prometheus text, the TensorBoard scalar writer behind the
+            # tensorboard_* config keys) read the same registry.
+            self.telemetry = MetricsRegistry(engine="training")
+            count_compiles_into(self.telemetry)
+            # Where this engine stands on its way to ready: 0 no step yet, 1
+            # its first step under way (``setup/first_step``), 2 ready.
+            self._startup = 0
+            # The span recorder the serving engine has (telemetry/tracing.py):
+            # one call writes the ring and the profiler's trace under one name.
+            # train_batch leaves train/step > train/shard_batch, train/dispatch,
+            # train/bookkeeping; the three-call path train/forward,
+            # train/backward, train/update. No span syncs the device.
+            self.tracer = SpanRecorder()
+            self.timers = SynchronizedWallClockTimer(registry=self.telemetry)
+            self.tput_timer = ThroughputTimer(
+                batch_size=self.train_micro_batch_size_per_gpu(),
+                num_workers=self.dp_world_size,
+                steps_per_output=self.steps_per_print(),
+                monitor_memory=False,
+                registry=self.telemetry)
+            self.telemetry.gauge("global_steps").set_fn(
+                lambda: self.global_steps)
+            self.telemetry.gauge("global_samples").set_fn(
+                lambda: self.global_samples)
+            self.telemetry.gauge("skipped_steps").set_fn(
+                lambda: self.skipped_steps)
+            self.telemetry.gauge("lr").set_fn(
+                lambda: (self.get_lr() if self.optimizer else [0.0])[0])
+            # What flash attention's launcher resolved from the shapes of the
+            # last call traced: S, the rows of the strips a diagonal block is
+            # taken in (0: the block is its own tile), and the share of the
+            # score tiles it computes (0.5625 at S 128 in a block of 1024).
+            self.telemetry.gauge("flash_subtile").set_fn(
+                lambda: flash_kernels.last_walk()["subtile"])
+            self.telemetry.gauge("flash_tiles_visited_share").set_fn(
+                lambda: flash_kernels.last_walk()["tiles_visited_share"])
+            # ... and the heads a 128-lane tile where that call took the
+            # projection's own [B, T, lanes] layout (2 at head dim 64); 0
+            # where the head-major [B, H, T, d] entry ran.
+            self.telemetry.gauge("flash_lane_pack").set_fn(
+                lambda: flash_kernels.last_walk()["lane_pack"])
+            # How the gradient leaves left the fused step last traced, where
+            # its forward and backward run per chip (_dp_value_and_grad): by
+            # psum_scatter onto ZeRO-2's partition, by psum. Both 0 where GSPMD
+            # partitions the step (one chip, stage 3, a model / pipe / seq mesh).
+            self._zero_leaves = (0, 0)
+            self.telemetry.gauge("zero_scatter_leaves").set_fn(
+                lambda: self._zero_leaves[0])
+            self.telemetry.gauge("zero_psum_leaves").set_fn(
+                lambda: self._zero_leaves[1])
+            # Perf X-ray (telemetry/xray.py): train_batch's fused path
+            # stashes each compiled step program's shape signature here
+            # (microseconds; no compile). perf_xray() / the flops profiler
+            # materialize the cost/memory records on demand.
+            self.xray = ProgramRegistry(self.telemetry,
+                                        platform=jax.default_backend())
 
-        self.training_dataloader = self.deepspeed_io(training_data) \
-            if training_data else None
+            self.training_dataloader = self.deepspeed_io(training_data) \
+                if training_data else None
 
-        # Parameters: client-provided pytree, module attribute, or lazy-init
-        # at first forward from the batch shapes.
-        self.params = self._extract_params(model, model_parameters)
+            # Parameters: client-provided pytree, module attribute, or lazy-init
+            # at first forward from the batch shapes.
+            with process_recorder().timed("setup/params"):
+                self.params = self._extract_params(model, model_parameters)
 
-        # Loss scaling (fp16 only; bf16/fp32 need none).
-        self.loss_scaler = None
-        if self.fp16_enabled():
-            self.loss_scaler = CreateLossScaler(
-                dynamic_scaling=self.dynamic_loss_scale(),
-                static_loss_scale=self.loss_scale() or 1.0,
-                dynamic_loss_args=self.dynamic_loss_scale_args())
+            # Loss scaling (fp16 only; bf16/fp32 need none).
+            self.loss_scaler = None
+            if self.fp16_enabled():
+                self.loss_scaler = CreateLossScaler(
+                    dynamic_scaling=self.dynamic_loss_scale(),
+                    static_loss_scale=self.loss_scale() or 1.0,
+                    dynamic_loss_args=self.dynamic_loss_scale_args())
 
-        self._configure_optimizer(optimizer, model_parameters)
-        self._configure_lr_scheduler(lr_scheduler)
+            with process_recorder().timed("setup/optimizer_state"):
+                self._configure_optimizer(optimizer, model_parameters)
+            self._configure_lr_scheduler(lr_scheduler)
 
-        if self.pld_enabled():
-            self.progressive_layer_drop = self._configure_progressive_layer_drop()
+            if self.pld_enabled():
+                self.progressive_layer_drop = self._configure_progressive_layer_drop()
 
-        self._configure_checkpointing()
+            self._configure_checkpointing()
 
-        # TensorBoard monitor (reference engine.py:149-150), now a
-        # telemetry.TensorBoardScalarWriter (lazy; warn-once no-op when
-        # the extra is missing).
-        self._tb_writer = None
-        self._last_loss = None
+            # TensorBoard monitor (reference engine.py:149-150), now a
+            # telemetry.TensorBoardScalarWriter (lazy; warn-once no-op when
+            # the extra is missing).
+            self._tb_writer = None
+            self._last_loss = None
 
-        # Jitted program caches, keyed by static call signature.
-        self._fwd_bwd_cache = {}
-        self._update_fn = None
-        self._fused_step_cache = {}
-        self._cached_grads = None
-        self._grad_acc = None
+            # Jitted program caches, keyed by static call signature.
+            self._fwd_bwd_cache = {}
+            self._update_fn = None
+            self._fused_step_cache = {}
+            self._cached_grads = None
+            self._grad_acc = None
 
-        # ZeRO sharding policy (applied when params exist).
-        self._shardings_ready = False
-        self._grad_constraint = None
-        if self.params is not None:
-            self._setup_shardings()
+            # ZeRO sharding policy (applied when params exist).
+            self._shardings_ready = False
+            self._grad_constraint = None
+            if self.params is not None:
+                self._setup_shardings()
 
-        if self.dump_state():
-            self._dump_state()
+            if self.dump_state():
+                self._dump_state()
 
     # ------------------------------------------------------------------ config
 
@@ -846,8 +858,10 @@ class DeepSpeedEngine(object):
                             lambda _: row_sh, self.opt_state[key])
             self.opt_state_sharding = moment_sh
             # Place state according to policy now (one-time reshard).
-            self.opt_state = jax.device_put(self.opt_state, moment_sh)
-        self.params = jax.device_put(self.params, self.param_sharding)
+            with process_recorder().timed("setup/optimizer_state"):
+                self.opt_state = jax.device_put(self.opt_state, moment_sh)
+        with process_recorder().timed("setup/params"):
+            self.params = jax.device_put(self.params, self.param_sharding)
         # ZeRO-2/3 semantics (reference stage2.py:675-738): gradients are
         # REDUCE-SCATTERED to their owner shard, never materialized
         # replicated. Enforced as a GSPMD constraint inside every grad-
@@ -856,6 +870,30 @@ class DeepSpeedEngine(object):
         # data-parallel region, which writes the psum_scatter itself.
         self._grad_constraint = self.grad_sharding if stage >= 2 else None
         self._shardings_ready = True
+
+    # -------------------------------------------------------------- start-up
+
+    def _first_step_begins(self):
+        """``setup/first_step`` of the process's record of its start-up
+        begins with the first ``train_batch`` / ``forward``: the call in
+        which the step's program is traced, lowered, compiled or loaded, and
+        run once. A stamp, and gone from the stack before the dispatch: one
+        more local or ``with`` item in a function that IS on the stack then
+        moves every frame beneath it (``_traced_with_room``; PERF.md, PR 53)."""
+        if not self._startup:
+            self._startup = 1
+            self._first_step_began = time.time()
+
+    def _mark_ready(self):
+        """``setup/first_step`` ends (recorded after the fact) and
+        ``setup/ready``, once: the first fused step (or the first ``step()``
+        of the three-call path) has returned."""
+        if self._startup == 1:
+            self._startup = 2
+            process_recorder().span("setup/first_step",
+                                    self._first_step_began,
+                                    engine="training")
+            mark_ready("training")
 
     # ------------------------------------------------------------------- RNG
 
@@ -1308,6 +1346,7 @@ class DeepSpeedEngine(object):
         Returns the module output (the loss, by DeepSpeed convention). The
         cached grads are consumed by :meth:`backward`.
         """
+        self._first_step_begins()
         if self.flops_profiler_enabled() and \
                 self.global_steps == self.flops_profiler_start_step() and \
                 self.global_rank == 0:
@@ -1931,6 +1970,7 @@ class DeepSpeedEngine(object):
             with self.tracer.timed("train/update",
                                    step_num=self.global_steps):
                 self._take_model_step(lr_kwargs)
+            self._mark_ready()
 
         self.tput_timer.stop(self.global_rank == 0)
 
@@ -2205,6 +2245,7 @@ class DeepSpeedEngine(object):
             return self._train_batch(batch)
 
     def _train_batch(self, batch):
+        self._first_step_begins()
         if self.fp16_enabled() or self.gradient_accumulation_steps() > 1 or \
                 self._offload_mode():
             loss = self.forward(*batch) if isinstance(batch, (tuple, list)) \
@@ -2236,7 +2277,8 @@ class DeepSpeedEngine(object):
             key = len(inputs)
         first = key not in self._fused_step_cache
         if first:
-            self._fused_step_cache[key] = self._build_fused_step()
+            with process_recorder().timed("setup/programs"):
+                self._fused_step_cache[key] = self._build_fused_step()
 
         self.tput_timer.start()
         group = self.optimizer.param_groups[0]
@@ -2277,6 +2319,7 @@ class DeepSpeedEngine(object):
                 self.optimizer.notify_step(
                     self.global_steps - self.skipped_steps)
             self.tput_timer.stop(True)
+        self._mark_ready()
         return loss
 
     # -------------------------------------------------------- flops profiler

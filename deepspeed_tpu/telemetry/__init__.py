@@ -6,13 +6,18 @@ One dependency-free subsystem every engine emits into:
   bounded-reservoir histograms with windowed snapshots.
 - ``SpanRecorder`` (tracing.py): per-request trace spans exported as
   Chrome trace-event JSON (Perfetto-loadable) and a JSONL flight ring.
+  ``process_recorder()`` is the process's one: its way to ready
+  (``setup/*``, ``compile/*``), whatever an engine's telemetry switch says.
 - ``TimeseriesCollector`` (timeseries.py): periodic windowed registry
   snapshots in a bounded ring — the per-window TTFT/ITL/queue-depth
   curves the sustained-load harness (loadgen/) reports, exportable as
   Chrome counter events next to the span export.
-- ``RecompileDetector`` / ``profile_window`` (instrumentation.py): jit
-  cache-miss detection as a live gauge and the
-  ``DS_TPU_PROFILE_DIR``-gated capture window.
+- ``RecompileDetector`` / ``install_compile_listeners`` /
+  ``startup_summary`` (instrumentation.py): jit cache-miss detection as a
+  live gauge; the process's one set of ``jax.monitoring`` listeners, which
+  turn every trace, lowering and compile (or cache read) into a named span
+  of the process recorder; and what ``engine.metrics()["startup"]`` reads
+  from them.
 - ``prometheus_text`` / ``PrometheusEndpoint`` /
   ``TensorBoardScalarWriter`` (exporters.py): the read-side. The
   tensorboard extra is imported lazily — this package imports clean on
@@ -55,9 +60,11 @@ from deepspeed_tpu.telemetry.exporters import (
     prometheus_text,
 )
 from deepspeed_tpu.telemetry.instrumentation import (
-    PROFILE_DIR_ENV,
     RecompileDetector,
-    profile_window,
+    count_compiles_into,
+    install_compile_listeners,
+    mark_ready,
+    startup_summary,
 )
 from deepspeed_tpu.telemetry.registry import (
     Counter,
@@ -68,7 +75,11 @@ from deepspeed_tpu.telemetry.registry import (
     NullRegistry,
 )
 from deepspeed_tpu.telemetry.timeseries import TimeseriesCollector
-from deepspeed_tpu.telemetry.tracing import NullRecorder, SpanRecorder
+from deepspeed_tpu.telemetry.tracing import (
+    NullRecorder,
+    SpanRecorder,
+    process_recorder,
+)
 from deepspeed_tpu.telemetry.xray import (
     DEVICE_PEAKS,
     HBMLedger,
@@ -87,8 +98,11 @@ __all__ = [
     "NullRecorder",
     "SpanRecorder",
     "RecompileDetector",
-    "profile_window",
-    "PROFILE_DIR_ENV",
+    "count_compiles_into",
+    "install_compile_listeners",
+    "mark_ready",
+    "startup_summary",
+    "process_recorder",
     "prometheus_text",
     "prometheus_digest",
     "PrometheusEndpoint",

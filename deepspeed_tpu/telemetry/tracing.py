@@ -17,9 +17,10 @@ export shapes read the same ring:
 
 The ring is a ``collections.deque(maxlen=capacity)``: memory is bounded
 whatever the run length, and the newest events win (a flight recorder
-keeps the crash, not the boot). Span counts per name are tracked
-EXACTLY (counters, not ring occupancy) so a reader can tell how many
-spans each phase emitted even after the ring wrapped.
+keeps the crash, not the boot). Span counts and summed span seconds per
+name are tracked EXACTLY (counters, not ring occupancy) so a reader can
+tell how many spans each phase emitted, and what they took, even after
+the ring wrapped.
 
 ONE CALL, TWO SINKS. ``timed(name, **args)`` and ``instant(name, **args)``
 write the ring AND a ``jax.profiler.TraceAnnotation(name, **args)`` under
@@ -31,6 +32,11 @@ capture the annotation costs one flag test. ``span(name, start, end)``
 records after the fact and so reaches the ring only.
 
 ``NullRecorder`` is the telemetry-off stand-in: same surface, no work.
+
+``process_recorder()`` is the PROCESS's one ``SpanRecorder``: what the
+process did on its way to ready (``setup/*``, ``compile/*``: the names in
+docs/OBSERVABILITY.md), never a request or a step. It is real whatever an
+engine's ``telemetry`` switch says.
 """
 
 import collections
@@ -59,7 +65,11 @@ class SpanRecorder(object):
         self._annotation = _profiler_annotation()
         self._ring = collections.deque(maxlen=capacity)
         self._counts = {}
+        self._seconds = {}
+        # ts=0 on both clocks, read back to back: a reader of a time on
+        # ``time.perf_counter`` converts once, exactly.
         self._t0 = clock()
+        self._t0_perf = time.perf_counter()
         self.dropped = 0
 
     # ------------------------------------------------------------ record
@@ -76,6 +86,8 @@ class SpanRecorder(object):
         seconds (``end`` defaults to now). Args must be JSON-safe."""
         if end is None:
             end = self._clock()
+        self._seconds[name] = self._seconds.get(name, 0.0) \
+            + max(end - start, 0.0)
         self._emit({
             "name": name,
             "ph": "X",
@@ -142,10 +154,21 @@ class SpanRecorder(object):
         shared epoch with this."""
         return self._t0
 
+    @property
+    def epoch_perf(self):
+        """``time.perf_counter()`` at the moment ``epoch`` was read."""
+        return self._t0_perf
+
     def span_counts(self):
         """Exact per-name event counts since construction (survives ring
         wraparound)."""
         return dict(self._counts)
+
+    def span_seconds(self):
+        """Exact per-name summed span seconds since construction (survives
+        ring wraparound). Spans of one name that nest or overlap are summed
+        twice: a reader that wants their union needs the events."""
+        return dict(self._seconds)
 
     def events(self):
         return list(self._ring)
@@ -181,6 +204,7 @@ class NullRecorder(object):
     capacity = 0
     dropped = 0
     epoch = 0.0
+    epoch_perf = 0.0
 
     class _Null(object):
         __slots__ = ()
@@ -205,6 +229,9 @@ class NullRecorder(object):
     def span_counts(self):
         return {}
 
+    def span_seconds(self):
+        return {}
+
     def events(self):
         return []
 
@@ -219,3 +246,21 @@ class NullRecorder(object):
 
     def write_jsonl(self, path):
         raise RuntimeError("telemetry is disabled: no trace to write")
+
+
+# Room for every event of a start-up with room to spare: the readers cut by
+# time, so they need the events and not only the totals. What fills it is
+# not the programs (1,042 at 128 slots, three spans each) but the ``jit``s
+# traced INSIDE a step's trace, some 370 a layer: 9,925 events at GPT-2
+# 355M's 24 layers, 11,613 in the Jamba cell, 17,995 at GPT-2 XL's 48 with
+# remat (chip runs, PR 53: 16,384 wrapped there). 65,536 events are some
+# 30 MB at the most.
+PROCESS_RING = 65536
+_PROCESS = []
+
+
+def process_recorder():
+    """The process's one ``SpanRecorder``, created on first use."""
+    if not _PROCESS:
+        _PROCESS.append(SpanRecorder(capacity=PROCESS_RING))
+    return _PROCESS[0]

@@ -113,6 +113,30 @@ KIND_UNKNOWN_TO_TEST_BUILDERS = (
     "test_a_builder_gives_what_the_drivers_and_readers_ask[sdar_moe]")
 
 
+# FOUR cases that pin a cell's EXACT set of per-layer metrics (PR 53), in
+# files this PR may not edit: each says the cell reports its family's readers
+# and the shared serving readers AND NOTHING ELSE, which was true until
+# ``setup_s`` got its first per-layer metrics. PR 53's eight ``setup_*`` rows
+# list all eleven cells by name (a row without a list is refused the day a PR
+# adds a cell), so the four sets are eight names short. Every line of the four
+# still holds on the manifest with those eight rows taken out, and
+# ``tests/benchmark/test_setup_phases.py::
+# test_a_pin_of_a_cells_exact_set_holds_beside_the_eight`` holds it so, by
+# CALLING the four functions themselves on that manifest: word for word, and
+# the eight rows by name beside them. The markers are STRICT: the day a
+# ``benchmark`` PR gives the four sets the eight names, the cases pass, the
+# markers turn that into failures, and these lines go.
+EXACT_SETS_BEFORE_THE_SETUP_ROWS = (
+    "tests/benchmark/test_deepseek_v3.py::"
+    "test_the_cell_is_one_chip_with_the_issues_traffic",
+    "tests/benchmark/test_kimi_linear.py::"
+    "test_the_cell_is_one_chip_with_dsv3s_traffic_unchanged",
+    "tests/benchmark/test_jamba.py::"
+    "test_the_lfm2_cell_stands_as_pr_44_left_it",
+    "tests/benchmark/test_sdar_moe.py::test_the_stand_in_is_the_cells",
+)
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
         if item.nodeid == KIND_UNKNOWN_TO_TEST_BUILDERS:
@@ -121,3 +145,10 @@ def pytest_collection_modifyitems(items):
                 reason="test_builders.METHODS has no kind serve_diffusion "
                 "(tests/conftest.py; tests/benchmark/test_sdar_moe.py holds "
                 "the builder to the table)"))
+        elif item.nodeid in EXACT_SETS_BEFORE_THE_SETUP_ROWS:
+            item.add_marker(pytest.mark.xfail(
+                raises=AssertionError, strict=True,
+                reason="pins the cell's exact per-layer set as it was before "
+                "PR 53's eight setup_* rows (tests/conftest.py; "
+                "tests/benchmark/test_setup_phases.py holds every line of it "
+                "beside the eight)"))
