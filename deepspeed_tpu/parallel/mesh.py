@@ -202,7 +202,8 @@ def _path_str(path):
                     for p in path)
 
 
-def zero_shardings(mesh: Mesh, params, stage: int, tp_rules=None):
+def zero_shardings(mesh: Mesh, params, stage: int, tp_rules=None,
+                   master_on_chips=True):
     """(param_sharding, grad_sharding, optstate_leaf_fn) for a ZeRO stage,
     composed with tensor parallelism when the mesh has a 'model' axis.
 
@@ -211,6 +212,14 @@ def zero_shardings(mesh: Mesh, params, stage: int, tp_rules=None):
     param policy for their stage). A leaf matching a TP rule carries 'model'
     on its rule dim in EVERY role; the ZeRO 'data' axis lands on the first
     other divisible dim.
+
+    ``params`` is the float32 MASTER, and from stage 1 on it lies as its
+    moments lie (the reference's ZeRO-1/2 update "the local fp32 partition",
+    stage1.py:246-265, stage2.py:1329-1491): the update is elementwise on
+    local shards, and whoever computes with the weights gathers their
+    compute-dtype cast. ``master_on_chips`` False (ZeRO-Offload: the master
+    is on the host and what is placed is the compute copy) keeps a stage
+    1-2 tree whole.
     """
     mp = mp_size(mesh)
     dp = dp_size(mesh)
@@ -234,7 +243,7 @@ def zero_shardings(mesh: Mesh, params, stage: int, tp_rules=None):
         return jax.tree_util.tree_map_with_path(
             lambda path, leaf: leaf_spec(path, leaf, with_data), tree)
 
-    param_sh = tree_spec(params, stage >= 3)
+    param_sh = tree_spec(params, stage >= (1 if master_on_chips else 3))
     grad_sh = tree_spec(params, stage >= 2)
 
     def opt_state_sharding(opt_state_template):

@@ -12,11 +12,15 @@ execution model is JAX-first:
   backward hooks / IPG bucket machinery (stage2.py:583-1060) vanish: gradient
   reduction is a GSPMD sharding constraint and XLA overlaps it with compute.
 - ZeRO stages are sharding policies over the 'data' mesh axis
-  (parallel/mesh.py:zero_shardings): stage 1 shards optimizer state, stage 2
-  reduce-scatters gradients (psum_scatter), stage 3 shards parameters. The
-  optimizer update runs on each rank's shard; params re-materialize via XLA
-  all-gather exactly like stage2.py:1444-1477's sharded allgather, but
-  compiler-scheduled.
+  (parallel/mesh.py:zero_shardings): stage 1 shards optimizer state AND the
+  float32 master it updates, stage 2 reduce-scatters gradients
+  (psum_scatter), stage 3 computes with sharded parameters. The optimizer
+  update runs on each rank's shard with no collective; what a program
+  computes with at stages 1-2 is the master's compute-dtype cast, gathered
+  once at the program's head (``_cast_to_compute``; the fused step's region
+  writes the all-gather itself, ``_dp_value_and_grad``): the reference's
+  sharded allgather of updated fp16 params (stage2.py:1444-1477), a step
+  later and with a backward to hide under.
 - Mixed precision: fp32 master params always; compute casts to bf16 (TPU
   default) or fp16 with full DynamicLossScaler semantics (overflow-skip,
   scale-window bookkeeping — reference fp16/fused_optimizer.py).
@@ -303,13 +307,23 @@ class DeepSpeedEngine(object):
                 lambda: flash_kernels.last_walk()["lane_pack"])
             # How the gradient leaves left the fused step last traced, where
             # its forward and backward run per chip (_dp_value_and_grad): by
-            # psum_scatter onto ZeRO-2's partition, by psum. Both 0 where GSPMD
-            # partitions the step (one chip, stage 3, a model / pipe / seq mesh).
-            self._zero_leaves = (0, 0)
+            # psum_scatter onto ZeRO-2's partition, by psum; and how many
+            # leaves of the sharded master's cast it gathered at its head.
+            # All 0 where GSPMD partitions the step (one chip, stage 3, a
+            # model / pipe / seq mesh).
+            self._zero_leaves = (0, 0, 0)
             self.telemetry.gauge("zero_scatter_leaves").set_fn(
                 lambda: self._zero_leaves[0])
             self.telemetry.gauge("zero_psum_leaves").set_fn(
                 lambda: self._zero_leaves[1])
+            self.telemetry.gauge("zero_gather_leaves").set_fn(
+                lambda: self._zero_leaves[2])
+            # Bytes of the master one chip holds over the whole master's
+            # (_setup_shardings): 1/dp at ZeRO 1-3, 1 at stage 0, on one
+            # chip and under ZeRO-Offload (the master is on the host).
+            self._master_shard_share = 1.0
+            self.telemetry.gauge("zero_master_shard_share").set_fn(
+                lambda: self._master_shard_share)
             # Perf X-ray (telemetry/xray.py): train_batch's fused path
             # stashes each compiled step program's shape signature here
             # (microseconds; no compile). perf_xray() / the flops profiler
@@ -358,6 +372,7 @@ class DeepSpeedEngine(object):
             # ZeRO sharding policy (applied when params exist).
             self._shardings_ready = False
             self._grad_constraint = None
+            self._compute_sharding = None
             if self.params is not None:
                 self._setup_shardings()
 
@@ -685,13 +700,28 @@ class DeepSpeedEngine(object):
             return model.params
         return None
 
-    def _cast_to_compute(self, params):
-        if self.compute_dtype == jnp.float32:
-            return params
+    def _cast_to_compute(self, params, gather=True):
+        """The weights a program computes with: ``params`` in the compute
+        dtype and, where the master is sharded for the optimizer's sake
+        (ZeRO 1-2), WHOLE over 'data' again: the cast ends in a constraint to
+        the layout without 'data', so a program gathers the compute copy once
+        at its head and the master's split cannot propagate into a matmul.
+        Not inside a ``shard_map`` over 'data' (its ``in_specs`` made the
+        weights whole already), and not with ``gather`` False: the fused
+        step's region gathers the local casts itself."""
         dtype = self.compute_dtype
-        return jax.tree_util.tree_map(
-            lambda p: p.astype(dtype) if jnp.issubdtype(p.dtype, jnp.floating) else p,
-            params)
+        if dtype != jnp.float32:
+            params = jax.tree_util.tree_map(
+                lambda p: p.astype(dtype)
+                if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
+        if gather and self._compute_sharding is not None and \
+                mesh_lib.active_sp_axis(mesh_lib.DATA_AXIS) is None:
+            # Pinned on both sides: left to itself, sharding propagation
+            # makes the CONVERT whole and gathers the float32 master.
+            params = jax.lax.with_sharding_constraint(
+                jax.lax.with_sharding_constraint(params, self.param_sharding),
+                self._compute_sharding)
+        return params
 
     def _configure_optimizer(self, client_optimizer, model_parameters):
         if client_optimizer is not None:
@@ -828,10 +858,24 @@ class DeepSpeedEngine(object):
     def _setup_shardings(self):
         self._embed_paths_cache = None  # params (re)set: recompute lazily
         stage = self.zero_optimization_stage() if self.zero_optimization() else 0
+        tp_rules = getattr(self.module, "tp_rules", None)
+        on_chips = not self._offload_mode()
         self.param_sharding, self.grad_sharding, opt_fn = \
             mesh_lib.zero_shardings(
-                self.mesh, self.params, stage,
-                tp_rules=getattr(self.module, "tp_rules", None))
+                self.mesh, self.params, stage, tp_rules=tp_rules,
+                master_on_chips=on_chips)
+        # ZeRO 1-2 split the master for the update alone: the layout a
+        # program computes in is the master's without 'data'
+        # (_cast_to_compute). Stage 3 computes on the shards themselves.
+        self._compute_sharding = None
+        if on_chips and 1 <= stage <= 2 and mesh_lib.dp_size(self.mesh) > 1:
+            self._compute_sharding = mesh_lib.zero_shardings(
+                self.mesh, self.params, 0, tp_rules=tp_rules)[0]
+        leaves = jax.tree_util.tree_leaves(self.params)
+        held = sum(np.prod(sh.shard_shape(p.shape)) for p, sh in zip(
+            leaves, jax.tree_util.tree_leaves(self.param_sharding)))
+        self._master_shard_share = float(
+            held / max(sum(np.prod(p.shape) for p in leaves), 1))
         if self.opt_state is not None and not self._offload_mode():
             moment_sh = {
                 "step": mesh_lib.replicated(self.mesh),
@@ -2112,13 +2156,14 @@ class DeepSpeedEngine(object):
                        out_shardings=out_shardings)
 
     def _dp_region_specs(self, batch):
-        """The PartitionSpec of every gradient leaf (``zero_shardings``'
-        own, read off ``grad_sharding``) where the fused step's forward and
-        backward run PER CHIP, else None. They do when all that is split is
-        the batch: the mesh splits only 'data', the parameters are whole
-        on every chip (ZeRO 0-2) and every leaf of ``batch`` splits over
-        'data'. Any other mesh, stage 3, a batch leaf that stays whole and
-        one chip keep the program GSPMD partitions."""
+        """The PartitionSpecs of every parameter leaf and of every gradient
+        leaf (``zero_shardings``' own, read off ``param_sharding`` and
+        ``grad_sharding``) where the fused step's forward and backward run
+        PER CHIP, else None. They do when all that is split is the batch:
+        the mesh splits only 'data', a chip computes with the whole weights
+        (ZeRO 0-2) and every leaf of ``batch`` splits over 'data'. Any other
+        mesh, stage 3, a batch leaf that stays whole and one chip keep the
+        program GSPMD partitions."""
         dp = mesh_lib.dp_size(self.mesh)
         stage = self.zero_optimization_stage() \
             if self.zero_optimization() else 0
@@ -2128,7 +2173,8 @@ class DeepSpeedEngine(object):
                 any(mesh_lib.batch_partition_spec(x, dp) != rows
                     for x in leaves):
             return None
-        return jax.tree_util.tree_map(lambda sh: sh.spec, self.grad_sharding)
+        return tuple(jax.tree_util.tree_map(lambda sh: sh.spec, tree)
+                     for tree in (self.param_sharding, self.grad_sharding))
 
     def _dp_value_and_grad(self, loss_fn, specs, params, args, rng):
         """``(loss, grads)`` of ``loss_fn(params, args, rng)`` under data
@@ -2138,23 +2184,38 @@ class DeepSpeedEngine(object):
         reach into the model (GSPMD propagated the tied table's
         feature-split gradient into the LM head: a contraction-sharded
         head over every chip's rows and an all-reduce of the float32
-        logits a chunk). The loss leaves as the mean over chips (equal
-        rows a chip: the global mean, and what the reference's ranks
-        compute); a gradient leaf whose spec names 'data' leaves through
-        ``psum_scatter`` onto that dim (ZeRO-2, reference
-        stage2.py:675-738), any other through ``psum``, in the dtype of
-        ``params``: differentiate with respect to the CAST parameters and
-        the cotangents cross the wire in the compute dtype."""
+        logits a chunk). ``specs`` is ``_dp_region_specs``' pair. A
+        parameter leaf whose spec names 'data' (ZeRO 1-2: ``params`` is the
+        sharded master's cast) enters as the chip's shard and is made whole
+        by ``all_gather`` on that dim at the region's head, a leaf a
+        collective in the tree's order so that a later block's gather can
+        run under an earlier block's compute (the reference's sharded
+        allgather of updated fp16 params, stage2.py:1444-1477). The loss
+        leaves as the mean over chips (equal rows a chip: the global mean,
+        and what the reference's ranks compute); a gradient leaf whose spec
+        names 'data' leaves through ``psum_scatter`` onto that dim (ZeRO-2,
+        reference stage2.py:675-738), any other through ``psum``, in the
+        dtype of ``params``: differentiate with respect to the whole CAST
+        parameters and the cotangents cross the wire in the compute dtype."""
         axis = mesh_lib.DATA_AXIS
         dp = mesh_lib.dp_size(self.mesh)
         is_spec = lambda x: isinstance(x, mesh_lib.P)
-        dims = [list(spec).index(axis) if axis in spec else None
-                for spec in jax.tree_util.tree_leaves(specs,
-                                                      is_leaf=is_spec)]
+        param_specs, grad_specs = specs
+        gather_dims, dims = (
+            [list(spec).index(axis) if axis in spec else None
+             for spec in jax.tree_util.tree_leaves(tree, is_leaf=is_spec)]
+            for tree in specs)
         self._zero_leaves = (sum(d is not None for d in dims),
-                             sum(d is None for d in dims))
+                             sum(d is None for d in dims),
+                             sum(d is not None for d in gather_dims))
 
-        def spmd(params, largs, rng):
+        def spmd(shards, largs, rng):
+            with jax.named_scope("zero_gather"):
+                leaves, treedef = jax.tree_util.tree_flatten(shards)
+                params = jax.tree_util.tree_unflatten(treedef, [
+                    p if d is None else
+                    jax.lax.all_gather(p, axis, axis=d, tiled=True)
+                    for p, d in zip(leaves, gather_dims)])
             rng = jax.random.fold_in(rng, jax.lax.axis_index(axis))
             # 1/dp of the chip's mean loss: the cotangents are then the
             # global mean's from the start, and the sums below finish it.
@@ -2174,8 +2235,9 @@ class DeepSpeedEngine(object):
         rows = jax.tree_util.tree_map(
             lambda _: mesh_lib.P(axis), args)
         return jax.shard_map(
-            spmd, mesh=self.mesh, in_specs=(whole, rows, whole),
-            out_specs=(whole, specs), check_vma=False)(params, args, rng)
+            spmd, mesh=self.mesh, in_specs=(param_specs, rows, whole),
+            out_specs=(whole, grad_specs), check_vma=False)(
+            params, args, rng)
 
     def _build_fused_step(self):
         """The fused fwd+bwd+update program of ``train_batch``."""
@@ -2187,8 +2249,9 @@ class DeepSpeedEngine(object):
         mesh = self.mesh
 
         # Named for what it is: a trace's hlo_module reads
-        # jit_train_step. Its regions (jax.named_scope): the model's
-        # (embed, block/ln|attn|mlp, lm_head: models/gpt2.py),
+        # jit_train_step. Its regions (jax.named_scope): zero_gather (the
+        # sharded master's cast made whole, where the step writes it), the
+        # model's (embed, block/ln|attn|mlp, lm_head: models/gpt2.py),
         # zero_reduce (the gradients' collectives, where they are
         # written) and optimizer (gradient cast, clip, update).
         def train_step(params, opt_state, args, rng, lr, beta1, beta2):
@@ -2200,7 +2263,7 @@ class DeepSpeedEngine(object):
             # Decided by what this trace can see: mesh, stage, shapes.
             specs = self._dp_region_specs(args)
             if specs is None:
-                self._zero_leaves = (0, 0)
+                self._zero_leaves = (0, 0, 0)
                 loss, grads = jax.value_and_grad(
                     lambda p: loss_fn(cast(p), args, rng))(params)
                 if grad_constraint is not None:
@@ -2208,7 +2271,7 @@ class DeepSpeedEngine(object):
                         grads, grad_constraint)
             else:
                 loss, grads = self._dp_value_and_grad(
-                    loss_fn, specs, cast(params), args, rng)
+                    loss_fn, specs, cast(params, gather=False), args, rng)
             with jax.named_scope("optimizer"):
                 grads = jax.tree_util.tree_map(
                     lambda g: g.astype(jnp.float32), grads)
@@ -2306,7 +2369,9 @@ class DeepSpeedEngine(object):
         self.xray.note("fused_train_step[{}]".format(key),
                        tokens=self.train_batch_size(),
                        zero_scatter_leaves=self._zero_leaves[0],
-                       zero_psum_leaves=self._zero_leaves[1])
+                       zero_psum_leaves=self._zero_leaves[1],
+                       zero_gather_leaves=self._zero_leaves[2],
+                       zero_master_shard_share=self._master_shard_share)
         with self.tracer.timed("train/bookkeeping"):
             if self.lr_scheduler is not None:
                 self.lr_scheduler.step()

@@ -1198,14 +1198,18 @@ def test_zero2_step_keeps_the_partition_out_of_the_model(host, monkeypatch):
     (149.5 ms of exposed collectives a step at GPT-2 XL on the chip, PR
     46). Under the engine's data-parallel region the model holds no
     collective, every matrix leaf that splits by rows leaves through a
-    reduce-scatter, and the flash kernels are in the program. The engine
+    reduce-scatter, and the flash kernels are in the program. Since PR 54
+    the float32 master is sharded as its moments are and the region
+    gathers its bf16 cast at its head (``zero_gather``): the step holds no
+    float32 all-gather and nothing after the update. The engine
     is built on described devices, which hold no array: ``device_put`` is
     the identity while it places its state, and the step is lowered from
     shapes."""
     import deepspeed_tpu as deepspeed
     from deepspeed_tpu.parallel import mesh as mesh_lib
     from tests.unit.test_engine import (
-        SEQ, gradient_scatters, partition_leaks, tiny_gpt2, tiny_gpt2_params)
+        SEQ, gradient_scatters, hlo_collectives, optimizer_collectives,
+        parameter_gathers, partition_leaks, tiny_gpt2, tiny_gpt2_params)
 
     ids = jnp.zeros((8, SEQ), I32)
     mesh = mesh_lib.build_mesh(devices=host)
@@ -1245,8 +1249,28 @@ def test_zero2_step_keeps_the_partition_out_of_the_model(host, monkeypatch):
     assert len(gradient_scatters(text)) >= len(matrices)
     assert sorted(c.split(".")[0] for c in _kernel_calls(text)) == \
         ["flash_bwd_fused", "flash_fwd"]
-    assert engine._zero_leaves == (len(jax.tree_util.tree_leaves(
-        engine.params)), 0)
+    # The master is sharded and its bf16 CAST is what crosses the wire,
+    # under the region's zero_gather (the TPU's compiler merges the small
+    # leaves' gathers, so they number at most a leaf each): no float32
+    # all-gather anywhere (the parent's tail: the updated master whole),
+    # and no collective of the optimizer's, whose update is local.
+    n_leaves = len(jax.tree_util.tree_leaves(engine.params))
+    assert engine._zero_leaves == (n_leaves, 0, n_leaves)
+    gathers = [result for kind, result in hlo_collectives(text)
+               if kind == "all-gather"]
+    assert gathers and all(
+        set(re.findall(r"\b([a-z]+\d+)\[", result)) == {"bf16"}
+        for result in gathers)
+    matrices_gathered = parameter_gathers(text, engine.params)
+    assert matrices_gathered and all(
+        "zero_gather" in name for _, _, _, name in matrices_gathered)
+    assert optimizer_collectives(text) == []
+    # ... and in the schedule every gather stands before the backward.
+    entry = text[text.index("ENTRY "):].splitlines()
+    backward = next(i for i, line in enumerate(entry)
+                    if re.match(r"\s*%flash_bwd_fused", line))
+    assert [line for line in entry[:backward] if " all-gather" in line]
+    assert not [line for line in entry[backward:] if " all-gather" in line]
 
 
 # ------------------------------------------------------- the LM head's loop

@@ -176,8 +176,9 @@ def _leaf_shard_fraction(arr):
 def test_zero_gradient_and_state_partitioning(eight_devices):
     """ZeRO-2/3 must actually SHARD, not just document sharding: per-device
     gradient shards are 1/N-sized at stage>=2 (reference reduce-scatter
-    semantics, stage2.py:675-738), optimizer moments 1/N at stage>=1, params
-    1/N at stage 3. Verified via addressable_shards, not loss values."""
+    semantics, stage2.py:675-738), optimizer moments AND the float32 master
+    they update 1/N at stage>=1 (the reference's "local fp32 partition",
+    stage1.py:246-265). Verified via addressable_shards, not loss values."""
     n = len(eight_devices)
     for stage in [0, 1, 2, 3]:
         model = SimpleModel(hidden_dim=16)
@@ -195,8 +196,11 @@ def test_zero_gradient_and_state_partitioning(eight_devices):
             assert all(f == pytest.approx(1.0 / n) for f in grad_fracs), \
                 "stage {}: grads not 1/{} per device: {}".format(
                     stage, n, grad_fracs)
-        else:
+        elif stage == 0:
             assert all(f == pytest.approx(1.0) for f in grad_fracs)
+        # (stage 1 promises nothing of forward()'s gradients: whole at the
+        # parent, and since the master is sharded GSPMD may hand the update
+        # the shard it needs.)
 
         engine.step()
         if stage >= 1:
@@ -205,10 +209,10 @@ def test_zero_gradient_and_state_partitioning(eight_devices):
             assert all(f == pytest.approx(1.0 / n) for f in m_fracs)
         p_fracs = [_leaf_shard_fraction(g)
                    for g in jax.tree_util.tree_leaves(engine.params)]
-        if stage >= 3:
-            assert all(f == pytest.approx(1.0 / n) for f in p_fracs)
-        else:
-            assert all(f == pytest.approx(1.0) for f in p_fracs)
+        assert all(f == pytest.approx(1.0 / n if stage else 1.0)
+                   for f in p_fracs), (stage, p_fracs)
+        assert engine.telemetry.snapshot()["zero_master_shard_share"] == \
+            pytest.approx(1.0 / n if stage else 1.0)
 
 
 def test_zero2_fused_train_batch_grads_sharded(eight_devices):
@@ -310,19 +314,44 @@ def partition_leaks(text, vocab=VOCAB, width=WIDTH):
     return leaks
 
 
+def optimizer_collectives(text):
+    """The lines of optimised HLO ``text`` that hold a collective under the
+    step's ``optimizer`` region: none, where its update is local."""
+    import re
+    return [line for line in text.splitlines()
+            if re.search(r" (all-gather|all-reduce|reduce-scatter|"
+                         r"collective-permute)(-start)?\(", line)
+            and "/optimizer/" in line]
+
+
 def gradient_scatters(text):
     return [c for c in hlo_collectives(text)
             if c[0] in ("reduce-scatter", "all-reduce-scatter")]
 
 
-def test_zero2_step_keeps_the_partition_out_of_the_model(eight_devices):
-    """ZeRO-2 on 4 devices, compiled: GSPMD propagated the tied table's
-    feature-split gradient into the LM head (``f32[2048,1001]
-    all-reduce(%dot)`` a chunk and three ``all-to-all``s on this very
-    model, on the CPU's partitioner as on the TPU's:
-    test_chip_compile.py holds the same for a described v5e:2x2); under
-    the region the model sees no collective at all."""
+def parameter_gathers(text, params):
+    """(dtype, dims, operand, op_name) of every all-gather in optimised
+    HLO ``text`` whose result has the shape of a leaf of ``params``."""
+    import re
+    shapes = {tuple(p.shape) for p in jax.tree_util.tree_leaves(params)}
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.-]+ = (\w+)\[([\d,]*)\]\S* "
+                     r"all-gather(?:-start)?\(%([\w.-]+)", line)
+        if m and tuple(int(d) for d in m.group(2).split(",") if d) in shapes:
+            name = re.search(r'op_name="([^"]*)"', line)
+            found.append(m.groups() + (name.group(1) if name else "",))
+    return found
+
+
+@pytest.fixture(scope="module")
+def zero2_step():
+    """(engine, optimised HLO of its fused step, the lowered module):
+    ZeRO-2 over 4 devices, bf16 compute, the tiny GPT-2."""
     import jax.numpy as jnp
+    eight_devices = jax.devices()
+    if len(eight_devices) < 8:
+        pytest.skip("needs 8 virtual devices")
     ids = np.random.RandomState(0).randint(0, VOCAB, size=(8, SEQ))
     engine, _, _, _ = deepspeed.initialize(
         model=tiny_gpt2(jnp.bfloat16, use_flash_attention=False),
@@ -330,11 +359,22 @@ def test_zero2_step_keeps_the_partition_out_of_the_model(eight_devices):
         mesh=mesh_lib.build_mesh(devices=eight_devices[:4]),
         config_params=base_config(bf16={"enabled": True},
                                   zero_optimization={"stage": 2}))
-    text = engine._build_fused_step().lower(
+    lowered = engine._build_fused_step().lower(
         engine.params, engine.opt_state,
         mesh_lib.shard_batch(engine.mesh, (jnp.asarray(ids),) * 2),
         jax.random.PRNGKey(0), jnp.float32(1e-2), jnp.float32(0.9),
-        jnp.float32(0.999)).compile().as_text()
+        jnp.float32(0.999))
+    return engine, lowered.compile().as_text(), lowered.as_text()
+
+
+def test_zero2_step_keeps_the_partition_out_of_the_model(zero2_step):
+    """ZeRO-2 on 4 devices, compiled: GSPMD propagated the tied table's
+    feature-split gradient into the LM head (``f32[2048,1001]
+    all-reduce(%dot)`` a chunk and three ``all-to-all``s on this very
+    model, on the CPU's partitioner as on the TPU's:
+    test_chip_compile.py holds the same for a described v5e:2x2); under
+    the region the model sees no collective at all."""
+    _, text, _ = zero2_step
     assert partition_leaks(text) == []
     # The detector is not blind: a logits-shaped all-reduce and an
     # all-to-all as the parent's program holds them.
@@ -344,6 +384,41 @@ def test_zero2_step_keeps_the_partition_out_of_the_model(eight_devices):
         "  %g = bf16[1001,256]{1,0} all-reduce(%dw), channel_id=3\n") == [
         ("all-reduce", "f32[2048,1001]{1,0}"),
         ("all-to-all", "bf16[4,2,256,64]{3,2,1,0}")]
+
+
+def test_zero2_step_gathers_the_cast_at_its_head(zero2_step):
+    """The float32 master is SHARDED (ZeRO's partition is the master's, the
+    reference's stage1.py:246-265) and what the step gathers is its bf16
+    cast, written by the region under ``zero_gather``, a leaf a gather: in
+    the LOWERED module every ``all_gather`` is of bf16 (the CPU's compiler
+    widens a bf16 collective to float32 around the wire; the TPU's keeps
+    it: test_chip_compile.py), in the compiled one every all-gather of a
+    parameter-shaped array gathers a CONVERT's result under
+    ``zero_gather``, and no collective belongs to the optimizer, whose
+    update is local (the parent gathered the UPDATED float32 master whole
+    at the step's tail: 50 ms of GPT-2 XL's dp4 step, nothing to hide it)."""
+    import re
+    engine, text, lowered = zero2_step
+    leaves = jax.tree_util.tree_leaves(engine.params)
+    assert all(_leaf_shard_fraction(p) == 0.25 for p in leaves)
+    written = re.findall(r'"stablehlo\.all_gather"\(.*', lowered)
+    assert len(written) == len(leaves) == engine._zero_leaves[2]
+    assert all(re.search(r"-> tensor<[\dx]*xbf16>", line)
+               for line in written)
+    gathers = parameter_gathers(text, engine.params)
+    assert len(gathers) == len(leaves)
+    assert all(operand.startswith("convert") and "zero_gather" in name
+               for _, _, operand, name in gathers)
+    assert optimizer_collectives(text) == []
+    # The detector is not blind: the parent's tail gather.
+    assert parameter_gathers(
+        '  %ag = f32[1001,256]{1,0} all-gather(%add.7), dimensions={1}, '
+        'metadata={op_name="jit(train_step)/optimizer/add"}\n',
+        engine.params) == [
+        ("f32", "1001,256", "add.7", "jit(train_step)/optimizer/add")]
+    assert len(optimizer_collectives(
+        '  %ag = f32[1001,256]{1,0} all-gather(%add.7), dimensions={1}, '
+        'metadata={op_name="jit(train_step)/optimizer/add"}\n')) == 1
 
 
 def _dp_config(stage):
@@ -413,10 +488,14 @@ def test_dp_region_matches_one_device(kind, n, stage, eight_devices,
     n_leaves = len(jax.tree_util.tree_leaves(params))
     gauges = engine.telemetry.snapshot()
     want = (n_leaves, 0) if stage == 2 else (0, n_leaves)
-    assert (gauges["zero_scatter_leaves"],
-            gauges["zero_psum_leaves"]) == want
+    want += (n_leaves if stage else 0,)
+    names = ("zero_scatter_leaves", "zero_psum_leaves", "zero_gather_leaves")
+    assert tuple(gauges[k] for k in names) == want
     (row,) = engine.perf_xray()["programs"]
-    assert (row["zero_scatter_leaves"], row["zero_psum_leaves"]) == want
+    assert tuple(row[k] for k in names) == want
+    share = 1.0 / n if stage else 1.0
+    assert gauges["zero_master_shard_share"] == pytest.approx(share)
+    assert row["zero_master_shard_share"] == pytest.approx(share)
 
 
 @pytest.mark.parametrize("n, stage, mp", [(1, 0, 1), (8, 3, 1), (8, 2, 2)])
@@ -429,8 +508,8 @@ def test_dp_region_not_entered(n, stage, mp, eight_devices,
     np.testing.assert_allclose(losses, one_device_runs("simple")[0],
                                rtol=0, atol=1e-5)
     gauges = engine.telemetry.snapshot()
-    assert (gauges["zero_scatter_leaves"], gauges["zero_psum_leaves"]) == \
-        (0, 0)
+    assert (gauges["zero_scatter_leaves"], gauges["zero_psum_leaves"],
+            gauges["zero_gather_leaves"]) == (0, 0, 0)
 
 
 def test_dp_region_drops_out_by_chip_and_by_seed(eight_devices):
@@ -472,6 +551,108 @@ def test_dp_region_drops_out_by_chip_and_by_seed(eight_devices):
     # One row a chip, kept entries 2: a mask shared by all four chips
     # would leave only 0 and 2.
     assert set(np.unique(np.asarray(grads["w"]))) - {0.0, 2.0}
+
+
+@pytest.mark.parametrize("stage", [1, 2])
+def test_three_call_path_matches_fused_on_a_sharded_master(
+        stage, eight_devices, one_device_runs):
+    """``forward`` / ``backward`` / ``step`` on the sharded master: the
+    parameters after two steps are the fused path's (float32), and the
+    compiled forward-and-backward gathers the WEIGHTS once and nothing
+    else: every all-gather is parameter-shaped, no all-to-all, no
+    logits-shaped all-reduce (``_cast_to_compute`` ends in a constraint to
+    the layout without 'data', so the master's split stays out of the
+    model under GSPMD too)."""
+    import jax.numpy as jnp
+    fused, _, want = _dp_run("simple", eight_devices[:4], stage)
+    engine, _, _, _ = deepspeed.initialize(
+        model=SimpleModel(hidden_dim=16), config_params=_dp_config(stage),
+        mesh=mesh_lib.build_mesh(devices=eight_devices[:4]))
+    engine.compute_dtype = jnp.float32
+    for x, y in _dp_batches("simple"):
+        engine.backward(engine(x, y))
+        engine.step()
+    leaves = jax.tree_util.tree_leaves(engine.params)
+    assert all(_leaf_shard_fraction(p) == 0.25 for p in leaves)
+    for got, ref in zip(leaves, jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(got), ref, rtol=0, atol=1e-5)
+
+    ids = np.random.RandomState(0).randint(0, VOCAB, size=(8, 64))
+    gpt2, _, _, _ = deepspeed.initialize(
+        model=tiny_gpt2(jnp.bfloat16, use_flash_attention=False),
+        model_parameters=tiny_gpt2_params(jnp.bfloat16),
+        mesh=mesh_lib.build_mesh(devices=eight_devices[:4]),
+        config_params=base_config(bf16={"enabled": True},
+                                  zero_optimization={"stage": stage}))
+    inputs = mesh_lib.shard_batch(gpt2.mesh, (jnp.asarray(ids),) * 2)
+    text = gpt2._get_fwd_bwd(2, {}, (), True).lower(
+        gpt2.params, inputs, {}, jax.random.PRNGKey(0),
+        jnp.float32(1.0)).compile().as_text()
+    assert partition_leaks(text) == []
+    gathers = parameter_gathers(text, gpt2.params)
+    assert len(gathers) == len(jax.tree_util.tree_leaves(gpt2.params)) == \
+        len([c for c in hlo_collectives(text) if c[0] == "all-gather"])
+    # ... of the CAST (the CPU's compiler widens it again for the wire).
+    assert all(operand.startswith("convert") for _, _, operand, _ in gathers)
+
+
+def test_zero2_checkpoint_of_a_sharded_master_loads_at_another_dp(
+        tmp_path, eight_devices, one_device_runs):
+    """A ZeRO-2 checkpoint written from the sharded master at dp 4 holds
+    the WHOLE master (host copies, the parent's file) and loads at dp 2:
+    parameters and moments bit for bit, the parameters one device's, and
+    the next step on both meshes agrees."""
+    import jax.numpy as jnp
+    engine, _, params = _dp_run("simple", eight_devices[:4], 2)
+    engine.save_checkpoint(str(tmp_path), tag="dp4")
+    for got, want in zip(jax.tree_util.tree_leaves(params),
+                         jax.tree_util.tree_leaves(
+                             one_device_runs("simple")[1])):
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-5)
+
+    other, _, _, _ = deepspeed.initialize(
+        model=SimpleModel(hidden_dim=16), config_params=_dp_config(2),
+        mesh=mesh_lib.build_mesh(devices=eight_devices[:2]))
+    other.compute_dtype = jnp.float32
+    x, y = random_batch()
+    other(x, y)  # materialize shapes before loading over them
+    path, _ = other.load_checkpoint(str(tmp_path))
+    assert path is not None and other.global_steps == 3
+    for tree, saved in ((other.params, engine.params),
+                        (other.opt_state, engine.opt_state)):
+        for a, b in zip(jax.tree_util.tree_leaves(other._to_host(tree)),
+                        jax.tree_util.tree_leaves(engine._to_host(saved))):
+            np.testing.assert_array_equal(a, b)
+    assert all(_leaf_shard_fraction(p) == 0.5
+               for p in jax.tree_util.tree_leaves(other.params))
+    batch = random_batch(seed=7)
+    assert float(other.train_batch(batch=batch)) == pytest.approx(
+        float(engine.train_batch(batch=batch)), abs=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(other.params),
+                    jax.tree_util.tree_leaves(engine.params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=1e-5)
+
+
+@pytest.mark.parametrize("zero, share", [
+    ({"stage": 0}, 1.0), ({"stage": 3}, 1.0 / 8),
+    ({"stage": 2, "cpu_offload": True}, 1.0)])
+def test_master_layout_unchanged_where_zero_does_not_split_it(
+        zero, share, eight_devices):
+    """Stage 0 keeps the master whole, stage 3 computes on its shards as
+    before, and under ZeRO-Offload the master is on the HOST: what
+    ``param_sharding`` places is the compute copy, whole on every chip."""
+    engine, _, _, _ = deepspeed.initialize(
+        model=SimpleModel(hidden_dim=16),
+        config_params=base_config(bf16={"enabled": True},
+                                  zero_optimization=zero))
+    x, y = random_batch()
+    engine(x, y)
+    assert all(_leaf_shard_fraction(p) == pytest.approx(share)
+               for p in jax.tree_util.tree_leaves(engine.params))
+    assert engine._compute_sharding is None
+    assert engine.telemetry.snapshot()["zero_master_shard_share"] == \
+        pytest.approx(share)
 
 
 def test_a_new_steps_trace_gets_a_megabyte_frame_and_no_collector():
@@ -542,7 +723,10 @@ def test_an_engine_given_no_parameters_initialises_them_as_the_eager_init(
         _EAGER_INITS[drawn] = make().init(
             {"params": key_params, "dropout": key_dropout}, *inputs)["params"]
     want = _EAGER_INITS[drawn]
-    shardings, _, _ = mesh_lib.zero_shardings(engine.mesh, want, 2)
+    # (the master lies as its moments from stage 1 on; the host tier's
+    # device copy stays whole)
+    shardings, _, _ = mesh_lib.zero_shardings(engine.mesh, want, 2,
+                                              master_on_chips=not offload)
 
     if entry == "forward":
         engine(*batch)
