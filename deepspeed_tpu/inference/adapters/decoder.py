@@ -67,6 +67,21 @@ prefill / decode roles (a slot's open block has no snapshot form); it holds
 ``kv_page_len`` and ``prefill_chunk`` to whole blocks and ``denoising_steps``
 to a divisor of the block.
 
+WINDOW LAYERS BESIDE FULL ONES (``layer_types`` with ``swa``; the
+decoder-hybrid-decoder stack of ``models/decoder.py``, whose ``xattn`` layers
+read the ONE full plane and whose ``gmu`` layers read a value of the pass):
+``cache_spec()`` names the window group (``window``, ``window_layers``), the
+pool gives every slot a fixed ring of pages a window layer (``kv_pool.py``, A
+WINDOW GROUP) and ``cache_gauges`` says what that costs: ``kv_window_tokens``,
+``kv_window_pages_slot``, ``kv_window_bytes``, and of the stack
+``kv_shared_readers`` (the layers that attend the most-read full plane: its
+writer and the cross layers after it) and ``gmu_layers``. ``bind`` refuses by
+the kind's name what has no ring form: speculation (a verify's rejected keys
+would have evicted pages a rollback needs), the prefix cache (a prefix is
+shared as pages of the full group's table, and a ring is a slot's own), int8
+planes, and host offload and the prefill / decode roles (the hierarchy's
+records walk the full group's pages and ship no ring).
+
 BOTH AT ONCE (Kimi Linear: a latent plane as deep as its MLA layers only,
 beside a KDA state a slot) gets both sets of refusals, each by its own
 mechanism's name: the latent plane's first (int8, prefix cache), then the
@@ -108,6 +123,11 @@ class DecoderAdapter(GPT2Adapter):
     def latent(self):
         """Does a token cache one latent plane in place of keys and values?"""
         return bool(self.gcfg.kv_lora_rank)
+
+    @property
+    def windowed(self):
+        """Do some layers keep a window of keys on a ring of pages a slot?"""
+        return bool(self.gcfg.window_layers)
 
     @property
     def block_length(self):
@@ -196,6 +216,33 @@ class DecoderAdapter(GPT2Adapter):
                         "(kv_lora_rank {}, one plane of {} values a "
                         "token): {}".format(what, self.gcfg.kv_lora_rank,
                                             self.gcfg.latent_width, why))
+        if config is not None and self.windowed:
+            refused = (
+                ("speculative decoding (spec_decode)",
+                 config.resolved_spec_decode(),
+                 "a verify appends drafted keys to the ring, and the ones it "
+                 "rejects have already evicted pages a rollback would read"),
+                ("the prefix cache (prefix_cache)", config.prefix_cache,
+                 "a prefix is shared as pages of the full group's table, and "
+                 "a ring of pages is a slot's own"),
+                ("int8 planes (int8_kv)", config.int8_kv,
+                 "the ring has no scale planes and a pool quantised in part "
+                 "is not built"),
+                ("host offload (host_offload)", config.host_offload,
+                 "the tiers' records walk the full group's pages and ship no "
+                 "ring"),
+                ("the prefill and decode roles (role)",
+                 config.role != "mixed",
+                 "a handoff's record ships the full group's pages and no "
+                 "ring"))
+            for what, asked, why in refused:
+                if asked:
+                    raise ValueError(
+                        "{} cannot serve a model with window layers ({} swa "
+                        "layers, a ring of pages a slot for the last {} "
+                        "positions): {}".format(
+                            what, len(self.gcfg.window_layers),
+                            self.gcfg.sliding_window, why))
         if config is not None and self.recurrent:
             refused = (
                 ("speculative decoding (spec_decode)",
@@ -266,6 +313,28 @@ class DecoderAdapter(GPT2Adapter):
         pos0 = cache["pos"]
         logits, cache = decoder.forward(params, self.gcfg, ids, cache)
         return logits, dict(cache, pos=pos0)
+
+    def cache_gauges(self, pool):
+        """What the pool holds BESIDE the full group's pages, as gauges the
+        engine sets once a pool and repeats in ``metrics()`` (module
+        docstring, WINDOW LAYERS BESIDE FULL ONES); empty for a model with
+        neither window layers nor gated memory units."""
+        from deepspeed_tpu.inference.kv_pool import window_pages_slot
+
+        c, out = self.gcfg, {}
+        if self.windowed:
+            out.update(
+                kv_window_tokens=c.sliding_window,
+                kv_window_pages_slot=window_pages_slot(pool),
+                kv_window_bytes=int(pool["wk"].nbytes + pool["wv"].nbytes))
+        if "xattn" in c.kinds:
+            planes = [c.kv_plane(i) for i, k in enumerate(c.kinds)
+                      if k in ("attention", "xattn")]
+            out["kv_shared_readers"] = max(planes.count(p)
+                                           for p in set(planes))
+        if "gmu" in c.kinds:
+            out["gmu_layers"] = c.kinds.count("gmu")
+        return out
 
     def observe(self, snap, registry):
         load = snap.get("aux_moe_load")

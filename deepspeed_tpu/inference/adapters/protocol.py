@@ -67,7 +67,7 @@ class ModelAdapter:
     Required surface: ``cache_spec`` / ``init_cache`` / ``prefill_append``
     / ``decode_step`` / ``verify_forward`` (plus the drafting pair for
     speculative decode). Optional hooks (``bind``, ``aux_state``,
-    ``observe``) have inert defaults.
+    ``cache_gauges``, ``observe``) have inert defaults.
     """
 
     name = "adapter"
@@ -164,6 +164,13 @@ class ModelAdapter:
         every program (e.g. DecoderAdapter's per-expert routed counts).
         NOT per-slot:
         hierarchy capture/restore skips these keys."""
+        return {}
+
+    def cache_gauges(self, pool):
+        """Static facts of what ``pool`` holds for this model beside the
+        k/v pages, as ``{gauge name: number}``: the engine sets them once a
+        pool and repeats them in ``metrics()`` (DecoderAdapter: a window
+        group's ring, the readers of a shared plane)."""
         return {}
 
     def observe(self, snap, registry):

@@ -1060,6 +1060,8 @@ class InferenceEngine(object):
                              hier=self._hier.spec if self._hier else None)
         self.telemetry.gauge("kda_update_unit_heads").set(
             self._kda_unit_heads(pool))
+        for name, value in self._adapter.cache_gauges(pool).items():
+            self.telemetry.gauge(name).set(value)
         if getattr(self._gcfg, "latent", 0):
             # A latent cache (kv_pool.py): bytes ONE token holds over all
             # layers, read back from the one plane's shape.
@@ -2890,6 +2892,7 @@ class InferenceEngine(object):
             "kv_hbm_bytes": pool_nbytes(self._pool),
             "kda_update_unit_heads": self._kda_unit_heads(),
         }
+        m.update(self._adapter.cache_gauges(self._pool))
         if self._pager is not None:
             pg = self._pager
             m.update({

@@ -166,6 +166,26 @@ the planes the pool HAS and every ``slot_*`` key, and the hierarchy's
 records walk the pool's keys, so a captured slot ships its latent pages and
 its state together. Such a model gets both sets of refusals.
 
+A WINDOW GROUP. A model whose ``cache_spec()`` gives ``window_layers`` > 0
+(layers that see the last ``window`` positions only: ``models/decoder.py``
+``swa``) holds a SECOND pair of planes, ``wk`` / ``wv``, as deep as those
+layers and as wide as the full group's. A paged pool stores them as A FIXED
+RING OF PAGES A SLOT, ``[Lw, 1 + slots * n_ring, H / g, page_len, g * D]``
+(page 0 the trash page; slot ``s`` owns pages ``1 + s * n_ring ..``;
+``n_ring = decode_attention.ring_pages(window, page_len, slack)``: 5 pages of
+128 at a window of 512 for a decode step, 6 with a lane slice of 128), so a
+window layer costs a slot the same memory at any context and ``max_len``
+does not enter its shape. It is fixed memory a slot, as ``slot_state`` is:
+the page allocator, admission and the block table know the full group only.
+No table of it is stored: the views make each row's ``ring_tbl`` from its slot
+index (``decode_attention.ring_table``; a freed row, told by its full-group
+table as everywhere, points at the trash page) and ``CacheAttention`` does the
+ring arithmetic (logical page ``lp`` at place ``lp % n_ring``, a key's
+position rebuilt from ``lp``, stale entries masked by position). The dense
+slot pool keeps the planes whole, ``[Lw, slots, H, plane_len, D]``, the
+window a mask. The hierarchy's records do not ship a ring, so what needs one
+is refused by name (``adapters/decoder.py``).
+
 CRASH-ONLY: the pool is DISPOSABLE state (docs/RESILIENCE.md). The
 durable truth about every request lives host-side in the scheduler's
 records; on a fatal step error the engine throws the pool away and
@@ -279,6 +299,12 @@ def init_pool(gcfg, num_slots, max_len, dtype=None, slack=0, hier=None,
             sc_shape = (gcfg.n_layer, P, hp * g, page_len)
             pool["k_scale"] = jnp.zeros(sc_shape, jnp.float32)
             pool["v_scale"] = jnp.zeros(sc_shape, jnp.float32)
+        if getattr(gcfg, "window_layers", 0):
+            # a fixed ring a slot, page 0 the trash page
+            n_ring = decode_attention.ring_pages(gcfg.window, page_len,
+                                                 max(slack, 1))
+            pool.update(_window_group(
+                gcfg, (1 + num_slots * n_ring,) + kv_shape[2:], dtype, int8))
         for name, ft, fill in _SLOT_FIELDS:
             pool[name] = jnp.full((num_slots,), fill, ft)
         return _with_slot_state(pool, gcfg, num_slots)
@@ -293,6 +319,7 @@ def init_pool(gcfg, num_slots, max_len, dtype=None, slack=0, hier=None,
             "toks": jnp.zeros((num_slots, plane_len), jnp.int32)}
     if not latent:
         pool["v"] = jnp.zeros(kv_shape, kv_dtype)
+    pool.update(_window_group(gcfg, kv_shape[1:], dtype, int8))
     if int8:
         sc_shape = kv_shape[:-1]
         pool["k_scale"] = jnp.zeros(sc_shape, jnp.float32)
@@ -312,6 +339,18 @@ def init_pool(gcfg, num_slots, max_len, dtype=None, slack=0, hier=None,
     return _with_slot_state(pool, gcfg, num_slots)
 
 
+def _window_group(gcfg, layer_shape, dtype, int8):
+    """The zeroed ``wk`` / ``wv`` planes ``gcfg.window_layers`` names, each
+    layer ``layer_shape`` (module docstring, A WINDOW GROUP); empty for a
+    model without window layers."""
+    n = getattr(gcfg, "window_layers", 0)
+    if not n:
+        return {}
+    assert not int8, "a window group has no int8 form"
+    return {name: jnp.zeros((n,) + tuple(layer_shape), dtype)
+            for name in ("wk", "wv")}
+
+
 def _with_slot_state(pool, gcfg, num_slots):
     """``pool`` with the recurrent state ``gcfg.slot_state`` names, zeroed
     (module docstring, A RECURRENT STATE A SLOT)."""
@@ -326,12 +365,34 @@ def _slot_state(tree):
 
 
 # The planes a pool may hold, in the order the views name them: a latent
-# cache has no ``v``, only the int8 tier has scales.
-_PLANES = ("k", "v", "k_scale", "v_scale")
+# cache has no ``v``, only the int8 tier has scales, only a model with window
+# layers a window group.
+_PLANES = ("k", "v", "k_scale", "v_scale", "wk", "wv")
 
 
 def _planes(pool):
     return {name: pool[name] for name in _PLANES if name in pool}
+
+
+def window_pages_slot(pool):
+    """Pages of ONE window layer's ring a slot holds in a paged pool (0: no
+    window group, or a dense pool): read back from the arena's shape."""
+    if "wk" not in pool or "block_tbl" not in pool:
+        return 0
+    return (pool["wk"].shape[1] - 1) // pool["block_tbl"].shape[0]
+
+
+def _ring_view(pool, slots, tbl):
+    """``{"ring_tbl": [B, n_ring]}`` for the rows of ``slots`` whose
+    full-group table rows are ``tbl`` (module docstring, A WINDOW GROUP);
+    empty for a pool without one."""
+    from deepspeed_tpu.inference.paging import TRASH_PAGE
+
+    n_ring = window_pages_slot(pool)
+    if not n_ring:
+        return {}
+    return {"ring_tbl": decode_attention.ring_table(
+        slots.astype(jnp.int32), n_ring, tbl[:, 0] != TRASH_PAGE)}
 
 
 def slot_state_nbytes(gcfg):
@@ -420,6 +481,8 @@ def cache_view(pool):
     cache = dict(_planes(pool), pos=pool["pos"])
     if "block_tbl" in pool:
         cache["block_tbl"] = pool["block_tbl"]
+        cache.update(_ring_view(
+            pool, jnp.arange(pool["block_tbl"].shape[0]), pool["block_tbl"]))
     if "pid" in pool:
         row = jnp.clip(pool["pid"], 0, pool["pk"].shape[1] - 1)
         cache["pk"] = jnp.take(pool["pk"], row, axis=1)
@@ -460,6 +523,8 @@ def slot_cache_view(pool, slot, pos):
         cache = dict(_planes(pool), pos=pos,
                      block_tbl=jax.lax.dynamic_slice_in_dim(
                          pool["block_tbl"], slot, 1, axis=0))
+        cache.update(_ring_view(pool, jnp.asarray(slot)[None],
+                                cache["block_tbl"]))
         for name in pool:
             if name.startswith("aux_"):
                 cache[name] = pool[name]
@@ -559,7 +624,7 @@ def pool_shardings(mesh, pool):
     rep = NamedSharding(mesh, P())
     # Prefix planes share the k/v rank/layout, so the same head-sharded
     # spec applies; scale planes are small — replicate them.
-    return {name: (kv if name in ("k", "v", "pk", "pv") else rep)
+    return {name: (kv if name in ("k", "v", "pk", "pv", "wk", "wv") else rep)
             for name in pool}
 
 

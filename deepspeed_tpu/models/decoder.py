@@ -93,6 +93,29 @@ with ``pos`` left where it was (the adapter's ``block_pass``), and which
 positions are unmasked when is the serving engine's scan
 (``inference/engine.py`` ``_diffusion_chunk_program``).
 
+And a seventh time Phi-4-mini-flash's (``model_type`` ``phi4flash``: the
+"SambaY" DECODER-HYBRID-DECODER of arXiv:2507.06607), by four more kinds in
+``layer_types`` and four fields that are off by default. ``"swa"`` layers are
+WINDOW ATTENTION: a query at ``p`` sees keys ``p - sliding_window < j <= p``
+(``decode_attention.visible``, the one expression of both bounds), and their
+keys live in a SECOND GROUP of planes (``cache_spec``'s ``window_layers``: a
+fixed ring of pages a slot in a paged pool, ``kv_pool.py`` A WINDOW GROUP;
+``CacheAttention.windowed``) beside the full group, which here is ONE plane
+deep: the one ``"attention"`` layer writes it and every ``"xattn"`` layer (a
+CROSS layer: it projects queries only, ``wq`` / ``wo``, and appends nothing)
+READS it (``kv_plane``: the plane a layer reads is the last one written
+before it; YOCO's cross-decoder). ``"gmu"`` layers are GATED MEMORY UNITS,
+``out = (m * silu(h W_in)) W_out`` with ``m`` the same token's MEMORY: the
+scan output ``y`` of the last Mamba-1 layer before them (``memory_layer``;
+``mamba1.mixer(.., hand_y=True)``), a value that lives for one ``forward`` and
+is threaded down the stack beside the stream: no state, no cache, no pool
+array. The fields: ``sliding_window``; ``layer_norm`` (LayerNorm with a
+weight AND a bias, ``<name>_b`` beside every norm's weight, in place of
+RMSNorm); ``attn_bias`` (a bias on the attention projections, ``bqkv`` / ``bq``
+and ``bo``); ``mamba_inner_norms`` False (Mamba-1 without Jamba's three inner
+norms). The region of a trace takes the kind's word: ``swa``, ``attn``,
+``xattn``, ``gmu``.
+
 THE ABSORBED FORM IS THE ONE PATH of latent attention, for the lane and the
 scan alike. Per head ``[k_nope_h | v_h] = c_kv W_kvb,h``, so
 ``q_nope_h . k_nope_h(u) = (q_nope_h W_uk,h) . c_kv(u)`` and
@@ -265,6 +288,16 @@ class DecoderConfig(typing.NamedTuple):
     # still-masked position carries.
     block_length: int = 1
     mask_token_id: typing.Optional[int] = None
+    # The decoder-hybrid-decoder stack (module docstring), where
+    # ``layer_types`` has "swa" | "xattn" | "gmu": the positions a window
+    # layer sees, its own counted (0: the model has no window layer).
+    sliding_window: int = 0
+    # LayerNorm (weight and bias) in place of RMSNorm, everywhere a norm of
+    # the stream stands; a bias on the attention projections; Mamba-1 with
+    # (Jamba) or without (as published) the three inner RMSNorms.
+    layer_norm: bool = False
+    attn_bias: bool = False
+    mamba_inner_norms: bool = True
 
     @property
     def stream_dtype(self):
@@ -294,6 +327,27 @@ class DecoderConfig(typing.NamedTuple):
         """The layers that hold keys (or a latent), in order: layer
         ``kv_layers[a]`` is layer ``a`` of the cache's planes."""
         return tuple(i for i, k in enumerate(self.kinds) if k == "attention")
+
+    @property
+    def window_layers(self):
+        """The layers that hold a WINDOW of keys, in order: layer
+        ``window_layers[a]`` is layer ``a`` of the window group's planes."""
+        return tuple(i for i, k in enumerate(self.kinds) if k == "swa")
+
+    def kv_plane(self, i):
+        """The plane of the full group that layer ``i`` (``"attention"``,
+        which writes it, or ``"xattn"``, which only reads) attends: the last
+        one written at or before it."""
+        return sum(1 for k in self.kinds[:i + 1] if k == "attention") - 1
+
+    @property
+    def memory_layer(self):
+        """The Mamba-1 layer whose scan output is the gated memory units'
+        memory: the last one before the first ``"gmu"``; None without one."""
+        if "gmu" not in self.kinds:
+            return None
+        return max(i for i in self.mamba1_layers
+                   if i < self.kinds.index("gmu"))
 
     @property
     def mamba_layers(self):
@@ -377,6 +431,10 @@ class CacheSpec(typing.NamedTuple):
     kv_page_len: int
     slot_state: tuple = ()
     latent: int = 0
+    # the window group (``kv_pool.py``, A WINDOW GROUP): positions a window
+    # layer sees and how many such layers hold keys, as wide as the full group
+    window: int = 0
+    window_layers: int = 0
 
 
 def cache_spec(cfg):
@@ -387,7 +445,8 @@ def cache_spec(cfg):
                      cfg.kv_page_len,
                      tuple(state for kind in RECURRENT.values()
                            for state in kind.state_shapes(cfg)),
-                     cfg.kv_lora_rank)
+                     cfg.kv_lora_rank, cfg.sliding_window,
+                     len(cfg.window_layers))
 
 
 def served_config(cfg, use_flash_decode=None):
@@ -413,9 +472,19 @@ def init_params(key, cfg):
     def normal(k, shape):
         return cfg.initializer_range * jax.random.normal(k, shape, cfg.dtype)
 
-    def attention(k_qkv, k_out):
-        out = {"wqkv": normal(k_qkv, (c, q_w + 2 * kv_w)),
-               "wo": normal(k_out, (q_w, c))}
+    def attention(k_qkv, k_out, cross=False):
+        # a cross layer projects queries only (``wq``: it reads another
+        # layer's keys); biases where the configuration has them
+        if cross:
+            out = {"wq": normal(k_qkv, (c, q_w)),
+                   "wo": normal(k_out, (q_w, c))}
+        else:
+            out = {"wqkv": normal(k_qkv, (c, q_w + 2 * kv_w)),
+                   "wo": normal(k_out, (q_w, c))}
+        if cfg.attn_bias:
+            out["bq" if cross else "bqkv"] = jnp.zeros(
+                (q_w if cross else q_w + 2 * kv_w,), cfg.dtype)
+            out["bo"] = jnp.zeros((c,), cfg.dtype)
         if cfg.qk_norm:
             a_head = cfg.qk_norm == "head"
             out["q_norm"] = jnp.ones((cfg.head_dim if a_head else q_w,),
@@ -442,6 +511,9 @@ def init_params(key, cfg):
         ks = jax.random.split(k, 5)
         out = {"attn_norm": jnp.ones((c,), cfg.dtype),
                "ffn_norm": jnp.ones((c,), cfg.dtype)}
+        if cfg.layer_norm:
+            out.update(attn_norm_b=jnp.zeros((c,), cfg.dtype),
+                       ffn_norm_b=jnp.zeros((c,), cfg.dtype))
         if not cfg.dense_layers:
             out.update(experts(k))
         if cfg.layer_types is None and not cfg.kv_lora_rank:
@@ -470,6 +542,11 @@ def init_params(key, cfg):
         return {"w_gate_up": normal(k1, (c, 2 * cfg.dense_width)),
                 "w_down": normal(k2, (cfg.dense_width, c))}
 
+    def memory_unit(k):
+        k1, k2 = jax.random.split(k)
+        w = mamba1.width(cfg)
+        return {"w_in": normal(k1, (c, w)), "w_out": normal(k2, (w, c))}
+
     def stacked(make, salt, n):
         return jax.lax.map(make, jax.random.split(
             jax.random.fold_in(key, salt), n))
@@ -482,6 +559,16 @@ def init_params(key, cfg):
         params["attn"] = jax.lax.map(
             lambda k: attention(*jax.random.split(k)), jax.random.split(
                 jax.random.fold_in(key, 3), len(cfg.kv_layers)))
+    for kind, salt, cross in (("swa", 12, False), ("xattn", 13, True)):
+        if kind in cfg.kinds:
+            params[kind] = stacked(
+                lambda k, cross=cross: attention(*jax.random.split(k),
+                                                 cross=cross),
+                salt, cfg.kinds.count(kind))
+    if "gmu" in cfg.kinds:
+        params["gmu"] = stacked(memory_unit, 14, cfg.kinds.count("gmu"))
+    if cfg.layer_norm:
+        params["final_norm_b"] = jnp.zeros((c,), cfg.dtype)
     for kind, salt in (("mamba", 4), ("kda", 9), ("shortconv", 10),
                        ("mamba1", 11)):
         if kind in cfg.kinds:
@@ -504,6 +591,20 @@ def _rms32(x, scale, eps):
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return y * scale.astype(jnp.float32)
+
+
+def _norm32(x, tree, name, cfg):
+    """The stack's norm of the stream in float32, the caller casts: RMSNorm
+    by ``tree[name]``, or where ``cfg.layer_norm`` LayerNorm by the weight
+    ``tree[name]`` and the bias ``tree[name + "_b"]``."""
+    if not cfg.layer_norm:
+        return _rms32(x, tree[name], cfg.rms_norm_eps)
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
+                            + cfg.rms_norm_eps)
+    return y * tree[name].astype(jnp.float32) \
+        + tree[name + "_b"].astype(jnp.float32)
 
 
 def yarn_inv_freq(dim, theta, factor, original, beta_fast, beta_slow):
@@ -566,16 +667,47 @@ def _residual(cfg, x, branch):
     return x + branch.astype(x.dtype)
 
 
-def attention_mix(layer, cfg, h, i, rope, attend, planes):
+# The region word of each kind of attention layer (module docstring).
+ATTENTION_SCOPES = {"attention": "attn", "swa": "swa", "xattn": "xattn"}
+
+
+def _cross_mix(layer, cfg, h, i, attend, planes):
+    """What a CROSS layer adds to the stream: its own queries against plane
+    ``i`` of the full group as another layer of this pass wrote it; nothing
+    is appended. No rotary and no QK norm: the one family that has such a
+    layer has neither."""
+    b, s, c = h.shape
+    nh, hd, dt = cfg.n_head, cfg.head_dim, cfg.dtype
+    assert not (cfg.rope or cfg.qk_norm), "a cross layer's queries are plain"
+    with jax.named_scope("xattn"):
+        q = h @ layer["wq"].astype(dt)
+        if cfg.attn_bias:
+            q = q + layer["bq"].astype(dt)
+        q = q.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
+    y, planes = attend(i, q, None, None, planes, write=False, scope="xattn")
+    with jax.named_scope("xattn"):
+        y = y.transpose(0, 2, 1, 3).reshape(b, s, nh * hd) \
+            @ layer["wo"].astype(dt)
+        return (y + layer["bo"].astype(dt) if cfg.attn_bias else y), planes
+
+
+def attention_mix(layer, cfg, h, i, rope, attend, planes, kind="attention"):
     """What an attention layer ADDS to the stream, from the normed stream
     ``h`` [B, S, C] in ``cfg.dtype``: (y [B, S, C], the cache planes with
-    layer ``i`` OF THE CACHE written). ``rope`` None: no rotary."""
+    layer ``i`` OF THE CACHE written). ``rope`` None: no rotary. ``kind``:
+    ``"swa"`` writes and reads layer ``i`` of the WINDOW group (``planes`` are
+    then that group's), ``"xattn"`` reads plane ``i`` and writes nothing."""
+    if kind == "xattn":
+        return _cross_mix(layer, cfg, h, i, attend, planes)
     b, s, c = h.shape
     nh, nkv, hd, eps, dt = cfg.n_head, cfg.n_kv, cfg.head_dim, \
         cfg.rms_norm_eps, cfg.dtype
-    with jax.named_scope("attn"):
-        q, k, v = jnp.split(h @ layer["wqkv"].astype(dt),
-                            [nh * hd, (nh + nkv) * hd], axis=-1)
+    scope = ATTENTION_SCOPES[kind]
+    with jax.named_scope(scope):
+        qkv = h @ layer["wqkv"].astype(dt)
+        if cfg.attn_bias:
+            qkv = qkv + layer["bqkv"].astype(dt)
+        q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
         if cfg.qk_norm == "head":
             # over each head's lanes, one weight for all the heads; else
             # over the whole projected width, before the heads split
@@ -592,20 +724,48 @@ def attention_mix(layer, cfg, h, i, rope, attend, planes):
         q = q.astype(dt).transpose(0, 2, 1, 3)
         k = k.astype(dt).transpose(0, 2, 1, 3)
         v = v.reshape(b, s, nkv, hd).transpose(0, 2, 1, 3)
-    y, planes = attend(i, q, k, v, planes)
-    with jax.named_scope("attn"):
-        y = y.transpose(0, 2, 1, 3).reshape(b, s, nh * hd)
-        return y @ layer["wo"].astype(dt), planes
+    if kind == "swa":
+        y, planes = attend.windowed(i, q, k, v, planes, scope)
+    else:
+        y, planes = attend(i, q, k, v, planes)
+    with jax.named_scope(scope):
+        y = y.transpose(0, 2, 1, 3).reshape(b, s, nh * hd) \
+            @ layer["wo"].astype(dt)
+        return (y + layer["bo"].astype(dt) if cfg.attn_bias else y), planes
 
 
-def attention(layer, cfg, x, i, rope, attend, planes):
+def attention(layer, cfg, x, i, rope, attend, planes, kind="attention"):
     """An attention layer's mixer: ``x`` [B, S, C] -> (x with
     ``attention_mix`` of its norm added, the cache planes)."""
-    with jax.named_scope("attn"):
-        h = _rms32(x, layer["attn_norm"], cfg.rms_norm_eps).astype(cfg.dtype)
-    y, planes = attention_mix(layer, cfg, h, i, rope, attend, planes)
-    with jax.named_scope("attn"):
+    scope = ATTENTION_SCOPES[kind]
+    with jax.named_scope(scope):
+        h = _norm32(x, layer, "attn_norm", cfg).astype(cfg.dtype)
+    y, planes = attention_mix(layer, cfg, h, i, rope, attend, planes, kind)
+    with jax.named_scope(scope):
         return _residual(cfg, x, y), planes
+
+
+def gmu_mix(layer, cfg, h, memory):
+    """What a GATED MEMORY UNIT adds to the stream (module docstring), from
+    the normed stream ``h`` [B, S, C] in ``cfg.dtype`` and ``memory``
+    [B, S, W] float32, the same tokens' scan output of ``cfg.memory_layer``:
+    ``(memory * silu(h W_in)) W_out`` [B, S, C] float32. Both matmuls take
+    ``cfg.dtype`` and emit float32, as a Mamba mixer's gate and ``out_proj``
+    do: the gate meets the memory unrounded."""
+    dt = cfg.dtype
+    gate = jnp.matmul(h, layer["w_in"].astype(dt),
+                      preferred_element_type=jnp.float32)
+    return jnp.matmul((memory * jax.nn.silu(gate)).astype(dt),
+                      layer["w_out"].astype(dt),
+                      preferred_element_type=jnp.float32)
+
+
+def gmu(layer, cfg, x, memory):
+    """A gated memory unit's mixer: ``x`` [B, S, C] with ``gmu_mix`` of its
+    norm added (region ``gmu``)."""
+    with jax.named_scope("gmu"):
+        h = _norm32(x, layer, "attn_norm", cfg).astype(cfg.dtype)
+        return _residual(cfg, x, gmu_mix(layer, cfg, h, memory))
 
 
 def latent_token(layer, cfg, h, rope):
@@ -735,7 +895,7 @@ def dense_ffn(layer, cfg, x):
     """A leading dense layer's feed-forward: ``x`` with ``dense_mix`` of
     its norm added (region ``mlp``)."""
     with jax.named_scope("mlp"):
-        h = _rms32(x, layer["ffn_norm"], cfg.rms_norm_eps).astype(cfg.dtype)
+        h = _norm32(x, layer, "ffn_norm", cfg).astype(cfg.dtype)
         return _residual(cfg, x, dense_mix(layer, cfg, h))
 
 
@@ -768,7 +928,7 @@ def forward(params, cfg, ids, cache, attn_name=None):
         if cfg.embedding_multiplier != 1.0:
             x = x * cfg.embedding_multiplier
         x = x.astype(cfg.stream_dtype)
-    planes = attend.planes
+    planes, wplanes = attend.planes, attend.wplanes
     rope = rope_angles(attend.q_pos, cfg.qk_rope_dim or cfg.head_dim,
                        cfg.rope_theta, cfg.rope_yarn) if cfg.rope else None
     state = {}
@@ -779,20 +939,38 @@ def forward(params, cfg, ids, cache, attn_name=None):
     chosen = [] if "aux_moe_choice" in cache else None
     n_attn = 0
     n_recurrent = {kind: 0 for kind in RECURRENT}
+    n_own = {kind: 0 for kind in ("swa", "xattn", "gmu")}
+    memory = None       # the gated memory units': this pass's, no state
     for i, kind in enumerate(cfg.kinds):
         layer = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
         if kind in RECURRENT:
             j = n_recurrent[kind]
             mix = jax.tree_util.tree_map(lambda a: a[j], params[kind])
             with jax.named_scope(kind):
-                h = _rms32(x, layer["attn_norm"], cfg.rms_norm_eps).astype(dt)
+                h = _norm32(x, layer, "attn_norm", cfg).astype(dt)
                 keys = RECURRENT[kind].state_keys(j)
+                hands = {"hand_y": True} if i == cfg.memory_layer else {}
                 h, *after = RECURRENT[kind].mixer(
                     mix, cfg, h, *(cache[k] for k in keys), attend.pos,
-                    n_valid)
+                    n_valid, **hands)
+                if hands:
+                    memory = after.pop()
                 state.update(zip(keys, after))
                 x = _residual(cfg, x, h)
             n_recurrent[kind] += 1
+        elif kind in n_own:
+            j = n_own[kind]
+            layer = dict(layer, **jax.tree_util.tree_map(
+                lambda a: a[j], params[kind]))
+            if kind == "gmu":
+                x = gmu(layer, cfg, x, memory)
+            elif kind == "swa":
+                x, wplanes = attention(layer, cfg, x, j, rope, attend,
+                                       wplanes, kind)
+            else:
+                x, planes = attention(layer, cfg, x, cfg.kv_plane(i), rope,
+                                      attend, planes, kind)
+            n_own[kind] += 1
         else:
             tree = "mla" if cfg.kv_lora_rank else "attn"
             if tree in params:
@@ -811,14 +989,14 @@ def forward(params, cfg, ids, cache, attn_name=None):
         x, counts, away = moe(layer, cfg, x, chosen)
         load, absent = load + counts, absent + away
     with jax.named_scope("lm_head"):
-        x = _rms32(x, params["final_norm"], cfg.rms_norm_eps).astype(dt)
+        x = _norm32(x, params, "final_norm", cfg).astype(dt)
         head = params["embed"].T if cfg.tie_word_embeddings \
             else params["lm_head"]
         logits = jnp.dot(x, head.astype(dt),
                          preferred_element_type=jnp.float32)
         if cfg.logits_scaling != 1.0:
             logits = logits / cfg.logits_scaling
-    cache = attend.advanced(planes)
+    cache = attend.advanced(planes, wplanes)
     cache.update(state)
     if "aux_moe_load" in cache:
         cache["aux_moe_load"] = cache["aux_moe_load"] + load

@@ -113,6 +113,11 @@ def init_cache(cfg, batch, max_len, dtype=None):
              "pos": jnp.zeros((batch,), jnp.int32)}
     if not getattr(cfg, "latent", 0):   # latent: a token's values are lanes
         cache["v"] = jnp.zeros(shape, dtype)    # of its key, one plane
+    if getattr(cfg, "window_layers", 0):
+        # a window group, dense: planes as long as the full group's, the
+        # window a mask (``CacheAttention.windowed``)
+        w_shape = (cfg.window_layers,) + shape[1:]
+        cache["wk"], cache["wv"] = (jnp.zeros(w_shape, dtype) for _ in "kv")
     return cache
 
 
@@ -175,7 +180,21 @@ class CacheAttention(object):
     is written by the same ``kv_append`` (one arena) and read by the
     ``latent_decode`` kernel; everything else takes the scatter, the gather
     and two einsums. The int8 and prefix tiers have no latent form
-    (``DecoderAdapter.bind`` refuses them by name)."""
+    (``DecoderAdapter.bind`` refuses them by name).
+
+    A WINDOW GROUP (``cfg.sliding_window`` > 0 and ``wk`` / ``wv`` in the
+    cache: the decoder block's ``swa`` layers) is a SECOND pair of planes, as
+    deep as the window layers, with its own addressing: in a paged pool a
+    FIXED RING of ``n_ring`` pages a row (``cache['ring_tbl']`` [B, n_ring],
+    ``kv_pool.py``), written by ``kv_append_ring`` and read by
+    ``window_decode`` (``decode_attention.py``, A window layer's RING OF
+    PAGES), or through a scatter, ``ring_view``'s gather and the einsum where
+    a page is no kernel block; in a dense cache planes as long as the full
+    group's, the window a mask. ``windowed`` is such a layer's call; the
+    mask's lower bound is ``decode_attention.visible``'s wherever it is
+    formed. A layer that only READS a plane another layer wrote (the decoder
+    block's ``xattn``) calls with ``write=False`` and no ``k``, ``v``. The
+    int8 and prefix tiers have no ring form (``bind`` refuses them)."""
 
     def __init__(self, cfg, cache, S, attn_name=None):
         self.cfg, self.cache, self.S = cfg, cache, S
@@ -291,6 +310,17 @@ class CacheAttention(object):
         self.planes = (cache["k"],) if self.latent else \
             (cache["k"], cache["v"]) + (
                 (cache["k_scale"], cache["v_scale"]) if self.int8 else ())
+        # The window group (class docstring): its two planes, threaded
+        # beside ``planes``; None for a model without window layers.
+        self.window = getattr(cfg, "sliding_window", 0) if "wk" in cache \
+            else 0
+        self.wplanes = None
+        if self.window:
+            assert not (self.int8 or self.has_prefix or self.latent), \
+                "a window group has no int8, prefix or latent form"
+            self.wplanes = (cache["wk"], cache["wv"])
+            if paged:
+                self.ring_tbl = cache["ring_tbl"]          # [B, n_ring]
 
     def _pad_prefix(self, p):
         # [B, H, prefix_len, ...] -> [B, H, max_len, ...]; the pad is
@@ -301,7 +331,10 @@ class CacheAttention(object):
         pad[2] = (0, self.max_len - p.shape[2])
         return jnp.pad(p, pad)
 
-    def _write_rows(self, plane_l, new):
+    def _write_rows(self, plane_l, new, at=None):
+        """One layer's plane with ``new`` [B, H, S, D] written at the
+        frontiers; ``at``: the (page, offset) [B, S] of a paged write where
+        they are not the full group's table's (a ring's)."""
         if self.paged:
             # Page arena [P, H/g, page_len, g*D] <- [B, H, S, D], regrouped
             # as the arena stores heads and scattered at (page, offset)
@@ -310,8 +343,8 @@ class CacheAttention(object):
             # row outside the trash page), so the scatter is
             # collision-free wherever it is ever read.
             new = decode_attention.pack_heads(new, self.pack)
-            return plane_l.at[self.w_pg, :, self.w_off, :].set(
-                new.transpose(0, 2, 1, 3))
+            pg, off = at or (self.w_pg, self.w_off)
+            return plane_l.at[pg, :, off, :].set(new.transpose(0, 2, 1, 3))
         # [B, H, T, D] cache plane <- [B, H, S, D] at each row's frontier
         # (vmapped dynamic_update_slice lowers to one scatter).
         return jax.vmap(lambda c, n, p: jax.lax.dynamic_update_slice(
@@ -362,12 +395,17 @@ class CacheAttention(object):
             return jnp.einsum("bhqk,bkd->bhqd", att,
                               k_eff[..., :rank]), (cache,)
 
-    def __call__(self, i, q, k, v, planes):
+    def __call__(self, i, q, k, v, planes, write=True, scope="attn"):
         """Layer ``i``'s attention: write ``k, v`` at the frontiers, read
         the cache, attend. Returns (y [B, H, S, D], the planes with layer
-        ``i`` written)."""
+        ``i`` written). ``write`` False: a layer that READS plane ``i`` as
+        another layer of this pass left it and appends nothing (``k`` and
+        ``v`` None). ``scope``: the region word the attention runs under."""
         if self.latent:
             return self._latent(i, q, k, planes)
+        if not write:
+            assert not self.int8, "a read-only layer has no int8 form"
+            return self._attend(i, q, planes, scope), planes
         cfg, cache = self.cfg, self.cache
         int8, paged, use_flash = self.int8, self.paged, self.use_flash
         pos, hd = self.pos, self.hd
@@ -432,7 +470,7 @@ class CacheAttention(object):
                 if int8:
                     ks_eff, vs_eff = (jnp.repeat(a, self.rep, axis=1)
                                       for a in (ks_eff, vs_eff))
-        with jax.named_scope("attn"):
+        with jax.named_scope(scope):
             if use_flash:
                 # Fused QK-score + online softmax + PV over the cache plane,
                 # frontier-aware: blocks past pos[b]+S-1 are skipped. The
@@ -483,17 +521,104 @@ class CacheAttention(object):
             return y, (k_cache, v_cache, ks_cache, vs_cache)
         return y, (k_cache, v_cache)
 
-    def advanced(self, planes):
-        """The cache dict after the pass: the written planes, every
-        frontier moved by S. ``dict(cache, ...)``, NOT a fresh literal, so
-        hierarchy keys (scale planes, prefix views) and an adapter's
-        ``aux_`` state survive the decode scan's cache threading."""
+    def _einsum(self, q, k_eff, v_eff, mask, scope):
+        """The einsum path on one layer's effective planes [B, Hkv, T, D]
+        under ``mask`` [B, S, T], grouped-query heads repeated: what
+        ``__call__`` computes without the kernels."""
+        if self.rep > 1:
+            with jax.named_scope("kv_view"):
+                k_eff, v_eff = (jnp.repeat(a, self.rep, axis=1)
+                                for a in (k_eff, v_eff))
+        with jax.named_scope(scope):
+            att = jnp.einsum("bhqd,bhkd->bhqk", q, k_eff).astype(jnp.float32)
+            att = att / jnp.sqrt(self.hd) if self.scale is None \
+                else att * self.scale
+            att = jnp.where(mask[:, None], att, jnp.finfo(jnp.float32).min)
+            att = jax.nn.softmax(att, axis=-1).astype(self.cfg.dtype)
+            return jnp.einsum("bhqk,bhkd->bhqd", att, v_eff)
+
+    def _attend(self, i, q, planes, scope):
+        """Plane ``i`` of the full group read as it stands (a read-only
+        layer's half of ``__call__``): the paged kernel under the caller's
+        name, or the gather / the dense plane and the einsum."""
+        k_cache, v_cache = planes
+        if self.use_flash:
+            scale = self.scale or 1.0 / float(self.hd) ** 0.5
+            with jax.named_scope(scope):
+                if self.paged:
+                    return decode_attention.flash_decode_attention_paged(
+                        q, k_cache, v_cache, self.tbl, self.pos, scale=scale,
+                        name=self.attn_name, layer=i, block=self.block)
+                return decode_attention.flash_decode_attention(
+                    q, k_cache[i], v_cache[i], self.pos, scale=scale,
+                    name=self.attn_name)
+        with jax.named_scope("kv_view"):
+            k_eff, v_eff = ((self._gather_pages(a[i]) if self.paged else a[i])
+                            for a in (k_cache, v_cache))
+        return self._einsum(q, k_eff, v_eff, self.mask, scope)
+
+    def windowed(self, i, q, k, v, planes, scope="swa"):
+        """Layer ``i`` OF THE WINDOW GROUP (class docstring): write ``k, v``
+        [B, Hkv, S, D] at the frontiers, attend the last
+        ``cfg.sliding_window`` positions. Returns (y [B, H, S, D], the
+        window planes with layer ``i`` written)."""
+        wk, wv = planes
+        window, S = self.window, self.S
+        if not self.paged:
+            # a dense cache holds a window layer's plane whole; the window
+            # is the mask's lower bound (no dense kernel masks one)
+            with jax.named_scope("kv_write"):
+                wk = wk.at[i].set(self._write_rows(wk[i], k))
+                wv = wv.at[i].set(self._write_rows(wv[i], v))
+            mask = decode_attention.visible(
+                jnp.arange(wk.shape[3])[None, None, :],
+                self.q_pos[:, :, None], 1, window)
+            return self._einsum(q, wk[i], wv[i], mask, scope), (wk, wv)
+        page_len, ring = self.page_len, self.ring_tbl
+        if self.use_flash:
+            with jax.named_scope("kv_write"):
+                wk, wv = decode_attention.kv_append_ring(
+                    (wk, wv), (k, v), ring, self.pos, layer=i)
+            with jax.named_scope(scope):
+                return decode_attention.window_decode(
+                    q, wk, wv, ring, self.pos, window,
+                    scale=self.scale or 1.0 / float(self.hd) ** 0.5,
+                    name="window_prefill" if self.attn_name else None,
+                    layer=i), (wk, wv)
+        with jax.named_scope("kv_write"):
+            # the scatter, through the ring: position p at place
+            # ``p // page_len % n_ring``
+            w_pos = self.q_pos
+            at = (jnp.take_along_axis(
+                ring, (w_pos // page_len) % ring.shape[1], axis=1),
+                w_pos % page_len)
+            wk, wv = (a.at[i].set(self._write_rows(a[i], new, at))
+                      for a, new in ((wk, k), (wv, v)))
+        with jax.named_scope("kv_view"):
+            tbl, shifted = decode_attention.ring_view(ring, self.pos, window,
+                                                      page_len)
+            k_eff, v_eff = (decode_attention.gather_pages(
+                a[i], tbl, self.nkv, self.pack) for a in (wk, wv))
+            mask = decode_attention.visible(
+                jnp.arange(k_eff.shape[2])[None, None, :],
+                (shifted[:, None] + jnp.arange(S)[None])[:, :, None], 1,
+                window)
+        return self._einsum(q, k_eff, v_eff, mask, scope), (wk, wv)
+
+    def advanced(self, planes, wplanes=None):
+        """The cache dict after the pass: the written planes (``wplanes``:
+        the window group's, where there is one), every frontier moved by S.
+        ``dict(cache, ...)``, NOT a fresh literal, so hierarchy keys (scale
+        planes, prefix views) and an adapter's ``aux_`` state survive the
+        decode scan's cache threading."""
         if self.latent:
             return dict(self.cache, k=planes[0], pos=self.pos + self.S)
         out = dict(self.cache, k=planes[0], v=planes[1],
                    pos=self.pos + self.S)
         if self.int8:
             out["k_scale"], out["v_scale"] = planes[2], planes[3]
+        if wplanes is not None:
+            out["wk"], out["wv"] = wplanes
         return out
 
 

@@ -57,6 +57,14 @@ prompt does not depend on how the prompt was chunked AT ALL: every chunking
 runs the same token steps in the same order. What would beat a trip's 3 us is
 a kernel that keeps the state in VMEM over the slice (ROADMAP.md).
 
+TWO THINGS A FAMILY MAY TAKE AWAY OR ASK FOR, both off for Jamba:
+``cfg.mamba_inner_norms`` False is Mamba-1 as published, WITHOUT the three
+inner RMSNorms (the tree then has no ``dt_norm`` / ``b_norm`` / ``c_norm``);
+``mixer(.., hand_y=True)`` also returns ``y`` [B, S, W] float32, the scan's
+output with the ``D`` skip and BEFORE the gate: the MEMORY a decoder-hybrid-
+decoder stack's gated memory units read (``models/decoder.py`` ``gmu``), a
+value of one pass and no state.
+
 Regions of a trace (``jax.named_scope``): ``mamba1`` holding ``in_proj``,
 ``conv``, ``x_proj`` (with the three norms), ``dt_proj``, ``ssm`` (Mamba-2's
 word for the recurrence), ``gate`` and ``out_proj``.
@@ -115,7 +123,7 @@ def init_layer(key, cfg):
     step = jnp.exp(jax.random.uniform(ks[2], (w,), jnp.float32,
                                       jnp.log(0.001), jnp.log(0.1)))
     bound = 1.0 / k ** 0.5
-    return {
+    out = {
         "in_proj": normal(ks[0], (c, 2 * w)),
         "conv_w": jax.random.uniform(ks[1], (k, w), jnp.float32,
                                      -bound, bound).astype(dt),
@@ -133,6 +141,10 @@ def init_layer(key, cfg):
         "D": jnp.ones((w,), jnp.float32),
         "out_proj": normal(ks[4], (w, c)),
     }
+    if not getattr(cfg, "mamba_inner_norms", True):
+        for name in ("dt_norm", "b_norm", "c_norm"):
+            del out[name]
+    return out
 
 
 def _rms(x, weight, eps):
@@ -149,9 +161,12 @@ def selection(p, cfg, x):
     with jax.named_scope("x_proj"):
         dbc = jnp.dot(x.astype(cfg.dtype), p["x_proj"].astype(cfg.dtype),
                       preferred_element_type=jnp.float32)
-        d = _rms(dbc[..., :r], p["dt_norm"], eps)
-        bmat = _rms(dbc[..., r:r + n], p["b_norm"], eps)
-        cmat = _rms(dbc[..., r + n:], p["c_norm"], eps)
+        if getattr(cfg, "mamba_inner_norms", True):
+            d = _rms(dbc[..., :r], p["dt_norm"], eps)
+            bmat = _rms(dbc[..., r:r + n], p["b_norm"], eps)
+            cmat = _rms(dbc[..., r + n:], p["c_norm"], eps)
+        else:
+            d, bmat, cmat = dbc[..., :r], dbc[..., r:r + n], dbc[..., r + n:]
     with jax.named_scope("dt_proj"):
         dt = jax.nn.softplus(jnp.dot(
             d, p["dt_proj"].astype(jnp.float32), precision=_HIGHEST)
@@ -186,7 +201,7 @@ def scan(x, dt, a, bmat, cmat, state):
     return jnp.moveaxis(y, 0, 1), state
 
 
-def mixer(p, cfg, hid, ssm, tail, pos, n_valid):
+def mixer(p, cfg, hid, ssm, tail, pos, n_valid, hand_y=False):
     """The mixer of one Mamba-1 layer.
 
     ``p`` the layer's parameters, ``hid`` [B, S, C] the normed stream,
@@ -195,7 +210,9 @@ def mixer(p, cfg, hid, ssm, tail, pos, n_valid):
     frontiers before this call, ``n_valid`` [B] how many leading columns of
     each row are real (0: the row does not move). Returns (out [B, S, C]
     float32, ``out_proj``'s sums as the MXU forms them: the caller casts
-    them to its stream's type as it adds them; ssm, tail)."""
+    them to its stream's type as it adds them; ssm, tail), and with
+    ``hand_y`` the scan's output ``y`` [B, S, W] float32 after them (module
+    docstring)."""
     s = hid.shape[1]
     dt_ = cfg.dtype
     with jax.named_scope("in_proj"):
@@ -226,7 +243,8 @@ def mixer(p, cfg, hid, ssm, tail, pos, n_valid):
         ssm = state.astype(ssm.dtype)
         y = y + p["D"] * x
     with jax.named_scope("gate"):
-        y = (y * jax.nn.silu(z)).astype(dt_)
+        gated = (y * jax.nn.silu(z)).astype(dt_)
     with jax.named_scope("out_proj"):
-        return jnp.matmul(y, p["out_proj"].astype(dt_),
-                          preferred_element_type=jnp.float32), ssm, tail
+        out = jnp.matmul(gated, p["out_proj"].astype(dt_),
+                         preferred_element_type=jnp.float32)
+    return (out, ssm, tail, y) if hand_y else (out, ssm, tail)

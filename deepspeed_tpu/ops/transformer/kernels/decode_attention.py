@@ -249,8 +249,34 @@ def visible_upto(q_pos, block=1):
     return (_div(q_pos, block) + 1) * block - 1
 
 
+def visible_from(q_pos, window=None):
+    """The FIRST key position a query at absolute position ``q_pos`` sees
+    under a sliding window of ``window`` positions, the query's own counted:
+    ``q_pos - window + 1`` (negative near a sequence's start, where every key
+    that exists is at or past it). ``window`` None is no window: there is no
+    lower bound, ``visible`` forms the mask it always has and a model without
+    window layers traces what it always did. The one expression of the LOWER
+    bound wherever a mask is formed, as ``visible_upto`` is of the upper:
+    the einsum path, the references and BOTH ends of the paged body's
+    straddle mask. ``window`` is static."""
+    if window is None:
+        return None
+    return q_pos - (window - 1)
+
+
+def visible(k_pos, q_pos, block=1, window=None):
+    """Whether the key at ``k_pos`` is seen by the query at ``q_pos``
+    (broadcast): ``visible_from(q_pos, window) <= k_pos <=
+    visible_upto(q_pos, block)``; without a window the upper bound alone, the
+    comparison every mask was."""
+    seen = k_pos <= visible_upto(q_pos, block)
+    first = visible_from(q_pos, window)
+    return seen if first is None else seen & (k_pos >= first)
+
+
 @hot_path
-def decode_attention_reference(q, k, v, pos, scale=None, block=1):
+def decode_attention_reference(q, k, v, pos, scale=None, block=1,
+                               window=None):
     """q: [B, H, S, D] query rows, row b starting at global position
     ``pos[b]`` (its k/v already written at ``pos[b] .. pos[b]+S-1``);
     k, v: [B, H, T, D] cache planes; pos: [B] int32 frontiers.
@@ -262,8 +288,8 @@ def decode_attention_reference(q, k, v, pos, scale=None, block=1):
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     prec = _mxu_precision(q.dtype)
     q_pos = pos[:, None] + jnp.arange(S)[None]               # [B, S]
-    mask = jnp.arange(T)[None, None, :] <= \
-        visible_upto(q_pos, block)[:, :, None]                # [B, S, T]
+    mask = visible(jnp.arange(T)[None, None, :], q_pos[:, :, None], block,
+                   window)                                    # [B, S, T]
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32), precision=prec) * scale
     s = jnp.where(mask[:, None], s, jnp.finfo(jnp.float32).min)
@@ -839,7 +865,7 @@ def gather_pages(arena, block_tbl, h, g):
 
 @hot_path
 def decode_attention_paged_reference(q, k, v, block_tbl, pos, scale=None,
-                                     block=1):
+                                     block=1, window=None):
     """Paged ground truth: gather each row's pages into its dense
     logical plane, then the dense reference — the same math the engine's
     einsum (flag-off) path computes, so kernel-on and kernel-off paged
@@ -854,7 +880,8 @@ def decode_attention_paged_reference(q, k, v, block_tbl, pos, scale=None,
     k, v = (gather_pages(a, block_tbl, h // rep, g) for a in (k, v))
     if rep > 1:
         k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
-    return decode_attention_reference(q, k, v, pos, scale=scale, block=block)
+    return decode_attention_reference(q, k, v, pos, scale=scale, block=block,
+                                      window=window)
 
 
 @hot_path
@@ -1054,7 +1081,7 @@ def _paged_units(tbl, pos, s_len, page_len, k_pages=1):
 
 def _paged_kernel(rows_ref, us_ref, pages_ref, pos_ref, live_ref, q_ref,
                   *refs, s_len, q8, single_kv, pack, rep=1, latent=0,
-                  k_pages=1, block=1):
+                  k_pages=1, block=1, window=None):
     """One grid step = one unit of ``_paged_units``: ``k_pages`` consecutive
     pages of all the heads (of the group, where all do not fit) of one row,
     a block each (``refs`` holds each arena's ``k_pages`` blocks in turn),
@@ -1069,7 +1096,9 @@ def _paged_kernel(rows_ref, us_ref, pages_ref, pos_ref, live_ref, q_ref,
     is the keys and, in its first ``latent`` lanes, the values. ``block``:
     ``visible_upto``'s (1: causal); the rows of a call start at a block's
     first position and a block never straddles a page, so the live pages and
-    the interior ones are the causal rule's."""
+    the interior ones are the causal rule's. ``window``: ``visible_from``'s
+    (None: no lower bound); a unit that holds a key behind the LAST query
+    row's window straddles too, at its low end."""
     n_a = 1 if latent else 4 if q8 else 2
     k_refs, *more = (refs[a * k_pages:(a + 1) * k_pages] for a in range(n_a))
     v_refs, scale_refs = (None, ()) if latent else (more[0], more[1:])
@@ -1128,12 +1157,15 @@ def _paged_kernel(rows_ref, us_ref, pages_ref, pos_ref, live_ref, q_ref,
             q_pos = pos_b + row
             k_pos = j * page_len + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 2)
-            return jnp.where(k_pos <= visible_upto(q_pos, block), s, NEG_INF)
+            return jnp.where(visible(k_pos, q_pos, block, window), s, NEG_INF)
 
-        # Interior pages (every key visible to even the FIRST query row)
-        # skip the iota/compare/select pass.
-        s = jax.lax.cond((j + n) * page_len - 1 <= pos_b,
-                         lambda: s, straddling)
+        # Interior pages (every key visible to even the FIRST query row,
+        # and under a window to the LAST) skip the iota/compare/select pass.
+        interior = (j + n) * page_len - 1 <= pos_b
+        if window is not None:
+            interior &= j * page_len >= visible_from(pos_b + (s_len - 1),
+                                                     window)
+        s = jax.lax.cond(interior, lambda: s, straddling)
 
         def times_v(p, v):
             return jax.lax.dot_general(p.astype(v.dtype), v,
@@ -1215,7 +1247,7 @@ def _paged_kernel(rows_ref, us_ref, pages_ref, pos_ref, live_ref, q_ref,
 
 
 def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1,
-                  latent=0, block=1):
+                  latent=0, block=1, window=None):
     """The one launcher of the paged families: ``arenas`` is (k, v) or
     (k, v, k_scale, v_scale), whole or one layer's (``layer`` None), or the
     ONE arena of a latent cache (``latent`` > 0: its value width; ``q`` is
@@ -1287,7 +1319,8 @@ def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1,
         name,
         functools.partial(_paged_kernel, s_len=s, q8=len(arenas) == 4,
                           single_kv=single_kv, pack=pack, rep=rep,
-                          latent=latent, k_pages=k_pages, block=block),
+                          latent=latent, k_pages=k_pages, block=block,
+                          window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s_blk, d_out), q.dtype),
     )(*units, q, *(a for a in arenas for _ in range(k_pages)))
@@ -1298,9 +1331,10 @@ def _paged_launch(name, q, arenas, tbl, pos, scale, layer, pack=1, rep=1,
 
 
 def _flash_decode_paged_pallas(q, k, v, tbl, pos, scale,
-                               name=None, layer=None, pack=1, rep=1, block=1):
+                               name=None, layer=None, pack=1, rep=1, block=1,
+                               window=None):
     return _paged_launch(name or "paged_decode", q, (k, v), tbl, pos, scale,
-                         layer, pack, rep, block=block)
+                         layer, pack, rep, block=block, window=window)
 
 
 def _flash_decode_paged_q8_pallas(q, k, v, k_scale, v_scale, tbl, pos,
@@ -1341,7 +1375,7 @@ def _paged_on_shards(launch, q, arenas, block_tbl, pos, scale, name, layer,
 
 @hot_path
 def flash_decode_attention_paged(q, k, v, block_tbl, pos, scale=None,
-                                 name=None, layer=None, block=1):
+                                 name=None, layer=None, block=1, window=None):
     """Block-table flash decode over a page arena.
 
     Args:
@@ -1364,6 +1398,10 @@ def flash_decode_attention_paged(q, k, v, block_tbl, pos, scale=None,
       block: static; ``visible_upto``'s block of positions (1: causal). Past
         1 every ``pos`` is a block's first position, ``S`` and the page
         length whole blocks.
+      window: static; ``visible_from``'s window (None: none). The pages
+        before a row's window are still units of the call: a caller that
+        wants them skipped hands a table and frontiers that START at the
+        window's first page (``window_decode``).
 
     block_k is page_len by construction (kernel blocks == pages, all
     heads of a page a step), so there is no autotuned tile here;
@@ -1381,9 +1419,112 @@ def flash_decode_attention_paged(q, k, v, block_tbl, pos, scale=None,
         if layer is not None:
             k, v = k[layer], v[layer]
         return decode_attention_paged_reference(q, k, v, block_tbl, pos,
-                                                scale=scale, block=block)
+                                                scale=scale, block=block,
+                                                window=window)
     return _paged_on_shards(_flash_decode_paged_pallas, q, (k, v), block_tbl,
-                            pos, scale, name, layer, block=block)
+                            pos, scale, name, layer, block=block,
+                            window=window)
+
+
+# ---------------------------------------------------------------------------
+# A window layer's RING OF PAGES (kernel "window_decode"; the lane's call is
+# "window_prefill"). A layer whose queries see the last ``window`` positions
+# only keeps a FIXED ring of ``n_ring`` pages a slot, whatever the context:
+# logical page ``lp`` (positions ``lp * page_len ..``) lives at ring place
+# ``lp % n_ring``, physical page ``ring_tbl[b, lp % n_ring]`` of an arena laid
+# out like any paged arena (page 0 the trash page; a freed row's ring table is
+# all trash, so the work lists below skip it as they skip a freed row of the
+# full group). Nothing in the kernels' bodies knows a ring: both calls are
+# handed COORDINATES IN WHICH THE RING IS A PLAIN TABLE.
+#
+# - The append (``kv_append_ring``) takes the frontier modulo the ring's span
+#   and the ring's table with its first place repeated at the end, so a write
+#   that straddles the ring's last page lands its tail on place 0:
+#   ``kv_append`` as it stands, the same bytes at the same (page, offset).
+# - The read (``window_decode``) takes the table ROTATED to start at the first
+#   page that holds a key some query row of the call still sees (``ring_view``:
+#   ``first = max(pos - window + 1, 0) // page_len``) and the frontiers less
+#   ``first * page_len``. Both ends of the mask are differences of positions
+#   (``visible``), so the shift changes no score; the units of a row are its
+#   ``(pos + S - 1) // page_len - first + 1`` pages, never more than
+#   ``n_ring``, and no page wholly behind the window is brought in.
+#
+# STALE ENTRIES ARE MASKED BY POSITION, never trusted to be zero: a ring place
+# holds whatever was last written there (this request's page ``lp - n_ring``,
+# or another request's keys), and a key's position is REBUILT from the logical
+# page the walk is on, so a place not yet overwritten reads as positions past
+# the frontier (masked above) and the first page's keys behind the window are
+# masked below. ``ring_pages`` sizes the ring so that a call's write never
+# lands on a page its own read still needs.
+# ---------------------------------------------------------------------------
+
+def ring_pages(window, page_len, s_len=1):
+    """``n_ring``: pages a ring holds so that a call of up to ``s_len`` query
+    positions a row (1 in the decode scan; the lane's slice) finds every key
+    of ``[pos - window + 1, pos + s_len - 1]`` after its own write: a span
+    of ``window + s_len - 1`` positions that starts anywhere in a page
+    touches ``ceil((window + s_len - 2) / page_len) + 1`` pages (5 at a
+    window of 512 over pages of 128 for a decode step, 6 with a lane slice
+    of up to 128)."""
+    return -(-(window + s_len - 2) // page_len) + 1
+
+
+def ring_table(slots, n_ring, live):
+    """Each row's ring ``[B, n_ring]``: row b of slot ``slots[b]`` owns
+    physical pages ``1 + slots[b] * n_ring ..`` of the window arena; a row
+    that is not ``live`` (a freed slot: its full-group table starts on the
+    trash page) gets the trash page throughout."""
+    from deepspeed_tpu.inference.paging import TRASH_PAGE
+
+    tbl = 1 + slots[:, None] * n_ring + jnp.arange(n_ring, dtype=jnp.int32)
+    return jnp.where(live[:, None], tbl, TRASH_PAGE).astype(jnp.int32)
+
+
+def ring_view(ring_tbl, pos, window, page_len):
+    """(table, frontiers) in which a call that starts at ``pos`` reads its
+    ring as a plain paged row (the block comment above): the ring's places
+    in the order of the logical pages from the window's first, and ``pos``
+    counted from that page's first position."""
+    n_ring = ring_tbl.shape[1]
+    pos = pos.astype(jnp.int32)
+    first = _div(jnp.maximum(pos - (window - 1), 0), page_len)
+    places = _rem(first[:, None] + jnp.arange(n_ring, dtype=jnp.int32),
+                  n_ring)
+    return jnp.take_along_axis(ring_tbl, places, axis=1), \
+        pos - first * page_len
+
+
+@hot_path
+def window_decode(q, k, v, ring_tbl, pos, window, scale=None, name=None,
+                  layer=None):
+    """Sliding-window attention over a ring of pages: ``q`` [B, H, S, D] at
+    frontiers ``pos`` (their keys already appended), ``k, v`` the WINDOW
+    arenas whole with a static ``layer`` (or one layer's, ``layer`` None),
+    ``ring_tbl`` [B, n_ring]. The paged body on ``ring_view``'s coordinates
+    with the mask's lower bound, under a kernel name of its own (readers that
+    match ``paged_decode`` at the start multiply by a FULL context); pages
+    that are no kernel block take the gather and the reference."""
+    tbl, shifted = ring_view(ring_tbl, pos, window, k.shape[-2])
+    return flash_decode_attention_paged(
+        q, k, v, tbl, shifted, scale=scale, name=name or "window_decode",
+        layer=layer, window=window)
+
+
+@hot_path
+def kv_append_ring(arenas, new, ring_tbl, pos, layer):
+    """``kv_append`` into a ring: row b's ``S`` new positions land at ring
+    places ``(pos[b] + s) // page_len % n_ring``, wrapping (the block comment
+    above). ``arenas``: the window group's, whole."""
+    page_len = arenas[0].shape[3]
+    span = ring_tbl.shape[1] * page_len
+    closed = jnp.concatenate([ring_tbl, ring_tbl[:, :1]], axis=1)
+    pos = pos.astype(jnp.int32)
+    s = new[0].shape[2]
+    for lo in range(0, s, page_len):          # at most a page's rows a call
+        part = tuple(jax.lax.slice_in_dim(x, lo, min(lo + page_len, s),
+                                          axis=2) for x in new)
+        arenas = kv_append(arenas, part, closed, _rem(pos + lo, span), layer)
+    return arenas
 
 
 @hot_path

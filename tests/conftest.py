@@ -137,9 +137,43 @@ EXACT_SETS_BEFORE_THE_SETUP_ROWS = (
 )
 
 
+# TWELVE cases of ``tests/benchmark/test_setup_phases.py`` that pin what PR
+# 53's eight ``setup_*`` rows were the day they landed (PR 55), in a file this
+# PR may not edit: eight say each row lists EXACTLY the eleven cells of that
+# day (``test_the_manifest_row_by_name``), four that the eight rows are the
+# manifest's LAST per-layer entries
+# (``test_a_pin_of_a_cells_exact_set_holds_beside_the_eight``). Neither can
+# stay true: a cell that reports ``setup_s`` is appended to each row's list
+# (PR 55's ``serve-phi4flash-decode-closed``, as its issue names the lists),
+# and every later per-layer metric is appended after the eight (the contract's
+# rule). Every OTHER line of the twelve still holds and is held, word for word
+# and case for case, by ``tests/benchmark/test_phi4flash.py``
+# (``test_a_setup_row_stands_as_pr_53_left_it_and_lists_this_cell`` x 8,
+# ``test_a_pin_of_a_cells_exact_set_holds_beside_the_eight_and_the_five`` x 4,
+# the latter by CALLING the same four pin functions). The markers are STRICT:
+# the day a ``benchmark`` PR drops the two stale lines, the cases pass, the
+# markers turn that into failures, and these lines go.
+SETUP_ROWS_AS_PR_53_LEFT_THEM = tuple(
+    "tests/benchmark/test_setup_phases.py::test_the_manifest_row_by_name[{}]"
+    .format(name) for name in (
+        "setup_boot_s", "setup_engine_init_s", "setup_trace_s",
+        "setup_lower_s", "setup_compile_s", "setup_warm_s", "setup_programs",
+        "setup_cache_misses")) + tuple(
+    "tests/benchmark/test_setup_phases.py::"
+    "test_a_pin_of_a_cells_exact_set_holds_beside_the_eight[{}]".format(pin)
+    for pin in ("dsv3", "kimi", "lfm2", "sdar"))
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
-        if item.nodeid == KIND_UNKNOWN_TO_TEST_BUILDERS:
+        if item.nodeid in SETUP_ROWS_AS_PR_53_LEFT_THEM:
+            item.add_marker(pytest.mark.xfail(
+                raises=AssertionError, strict=True,
+                reason="pins PR 53's eight setup_* rows as listing eleven "
+                "cells and standing last (tests/conftest.py; "
+                "tests/benchmark/test_phi4flash.py holds every other line "
+                "of it)"))
+        elif item.nodeid == KIND_UNKNOWN_TO_TEST_BUILDERS:
             item.add_marker(pytest.mark.xfail(
                 raises=KeyError, strict=True,
                 reason="test_builders.METHODS has no kind serve_diffusion "
