@@ -266,6 +266,13 @@ class DecoderAdapter(GPT2Adapter):
                             if k in self.gcfg.kinds), why))
         return super().bind(config, mesh)
 
+    def serving_params(self, params):
+        # The protocol's default, NOT GPT-2's cast: these families' weights
+        # are made in ``cfg.dtype``, and a float32 leaf (Mamba's ``A_log``
+        # and ``dt`` path, a float32 state's parameters, a norm computed in
+        # float32) is float32 on purpose.
+        return params
+
     def init_cache(self, batch, max_len, dtype=None):
         return dict(decoder.init_cache(self.gcfg, batch, max_len),
                     **self.aux_state())

@@ -61,6 +61,9 @@ class GPT2Adapter(ModelAdapter):
             return self
         return dataclasses.replace(self, gcfg=gcfg)
 
+    def serving_params(self, params):
+        return generation.serving_params(params, self.gcfg)
+
     def init_cache(self, batch, max_len, dtype=None):
         return generation.init_cache(self.gcfg, batch, max_len, dtype)
 

@@ -66,8 +66,8 @@ class ModelAdapter:
 
     Required surface: ``cache_spec`` / ``init_cache`` / ``prefill_append``
     / ``decode_step`` / ``verify_forward`` (plus the drafting pair for
-    speculative decode). Optional hooks (``bind``, ``aux_state``,
-    ``cache_gauges``, ``observe``) have inert defaults.
+    speculative decode). Optional hooks (``bind``, ``serving_params``,
+    ``aux_state``, ``cache_gauges``, ``observe``) have inert defaults.
     """
 
     name = "adapter"
@@ -157,6 +157,18 @@ class ModelAdapter:
         a 'seq' axis). Must return an adapter — ``self`` when nothing
         changes."""
         return self
+
+    def serving_params(self, params):
+        """The tree as this adapter's step READS it, made once when the
+        engine is built: an adapter whose forward casts a leaf to its compute
+        type at every use returns the tree with that leaf already cast (a
+        float32 GPT-2 tree otherwise pays the whole cast once a step), and
+        leaves what the forward reads as it came. Idempotent, and never
+        donates: the caller keeps its tree. The default returns its argument,
+        the SAME object (DecoderAdapter: the catalog families' weights are
+        made in ``cfg.dtype``, and their float32 leaves are float32 on
+        purpose)."""
+        return params
 
     def aux_state(self):
         """Extra pool-resident model state: a dict of ``aux_``-prefixed

@@ -785,12 +785,25 @@ class InferenceEngine(object):
             self._tp = mesh is not None and mesh_lib.mp_size(mesh) > 1
             with process_recorder().timed("setup/pool"):
                 pool = self._build_pool()
-            if self._tp:
-                with process_recorder().timed("setup/params"):
+            # The weights as the step reads them (``ModelAdapter
+            # .serving_params``): what the adapter's forward would cast at
+            # every use is cast here, once. ``cast_bytes`` are the bytes, as
+            # they came, of the leaves that left the step's arguments.
+            with process_recorder().timed("setup/params") as placed:
+                given = jax.tree_util.tree_leaves(params)
+                params = self._adapter.serving_params(params)
+                cast = [a for a, b in zip(
+                    given, jax.tree_util.tree_leaves(params)) if a is not b]
+                cast_bytes = sum(int(a.nbytes) for a in cast)
+                placed.args.update(cast_leaves=len(cast),
+                                   cast_bytes=cast_bytes)
+                self.telemetry.gauge("params_cast_bytes").set(cast_bytes)
+                if self._tp:
                     param_sh, _, _ = mesh_lib.zero_shardings(
                         mesh, params, stage=0)
                     params = jax.tree_util.tree_map(
                         jax.device_put, params, param_sh)
+            if self._tp:
                 pool_out = pool_shardings(mesh, pool)
                 rep = mesh_lib.replicated(mesh)
                 mixed_out = (pool_out, rep, rep, rep, rep)
@@ -2890,6 +2903,10 @@ class InferenceEngine(object):
             # dense planes (the A/B default) and no page gauges follow.
             "paged_kv": self._pager is not None,
             "kv_hbm_bytes": pool_nbytes(self._pool),
+            # Bytes, as the caller gave them, of the weights the constructor
+            # cast to the type the step reads (0: the tree came as served).
+            "params_cast_bytes": int(
+                self.telemetry.gauge("params_cast_bytes").value),
             "kda_update_unit_heads": self._kda_unit_heads(),
         }
         m.update(self._adapter.cache_gauges(self._pool))

@@ -134,6 +134,43 @@ def _dense(x, p):
             p["bias"].astype(x.dtype))
 
 
+def _read_in_cfg_dtype(path):
+    """True for the leaves EVERY use in ``_forward`` casts to ``cfg.dtype``:
+    each ``Dense``'s ``kernel`` and ``bias`` (``_dense``) and ``wpe``. The
+    LayerNorms multiply in float32 (``_ln``) and the head reads ``wte`` as
+    float32, so those stay as they came."""
+    keys = [getattr(k, "key", None) for k in path]
+    return keys == ["wpe"] or (
+        len(keys) > 1 and keys[-2] in ("c_attn", "c_proj", "c_fc"))
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _cast_leaves(leaves, dtype):
+    return [x.astype(dtype) for x in leaves]
+
+
+def serving_params(params, cfg):
+    """``params`` as ``_forward`` READS it: the leaves whose every use casts
+    them to ``cfg.dtype`` held in ``cfg.dtype``, so that a step handed the
+    result converts no weight (a float32 tree otherwise pays the whole cast
+    once a step: XLA hoists the converts out of the decode scan, 2.1 GB of
+    traffic at 355M parameters). ``wte`` and the LayerNorms stay float32
+    (``_read_in_cfg_dtype``). ONE jitted program casts every such leaf, without
+    donation: the caller keeps its tree. A leaf already in ``cfg.dtype``
+    passes through as the same object, so a tree cast twice is the same
+    tree, and a tree with nothing to cast runs no program."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    dtype = jnp.dtype(cfg.dtype)
+    at = [i for i, (path, leaf) in enumerate(flat)
+          if _read_in_cfg_dtype(path) and leaf.dtype != dtype]
+    if not at:
+        return params
+    leaves = [leaf for _, leaf in flat]
+    for i, cast in zip(at, _cast_leaves([leaves[i] for i in at], dtype)):
+        leaves[i] = cast
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
 class CacheAttention(object):
     """The cache side of one forward pass over ``ids`` [B, S]: where each
     layer's new keys and values are written, what attention reads, and the
@@ -644,10 +681,11 @@ def _forward(params, cfg, ids, cache, last_only=False, attn_name=None):
     nh, hd = cfg.n_head, cfg.n_embd // cfg.n_head
     attend = CacheAttention(cfg, cache, S, attn_name)
     eps = cfg.layer_norm_epsilon
-    wte = params["wte"].astype(cfg.dtype)
-    pe = params["wpe"].astype(cfg.dtype)[attend.q_pos]  # [B, S, C] gather
+    # gather, THEN cast (bit for bit the cast table's rows): no whole-table
+    # convert that XLA would hoist out of the decode scan and run once a step
+    pe = params["wpe"][attend.q_pos].astype(cfg.dtype)     # [B, S, C]
     with jax.named_scope("embed"):
-        x = wte[ids] + pe
+        x = params["wte"][ids].astype(cfg.dtype) + pe
     planes = attend.planes
 
     for i in range(cfg.n_layer):

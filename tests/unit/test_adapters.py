@@ -173,6 +173,8 @@ def test_protocol_required_surface_raises_unimplemented():
     assert base.bind(None) is base
     assert base.aux_state() == {}
     assert base.observe(None, None) is None
+    tree = {"w": np.zeros(2, np.float32)}
+    assert base.serving_params(tree) is tree
 
 
 @pytest.mark.parametrize("kind", KINDS)
@@ -182,6 +184,26 @@ def test_adapter_is_hashable_static_arg(kind):
     assert adapter == type(adapter)(**{
         f.name: getattr(adapter, f.name)
         for f in __import__("dataclasses").fields(adapter)})
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_serving_params_keeps_the_structure_and_is_idempotent(kind):
+    """``serving_params``: the tree as the adapter's step reads it. The same
+    structure and shapes, every leaf in the type it came in or in the
+    adapter's compute type, and a second call changes nothing (the same
+    leaf objects)."""
+    adapter, params, _ = adapter_of(kind)
+    once = adapter.serving_params(params)
+    assert jax.tree_util.tree_structure(once) == \
+        jax.tree_util.tree_structure(params)
+    served = jnp.dtype(adapter.gcfg.dtype)
+    for given, held in zip(jax.tree_util.tree_leaves(params),
+                           jax.tree_util.tree_leaves(once)):
+        assert held.shape == given.shape
+        assert held.dtype in (given.dtype, served)
+    twice = adapter.serving_params(once)
+    assert all(a is b for a, b in zip(jax.tree_util.tree_leaves(once),
+                                      jax.tree_util.tree_leaves(twice)))
 
 
 # ------------------------------------------------- 1. chunk-vs-whole

@@ -744,8 +744,11 @@ def test_mixed_step_forms_no_layer_of_the_arena_in_the_decode_scan(
         kv_page_len=PAGE, prefill_chunk=lane, use_flash_decode=True))
     adapter = GPT2Adapter.from_model(model, use_flash_decode=True).bind(
         config, None)
-    params = jax.eval_shape(lambda: model.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), I32))["params"])
+    # flax's float32 tree as the engine holds it: the matrices in bf16
+    params = jax.eval_shape(lambda: adapter.serving_params(model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), I32))["params"]))
+    assert params["h_0"]["mlp"]["c_fc"]["kernel"].dtype == BF16
+    assert params["wte"].dtype == params["ln_f"]["scale"].dtype == F32
     pool = jax.eval_shape(lambda: kv_pool.init_pool(
         adapter.cache_spec(), SLOTS, T_KV, slack=lane, page_len=PAGE,
         num_pages=PAGES - 1))
@@ -753,6 +756,17 @@ def test_mixed_step_forms_no_layer_of_the_arena_in_the_decode_scan(
         (n_layer, PAGES, HEADS // 2, PAGE, 128)
 
     text = _mixed_step_text(chip, adapter, params, pool, chunk, lane)
+    # No matrix is converted inside the step, hoisted out of the scan or not
+    # (a float32 tree paid 3.7 ms of a 35 ms step for it until PR 56): no
+    # ``convert`` gives a bf16 array of a matrix's shape. (The TABLE still has
+    # one, the compiler's own: the head's float32 matmul runs as one bf16
+    # pass, and its operand's rounding is hoisted to ENTRY, once a step.)
+    matrices = {"{},{}".format(*leaf.shape)
+                for leaf in jax.tree_util.tree_leaves(params)
+                if leaf.ndim == 2 and leaf.dtype == BF16}
+    assert len(matrices) == 4       # wpe's shape is c_proj's
+    assert re.findall(r"= bf16\[({})\]\S* convert\(".format(
+        "|".join(sorted(matrices))), text) == []
 
     comps = _computations(text)
     scan, in_scan = _scan_lines(comps)

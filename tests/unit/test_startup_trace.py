@@ -289,9 +289,12 @@ def served():
 def test_an_inference_engine_leaves_its_phases_once_each(served):
     eng, events, after_first, _, _ = served
     phases = _phases(events)
-    assert phases == ["setup/pool", "setup/engine_init", "setup/first_step",
-                      "setup/ready", "engine/closed"]
-    assert after_first == phases[:4]  # ready is not said again by a step
+    assert phases == ["setup/pool", "setup/params", "setup/engine_init",
+                      "setup/first_step", "setup/ready", "engine/closed"]
+    assert after_first == phases[:5]  # ready is not said again by a step
+    # a float32 model served in float32: nothing for the constructor to cast
+    assert _named(events, "setup/params")[0]["args"] == {
+        "cast_leaves": 0, "cast_bytes": 0}
     init, first = (_named(events, n)[0] for n in (
         "setup/engine_init", "setup/first_step"))
     assert init["args"] == first["args"] == {"engine": "inference"}
@@ -380,8 +383,8 @@ def test_telemetry_off_still_records_at_process_scope():
     assert isinstance(eng.tracer, NullRecorder)
     assert eng.tracer.events() == [] and eng.tracer.span_counts() == {}
     assert _phases(_since(mark)) == [
-        "setup/pool", "setup/engine_init", "setup/first_step", "setup/ready",
-        "engine/closed"]
+        "setup/pool", "setup/params", "setup/engine_init", "setup/first_step",
+        "setup/ready", "engine/closed"]
     assert set(eng.metrics()["startup"]) == SUMMARY_KEYS
 
 
