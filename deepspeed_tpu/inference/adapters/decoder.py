@@ -15,11 +15,17 @@ stamps ``use_flash_decode`` and ``kv_page_len`` into the static config.
 Routing is exact top-k with no capacity, so nothing is ever dropped and a
 row's logits depend on that row alone (the protocol's replay invariant).
 Per-expert routed counts ride the pool's ``aux_`` channel and ``observe``
-publishes ``moe_experts_held``, ``moe_expert_load{expert=i}`` over the held
-experts, ``moe_tokens_routed`` and, for a chip's share of an expert-parallel
-layer, ``moe_tokens_absent`` (choices that fell on experts held elsewhere).
+publishes ``moe_experts_held``, ``moe_expert_layers`` (how many layers of the
+stack route: every one past the dense leading layers, or a ONE-BRANCH stack's
+``"moe"`` layers alone, 23 of Nemotron-H's 52, so that a reader and a person
+can tell how many calls a step holds), ``moe_expert_load{expert=i}`` over the
+held experts, ``moe_tokens_routed`` and, for a chip's share of an
+expert-parallel layer, ``moe_tokens_absent`` (choices that fell on experts
+held elsewhere), all summed over the layers that route and no others.
 They count every row the program computes (idle slots decode garbage by
 design), so they read as the program's load, not as requests' tokens. A
+stack that names a kind a layer (``layer_types``) also says how many layers
+of each kind it holds, ``stack_layers{kind=k}`` (23 / 23 / 6). A
 STACK WITHOUT EXPERTS (``expert_layers`` 0: Jamba2-3B, every feed-forward
 dense) gets no ``aux_moe_*`` channel in its pool and publishes NO ``moe_*``
 gauge: a gauge that read 0 experts held would be a wrong reading, not an
@@ -90,6 +96,7 @@ refuses still may not speculate).
 """
 
 import dataclasses
+import functools
 from typing import ClassVar
 
 import jax.numpy as jnp
@@ -98,6 +105,13 @@ from deepspeed_tpu.analysis.annotations import hot_path
 from deepspeed_tpu.inference.adapters.gpt2 import GPT2Adapter
 from deepspeed_tpu.inference.kv_pool import slot_state_nbytes
 from deepspeed_tpu.models import decoder
+
+
+@functools.lru_cache(maxsize=None)
+def _kind_counts(kinds):
+    """((kind, how many layers of it), ..) of a stack's ``kinds``: counted
+    once a configuration, ``observe`` runs every step."""
+    return tuple((kind, kinds.count(kind)) for kind in sorted(set(kinds)))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -348,6 +362,7 @@ class DecoderAdapter(GPT2Adapter):
         if load is not None:
             first, held = self.gcfg.held
             registry.gauge("moe_experts_held").set(held)
+            registry.gauge("moe_expert_layers").set(self.gcfg.expert_layers)
             for i, v in enumerate(load):
                 registry.gauge("moe_expert_load",
                                expert=str(first + i)).set(float(v))
@@ -356,6 +371,9 @@ class DecoderAdapter(GPT2Adapter):
             if "aux_moe_absent" in snap:
                 registry.gauge("moe_tokens_absent").set(
                     float(snap["aux_moe_absent"]))
+        if self.gcfg.layer_types is not None:
+            for kind, layers in _kind_counts(self.gcfg.kinds):
+                registry.gauge("stack_layers", kind=kind).set(layers)
         if self.recurrent:
             registry.gauge("ssm_state_bytes").set(
                 len(snap["pos"]) * slot_state_nbytes(self.cache_spec()))

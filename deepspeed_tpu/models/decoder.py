@@ -116,6 +116,22 @@ and ``bo``); ``mamba_inner_norms`` False (Mamba-1 without Jamba's three inner
 norms). The region of a trace takes the kind's word: ``swa``, ``attn``,
 ``xattn``, ``gmu``.
 
+And an eighth time Nemotron-H's (``model_type`` ``nemotron_h``:
+NVIDIA-Nemotron-3-Nano-30B-A3B), by a kind that is NO MIXER and three values.
+Every stack above gives a layer TWO branches, a mixer then a feed-forward;
+here a layer is ONE branch, ``x = x + f_i(norm_i(x))``, and ``layer_types``
+says which: a mixer kind (``"mamba"``, ``"attention"``) that takes NO
+feed-forward after it, or ``"moe"``, the expert feed-forward ALONE
+(``one_branch``: a stack is one as soon as it names a ``"moe"`` layer). Such a
+stack holds ONE norm a layer, and its expert stacks are as deep as its
+``"moe"`` layers only (``moe_layers``; ``expert_layers`` counts them: 23 of
+52, so the 29 layers without experts hold none). ``mamba_groups`` 8 gives the
+Mamba-2 mixer eight groups of ``B`` and ``C`` and a gated norm a group
+(``models/mamba2.py``); ``expert_act`` ``"relu2"`` makes an expert, and the
+shared one, the UNGATED ``down(relu(up(x)) ** 2)`` over ``w_up [E, C, F]``
+(``moe/routed.py``, TWO FORMS); its router is DeepSeek-V3's with one group, and
+its six attention layers are grouped-query without positions (32 over 2).
+
 THE ABSORBED FORM IS THE ONE PATH of latent attention, for the lane and the
 scan alike. Per head ``[k_nope_h | v_h] = c_kv W_kvb,h``, so
 ``q_nope_h . k_nope_h(u) = (q_nope_h W_uk,h) . c_kv(u)`` and
@@ -170,7 +186,13 @@ step, 0.9 GB of temporaries at DeepSeek-V3's widths; found by compiling for
 a described v5e); ``dense/w_gate_up [Ld, C, 2 Fd]``, ``w_down [Ld, Fd, C]``
 for the leading dense layers; ``moe/...`` [L - Ld, ..] what ``layers`` holds
 of an expert layer elsewhere, and ``router_bias [L - Ld, E]`` float32 (no
-``moe`` at all where ``Ld == L``).
+``moe`` at all where ``Ld == L``). A ONE-BRANCH stack keeps under ``layers``
+the one norm a layer, ``norm [L, C]``, and nothing else; its mixers by kind as
+above (``mamba/...`` [Lm, ..], ``attn/...`` [La, ..]) and ``moe/...`` [Le, ..]
+over its ``"moe"`` layers: ``router [Le, C, E]``, ``router_bias [Le, E]``,
+``w_up [Le, E_held, C, F]`` / ``w_down [Le, E_held, F, C]`` and ``shared_up
+[Le, C, Fs]`` / ``shared_down [Le, Fs, C]`` where ``expert_act`` is
+``"relu2"`` (``w_gate_up`` / ``shared_gate_up`` as above where it is gated).
 
 The regions of a trace (``jax.named_scope``, under the caller's
 ``prefill_lane`` / ``decode_scan``): ``embed``; per layer ``attn`` (norm, qkv,
@@ -181,7 +203,8 @@ or ``mamba`` (``mamba2.mixer``'s words), ``kda`` (``kda.mixer``'s),
 ``shortconv`` (``shortconv.mixer``'s) or ``mamba1`` (``mamba1.mixer``'s);
 ``moe`` holding ``router``,
 ``dispatch``, ``experts``, ``combine`` and ``shared``, or ``mlp`` for a dense
-layer; then ``lm_head``.
+layer; then ``lm_head``. A one-branch layer is ONE of these regions
+(``mamba``, ``attn`` or ``moe``), under the same words.
 
 The layers are unrolled (a static ``layer=`` in the cache kernels' index
 maps), not scanned: eight of them compile in well under GPT-2's 24, and a
@@ -237,7 +260,8 @@ class DecoderConfig(typing.NamedTuple):
     # (first, count) of the router's experts this chip holds; None: all
     experts_held: typing.Optional[typing.Tuple[int, int]] = None
     # "attention" | "mamba" | "kda" | "shortconv" | "mamba1" a layer; None:
-    # attention everywhere
+    # attention everywhere. "moe": the expert feed-forward ALONE, in a stack
+    # whose layers are ONE branch each (``one_branch``)
     layer_types: typing.Optional[typing.Tuple[str, ...]] = None
     mamba_heads: int = 0
     mamba_head_dim: int = 0
@@ -298,6 +322,20 @@ class DecoderConfig(typing.NamedTuple):
     layer_norm: bool = False
     attn_bias: bool = False
     mamba_inner_norms: bool = True
+    # Groups of Mamba-2 heads that share a ``B`` and a ``C``, and whose
+    # channels the gated norm runs over apart (``models/mamba2.py``).
+    mamba_groups: int = 1
+    # The step's ``H`` columns of a Mamba-2 layer a matrix of their own
+    # (``mamba/dt_proj`` [C, H]) beside ``in_proj`` [C, 2W + 2GN]: for a
+    # stack whose ``2W + 2GN + H`` columns are not whole 128-lane tiles
+    # (Nemotron-H: 10,304 = 80.5 x 128; as one matrix the TPU compiler
+    # copies the WHOLE stacked ``in_proj``, 1.19 GB, before every layer's
+    # matmul of the prefill lane: found by compiling the step for a
+    # described v5e). False: one matrix (Granite's 16,768 are whole tiles).
+    mamba_dt_apart: bool = False
+    # The form of an expert and of the shared one (``moe/routed.py``, TWO
+    # FORMS): "swiglu" (gated, ``w_gate_up``) | "relu2" (ungated, ``w_up``).
+    expert_act: str = "swiglu"
 
     @property
     def stream_dtype(self):
@@ -366,10 +404,27 @@ class DecoderConfig(typing.NamedTuple):
         return tuple(i for i, k in enumerate(self.kinds) if k == "mamba1")
 
     @property
+    def one_branch(self):
+        """Is a layer ONE branch (a mixer OR a feed-forward, one norm) and
+        not a mixer then a feed-forward? So as soon as ``layer_types`` names
+        a feed-forward as a layer of its own."""
+        return "moe" in self.kinds
+
+    @property
+    def moe_layers(self):
+        """The layers of a one-branch stack that ARE an expert feed-forward,
+        in order: layer ``moe_layers[e]`` is layer ``e`` of the expert
+        stacks."""
+        return tuple(i for i, k in enumerate(self.kinds) if k == "moe")
+
+    @property
     def expert_layers(self):
         """How many layers route to experts: every one past the leading
-        ``dense_layers``. 0 (``dense_layers == n_layer``) is a stack WITHOUT
+        ``dense_layers``, or a one-branch stack's ``moe_layers``. 0
+        (``dense_layers == n_layer``) is a stack WITHOUT
         experts: no ``moe`` tree, no router, no ``aux_moe_*``."""
+        if self.one_branch:
+            return len(self.moe_layers)
         return self.n_layer - self.dense_layers
 
     @property
@@ -494,20 +549,25 @@ def init_params(key, cfg):
         return out
 
     def experts(k):
-        # the feed-forward half of a layer that has experts
+        # the feed-forward half of a layer that has experts, in the form
+        # ``cfg.expert_act`` names: gate and up side by side, or up alone
         ks = jax.random.split(k, 5)
+        first, wide = routed.first_matrix(cfg.expert_act)
         out = {"router": normal(ks[2], (c, cfg.n_experts)),
-               "w_gate_up": normal(ks[3], (e, c, 2 * f)),
+               "w_" + first: normal(ks[3], (e, c, wide * f)),
                "w_down": normal(ks[4], (e, f, c))}
         if cfg.shared_width:
             k1, k2 = jax.random.split(jax.random.fold_in(k, 5))
-            out["shared_gate_up"] = normal(k1, (c, 2 * cfg.shared_width))
+            out["shared_" + first] = normal(
+                k1, (c, wide * cfg.shared_width))
             out["shared_down"] = normal(k2, (cfg.shared_width, c))
         if cfg.router_scoring == "sigmoid":
             out["router_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
         return out
 
     def layer(k):
+        if cfg.one_branch:      # one norm, and its branch by kind below
+            return {"norm": jnp.ones((c,), cfg.dtype)}
         ks = jax.random.split(k, 5)
         out = {"attn_norm": jnp.ones((c,), cfg.dtype),
                "ffn_norm": jnp.ones((c,), cfg.dtype)}
@@ -579,8 +639,8 @@ def init_params(key, cfg):
         params["mla"] = stacked(latent, 6, len(cfg.kv_layers))
     if cfg.dense_layers:
         params["dense"] = stacked(dense, 7, cfg.dense_layers)
-        if cfg.expert_layers:
-            params["moe"] = stacked(experts, 8, cfg.expert_layers)
+    if (cfg.dense_layers or cfg.one_branch) and cfg.expert_layers:
+        params["moe"] = stacked(experts, 8, cfg.expert_layers)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = normal(k_head, (c, cfg.vocab_size))
     return params
@@ -867,11 +927,13 @@ def moe(layer, cfg, x, chosen=None):
         if chosen is not None:
             chosen.append(experts.reshape(b, s, -1))
         gate, counts = routed.dispatch(weights, experts, held, first)
-        out = routed.expert_ffn(n32.astype(dt), gate, layer["w_gate_up"],
-                                layer["w_down"])
+        first, _ = routed.first_matrix(cfg.expert_act)
+        out = routed.expert_ffn(n32.astype(dt), gate, layer["w_" + first],
+                                layer["w_down"], cfg.expert_act)
         if cfg.shared_width:
             out = out + routed.shared_ffn(
-                n32.astype(dt), layer["shared_gate_up"], layer["shared_down"])
+                n32.astype(dt), layer["shared_" + first],
+                layer["shared_down"], cfg.expert_act)
         x = _residual(cfg, x, out.reshape(b, s, c))
     absent = b * s * cfg.experts_per_token - jnp.sum(counts)
     return x, counts, absent
@@ -937,12 +999,20 @@ def forward(params, cfg, ids, cache, attn_name=None):
     load = jnp.zeros((cfg.held[1],), jnp.float32)
     absent = jnp.zeros((), jnp.float32)
     chosen = [] if "aux_moe_choice" in cache else None
-    n_attn = 0
+    n_attn = n_moe = 0
     n_recurrent = {kind: 0 for kind in RECURRENT}
     n_own = {kind: 0 for kind in ("swa", "xattn", "gmu")}
     memory = None       # the gated memory units': this pass's, no state
     for i, kind in enumerate(cfg.kinds):
         layer = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
+        if cfg.one_branch:      # the one norm, under the name its branch reads
+            layer = {"attn_norm": layer["norm"], "ffn_norm": layer["norm"]}
+        if kind == "moe":
+            x, counts, away = moe(dict(layer, **jax.tree_util.tree_map(
+                lambda a: a[n_moe], params["moe"])), cfg, x, chosen)
+            load, absent = load + counts, absent + away
+            n_moe += 1
+            continue
         if kind in RECURRENT:
             j = n_recurrent[kind]
             mix = jax.tree_util.tree_map(lambda a: a[j], params[kind])
@@ -979,6 +1049,8 @@ def forward(params, cfg, ids, cache, attn_name=None):
             x, planes = (mla if cfg.kv_lora_rank else attention)(
                 layer, cfg, x, n_attn, rope, attend, planes)
             n_attn += 1
+        if cfg.one_branch:      # a mixer is the whole layer
+            continue
         if i < cfg.dense_layers:
             x = dense_ffn(dict(layer, **jax.tree_util.tree_map(
                 lambda a: a[i], params["dense"])), cfg, x)

@@ -14,9 +14,12 @@ emit the same stream).
 router). ``route_grouped`` is the DeepSeek-V3 router (``noaux_tc``): sigmoid
 scores, a selection bias, the choice limited to the best groups of experts.
 ``dispatch`` turns the choice into a gate over the experts THIS
-chip holds and counts their load; ``expert_ffn`` is the gated feed-forward
-of the chosen experts, ``sum_j w[t, j] * down_e(silu(gate_e(x_t)) *
-up_e(x_t))`` with ``e = experts[t, j]``.
+chip holds and counts their load; ``expert_ffn`` is the feed-forward of the
+chosen experts, ``sum_j w[t, j] * f_e(x_t)`` with ``e = experts[t, j]``, in
+one of TWO FORMS the configuration names (``act``): ``"swiglu"``, the gated
+``f_e(x) = down_e(silu(gate_e(x)) * up_e(x))`` over ``w_gate_up [E, C, 2F]``
+(three matrices an expert), or ``"relu2"``, the ungated ``f_e(x) =
+down_e(relu(up_e(x)) ** 2)`` over ``w_up [E, C, F]`` (two: Nemotron-H's).
 
 THE EXPERTS HELD. A layer is told which of the router's experts it holds: a
 contiguous range ``first .. first + held - 1`` (``dispatch``'s arguments),
@@ -25,8 +28,8 @@ of an expert-parallel layer otherwise (Granite 4.0-H Small: 36 of 72). The
 router runs over ALL experts either way; the layer computes the terms of
 the experts it holds and leaves out what the absent ones would add (another
 chip's part of the sum, which an all-to-all would bring: nothing here
-stands in for it). ``shared_ffn`` is the dense gated feed-forward every
-token takes beside its routed experts.
+stands in for it). ``shared_ffn`` is the dense feed-forward of the same form
+every token takes beside its routed experts.
 """
 
 import jax
@@ -93,14 +96,31 @@ def dispatch(weights, experts, held, first=0):
         return gate, jnp.sum(chosen, axis=(0, 1)).astype(jnp.float32)
 
 
-def expert_ffn(x, gate, w_gate_up, w_down):
-    """The chosen experts' gated feed-forward, summed with the router's
-    weights. x ``[T, C]``; gate ``[T, E]`` (``dispatch``; ``E`` the experts
-    held); w_gate_up ``[E, C, 2F]`` (gate then up); w_down ``[E, F, C]``.
-    Returns ``[T, C]`` in x's type.
+def first_matrix(act):
+    """(the name of an expert's FIRST matrix after ``w_`` / ``shared_``, how
+    many hidden widths its columns hold) in the form ``act``: gate and up
+    side by side, or up alone."""
+    return ("up", 1) if act == "relu2" else ("gate_up", 2)
+
+
+def _activate(h, act):
+    """An expert's hidden value from its first matmul's output ``h``
+    [.., 2F] (``"swiglu"``: gate then up) or [.., F] (``"relu2"``)."""
+    if act == "relu2":
+        return jnp.square(jax.nn.relu(h))
+    f = h.shape[-1] // 2
+    return jax.nn.silu(h[..., :f]) * h[..., f:]
+
+
+def expert_ffn(x, gate, w_in, w_down, act="swiglu"):
+    """The chosen experts' feed-forward, summed with the router's weights.
+    x ``[T, C]``; gate ``[T, E]`` (``dispatch``; ``E`` the experts held);
+    w_in ``[E, C, 2F]`` (``"swiglu"``: gate then up) or ``[E, C, F]``
+    (``"relu2"``: up alone); w_down ``[E, F, C]``. Returns ``[T, C]`` in x's
+    type.
 
     Every held expert computes every token, and the gate (0 for an expert a
-    token did not choose) picks the sum: three plain matmuls (``combine``
+    token did not choose) picks the sum: plain matmuls (``combine``
     folds the gate in BEFORE the down projection, so no ``[T, E, C]`` value
     is formed). A decode batch touches nearly every expert anyway (32 rows
     of top-8 of 64 leave 1.4% untouched), so the step is bound by streaming
@@ -110,24 +130,23 @@ def expert_ffn(x, gate, w_gate_up, w_down):
     tokens: the grouped matmul wants its layer of the stacked weights copied
     out first (PERF.md section 6, PR 27). A chip's share of a sharded layer
     is touched as fully (64 rows of top-10 of 72 leave 0.007% of 36 held
-    experts untouched), so the same holds there; a deployment that prefills
+    experts untouched; 64 of top-6 of 128 leave 4.6% of 16), so the same
+    holds there; a deployment that prefills
     thousands of tokens a call is where grouping pays, and is not served
     here yet."""
-    f = w_gate_up.shape[-1] // 2
     with jax.named_scope("experts"):
-        gu = jnp.einsum("tc,ecf->tef", x, w_gate_up.astype(x.dtype))
-        h = jax.nn.silu(gu[..., :f]) * gu[..., f:]
+        h = _activate(jnp.einsum("tc,ecf->tef", x, w_in.astype(x.dtype)),
+                      act)
     with jax.named_scope("combine"):
         h = (h.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
     with jax.named_scope("experts"):
         return jnp.einsum("tef,efc->tc", h, w_down.astype(x.dtype))
 
 
-def shared_ffn(x, w_gate_up, w_down):
-    """The shared expert: the same gated form, every token, weight 1.
-    x ``[T, C]``; w_gate_up ``[C, 2F]`` (gate then up); w_down ``[F, C]``."""
-    f = w_gate_up.shape[-1] // 2
+def shared_ffn(x, w_in, w_down, act="swiglu"):
+    """The shared expert: the same form, every token, weight 1.
+    x ``[T, C]``; w_in ``[C, 2F]`` (gate then up) or ``[C, F]`` (``act``
+    ``"relu2"``); w_down ``[F, C]``."""
     with jax.named_scope("shared"):
-        gu = x @ w_gate_up.astype(x.dtype)
-        return (jax.nn.silu(gu[..., :f]) * gu[..., f:]) \
+        return _activate(x @ w_in.astype(x.dtype), act) \
             @ w_down.astype(x.dtype)

@@ -164,9 +164,44 @@ SETUP_ROWS_AS_PR_53_LEFT_THEM = tuple(
     for pin in ("dsv3", "kimi", "lfm2", "sdar"))
 
 
+# TWELVE cases of ``tests/benchmark/test_phi4flash.py`` that hold what the
+# twelve above held the day PR 55 landed (PR 58), in a file this PR may not
+# edit: eight say each ``setup_*`` row lists the eleven cells AND PR 55's cell
+# and NO OTHER (``test_a_setup_row_stands_as_pr_53_left_it_and_lists_this_
+# cell``), four that PR 55's five metrics are the manifest's LAST per-layer
+# entries (``test_a_pin_of_a_cells_exact_set_holds_beside_the_eight_and_the_
+# five``). Neither can stay true, for the reason the twelve above could not:
+# PR 58's cell reports ``setup_s`` and is appended to each row's list (its
+# issue names the lists), and its two per-layer metrics are appended after the
+# five. Every OTHER line of the twelve still holds and is held, word for word
+# and case for case, by ``tests/benchmark/test_nemotron_h.py``
+# (``test_a_setup_row_stands_as_pr_55_left_it_and_lists_this_cell`` x 8,
+# ``test_a_pin_of_a_cells_exact_set_holds_beside_the_eight_five_and_two`` x
+# 4). The markers are STRICT: the day a ``benchmark`` PR drops the two stale
+# lines, the cases pass, the markers turn that into failures, and these lines
+# go.
+PINS_AS_PR_55_LEFT_THEM = tuple(
+    "tests/benchmark/test_phi4flash.py::"
+    "test_a_setup_row_stands_as_pr_53_left_it_and_lists_this_cell[{}]"
+    .format(name) for name in (
+        "setup_boot_s", "setup_engine_init_s", "setup_trace_s",
+        "setup_lower_s", "setup_compile_s", "setup_warm_s", "setup_programs",
+        "setup_cache_misses")) + tuple(
+    "tests/benchmark/test_phi4flash.py::"
+    "test_a_pin_of_a_cells_exact_set_holds_beside_the_eight_and_the_five[{}]"
+    .format(pin) for pin in ("dsv3", "kimi", "lfm2", "sdar"))
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
-        if item.nodeid in SETUP_ROWS_AS_PR_53_LEFT_THEM:
+        if item.nodeid in PINS_AS_PR_55_LEFT_THEM:
+            item.add_marker(pytest.mark.xfail(
+                raises=AssertionError, strict=True,
+                reason="pins the setup_* rows as listing twelve cells and PR "
+                "55's five metrics as standing last (tests/conftest.py; "
+                "tests/benchmark/test_nemotron_h.py holds every other line "
+                "of it)"))
+        elif item.nodeid in SETUP_ROWS_AS_PR_53_LEFT_THEM:
             item.add_marker(pytest.mark.xfail(
                 raises=AssertionError, strict=True,
                 reason="pins PR 53's eight setup_* rows as listing eleven "
